@@ -1,0 +1,624 @@
+"""chip_smoke.py — the standing proof that the main path starts on the chip.
+
+    python chip_smoke.py        (on a machine with a TPU; no arguments)
+
+One process drives, through the entry points a user calls, at full width:
+
+- ``device``    a TPU backend, or exit non-zero with one line
+- ``executor``  ResNet-18 / CIFAR shapes, bf16, SGD, through ``ht.Executor``
+- ``flagship``  BERT-base (768/12/12/3072/30522, seq 512) through
+                ``bert.make_pretrain_step``: flash attention + fused MLM CE
+- ``ps``        Wide&Deep through a live local PS cluster (Hybrid, prefetch)
+- ``kernels``   every Pallas kernel compiled, against its XLA fallback
+- ``multichip`` (more than one chip visible) dp=4 Executor, dp2 x tp2 BERT,
+                ``dryrun_multichip`` on the real devices
+
+Each phase prints one JSON line; any failed check raises, so the exit code
+is non-zero at the first failed phase and no result line is printed. The
+last stdout line of a full pass is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}``.
+
+The phase functions take their sizes as arguments and ``chip=False`` drops
+the checks only a TPU can meet, so tests/test_chip_smoke.py drives the
+same code at tiny sizes under the CPU pin. Weights and data are random,
+made from seeds; nothing is read from the network or from git.
+"""
+import contextlib
+import dataclasses
+import json
+import sys
+import time
+
+import numpy as np
+
+# examples/<suite>/models under the bare name ``models``; bench.py is
+# jax-free to import
+from bench import _import_models  # noqa: E402
+
+# Mosaic kernels appear in compiled HLO as this custom-call target; an
+# interpret-mode pallas_call lowers to plain HLO and never does
+_MOSAIC = "tpu_custom_call"
+
+
+# ---------------------------------------------------------------------------
+# shared plumbing
+# ---------------------------------------------------------------------------
+
+class _CompileCounter:
+    """Persistent-cache hits and misses, from jax's own monitoring events:
+    a warm second run must report zero misses."""
+
+    def __init__(self):
+        from jax import monitoring
+        self.hits = self.misses = 0
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, name, **_kw):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snapshot(self):
+        return self.hits, self.misses
+
+
+_COUNTER = None
+
+
+def _device_fields():
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "devices": len(jax.devices())}
+
+
+@contextlib.contextmanager
+def _phase(name, record):
+    """Time a phase and print its JSON line on success. ``record`` is the
+    dict the phase body fills; a raise propagates (no line, non-zero exit)."""
+    before = _COUNTER.snapshot() if _COUNTER else (0, 0)
+    t0 = time.time()
+    yield
+    after = _COUNTER.snapshot() if _COUNTER else (0, 0)
+    line = {"phase": name, **_device_fields(), **record,
+            "phase_s": round(time.time() - t0, 2),
+            "cache_hits": after[0] - before[0],
+            "cache_misses": after[1] - before[1]}
+    print(json.dumps(line), flush=True)
+
+
+def _check(cond, what):
+    if not cond:
+        raise AssertionError(f"chip_smoke: {what}")
+
+
+def _finite(x):
+    return bool(np.all(np.isfinite(np.asarray(x, np.float32))))
+
+
+def _timed_steps(step_once, steps):
+    """Run ``steps`` steps, each closed by a host read of its loss (so no
+    interval measures the enqueue). Returns the timing fields of the phase
+    line — seconds to the first step (compilation included), seconds for
+    the rest, and the median of the later steps — and the losses."""
+    losses, dts = [], []
+    for _ in range(steps):
+        t0 = time.time()
+        losses.append(float(step_once()))
+        dts.append(time.time() - t0)
+    timing = {"first_step_s": round(dts[0], 2),
+              "rest_s": round(sum(dts[1:]), 3), "steps": steps,
+              "late_step_ms_median": round(
+                  1e3 * float(np.median(dts[len(dts) // 2:])), 2),
+              "first_loss": round(losses[0], 4),
+              "last_loss": round(losses[-1], 4)}
+    return timing, losses
+
+
+def _on_tpu_devices(arrays, n_devices=1):
+    """Every array lives on exactly ``n_devices`` TPU devices."""
+    for a in arrays:
+        devs = a.sharding.device_set
+        _check(all(d.platform == "tpu" for d in devs),
+               f"array on {sorted(d.platform for d in devs)}, not tpu")
+        _check(len(devs) == n_devices,
+               f"array spans {len(devs)} device(s), layout says {n_devices}")
+
+
+def _hbm_in_use(min_bytes):
+    """``bytes_in_use`` per chip; every chip must hold at least
+    ``min_bytes`` — code that never saw two devices may fill only one."""
+    import jax
+    used = [int(d.memory_stats()["bytes_in_use"]) for d in jax.devices()]
+    _check(all(u >= min_bytes for u in used),
+           f"bytes_in_use per chip {used}: some chip holds < {min_bytes}")
+    return used
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+def phase_device():
+    """Fail unless the backend is a TPU. Returns the contract's device dict."""
+    import jax
+    import jaxlib
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise SystemExit(
+            f"chip_smoke: device phase failed: jax backend is {backend!r} "
+            f"({jax.devices()[0].device_kind}), not a TPU")
+    from hetu_tpu.utils import use_compile_cache
+    cache_dir = use_compile_cache()
+    from importlib import metadata
+    rec = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+           "libtpu": metadata.version("libtpu"), "compile_cache": cache_dir}
+    with _phase("device", rec):
+        pass
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+# ---------------------------------------------------------------------------
+# executor: ResNet-18 as examples/cnn/main.py builds and runs it
+# ---------------------------------------------------------------------------
+
+def phase_executor(*, batch=128, steps=30, n_batches=8, comm_mode=None,
+                   chip=True, name="executor"):
+    import hetu_tpu as ht
+    from hetu_tpu.kernels import registry
+    models = _import_models("cnn")
+
+    rec = {}
+    with _phase(name, rec):
+        # the generator ht.data.cifar10() falls back to when no dataset is
+        # on disk, at the size the phase needs (the full 50,000 take 20 s)
+        train_x, labels = ht.data._synthetic_classification(
+            batch * n_batches, (3, 32, 32), 10, seed=11)
+        train_y = ht.data.convert_to_one_hot(labels, max_val=10)
+        x = ht.dataloader_op([ht.Dataloader(train_x, batch, "train")])
+        y_ = ht.dataloader_op([ht.Dataloader(train_y, batch, "train")])
+        with contextlib.redirect_stdout(sys.stderr):   # "Building ..." banner
+            loss, _y = models.resnet18(x, y_, 10)
+        train_op = ht.optim.SGDOptimizer(learning_rate=0.1).minimize(loss)
+        registry.reset_stats()
+        ex = ht.Executor({"train": [loss, train_op]}, ctx=ht.tpu(0), seed=0,
+                         dtype="bfloat16", comm_mode=comm_mode)
+        timing, losses = _timed_steps(
+            lambda: np.mean(ex.run("train")[0].asnumpy()), steps)
+        params = list(ex.state["params"].values())
+        import jax
+        jax.block_until_ready(params)
+        _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+        k = max(1, steps // 5)
+        _check(np.mean(losses[-k:]) < np.mean(losses[:k]),
+               f"loss not falling: {losses}")
+        stats = registry.dispatch_stats()
+        n_dev = 1 if ex.config.mesh is None else ex.config.mesh.size
+        if chip:
+            _on_tpu_devices(params, n_dev)
+            if n_dev == 1:
+                _check(stats.get(("fused_sgd", "pallas"), 0) > 0,
+                       f"fused_sgd never took the Pallas path: {stats}")
+                hlo = ex.subexecutors["train"].dump_hlo(stage="optimized")
+                _check(_MOSAIC in hlo, "no Mosaic custom call in the "
+                       "compiled ResNet step")
+            else:
+                rec["hbm_bytes_in_use"] = _hbm_in_use(1 << 20)
+        rec.update({"model": "resnet18", "batch": batch, "dtype": "bfloat16",
+                    "mesh": None if ex.config.mesh is None
+                    else dict(ex.config.mesh.shape),
+                    **timing, "dispatch": _dispatch_report()})
+        ex.close()
+    return rec
+
+
+def _dispatch_report():
+    """``dispatch_stats()`` as printable rows, every fallback with the
+    reason that caused it. A backend reason on the chip is a failure: the
+    only admissible reasons are shapes."""
+    from hetu_tpu.kernels import registry
+    rows = {f"{k}:{path}": n
+            for (k, path), n in sorted(registry.dispatch_stats().items())}
+    reasons = {f"{k}: {why}": n
+               for (k, why), n in sorted(registry.fallback_reasons().items())}
+    return {"counts": rows, "fallback_reasons": reasons}
+
+
+def _check_fallback_reasons():
+    from hetu_tpu.kernels import registry
+    for (kernel, why), _n in registry.fallback_reasons().items():
+        _check("backend" not in why,
+               f"{kernel} fell back for a backend reason on the chip: {why}")
+
+
+# ---------------------------------------------------------------------------
+# flagship: BERT-base through bert.make_pretrain_step
+# ---------------------------------------------------------------------------
+
+def _bert_batch(rng, cfg, batch, seq, n_pred, padded):
+    b = {
+        "input_ids": rng.randint(0, cfg.vocab_size,
+                                 (batch, seq)).astype(np.int32),
+        "segment_ids": (rng.rand(batch, seq) > 0.5).astype(np.int32),
+        "mlm_positions": np.sort(rng.randint(
+            1, seq, (batch, n_pred)).astype(np.int32), axis=1),
+        "mlm_ids": rng.randint(0, cfg.vocab_size,
+                               (batch, n_pred)).astype(np.int32),
+        "mlm_weights": np.ones((batch, n_pred), np.float32),
+        "nsp_label": rng.randint(0, 2, (batch,)).astype(np.int32),
+    }
+    if padded:
+        b["input_mask"] = (np.arange(seq)[None, :] < rng.randint(
+            seq // 2, seq + 1, (batch, 1))).astype(np.int32)
+    return b
+
+
+def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
+                   ref_batch=2, mesh_axes=None, chip=True, name="flagship"):
+    """BERT pretrain steps on an unpadded then a padded batch; the loss
+    must stay finite (at lr 1e-4 without warm-up AdamW's first steps
+    overshoot at BERT-base size, so "falling" is not asked here). On one
+    chip the loss of the kernel path (flash + fused CE) is also compared
+    with the unfused dot/einsum reference on ``ref_batch`` rows."""
+    import jax
+    from hetu_tpu.kernels.fused_ce import should_fuse
+    from hetu_tpu.models import bert
+    from hetu_tpu.models import transformer as tfm
+
+    cfg = cfg or bert.BERT_BASE
+    rec = {}
+    with _phase(name, rec):
+        mesh = None
+        if mesh_axes:
+            from hetu_tpu.parallel import mesh as meshlib
+            mesh = meshlib.make_mesh(**mesh_axes)
+        impl = tfm._resolve_attn_impl(cfg.trunk(), mesh, seq)
+        masked_impl = tfm._resolve_attn_impl(
+            cfg.trunk(), mesh, seq, jax.numpy.zeros((batch, 1, 1, seq)))
+        fused = should_fuse(cfg.fused_mlm_ce, mesh)
+        if chip:
+            _check(impl == "flash" and masked_impl == "flash",
+                   f"attn_impl resolved to {impl!r}/{masked_impl!r}")
+            _check(fused or mesh is not None, "fused MLM CE not engaged")
+
+        params = bert.init_params(jax.random.PRNGKey(0), cfg)
+        opt = bert.init_opt_state(params)
+        if mesh is not None:
+            # tfm.shard_params knows the trunk's tree only; BERT's heads
+            # ride bert.param_specs through the same placement recipe
+            specs = bert.param_specs(cfg)
+            params = jax.device_put(params, jax.tree.map(
+                lambda s: jax.sharding.NamedSharding(mesh, s), specs,
+                is_leaf=lambda x: isinstance(
+                    x, jax.sharding.PartitionSpec)))
+            opt = tfm.place_opt_state(opt, specs, mesh)
+        step = bert.make_pretrain_step(cfg, mesh=mesh, lr=1e-4)
+        rng = np.random.RandomState(0)
+        timings = {}
+        for label, padded in (("unpadded", False), ("padded", True)):
+            b = _bert_batch(rng, cfg, batch, seq, n_pred, padded)
+
+            def once():
+                nonlocal params, opt
+                loss, _parts, params, opt = step(params, opt, b)
+                return loss
+
+            timing, losses = _timed_steps(once, steps)
+            _check(all(np.isfinite(losses)),
+                   f"{label}: non-finite loss {losses}")
+            timings[label] = {**timing,
+                              "losses": [round(v, 4) for v in losses]}
+            if chip and mesh is None:
+                hlo = step.lower(params, opt, b).compile().as_text()
+                _check(_MOSAIC in hlo,
+                       f"{label}: no Mosaic custom call in the compiled step")
+        jax.block_until_ready(params)
+        leaves = jax.tree.leaves(params)
+        if chip:
+            if mesh is None:
+                _on_tpu_devices(leaves, 1)
+            else:
+                # tp-sharded and replicated leaves alike span the mesh
+                _on_tpu_devices(leaves, mesh.size)
+                rec["hbm_bytes_in_use"] = _hbm_in_use(1 << 20)
+
+        if mesh is None:
+            # the repo's own reference: the same trained weights through
+            # the unfused attention and the materialized-logits CE, on a
+            # small padded batch — final hidden states and loss must agree
+            ref_cfg = dataclasses.replace(cfg, attn_impl="dot",
+                                          fused_mlm_ce=False)
+            rb = _bert_batch(rng, cfg, ref_batch, seq, n_pred, True)
+
+            def loss_and_hidden(c):
+                return jax.jit(lambda p, b: (
+                    bert.pretrain_loss(p, b, c, None)[0],
+                    bert.encode(p, b["input_ids"], b["segment_ids"], c,
+                                None, b["input_mask"])))(params, rb)
+
+            got, h_got = loss_and_hidden(cfg)
+            want, h_want = loss_and_hidden(ref_cfg)
+            got, want = float(got), float(want)
+            h_err = float(np.max(np.abs(
+                np.asarray(h_got, np.float32)
+                - np.asarray(h_want, np.float32))))
+            h_scale = float(np.max(np.abs(np.asarray(h_want, np.float32))))
+            _check(abs(got - want) <= 2e-2 * abs(want),
+                   f"kernel-path loss {got} vs reference {want}")
+            _check(np.isfinite(h_err) and h_err <= 5e-2 * h_scale,
+                   f"hidden states differ by {h_err} (scale {h_scale})")
+            rec["vs_reference"] = {"loss": round(got, 5),
+                                   "reference_loss": round(want, 5),
+                                   "hidden_max_abs_err": round(h_err, 5),
+                                   "hidden_max_abs": round(h_scale, 3)}
+        rec.update({"model": "bert", "d_model": cfg.d_model,
+                    "n_heads": cfg.n_heads, "n_layers": cfg.n_layers,
+                    "d_ff": cfg.d_ff, "vocab": cfg.vocab_size, "seq": seq,
+                    "batch": batch, "attn_impl": impl,
+                    "masked_attn_impl": masked_impl,
+                    "mlm_ce": "fused" if fused else "einsum",
+                    "mesh": None if mesh is None else dict(mesh.shape),
+                    "n_params": bert.count_params(params), **timings})
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# ps: Wide&Deep through a live local PS cluster
+# ---------------------------------------------------------------------------
+
+def phase_ps(*, batch=128, steps=20, feature_dim=100000, embedding_size=16,
+             n_servers=2, chip=True):
+    from hetu_tpu import ps as ps_pkg
+    from hetu_tpu.chaos import check_update_accounting
+    from hetu_tpu.kernels import registry
+    from hetu_tpu.ps.local_cluster import local_cluster
+
+    rec = {}
+    with _phase("ps", rec):
+        registry.reset_stats()
+        with local_cluster(n_servers=n_servers, n_workers=1):
+            import hetu_tpu as ht
+            models = _import_models("ctr")
+            from models.load_data import load_criteo_data
+            (dense_x, sparse_x, y), _ = load_criteo_data(
+                feature_dimension=feature_dim, n_train=batch * 8, n_test=64)
+            dense = ht.dataloader_op([ht.Dataloader(dense_x, batch, "train")])
+            sparse = ht.dataloader_op(
+                [ht.Dataloader(sparse_x, batch, "train")])
+            y_ = ht.dataloader_op([ht.Dataloader(y, batch, "train")])
+            loss, _y, _labels, train_op = models.wdl_criteo(
+                dense, sparse, y_, feature_dimension=feature_dim,
+                embedding_size=embedding_size)
+            ex = ht.Executor({"train": [loss, train_op]}, ctx=ht.tpu(0),
+                             comm_mode="Hybrid", seed=0, prefetch=True)
+            try:
+                timing, losses = _timed_steps(
+                    lambda: np.mean(ex.run("train")[0].asnumpy()), steps)
+                ex.ps_runtime.drain()
+                comm = ps_pkg.get_worker_communicate()
+                acct = check_update_accounting(
+                    comm.ClientStats(),
+                    [comm.ServerStats(s) for s in range(n_servers)])
+                _check(acct["client_pushes_ok"] > 0, "no PS push completed")
+                _check(all(np.isfinite(losses)),
+                       f"non-finite loss in {losses}")
+                mesh = ex.config.mesh   # Hybrid: dp over every chip visible
+                if chip:
+                    _on_tpu_devices(ex.state["params"].values(),
+                                    1 if mesh is None else mesh.size)
+                perf = dict(ex.ps_runtime.perf)
+            finally:
+                ex.close()
+                ps_pkg.worker_finish()
+        rec.update({"model": "wdl_criteo", "batch": batch,
+                    "table": [feature_dim, embedding_size],
+                    "servers": n_servers, "comm_mode": "Hybrid",
+                    "mesh": None if mesh is None else dict(mesh.shape),
+                    **timing,
+                    "pushes_ok": acct["client_pushes_ok"],
+                    "server_updates": acct["server_updates"],
+                    "ps_perf": perf, "dispatch": _dispatch_report()})
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# kernels: each Pallas kernel compiled, against its XLA fallback
+# ---------------------------------------------------------------------------
+
+# one eligible shape per kernel, taken from the phases above or from the
+# bench cell that uses the kernel
+KERNEL_SHAPES = {
+    "flash": (2, 12, 512, 64),           # BERT-base heads at seq 512, bf16
+    "fused_ce": (256, 768, 30522),       # MLM rows x d_model x vocab, bf16
+    "embed_grad": (3328, 128, 100000),   # 128 x 26 slots, published width
+    "csr_spmm": (4096, 1024, 1024, 128),  # nnz, nrow, K, F
+    "quant": (512 * 512, 256),           # the comm_quant_dp MLP grad, block
+    "opt": (512, 512, 3, 3),             # ResNet-18's largest conv weight
+}
+
+
+def phase_kernels(*, shapes=None, chip=True):
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu import comm_quant
+    from hetu_tpu.kernels import (csr_spmm, embed_grad, fused_opt,
+                                  quant_comm, registry)
+    from hetu_tpu.kernels.flash_attention import (flash_attention,
+                                                  mha_reference)
+    from hetu_tpu.kernels.fused_ce import (fused_linear_nll,
+                                           linear_nll_reference)
+
+    shapes = {**KERNEL_SHAPES, **(shapes or {})}
+    rng = np.random.RandomState(0)
+    results = {}
+
+    def compare(name, kernel_fn, fallback_fn, args, *, atol, rtol=0.0,
+                exact=False):
+        """Both sides under jit (eager XLA differs from compiled XLA by
+        1-ulp rewrites); the kernel side must hold a Mosaic custom call."""
+        def forced(*a):
+            with registry.active("force"):
+                return kernel_fn(*a)
+
+        def off(*a):
+            with registry.active("off"):
+                return fallback_fn(*a)
+
+        kj = jax.jit(forced)
+        if chip:
+            _check(_MOSAIC in kj.lower(*args).compile().as_text(),
+                   f"{name}: kernel side has no Mosaic custom call "
+                   "(interpret mode?)")
+        got = jax.tree.leaves(jax.tree.map(np.asarray, kj(*args)))
+        want = jax.tree.leaves(jax.tree.map(np.asarray,
+                                            jax.jit(off)(*args)))
+        _check(len(got) == len(want), f"{name}: output structure differs")
+        err = 0.0
+        for g, w in zip(got, want):
+            g32, w32 = g.astype(np.float32), w.astype(np.float32)
+            _check(g.shape == w.shape and _finite(g32),
+                   f"{name}: bad shape or non-finite output")
+            err = max(err, float(np.max(np.abs(g32 - w32))) if g.size else 0)
+            if exact:
+                _check(np.array_equal(g, w), f"{name}: not bit-identical "
+                       f"(max abs err {err})")
+            else:
+                np.testing.assert_allclose(g32, w32, atol=atol, rtol=rtol,
+                                           err_msg=f"chip_smoke: {name}")
+        results[name] = {"max_abs_err": err}
+
+    rec = {"kernels": results}
+    with _phase("kernels", rec):
+        # -- flash attention, fwd + bwd, bf16 (tests/test_attention.py's
+        # bf16 tolerance) -----------------------------------------------
+        b, h, s, d = shapes["flash"]
+        q, k, v = (jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
+                   for _ in range(3))
+        k_bias = jnp.asarray(np.where(
+            np.arange(s)[None, :] < rng.randint(s // 2, s + 1, (b, 1)),
+            0.0, -1e9), jnp.float32)
+
+        def attn_and_grads(fn, causal, bias):
+            def run(q, k, v):
+                def loss(q, k, v):
+                    return jnp.sum(fn(q, k, v, causal=causal, k_bias=bias)
+                                   .astype(jnp.float32) ** 2)
+                return (fn(q, k, v, causal=causal, k_bias=bias),
+                        jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+            return run
+
+        for label, causal, bias in (("flash_causal", True, None),
+                                    ("flash_key_padding", False, k_bias)):
+            compare(label, attn_and_grads(flash_attention, causal, bias),
+                    attn_and_grads(mha_reference, causal, bias), (q, k, v),
+                    atol=2e-2 * max(1.0, float(s) ** 0.5), rtol=2e-2)
+
+        # -- fused linear + softmax CE, fwd + bwd, bf16 -------------------
+        n, dm, vocab = shapes["fused_ce"]
+        hh = jnp.asarray(rng.randn(n, dm) * 0.5, jnp.bfloat16)
+        ww = jnp.asarray(rng.randn(vocab, dm) * 0.05, jnp.bfloat16)
+        bb = jnp.asarray(rng.randn(vocab) * 0.1, jnp.float32)
+        tt = jnp.asarray(rng.randint(0, vocab, (n,)), jnp.int32)
+
+        def ce_and_grads(fn):
+            def run(h, w, b):
+                return (fn(h, w, b, tt),
+                        jax.grad(lambda h, w, b: jnp.mean(fn(h, w, b, tt)),
+                                 argnums=(0, 1, 2))(h, w, b))
+            return run
+
+        compare("fused_ce", ce_and_grads(fused_linear_nll),
+                ce_and_grads(linear_nll_reference), (hh, ww, bb),
+                atol=2e-2, rtol=2e-2)
+
+        # -- the four registry kernels (bench.py's kernels-cell tolerances)
+        n, d, vocab = shapes["embed_grad"]
+        ev = jnp.asarray(rng.randn(n, d).astype(np.float32))
+        ei = jnp.asarray((rng.zipf(1.3, n) % vocab).astype(np.int32))
+        rows_fn = lambda v, i: embed_grad.embed_grad_rows(v, i, vocab)  # noqa
+        compare("fused_embed_grad", rows_fn, rows_fn, (ev, ei), atol=1e-4,
+                rtol=1e-5)
+
+        nnz, nrow, kk, f = shapes["csr_spmm"]
+        sv = jnp.asarray(rng.randn(nnz).astype(np.float32))
+        sr = jnp.asarray(rng.randint(0, nrow, nnz).astype(np.int32))
+        sc = jnp.asarray(rng.randint(0, kk, nnz).astype(np.int32))
+        sb = jnp.asarray(rng.randn(kk, f).astype(np.float32))
+        spmm = lambda v, r, c, b: csr_spmm.coo_matmat(v, r, c, nrow, b)  # noqa
+        compare("csr_spmm", spmm, spmm, (sv, sr, sc, sb), atol=1e-4,
+                rtol=1e-5)
+
+        nq, block = shapes["quant"]
+        qx = jnp.asarray(rng.randn(nq).astype(np.float32))
+        compare("quant_blocks",
+                lambda x: quant_comm.quantize_blocks(x, block, "int8")[:2],
+                lambda x: comm_quant.quantize_blocks(x, block, "int8")[:2],
+                (qx,), atol=0, exact=True)
+        qq, qs, _n = comm_quant.quantize_blocks(qx, block, "int8")
+        compare("dequant_blocks",
+                lambda q, s: quant_comm.dequantize_blocks(q, s, nq, block),
+                lambda q, s: comm_quant.dequantize_blocks(q, s, nq, block),
+                (qq, qs), atol=0, exact=True)
+
+        class _Opt:
+            beta1, beta2, epsilon = 0.9, 0.999, 1e-7
+            weight_decay, l2reg = 0.01, 1e-4
+
+        p, g, m = (jnp.asarray(rng.randn(*shapes["opt"]).astype(np.float32))
+                   for _ in range(3))
+        vv = jnp.abs(jnp.asarray(rng.randn(*shapes["opt"]), jnp.float32))
+        adam = lambda p, g, m, v: fused_opt.adam_step(  # noqa: E731
+            _Opt, p, g, {"m": m, "v": v, "t": jnp.float32(3.0)}, 0.01)
+        compare("fused_adam", adam, adam, (p, g, m, vv), atol=1e-6,
+                rtol=1e-6)
+        sgd = lambda p, g: fused_opt.sgd_step(_Opt, p, g, 0.1)  # noqa: E731
+        compare("fused_sgd", sgd, sgd, (p, g), atol=1e-6, rtol=1e-6)
+        if chip:
+            _check_fallback_reasons()
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# multichip: runs whenever more than one chip is visible
+# ---------------------------------------------------------------------------
+
+def phase_multichip(n_devices):
+    """(a) the executor phase under AllReduce on the deduced dp mesh,
+    (b) the flagship phase on dp2 x tp2 with sharded params and optimizer
+    state, (c) dryrun_multichip on the real devices. The registry kernels
+    decline inside multi-device programs (docs/KERNELS.md, "Partitioned
+    programs"); their counts are printed, not asserted."""
+    rec = {}
+    with _phase("multichip", rec):
+        a = phase_executor(comm_mode="AllReduce", name="multichip:executor")
+        _check(a["mesh"] == {"dp": n_devices},
+               f"deduced mesh {a['mesh']}, expected dp={n_devices}")
+        b = phase_flagship(mesh_axes={"dp": n_devices // 2, "tp": 2},
+                           name="multichip:flagship")
+        import __graft_entry__ as graft
+        graft.dryrun_multichip(n_devices)
+        rec.update({"executor_mesh": a["mesh"], "flagship_mesh": b["mesh"],
+                    "kernel_dispatch": a["dispatch"]["counts"]})
+    return rec
+
+
+def main():
+    global _COUNTER
+    device = phase_device()          # exits non-zero off-TPU, first of all
+    _COUNTER = _CompileCounter()
+    phase_executor()
+    _check_fallback_reasons()
+    phase_flagship()
+    phase_ps()
+    _check_fallback_reasons()
+    phase_kernels()
+    if device["count"] > 1:
+        phase_multichip(device["count"])
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -157,9 +157,8 @@ def host_transfer_findings(sub) -> list[Finding]:
 
 
 def cost_analysis_of(sub) -> Optional[dict]:
-    """Cost analysis dict of the latest executed step, or None.
-    ``SubExecutor.last_cost_analysis`` owns the jax-version normalization
-    (0.4.x wraps the dict in a list); this is the analysis-side alias."""
+    """Cost analysis dict of the latest executed step, or None — the
+    analysis-side alias of ``SubExecutor.last_cost_analysis``."""
     return sub.last_cost_analysis()
 
 

@@ -30,7 +30,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
-BLOCK_NNZ = 256
+# nnz entries per grid step: the tile XLA lays a 1-D array out in, which is
+# the only 1-D block shape Mosaic accepts for an SMEM operand
+BLOCK_NNZ = 1024
 _LANE = registry.LANE
 _SUBLANE = registry.SUBLANE
 # the whole (nrow, F) output block plus the (K, F) dense operand live in

@@ -94,12 +94,16 @@ def _segsum_kernel(seg_ref, g_ref, o_ref, *, block_rows, n):
     k0 = i * block_rows
 
     def body(jb, acc):
-        seg = seg_ref[pl.ds(jb * block_rows, block_rows)]
+        seg = seg_ref[pl.ds(jb, 1), :]                  # (1, block_rows)
         g = g_ref[pl.ds(jb * block_rows, block_rows), :]
         krow = k0 + jax.lax.broadcasted_iota(
             jnp.int32, (block_rows, block_rows), 0)
-        m = (krow == seg[None, :]).astype(jnp.float32)
-        return acc + jax.lax.dot(m, g, preferred_element_type=jnp.float32)
+        m = (krow == seg).astype(jnp.float32)
+        # HIGHEST: at default precision the MXU rounds the f32 grads to
+        # bf16 (3e-3 relative on the chip) — the sums must stay f32-exact
+        # like the segment_sum fallback's adds
+        return acc + jax.lax.dot(m, g, precision=jax.lax.Precision.HIGHEST,
+                                 preferred_element_type=jnp.float32)
 
     acc0 = jnp.zeros((block_rows, g_ref.shape[1]), jnp.float32)
     o_ref[:] = jax.lax.fori_loop(0, n // block_rows, body, acc0)
@@ -111,13 +115,16 @@ def _segsum_pallas(sv, seg):
         functools.partial(_segsum_kernel, block_rows=BLOCK_ROWS, n=n),
         grid=(n // BLOCK_ROWS,),
         in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),
+            # ids ride as (n / 128, 128): one row per g block. A 1-D VMEM
+            # ref can only be sliced at multiples of its 1024-element
+            # tile, which Mosaic cannot prove of jb * 128.
+            pl.BlockSpec((n // BLOCK_ROWS, BLOCK_ROWS), lambda i: (0, 0)),
             pl.BlockSpec((n, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         interpret=not registry._on_tpu(),
-    )(seg, sv)
+    )(seg.reshape(n // BLOCK_ROWS, BLOCK_ROWS), sv)
     return out
 
 

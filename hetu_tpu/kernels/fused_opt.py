@@ -16,12 +16,14 @@ Numerical contract: the kernel body is the SAME expression sequence as
 off/auto/force agree to f32 rounding; the equality tests pin it.
 
 Layout: parameters arrive in their natural shapes; the kernel views them
-as lane-shaped ``(rows, 128)`` blocks, zero-padded up to the 8x128 f32
+as lane-shaped ``(rows, 128)`` arrays, zero-padded up to the 8x128 f32
 tile and sliced back — elementwise kernels can always be tiled by
 padding, so only dtype (f32 master precision) disqualifies a call, and
 the whole parameter set of a real model (odd biases included) rides the
-fused pass. An optional extra addend (e.g. a decoded error-feedback
-residual folded into the grad) rides the same pass.
+fused pass. The call is a 1-D grid over ``_BLOCK_ROWS``-row blocks, so a
+tensor of any size streams through a bounded VMEM footprint (the last
+block may be partial: Pallas pads its reads and drops its out-of-range
+writes, which an elementwise rule tolerates).
 """
 from __future__ import annotations
 
@@ -37,6 +39,23 @@ from . import registry
 _LANE = registry.LANE
 _SUBLANE = registry.SUBLANE
 _TILE = _LANE * _SUBLANE
+# rows per grid step: one (1024, 128) f32 block is 512 KiB, and Adam's
+# seven operands (p, g, m, v in; p, m, v out), double-buffered by the
+# pipeline, hold 7 MiB — inside the registry's shared VMEM budget
+_BLOCK_ROWS = 1024
+assert 7 * 2 * _BLOCK_ROWS * _LANE * 4 <= registry.VMEM_BUDGET_BYTES
+
+
+def _row_grid(rows):
+    """(grid, vector BlockSpec) of the row-block sweep over a
+    ``(rows, 128)`` lane view."""
+    block = min(rows, _BLOCK_ROWS)
+    return ((pl.cdiv(rows, block),),
+            pl.BlockSpec((block, _LANE), lambda i: (i, 0)))
+
+
+_SCALAR = pl.BlockSpec(memory_space=pltpu.SMEM)
+_PARALLEL = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def _lane_view(x):
@@ -74,16 +93,18 @@ def _adam_xla(param, grad, m, v, t, lr, *, beta1, beta2, eps, weight_decay):
     return new_param, m, v, t
 
 
-def _adam_kernel(p_ref, g_ref, m_ref, v_ref, t_ref, lr_ref,
+def _adam_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
                  po_ref, mo_ref, vo_ref, *, beta1, beta2, eps, weight_decay):
-    t = t_ref[0, 0] + 1.0
-    lr = lr_ref[0, 0]
+    # sc_ref: (lr, 1 - beta1**t, 1 - beta2**t). The bias corrections are
+    # formed outside: Mosaic cannot legalize a scalar float power
+    # (math.powf), and XLA computes the same two scalars either way.
+    lr, c1, c2 = sc_ref[0, 0], sc_ref[0, 1], sc_ref[0, 2]
     g = g_ref[:]
     p = p_ref[:]
     m = beta1 * m_ref[:] + (1.0 - beta1) * g
     v = beta2 * v_ref[:] + (1.0 - beta2) * g * g
-    m_hat = m / (1.0 - beta1 ** t)
-    v_hat = v / (1.0 - beta2 ** t)
+    m_hat = m / c1
+    v_hat = v / c2
     new_p = p - lr * m_hat / (jnp.sqrt(v_hat) + eps)
     if weight_decay > 0:
         new_p = new_p - lr * weight_decay * p
@@ -97,20 +118,22 @@ def _adam_pallas(param, grad, m, v, t, lr, *, beta1, beta2, eps,
     shape = param.shape
     (pv, n), (gv, _), (mv, _), (vv, _) = (
         _lane_view(x) for x in (param, grad, m, v))
-    t_in = jnp.asarray(t, jnp.float32).reshape(1, 1)
-    lr_in = jnp.asarray(lr, jnp.float32).reshape(1, 1)
-    vec = pl.BlockSpec(memory_space=pltpu.VMEM)
-    sca = pl.BlockSpec(memory_space=pltpu.SMEM)
+    t1 = jnp.asarray(t, jnp.float32) + 1.0
+    scalars = jnp.stack([jnp.asarray(lr, jnp.float32), 1.0 - beta1 ** t1,
+                         1.0 - beta2 ** t1]).reshape(1, 3)
+    grid, vec = _row_grid(pv.shape[0])
     new_p, new_m, new_v = pl.pallas_call(
         functools.partial(_adam_kernel, beta1=beta1, beta2=beta2, eps=eps,
                           weight_decay=weight_decay),
-        in_specs=[vec, vec, vec, vec, sca, sca],
+        grid=grid,
+        in_specs=[vec, vec, vec, vec, _SCALAR],
         out_specs=[vec, vec, vec],
         out_shape=[jax.ShapeDtypeStruct(pv.shape, jnp.float32)] * 3,
+        compiler_params=_PARALLEL,
         interpret=not registry._on_tpu(),
-    )(pv, gv, mv, vv, t_in, lr_in)
+    )(pv, gv, mv, vv, scalars)
     return (_unview(new_p, n, shape), _unview(new_m, n, shape),
-            _unview(new_v, n, shape), jnp.asarray(t, jnp.float32) + 1.0)
+            _unview(new_v, n, shape), t1)
 
 
 def _sized_f32(name, x):
@@ -166,12 +189,14 @@ def _sgd_pallas(param, grad, lr, *, l2reg):
     pv, n = _lane_view(param)
     gv, _ = _lane_view(grad)
     lr_in = jnp.asarray(lr, jnp.float32).reshape(1, 1)
-    vec = pl.BlockSpec(memory_space=pltpu.VMEM)
+    grid, vec = _row_grid(pv.shape[0])
     out = pl.pallas_call(
         functools.partial(_sgd_kernel, l2reg=l2reg),
-        in_specs=[vec, vec, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        grid=grid,
+        in_specs=[vec, vec, _SCALAR],
         out_specs=vec,
         out_shape=jax.ShapeDtypeStruct(pv.shape, jnp.float32),
+        compiler_params=_PARALLEL,
         interpret=not registry._on_tpu(),
     )(pv, gv, lr_in)
     return _unview(out, n, shape)

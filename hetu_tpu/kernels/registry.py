@@ -84,6 +84,9 @@ _REGISTRY: dict[str, KernelSpec] = {}
 # telemetry counter so the hetulint fallback-ratio note works without an
 # active telemetry session.
 _stats: dict[tuple, int] = {}
+# why auto declined: {(kernel, reason): count}, so a run on the chip can
+# show that every fallback was a shape's doing and none the backend's
+_reasons: dict[tuple, int] = {}
 _stats_lock = threading.Lock()
 
 # scoped-mode stack (executor traces push config.kernels here); thread-local
@@ -164,10 +167,12 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _count(kernel: str, path: str) -> None:
+def _count(kernel: str, path: str, reason: Optional[str] = None) -> None:
     with _stats_lock:
         key = (kernel, path)
         _stats[key] = _stats.get(key, 0) + 1
+        if reason is not None:
+            _reasons[(kernel, reason)] = _reasons.get((kernel, reason), 0) + 1
     from .. import telemetry as _tel
     t = _tel.get()
     if t is not None:
@@ -182,9 +187,18 @@ def dispatch_stats() -> dict:
         return dict(_stats)
 
 
+def fallback_reasons() -> dict:
+    """``{(kernel, reason): count}`` for every dispatch ``auto`` served
+    from the fallback: the eligibility predicate's reason, or the backend
+    when an eligible shape met a non-TPU one."""
+    with _stats_lock:
+        return dict(_reasons)
+
+
 def reset_stats() -> None:
     with _stats_lock:
         _stats.clear()
+        _reasons.clear()
 
 
 def fallback_ratio(kernel: str) -> Optional[float]:
@@ -224,7 +238,8 @@ def dispatch(name: str, *args, **kwargs):
     if ok and _on_tpu():
         _count(name, "pallas")
         return spec.pallas_fn(*args, **kwargs)
-    _count(name, "fallback")
+    _count(name, "fallback",
+           "backend is not a tpu" if ok else (reason or "ineligible"))
     return spec.xla_fallback(*args, **kwargs)
 
 
@@ -256,37 +271,12 @@ def _partitioned_context() -> bool:
 
 
 def _in_named_axis_trace() -> bool:
-    """True inside a shard_map/pmap named-axis trace, where a pallas_call
+    """True inside a shard_map/pmap/named-vmap trace, where a pallas_call
     cannot be partitioned by GSPMD — eligibility predicates use this to
     decline (the DistGCN call site lives inside shard_map).
 
-    The probes read private jax internals, so version drift can make both
-    unusable. That failure FAILS CLOSED for ``auto`` (report 'inside', so
-    auto declines — the safe direction: a wrongly-attempted pallas_call
-    inside shard_map is a trace-time crash) but open for ``force`` — the
-    user explicitly demanded kernels, and a closed answer would turn every
-    forced call into a misleading 'inside a named-axis trace' error."""
-    probed = False
-    try:
-        import jax.core as jc
-        frame = getattr(jc, "thread_local_state", None)
-        if frame is not None:
-            env = getattr(frame.trace_state, "axis_env", None)
-            probed = True
-            if env:
-                return True
-    except Exception:  # noqa: BLE001 — version drift must not break dispatch
-        pass
-    try:
-        from jax._src.core import get_axis_env
-        env = get_axis_env()
-        names = getattr(env, "axis_names", None)
-        probed = True
-        if callable(names):
-            return bool(names())
-        return bool(getattr(env, "axis_sizes", None))
-    except Exception:  # noqa: BLE001
-        pass
-    if probed:
-        return False
-    return current_mode() != "force"
+    One probe, jax's own axis environment. It is a private name: if a jax
+    upgrade moves it, this raises at the first dispatch instead of making
+    ``auto`` decline every kernel without a word."""
+    from jax._src.core import get_axis_env
+    return bool(get_axis_env().axis_sizes)

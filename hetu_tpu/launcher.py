@@ -105,6 +105,11 @@ def launch(target, args):
     ctx = multiprocessing.get_context("spawn")
     n_workers = int(settings["launch"]["worker"])
     args.num_local_worker = n_workers
+    # one process per chip, exactly as heturun does it (refused here when
+    # there are more workers than chips); start_worker applies its env
+    # before the child's first jax backend touch
+    from hetu_tpu.runner import plan_local_chips, worker_chip_env
+    chips = plan_local_chips(n_workers, env)
     if settings["launch"].get("scheduler", 0):
         _procs.append(ctx.Process(target=start_sched, args=(env,)))
     server_procs = {}
@@ -113,7 +118,8 @@ def launch(target, args):
         _procs.append(server_procs[i])
     workers = []
     for i in range(n_workers):
-        p = ctx.Process(target=start_worker, args=(target, args, i, env))
+        p = ctx.Process(target=start_worker, args=(
+            target, args, i, {**env, **worker_chip_env(chips, i)}))
         _procs.append(p)
         workers.append(p)
     signal.signal(signal.SIGINT, _signal_handler)

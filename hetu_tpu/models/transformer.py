@@ -349,23 +349,38 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
             kb = jnp.broadcast_to(kb, (q.shape[0], kb.shape[1]))
     if impl == "ring":
         from ..parallel.ring_attention import ring_attention
-        from ..utils import shard_map
         spec = P("dp", "tp", "sp", None)
         fn_part = functools.partial(ring_attention, axis_name="sp",
                                     causal=cfg.causal)
         if kb is not None:
             # the bias shards like k's sequence axis; each column rotates
             # around the ring with its k/v chunk
-            fn = shard_map(fn_part, mesh=mesh,
-                           in_specs=(spec, spec, spec, P("dp", "sp")),
-                           out_specs=spec)
+            fn = jax.shard_map(fn_part, mesh=mesh,
+                               in_specs=(spec, spec, spec, P("dp", "sp")),
+                               out_specs=spec)
             return fn(q, k, v, kb)
-        fn = shard_map(fn_part, mesh=mesh, in_specs=(spec,) * 3,
-                       out_specs=spec)
+        fn = jax.shard_map(fn_part, mesh=mesh, in_specs=(spec,) * 3,
+                           out_specs=spec)
         return fn(q, k, v)
     if impl == "flash":
         from ..kernels.flash_attention import flash_attention
-        return flash_attention(q, k, v, cfg.causal, k_bias=kb)
+        if mesh is None or mesh.size == 1:
+            return flash_attention(q, k, v, cfg.causal, k_bias=kb)
+        # A Mosaic kernel has no partitioning rule and only lowers in a
+        # fully manual context, so under a mesh it runs per shard:
+        # attention is independent per (batch, head), which is how q/k/v
+        # are laid out here (batch over dp, heads over tp, full sequence).
+        spec = P("dp", "tp", None, None)
+
+        def per_shard(q, k, v, *kb):
+            return flash_attention(q, k, v, cfg.causal,
+                                   k_bias=kb[0] if kb else None)
+
+        bias = () if kb is None else (kb,)
+        return jax.shard_map(
+            per_shard, mesh=mesh, check_vma=False,
+            in_specs=(spec,) * 3 + (P("dp", None),) * len(bias),
+            out_specs=spec)(q, k, v, *bias)
     T = q.shape[2]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) / np.sqrt(hd)

@@ -33,24 +33,15 @@ class DLContext:
 
     # -- resolution to a physical jax device -------------------------------
     def jax_device(self):
-        """Resolve to a local jax.Device, falling back gracefully.
-
-        On a CPU-only test host ``tpu(0)`` resolves to a CPU device so the
-        same script runs anywhere (the reference hard-fails without CUDA).
-        """
-        if self.device_type == "tpu":
-            try:
-                devs = [d for d in jax.devices() if d.platform != "cpu"]
-            except RuntimeError:
-                devs = []
-            if not devs:
-                devs = jax.devices()
-        else:
-            try:
-                devs = jax.devices("cpu")
-            except RuntimeError:
-                devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        """Resolve to a local jax.Device; an id the backend does not have
+        raises instead of wrapping onto another chip."""
+        devs = (jax.devices("cpu") if self.device_type == "cpu"
+                else tpu_devices())
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                f"{self!r} requested but only {len(devs)} "
+                f"{devs[0].platform} device(s) exist")
+        return devs[self.device_id]
 
     @property
     def local(self) -> bool:
@@ -74,6 +65,23 @@ class DLContext:
         if self.local:
             return f"{self.device_type}({self.device_id})"
         return f"{self.hostname}:{self.device_type}({self.device_id})"
+
+
+def tpu_devices():
+    """The devices a ``tpu`` context resolves onto: the chips of the TPU
+    backend. With no TPU this raises (the reference hard-fails without
+    CUDA the same way) — except in a process pinned to the CPU on purpose
+    (``utils.cpu_pinned``: the test suite, bench smoke mode), where the
+    virtual CPU devices stand in so the same script runs unchanged."""
+    from .utils import cpu_pinned
+    if cpu_pinned():
+        return jax.devices("cpu")
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"a tpu device was requested but the jax backend is "
+            f"{jax.default_backend()!r}; set JAX_PLATFORMS=cpu to run on "
+            "the CPU on purpose")
+    return jax.devices()
 
 
 def cpu(dev_id: int = 0) -> DLContext:

@@ -30,7 +30,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils import shard_map
 
 
 def partition_adjacency(rows: np.ndarray, cols: np.ndarray,
@@ -96,9 +95,9 @@ def spmm_15d(mesh: Mesh, adj_parts, h, n_nodes: int,
     spec_adj = P(gr_axis, gc_axis, None)
     spec_h = P((gc_axis, gr_axis), None)
     spec_z = P(gr_axis, None)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(spec_adj, spec_adj, spec_adj, spec_h),
-                     out_specs=spec_z)(*adj_parts, h)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(spec_adj, spec_adj, spec_adj, spec_h),
+                         out_specs=spec_z)(*adj_parts, h)
 
 
 def shard_gcn_inputs(mesh: Mesh, rows, cols, values, h, n_nodes,

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
-from ..utils import pvary, shard_map
 
 
 def _stack_stages(params, pp: int):
@@ -74,7 +73,8 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
             h, a = block(h, layer_params, dropout_rng=rng)
             return (h, aux + a), None
 
-        aux0 = pvary(jnp.zeros((), jnp.float32), ("pp",))
+        aux0 = jax.lax.pcast(jnp.zeros((), jnp.float32), ("pp",),
+                              to="varying")
         (h, aux), _ = jax.lax.scan(
             body, (h, aux0), (stage_blocks, jnp.arange(layers_per_stage)))
         return h, aux
@@ -179,7 +179,7 @@ def make_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
             n_ticks = M + pp - 1
             # carries vary per pp-shard: mark them 'varying' for the vma type
             # system before entering the scan
-            varying = lambda x: pvary(x, ("pp",))
+            varying = lambda x: jax.lax.pcast(x, ("pp",), to="varying")
             state = varying(state0)
             loss_sum = varying(jnp.zeros((), jnp.float32))
             aux_sum = varying(jnp.zeros((), jnp.float32))
@@ -229,7 +229,7 @@ def make_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
         if dropout_rng is not None:
             in_specs.append(P())
             args.append(dropout_rng)
-        return shard_map(
+        return jax.shard_map(
             pipelined,
             mesh=mesh,
             in_specs=tuple(in_specs),
@@ -515,7 +515,7 @@ def make_pipeline_train_step_1f1b(cfg: tfm.TransformerConfig, mesh: Mesh,
             local_blocks = jax.tree.map(lambda x: x[0], stage_blocks)
             perm_f = [(i, (i + 1) % pp) for i in range(pp)]
             perm_b = [(i, (i - 1) % pp) for i in range(pp)]
-            varying = lambda x: pvary(x, ("pp",))
+            varying = lambda x: jax.lax.pcast(x, ("pp",), to="varying")
 
             zero_act = jnp.zeros((B, T, cfg.d_model), cfg.dtype)
             carry0 = (
@@ -726,7 +726,7 @@ def make_pipeline_train_step_1f1b(cfg: tfm.TransformerConfig, mesh: Mesh,
         if dropout_rng is not None:
             in_specs.append(P())
             args.append(dropout_rng)
-        loss, g_blocks, g_other = shard_map(
+        loss, g_blocks, g_other = jax.shard_map(
             pipelined,
             mesh=mesh,
             in_specs=tuple(in_specs),

@@ -9,10 +9,18 @@ workers as subprocesses with WORKER_ID env. Across machines, remote roles are
 started over ``ssh`` (the reference uses paramiko + mpirun; TPU pods use one
 process per host, so workers get ``jax.distributed`` coordinator env vars
 instead of an MPI world).
+
+One process per chip: a process that has initialised a jax backend holds its
+chips, and a second one that needs them fails or hangs. So this parent never
+touches a jax backend (importing the package loads the jax module, no more),
+and several local workers are each bound to one chip of the host through the
+environment libtpu reads (``plan_local_chips``); more local workers than
+chips is refused before anything starts.
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import multiprocessing
 import os
 import shlex
@@ -32,6 +40,46 @@ EXIT_WATCHDOG = 85
 _procs: list = []
 _shells: list = []
 _tel_dir: str = ""   # --telemetry-dir (run summary written at every exit)
+
+# what libtpu reads to bind a process to one chip of its host
+_CHIP_ENV = {"TPU_VISIBLE_CHIPS": "{chip}",
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def local_tpu_chips() -> int:
+    """How many TPU chips this host has, counted from the device nodes the
+    driver exposes — no jax, so the launcher parent stays off the chips."""
+    return len(glob.glob("/dev/vfio/[0-9]*")
+               or glob.glob("/dev/accel[0-9]*"))
+
+
+def plan_local_chips(n_workers: int, env: dict):
+    """How many chips the local workers share out, one each (worker ``w``
+    gets chip ``w``) — or None when there is nothing to assign: workers
+    pinned to the CPU need no chip, and a single worker keeps the whole
+    host (it is the one process). More workers than chips raises
+    ``SystemExit`` with one line."""
+    if n_workers <= 1 or \
+            env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    chips = local_tpu_chips()
+    if n_workers > chips:
+        raise SystemExit(
+            f"heturun: {n_workers} local workers but {chips} TPU chip(s) "
+            "on this host — one process per chip (set JAX_PLATFORMS=cpu to "
+            "run the workers on the CPU on purpose)")
+    return chips
+
+
+def worker_chip_env(chips, w: int) -> dict:
+    """Environment binding local worker ``w`` to chip ``w`` ({} when
+    ``plan_local_chips`` assigned none)."""
+    if chips is None:
+        return {}
+    if w >= chips:   # an elastic grow past the host's chips
+        raise RuntimeError(f"worker {w} has no chip: this host has {chips}")
+    return {k: v.format(chip=w) for k, v in _CHIP_ENV.items()}
 
 
 def _story_mod():
@@ -289,6 +337,11 @@ def main(argv=None):
           f"workers({num_workers}): {workers} }}")
 
     env = dict(os.environ)
+    # one process per chip; refused here, before any role is started
+    chips = plan_local_chips(num_workers, env) if len(hosts) == 1 else None
+    # workers share one persistent compile cache, handed over by name
+    from hetu_tpu.utils import compile_cache_path
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_path())
     # Run identity (docs/OBSERVABILITY.md pillar 7): every JSONL row,
     # pilot ledger line and flight ring this job writes carries
     # (run_id, inc). A fresh launch mints the id; a relaunch that inherited
@@ -430,8 +483,10 @@ def main(argv=None):
                 # late joiner: skip init pushes/barriers, bootstrap step +
                 # data partition from the scheduler's world log
                 wenv["HETU_ELASTIC_JOIN"] = "1"
-            # multi-chip single host: each worker is one jax process
+            # multi-chip single host: each worker is one jax process on
+            # one chip of its own
             wenv["HETU_NUM_WORKER"] = str(num_workers)
+            wenv.update(worker_chip_env(chips, w))
             p = subprocess.Popen(args.command, env=wenv)
             _shells.append(p)   # visible to the signal handler
             return p

@@ -4,7 +4,7 @@
 
 Reads the per-rank ``metrics-r<N>.jsonl`` files a run writes (see
 docs/OBSERVABILITY.md for the record schemas) and renders throughput, step-
-time percentiles, MFU against the assumed peak (docs/ROOFLINE.md), PS-tier
+time percentiles, MFU against the device's tabled peak (docs/ROOFLINE.md), PS-tier
 health and cache hit rate. Stdlib-only and jax-free: it runs on a login node
 against a shared filesystem while the job trains.
 """
@@ -22,10 +22,6 @@ from typing import Optional
 
 from . import story as _story      # shared ledger readers (stdlib-only)
 from .profiler import attn_flops   # stdlib-only module (shared w/ bench.py)
-
-# MFU denominator when no peak rides in the records: same default as
-# bench.py / docs/ROOFLINE.md (assumption, not a reading)
-DEFAULT_PEAK_TFLOPS = float(os.environ.get("HETU_PEAK_TFLOPS", "197"))
 
 # metrics snapshots ride only every Nth step record (plus every "final"
 # record) — the per-step cost of percentile math is paid on a cadence
@@ -292,14 +288,14 @@ def _metric_children(m: dict, base: str, suffix: str):
     return sorted(out)
 
 
-def _mfu_pair(m: dict, model: dict, p50_ms, peak_tflops: float):
+def _mfu_pair(m: dict, model: dict, p50_ms, peak_tflops):
     """MFU under BOTH denominators (docs/ROOFLINE.md: 6ND alone overstates
     utilization at long seq): 6ND from the executor's
     ``hetu_flops_per_step_6nd`` gauge; attention-inclusive as 6ND + the
     analytic attention add-on when model geometry is known
     (``telemetry.record_model_info``), else the measured XLA cost-analysis
     flops — which count the score matmuls by construction."""
-    if not p50_ms:
+    if not p50_ms or not peak_tflops:   # unknown device_kind: no MFU
         return None, None
     denom = (p50_ms / 1e3) * peak_tflops * 1e12
     f6 = m.get("hetu_flops_per_step_6nd")
@@ -323,14 +319,16 @@ def _mfu_pair(m: dict, model: dict, p50_ms, peak_tflops: float):
     return mfu6, mfu_a
 
 
-def render_frame(state: dict, peak_tflops: float = DEFAULT_PEAK_TFLOPS
-                 ) -> str:
+def render_frame(state: dict) -> str:
     lines = []
     info = state["run_info"]
     dev = info.get("device_kind", "?")
-    peak = float(info.get("peak_tflops_assumed", peak_tflops))
-    lines.append(f"hetutop — device {dev}, assumed peak {peak:g} TFLOP/s "
-                 f"(see docs/ROOFLINE.md)")
+    # the run recorded its peak from profiler.DEVICE_PEAKS; a device_kind
+    # that table does not know shows no MFU
+    peak = info.get("peak_tflops")
+    lines.append(f"hetutop — device {dev}, peak "
+                 + (f"{peak:g} TFLOP/s" if peak else "unknown (no MFU)")
+                 + " (see docs/ROOFLINE.md)")
     lines.append("rank  sub        step   steps/s    ex/s   p50ms   p90ms"
                  "   p99ms   maxms MFU6nd% MFUatt%  recompiles  anomalies")
     for rank in sorted(state["ranks"]):

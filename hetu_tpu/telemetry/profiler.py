@@ -20,7 +20,7 @@ Three pillars on top of the telemetry bus:
 3. **Perf-regression gate** — ``gate()`` diffs two bench/telemetry summaries
    cell-by-cell with a tolerance, and distinguishes *regressed* from *could
    not measure*: exit 0 clean, 1 regressed, 2 current run incomplete,
-   3 baseline unusable — a partial run (the BENCH_r05 rc=124 mode) can
+   3 baseline unusable — a partial run (a round killed rc=124) can
    never read as a win or a loss.
 
 Import contract: module-level imports are **stdlib only**, and there are no
@@ -43,10 +43,39 @@ import sys
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-# Assumed hardware peaks (docs/ROOFLINE.md: assumptions, not readings; both
-# recorded next to every number they produce).
-DEFAULT_PEAK_TFLOPS = float(os.environ.get("HETU_PEAK_TFLOPS", "197"))
-DEFAULT_PEAK_GBS = float(os.environ.get("HETU_PEAK_GBS", "819"))
+# Published per-chip peaks, keyed by the ``device_kind`` jax reports, each
+# with its source: the one table the executor's run_info, hetutop and
+# bench.py read. A kind that is not here has no peak — its MFU and roofline
+# figures are None and run_info says ``peak: unknown``, never another
+# chip's numbers.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "tflops": 197.0, "gbs": 819.0,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16 and 819 GB/s HBM per chip"},
+}
+
+
+def device_peaks(device_kind) -> Optional[dict]:
+    """``{"tflops", "gbs", "source"}`` for a known ``device_kind``, else
+    None."""
+    return DEVICE_PEAKS.get(device_kind)
+
+
+def mfu(flops_per_step, step_s, device_kind) -> Optional[float]:
+    """Model FLOP/s utilization against the table's peak for this device,
+    or None when any of the three is unknown."""
+    peaks = device_peaks(device_kind)
+    if not flops_per_step or not step_s or peaks is None:
+        return None
+    return flops_per_step / step_s / (peaks["tflops"] * 1e12)
+
+
+# The analytic tools (roofline prediction, the planner's cost model) price
+# a graph for a NAMED target, not for whatever device happens to be found:
+# their defaults are the v5e row, overridable per call.
+DEFAULT_PEAK_TFLOPS = DEVICE_PEAKS["TPU v5 lite"]["tflops"]
+DEFAULT_PEAK_GBS = DEVICE_PEAKS["TPU v5 lite"]["gbs"]
 
 # gate exit codes — the contract CI scripts key on
 GATE_OK = 0
@@ -165,7 +194,10 @@ COLLECTIVE_BASES = ("all-reduce", "all-gather", "reduce-scatter",
 # host-side profiler noise that must never be attributed as device time
 _NOISE_PREFIXES = ("ThreadpoolListener", "Thunk", "TaskDispatcher",
                    "H2D ", "D2H ", "$", "Tfrt", "DevicePut", "copy_",
-                   "BufferFromHostBuffer")
+                   "BufferFromHostBuffer",
+                   # the CPU thunk executor's completion markers ("end:
+                   # dot.3" closes the async "dot.3" it follows) and waits
+                   "end: ", "SlinkyThreadPool")
 
 
 def _base_name(event_name: str) -> str:
@@ -992,8 +1024,8 @@ def _flatten_cell(cell: dict, prefix: str = "") -> Dict[str, float]:
 
 def summary_has_measurement(cells: Dict[str, dict]) -> bool:
     """Does this summary contain at least one gateable number? (bench.py's
-    baseline-selection predicate: a round of nothing but errors — BENCH_r05
-    — must not become the trajectory anchor.)"""
+    baseline-selection predicate: a round of nothing but errors must not
+    become the trajectory anchor.)"""
     for data in cells.values():
         if isinstance(data, dict) and "error" not in data and any(
                 metric_direction(k) is not None
@@ -1059,7 +1091,7 @@ def gate(baseline_cells: Dict[str, dict], current_cells: Dict[str, dict],
     the wrong way past the tolerance. A baseline cell the current run
     errored on (or never reached) is *incomplete*, never a win or a loss:
     status 2 keeps partial runs from polluting the trajectory — the
-    BENCH_r05 failure mode this gate exists for. A PARTIAL baseline
+    failure mode this gate exists for. A PARTIAL baseline
     (``baseline_meta['incomplete']``) still gates its measured cells,
     flagged in ``notes``; only one with nothing measurable is status 3."""
     notes = []

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from hetu_tpu.utils import shard_map
 
 from hetu_tpu.kernels.flash_attention import flash_attention, mha_reference
 from hetu_tpu.parallel.ring_attention import ring_attention
@@ -214,7 +213,7 @@ def test_ring_attention_matches_full(causal):
     mesh = _sp_mesh(4)
     q, k, v = _rand_qkv(np.random.RandomState(3), b=1, h=2, s=128, d=32)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp", causal=causal),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
@@ -234,7 +233,7 @@ def test_ring_attention_key_bias_matches_full(causal):
     q, k, v = _rand_qkv(rng, b=2, h=2, s=128, d=32)
     k_bias = _padding_bias(rng, 2, 128)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp", causal=causal),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),),
@@ -251,7 +250,7 @@ def test_ring_attention_key_bias_gradients():
     q, k, v = _rand_qkv(rng, b=1, h=2, s=64, d=16)
     k_bias = _padding_bias(rng, 1, 64)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp", causal=False),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),),
@@ -274,7 +273,7 @@ def test_ring_attention_gradients():
     mesh = _sp_mesh(4)
     q, k, v = _rand_qkv(np.random.RandomState(4), b=1, h=1, s=64, d=16)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp", causal=True),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,

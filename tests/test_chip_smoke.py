@@ -1,0 +1,74 @@
+"""chip_smoke.py's contract off the chip: it refuses a non-TPU backend in
+one line, and its phase functions run at tiny sizes under the CPU pin
+(``chip=False`` drops the checks only a TPU can meet; the kernels run in
+interpret mode), so the chip run is never the first time a phase's Python
+executes."""
+import json
+import os
+import subprocess
+import sys
+
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+import chip_smoke  # noqa: E402
+
+
+def _last_json(capsys):
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_refuses_cpu_backend_in_one_line():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                       capture_output=True, text=True, env=env, cwd=ROOT,
+                       timeout=120)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""            # no result line, no phase line
+    lines = [ln for ln in p.stderr.strip().splitlines() if ln.strip()]
+    assert len(lines) == 1 and "device phase failed" in lines[0]
+    assert "'cpu'" in lines[0]               # names the platform it found
+
+
+def test_executor_phase_tiny(capsys):
+    rec = chip_smoke.phase_executor(batch=8, steps=8, n_batches=2,
+                                    chip=False)
+    line = _last_json(capsys)
+    assert line["phase"] == "executor" and line["platform"] == "cpu"
+    assert rec["last_loss"] < rec["first_loss"]
+    # under the CPU pin auto declines for the backend, and says so
+    assert any("backend" in k for k in rec["dispatch"]["fallback_reasons"])
+
+
+def test_flagship_phase_tiny(capsys):
+    from hetu_tpu.models import bert
+    cfg = bert.BertConfig(vocab_size=512, d_model=64, n_heads=4, n_layers=2,
+                          d_ff=128, max_seq_len=128, dtype=jnp.float32,
+                          remat=False)
+    rec = chip_smoke.phase_flagship(cfg=cfg, batch=4, seq=128, n_pred=8,
+                                    steps=3, chip=False)
+    line = _last_json(capsys)
+    assert line["phase"] == "flagship"
+    assert rec["attn_impl"] == "dot" and rec["mlm_ce"] == "einsum"
+    assert abs(rec["vs_reference"]["loss"]
+               - rec["vs_reference"]["reference_loss"]) < 1e-3
+
+
+def test_ps_phase_tiny(capsys):
+    rec = chip_smoke.phase_ps(batch=16, steps=6, feature_dim=1000,
+                              embedding_size=16, chip=False)
+    assert _last_json(capsys)["phase"] == "ps"
+    assert rec["pushes_ok"] == rec["server_updates"] > 0
+
+
+def test_kernels_phase_tiny(capsys):
+    rec = chip_smoke.phase_kernels(chip=False, shapes={
+        "flash": (1, 2, 128, 32), "fused_ce": (32, 64, 300),
+        "embed_grad": (128, 128, 1000), "csr_spmm": (300, 16, 16, 128),
+        "quant": (4096, 256), "opt": (300, 700)})
+    assert _last_json(capsys)["phase"] == "kernels"
+    assert set(rec["kernels"]) == {
+        "flash_causal", "flash_key_padding", "fused_ce", "fused_embed_grad",
+        "csr_spmm", "quant_blocks", "dequant_blocks", "fused_adam",
+        "fused_sgd"}

@@ -557,7 +557,7 @@ def test_hetutop_kernels_panel(tmp_path):
     d.mkdir()
     recs = [
         {"kind": "run_info", "ts": 1.0, "rank": 0, "device_kind": "cpu",
-         "peak_tflops_assumed": 197.0},
+         "peak_tflops": None, "peak": "unknown"},
         {"kind": "step", "ts": 2.0, "rank": 0, "sub": "train", "step": 1,
          "step_ms": 5.0,
          "metrics": {

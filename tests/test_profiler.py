@@ -314,7 +314,7 @@ def test_gate_incomplete_baseline_distinct_code():
     dead = {"detail": {"a": {"error": "skipped: backend unresponsive"}},
             "value": None}
     assert _gate(dead, GOOD).status == prof.GATE_INCOMPLETE_BASELINE
-    # the BENCH_r05 wrapper form: rc=124, parsed null
+    # the driver's wrapper form of a dead round: rc=124, parsed null
     wrapper = {"n": 5, "cmd": "python bench.py", "rc": 124, "parsed": None}
     bc, bm = prof.normalize_summary(wrapper)
     assert bc == {} and bm["incomplete"]
@@ -448,7 +448,7 @@ def test_hetutop_reports_both_mfu_denominators(tmp_path):
     f6 = 6.0 * n_params * tokens
     recs = [
         {"kind": "run_info", "ts": 1.0, "rank": 0,
-         "device_kind": "fake-v5e", "peak_tflops_assumed": 197.0},
+         "device_kind": "fake-v5e", "peak_tflops": 197.0},
         {"kind": "model_info", "ts": 1.0, "rank": 0, "n_layers": 12,
          "d_model": 768, "seq_len": 512, "causal": False,
          "n_params": n_params},
@@ -492,18 +492,30 @@ def test_profile_executor_end_to_end(tmp_path, monkeypatch):
     'within 15% of the measured compute span' criterion), with backward
     shares and the exact HLO join."""
     from hetu_tpu import telemetry
-    telemetry.shutdown()
-    monkeypatch.delenv("HETU_TELEMETRY", raising=False)
-    monkeypatch.setenv("HETU_TELEMETRY_DIR", str(tmp_path / "tel"))
-    monkeypatch.setenv("HETU_XLA_TRACE", str(tmp_path / "xla") + ":2:3")
     import hetu_tpu as ht
-    x, y_, loss, train_op = _tiny_mlp(ht)
-    ex = ht.Executor({"train": [loss, train_op]}, ctx=ht.cpu(0), seed=0,
-                     telemetry="trace")
-    _run_steps(ex, x, y_, n=7, bs=64)
-    telemetry.get().flush()
-    rep = prof.profile_executor(ex, "train")
-    att = rep["attribution"]
+    monkeypatch.delenv("HETU_TELEMETRY", raising=False)
+
+    def traced_run(root):
+        telemetry.shutdown()
+        monkeypatch.setenv("HETU_TELEMETRY_DIR", str(root / "tel"))
+        monkeypatch.setenv("HETU_XLA_TRACE", str(root / "xla") + ":2:3")
+        x, y_, loss, train_op = _tiny_mlp(ht)
+        ex = ht.Executor({"train": [loss, train_op]}, ctx=ht.cpu(0), seed=0,
+                         telemetry="trace")
+        # a batch large enough that graph-op work, not the ~1 us thunks
+        # copying scalar constants into the outputs, fills the step
+        _run_steps(ex, x, y_, n=7, bs=8192)
+        telemetry.get().flush()
+        return prof.profile_executor(ex, "train")["attribution"]
+
+    # jax 0.9.0's CPU profiler leaves the client's worker threads out of
+    # some sessions altogether (about one in four here, and every time the
+    # test runs alone in a fresh process): such a session has no per-op
+    # event to join, so the window is taken again
+    for attempt in range(4):
+        att = traced_run(tmp_path / str(attempt))
+        if att.rows:
+            break
     assert att.steps == 3                      # the configured window
     assert att.rows and att.device_wall_us > 0
     matmul = [r for r in att.rows.values() if r.family == "MatMul"]
