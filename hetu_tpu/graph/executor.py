@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..context import DeviceGroup, get_current_context
-from ..telemetry.tracing import XlaTraceWindow as _XW
+from ..telemetry import tracing as _tr
 from ..ndarray import (DLContext, NDArray, ND_Sparse_Array, SparseValue, cpu,
                        tpu, tpu_devices)
 from .node import Op, PlaceholderOp, find_topo_sort
@@ -557,13 +557,6 @@ class SubExecutor:
         self._exe_cache: dict[int, Any] = {}
         # device-side input double buffer: id(node) -> (host batch, device arr)
         self._dev_prefetch: dict[int, tuple] = {}
-        # HETU_PROFILE=1: cumulative host-side phase timings + step count
-        # (the reference's profiling surface is --timing walls + PS load
-        # recording; this adds a per-phase breakdown, ``sub.profile_summary()``)
-        self._profile = ({"prestep_s": 0.0, "trace_build_s": 0.0,
-                          "dispatch_s": 0.0, "poststep_s": 0.0, "steps": 0}
-                         if os.environ.get("HETU_PROFILE", "0")
-                         not in ("", "0") else None)
         # telemetry (docs/OBSERVABILITY.md): PS server-health poll cadence
         # and the last recorded per-phase wall times (graphboard's
         # render(..., timings=True) overlay reads these)
@@ -760,7 +753,12 @@ class SubExecutor:
                 if node.is_placeholder:
                     raise ValueError(f"Placeholder {node.name} was not fed")
                 if node.is_optimizer:
-                    with jax.named_scope(_op_scope(node)):
+                    # the phase scope is the parent, the op stays one
+                    # segment below it (hetuprof's scope_of join); forward
+                    # and backward carry jax's own jvp(/transpose( marks,
+                    # GradientOp being a jax.vjp
+                    with jax.named_scope(_tr.SCOPE_OPT), \
+                            jax.named_scope(_op_scope(node)):
                         node.apply_updates(env, slots_in[id(node)], tc)
                     env[id(node)] = _NO_OUTPUT
                     continue
@@ -901,32 +899,27 @@ class SubExecutor:
         mesh = self.config.mesh
         return mesh is not None and mesh.size > 1
 
-    def profile_summary(self):
-        """Per-step host-phase breakdown (HETU_PROFILE=1), or None.
-
-        prestep = feeds/batches/PS pulls staging; dispatch = the jit call
-        (enqueue + any blocking transfers); poststep = PS push issue,
-        prefetch issue, state bookkeeping; trace_build = tracing+compile.
-        Host-side phases only: under async dispatch the device compute wait
-        lands wherever the first output is materialized (often the caller's
-        ``asnumpy``), so the phases need not sum to wall time per step.
-        """
-        p = self._profile
-        if p is None or p["steps"] == 0:
-            return None
-        n = p["steps"]
-        return {k.replace("_s", "_ms_per_step"): round(v / n * 1000, 3)
-                for k, v in p.items() if k != "steps"} | {"steps": n}
-
-    def _record_telemetry(self, tel, step, t0, t_pre, t_c0, t_c1, t_d0,
-                          t_d1, t_end, compiled_now, feed_vals, batch_vals,
-                          ps_comm_ms=None, ps_pull_ms=None,
-                          ps_push_ms=None):
+    def _record_telemetry(self, tel, step, stamps, compiled_now, feed_vals,
+                          batch_vals):
         """Per-step telemetry: phase spans (trace mode), step metrics and
         the JSONL step record; PS server health on its poll cadence. Runs
-        only when telemetry is active — the hot path records raw
-        ``perf_counter`` stamps and this emits everything post-hoc."""
+        only when telemetry is active, inside ``hetu.poststep`` — the
+        ``perf_counter`` stamps are the ones the step's ``hetu.*`` spans
+        took (``tracing.span``), and this emits everything post-hoc."""
         ex = self.executor
+        t_end = time.perf_counter()
+        t0 = stamps[_tr.STEP][0]
+        t_pre = stamps[_tr.PS_PULL][1]
+        t_c0, t_c1 = stamps[_tr.BUILD]
+        t_d0, t_d1 = stamps[_tr.DISPATCH]
+        ps_comm_ms = ps_pull_ms = ps_push_ms = None
+        if ex.ps_runtime is not None:
+            # the two PS legs separately (pull wait in prestep, push in
+            # poststep): what hetutrail's critical path decomposes
+            ps_pull_ms, ps_push_ms = (
+                (stamps[k][1] - stamps[k][0]) * 1e3
+                for k in (_tr.PS_PULL, _tr.PS_PUSH))
+            ps_comm_ms = ps_pull_ms + ps_push_ms
         step_ms = (t_end - t0) * 1e3
         phases = {"prestep_ms": (t_pre - t0) * 1e3,
                   "dispatch_ms": (t_d1 - t_d0) * 1e3,
@@ -935,11 +928,8 @@ class SubExecutor:
             phases["compile_ms"] = (t_c1 - t_c0) * 1e3
         if ps_comm_ms is not None:
             phases["ps_comm_ms"] = ps_comm_ms
-        if ps_pull_ms is not None:
-            # the two PS legs separately (pull wait in prestep, push in
-            # poststep): what hetutrail's critical path decomposes
             phases["ps_pull_ms"] = ps_pull_ms
-            phases["ps_push_ms"] = ps_push_ms or 0.0
+            phases["ps_push_ms"] = ps_push_ms
         self.last_phases = {"step_ms": step_ms, "step": int(step), **phases}
         tracer = tel.tracer
         label = "step" if self.training else "eval"
@@ -1303,358 +1293,358 @@ class SubExecutor:
     # ------------------------------------------------------------------
     def run(self, feed_dict=None, convert_to_numpy_ret_vals=False,
             eval_node_list=None):
+        """One step. Every phase is one ``hetu.*`` span of
+        ``telemetry/tracing.py``'s vocabulary, a child of ``hetu_step``, in
+        whatever jax.profiler capture is open; the ``perf_counter`` stamps
+        telemetry, hetutrail and hetuscope read are taken by the same spans,
+        and only when one of them is on."""
         ex = self.executor
-        prof = self._profile  # HETU_PROFILE=1: per-phase wall-time ledger
         tel = ex.telemetry   # None when telemetry is off (the only check)
         intro = ex.introspector if self.introspect else None
-        timed = prof is not None or tel is not None or intro is not None
-        t_run0 = time.perf_counter() if timed else 0.0
+        stamps = {} if tel is not None or intro is not None else None
         step = ex.state["step"]
-        # resilience supervisor (watchdog beat, host fault injection);
-        # training targets only — an eval pass is not a supervised step
-        sup = getattr(ex, "supervisor", None) if self.training else None
-        if sup is not None:
-            sup.pre_step(ex, self, step)
-        # hetu-elastic: pending-resize check AFTER fault injection (a
-        # ps_join fault proposes the resize this same boundary commits)
-        ela = getattr(ex, "elastic", None) if self.training else None
-        if ela is not None:
-            ela.step_boundary(self, step)
-        # hetupilot actuation/verdict point, AFTER the elastic agent's own
-        # commit (a pilot barrier must never race a real pending resize).
-        # An actuation rebuilds ex.subexecutors: this (stale) instance
-        # delegates the step to its replacement, which re-enters this hook
-        # idempotently at the same step.
-        pil = getattr(ex, "pilot", None) if self.training else None
-        if pil is not None:
-            pil.step_boundary(self, step)
-            fresh = ex.subexecutors.get(self.name)
-            if fresh is not None and fresh is not self:
-                return fresh.run(
-                    feed_dict=feed_dict,
-                    convert_to_numpy_ret_vals=convert_to_numpy_ret_vals,
-                    eval_node_list=eval_node_list)
+        if ex.xla_window is not None and self.training:
+            # env-gated deep dive: HETU_XLA_TRACE=dir[:start[:n]] opens a
+            # bounded jax.profiler window around the configured steps,
+            # before the step's own span so the capture holds it whole
+            ex.xla_window.on_step(step)
+        with _tr.step_span(step, stamps):
+            return self._run_step(ex, tel, intro, stamps, step, feed_dict,
+                                  convert_to_numpy_ret_vals, eval_node_list)
+
+    def _run_step(self, ex, tel, intro, stamps, step, feed_dict,
+                  convert_to_numpy_ret_vals, eval_node_list):
+        """The body of ``run``, inside its ``hetu_step`` span."""
+        with _tr.span(_tr.BOUNDARY, stamps):
+            # resilience supervisor (watchdog beat, host fault injection);
+            # training targets only — an eval pass is not a supervised step
+            sup = getattr(ex, "supervisor", None) if self.training else None
+            if sup is not None:
+                sup.pre_step(ex, self, step)
+            # hetu-elastic: pending-resize check AFTER fault injection (a
+            # ps_join fault proposes the resize this same boundary commits)
+            ela = getattr(ex, "elastic", None) if self.training else None
+            if ela is not None:
+                ela.step_boundary(self, step)
+            # hetupilot actuation/verdict point, AFTER the elastic agent's
+            # own commit (a pilot barrier must never race a real pending
+            # resize). An actuation rebuilds ex.subexecutors: this (stale)
+            # instance delegates the step to its replacement, which
+            # re-enters this hook idempotently at the same step.
+            pil = getattr(ex, "pilot", None) if self.training else None
+            if pil is not None:
+                pil.step_boundary(self, step)
+                fresh = ex.subexecutors.get(self.name)
+                if fresh is not None and fresh is not self:
+                    return fresh.run(
+                        feed_dict=feed_dict,
+                        convert_to_numpy_ret_vals=convert_to_numpy_ret_vals,
+                        eval_node_list=eval_node_list)
         feed_dict = feed_dict or {}
-        feed_vals = []
-        for node in self.feed_nodes:
-            if node not in feed_dict:
-                raise ValueError(f"Missing feed for placeholder {node.name!r}")
-            feed_vals.append(ex._prepare_input(feed_dict[node],
-                                               batch=getattr(node, "batch", True)))
-        batch_host = {}
-        batch_vals = []
-        for n in self.host_dl_nodes:
-            hv = n.get_batch(self.name)
-            pf = self._dev_prefetch.pop(id(n), None)
-            # identity check: get_batch returns the exact peeked object when
-            # the prefetch ran, so a hit means the device_put already happened
-            dv = pf[1] if pf is not None and pf[0] is hv \
-                else ex._prepare_input(hv)
-            batch_host[id(n)] = np.asarray(hv)
-            batch_vals.append(dv)
-        dl_cursors = []
-        for n in self.res_dl_nodes:
-            cur = self._dl_cursor.get(id(n), 0)
-            dl_cursors.append(np.int32(cur))
-            self._dl_cursor[id(n)] = cur + 1
+        with _tr.span(_tr.FEED, stamps):
+            feed_vals = []
+            for node in self.feed_nodes:
+                if node not in feed_dict:
+                    raise ValueError(
+                        f"Missing feed for placeholder {node.name!r}")
+                feed_vals.append(ex._prepare_input(
+                    feed_dict[node], batch=getattr(node, "batch", True)))
+        with _tr.span(_tr.DL_WAIT, stamps):
+            batch_host = {}
+            batch_vals = []
+            for n in self.host_dl_nodes:
+                hv = n.get_batch(self.name)
+                pf = self._dev_prefetch.pop(id(n), None)
+                # identity check: get_batch returns the exact peeked object
+                # when the prefetch ran, so a hit means the device_put
+                # already happened
+                dv = pf[1] if pf is not None and pf[0] is hv \
+                    else ex._prepare_input(hv)
+                batch_host[id(n)] = np.asarray(hv)
+                batch_vals.append(dv)
+            dl_cursors = []
+            for n in self.res_dl_nodes:
+                cur = self._dl_cursor.get(id(n), 0)
+                dl_cursors.append(np.int32(cur))
+                self._dl_cursor[id(n)] = cur + 1
 
         # -- PS pre-step: pull this batch's embedding rows ------------------
         # Lookups are grouped by table: a table feeding several lookup ops
         # (shared CTR embeddings) pulls the UNION of its row indices once,
         # then distributes rows to each lookup — one RPC instead of k.
+        # The pull wait and the push leg are two spans: hetutrail's critical
+        # path needs to know WHICH PS leg blocked, not just the total.
         ps = ex.ps_runtime
-        ps_timed = timed and ps is not None
-        t_ps0 = time.perf_counter() if ps_timed else 0.0
-        staged_idx: dict[int, np.ndarray] = {}
-        staged_rows: dict[int, np.ndarray] = {}
-        for tid, ops in self._staged_by_table.items():
-            p = ps.params[tid]
-            for op in ops:
-                staged_idx[id(op)] = self._host_value(op.inputs[1], feed_dict,
-                                                      batch_host)
-            if len(ops) == 1:
-                op = ops[0]
-                idx = staged_idx[id(op)]
-                rows = (ps.take_prefetched(id(op), idx)
-                        if ps.async_enabled else None)
-                if rows is None:
-                    rows = ps.stage_lookup(p, idx)
-                staged_rows[id(op)] = rows
-            else:
-                flat = [np.ascontiguousarray(staged_idx[id(op)],
-                                             np.int64).ravel() for op in ops]
-                union = np.unique(np.concatenate(flat))
-                # union prefetch (keyed by table): issued post-step from the
-                # peeked next batches, consumed here when they match
-                urows = (ps.take_prefetched(tid, union)
-                         if ps.async_enabled else None)
-                if urows is None:
-                    urows = ps.stage_lookup(p, union)      # (U, *tail)
-                tail = tuple(p.shape[1:])
-                for op, f in zip(ops, flat):
-                    pos = np.searchsorted(union, f)
-                    staged_rows[id(op)] = urows[pos].reshape(
-                        tuple(np.shape(staged_idx[id(op)])) + tail)
-        ps_staged_vals = [ex._prepare_input(staged_rows[id(op)])
-                          for op in self.ps_staged_ops]
-        ps_dense_vals = []
-        for n in self.ps_dense_vars:
-            p = ps.params[id(n)]
-            ps.wait_dense(p)   # async DDPushPull updates host_value
-            ps_dense_vals.append(ex._prepare_input(p.host_value, batch=False))
-        # pull-wait vs push legs tracked separately: hetutrail's critical
-        # path needs to know WHICH PS leg blocked, not just the total
-        ps_pull_s = (time.perf_counter() - t_ps0) if ps_timed else 0.0
-        ps_comm_s = ps_pull_s
+        with _tr.span(_tr.PS_PULL, stamps):
+            staged_idx: dict[int, np.ndarray] = {}
+            staged_rows: dict[int, np.ndarray] = {}
+            for tid, ops in self._staged_by_table.items():
+                p = ps.params[tid]
+                for op in ops:
+                    staged_idx[id(op)] = self._host_value(
+                        op.inputs[1], feed_dict, batch_host)
+                if len(ops) == 1:
+                    op = ops[0]
+                    idx = staged_idx[id(op)]
+                    rows = (ps.take_prefetched(id(op), idx)
+                            if ps.async_enabled else None)
+                    if rows is None:
+                        rows = ps.stage_lookup(p, idx)
+                    staged_rows[id(op)] = rows
+                else:
+                    flat = [np.ascontiguousarray(staged_idx[id(op)],
+                                                 np.int64).ravel()
+                            for op in ops]
+                    union = np.unique(np.concatenate(flat))
+                    # union prefetch (keyed by table): issued post-step from
+                    # the peeked next batches, consumed here when they match
+                    urows = (ps.take_prefetched(tid, union)
+                             if ps.async_enabled else None)
+                    if urows is None:
+                        urows = ps.stage_lookup(p, union)      # (U, *tail)
+                    tail = tuple(p.shape[1:])
+                    for op, f in zip(ops, flat):
+                        pos = np.searchsorted(union, f)
+                        staged_rows[id(op)] = urows[pos].reshape(
+                            tuple(np.shape(staged_idx[id(op)])) + tail)
+            ps_staged_vals = [ex._prepare_input(staged_rows[id(op)])
+                              for op in self.ps_staged_ops]
+            ps_dense_vals = []
+            for n in self.ps_dense_vars:
+                p = ps.params[id(n)]
+                ps.wait_dense(p)   # async DDPushPull updates host_value
+                ps_dense_vals.append(
+                    ex._prepare_input(p.host_value, batch=False))
 
-        t_pre = time.perf_counter() if timed else 0.0
-        if prof is not None:
-            prof["prestep_s"] += t_pre - t_run0
+        with _tr.span(_tr.BUILD, stamps) as build_span:
+            # hetuscope: cadence-gated stats variant + nan_op fault
+            # poisoning. Variants key the compile cache alongside the shape
+            # signature; _base_sigs keeps recompile accounting blind to them.
+            introspect_now = intro is not None and step % intro.cadence == 0
+            poison_scope = None
+            if sup is not None and hasattr(sup, "poison_op"):
+                p = sup.poison_op(step)
+                if p is not None:
+                    poison_scope = p or self._default_poison_scope()
 
-        # hetuscope: cadence-gated stats variant + nan_op fault poisoning.
-        # Variants key the compile cache alongside the shape signature;
-        # _base_sigs keeps recompile accounting blind to them.
-        introspect_now = intro is not None and step % intro.cadence == 0
-        poison_scope = None
-        if sup is not None and hasattr(sup, "poison_op"):
-            p = sup.poison_op(step)
-            if p is not None:
-                poison_scope = p or self._default_poison_scope()
+            base_key = self._signature(feed_vals, batch_vals) + (
+                tuple(tuple(v.shape) for v in ps_staged_vals),)
+            key = base_key + (introspect_now, poison_scope)
+            fn = self._compiled.get(key)
+            compiled_now = fn is None
+            if compiled_now:
+                build_span.set_metadata(compiled=1)
+                fn = self._build(introspect_now=introspect_now,
+                                 poison_scope=poison_scope)
+                self._compiled[key] = fn
+            self._base_sigs.add(base_key)
 
-        base_key = self._signature(feed_vals, batch_vals) + (
-            tuple(tuple(v.shape) for v in ps_staged_vals),)
-        key = base_key + (introspect_now, poison_scope)
-        fn = self._compiled.get(key)
-        compiled_now = fn is None
-        t_c0 = t_c1 = t_pre
-        if fn is None:
-            t_c0 = time.perf_counter() if timed else 0.0
-            fn = self._build(introspect_now=introspect_now,
-                             poison_scope=poison_scope)
-            self._compiled[key] = fn
-            t_c1 = time.perf_counter() if timed else 0.0
-            if prof is not None:
-                prof["trace_build_s"] += t_c1 - t_c0
-        self._base_sigs.add(base_key)
-
-        params_t = tuple(ex.state["params"][id(n)] for n in ex.param_nodes)
-        slots_t = tuple(ex.state["slots"][id(n)] for n in self.optimizer_nodes)
-        opstate_t = tuple(ex.state["op_state"][id(n)] for n in self.stateful_nodes)
-        qresid_t = tuple(ex.state["qresid"][id(n)] for n in self.qresid_nodes)
-
-        res_data = tuple(self.resident_dl[id(n)][0]
-                         for n in self.res_dl_nodes)
-        inject_nan = bool(self.anomaly_guard and sup is not None
-                          and sup.inject_nan(step))
-        args = (params_t, slots_t, opstate_t, ex.rng_root, np.int32(step),
-                tuple(feed_vals), tuple(batch_vals), tuple(dl_cursors),
-                res_data, tuple(ps_staged_vals), tuple(ps_dense_vals),
-                np.bool_(inject_nan), qresid_t)
-        self._last_call = (fn, args)
-        if tel is not None and tel.xla_window is not None and self.training:
-            # env-gated deep dive: HETU_XLA_TRACE=dir[:start[:n]] opens a
-            # bounded jax.profiler window around the configured steps
-            tel.xla_window.on_step(step)
-        t_d0 = time.perf_counter() if timed else 0.0
-        # hetukern: scope the kernel dispatch mode around the call — jit
-        # traces lazily, so the trace (where dispatch decisions live) runs
-        # under this scope; on cache-hit steps the context is a ~µs no-op
-        from ..kernels import registry as _kreg
-        if tel is not None and tel.tracer is not None:
-            # named step regions in the device timeline when a jax profiler
-            # trace is active (the XLA window above, or an external capture)
-            with _XW.step_annotation(step), \
-                    _kreg.active(self.config.kernels,
-                                 spmd=self._kern_spmd()):
-                outputs, new_params, new_slots, new_opstate, ps_grads, \
-                    qresid_out, finite_t, scope_stats_t = fn(*args)
-        else:
+        with _tr.span(_tr.DISPATCH, stamps):
+            params_t = tuple(ex.state["params"][id(n)]
+                             for n in ex.param_nodes)
+            slots_t = tuple(ex.state["slots"][id(n)]
+                            for n in self.optimizer_nodes)
+            opstate_t = tuple(ex.state["op_state"][id(n)]
+                              for n in self.stateful_nodes)
+            qresid_t = tuple(ex.state["qresid"][id(n)]
+                             for n in self.qresid_nodes)
+            res_data = tuple(self.resident_dl[id(n)][0]
+                             for n in self.res_dl_nodes)
+            inject_nan = bool(self.anomaly_guard and sup is not None
+                              and sup.inject_nan(step))
+            args = (params_t, slots_t, opstate_t, ex.rng_root,
+                    np.int32(step), tuple(feed_vals), tuple(batch_vals),
+                    tuple(dl_cursors), res_data, tuple(ps_staged_vals),
+                    tuple(ps_dense_vals), np.bool_(inject_nan), qresid_t)
+            self._last_call = (fn, args)
+            # hetukern: scope the kernel dispatch mode around the call — jit
+            # traces lazily, so the trace (where dispatch decisions live)
+            # runs under this scope; on cache-hit steps the context is a
+            # ~µs no-op
+            from ..kernels import registry as _kreg
             with _kreg.active(self.config.kernels, spmd=self._kern_spmd()):
                 outputs, new_params, new_slots, new_opstate, ps_grads, \
                     qresid_out, finite_t, scope_stats_t = fn(*args)
-        t_d1 = time.perf_counter() if timed else 0.0
-        if prof is not None:
-            prof["dispatch_s"] += t_d1 - t_d0
 
         # -- device-side input prefetch: enqueue batch N+1's device_put now,
         # so its H2D transfer overlaps this step's compute (the reference's
         # 3-deep pinned ring + h2d stream, dataloader.py:26-55)
-        for n in self.host_dl_nodes:
-            if hasattr(n, "peek_batch"):
-                nxt = n.peek_batch(self.name)
-                self._dev_prefetch[id(n)] = (nxt, ex._prepare_input(nxt))
+        with _tr.span(_tr.PREFETCH, stamps):
+            for n in self.host_dl_nodes:
+                if hasattr(n, "peek_batch"):
+                    nxt = n.peek_batch(self.name)
+                    self._dev_prefetch[id(n)] = (nxt,
+                                                 ex._prepare_input(nxt))
 
         # -- PS post-step: push gradients (reference push/pull, ASP/BSP) ----
-        t_pu0 = time.perf_counter() if ps_timed else 0.0
-        if ps is not None and ps.async_enabled:
-            # async push: the device sync (np.asarray) happens on the push
-            # thread, off the critical path
-            items = []
-            for op, grad in zip(self.ps_comm_ops, ps_grads):
-                p = ps.params[id(op.ps_param_node)]
-                idx = self._push_idx(op, staged_idx)
-                items.append((p, grad, idx))
-            if items:
-                ps.push_grads_async(items, step)
-            # prefetch pulls for batch N+1 (dataloader-fed lookups only):
-            # issued now, so under ASP they overlap this step's compute and
-            # its pushes — the reference's prefetch-stream semantics.
-            # Single-lookup tables prefetch per op; a shared table
-            # prefetches the UNION of its peeked next batches (keyed by
-            # table id, matching the union pull in the pre-step).
-            for tid, ops in self._staged_by_table.items():
-                idx_nodes = [op.inputs[1] for op in ops]
-                if not all(n in self.dataloader_nodes
-                           and hasattr(n, "peek_batch") for n in idx_nodes):
-                    continue
-                if len(ops) == 1:
-                    ps.prefetch_lookup(
-                        id(ops[0]), ps.params[tid],
-                        np.asarray(idx_nodes[0].peek_batch(self.name)))
-                else:
-                    nxt = np.unique(np.concatenate(
-                        [np.ascontiguousarray(
-                            np.asarray(n.peek_batch(self.name)),
-                            np.int64).ravel() for n in idx_nodes]))
-                    ps.prefetch_lookup(tid, ps.params[tid], nxt)
-        else:
-            for op, grad in zip(self.ps_comm_ops, ps_grads):
-                p = ps.params[id(op.ps_param_node)]
-                idx = self._push_idx(op, staged_idx)
-                ps.push_grad(p, grad, idx, step=step)
-        ps_push_s = 0.0
-        if ps_timed:
-            ps_push_s = time.perf_counter() - t_pu0
-            ps_comm_s += ps_push_s
-
-        if self.training:
-            for node, val in zip(ex.param_nodes, new_params):
-                ex.state["params"][id(node)] = val
-            for node, val in zip(self.optimizer_nodes, new_slots):
-                ex.state["slots"][id(node)] = val
-            for node, val in zip(self.stateful_nodes, new_opstate):
-                ex.state["op_state"][id(node)] = val
-            for node, val in zip(self.qresid_nodes, qresid_out):
-                ex.state["qresid"][id(node)] = val
-            ex.state["step"] = step + 1
-
-        finite = True
-        if self.anomaly_guard:
-            # materializing the scalar syncs on the step — the documented
-            # cost of the guard (callers reading the loss sync anyway)
-            finite = bool(np.asarray(finite_t))
-            if finite:
-                ex.state["anomaly_streak"] = 0
+        with _tr.span(_tr.PS_PUSH, stamps):
+            if ps is not None and ps.async_enabled:
+                # async push: the device sync (np.asarray) happens on the
+                # push thread, off the critical path
+                items = []
+                for op, grad in zip(self.ps_comm_ops, ps_grads):
+                    p = ps.params[id(op.ps_param_node)]
+                    idx = self._push_idx(op, staged_idx)
+                    items.append((p, grad, idx))
+                if items:
+                    ps.push_grads_async(items, step)
+                # prefetch pulls for batch N+1 (dataloader-fed lookups
+                # only): issued now, so under ASP they overlap this step's
+                # compute and its pushes — the reference's prefetch-stream
+                # semantics. Single-lookup tables prefetch per op; a shared
+                # table prefetches the UNION of its peeked next batches
+                # (keyed by table id, matching the union pull in the
+                # pre-step).
+                for tid, ops in self._staged_by_table.items():
+                    idx_nodes = [op.inputs[1] for op in ops]
+                    if not all(n in self.dataloader_nodes
+                               and hasattr(n, "peek_batch")
+                               for n in idx_nodes):
+                        continue
+                    if len(ops) == 1:
+                        ps.prefetch_lookup(
+                            id(ops[0]), ps.params[tid],
+                            np.asarray(idx_nodes[0].peek_batch(self.name)))
+                    else:
+                        nxt = np.unique(np.concatenate(
+                            [np.ascontiguousarray(
+                                np.asarray(n.peek_batch(self.name)),
+                                np.int64).ravel() for n in idx_nodes]))
+                        ps.prefetch_lookup(tid, ps.params[tid], nxt)
             else:
-                ex.state["anomaly_streak"] += 1
-                ex.state["anomaly_total"] += 1
-                if tel is not None:
-                    ex._tel_metrics["anomalies"].inc()
-            ex.state["last_step_finite"] = finite
+                for op, grad in zip(self.ps_comm_ops, ps_grads):
+                    p = ps.params[id(op.ps_param_node)]
+                    idx = self._push_idx(op, staged_idx)
+                    ps.push_grad(p, grad, idx, step=step)
 
-        # -- hetuscope: stats fetch, flight record, NaN/Inf provenance ------
-        prov = None
-        if intro is not None:
-            from ..telemetry import scope as _scope
-            stats_host = None
-            if self.anomaly_guard and not finite:
-                if introspect_now:
-                    # the failing step WAS a stats step, and the guard's
-                    # finite check already synced it: its own packed table
-                    # localizes the culprit, no replay needed
-                    stats_host = _scope.host_stats(self._scope_meta[2],
-                                                   scope_stats_t)
-                    order, inputs_map = self._scope_meta[:2]
-                    prov = _scope.find_culprit(order, inputs_map,
-                                               stats_host, step)
+        with _tr.span(_tr.POSTSTEP, stamps):
+            if self.training:
+                for node, val in zip(ex.param_nodes, new_params):
+                    ex.state["params"][id(node)] = val
+                for node, val in zip(self.optimizer_nodes, new_slots):
+                    ex.state["slots"][id(node)] = val
+                for node, val in zip(self.stateful_nodes, new_opstate):
+                    ex.state["op_state"][id(node)] = val
+                for node, val in zip(self.qresid_nodes, qresid_out):
+                    ex.state["qresid"][id(node)] = val
+                ex.state["step"] = step + 1
+
+            finite = True
+            if self.anomaly_guard:
+                # materializing the scalar syncs on the step — the
+                # documented cost of the guard (callers reading the loss
+                # sync anyway)
+                finite = bool(np.asarray(finite_t))
+                if finite:
+                    ex.state["anomaly_streak"] = 0
                 else:
-                    prov = self._provenance_replay(
-                        step, base_key, feed_vals, batch_vals, dl_cursors,
-                        res_data, ps_staged_vals, ps_dense_vals, inject_nan,
-                        poison_scope)
-            rec = {"sub": self.name, "step": int(step),
-                   "step_ms": round((time.perf_counter() - t_run0) * 1e3, 4),
-                   "finite": bool(finite), "seed": int(self.config.seed),
-                   "lr": self._host_lr(),
-                   "batch_crc32": _flight_crc(feed_dict, batch_host),
-                   "cursors": self._flight_cursors()}
-            intro.record_step(rec, stats=stats_host)
-            if introspect_now and stats_host is None:
-                # DEFER the cadence fetch: materializing the packed vector
-                # now would block on this step's compute and stall the
-                # dispatch pipeline (measured: the stall, not the fused
-                # reductions, dominated the overhead). It resolves at the
-                # next step boundary / flush / first read, mutating the
-                # ring record in place and exporting the hetu_scope_*
-                # gauges + scope JSONL row then.
-                def _resolve(vec=scope_stats_t, spec=self._scope_meta[2],
-                             name=self.name, s=int(step), tel=tel,
-                             intro=intro):
-                    stats = _scope.host_stats(spec, vec)
+                    ex.state["anomaly_streak"] += 1
+                    ex.state["anomaly_total"] += 1
                     if tel is not None:
-                        intro.export(tel, name, s, stats)
-                    return stats
+                        ex._tel_metrics["anomalies"].inc()
+                ex.state["last_step_finite"] = finite
 
-                intro.defer(rec, _resolve)
-            elif tel is not None and stats_host is not None:
-                intro.export(tel, self.name, step, stats_host)
-            if prov is not None:
-                intro.on_anomaly(prov, telemetry=tel)
+            # -- hetuscope: stats fetch, flight record, NaN/Inf provenance --
+            prov = None
+            if intro is not None:
+                from ..telemetry import scope as _scope
+                stats_host = None
+                if self.anomaly_guard and not finite:
+                    if introspect_now:
+                        # the failing step WAS a stats step, and the
+                        # guard's finite check already synced it: its own
+                        # packed table localizes the culprit, no replay
+                        stats_host = _scope.host_stats(self._scope_meta[2],
+                                                       scope_stats_t)
+                        order, inputs_map = self._scope_meta[:2]
+                        prov = _scope.find_culprit(order, inputs_map,
+                                                   stats_host, step)
+                    else:
+                        prov = self._provenance_replay(
+                            step, base_key, feed_vals, batch_vals,
+                            dl_cursors, res_data, ps_staged_vals,
+                            ps_dense_vals, inject_nan, poison_scope)
+                rec = {"sub": self.name, "step": int(step),
+                       "step_ms": round((time.perf_counter()
+                                         - stamps[_tr.STEP][0]) * 1e3, 4),
+                       "finite": bool(finite), "seed": int(self.config.seed),
+                       "lr": self._host_lr(),
+                       "batch_crc32": _flight_crc(feed_dict, batch_host),
+                       "cursors": self._flight_cursors()}
+                intro.record_step(rec, stats=stats_host)
+                if introspect_now and stats_host is None:
+                    # DEFER the cadence fetch: materializing the packed
+                    # vector now would block on this step's compute and
+                    # stall the dispatch pipeline (measured: the stall, not
+                    # the fused reductions, dominated the overhead). It
+                    # resolves at the next step boundary / flush / first
+                    # read, mutating the ring record in place and exporting
+                    # the hetu_scope_* gauges + scope JSONL row then.
+                    def _resolve(vec=scope_stats_t,
+                                 spec=self._scope_meta[2], name=self.name,
+                                 s=int(step), tel=tel, intro=intro):
+                        stats = _scope.host_stats(spec, vec)
+                        if tel is not None:
+                            intro.export(tel, name, s, stats)
+                        return stats
 
-        t_end = time.perf_counter() if timed else 0.0
-        if prof is not None:
-            prof["poststep_s"] += t_end - t_d1
-            prof["steps"] += 1
-        # hetutrail step boundary: drain this step's client RPC spans and
-        # advance the span step stamp (None writer when off — one check)
-        if ps is not None and self.training \
-                and ps.trail_writer is not None:
-            ps.trail_step_boundary(step)
-        if tel is not None:
-            # recorded BEFORE supervisor post-step: an emergency flush on
-            # the preemption path must already contain this step's record
-            self._record_telemetry(
-                tel, step, t_run0, t_pre, t_c0, t_c1, t_d0, t_d1, t_end,
-                compiled_now, feed_vals, batch_vals,
-                ps_comm_ms=ps_comm_s * 1e3 if ps_timed else None,
-                ps_pull_ms=ps_pull_s * 1e3 if ps_timed else None,
-                ps_push_ms=ps_push_s * 1e3 if ps_timed else None)
-
-        # post-step supervision LAST: a rollback rewrites ex.state, an
-        # emergency save captures it, and Preempted aborts the return — all
-        # only valid after the commit above. On a trip the anomaly event
-        # carries the headline numbers (loss at trip; global grad norm when
-        # provenance ran) so post-mortems don't need the flight recorder
-        # for them.
-        if sup is not None:
-            extra = {}
-            if self.anomaly_guard and not finite:
-                # the provenance stats already carry the at-trip loss —
-                # reuse them; the extra device fetch is only for guard-
-                # without-introspection runs
-                loss_v = prov.get("loss") if prov is not None else None
-                extra["loss"] = (loss_v if loss_v is not None
-                                 else self._loss_at_trip(outputs))
+                    intro.defer(rec, _resolve)
+                elif tel is not None and stats_host is not None:
+                    intro.export(tel, self.name, step, stats_host)
                 if prov is not None:
-                    extra["grad_norm"] = prov.get("grad_norm")
-            sup.post_step(ex, self, step, finite=finite, **extra)
+                    intro.on_anomaly(prov, telemetry=tel)
 
-        results = []
-        wanted = eval_node_list if eval_node_list is not None else self.eval_nodes
-        out_by_node = {id(n): v for n, v in zip(self.eval_nodes, outputs)}
-        for node in wanted:
-            if node.is_optimizer:
-                results.append(None)
-            else:
-                if id(node) not in out_by_node:
-                    raise ValueError(
-                        f"Node {node.name!r} is not among subexecutor "
-                        f"{self.name!r}'s eval nodes; include it in the "
-                        "eval_node_dict at Executor construction")
-                v = out_by_node[id(node)]
-                results.append(np.asarray(v) if convert_to_numpy_ret_vals
-                               else NDArray(v))
-        return results
+            # hetutrail step boundary: drain this step's client RPC spans
+            # and advance the span step stamp (None writer when off — one
+            # check)
+            if ps is not None and self.training \
+                    and ps.trail_writer is not None:
+                ps.trail_step_boundary(step)
+            if tel is not None:
+                # recorded BEFORE supervisor post-step: an emergency flush on
+                # the preemption path must already contain this step's record
+                self._record_telemetry(tel, step, stamps, compiled_now,
+                                       feed_vals, batch_vals)
+
+            # post-step supervision LAST: a rollback rewrites ex.state, an
+            # emergency save captures it, and Preempted aborts the return —
+            # all only valid after the commit above. On a trip the anomaly
+            # event carries the headline numbers (loss at trip; global grad
+            # norm when provenance ran) so post-mortems don't need the
+            # flight recorder for them.
+            if sup is not None:
+                extra = {}
+                if self.anomaly_guard and not finite:
+                    # the provenance stats already carry the at-trip loss —
+                    # reuse them; the extra device fetch is only for guard-
+                    # without-introspection runs
+                    loss_v = prov.get("loss") if prov is not None else None
+                    extra["loss"] = (loss_v if loss_v is not None
+                                     else self._loss_at_trip(outputs))
+                    if prov is not None:
+                        extra["grad_norm"] = prov.get("grad_norm")
+                sup.post_step(ex, self, step, finite=finite, **extra)
+
+            # with convert_to_numpy_ret_vals the device wait lands here
+            results = []
+            wanted = (eval_node_list if eval_node_list is not None
+                      else self.eval_nodes)
+            out_by_node = {id(n): v
+                           for n, v in zip(self.eval_nodes, outputs)}
+            for node in wanted:
+                if node.is_optimizer:
+                    results.append(None)
+                else:
+                    if id(node) not in out_by_node:
+                        raise ValueError(
+                            f"Node {node.name!r} is not among subexecutor "
+                            f"{self.name!r}'s eval nodes; include it in the "
+                            "eval_node_dict at Executor construction")
+                    v = out_by_node[id(node)]
+                    results.append(np.asarray(v)
+                                   if convert_to_numpy_ret_vals
+                                   else NDArray(v))
+            return results
 
 
 class Executor:
@@ -1695,6 +1685,12 @@ class Executor:
         # None check — no timestamps, no allocations.
         from .. import telemetry as _tel_pkg
         self.telemetry = _tel_pkg.activate(config.telemetry)
+        # HETU_XLA_TRACE=dir[:start[:n]] opens its jax.profiler window
+        # whether or not telemetry is on (telemetry's own is advertised in
+        # its JSONL and stopped by its abort-path flush)
+        self.xla_window = (self.telemetry.xla_window
+                           if self.telemetry is not None
+                           else _tr.XlaTraceWindow.from_env())
         self._tel_metrics = None
         self._tel_recompile_mon = None
         if self.telemetry is not None:
@@ -2378,6 +2374,10 @@ class Executor:
             self.ps_runtime.shutdown()
         if self.introspector is not None:
             self.introspector.close()
+        if self.telemetry is None and self.xla_window is not None:
+            # a job that ends inside its HETU_XLA_TRACE window keeps the
+            # capture (idempotent; telemetry's flush stops the one it owns)
+            self.xla_window.stop()
 
     def fetch_dense_parameter_value(self, nodes):
         """Reference executor.py:1236 — current parameter values (PS-hosted

@@ -30,6 +30,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
+# the kernel's name in the device trace (the pallas_call's `name=`) and in
+# the registry: `csr_spmv` is served by the same call (docs/KERNELS.md)
+CSR_SPMM = "csr_spmm"
+
 # nnz entries per grid step: the tile XLA lays a 1-D array out in, which is
 # the only 1-D block shape Mosaic accepts for an SMEM operand
 BLOCK_NNZ = 1024
@@ -98,6 +102,7 @@ def _spmm_pallas(values, rows, cols, b, *, nrow: int):
         out_specs=pl.BlockSpec((nrow, f), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((nrow, f), jnp.float32),
         interpret=not registry._on_tpu(),
+        name=CSR_SPMM,
     )(values, rows, cols, b)
     return out
 
@@ -125,7 +130,7 @@ def _spmm_eligible(values, rows, cols, b, *, nrow: int):
 
 
 registry.register_kernel(
-    "csr_spmm",
+    CSR_SPMM,
     pallas_fn=_spmm_pallas,
     xla_fallback=_spmm_xla,
     eligibility=_spmm_eligible,

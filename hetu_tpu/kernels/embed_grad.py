@@ -39,6 +39,10 @@ from jax.experimental import pallas as pl
 
 from . import registry
 
+# the kernel's name in the device trace (the pallas_call's `name=`) and in
+# the registry: one constant a call site (docs/KERNELS.md)
+FUSED_EMBED_GRAD = "fused_embed_grad"
+
 # MXU-friendly tile for the mask-matmul; eligibility asks the padded row
 # count to divide it and the trailing dim to be lane-aligned. Tiling and
 # VMEM-budget constants are the registry's shared ones: the kernel holds
@@ -124,6 +128,7 @@ def _segsum_pallas(sv, seg):
         out_specs=pl.BlockSpec((BLOCK_ROWS, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         interpret=not registry._on_tpu(),
+        name=FUSED_EMBED_GRAD,
     )(seg.reshape(n // BLOCK_ROWS, BLOCK_ROWS), sv)
     return out
 
@@ -148,7 +153,7 @@ def _segsum_eligible(sv, seg):
 
 
 registry.register_kernel(
-    "fused_embed_grad",
+    FUSED_EMBED_GRAD,
     pallas_fn=_segsum_pallas,
     xla_fallback=_segsum_xla,
     eligibility=_segsum_eligible,

@@ -31,6 +31,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the kernels' names in the device trace (docs/KERNELS.md): one constant a
+# pallas_call site, written as the call's `name=`
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
@@ -146,6 +152,7 @@ def _fwd_pallas(q, k, v, k_bias, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=FLASH_FWD,
     )(*ops)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
@@ -281,6 +288,7 @@ def _bwd_pallas(res, do, *, scale, causal, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret,
+        name=FLASH_BWD_DQ,
     )(*dq_ops)
 
     dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale,
@@ -318,6 +326,7 @@ def _bwd_pallas(res, do, *, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
         interpret=interpret,
+        name=FLASH_BWD_DKV,
     )(*dkv_ops)
 
     return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),

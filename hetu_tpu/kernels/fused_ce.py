@@ -36,6 +36,12 @@ DEFAULT_BLOCK_N = 128
 DEFAULT_BLOCK_V = 512
 _NEG_INF = -1e30
 
+# the kernels' names in the device trace (docs/KERNELS.md): one constant a
+# pallas_call site, written as the call's `name=`
+FUSED_CE_FWD = "fused_ce_fwd"
+FUSED_CE_BWD_DH = "fused_ce_bwd_dh"
+FUSED_CE_BWD_DW = "fused_ce_bwd_dw"
+
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -244,6 +250,7 @@ def _fused_fwd(h, w, b, targets, block_n, block_v, w_dv):
         ],
         scratch_shapes=[pltpu.VMEM((block_n,), jnp.float32)] * 3,
         interpret=not _on_tpu(),
+        name=FUSED_CE_FWD,
     )(hp[None], wp[None], bp[None, :, None], tp[None, :, None])
     nll = (lse[0, :N, 0] - tl[0, :N, 0])
     return nll, (h, w, b, targets, lse[0, :, 0])
@@ -274,6 +281,7 @@ def _fused_bwd(block_n, block_v, w_dv, res, ct):
         out_shape=jax.ShapeDtypeStruct((1, Np, D), h.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, D), jnp.float32)],
         interpret=not _on_tpu(),
+        name=FUSED_CE_BWD_DH,
     )(hp[None], wp[None], bp[None, :, None], tp[None, :, None], lsep,
       ctp[None, :, None])
 
@@ -307,6 +315,7 @@ def _fused_bwd(block_n, block_v, w_dv, res, ct):
         ],
         scratch_shapes=[dw_sc, pltpu.VMEM((block_v,), jnp.float32)],
         interpret=not _on_tpu(),
+        name=FUSED_CE_BWD_DW,
     )(hp[None], wp[None], bp[None, :, None], tp[None, :, None], lsep,
       ctp[None, :, None])
 

@@ -36,6 +36,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
+# the kernels' names in the device trace (the pallas_call's `name=`) and in
+# the registry: one constant a call site (docs/KERNELS.md)
+FUSED_ADAM = "fused_adam"
+FUSED_SGD = "fused_sgd"
+
 _LANE = registry.LANE
 _SUBLANE = registry.SUBLANE
 _TILE = _LANE * _SUBLANE
@@ -131,6 +136,7 @@ def _adam_pallas(param, grad, m, v, t, lr, *, beta1, beta2, eps,
         out_shape=[jax.ShapeDtypeStruct(pv.shape, jnp.float32)] * 3,
         compiler_params=_PARALLEL,
         interpret=not registry._on_tpu(),
+        name=FUSED_ADAM,
     )(pv, gv, mv, vv, scalars)
     return (_unview(new_p, n, shape), _unview(new_m, n, shape),
             _unview(new_v, n, shape), t1)
@@ -159,7 +165,7 @@ def _adam_eligible(param, grad, m, v, t, lr, **_kw):
 
 
 registry.register_kernel(
-    "fused_adam",
+    FUSED_ADAM,
     pallas_fn=_adam_pallas,
     xla_fallback=_adam_xla,
     eligibility=_adam_eligible,
@@ -198,6 +204,7 @@ def _sgd_pallas(param, grad, lr, *, l2reg):
         out_shape=jax.ShapeDtypeStruct(pv.shape, jnp.float32),
         compiler_params=_PARALLEL,
         interpret=not registry._on_tpu(),
+        name=FUSED_SGD,
     )(pv, gv, lr_in)
     return _unview(out, n, shape)
 
@@ -211,7 +218,7 @@ def _sgd_eligible(param, grad, lr, **_kw):
 
 
 registry.register_kernel(
-    "fused_sgd",
+    FUSED_SGD,
     pallas_fn=_sgd_pallas,
     xla_fallback=_sgd_xla,
     eligibility=_sgd_eligible,

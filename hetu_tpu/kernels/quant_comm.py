@@ -29,6 +29,11 @@ from jax.experimental import pallas as pl
 
 from . import registry
 
+# the kernels' names in the device trace (the pallas_call's `name=`) and in
+# the registry: one constant a call site (docs/KERNELS.md)
+QUANT_BLOCKS = "quant_blocks"
+DEQUANT_BLOCKS = "dequant_blocks"
+
 _INT8_Q = 127.0
 _FP8_Q = 448.0
 _LANE = registry.LANE
@@ -86,6 +91,7 @@ def _quant_pallas(x, *, block: int, mode: str):
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=not registry._on_tpu(),
+        name=QUANT_BLOCKS,
     )(blocks)
     return q.reshape(-1), scales.reshape(-1), n
 
@@ -119,6 +125,7 @@ def _dequant_pallas(q, scales, *, n: int, block: int):
         _dequant_kernel,
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=not registry._on_tpu(),
+        name=DEQUANT_BLOCKS,
     )(q.reshape(nb, block), scales.reshape(nb, 1))
     return out.reshape(-1)[:n]
 
@@ -136,14 +143,14 @@ def _dequant_eligible(q, scales, *, n: int, block: int):
 
 
 registry.register_kernel(
-    "quant_blocks",
+    QUANT_BLOCKS,
     pallas_fn=_quant_pallas,
     xla_fallback=_quant_xla,
     eligibility=_quant_eligible,
 )
 
 registry.register_kernel(
-    "dequant_blocks",
+    DEQUANT_BLOCKS,
     pallas_fn=_dequant_pallas,
     xla_fallback=_dequant_xla,
     eligibility=_dequant_eligible,

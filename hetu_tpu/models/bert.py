@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..telemetry.tracing import SCOPE_FWD, SCOPE_OPT, scoped
 from . import transformer as tfm
 
 
@@ -220,9 +221,10 @@ def make_pretrain_step(cfg: BertConfig, mesh: Optional[Mesh] = None,
 
     def step(params, opt_state, batch):
         (loss, parts), grads = jax.value_and_grad(
-            pretrain_loss, has_aux=True)(params, batch, cfg, mesh)
-        new_params, new_opt = tfm.adamw_update(params, grads, opt_state,
-                                               lr=lr)
+            scoped(SCOPE_FWD, pretrain_loss), has_aux=True)(
+                params, batch, cfg, mesh)
+        new_params, new_opt = scoped(SCOPE_OPT, tfm.adamw_update)(
+            params, grads, opt_state, lr=lr)
         return loss, parts, new_params, new_opt
 
     if mesh is None:
@@ -289,9 +291,10 @@ def make_finetune_step(cfg: BertConfig, lr: float = 2e-5, mesh=None):
             acc = jnp.mean((jnp.argmax(logits, -1) ==
                             batch["label"]).astype(jnp.float32))
             return loss, acc
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        new_params, new_opt = tfm.adamw_update(params, grads, opt_state,
-                                               lr=lr)
+        (loss, acc), grads = jax.value_and_grad(
+            scoped(SCOPE_FWD, loss_fn), has_aux=True)(params)
+        new_params, new_opt = scoped(SCOPE_OPT, tfm.adamw_update)(
+            params, grads, opt_state, lr=lr)
         return loss, acc, new_params, new_opt
 
     return jax.jit(step, donate_argnums=(0, 1))

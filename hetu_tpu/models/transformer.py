@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..telemetry.tracing import SCOPE_FWD, SCOPE_OPT, scoped
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -736,7 +738,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             assert dropout_rng is not None, (
                 "cfg.dropout_rate > 0: pass dropout_rng to the train step")
         if accum_steps == 1:
-            loss, grads = jax.value_and_grad(loss_fn)(
+            loss, grads = jax.value_and_grad(scoped(SCOPE_FWD, loss_fn))(
                 params, tokens, targets, cfg, mesh,
                 dropout_rng=dropout_rng)
         else:
@@ -749,8 +751,8 @@ def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
                 tok, tgt, mi = xs
                 rng = (None if dropout_rng is None
                        else jax.random.fold_in(dropout_rng, mi))
-                l, g = jax.value_and_grad(loss_fn)(params, tok, tgt, cfg,
-                                                   mesh, dropout_rng=rng)
+                l, g = jax.value_and_grad(scoped(SCOPE_FWD, loss_fn))(
+                    params, tok, tgt, cfg, mesh, dropout_rng=rng)
                 return (loss_sum + l,
                         jax.tree.map(jnp.add, gsum, g)), None
 
@@ -760,7 +762,8 @@ def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
                 (tokens, targets, jnp.arange(accum_steps)))
             loss = loss_sum / accum_steps
             grads = jax.tree.map(lambda g: g / accum_steps, gsum)
-        new_params, new_opt = adamw_update(params, grads, opt_state, lr=lr)
+        new_params, new_opt = scoped(SCOPE_OPT, adamw_update)(
+            params, grads, opt_state, lr=lr)
         return loss, new_params, new_opt
 
     if not use_dropout:

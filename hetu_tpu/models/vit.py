@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..telemetry.tracing import SCOPE_FWD, SCOPE_OPT, scoped
 from . import transformer as tfm
 
 
@@ -159,9 +160,10 @@ def make_train_step(cfg: ViTConfig, lr: float = 1e-3,
             acc = jnp.mean((jnp.argmax(logits, -1) == labels)
                            .astype(jnp.float32))
             return loss, acc
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        new_params, new_opt = tfm.adamw_update(params, grads, opt_state,
-                                               lr=lr)
+        (loss, acc), grads = jax.value_and_grad(
+            scoped(SCOPE_FWD, loss_fn), has_aux=True)(params)
+        new_params, new_opt = scoped(SCOPE_OPT, tfm.adamw_update)(
+            params, grads, opt_state, lr=lr)
         return loss, acc, new_params, new_opt
 
     if mesh is None:

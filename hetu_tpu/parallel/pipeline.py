@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
+from ..telemetry.tracing import SCOPE_FWD, SCOPE_OPT, scoped
 
 
 def _stack_stages(params, pp: int):
@@ -242,9 +243,10 @@ def make_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
             # a forgotten key must not silently train WITHOUT dropout
             assert dropout_rng is not None, (
                 "cfg.dropout_rate > 0: pass dropout_rng to the pipeline step")
-        loss, grads = jax.value_and_grad(fwd_loss)(
+        loss, grads = jax.value_and_grad(scoped(SCOPE_FWD, fwd_loss))(
             params, tokens, targets, dropout_rng=dropout_rng)
-        new_params, new_opt = tfm.adamw_update(params, grads, opt_state, lr=lr)
+        new_params, new_opt = scoped(SCOPE_OPT, tfm.adamw_update)(
+            params, grads, opt_state, lr=lr)
         return loss, new_params, new_opt
 
     jitted = _wrap_step(step, cfg, mesh, pp, use_dropout, zero1=zero1)
@@ -739,10 +741,12 @@ def make_pipeline_train_step_1f1b(cfg: tfm.TransformerConfig, mesh: Mesh,
         if use_dropout:
             assert dropout_rng is not None, (
                 "cfg.dropout_rate > 0: pass dropout_rng to the pipeline step")
+        # the 1F1B schedule interleaves hand-rolled forward and backward
+        # stages: only the optimizer phase has a scope here
         loss, grads = fwd_bwd(params, tokens, targets,
                               dropout_rng=dropout_rng)
-        new_params, new_opt = tfm.adamw_update(params, grads, opt_state,
-                                               lr=lr)
+        new_params, new_opt = scoped(SCOPE_OPT, tfm.adamw_update)(
+            params, grads, opt_state, lr=lr)
         return loss, new_params, new_opt
 
     jitted = _wrap_step(step, cfg, mesh, pp, use_dropout, zero1=zero1)

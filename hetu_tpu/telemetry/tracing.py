@@ -5,16 +5,18 @@ one lane per thread (the PS push/pull streams show up as their own rows under
 the worker's process lane). Per-rank files are merged into one timeline with
 rank lanes by ``bin/hetutrace``.
 
-Deep dives escalate in two env-gated stages, both owned by
-:class:`XlaTraceWindow`:
-
-- ``jax.profiler.StepTraceAnnotation`` — when the step runs inside an active
-  jax profiler trace, each step gets its own named region in the device
-  timeline (no-op context otherwise; the annotation itself is cheap).
-- ``HETU_XLA_TRACE=dir[:start_step[:n_steps]]`` — a bounded
-  ``jax.profiler.start_trace``/``stop_trace`` window around the configured
-  steps, so a production job can capture an XLA-level trace of steps
-  1000..1009 without tracing the whole run.
+The one vocabulary of names (below, and docs/OBSERVABILITY.md) is written
+where the work happens and lands in whatever ``jax.profiler`` capture is
+open, with no switch: ``span``/``step_span`` open the ``hetu.*`` host spans
+of ``SubExecutor.run`` as ``jax.profiler.TraceAnnotation`` s (jax's own
+always-on TraceMe, ~0.4 us each with no capture open), so they share the
+profiler's clock and thread line with the ``PjitFunction`` call and the
+device ops; ``scoped`` puts a phase scope into the compiled program's HLO
+metadata. ``HETU_XLA_TRACE=dir[:start_step[:n_steps]]``
+(:class:`XlaTraceWindow`) is one way to open such a capture: a bounded
+``jax.profiler.start_trace``/``stop_trace`` window around the configured
+steps, so a production job can capture an XLA-level trace of steps
+1000..1009 without tracing the whole run.
 """
 from __future__ import annotations
 
@@ -35,9 +37,33 @@ from typing import Optional
 _T0_PERF = time.perf_counter()
 _T0_UNIX = time.time()
 
-# jax.profiler.StepTraceAnnotation, resolved lazily on first use
-# (None = unresolved, False = jax unavailable — stay stdlib-importable)
-_STEP_ANNOT = None
+# -- the one vocabulary -------------------------------------------------------
+# Each name is written by one module and read by the metrics PERF.md section
+# 3 lists; the Pallas kernels' names live with the kernels (docs/KERNELS.md).
+STEP = "hetu_step"        # one SubExecutor.run call, step_num=<step>
+# phase scopes in the compiled program (HLO metadata `op_name`): a device op
+# under SCOPE_OPT is optimizer work, one under `transpose(` backward (its
+# `checkpoint`ed body recomputation), a collective by opcode, the rest
+# forward
+SCOPE_FWD = "hetu_fwd"    # around the loss function that is differentiated
+SCOPE_OPT = "hetu_opt"    # around the optimizer update
+# host spans inside SubExecutor.run, children of STEP, in call order
+(BOUNDARY, FEED, DL_WAIT, PS_PULL, BUILD, DISPATCH, PREFETCH, PS_PUSH,
+ POSTSTEP) = STEP_SPANS = (
+    "hetu.boundary",      # supervisor / elastic / pilot hooks
+    "hetu.feed",          # placeholders through _prepare_input
+    "hetu.dl_wait",       # dataloader get_batch, resident cursors: input wait
+    "hetu.ps_pull",       # staged lookups, prefetch misses, wait_dense
+    "hetu.build",         # signature, cache lookup, _build (compiled=1 then)
+    "hetu.dispatch",      # fn(*args): host dispatch time, NOT device time
+    "hetu.prefetch",      # next batch's device_put
+    "hetu.ps_push",       # gradient push issue, next-batch prefetch pulls
+    "hetu.poststep",      # state commit, guard read, hetuscope, telemetry
+)
+
+# jax.profiler.TraceAnnotation, resolved lazily on first use (None =
+# unresolved; _NoSpan where jax is unavailable — stay stdlib-importable)
+_ANNOT = None
 
 
 try:
@@ -201,13 +227,13 @@ class Tracer:
 
 
 class XlaTraceWindow:
-    """Bounded jax.profiler trace window + per-step annotations.
+    """Bounded jax.profiler trace window.
 
     ``spec`` is ``dir[:start_step[:n_steps]]`` (defaults: start 0, 10 steps).
-    ``step_annotation(step)`` returns a context manager for the step body:
-    a ``jax.profiler.StepTraceAnnotation`` while jax is importable, else a
-    no-op. ``on_step(step)`` opens/closes the profiler window; call it at
-    every step boundary — two integer compares when outside the window.
+    ``on_step(step)`` opens/closes the profiler window; call it at every
+    step boundary — two integer compares when outside the window. The
+    Executor owns one whether or not telemetry is on; a window still open
+    at interpreter exit is stopped there, or jax discards the profile.
     """
 
     def __init__(self, spec: str):
@@ -234,9 +260,12 @@ class XlaTraceWindow:
                 # wrong steps, not the configured ones
                 self._done = True
             elif step >= self.start_step:
+                import atexit
+
                 import jax.profiler
                 jax.profiler.start_trace(self.dir)
                 self._active = True
+                atexit.register(self.stop)
         elif step >= end:
             self.stop()
 
@@ -247,16 +276,77 @@ class XlaTraceWindow:
             self._active = False
             self._done = True
 
-    @staticmethod
-    def step_annotation(step: int):
-        global _STEP_ANNOT
-        if _STEP_ANNOT is None:   # resolve once, not per step
-            try:
-                import jax.profiler
-                _STEP_ANNOT = jax.profiler.StepTraceAnnotation
-            except Exception:  # noqa: BLE001 — annotation is best-effort
-                _STEP_ANNOT = False
-        if _STEP_ANNOT:
-            return _STEP_ANNOT("hetu_step", step_num=int(step))
-        import contextlib
-        return contextlib.nullcontext()
+
+
+class _NoSpan:
+    """Stands in for a TraceAnnotation where jax cannot be imported."""
+
+    def __init__(self, _name, **_args):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **_args):
+        pass
+
+
+class _Stamped:
+    """A span that also keeps its ``perf_counter`` stamps in ``stamps``:
+    what ``last_phases``, ``Tracer.complete``, hetutrail's legs and the
+    watch read, so each phase is delimited once."""
+
+    __slots__ = ("_ann", "_name", "_stamps", "_t0")
+
+    def __init__(self, ann, name, stamps):
+        self._ann, self._name, self._stamps = ann, name, stamps
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = t0 = time.perf_counter()
+        self._stamps[self._name] = (t0, t0)   # its start, while it is open
+        return self._ann
+
+    def __exit__(self, *exc):
+        self._stamps[self._name] = (self._t0, time.perf_counter())
+        return self._ann.__exit__(*exc)
+
+
+def _resolve():
+    """jax.profiler's TraceAnnotation, or the stand-in: once, not per span."""
+    global _ANNOT
+    try:
+        from jax.profiler import TraceAnnotation
+        _ANNOT = TraceAnnotation
+    except Exception:  # noqa: BLE001 — a span is best-effort
+        _ANNOT = _NoSpan
+    return _ANNOT
+
+
+def span(name: str, stamps: Optional[dict] = None, **args):
+    """Open the host span ``name`` (one of ``STEP_SPANS``) as a
+    ``jax.profiler.TraceAnnotation``; entering it yields the annotation
+    (``set_metadata(k=v)`` adds arguments). With a ``stamps`` dict (a timed
+    step) the span's ``(start, end)`` ``perf_counter`` readings are also
+    kept there under its name."""
+    ann = (_ANNOT or _resolve())(name, **args)
+    return ann if stamps is None else _Stamped(ann, name, stamps)
+
+
+def step_span(step: int, stamps: Optional[dict] = None):
+    """The step-level span ``STEP`` with ``step_num``, the identifier every
+    child span shares: what ``jax.profiler.StepTraceAnnotation`` writes
+    (``_r=1`` marks a step to the profiler's tools)."""
+    return span(STEP, stamps, _r=1, step_num=int(step))
+
+
+def scoped(name: str, fn):
+    """``fn`` run under ``jax.named_scope(name)``: how a train step marks
+    the function it differentiates (``SCOPE_FWD``; its backward ops then
+    carry ``transpose(jvp(hetu_fwd))``) and its optimizer update
+    (``SCOPE_OPT``). Trace-time only: HLO metadata, no run-time cost."""
+    import jax
+    return jax.named_scope(name)(fn)

@@ -58,3 +58,24 @@ def import_example_models(example):
         sys.path.remove(path)
     sys.path.insert(0, path)
     return importlib.import_module("models")
+
+
+def read_hetu_spans(trace_dir):
+    """The `hetu*` host spans of the newest jax.profiler capture under
+    `trace_dir`, in time order: [(name, start_ns, end_ns, args)] of the
+    thread that holds the most of them (shared by test_spans /
+    test_executor)."""
+    import glob
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)[-1]
+    best = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                      dict(e.stats)) for e in line.events
+                     if e.name.startswith("hetu")]
+            if len(spans) > len(best):
+                best = spans
+    return sorted(best, key=lambda s: (s[1], -s[2]))

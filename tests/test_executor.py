@@ -227,25 +227,36 @@ def test_bf16_compute_mode():
     assert l16[-1] < l16[0]
 
 
-def test_profile_summary(monkeypatch):
-    """HETU_PROFILE=1 produces a per-phase breakdown; off by default."""
-    monkeypatch.setenv("HETU_PROFILE", "1")
+def test_phase_spans_in_a_capture(tmp_path):
+    """The per-phase breakdown is in any jax.profiler capture (before PR 23
+    an environment variable armed a ledger for it): one hetu_step a run
+    call, its hetu.* phases inside it, the first call's build marked
+    compiled; no switch."""
+    import jax
+    from conftest import read_hetu_spans
     x = ht.Variable(name="x", trainable=False)
     w = ht.Variable("wprof", value=np.ones((3, 2), np.float32))
     out = ht.matmul_op(x, w)
     ex = ht.Executor([out], ctx=ht.cpu(0))
-    for _ in range(3):
-        ex.run("default", feed_dict={x: np.ones((4, 3), np.float32)})
-    prof = ex.subexecutors["default"].profile_summary()
-    assert prof["steps"] == 3
-    for key in ("prestep_ms_per_step", "dispatch_ms_per_step",
-                "poststep_ms_per_step", "trace_build_ms_per_step"):
-        assert prof[key] >= 0.0
-
-    monkeypatch.delenv("HETU_PROFILE")
-    ex2 = ht.Executor([out], ctx=ht.cpu(0))
-    ex2.run("default", feed_dict={x: np.ones((4, 3), np.float32)})
-    assert ex2.subexecutors["default"].profile_summary() is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            ex.run("default", feed_dict={x: np.ones((4, 3), np.float32)})
+    finally:
+        jax.profiler.stop_trace()
+    spans = read_hetu_spans(str(tmp_path))
+    steps = [s for s in spans if s[0] == "hetu_step"]
+    assert len(steps) == 3
+    for lo, hi in ((s[1], s[2]) for s in steps):
+        inside = [c[0] for c in spans
+                  if c[0] != "hetu_step" and lo <= c[1] and c[2] <= hi]
+        assert inside == ["hetu.boundary", "hetu.feed", "hetu.dl_wait",
+                          "hetu.ps_pull", "hetu.build", "hetu.dispatch",
+                          "hetu.prefetch", "hetu.ps_push", "hetu.poststep"]
+    builds = [s for s in spans if s[0] == "hetu.build"]
+    assert [b[3].get("compiled") for b in builds] == [1, None, None]
+    # nothing is kept in the process when no one asked for stamps
+    assert ex.subexecutors["default"].last_phases is None
 
 
 def test_bf16_conv_bn_training():

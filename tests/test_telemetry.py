@@ -91,7 +91,8 @@ def test_xla_trace_window_spec_parsing():
     w2 = XlaTraceWindow("/tmp/xla")
     assert (w2.start_step, w2.n_steps) == (0, 10)
     # the annotation is usable as a context manager with or without jax
-    with XlaTraceWindow.step_annotation(7):
+    from hetu_tpu.telemetry.tracing import step_span
+    with step_span(7):
         pass
 
 
@@ -274,6 +275,16 @@ def test_off_mode_adds_no_instrument_calls(tmp_path, monkeypatch):
                         lambda self, rec: calls.append(("jsonl", rec)))
     monkeypatch.setattr(tr_mod.Tracer, "_append",
                         lambda self, ev: calls.append(("trace", ev)))
+    # the hetu.* spans are jax's own TraceMe and are always written; the
+    # perf_counter stamps beside them are taken only for a consumer
+    monkeypatch.setattr(tr_mod._Stamped, "__init__",
+                        lambda self, *a: calls.append(("stamp", a[1])))
+    spans = []
+    real_span = tr_mod.span
+    monkeypatch.setattr(
+        tr_mod, "span",
+        lambda name, stamps=None, **kw: (spans.append((name, stamps)),
+                                         real_span(name, stamps, **kw))[1])
     import hetu_tpu as ht
     x, y_, loss, train_op = _tiny_mlp(ht)
     ex = ht.Executor({"train": [loss, train_op]}, ctx=ht.cpu(0), seed=0)
@@ -284,6 +295,10 @@ def test_off_mode_adds_no_instrument_calls(tmp_path, monkeypatch):
         ex.run("train", feed_dict={x: xv, y_: yv})
     assert calls == []   # instrument count: exactly zero
     assert ex.subexecutors["train"].last_phases is None
+    # ... while every step still wrote its spans, none of them stamped
+    assert [n for n, _s in spans] == 3 * ([tr_mod.STEP]
+                                          + list(tr_mod.STEP_SPANS))
+    assert all(stamps is None for _n, stamps in spans)
 
 
 def test_hetutop_check_rejects_invalid(tmp_path):
