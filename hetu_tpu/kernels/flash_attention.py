@@ -37,22 +37,136 @@ FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
+_LANES = 128
+
+# What one grid step may hold in VMEM, by `_vmem_bytes`' count: three
+# quarters of the 16 MiB Mosaic gives a kernel by default on a v5e. The
+# count follows the compiler's own: choices it puts at 14.8 and 15.5 MiB
+# compile for the v5e, at 17.8 and more they are refused (PR 24).
+_VMEM_BUDGET = 12 * 1024 * 1024
+# FLOPs of the forward a grid step should carry at least: 1.3 us of the
+# MXU at the v5e's 197 TFLOP/s (twice that at head size 64, which fills
+# half of the array), against the ~0.35 us a grid step costs whatever it
+# does. On the chip (PR 24) 2, 4 and 6 heads of 512 x 512 x 64 a step ran
+# the three kernels in 7.09, 6.92 and 6.74 ms a layer: flat beyond this.
+_STEP_FLOPS = 256e6
+_MAX_HEADS = 16     # the head loop of a grid step is unrolled
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _causal_mask(s, q_start, k_start, block_q, block_k):
-    """Mask scores above the diagonal for one (q block, k block) tile."""
-    q_pos = q_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+def _vmem_bytes(s, d, itemsize, block_q, block_k, heads):
+    """VMEM one grid step of the hungriest of the three kernels holds:
+    two operands whole (k, v; or q, dO in `flash_bwd_dkv`) and at most
+    four blocks (k, v, dk, dv there), the lse and delta rows padded to
+    eight sublanes, all double-buffered, and five f32 arrays of the score
+    tile's size (s, p, dp, ds and one in flight) for the one head being
+    worked on."""
+    blocks = 2 * heads * d * itemsize * (2 * s + 4 * max(block_q, block_k))
+    rows = 2 * 2 * heads * 8 * s * 4
+    return blocks + rows + 5 * block_q * block_k * 4
+
+
+def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
+    """-> (block_q, block_k, heads a grid step), from the call's shapes alone.
+
+    `heads` is how many consecutive rows of the flattened (batch*heads)
+    axis may share a grid step: the head count where a key bias ties a step
+    to one batch row, batch*heads where there is none. A `block_q`/`block_k`
+    the caller passed is kept.
+
+    Blocks: the largest of 512/256/128 that divides `s` (the whole sequence
+    below 128), for causal calls too: on the chip a 512 x 512 tile that
+    computes its masked half beat 256 x 256 tiles that skip more (PR 24).
+    Heads: the fewest that give a step `_STEP_FLOPS` of work, among the
+    divisors of `heads`. Both give way, heads first, until `_vmem_bytes`
+    fits `_VMEM_BUDGET`; the floor is one head and the smallest blocks,
+    which is what every call had before PR 24."""
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def sizes(given):
+        if given is not None:
+            return [min(given, s)]
+        return [b for b in (512, 256, 128) if s % b == 0] or [s]
+
+    picks = [(bq, bk) for bq in sizes(block_q) for bk in sizes(block_k)]
+    picks.sort(key=lambda p: -p[0] * p[1])
+    groups = [g for g in range(1, min(heads, _MAX_HEADS) + 1)
+              if heads % g == 0]
+    for bq, bk in picks:
+        step = 4.0 * bq * s * d * (0.5 if causal else 1.0)
+        want = next((g for g in groups if g * step >= _STEP_FLOPS),
+                    groups[-1])
+        for g in reversed([g for g in groups if g <= want]):
+            if _vmem_bytes(s, d, itemsize, bq, bk, g) <= _VMEM_BUDGET:
+                return bq, bk, g
+    bq, bk = picks[-1]
+    return bq, bk, 1
+
+
+def _tiles_for(q, k_bias, causal, block_q, block_k):
+    """`_choose_tiles` for a (batch, heads, seq, head_dim) call; blocks that
+    do not divide the sequence (only ones the caller passed can) raise."""
+    b, h, s, d = q.shape
+    block_q, block_k, heads = _choose_tiles(
+        s, d, q.dtype, causal, b * h if k_bias is None else h, block_q,
+        block_k)
+    if s % block_q or s % block_k:
+        raise ValueError(f"seq_len {s} must divide blocks ({block_q},{block_k})")
+    return block_q, block_k, heads
+
+
+def _dot(a, b):
+    return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a @ b.T on the MXU: operands as they come, f32 out."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _col_to_row(col):
+    """(n, 1) -> (1, n): per-row statistics onto the lane axis, through a
+    128-wide transpose (the shape Mosaic's transpose takes)."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
+
+
+def _row_to_col(row):
+    """(1, n) -> (n, 1): the inverse, once a grid step and head."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _block(i, size):
+    """Slice of block `i` along an axis cut into blocks of `size`."""
+    if isinstance(i, int):
+        return pl.ds(i * size, size)
+    return pl.ds(pl.multiple_of(i * size, size), size)
+
+
+def _loop(lower, upper, body, init):
+    """`fori_loop`; a single pass is written out instead, with static
+    slices. (Writing more passes out keeps every pass's tiles alive: 32 of
+    them ran Mosaic out of VMEM where the loop compiled.)"""
+    if isinstance(lower, int) and isinstance(upper, int) \
+            and upper == lower + 1:
+        return body(lower, init)
+    return jax.lax.fori_loop(lower, upper, body, init)
+
+
+def _causal_mask(s, q_start, k_start, keys_first=False):
+    """Mask scores above the diagonal for one tile: (q rows, k columns), or
+    (k rows, q columns) with `keys_first`."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if keys_first:
+        keep = q_start + cols >= k_start + rows
+    else:
+        keep = q_start + rows >= k_start + cols
+    return jnp.where(keep, s, _NEG_INF)
 
 
 def _causal_upper_kb(q_start, block_q, block_k):
@@ -63,93 +177,109 @@ def _causal_upper_kb(q_start, block_q, block_k):
     return (q_start + block_q + block_k - 1) // block_k
 
 
+def _optional_bias(kernel, n_before, use_bias):
+    """pallas hands a kernel its refs in order, inputs then outputs; the
+    bias is the input after the first `n_before`, or absent."""
+    if use_bias:
+        return kernel
+    return lambda *refs: kernel(*refs[:n_before], None, *refs[n_before:])
+
+
 # ---------------------------------------------------------------------------
-# forward kernel
+# The tile program, common to the three kernels. A grid step owns `heads`
+# consecutive rows of the flattened (batch*heads) axis and one block of the
+# sequence, and loops over the blocks of the other side:
+# - MXU operands go in as the caller gave them (bf16 from a bf16 model, f32
+#   from an f32 caller), every dot accumulates in f32, and everything else
+#   is f32: scores, bias add, m, l, exp, lse, delta, the accumulators.
+# - per-row statistics (lse, delta) and the key bias live in HBM with the
+#   sequence on the lane axis, (batch*heads, 1, s) and (batch, 1, s). Where
+#   a kernel needs one as a column it transposes it once a step, never in
+#   the loop; `flash_bwd_dkv` works on the transposed score tile (keys down
+#   the sublanes) so that lse and delta broadcast as rows and P^T, dS^T
+#   feed dV and dK without a transposed matmul.
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
-                causal, use_bias, block_k, seq_len):
-    # grid: (batch*heads, q_blocks); refs carry one q block and the full k/v
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # (block_q, d)
-    block_q = q.shape[0]
-    q_start = qi * block_q
-
-    num_kb = seq_len // block_k
-
-    def body(kj, carry):
-        acc, m_prev, l_prev = carry
-        k_blk = k_ref[0, pl.ds(kj * block_k, block_k)].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(kj * block_k, block_k)].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if use_bias:
-            s = s + bias_ref[0, pl.ds(kj * block_k, block_k), 0][None, :]
-        if causal:
-            s = _causal_mask(s, q_start, kj * block_k, block_q, block_k)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc = acc * alpha[:, None] + jax.lax.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
-
+                causal, block_k):
+    # grid: (batch*heads / heads, q blocks); one q block, the whole k and v
+    heads, block_q, d = q_ref.shape
+    q_start = pl.program_id(1) * block_q
     # causal: skip key blocks entirely above the diagonal
-    upper = (num_kb if not causal
-             else _causal_upper_kb(q_start, block_q, block_k))
-    acc0 = jnp.zeros((block_q, q.shape[1]), jnp.float32)
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, upper, body, (acc0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-    lse_ref[0, :, 0] = m + jnp.log(l)
+    upper = (_causal_upper_kb(q_start, block_q, block_k) if causal
+             else k_ref.shape[1] // block_k)
+
+    for g in range(heads):
+        q = q_ref[g]                                  # (block_q, d)
+
+        def body(kj, carry):
+            acc, m_prev, l_prev = carry
+            keys = _block(kj, block_k)
+            v_blk = v_ref[g, keys]
+            s = _dot_nt(q, k_ref[g, keys]) * scale    # (block_q, block_k)
+            if bias_ref is not None:
+                s = s + bias_ref[0, :, keys]
+            if causal:
+                s = _causal_mask(s, q_start, kj * block_k)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + _dot(p.astype(v_blk.dtype), v_blk)
+            return acc, m_new, l_new
+
+        acc, m, l = _loop(0, upper, body, (
+            jnp.zeros((block_q, d), jnp.float32),
+            jnp.full((block_q, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32)))
+        l = jnp.maximum(l, 1e-30)
+        o_ref[g] = (acc / l).astype(o_ref.dtype)
+        lse_ref[g] = _col_to_row(m + jnp.log(l))
 
 
-def _expand_bias(k_bias, b, h, s):
-    """(b, s) per-key bias -> (b*h, s, 1) column blocks for the kernels."""
-    kb = jnp.broadcast_to(k_bias.astype(jnp.float32)[:, None, :], (b, h, s))
-    return kb.reshape(b * h, s, 1)
+def _specs(heads, s, d, block, n_heads):
+    """BlockSpecs over the flattened arrays for a grid (groups, blocks):
+    a (block, d) tile and a whole (s, d) operand; a row of statistics for
+    the block and the whole row; the same two of the key bias, which has a
+    row a batch row, so a group reads the row of the batch it lies in."""
+    def batch_row(i):
+        return i * heads // n_heads
+
+    return (pl.BlockSpec((heads, block, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((heads, s, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((heads, 1, block), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((heads, 1, s), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, block), lambda i, j: (batch_row(i), 0, j)),
+            pl.BlockSpec((1, 1, s), lambda i, j: (batch_row(i), 0, 0)))
+
+
+def _bias_rows(k_bias):
+    """(b, s) key bias -> (b, 1, s) f32 rows: one a batch row, whatever the
+    head (the index map sends a group to its batch row)."""
+    return k_bias.astype(jnp.float32)[:, None, :]
 
 
 def _fwd_pallas(q, k, v, k_bias, scale, causal, block_q, block_k, interpret):
     b, h, s, d = q.shape
     bh = b * h
-    qf = q.reshape(bh, s, d)
-    kf = k.reshape(bh, s, d)
-    vf = v.reshape(bh, s, d)
-    grid = (bh, s // block_q)
     use_bias = k_bias is not None
-    kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             use_bias=use_bias, block_k=block_k, seq_len=s)
-    if not use_bias:
-        def kern(q_ref, k_ref, v_ref, o_ref, lse_ref):  # noqa: F811
-            return _fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                               scale=scale, causal=causal, use_bias=False,
-                               block_k=block_k, seq_len=s)
-    in_specs = [
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),
-    ]
-    ops = [qf, kf, vf]
+    block_q, block_k, heads = _tiles_for(q, k_bias, causal, block_q, block_k)
+    tile, whole, row, _, _, whole_bias = _specs(heads, s, d, block_q, h)
+    in_specs = [tile, whole, whole]
+    ops = [x.reshape(bh, s, d) for x in (q, k, v)]
     if use_bias:
-        in_specs.append(pl.BlockSpec((1, s, 1), lambda i, j: (i, 0, 0)))
-        ops.append(_expand_bias(k_bias, b, h, s))
+        in_specs.append(whole_bias)
+        ops.append(_bias_rows(k_bias))
+    kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                             block_k=block_k)
     out, lse = pl.pallas_call(
-        kern,
-        grid=grid,
+        _optional_bias(kern, 3, use_bias),
+        grid=(bh // heads, s // block_q),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            # trailing singleton keeps the block's last-two dims TPU-tileable
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-        ],
+        out_specs=[tile, row],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         interpret=interpret,
         name=FLASH_FWD,
@@ -161,87 +291,78 @@ def _fwd_pallas(q, k, v, k_bias, scale, causal, block_q, block_k, interpret):
 # backward Pallas kernels (dq; dk+dv) — flash backward both directions:
 # each tile recomputes its probability block from (q, k, lse), so nothing
 # (S, S)-shaped ever exists. delta = rowsum(dO * O) is precomputed in XLA.
+# `scale` multiplies the f32 scores on the way in and the f32 accumulators
+# of dq and dk on the way out (d x block elements, not block x block).
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   bias_ref, dq_ref, *, scale, causal, use_bias, block_k,
-                   seq_len):
-    # grid: (batch*heads, q_blocks); owns one q block, loops over k blocks
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)                  # (block_q, d)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]                            # (block_q,)
-    delta = delta_ref[0, :, 0]
-    block_q = q.shape[0]
-    q_start = qi * block_q
-    num_kb = seq_len // block_k
+                   bias_ref, dq_ref, *, scale, causal, block_k):
+    # grid: (groups, q blocks); owns one q block, loops over k blocks
+    heads, block_q, d = q_ref.shape
+    q_start = pl.program_id(1) * block_q
+    upper = (_causal_upper_kb(q_start, block_q, block_k) if causal
+             else k_ref.shape[1] // block_k)
 
-    def body(kj, dq):
-        k_blk = k_ref[0, pl.ds(kj * block_k, block_k)].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(kj * block_k, block_k)].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if use_bias:
-            s = s + bias_ref[0, pl.ds(kj * block_k, block_k), 0][None, :]
-        if causal:
-            s = _causal_mask(s, q_start, kj * block_k, block_q, block_k)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        return dq + jax.lax.dot(ds, k_blk,
-                                preferred_element_type=jnp.float32)
+    for g in range(heads):
+        q = q_ref[g]                                  # (block_q, d)
+        do = do_ref[g]
+        lse = _row_to_col(lse_ref[g])                 # (block_q, 1)
+        delta = _row_to_col(delta_ref[g])
 
-    upper = (num_kb if not causal
-             else _causal_upper_kb(q_start, block_q, block_k))
-    dq = jax.lax.fori_loop(0, upper, body,
-                           jnp.zeros((block_q, q.shape[1]), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+        def body(kj, dq):
+            keys = _block(kj, block_k)
+            k_blk = k_ref[g, keys]
+            s = _dot_nt(q, k_blk) * scale
+            if bias_ref is not None:
+                s = s + bias_ref[0, :, keys]
+            if causal:
+                s = _causal_mask(s, q_start, kj * block_k)
+            p = jnp.exp(s - lse)
+            dp = _dot_nt(do, v_ref[g, keys])
+            ds = p * (dp - delta)
+            return dq + _dot(ds.astype(q.dtype), k_blk)
+
+        dq = _loop(0, upper, body, jnp.zeros((block_q, d), jnp.float32))
+        dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    bias_ref, dk_ref, dv_ref, *, scale, causal, use_bias,
-                    block_q, seq_len):
-    # grid: (batch*heads, k_blocks); owns one k/v block, loops over q blocks
-    ki = pl.program_id(1)
-    k_blk = k_ref[0].astype(jnp.float32)              # (block_k, d)
-    v_blk = v_ref[0].astype(jnp.float32)
-    block_k = k_blk.shape[0]
-    k_start = ki * block_k
-    num_qb = seq_len // block_q
-
-    def body(qi, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q)].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qi * block_q, block_q)].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), 0]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if use_bias:
-            # this kernel owns ONE k block: its bias column is constant
-            s = s + bias_ref[0, :, 0][None, :]
-        if causal:
-            s = _causal_mask(s, qi * block_q, k_start, block_q, block_k)
-        p = jnp.exp(s - lse[:, None])                 # (block_q, block_k)
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dk, dv
-
+                    bias_ref, dk_ref, dv_ref, *, scale, causal, block_q):
+    # grid: (groups, k blocks); owns one k/v block, loops over q blocks.
+    # Tiles are (block_k, block_q): S^T, P^T, dP^T, dS^T.
+    heads, block_k, d = k_ref.shape
+    k_start = pl.program_id(1) * block_k
     # causal: q blocks strictly before this k block contribute nothing
     lower = (k_start // block_q) if causal else 0
-    d = k_blk.shape[1]
-    dk, dv = jax.lax.fori_loop(
-        lower, num_qb, body,
-        (jnp.zeros((block_k, d), jnp.float32),
-         jnp.zeros((block_k, d), jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    upper = q_ref.shape[1] // block_q
+    # this kernel owns ONE k block: its bias column is constant
+    bias = None if bias_ref is None else _row_to_col(bias_ref[0])
+
+    for g in range(heads):
+        k_blk = k_ref[g]                              # (block_k, d)
+        v_blk = v_ref[g]
+
+        def body(qi, carry):
+            dk, dv = carry
+            rows = _block(qi, block_q)
+            q = q_ref[g, rows]
+            do = do_ref[g, rows]
+            st = _dot_nt(k_blk, q) * scale
+            if bias is not None:
+                st = st + bias
+            if causal:
+                st = _causal_mask(st, qi * block_q, k_start, keys_first=True)
+            pt = jnp.exp(st - lse_ref[g, :, rows])    # (block_k, block_q)
+            dv = dv + _dot(pt.astype(do.dtype), do)
+            dpt = _dot_nt(v_blk, do)
+            dst = pt * (dpt - delta_ref[g, :, rows])
+            dk = dk + _dot(dst.astype(q.dtype), q)
+            return dk, dv
+
+        zeros = jnp.zeros((block_k, d), jnp.float32)
+        dk, dv = _loop(lower, upper, body, (zeros, zeros))
+        dk_ref[g] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[g] = dv.astype(dv_ref.dtype)
 
 
 def _bwd_pallas(res, do, *, scale, causal, block_q, block_k, interpret):
@@ -249,85 +370,49 @@ def _bwd_pallas(res, do, *, scale, causal, block_q, block_k, interpret):
     b, h, s, d = q.shape
     bh = b * h
     use_bias = k_bias is not None
-    biasf = _expand_bias(k_bias, b, h, s) if use_bias else None
+    block_q, block_k, heads = _tiles_for(q, k_bias, causal, block_q, block_k)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)                           # (b, h, s)
-    qf, kf, vf = (x.reshape(bh, s, d) for x in (q, k, v))
-    dof = do.reshape(bh, s, d)
-    lsef = lse.reshape(bh, s, 1)
-    deltaf = delta.reshape(bh, s, 1)
-
-    full = pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0))
-    col = pl.BlockSpec((1, s, 1), lambda i, j: (i, 0, 0))
-
-    dq_kern = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                use_bias=use_bias, block_k=block_k,
-                                seq_len=s)
-    if not use_bias:
-        def dq_kern(q_ref, k_ref, v_ref, do_ref, lse_ref,  # noqa: F811
-                    delta_ref, dq_ref):
-            return _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                  delta_ref, None, dq_ref, scale=scale,
-                                  causal=causal, use_bias=False,
-                                  block_k=block_k, seq_len=s)
-    dq_specs = [
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            full, full,
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-    ]
-    dq_ops = [qf, kf, vf, dof, lsef, deltaf]
+    ops = [x.reshape(bh, s, d) for x in (q, k, v, do)]
+    ops += [lse.reshape(bh, 1, s), delta.reshape(bh, 1, s)]
     if use_bias:
-        dq_specs.append(col)
-        dq_ops.append(biasf)
+        ops.append(_bias_rows(k_bias))
+    grid = bh // heads
+
+    tile, whole, row, _, _, whole_bias = _specs(heads, s, d, block_q, h)
+    dq_specs = [tile, whole, whole, tile, row, row]
+    if use_bias:
+        dq_specs.append(whole_bias)
+    dq_kern = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                                block_k=block_k)
     dq = pl.pallas_call(
-        dq_kern,
-        grid=(bh, s // block_q),
+        _optional_bias(dq_kern, 6, use_bias),
+        grid=(grid, s // block_q),
         in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret,
         name=FLASH_BWD_DQ,
-    )(*dq_ops)
+    )(*ops)
 
-    dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                 causal=causal, use_bias=use_bias,
-                                 block_q=block_q, seq_len=s)
-    if not use_bias:
-        def dkv_kern(q_ref, k_ref, v_ref, do_ref, lse_ref,  # noqa: F811
-                     delta_ref, dk_ref, dv_ref):
-            return _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                   delta_ref, None, dk_ref, dv_ref,
-                                   scale=scale, causal=causal,
-                                   use_bias=False, block_q=block_q,
-                                   seq_len=s)
-    dkv_specs = [
-            full,
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            full, col, col,
-    ]
-    dkv_ops = [qf, kf, vf, dof, lsef, deltaf]
+    tile, whole, _, whole_row, bias_row, _ = _specs(heads, s, d, block_k, h)
+    dkv_specs = [whole, tile, tile, whole, whole_row, whole_row]
     if use_bias:
-        dkv_specs.append(
-            pl.BlockSpec((1, block_k, 1), lambda i, j: (i, j, 0)))
-        dkv_ops.append(biasf)
+        dkv_specs.append(bias_row)
+    dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale,
+                                 causal=causal, block_q=block_q)
     dk, dv = pl.pallas_call(
-        dkv_kern,
-        grid=(bh, s // block_k),
+        _optional_bias(dkv_kern, 6, use_bias),
+        grid=(grid, s // block_k),
         in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-        ],
+        out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
         interpret=interpret,
         name=FLASH_BWD_DKV,
-    )(*dkv_ops)
+    )(*ops)
 
     return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),
             dv.reshape(b, h, s, d))
@@ -391,10 +476,13 @@ def _flash(q, k, v, k_bias, causal, scale, block_q, block_k):
     return out
 
 
-def flash_attention(q, k, v, causal=True, scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    k_bias=None):
+def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
+                    block_k=None, k_bias=None):
     """Fused attention. q/k/v: (batch, heads, seq, head_dim).
+
+    The matmuls run in the dtype of q/k/v with f32 accumulation; the softmax
+    is f32 whatever that dtype. ``block_q``/``block_k`` left at None are
+    chosen from the shapes (`_choose_tiles`).
 
     ``k_bias``: optional (batch, seq) float added to every score column —
     the key-padding mask form (0 valid / -1e9 padded). Non-trainable: its
@@ -402,34 +490,26 @@ def flash_attention(q, k, v, causal=True, scale=None,
     return _flash(q, k, v, k_bias, causal, scale, block_q, block_k)
 
 
-def _resolve(q, scale, block_q, block_k):
-    s = q.shape[2]
-    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if s % block_q or s % block_k:
-        raise ValueError(f"seq_len {s} must divide blocks ({block_q},{block_k})")
-    return scale, block_q, block_k
+def _scale(q, scale):
+    return scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
 
 
 def _flash_fwd(q, k, v, k_bias, causal, scale, block_q, block_k):
-    scale, block_q, block_k = _resolve(q, scale, block_q, block_k)
-    out, lse = _fwd_pallas(q, k, v, k_bias, scale, causal, block_q, block_k,
-                           interpret=not _on_tpu())
+    out, lse = _fwd_pallas(q, k, v, k_bias, _scale(q, scale), causal,
+                           block_q, block_k, interpret=not _on_tpu())
     return out, (q, k, v, out, lse, k_bias)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, res, do):
-    q = res[0]
-    scale, block_q, block_k = _resolve(q, scale, block_q, block_k)
+    q, k_bias = res[0], res[5]
     if _on_tpu():
-        grads = _bwd_pallas(res, do, scale=scale, causal=causal,
+        grads = _bwd_pallas(res, do, scale=_scale(q, scale), causal=causal,
                             block_q=block_q, block_k=block_k,
                             interpret=False)
     else:
-        grads = _bwd_blockwise(res, do, scale=scale, causal=causal,
-                               block_k=block_k)
-    k_bias = res[5]
+        block_k = _tiles_for(q, k_bias, causal, block_q, block_k)[1]
+        grads = _bwd_blockwise(res, do, scale=_scale(q, scale),
+                               causal=causal, block_k=block_k)
     dbias = None if k_bias is None else jnp.zeros_like(k_bias)
     return grads + (dbias,)
 

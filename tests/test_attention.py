@@ -300,3 +300,135 @@ def test_flash_bf16():
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                rtol=2e-2, atol=2e-2)
+
+
+# ---------------------------------------------------------------------------
+# the tile program with the blocks it chooses itself (no block_q/block_k)
+# ---------------------------------------------------------------------------
+
+def _chosen_case(s, d, causal, bias, dtype, b=2, h=3):
+    """Seeded (q, k, v, dO, k_bias) in `dtype`, and the same values in f32
+    for the oracle (so the inputs' own rounding is not counted). b*h = 6 and
+    h = 3 are multiples of no power-of-two head group; with a bias the
+    second batch row is padded entirely."""
+    rng = np.random.RandomState(s + d + causal + 2 * bias)
+    q, k, v = _rand_qkv(rng, b=b, h=h, s=s, d=d)
+    do = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
+    k_bias = None
+    if bias:
+        k_bias = _padding_bias(rng, b, s).at[1].set(-1e9)
+    given = tuple(x.astype(dtype) for x in (q, k, v, do))
+    return given, tuple(x.astype(jnp.float32) for x in given), k_bias
+
+
+@pytest.mark.parametrize("dtype,tol_fwd,tol_bwd", [
+    (jnp.float32, 2e-5, 2e-4), (jnp.bfloat16, 2e-2, 2e-2)],
+    ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal,bias", [(False, True), (True, False),
+                                         (True, True), (False, False)])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [128, 256, 512, 1024])
+def test_flash_chosen_blocks_match_reference(s, d, causal, bias, dtype,
+                                             tol_fwd, tol_bwd):
+    """Forward and dq, dk, dv of the three kernels (interpret mode) at the
+    blocks and head group `_choose_tiles` picks, against the unfused
+    reference in f32."""
+    from hetu_tpu.kernels import flash_attention as fa
+
+    (q, k, v, do), (qf, kf, vf, dof), k_bias = _chosen_case(
+        s, d, causal, bias, dtype)
+    scale = 1.0 / np.sqrt(d)
+    out, lse = fa._fwd_pallas(q, k, v, k_bias, scale, causal, None, None,
+                              interpret=True)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    ref, vjp = jax.vjp(
+        lambda q, k, v: mha_reference(q, k, v, causal, k_bias=k_bias),
+        qf, kf, vf)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol_fwd, atol=tol_fwd)
+    grads = fa._bwd_pallas((q, k, v, out, lse, k_bias), do, scale=scale,
+                           causal=causal, block_q=None, block_k=None,
+                           interpret=True)
+    # A row with every key padded: its forward is the reference's uniform
+    # softmax (checked above), its backward never was: lse = -1e9 + log(l)
+    # rounds to -1e9 in f32, so the rebuilt p is 1 and not 1/l. Such a
+    # row's dO is zero in a real loss; here its gradients must be finite.
+    rows = slice(0, 1) if bias else slice(None)
+    for got, want in zip(grads, vjp(dof)):
+        assert got.dtype == dtype
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        np.testing.assert_allclose(np.asarray(got[rows], np.float32),
+                                   np.asarray(want[rows]), rtol=tol_bwd,
+                                   atol=tol_bwd)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [64, 128, 256, 384, 512, 1024, 2048, 4096])
+def test_choose_tiles(s, d, causal, dtype):
+    """The chooser is a pure function of the call's shapes: its blocks
+    divide s, its head group divides the heads it may group, what it picks
+    fits the VMEM budget by its own count, and the same shapes give the same
+    answer."""
+    from hetu_tpu.kernels import flash_attention as fa
+
+    for heads in (1, 3, 12, 16, 96):
+        picked = fa._choose_tiles(s, d, dtype, causal, heads)
+        assert picked == fa._choose_tiles(s, d, dtype, causal, heads)
+        block_q, block_k, group = picked
+        assert s % block_q == 0 and s % block_k == 0
+        assert heads % group == 0 and 1 <= group <= fa._MAX_HEADS
+        assert (block_q, block_k) == (s, s) if s < 128 else (
+            block_q % 128 == 0 and block_k % 128 == 0)
+        # the floor (what every call had before) is taken where nothing
+        # fits the count: whole f32 k and v at s = 4096, d = 128
+        assert picked == (128, 128, 1) or fa._vmem_bytes(
+            s, d, jnp.dtype(dtype).itemsize, block_q, block_k,
+            group) <= fa._VMEM_BUDGET
+    # blocks the caller passes are kept, and still get a head group
+    assert fa._choose_tiles(s, d, dtype, causal, 12, 64, 32)[:2] == (
+        min(64, s), min(32, s))
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general in a jaxpr, kernels' and loops' bodies included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dot_generals(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
+def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias):
+    """The dtype rule, pinned on the kernels' jaxprs: every matmul of the
+    three kernels takes both operands in the caller's dtype (bf16 in, bf16
+    on the MXU; f32 in, f32) and accumulates in f32. Two matmuls in the
+    forward, three in dq, four in dk+dv, times the heads of a step."""
+    from hetu_tpu.kernels import flash_attention as fa
+
+    (q, k, v, do), _, k_bias = _chosen_case(256, 64, causal, bias, dtype)
+    heads = fa._choose_tiles(256, 64, dtype, causal,
+                             3 if bias else 6)[2]
+
+    def both(q, k, v, do):
+        out, lse = fa._fwd_pallas(q, k, v, k_bias, 0.125, causal, None, None,
+                                  interpret=False)
+        return fa._bwd_pallas((q, k, v, out, lse, k_bias), do, scale=0.125,
+                              causal=causal, block_q=None, block_k=None,
+                              interpret=False)
+
+    calls = [e for e in jax.make_jaxpr(both)(q, k, v, do).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3
+    for call, n_dots in zip(calls, (2, 3, 4)):
+        dots = list(_dot_generals(call.params["jaxpr"]))
+        assert len(dots) == n_dots * heads
+        for eqn in dots:
+            assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]
+            assert eqn.outvars[0].aval.dtype == jnp.float32
+            assert eqn.params["preferred_element_type"] == jnp.float32
