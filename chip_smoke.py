@@ -257,12 +257,15 @@ def _bert_batch(rng, cfg, batch, seq, n_pred, padded):
 
 
 def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
-                   ref_batch=2, mesh_axes=None, chip=True, name="flagship"):
+                   ref_batch=2, mesh_axes=None, chip=True, name="flagship",
+                   moe=None, moe_batch=2):
     """BERT pretrain steps on an unpadded then a padded batch; the loss
     must stay finite (at lr 1e-4 without warm-up AdamW's first steps
     overshoot at BERT-base size, so "falling" is not asked here). On one
     chip the loss of the kernel path (flash + fused CE) is also compared
-    with the unfused dot/einsum reference on ``ref_batch`` rows."""
+    with the unfused dot/einsum reference on ``ref_batch`` rows, and the
+    ``moe`` row runs one MoE layer of sizes ``moe`` or ``MOE_ROW``
+    (``_moe_row``)."""
     import jax
     from hetu_tpu.kernels.fused_ce import should_fuse
     from hetu_tpu.models import bert
@@ -354,6 +357,7 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                                    "reference_loss": round(want, 5),
                                    "hidden_max_abs_err": round(h_err, 5),
                                    "hidden_max_abs": round(h_scale, 3)}
+            rec["moe"] = _moe_row(moe or MOE_ROW, moe_batch, chip)
         rec.update({"model": "bert", "d_model": cfg.d_model,
                     "n_heads": cfg.n_heads, "n_layers": cfg.n_layers,
                     "d_ff": cfg.d_ff, "vocab": cfg.vocab_size, "seq": seq,
@@ -363,6 +367,95 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                     "mesh": None if mesh is None else dict(mesh.shape),
                     "n_params": bert.count_params(params), **timings})
     return rec
+
+
+# OLMoE-1B-7B's layer (models/hf_olmoe.py), with a small vocabulary: the
+# `moe` row of the flagship phase
+MOE_ROW = dict(vocab_size=1024, d_model=2048, n_heads=16, n_layers=1,
+               d_ff=1024, max_seq_len=1024, n_experts=64,
+               n_experts_per_tok=8, norm="rmsnorm", rope=True, mlp="swiglu",
+               qk_norm=True, use_pos_emb=False)
+
+
+def _moe_loop_hidden(params, tokens, cfg):
+    """The one-layer model's hidden states and aux losses with the MoE
+    block as a loop over the experts, every expert on every token and
+    masked: no sort, no gather, no grouped matmul."""
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.models import transformer as tfm
+    layer = {k: v[0] for k, v in params["blocks"].items()}
+    h = tfm.embed_tokens(params, tokens, cfg)
+    h, m = tfm._block_attn(h, layer, cfg, None, None, None)
+    x = m.reshape(-1, m.shape[-1])
+    top_p, top_e, _, _, aux = tfm._route(x, layer["router"], cfg)
+
+    def one_expert(y, e):
+        w1, w3, w2 = (layer[k][e].astype(x.dtype) for k in ("w1", "w3", "w2"))
+        weight = jnp.sum(jnp.where(top_e == e, top_p, 0.0), -1)
+        u = (jax.nn.silu(jnp.dot(x, w1, preferred_element_type=jnp.float32))
+             * jnp.dot(x, w3, preferred_element_type=jnp.float32))
+        out = jnp.dot(u.astype(x.dtype), w2,
+                      preferred_element_type=jnp.float32)
+        return y + weight[:, None] * out, None
+
+    y, _ = jax.lax.scan(one_expert, jnp.zeros(x.shape, jnp.float32),
+                        jnp.arange(cfg.n_experts))
+    return h + y.astype(h.dtype).reshape(h.shape), aux
+
+
+def _moe_row(sizes, batch, chip):
+    """One OLMoE-width layer forward and backward through the dropless
+    sort + grouped-matmul path against the loop above: hidden states, loss
+    and the gradients of the router and one expert matrix agree, and no
+    pick is dropped."""
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.models import transformer as tfm
+    cfg = tfm.TransformerConfig(**{"dtype": jnp.bfloat16, **sizes})
+    params = tfm.init_params(jax.random.PRNGKey(1), cfg)
+    tokens = jnp.asarray(np.random.RandomState(1).randint(
+        0, cfg.vocab_size, (batch, cfg.max_seq_len)), jnp.int32)
+
+    def loss_and_hidden(hidden_fn):
+        def loss(p):
+            h, aux = hidden_fn(p, tokens, cfg)
+            return (jnp.mean(h.astype(jnp.float32) ** 2)
+                    + tfm.aux_weights() @ aux), h
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))
+
+    system = loss_and_hidden(tfm.forward_hidden)
+    (got, h_got), g_got = system(params)
+    (want, h_want), g_want = loss_and_hidden(_moe_loop_hidden)(params)
+    stats = jax.jit(lambda p: tfm.moe_routing_stats(p, tokens, cfg))(params)
+    dropped = int(np.sum(stats["dropped"]))
+    _check(dropped == 0, f"moe: {dropped} dropped picks")
+    _check(int(np.sum(stats["picks"])) == tokens.size * cfg.n_experts_per_tok,
+           "moe: picks do not sum to tokens x k")
+    if chip:
+        hlo = system.lower(params).compile().as_text()
+        _check("ragged-dot" in hlo and _MOSAIC in hlo,
+               "moe: no grouped matmul custom call in the compiled layer")
+    f32 = lambda a: np.asarray(a, np.float32)
+    rel = lambda a, b: float(np.sqrt(np.mean((f32(a) - f32(b)) ** 2))
+                             / np.sqrt(np.mean(f32(b) ** 2)))
+    errs = {"hidden": rel(h_got, h_want),
+            "d_router": rel(g_got["blocks"]["router"],
+                            g_want["blocks"]["router"]),
+            "d_w1": rel(g_got["blocks"]["w1"], g_want["blocks"]["w1"])}
+    got, want = float(got), float(want)
+    _check(abs(got - want) <= 1e-2 * abs(want),
+           f"moe: loss {got} vs loop {want}")
+    # both sides feed bfloat16 into the MXU and accumulate in float32; the
+    # grouped path rounds each pick's output to bfloat16 before the
+    # weighted sum, the loop sums in float32
+    _check(all(np.isfinite(v) and v <= 5e-2 for v in errs.values()),
+           f"moe: relative RMS errors against the loop {errs}")
+    return {"loss": round(got, 5), "loop_loss": round(want, 5),
+            "rel_rms_err": {k: round(v, 5) for k, v in errs.items()},
+            "dropped_picks": dropped, "tokens": int(tokens.size),
+            "experts": cfg.n_experts, "per_tok": cfg.n_experts_per_tok,
+            "max_over_mean": round(float(np.max(stats["max_over_mean"])), 3)}
 
 
 # ---------------------------------------------------------------------------

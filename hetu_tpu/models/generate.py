@@ -19,8 +19,8 @@ crosses its own prompt boundary at a different step. Either way the whole
 thing is one compiled program.
 
 Dense MLP blocks only (the switch MoE flagship path is a training
-configuration; decode asserts ``n_experts == 0``). Decode runs
-single-program (``mesh=None``) or distributed: with a mesh, params keep
+configuration; decode asserts ``n_experts == 0`` and refuses
+``qk_norm``). Decode runs single-program (``mesh=None``) or distributed: with a mesh, params keep
 their Megatron tp layout, the KV cache shards batch-over-dp and
 heads-over-tp, and GSPMD inserts the collectives (see
 ``make_generate_fn``).
@@ -165,6 +165,7 @@ def _prefill_prefix(params, cfg, prompt, kcache, vcache, enabled,
 def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
                        top_k: int) -> None:
     assert cfg.n_experts == 0, "decode supports dense blocks (no MoE)"
+    assert not cfg.qk_norm, "decode does not mirror qk_norm (_decode_layer)"
     assert cfg.causal, "decode is autoregressive — causal configs only"
     assert max_len <= cfg.max_seq_len
     assert 0 <= top_k <= cfg.vocab_size, (

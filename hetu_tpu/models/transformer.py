@@ -12,8 +12,10 @@ distributed are first-class here:
 - **sp**: sequence dimension sharded over ``sp``; k/v are gathered for
   attention (Ulysses-style; a Pallas ring-attention path lives in
   ``hetu_tpu/ops/pallas``).
-- **ep**: switch-style top-1 MoE with capacity; experts sharded over ``ep``,
-  token dispatch/combine become all-to-alls.
+- **moe**: dropless top-k routing — picks sorted by expert, one grouped
+  matmul a projection (``_moe_mlp``). Under an ``ep > 1`` mesh the older
+  top-1 capacity form stays: experts sharded over ``ep``, token
+  dispatch/combine become all-to-alls.
 - **pp**: see ``hetu_tpu/parallel/pipeline.py`` (explicit ppermute GPipe).
 
 Params are f32, compute in bf16 (MXU native), losses/reductions f32.
@@ -32,7 +34,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..telemetry.tracing import SCOPE_FWD, SCOPE_OPT, scoped
+from ..telemetry.tracing import (SCOPE_FWD, SCOPE_MOE_COMBINE,
+                                 SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
+                                 SCOPE_MOE_ROUTE, SCOPE_OPT, scoped)
+
+# router z-loss weight (ST-MoE, OLMoE: 1e-3); the balance loss keeps
+# ``loss_fn``'s ``aux_weight``
+Z_LOSS_WEIGHT = 1e-3
+
+
+class MoEConfigError(ValueError):
+    """A MoE setting the chosen layout does not implement."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,8 +55,11 @@ class TransformerConfig:
     n_layers: int = 8
     d_ff: int = 2048
     max_seq_len: int = 1024
-    n_experts: int = 0          # 0 = dense MLP; >0 = switch MoE
-    capacity_factor: float = 1.25
+    n_experts: int = 0          # 0 = dense MLP; >0 = MoE (experts honour
+                                # ``mlp``: gelu with biases, or swiglu)
+    n_experts_per_tok: int = 1  # picks a token: the k largest router
+                                # probabilities, weighted UNnormalised
+    capacity_factor: float = 1.25   # ep > 1 meshes only (``_moe_mlp``)
     dropout_rate: float = 0.0
     dtype: Any = jnp.bfloat16   # compute dtype
     remat: bool = True
@@ -95,13 +110,16 @@ class TransformerConfig:
                                 # heads and broadcast to the q heads
     use_pos_emb: bool = True    # False: no learned position table (rope
                                 # carries positions)
+    qk_norm: bool = False       # OLMoE: RMSNorm (``q_norm``/``k_norm``
+                                # scales, ``ln_eps``) over the whole q and
+                                # k projections, before the head split
 
     def __post_init__(self):
-        if self.mlp == "swiglu" and self.n_experts > 0:
-            raise ValueError(
-                "mlp='swiglu' with n_experts>0: the MoE expert MLP is "
-                "gelu-only — a swiglu config would silently train a "
-                "different architecture than requested")
+        if self.n_experts and not (
+                1 <= self.n_experts_per_tok <= self.n_experts):
+            raise MoEConfigError(
+                f"n_experts_per_tok={self.n_experts_per_tok} of "
+                f"n_experts={self.n_experts}")
 
     @property
     def kv_heads(self):
@@ -148,8 +166,14 @@ def _init_trunk(ks, cfg: TransformerConfig):
     if cfg.attn_proj_bias:
         blocks["bqkv"] = jnp.zeros((L, qkv_width), jnp.float32)
         blocks["bo"] = jnp.zeros((L, D), jnp.float32)
+    if cfg.qk_norm:
+        blocks["q_norm"] = jnp.ones((L, cfg.n_heads * cfg.head_dim),
+                                    jnp.float32)
+        blocks["k_norm"] = jnp.ones((L, cfg.kv_heads * cfg.head_dim),
+                                    jnp.float32)
     if cfg.mlp == "swiglu":
-        blocks["w3"] = norm(ks[8], (L, D, F), 0.02)
+        blocks["w3"] = norm(ks[8], (L, E, D, F) if E > 0 else (L, D, F),
+                            0.02)
     if E > 0:
         blocks.update({
             "router": norm(ks[2], (L, D, E), 0.02),
@@ -199,8 +223,12 @@ def param_specs(cfg: TransformerConfig):
     if cfg.attn_proj_bias:
         blocks["bqkv"] = P(None, "tp")
         blocks["bo"] = P(None, None)
+    if cfg.qk_norm:
+        blocks["q_norm"] = P(None, "tp")
+        blocks["k_norm"] = P(None, "tp")
     if cfg.mlp == "swiglu":
-        blocks["w3"] = P(None, None, "tp")
+        blocks["w3"] = (P(None, "ep", None, "tp") if moe
+                        else P(None, None, "tp"))
     if moe:
         blocks.update({
             "router": P(None, None, None),
@@ -407,6 +435,10 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     if cfg.attn_proj_bias:
         qkv = qkv + p["bqkv"].astype(h.dtype)
     q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+    if cfg.qk_norm:
+        # the statistic runs over every head of the projection at once
+        q = _rms_norm(q, p["q_norm"], cfg.ln_eps)
+        k = _rms_norm(k, p["k_norm"], cfg.ln_eps)
     q = q.reshape(B, T, nh, hd).transpose(0, 2, 1, 3)
     if impl == "ring":
         # k/v stay sequence-sharded: the ring rotates chunks over ICI
@@ -455,18 +487,134 @@ def _dense_mlp(h, p, cfg, mesh):
     return out + p["b2"].astype(h.dtype)
 
 
+def _route(x, router, cfg: TransformerConfig):
+    """The router of one MoE block on token rows ``x`` (S, D), in float32
+    whatever the compute dtype -> (top_p (S, k) the picks' UNnormalised
+    softmax probabilities, top_e (S, k) int32, counts (E,) picks an expert,
+    probs (S, E), aux (2,) = [balance, z]).
+
+    balance = E * sum_e f_e P_e with f_e the picks of expert e over tokens
+    (they sum to k) and P_e its mean probability: Switch Transformer eq. 4
+    at k = 1, HF ``load_balancing_loss_func`` otherwise. z = mean over
+    tokens of logsumexp(logits)^2 (ST-MoE). ``loss_fn`` weights them."""
+    E, k = cfg.n_experts, cfg.n_experts_per_tok
+    logits = jnp.einsum("sd,de->se", x.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, -1)
+    top_p, top_e = jax.lax.top_k(probs, k)
+    counts = jnp.sum(top_e[..., None] == jnp.arange(E), axis=(0, 1))
+    balance = E * jnp.sum(counts / x.shape[0] * jnp.mean(probs, 0))
+    z = jnp.mean(jax.nn.logsumexp(logits, -1) ** 2)
+    return top_p, top_e, counts, probs, jnp.stack([balance, z])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, order, inv, k):
+    """Token rows (S, D) -> pick rows (S*k, D) in sorted order: pick
+    ``order[i]`` belongs to token ``order[i] // k``. ``inv`` is ``order``'s
+    inverse: the cotangent is a gather and a sum over the k picks, where
+    autodiff would emit a scatter-add of S*k rows."""
+    return x[order // k]
+
+
+def _dispatch_rows_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _dispatch_rows_bwd(k, inv, g):
+    S = g.shape[0] // k
+    dx = jnp.sum(g[inv].reshape(S, k, -1).astype(jnp.float32), 1)
+    return dx.astype(g.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv):
+    """``x[perm]`` for a permutation and its inverse: cotangent ``g[inv]``."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_rows_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _grouped_matmul(xs, w, group_sizes):
+    """Rows sorted by group (M, K) x one matrix a group (E, K, N) -> (M, N):
+    ``jax.lax.ragged_dot``, which the TPU compiler lowers to its own grouped
+    matmul kernel (M*K*N multiply-adds, not E times that) and differentiates
+    into two more (PERF.md has its share of the roofline)."""
+    return jax.lax.ragged_dot(xs, w.astype(xs.dtype), group_sizes,
+                              preferred_element_type=xs.dtype)
+
+
 def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
-    """Switch-style top-1 MoE with capacity (experts sharded over ep; the
-    dispatch/combine einsums become all-to-alls under GSPMD)."""
+    """Dropless top-k MoE: every pick is computed. The S*k picks are sorted
+    by expert (stable), token rows gathered in that order, each projection
+    is one grouped matmul over the E uneven groups, and the results return
+    to token order weighted by the picks' router probabilities. -> (out,
+    aux (2,)). k = 1 is the same code.
+
+    Under a mesh with ``ep > 1`` the older top-1 capacity form runs instead
+    (``_moe_mlp_capacity``): experts over ``ep`` by all-to-all for this
+    form is ROADMAP R1's remaining item."""
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        return _moe_mlp_capacity(h, p, cfg, mesh)
+    B, T, D = h.shape
+    k = cfg.n_experts_per_tok
+    x = h.reshape(B * T, D)
+    with jax.named_scope(SCOPE_MOE_ROUTE):
+        top_p, top_e, counts, _, aux = _route(x, p["router"], cfg)
+        flat_e = top_e.reshape(-1)
+        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = counts       # picks an expert = rows of its group
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        xs = _dispatch_rows(x, order, inv, k)              # (S*k, D)
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        u = _grouped_matmul(xs, p["w1"], group_sizes)
+        if cfg.mlp == "swiglu":
+            up = _grouped_matmul(xs, p["w3"], group_sizes)
+            u = (jax.nn.silu(u.astype(jnp.float32))
+                 * up.astype(jnp.float32)).astype(x.dtype)
+            ys = _grouped_matmul(u, p["w2"], group_sizes)
+        else:
+            sorted_e = flat_e[order]
+            u = _gelu(u + p["b1"].astype(x.dtype)[sorted_e], cfg)
+            ys = (_grouped_matmul(u, p["w2"], group_sizes)
+                  + p["b2"].astype(x.dtype)[sorted_e])
+    with jax.named_scope(SCOPE_MOE_COMBINE):
+        y = _permute_rows(ys, inv, order).reshape(B * T, k, D)
+        out = jnp.sum(y.astype(jnp.float32) * top_p[..., None], 1)
+    return out.astype(h.dtype).reshape(B, T, D), aux
+
+
+def _moe_mlp_capacity(h, p, cfg: TransformerConfig, mesh):
+    """Switch-style top-1 MoE with a capacity that DROPS tokens, kept for
+    ``ep > 1`` meshes only (experts sharded over ep; the dispatch/combine
+    einsums become all-to-alls under GSPMD). Its one-hot (S, E, cap)
+    dispatch tensor does not scale to many experts or picks."""
+    if cfg.n_experts_per_tok != 1:
+        raise MoEConfigError(
+            f"n_experts_per_tok={cfg.n_experts_per_tok} on a mesh with "
+            f"ep={mesh.shape['ep']}: the expert-parallel path is top-1 with "
+            "a capacity; run top-k experts without an ep axis")
     B, T, D = h.shape
     E = cfg.n_experts
     S = B * T
     cap = max(1, int(cfg.capacity_factor * S / E))
     x = h.reshape(S, D)
-    logits = jnp.einsum("sd,de->se", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, -1)
-    gate, expert = jnp.max(probs, -1), jnp.argmax(probs, -1)
+    top_p, top_e, _, _, aux = _route(x, p["router"], cfg)
+    gate, expert = top_p[:, 0], top_e[:, 0]
     # position of each token within its expert's capacity buffer
     onehot = jax.nn.one_hot(expert, E, dtype=jnp.int32)
     pos_in_expert = jnp.cumsum(onehot, axis=0) * onehot
@@ -478,38 +626,34 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     expert_in = jnp.einsum("sec,sd->ecd", dispatch, x)  # (E, cap, D)
     expert_in = _constrain(expert_in, mesh, "ep", None, None)
     u = jnp.einsum("ecd,edf->ecf", expert_in, p["w1"].astype(x.dtype),
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    u = _gelu(u + p["b1"][:, None, :].astype(x.dtype), cfg)
-    y = jnp.einsum("ecf,efd->ecd", u, p["w2"].astype(x.dtype),
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    y = y + p["b2"][:, None, :].astype(x.dtype)
+                   preferred_element_type=jnp.float32)
+    if cfg.mlp == "swiglu":
+        up = jnp.einsum("ecd,edf->ecf", expert_in, p["w3"].astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+        u = (jax.nn.silu(u) * up).astype(x.dtype)
+        y = jnp.einsum("ecf,efd->ecd", u, p["w2"].astype(x.dtype),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    else:
+        u = _gelu(u.astype(x.dtype) + p["b1"][:, None, :].astype(x.dtype),
+                  cfg)
+        y = jnp.einsum("ecf,efd->ecd", u, p["w2"].astype(x.dtype),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+        y = y + p["b2"][:, None, :].astype(x.dtype)
     combine = dispatch * gate[:, None, None].astype(x.dtype)
     out = jnp.einsum("sec,ecd->sd", combine, y)
-    # aux load-balancing loss (Switch Transformer eq. 4)
-    density = jnp.mean(jax.nn.one_hot(expert, E, dtype=jnp.float32), axis=0)
-    density_proxy = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(density * density_proxy)
     return out.reshape(B, T, D), aux
 
 
-def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
-           dropout_rng=None):
-    """One transformer block. Pre-LN (flagship default): LN -> sublayer ->
-    residual. Post-LN (``cfg.post_ln``, canonical BERT / original
-    Transformer): sublayer -> residual -> LN, with ln1 after attention and
-    ln2 after the MLP.
-
-    LOCKSTEP CONTRACT: any new dialect knob added here must be mirrored
-    in ``generate._decode_layer`` (the KV-cache form of this block) or
-    decode silently diverges from training for that config."""
+def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
+                dropout_rng):
+    """The attention half of ``_block`` -> (h after the residual, the MLP
+    half's input)."""
     post = cfg.post_ln
     h = _constrain(h, mesh, "dp", "sp", None)
     attn_in = h if post else _norm(
         h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
     attn_out = _attention(attn_in, layer_params, cfg, mesh, attn_bias)
-    if dropout_rng is not None:
-        k1, k2 = jax.random.split(dropout_rng)
-        attn_out = _dropout(attn_out, cfg.dropout_rate, k1)
+    attn_out = _dropout(attn_out, cfg.dropout_rate, dropout_rng)
     h = h + attn_out
     if post:
         h = _norm(h, layer_params["ln1_scale"],
@@ -517,14 +661,30 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     h = _constrain(h, mesh, "dp", "sp", None)
     mlp_in = h if post else _norm(
         h, layer_params["ln2_scale"], layer_params["ln2_bias"], cfg)
+    return h, mlp_in
+
+
+def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
+           dropout_rng=None):
+    """One transformer block -> (h, aux (2,) = the MoE block's [balance,
+    z] losses, zeros for a dense MLP). Pre-LN (flagship default): LN ->
+    sublayer -> residual. Post-LN (``cfg.post_ln``, canonical BERT /
+    original Transformer): sublayer -> residual -> LN, with ln1 after
+    attention and ln2 after the MLP.
+
+    LOCKSTEP CONTRACT: any new dialect knob added here must be mirrored
+    in ``generate._decode_layer`` (the KV-cache form of this block) or
+    decode silently diverges from training for that config."""
+    k1, k2 = (None, None) if dropout_rng is None else jax.random.split(
+        dropout_rng)
+    h, mlp_in = _block_attn(h, layer_params, cfg, mesh, attn_bias, k1)
     if cfg.n_experts > 0:
         out, aux = _moe_mlp(mlp_in, layer_params, cfg, mesh)
     else:
-        out, aux = _dense_mlp(mlp_in, layer_params, cfg, mesh), jnp.zeros((), jnp.float32)
-    if dropout_rng is not None:
-        out = _dropout(out, cfg.dropout_rate, k2)
-    h = h + out
-    if post:
+        out = _dense_mlp(mlp_in, layer_params, cfg, mesh)
+        aux = jnp.zeros((2,), jnp.float32)
+    h = h + _dropout(out, cfg.dropout_rate, k2)
+    if cfg.post_ln:
         h = _norm(h, layer_params["ln2_scale"],
                   layer_params["ln2_bias"], cfg)
     return h, aux
@@ -561,7 +721,8 @@ def nll_loss(logits, targets):
 
 def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
            attn_bias=None, dropout_rng=None):
-    """Run the block stack on embedded input h (B, T, D) -> (h, aux_sum).
+    """Run the block stack on embedded input h (B, T, D) -> (h, aux_sum
+    (2,): the layers' MoE [balance, z] losses summed; ``aux_weights``).
     The trunk shared by the causal LM and the bidirectional encoder (BERT);
     ``attn_bias`` (a padding mask, constant across layers) is a scan
     constant via closure. ``dropout_rng``: training-time dropout when
@@ -581,7 +742,7 @@ def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         return (h, aux_sum + aux), None
 
     (h, aux_sum), _ = jax.lax.scan(
-        scan_body, (h, jnp.zeros((), jnp.float32)),
+        scan_body, (h, jnp.zeros((2,), jnp.float32)),
         (params["blocks"], jnp.arange(L)))
     return h, aux_sum
 
@@ -602,8 +763,48 @@ def forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     return lm_head(params, h, cfg), aux_sum
 
 
+def moe_routing_stats(params, tokens, cfg: TransformerConfig):
+    """What the routers of a MoE model do with ``tokens`` (B, T): a pure
+    function beside the step, for counters and checks (no mesh). Per layer
+    (leading axis L): ``picks`` (E,) picks an expert takes, ``experts``
+    (B*T, k) the experts a token picks, ``max_over_mean`` the fullest
+    expert's load over the mean load, ``dropped`` picks the grouped
+    matmuls' group sizes do not cover (0: ``_moe_mlp`` is dropless),
+    ``entropy`` the mean entropy of the router's softmax, in nats."""
+    if not cfg.n_experts:
+        raise MoEConfigError("moe_routing_stats: a dense config")
+    k = cfg.n_experts_per_tok
+    S = tokens.shape[0] * tokens.shape[1]
+
+    def body(h, layer_params):
+        _, mlp_in = _block_attn(h, layer_params, cfg, None, None, None)
+        _, top_e, counts, probs, _ = _route(
+            mlp_in.reshape(S, -1), layer_params["router"], cfg)
+        stats = {
+            "picks": counts, "experts": top_e,
+            "max_over_mean": jnp.max(counts) * cfg.n_experts / (S * k),
+            "dropped": S * k - jnp.sum(counts),
+            "entropy": -jnp.mean(jnp.sum(
+                probs * jnp.log(jnp.maximum(probs, 1e-30)), -1))}
+        h, _ = _block(h, layer_params, cfg, None)
+        return h, stats
+
+    _, stats = jax.lax.scan(body, embed_tokens(params, tokens, cfg),
+                            params["blocks"])
+    return stats
+
+
+def aux_weights(aux_weight=0.01):
+    """Weights of ``encode``'s aux (2,): the balance loss takes the
+    caller's ``aux_weight``, the router z-loss ``Z_LOSS_WEIGHT``."""
+    return jnp.array([aux_weight, Z_LOSS_WEIGHT], jnp.float32)
+
+
 def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
             aux_weight=0.01, dropout_rng=None):
+    """Next-token cross-entropy + ``aux_weight`` x the MoE balance loss +
+    ``Z_LOSS_WEIGHT`` x the router z-loss, both summed over layers (zero
+    for dense blocks)."""
     from ..kernels.fused_ce import should_fuse
     if should_fuse(cfg.fused_lm_ce, mesh):
         # fused linear+CE: the (B*T, V) logits never exist in HBM; the
@@ -625,9 +826,9 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
         per = fused_linear_nll(h.reshape(B * T, D), w,
                                jnp.zeros((V,), jnp.float32),
                                targets.reshape(-1), w_layout=layout)
-        return jnp.mean(per) + aux_weight * aux
+        return jnp.mean(per) + aux_weights(aux_weight) @ aux
     logits, aux = forward(params, tokens, cfg, mesh, dropout_rng=dropout_rng)
-    return nll_loss(logits, targets) + aux_weight * aux
+    return nll_loss(logits, targets) + aux_weights(aux_weight) @ aux
 
 
 # ---------------------------------------------------------------------------

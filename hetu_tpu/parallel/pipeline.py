@@ -74,7 +74,7 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
             h, a = block(h, layer_params, dropout_rng=rng)
             return (h, aux + a), None
 
-        aux0 = jax.lax.pcast(jnp.zeros((), jnp.float32), ("pp",),
+        aux0 = jax.lax.pcast(jnp.zeros((2,), jnp.float32), ("pp",),
                               to="varying")
         (h, aux), _ = jax.lax.scan(
             body, (h, aux0), (stage_blocks, jnp.arange(layers_per_stage)))
@@ -183,7 +183,7 @@ def make_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
             varying = lambda x: jax.lax.pcast(x, ("pp",), to="varying")
             state = varying(state0)
             loss_sum = varying(jnp.zeros((), jnp.float32))
-            aux_sum = varying(jnp.zeros((), jnp.float32))
+            aux_sum = varying(jnp.zeros((2,), jnp.float32))
 
             def tick(carry, t):
                 state, loss_sum, aux_sum = carry
@@ -221,7 +221,7 @@ def make_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
             # NLL lives on the last stage, aux is spread over stages; combine
             loss = jax.lax.psum(loss_sum, "pp") / M
             aux = jax.lax.psum(aux_sum, "pp") / M
-            return loss + aux_weight * aux
+            return loss + tfm.aux_weights(aux_weight) @ aux
 
         block_in_spec = jax.tree.map(lambda _: P("pp"), stage_blocks)
         other_spec = jax.tree.map(lambda _: P(), other)
@@ -529,7 +529,7 @@ def make_pipeline_train_step_1f1b(cfg: tfm.TransformerConfig, mesh: Mesh,
                 jax.tree.map(jnp.zeros_like, local_blocks),   # g_blocks
                 jax.tree.map(lambda x: varying(jnp.zeros_like(x)), other),
                 varying(jnp.zeros((), jnp.float32)),     # loss_sum
-                varying(jnp.zeros((), jnp.float32)),     # aux_sum
+                varying(jnp.zeros((2,), jnp.float32)),   # aux_sum
             )
 
             def mb_rng(m):
@@ -599,8 +599,7 @@ def make_pipeline_train_step_1f1b(cfg: tfm.TransformerConfig, mesh: Mesh,
                         # vma type as the outputs they correspond to
                         db, dother, dh = vjp(
                             (ct_h2,
-                             varying(jnp.full((), aux_weight / M,
-                                              jnp.float32)),
+                             varying(tfm.aux_weights(aux_weight) / M),
                              varying(ct_nll)))
                         return db, dother, dh, nll
 
@@ -640,7 +639,7 @@ def make_pipeline_train_step_1f1b(cfg: tfm.TransformerConfig, mesh: Mesh,
                                        0.0).astype(jnp.float32)
                     db, dother, dh = vjp(
                         (ct_h2,
-                         varying(jnp.full((), aux_weight / M, jnp.float32)),
+                         varying(tfm.aux_weights(aux_weight) / M),
                          ct_nll))
                     de = embed_grads(dh)
                     dother = jax.tree.map(
@@ -719,7 +718,7 @@ def make_pipeline_train_step_1f1b(cfg: tfm.TransformerConfig, mesh: Mesh,
             aux = jax.lax.psum(aux_sum, "pp") / M
             g_other = jax.tree.map(lambda g: jax.lax.psum(g, "pp"), g_other)
             g_blocks = jax.tree.map(lambda g: g[None], g_blocks)
-            return loss + aux_weight * aux, g_blocks, g_other
+            return loss + tfm.aux_weights(aux_weight) @ aux, g_blocks, g_other
 
         block_in_spec = jax.tree.map(lambda _: P("pp"), stage_blocks)
         other_spec = jax.tree.map(lambda _: P(), other)

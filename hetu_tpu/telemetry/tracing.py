@@ -47,6 +47,15 @@ STEP = "hetu_step"        # one SubExecutor.run call, step_num=<step>
 # forward
 SCOPE_FWD = "hetu_fwd"    # around the loss function that is differentiated
 SCOPE_OPT = "hetu_opt"    # around the optimizer update
+# the four parts of a MoE block (transformer._moe_mlp), nested under
+# SCOPE_FWD: the phase rule above still says forward / recompute / backward
+SCOPE_MOE_ROUTE = "hetu_moe_route"        # router matmul, softmax, top-k,
+                                          # sort, group sizes, both aux losses
+SCOPE_MOE_DISPATCH = "hetu_moe_dispatch"  # token rows gathered by expert
+SCOPE_MOE_EXPERTS = "hetu_moe_experts"    # grouped matmuls + activation
+SCOPE_MOE_COMBINE = "hetu_moe_combine"    # un-permute, weight, sum over k
+MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
+              SCOPE_MOE_COMBINE)
 # host spans inside SubExecutor.run, children of STEP, in call order
 (BOUNDARY, FEED, DL_WAIT, PS_PULL, BUILD, DISPATCH, PREFETCH, PS_PUSH,
  POSTSTEP) = STEP_SPANS = (

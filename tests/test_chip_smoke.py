@@ -46,10 +46,16 @@ def test_flagship_phase_tiny(capsys):
     cfg = bert.BertConfig(vocab_size=512, d_model=64, n_heads=4, n_layers=2,
                           d_ff=128, max_seq_len=128, dtype=jnp.float32,
                           remat=False)
+    moe = dict(chip_smoke.MOE_ROW, vocab_size=64, d_model=64, n_heads=4,
+               d_ff=32, max_seq_len=32, n_experts=8, n_experts_per_tok=2,
+               dtype=jnp.float32)
     rec = chip_smoke.phase_flagship(cfg=cfg, batch=4, seq=128, n_pred=8,
-                                    steps=3, chip=False)
+                                    steps=3, chip=False, moe=moe)
     line = _last_json(capsys)
     assert line["phase"] == "flagship"
+    assert line["moe"]["dropped_picks"] == 0
+    assert line["moe"]["tokens"] == 64 and line["moe"]["experts"] == 8
+    assert max(line["moe"]["rel_rms_err"].values()) < 1e-4
     assert rec["attn_impl"] == "dot" and rec["mlm_ce"] == "einsum"
     assert abs(rec["vs_reference"]["loss"]
                - rec["vs_reference"]["reference_loss"]) < 1e-3

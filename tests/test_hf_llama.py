@@ -203,6 +203,28 @@ def test_import_refuses_sliding_window_and_odd_head_dim():
         config_from_hf(FakeCfg())
 
 
-def test_swiglu_moe_combination_refuses():
-    with pytest.raises(ValueError, match="swiglu"):
-        tfm.TransformerConfig(mlp="swiglu", n_experts=4)
+def test_swiglu_experts_compute_swiglu():
+    """mlp='swiglu' with experts (refused until ISSUE 25): every expert is
+    the dialect's down(silu(gate x) * up x). With E copies of one dense
+    SwiGLU MLP as the experts, a token's output is that MLP's output times
+    the router probabilities of its picks."""
+    import jax
+    import jax.numpy as jnp
+    cfg = tfm.TransformerConfig(d_model=32, n_heads=4, d_ff=48, mlp="swiglu",
+                                n_experts=4, n_experts_per_tok=2,
+                                dtype=jnp.float32)
+    dense_cfg = tfm.TransformerConfig(d_model=32, n_heads=4, d_ff=48,
+                                      mlp="swiglu", dtype=jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    dense = {"w1": jax.random.normal(ks[0], (32, 48)) * 0.2,
+             "w3": jax.random.normal(ks[1], (32, 48)) * 0.2,
+             "w2": jax.random.normal(ks[2], (48, 32)) * 0.2}
+    layer = {k: jnp.broadcast_to(v, (4,) + v.shape) for k, v in dense.items()}
+    layer["router"] = jax.random.normal(ks[3], (32, 4))
+    h = jax.random.normal(ks[4], (2, 8, 32))
+    out, _aux = tfm._moe_mlp(h, layer, cfg, None)
+    probs = jax.nn.softmax(h.reshape(16, 32) @ layer["router"], -1)
+    weight = jnp.sum(jax.lax.top_k(probs, 2)[0], -1).reshape(2, 8, 1)
+    want = tfm._dense_mlp(h, dense, dense_cfg, None) * weight
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
