@@ -539,7 +539,7 @@ def phase_kernels(*, shapes=None, chip=True):
     from hetu_tpu import comm_quant
     from hetu_tpu.kernels import (csr_spmm, embed_grad, fused_opt,
                                   quant_comm, registry)
-    from hetu_tpu.kernels.flash_attention import (flash_attention,
+    from hetu_tpu.kernels.flash_attention import (flash_attention_btd,
                                                   mha_reference)
     from hetu_tpu.kernels.fused_ce import (fused_linear_nll,
                                            linear_nll_reference)
@@ -586,27 +586,36 @@ def phase_kernels(*, shapes=None, chip=True):
     rec = {"kernels": results}
     with _phase("kernels", rec):
         # -- flash attention, fwd + bwd, bf16 (tests/test_attention.py's
-        # bf16 tolerance) -----------------------------------------------
+        # bf16 tolerance), through the entry the trunk calls: the fused
+        # projection's (batch, seq, [q|k|v] x heads x head_dim) array in,
+        # (batch, seq, heads x head_dim) out -------------------------------
         b, h, s, d = shapes["flash"]
-        q, k, v = (jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
-                   for _ in range(3))
+        qkv = jnp.asarray(rng.randn(b, s, 3 * h * d), jnp.bfloat16)
         k_bias = jnp.asarray(np.where(
             np.arange(s)[None, :] < rng.randint(s // 2, s + 1, (b, 1)),
             0.0, -1e9), jnp.float32)
 
+        def reference_btd(qkv, causal, k_bias):
+            q, k, v = (x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+                       for x in jnp.split(qkv, 3, axis=-1))
+            out = mha_reference(q, k, v, causal=causal, k_bias=k_bias)
+            return out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
         def attn_and_grads(fn, causal, bias):
-            def run(q, k, v):
-                def loss(q, k, v):
-                    return jnp.sum(fn(q, k, v, causal=causal, k_bias=bias)
+            def run(qkv):
+                def loss(qkv):
+                    return jnp.sum(fn(qkv, causal, bias)
                                    .astype(jnp.float32) ** 2)
-                return (fn(q, k, v, causal=causal, k_bias=bias),
-                        jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+                return fn(qkv, causal, bias), jax.grad(loss)(qkv)
             return run
+
+        def flash(qkv, causal, k_bias):
+            return flash_attention_btd(qkv, h, causal, k_bias=k_bias)
 
         for label, causal, bias in (("flash_causal", True, None),
                                     ("flash_key_padding", False, k_bias)):
-            compare(label, attn_and_grads(flash_attention, causal, bias),
-                    attn_and_grads(mha_reference, causal, bias), (q, k, v),
+            compare(label, attn_and_grads(flash, causal, bias),
+                    attn_and_grads(reference_btd, causal, bias), (qkv,),
                     atol=2e-2 * max(1.0, float(s) ** 0.5), rtol=2e-2)
 
         # -- fused linear + softmax CE, fwd + bwd, bf16 -------------------

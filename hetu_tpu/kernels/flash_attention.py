@@ -13,8 +13,11 @@ only (block_q, block_k) tiles ever exist:
   tile from (q, k, lse) — no (S,S) materialization); off-TPU, a blockwise
   `lax.scan` recomputation in XLA serves as fallback and numerical oracle.
 
-Public entry: ``flash_attention(q, k, v, causal=True)`` with shapes
-(batch, heads, seq, head_dim), differentiable via custom_vjp. An optional
+Public entry: ``flash_attention_btd(qkv, n_heads, causal=True)`` on the
+projections' own layout, (batch, seq, heads * head_dim) in and out (``qkv``
+one fused [q | k | v] array or three arrays), differentiable via custom_vjp:
+nothing is transposed around the kernels. ``flash_attention(q, k, v)`` on
+(batch, heads, seq, head_dim) wraps it with XLA transposes. An optional
 ``k_bias`` (batch, seq) float is ADDED to every score column — the key-
 padding mask form (0 valid / -1e9 padded) the BERT encoder uses — so masked
 batches keep the fused kernel instead of falling back to the unfused path.
@@ -70,21 +73,32 @@ def _vmem_bytes(s, d, itemsize, block_q, block_k, heads):
     return blocks + rows + 5 * block_q * block_k * 4
 
 
+def _head_groups(heads, d):
+    """How many heads a grid step may take. Its block is `g * d` columns of
+    a (batch, seq, heads * d) array, and Mosaic takes a block whose last
+    axis is whole 128-lane tiles or the whole axis: the divisors `g` of
+    `heads`, up to `_MAX_HEADS`, with `g * d` a multiple of 128 (2, 4, 6,
+    12 of BERT's twelve heads of 64; any at d = 128) or `g` every head (3
+    heads of 64 go as one block of the full 192). A head count that leaves
+    none of these (17 heads of 64) goes whole."""
+    groups = [g for g in range(1, min(heads, _MAX_HEADS) + 1)
+              if heads % g == 0 and ((g * d) % _LANES == 0 or g == heads)]
+    return groups or [heads]
+
+
 def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
     """-> (block_q, block_k, heads a grid step), from the call's shapes alone.
 
-    `heads` is how many consecutive rows of the flattened (batch*heads)
-    axis may share a grid step: the head count where a key bias ties a step
-    to one batch row, batch*heads where there is none. A `block_q`/`block_k`
-    the caller passed is kept.
+    `heads` is the head count: a grid step takes `g` consecutive heads of
+    one batch row, `g` one of `_head_groups`. A `block_q`/`block_k` the
+    caller passed is kept.
 
     Blocks: the largest of 512/256/128 that divides `s` (the whole sequence
     below 128), for causal calls too: on the chip a 512 x 512 tile that
     computes its masked half beat 256 x 256 tiles that skip more (PR 24).
-    Heads: the fewest that give a step `_STEP_FLOPS` of work, among the
-    divisors of `heads`. Both give way, heads first, until `_vmem_bytes`
-    fits `_VMEM_BUDGET`; the floor is one head and the smallest blocks,
-    which is what every call had before PR 24."""
+    Heads: the fewest that give a step `_STEP_FLOPS` of work. Both give
+    way, heads first, until `_vmem_bytes` fits `_VMEM_BUDGET`; the floor is
+    the smallest group and the smallest blocks."""
     itemsize = jnp.dtype(dtype).itemsize
 
     def sizes(given):
@@ -94,8 +108,7 @@ def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
 
     picks = [(bq, bk) for bq in sizes(block_q) for bk in sizes(block_k)]
     picks.sort(key=lambda p: -p[0] * p[1])
-    groups = [g for g in range(1, min(heads, _MAX_HEADS) + 1)
-              if heads % g == 0]
+    groups = _head_groups(heads, d)
     for bq, bk in picks:
         step = 4.0 * bq * s * d * (0.5 if causal else 1.0)
         want = next((g for g in groups if g * step >= _STEP_FLOPS),
@@ -104,19 +117,39 @@ def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
             if _vmem_bytes(s, d, itemsize, bq, bk, g) <= _VMEM_BUDGET:
                 return bq, bk, g
     bq, bk = picks[-1]
-    return bq, bk, 1
+    return bq, bk, groups[0]
 
 
-def _tiles_for(q, k_bias, causal, block_q, block_k):
-    """`_choose_tiles` for a (batch, heads, seq, head_dim) call; blocks that
-    do not divide the sequence (only ones the caller passed can) raise."""
-    b, h, s, d = q.shape
-    block_q, block_k, heads = _choose_tiles(
-        s, d, q.dtype, causal, b * h if k_bias is None else h, block_q,
-        block_k)
+def _is_fused(qkv):
+    """One (batch, seq, 3 * heads * d) array [q | k | v], as a fused
+    projection writes it, and not three (batch, seq, heads * d) arrays."""
+    return not isinstance(qkv, (tuple, list))
+
+
+def _width(qkv):
+    """heads * d, the columns of q (and of o)."""
+    return qkv.shape[-1] // 3 if _is_fused(qkv) else qkv[0].shape[-1]
+
+
+def _three(qkv):
+    """The three arrays, whichever form the caller gave."""
+    return jnp.split(qkv, 3, axis=-1) if _is_fused(qkv) else tuple(qkv)
+
+
+def _tiles_for(qkv, n_heads, causal, block_q, block_k):
+    """-> (qkv, block_q, block_k, heads a step, d). Blocks that do not
+    divide the sequence (only ones the caller passed can) raise. A fused
+    array whose head group is not whole lane tiles (three heads of 64)
+    cannot be indexed by column block: it is cut in three here."""
+    q = qkv if _is_fused(qkv) else qkv[0]
+    s, d = q.shape[1], _width(qkv) // n_heads
+    block_q, block_k, heads = _choose_tiles(s, d, q.dtype, causal, n_heads,
+                                            block_q, block_k)
     if s % block_q or s % block_k:
         raise ValueError(f"seq_len {s} must divide blocks ({block_q},{block_k})")
-    return block_q, block_k, heads
+    if _is_fused(qkv) and (heads * d) % _LANES:
+        qkv = _three(qkv)
+    return qkv, block_q, block_k, heads, d
 
 
 def _dot(a, b):
@@ -127,6 +160,26 @@ def _dot_nt(a, b):
     """a @ b.T on the MXU: operands as they come, f32 out."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
+
+
+def _head_sums(x, d, pieces):
+    """(n, heads * d) f32 -> (n, 128) f32: column `h` is the sum of head
+    `h`'s d columns, every head of the block at once, on the MXU: x goes
+    in as `pieces` bf16 terms that add up to it (two hold the product of
+    two bf16 values exactly, three an f32 value) against a 0/1 matrix, so
+    each pass is exact and the sum is f32's. (Summed head by head along the
+    lanes of a slice, delta cost `flash_bwd_dq` 0.44 ms of 2.14 a call at
+    BERT's shapes, and one matmul at "highest" precision as much; PR 26.)"""
+    shape = (x.shape[1], _LANES)
+    pick = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // d
+            == jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            ).astype(jnp.bfloat16)
+    total = jnp.zeros((x.shape[0], _LANES), jnp.float32)
+    for _ in range(pieces):
+        piece = x.astype(jnp.bfloat16)
+        total = total + _dot(piece, pick)
+        x = x - piece.astype(jnp.float32)
+    return total
 
 
 def _col_to_row(col):
@@ -186,9 +239,12 @@ def _optional_bias(kernel, n_before, use_bias):
 
 
 # ---------------------------------------------------------------------------
-# The tile program, common to the three kernels. A grid step owns `heads`
-# consecutive rows of the flattened (batch*heads) axis and one block of the
-# sequence, and loops over the blocks of the other side:
+# The tile program, common to the three kernels. q, k, v, o and their
+# gradients lie in HBM as the projections write and read them: (batch, seq,
+# heads * d), a head's d columns side by side on the lane axis. A grid step
+# owns one batch row, `heads` consecutive heads (a block of heads * d
+# columns, whole lane tiles) and one block of the sequence, and loops over
+# the blocks of the other side; a head is a static slice of the lanes.
 # - MXU operands go in as the caller gave them (bf16 from a bf16 model, f32
 #   from an f32 caller), every dot accumulates in f32, and everything else
 #   is f32: scores, bias add, m, l, exp, lse, delta, the accumulators.
@@ -201,22 +257,23 @@ def _optional_bias(kernel, n_before, use_bias):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
-                causal, block_k):
-    # grid: (batch*heads / heads, q blocks); one q block, the whole k and v
-    heads, block_q, d = q_ref.shape
-    q_start = pl.program_id(1) * block_q
+                causal, block_k, d):
+    # grid: (batch, head groups, q blocks); one q block, the whole k and v
+    block_q = q_ref.shape[0]
+    q_start = pl.program_id(2) * block_q
     # causal: skip key blocks entirely above the diagonal
     upper = (_causal_upper_kb(q_start, block_q, block_k) if causal
-             else k_ref.shape[1] // block_k)
+             else k_ref.shape[0] // block_k)
 
-    for g in range(heads):
-        q = q_ref[g]                                  # (block_q, d)
+    for g in range(q_ref.shape[1] // d):
+        lanes = _block(g, d)
+        q = q_ref[:, lanes]                           # (block_q, d)
 
         def body(kj, carry):
             acc, m_prev, l_prev = carry
             keys = _block(kj, block_k)
-            v_blk = v_ref[g, keys]
-            s = _dot_nt(q, k_ref[g, keys]) * scale    # (block_q, block_k)
+            v_blk = v_ref[keys, lanes]
+            s = _dot_nt(q, k_ref[keys, lanes]) * scale  # (block_q, block_k)
             if bias_ref is not None:
                 s = s + bias_ref[0, :, keys]
             if causal:
@@ -233,120 +290,153 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
             jnp.full((block_q, 1), _NEG_INF, jnp.float32),
             jnp.zeros((block_q, 1), jnp.float32)))
         l = jnp.maximum(l, 1e-30)
-        o_ref[g] = (acc / l).astype(o_ref.dtype)
+        o_ref[:, lanes] = (acc / l).astype(o_ref.dtype)
         lse_ref[g] = _col_to_row(m + jnp.log(l))
 
 
-def _specs(heads, s, d, block, n_heads):
-    """BlockSpecs over the flattened arrays for a grid (groups, blocks):
-    a (block, d) tile and a whole (s, d) operand; a row of statistics for
-    the block and the whole row; the same two of the key bias, which has a
-    row a batch row, so a group reads the row of the batch it lies in."""
-    def batch_row(i):
-        return i * heads // n_heads
+def _specs(qkv, heads, d, n_heads, block):
+    """BlockSpec makers for a grid (batch, head groups, blocks of the
+    sequence), each for one block of the sequence or, with `whole`, all of
+    it: `cols(name)` the `heads * d` columns of head group `j` in q, k, v
+    (column block `j`, `groups + j`, `2 * groups + j` of a fused array) or
+    in "o" (any array of q's width alone: o, dO, dq, dv); `stats()` the
+    group's rows of lse or delta; `bias()` the batch row's key bias."""
+    groups = n_heads // heads
+    s = (qkv if _is_fused(qkv) else qkv[0]).shape[1]
+    first = dict(q=0, k=0, v=0, o=0)
+    if _is_fused(qkv):
+        first.update(k=groups, v=2 * groups)
 
-    return (pl.BlockSpec((heads, block, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((heads, s, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((heads, 1, block), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((heads, 1, s), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, block), lambda i, j: (batch_row(i), 0, j)),
-            pl.BlockSpec((1, 1, s), lambda i, j: (batch_row(i), 0, 0)))
+    def along(whole):
+        return (s, lambda i: 0) if whole else (block, lambda i: i)
+
+    def cols(name, whole=False):
+        n, at = along(whole)
+        return pl.BlockSpec((None, n, heads * d),
+                            lambda b, j, i: (b, at(i), first[name] + j))
+
+    def stats(whole=False):
+        n, at = along(whole)
+        return pl.BlockSpec((heads, 1, n),
+                            lambda b, j, i: (b * groups + j, 0, at(i)))
+
+    def bias(whole=False):
+        n, at = along(whole)
+        return pl.BlockSpec((1, 1, n), lambda b, j, i: (b, 0, at(i)))
+
+    return cols, stats, bias
+
+
+def _operands(qkv):
+    """q, k and v as the kernels take them: the same array three times
+    where they are one, under three index maps (`_specs`)."""
+    return [qkv] * 3 if _is_fused(qkv) else list(qkv)
 
 
 def _bias_rows(k_bias):
     """(b, s) key bias -> (b, 1, s) f32 rows: one a batch row, whatever the
-    head (the index map sends a group to its batch row)."""
+    head (the index map sends a step to its batch row)."""
     return k_bias.astype(jnp.float32)[:, None, :]
 
 
-def _fwd_pallas(q, k, v, k_bias, scale, causal, block_q, block_k, interpret):
-    b, h, s, d = q.shape
-    bh = b * h
+def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
+                interpret):
+    """-> o (batch, seq, heads * d), lse (batch * heads, 1, seq)."""
+    qkv, block_q, block_k, heads, d = _tiles_for(qkv, n_heads, causal,
+                                                 block_q, block_k)
+    ops = _operands(qkv)
+    b, s, _ = ops[0].shape
     use_bias = k_bias is not None
-    block_q, block_k, heads = _tiles_for(q, k_bias, causal, block_q, block_k)
-    tile, whole, row, _, _, whole_bias = _specs(heads, s, d, block_q, h)
-    in_specs = [tile, whole, whole]
-    ops = [x.reshape(bh, s, d) for x in (q, k, v)]
-    if use_bias:
-        in_specs.append(whole_bias)
-        ops.append(_bias_rows(k_bias))
+    bias = [_bias_rows(k_bias)] if use_bias else []
+    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_q)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_k=block_k)
-    out, lse = pl.pallas_call(
+                             block_k=block_k, d=d)
+    return pl.pallas_call(
         _optional_bias(kern, 3, use_bias),
-        grid=(bh // heads, s // block_q),
-        in_specs=in_specs,
-        out_specs=[tile, row],
+        grid=(b, n_heads // heads, s // block_q),
+        in_specs=[cols("q"), cols("k", True), cols("v", True)]
+        + [bias_spec(True)] * use_bias,
+        out_specs=[cols("o"), stats()],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, s, n_heads * d), ops[0].dtype),
+            jax.ShapeDtypeStruct((b * n_heads, 1, s), jnp.float32),
         ],
         interpret=interpret,
         name=FLASH_FWD,
-    )(*ops)
-    return out.reshape(b, h, s, d), lse.reshape(b, h, s)
+    )(*ops, *bias)
 
 
 # ---------------------------------------------------------------------------
 # backward Pallas kernels (dq; dk+dv) — flash backward both directions:
 # each tile recomputes its probability block from (q, k, lse), so nothing
-# (S, S)-shaped ever exists. delta = rowsum(dO * O) is precomputed in XLA.
+# (S, S)-shaped ever exists. delta = rowsum(dO * O) over a head's columns is
+# made by `flash_bwd_dq`, which needs it as the columns it falls out as, and
+# written as rows like lse for `flash_bwd_dkv` (left to XLA, the sum over a
+# (batch, seq, heads, d) view cost a relayout of the whole f32 product).
 # `scale` multiplies the f32 scores on the way in and the f32 accumulators
 # of dq and dk on the way out (d x block elements, not block x block).
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   bias_ref, dq_ref, *, scale, causal, block_k):
-    # grid: (groups, q blocks); owns one q block, loops over k blocks
-    heads, block_q, d = q_ref.shape
-    q_start = pl.program_id(1) * block_q
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
+                   dq_ref, delta_ref, *, scale, causal, block_k, d):
+    # grid: (batch, head groups, q blocks); owns one q block, loops over k
+    block_q = q_ref.shape[0]
+    q_start = pl.program_id(2) * block_q
     upper = (_causal_upper_kb(q_start, block_q, block_k) if causal
-             else k_ref.shape[1] // block_k)
+             else k_ref.shape[0] // block_k)
+
+    heads = q_ref.shape[1] // d
+    deltas = _head_sums(
+        do_ref[...].astype(jnp.float32) * o_ref[...].astype(jnp.float32), d,
+        pieces=2 if do_ref.dtype == jnp.bfloat16 else 3)
+    delta_ref[:, 0, :] = deltas.T[:heads]
 
     for g in range(heads):
-        q = q_ref[g]                                  # (block_q, d)
-        do = do_ref[g]
+        lanes = _block(g, d)
+        q = q_ref[:, lanes]                           # (block_q, d)
+        do = do_ref[:, lanes]
         lse = _row_to_col(lse_ref[g])                 # (block_q, 1)
-        delta = _row_to_col(delta_ref[g])
+        delta = deltas[:, g:g + 1]
 
         def body(kj, dq):
             keys = _block(kj, block_k)
-            k_blk = k_ref[g, keys]
+            k_blk = k_ref[keys, lanes]
             s = _dot_nt(q, k_blk) * scale
             if bias_ref is not None:
                 s = s + bias_ref[0, :, keys]
             if causal:
                 s = _causal_mask(s, q_start, kj * block_k)
             p = jnp.exp(s - lse)
-            dp = _dot_nt(do, v_ref[g, keys])
+            dp = _dot_nt(do, v_ref[keys, lanes])
             ds = p * (dp - delta)
             return dq + _dot(ds.astype(q.dtype), k_blk)
 
         dq = _loop(0, upper, body, jnp.zeros((block_q, d), jnp.float32))
-        dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
+        dq_ref[:, lanes] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    bias_ref, dk_ref, dv_ref, *, scale, causal, block_q):
-    # grid: (groups, k blocks); owns one k/v block, loops over q blocks.
-    # Tiles are (block_k, block_q): S^T, P^T, dP^T, dS^T.
-    heads, block_k, d = k_ref.shape
-    k_start = pl.program_id(1) * block_k
+                    bias_ref, dv_ref, dk_ref, *, scale, causal, block_q, d):
+    # grid: (batch, head groups, k blocks); owns one k/v block, loops over
+    # q blocks. Tiles are (block_k, block_q): S^T, P^T, dP^T, dS^T.
+    block_k = k_ref.shape[0]
+    k_start = pl.program_id(2) * block_k
     # causal: q blocks strictly before this k block contribute nothing
     lower = (k_start // block_q) if causal else 0
-    upper = q_ref.shape[1] // block_q
+    upper = q_ref.shape[0] // block_q
     # this kernel owns ONE k block: its bias column is constant
     bias = None if bias_ref is None else _row_to_col(bias_ref[0])
 
-    for g in range(heads):
-        k_blk = k_ref[g]                              # (block_k, d)
-        v_blk = v_ref[g]
+    for g in range(k_ref.shape[1] // d):
+        lanes = _block(g, d)
+        k_blk = k_ref[:, lanes]                       # (block_k, d)
+        v_blk = v_ref[:, lanes]
 
         def body(qi, carry):
             dk, dv = carry
             rows = _block(qi, block_q)
-            q = q_ref[g, rows]
-            do = do_ref[g, rows]
+            q = q_ref[rows, lanes]
+            do = do_ref[rows, lanes]
             st = _dot_nt(k_blk, q) * scale
             if bias is not None:
                 st = st + bias
@@ -361,61 +451,64 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         zeros = jnp.zeros((block_k, d), jnp.float32)
         dk, dv = _loop(lower, upper, body, (zeros, zeros))
-        dk_ref[g] = (dk * scale).astype(dk_ref.dtype)
-        dv_ref[g] = dv.astype(dv_ref.dtype)
+        dk_ref[:, lanes] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[:, lanes] = dv.astype(dv_ref.dtype)
 
 
-def _bwd_pallas(res, do, *, scale, causal, block_q, block_k, interpret):
-    q, k, v, o, lse, k_bias = res
-    b, h, s, d = q.shape
-    bh = b * h
+def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
+                interpret):
+    """-> dq, dk, dv in the form qkv came in: three arrays where it was
+    three; where it was one, one (batch, seq, 3 * heads * d) array, which
+    `flash_bwd_dkv` makes and writes the k columns of, and dq and dv are
+    set into: two passes over a third of it each, that transpose nothing.
+    (The first result of every kernel stays an array of o's shape: the
+    benchmark's reader takes a call's FLOPs from it.)"""
+    given, o, lse, k_bias = res
+    qkv, block_q, block_k, heads, d = _tiles_for(given, n_heads, causal,
+                                                 block_q, block_k)
+    b, s, width = o.shape
     use_bias = k_bias is not None
-    block_q, block_k, heads = _tiles_for(q, k_bias, causal, block_q, block_k)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                           # (b, h, s)
-    ops = [x.reshape(bh, s, d) for x in (q, k, v, do)]
-    ops += [lse.reshape(bh, 1, s), delta.reshape(bh, 1, s)]
-    if use_bias:
-        ops.append(_bias_rows(k_bias))
-    grid = bh // heads
+    bias = [_bias_rows(k_bias)] if use_bias else []
+    grid = (b, n_heads // heads)
+    grad = jax.ShapeDtypeStruct(o.shape, o.dtype)
 
-    tile, whole, row, _, _, whole_bias = _specs(heads, s, d, block_q, h)
-    dq_specs = [tile, whole, whole, tile, row, row]
-    if use_bias:
-        dq_specs.append(whole_bias)
+    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_q)
     dq_kern = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                block_k=block_k)
-    dq = pl.pallas_call(
+                                block_k=block_k, d=d)
+    dq, delta = pl.pallas_call(
         _optional_bias(dq_kern, 6, use_bias),
-        grid=(grid, s // block_q),
-        in_specs=dq_specs,
-        out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        grid=grid + (s // block_q,),
+        in_specs=[cols("q"), cols("k", True), cols("v", True), cols("o"),
+                  cols("o"), stats()] + [bias_spec(True)] * use_bias,
+        out_specs=[cols("o"), stats()],
+        out_shape=[grad, jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
         interpret=interpret,
         name=FLASH_BWD_DQ,
-    )(*ops)
+    )(*_operands(qkv), do, o, lse, *bias)
 
-    tile, whole, _, whole_row, bias_row, _ = _specs(heads, s, d, block_k, h)
-    dkv_specs = [whole, tile, tile, whole, whole_row, whole_row]
-    if use_bias:
-        dkv_specs.append(bias_row)
+    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_k)
     dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                 causal=causal, block_q=block_q)
-    dk, dv = pl.pallas_call(
+                                 causal=causal, block_q=block_q, d=d)
+    # dk goes where k came from: the same columns of an array of qkv's
+    # shape, or an array of its own
+    dv, dk = pl.pallas_call(
         _optional_bias(dkv_kern, 6, use_bias),
-        grid=(grid, s // block_k),
-        in_specs=dkv_specs,
-        out_specs=[tile, tile],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
-        ],
+        grid=grid + (s // block_k,),
+        in_specs=[cols("q", True), cols("k"), cols("v"), cols("o", True),
+                  stats(True), stats(True)] + [bias_spec()] * use_bias,
+        out_specs=[cols("o"), cols("k")],
+        out_shape=[grad, jax.ShapeDtypeStruct(qkv.shape, o.dtype)
+                   if _is_fused(qkv) else grad],
         interpret=interpret,
         name=FLASH_BWD_DKV,
-    )(*ops)
+    )(*_operands(qkv), do, lse, delta, *bias)
 
-    return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),
-            dv.reshape(b, h, s, d))
+    if _is_fused(qkv):
+        out = jax.lax.dynamic_update_slice_in_dim(dk, dq, 0, axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(out, dv, 2 * width, axis=2)
+    if _is_fused(given):    # cut in three by `_tiles_for`: three heads of 64
+        return jnp.concatenate([dq, dk, dv], axis=-1)
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -423,23 +516,27 @@ def _bwd_pallas(res, do, *, scale, causal, block_q, block_k, interpret):
 # (off-TPU fallback and the Pallas backward's numerical oracle)
 # ---------------------------------------------------------------------------
 
-def _bwd_blockwise(res, do, *, scale, causal, block_k):
-    q, k, v, o, lse, k_bias = res
-    b, h, s, d = q.shape
+def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k):
+    qkv, o, lse, k_bias = res
+    b, s, _ = o.shape
     nkb = s // block_k
-    do_f = do.astype(jnp.float32)
-    q_f = q.astype(jnp.float32)
+
+    def heads(x):
+        return x.astype(jnp.float32).reshape(b, s, n_heads, -1)
+
+    q_f, k_f, v_f = (heads(x) for x in _three(qkv))
+    do_f = heads(do)
+    lse = lse.reshape(b, n_heads, s)
     # delta_i = sum_j dO_ij O_ij  (rowwise), standard flash backward
-    delta = jnp.sum(do_f * o.astype(jnp.float32), axis=-1)  # (b,h,s)
+    delta = jnp.einsum("bqhd,bqhd->bhq", do_f, heads(o))
 
     q_pos = jnp.arange(s)
 
     def one_kblock(kj):
         ks = kj * block_k
-        k_blk = jax.lax.dynamic_slice_in_dim(k, ks, block_k, 2)
-        v_blk = jax.lax.dynamic_slice_in_dim(v, ks, block_k, 2)
-        s_blk = jnp.einsum("bhqd,bhkd->bhqk", q_f,
-                           k_blk.astype(jnp.float32)) * scale
+        k_blk = jax.lax.dynamic_slice_in_dim(k_f, ks, block_k, 1)
+        v_blk = jax.lax.dynamic_slice_in_dim(v_f, ks, block_k, 1)
+        s_blk = jnp.einsum("bqhd,bkhd->bhqk", q_f, k_blk) * scale
         if k_bias is not None:
             kb = jax.lax.dynamic_slice_in_dim(
                 k_bias.astype(jnp.float32), ks, block_k, 1)
@@ -448,11 +545,11 @@ def _bwd_blockwise(res, do, *, scale, causal, block_k):
             mask = q_pos[:, None] >= (ks + jnp.arange(block_k))[None, :]
             s_blk = jnp.where(mask, s_blk, _NEG_INF)
         p = jnp.exp(s_blk - lse[..., None])                    # (b,h,s,bk)
-        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, do_f)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", do_f, v_blk.astype(jnp.float32))
+        dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p, do_f)
+        dp = jnp.einsum("bqhd,bkhd->bhqk", do_f, v_blk)
         ds = p * (dp - delta[..., None]) * scale
-        dq_part = jnp.einsum("bhqk,bhkd->bhqd", ds, k_blk.astype(jnp.float32))
-        dk_blk = jnp.einsum("bhqk,bhqd->bhkd", ds, q_f)
+        dq_part = jnp.einsum("bhqk,bkhd->bqhd", ds, k_blk)
+        dk_blk = jnp.einsum("bhqk,bqhd->bkhd", ds, q_f)
         return dq_part, dk_blk, dv_blk
 
     def scan_body(dq_acc, kj):
@@ -460,25 +557,35 @@ def _bwd_blockwise(res, do, *, scale, causal, block_k):
         return dq_acc + dq_part, (dk_blk, dv_blk)
 
     dq, (dk_blocks, dv_blocks) = jax.lax.scan(
-        scan_body, jnp.zeros(q.shape, jnp.float32), jnp.arange(nkb))
-    dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(b, h, s, d)
-    dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(b, h, s, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        scan_body, jnp.zeros(q_f.shape, jnp.float32), jnp.arange(nkb))
+    grads = [dq] + [jnp.moveaxis(x, 0, 1) for x in (dk_blocks, dv_blocks)]
+    grads = [x.reshape(o.shape).astype(o.dtype) for x in grads]
+    if _is_fused(qkv):
+        return jnp.concatenate(grads, axis=-1)
+    return tuple(grads)
 
 
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, k_bias, causal, scale, block_q, block_k):
-    out, _ = _flash_fwd(q, k, v, k_bias, causal, scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 3, 4, 5, 6))
+def _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
+    out, _ = _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q,
+                        block_k)
     return out
 
 
-def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
-                    block_k=None, k_bias=None):
-    """Fused attention. q/k/v: (batch, heads, seq, head_dim).
+def flash_attention_btd(qkv, n_heads, causal=True, scale=None, block_q=None,
+                        block_k=None, k_bias=None):
+    """Fused attention in the projections' own layout: the kernels' entry.
+
+    ``qkv``: one (batch, seq, 3 * heads * head_dim) array, [q | k | v] along
+    the columns as a fused projection writes it (the kernels read the three
+    out of it in place), or a tuple of three (batch, seq, heads * head_dim)
+    arrays where q and k are touched in between. -> (batch, seq, heads *
+    head_dim), what the output projection reads; the gradient comes back in
+    the form ``qkv`` had.
 
     The matmuls run in the dtype of q/k/v with f32 accumulation; the softmax
     is f32 whatever that dtype. ``block_q``/``block_k`` left at None are
@@ -487,31 +594,48 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
     ``k_bias``: optional (batch, seq) float added to every score column —
     the key-padding mask form (0 valid / -1e9 padded). Non-trainable: its
     cotangent is zero."""
-    return _flash(q, k, v, k_bias, causal, scale, block_q, block_k)
+    if not _is_fused(qkv):
+        qkv = tuple(qkv)
+    return _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k)
 
 
-def _scale(q, scale):
-    return scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
+                    block_k=None, k_bias=None):
+    """`flash_attention_btd` for q/k/v of (batch, heads, seq, head_dim):
+    transposed in XLA on the way in and out, so a caller that holds that
+    layout pays four passes a call that the trunk does not."""
+    b, h, s, d = q.shape
+    out = flash_attention_btd(
+        tuple(x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+              for x in (q, k, v)),
+        h, causal, scale, block_q, block_k, k_bias)
+    return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd(q, k, v, k_bias, causal, scale, block_q, block_k):
-    out, lse = _fwd_pallas(q, k, v, k_bias, _scale(q, scale), causal,
-                           block_q, block_k, interpret=not _on_tpu())
-    return out, (q, k, v, out, lse, k_bias)
+def _scale(qkv, n_heads, scale):
+    if scale is not None:
+        return scale
+    return 1.0 / math.sqrt(_width(qkv) // n_heads)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, res, do):
-    q, k_bias = res[0], res[5]
+def _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
+    out, lse = _fwd_pallas(qkv, n_heads, k_bias, _scale(qkv, n_heads, scale),
+                           causal, block_q, block_k, interpret=not _on_tpu())
+    return out, (qkv, out, lse, k_bias)
+
+
+def _flash_bwd(n_heads, causal, scale, block_q, block_k, res, do):
+    qkv, k_bias = res[0], res[3]
+    kw = dict(n_heads=n_heads, scale=_scale(qkv, n_heads, scale),
+              causal=causal)
     if _on_tpu():
-        grads = _bwd_pallas(res, do, scale=_scale(q, scale), causal=causal,
-                            block_q=block_q, block_k=block_k,
-                            interpret=False)
+        grads = _bwd_pallas(res, do, block_q=block_q, block_k=block_k,
+                            interpret=False, **kw)
     else:
-        block_k = _tiles_for(q, k_bias, causal, block_q, block_k)[1]
-        grads = _bwd_blockwise(res, do, scale=_scale(q, scale),
-                               causal=causal, block_k=block_k)
+        block_k = _tiles_for(qkv, n_heads, causal, block_q, block_k)[2]
+        grads = _bwd_blockwise(res, do, block_k=block_k, **kw)
     dbias = None if k_bias is None else jnp.zeros_like(k_bias)
-    return grads + (dbias,)
+    return grads, dbias
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -69,14 +69,14 @@ def _decode_layer(carry, layer_inputs, *, cfg, pos):
     if cfg.attn_proj_bias:
         qkv = qkv + p["bqkv"].astype(h.dtype)
     q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-    q = q.reshape(B, C, nh, hd).transpose(0, 2, 1, 3)   # (B, nh, C, hd)
-    k = k.reshape(B, C, nkv, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(B, C, nkv, hd).transpose(0, 2, 1, 3)
     if cfg.rope:
         # rotate at the chunk's absolute positions; the cache stores
         # ROTATED keys (scores are position-relative after rotation)
-        q = tfm._rope(q, pos, cfg.rope_theta)
-        k = tfm._rope(k, pos, cfg.rope_theta)
+        q = tfm._rope(q, pos, cfg.rope_theta, hd)
+        k = tfm._rope(k, pos, cfg.rope_theta, hd)
+    q = q.reshape(B, C, nh, hd).transpose(0, 2, 1, 3)   # (B, nh, C, hd)
+    k = k.reshape(B, C, nkv, hd).transpose(0, 2, 1, 3)
+    v = v.reshape(B, C, nkv, hd).transpose(0, 2, 1, 3)
     # gqa: the cache stores the nkv UNBROADCAST heads — the memory saving
     # is the point of a GQA checkpoint at serving time — and the scores
     # ride a grouped einsum (g query heads share each kv head); g=1

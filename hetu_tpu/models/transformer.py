@@ -303,20 +303,27 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
     return _layer_norm(x, scale, bias, cfg.ln_eps)
 
 
-def _rope(x, pos0, theta):
-    """Rotary position embeddings, HF rotate_half convention: x (B, nh, T,
-    hd) at absolute positions pos0..pos0+T-1; the head dim splits into two
-    halves rotated by position-dependent angles."""
-    B, nh, T, hd = x.shape
+def _rope(x, pos0, theta, hd):
+    """Rotary position embeddings, HF rotate_half convention: x (B, T,
+    heads*hd), the heads side by side as the projection writes them, at
+    absolute positions pos0..pos0+T-1; each head's hd columns split into
+    two halves rotated by position-dependent angles. A column's partner
+    lies hd/2 columns to its right (first half) or left (second half), in
+    the same head, so the halves swap by two rolls of the whole axis and no
+    (B, T, heads, hd) array, which the TPU would lay out anew, is made."""
+    B, T, W = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     t = pos0 + jnp.arange(T, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)                       # (T, hd/2)
-    cos = jnp.concatenate([jnp.cos(freqs)] * 2, -1)  # (T, hd)
-    sin = jnp.concatenate([jnp.sin(freqs)] * 2, -1)
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    cos = jnp.tile(jnp.concatenate([cos, cos], -1), W // hd)    # (T, W)
+    # rotate_half is [-x2, x1]: the sign rides on the sine
+    sin = jnp.tile(jnp.concatenate([-sin, sin], -1), W // hd)
     x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :hd // 2], x32[..., hd // 2:]
-    rotated = jnp.concatenate([-x2, x1], -1)
-    return (x32 * cos + rotated * sin).astype(x.dtype)
+    first = jnp.arange(W) % hd < hd // 2
+    partner = jnp.where(first, jnp.roll(x32, -(hd // 2), -1),
+                        jnp.roll(x32, hd // 2, -1))
+    return (x32 * cos + partner * sin).astype(x.dtype)
 
 
 def _is_key_padding_bias(attn_bias):
@@ -359,60 +366,75 @@ def _resolve_attn_impl(cfg: TransformerConfig, mesh, T, attn_bias=None):
     return "dot"
 
 
+def _key_bias(attn_bias, B):
+    """(B, 1, 1, T) key-padding bias -> the (B, T) per-key form the fused
+    paths share; a broadcast-batch (1, 1, 1, T) mask expands to the real
+    batch so dp/sp sharding of the bias is always well-formed."""
+    if attn_bias is None:
+        return None
+    kb = attn_bias.reshape(attn_bias.shape[0], attn_bias.shape[-1])
+    if kb.shape[0] == 1 and B > 1:
+        kb = jnp.broadcast_to(kb, (B, kb.shape[1]))
+    return kb
+
+
+def _flash(qkv, cfg: TransformerConfig, mesh, kb):
+    """The fused Pallas kernels on the projection's own layout: ``qkv`` is
+    the (B, T, 3*D) projection as it stands, or three (B, T, D) arrays;
+    -> (B, T, D), what ``wo`` reads. Nothing is transposed either way."""
+    from ..kernels.flash_attention import flash_attention_btd
+    if mesh is None or mesh.size == 1:
+        return flash_attention_btd(qkv, cfg.n_heads, cfg.causal, k_bias=kb)
+    # A Mosaic kernel has no partitioning rule and only lowers in a fully
+    # manual context, so under a mesh it runs per shard: attention is
+    # independent per (batch, head), which is how the arrays are laid out
+    # here (batch over dp, head columns over tp, full sequence).
+    spec = P("dp", None, "tp")
+    heads = cfg.n_heads // mesh.shape["tp"]
+
+    def per_shard(qkv, *kb):
+        return flash_attention_btd(qkv, heads, cfg.causal,
+                                   k_bias=kb[0] if kb else None)
+
+    bias = () if kb is None else (kb,)
+    return jax.shard_map(
+        per_shard, mesh=mesh, check_vma=False,
+        in_specs=(spec,) + (P("dp", None),) * len(bias),
+        out_specs=spec)(qkv, *bias)
+
+
 def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
                     attn_bias=None):
-    """q/k/v: (B, nh, T, hd) -> (B, nh, T, hd). Three paths:
+    """q/k/v: (B, T, D), every head's columns side by side -> (B, T, D).
+    Three paths:
     - ring: sequence-parallel exact attention over the sp axis (shard_map +
       ppermute ring, hetu_tpu/parallel/ring_attention.py)
     - flash: fused Pallas online-softmax kernel (hetu_tpu/kernels); folds a
       key-padding ``attn_bias`` (B, 1, 1, T) into its score blocks
     - dot: unfused reference form (the reference framework's
       BatchMatMul+Softmax attention); applies any additive ``attn_bias``"""
-    hd = q.shape[-1]
-    # (B, 1, 1, T) key-padding bias -> (B, T) per-key form shared by the
-    # fused paths; a broadcast-batch (1, 1, 1, T) mask expands to the real
-    # batch so dp/sp sharding of the bias is always well-formed
-    kb = None
-    if attn_bias is not None:
-        kb = attn_bias.reshape(attn_bias.shape[0], attn_bias.shape[-1])
-        if kb.shape[0] == 1 and q.shape[0] > 1:
-            kb = jnp.broadcast_to(kb, (q.shape[0], kb.shape[1]))
+    B, T, D = q.shape
+    nh, hd = cfg.n_heads, cfg.head_dim
+    kb = _key_bias(attn_bias, B)
+    if impl == "flash":
+        return _flash((q, k, v), cfg, mesh, kb)
+    q, k, v = (x.reshape(B, T, nh, hd) for x in (q, k, v))
     if impl == "ring":
         from ..parallel.ring_attention import ring_attention
+        # the ring works on (B, nh, T, hd) chunks: transposed here, locally
         spec = P("dp", "tp", "sp", None)
         fn_part = functools.partial(ring_attention, axis_name="sp",
                                     causal=cfg.causal)
-        if kb is not None:
-            # the bias shards like k's sequence axis; each column rotates
-            # around the ring with its k/v chunk
-            fn = jax.shard_map(fn_part, mesh=mesh,
-                               in_specs=(spec, spec, spec, P("dp", "sp")),
-                               out_specs=spec)
-            return fn(q, k, v, kb)
-        fn = jax.shard_map(fn_part, mesh=mesh, in_specs=(spec,) * 3,
-                           out_specs=spec)
-        return fn(q, k, v)
-    if impl == "flash":
-        from ..kernels.flash_attention import flash_attention
-        if mesh is None or mesh.size == 1:
-            return flash_attention(q, k, v, cfg.causal, k_bias=kb)
-        # A Mosaic kernel has no partitioning rule and only lowers in a
-        # fully manual context, so under a mesh it runs per shard:
-        # attention is independent per (batch, head), which is how q/k/v
-        # are laid out here (batch over dp, heads over tp, full sequence).
-        spec = P("dp", "tp", None, None)
-
-        def per_shard(q, k, v, *kb):
-            return flash_attention(q, k, v, cfg.causal,
-                                   k_bias=kb[0] if kb else None)
-
+        # the bias shards like k's sequence axis; each column rotates
+        # around the ring with its k/v chunk
         bias = () if kb is None else (kb,)
-        return jax.shard_map(
-            per_shard, mesh=mesh, check_vma=False,
-            in_specs=(spec,) * 3 + (P("dp", None),) * len(bias),
-            out_specs=spec)(q, k, v, *bias)
-    T = q.shape[2]
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+        fn = jax.shard_map(
+            fn_part, mesh=mesh,
+            in_specs=(spec,) * 3 + (P("dp", "sp"),) * len(bias),
+            out_specs=spec)
+        out = fn(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)), *bias)
+        return out.transpose(0, 2, 1, 3).reshape(B, T, D)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) / np.sqrt(hd)
     if cfg.causal:
         qpos = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
@@ -421,8 +443,9 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
     if attn_bias is not None:
         scores = scores + attn_bias.astype(jnp.float32)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    return out.reshape(B, T, D)
 
 
 def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
@@ -434,33 +457,34 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
                      preferred_element_type=jnp.float32).astype(h.dtype)
     if cfg.attn_proj_bias:
         qkv = qkv + p["bqkv"].astype(h.dtype)
-    q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-    if cfg.qk_norm:
-        # the statistic runs over every head of the projection at once
-        q = _rms_norm(q, p["q_norm"], cfg.ln_eps)
-        k = _rms_norm(k, p["k_norm"], cfg.ln_eps)
-    q = q.reshape(B, T, nh, hd).transpose(0, 2, 1, 3)
-    if impl == "ring":
-        # k/v stay sequence-sharded: the ring rotates chunks over ICI
-        k = k.reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
+    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+    if impl == "flash" and nkv == nh and tp == 1 and not (
+            cfg.qk_norm or cfg.rope):
+        # nothing touches q or k on the way and the [q|k|v] columns lie on
+        # one shard: the kernels read the projection where it stands
+        out = _flash(qkv, cfg, mesh, _key_bias(attn_bias, B))
     else:
-        # Ulysses-style: gather k/v over sp, heads stay tp-sharded
-        k = _constrain(k, mesh, "dp", None, "tp").reshape(
-            B, T, nkv, hd).transpose(0, 2, 1, 3)
-        v = _constrain(v, mesh, "dp", None, "tp").reshape(
-            B, T, nkv, hd).transpose(0, 2, 1, 3)
-    if cfg.rope:
-        # rotate BEFORE any gqa broadcast (rope is per-kv-head)
-        q = _rope(q, 0, cfg.rope_theta)
-        k = _rope(k, 0, cfg.rope_theta)
-    if nkv != nh:
-        # grouped-query: broadcast each kv head to its query group; every
-        # attention impl then sees matching head counts
-        k = jnp.repeat(k, nh // nkv, axis=1)
-        v = jnp.repeat(v, nh // nkv, axis=1)
-    out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias)
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
+        # cut along the columns; a head stays hd columns of its array
+        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+        if cfg.qk_norm:
+            # the statistic runs over every head of the projection at once
+            q = _rms_norm(q, p["q_norm"], cfg.ln_eps)
+            k = _rms_norm(k, p["k_norm"], cfg.ln_eps)
+        if impl != "ring":
+            # Ulysses-style: gather k/v over sp, heads stay tp-sharded
+            # (the ring keeps them sequence-sharded and rotates chunks)
+            k = _constrain(k, mesh, "dp", None, "tp")
+            v = _constrain(v, mesh, "dp", None, "tp")
+        if cfg.rope:
+            # rotate BEFORE any gqa broadcast (rope is per-kv-head)
+            q = _rope(q, 0, cfg.rope_theta, hd)
+            k = _rope(k, 0, cfg.rope_theta, hd)
+        if nkv != nh:
+            # grouped-query: broadcast each kv head to its query group;
+            # every attention impl then sees matching head counts
+            k, v = (jnp.repeat(x.reshape(B, T, nkv, hd), nh // nkv,
+                               axis=2).reshape(B, T, D) for x in (k, v))
+        out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias)
     out = jnp.einsum("btd,de->bte", out, p["wo"].astype(h.dtype),
                      preferred_element_type=jnp.float32).astype(h.dtype)
     if cfg.attn_proj_bias:

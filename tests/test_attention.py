@@ -19,6 +19,18 @@ def _rand_qkv(rng, b=2, h=2, s=256, d=64):
     return q, k, v
 
 
+def _rows(x):
+    """(b, h, s, d) -> (b, s, h*d): the layout the kernels index."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _heads(x, h):
+    """(b, s, h*d) -> (b, h, s, d): the layout `mha_reference` takes."""
+    b, s, w = x.shape
+    return x.reshape(b, s, h, w // h).transpose(0, 2, 1, 3)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_forward_matches_reference(causal):
     q, k, v = _rand_qkv(np.random.RandomState(0))
@@ -53,17 +65,20 @@ def test_pallas_backward_kernels_match_blockwise(causal, block_q, block_k):
     from hetu_tpu.kernels import flash_attention as fa
 
     q, k, v = _rand_qkv(np.random.RandomState(2), s=128)
+    h = q.shape[1]
     scale = 1.0 / np.sqrt(q.shape[-1])
-    out, lse = fa._fwd_pallas(q, k, v, None, scale, causal, block_q, block_k,
+    qkv = tuple(_rows(x) for x in (q, k, v))
+    out, lse = fa._fwd_pallas(qkv, h, None, scale, causal, block_q, block_k,
                               interpret=True)
     rng = np.random.RandomState(3)
-    do = jnp.asarray(rng.randn(*out.shape), jnp.float32)
-    res = (q, k, v, out, lse, None)
+    do = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    res = (qkv, out, lse, None)
 
-    dq_p, dk_p, dv_p = fa._bwd_pallas(res, do, scale=scale, causal=causal,
-                                      block_q=block_q, block_k=block_k,
-                                      interpret=True)
-    dq_b, dk_b, dv_b = fa._bwd_blockwise(res, do, scale=scale, causal=causal,
+    dq_p, dk_p, dv_p = fa._bwd_pallas(res, _rows(do), n_heads=h, scale=scale,
+                                      causal=causal, block_q=block_q,
+                                      block_k=block_k, interpret=True)
+    dq_b, dk_b, dv_b = fa._bwd_blockwise(res, _rows(do), n_heads=h,
+                                         scale=scale, causal=causal,
                                          block_k=block_k)
     for a, b in zip((dq_p, dk_p, dv_p), (dq_b, dk_b, dv_b)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -74,7 +89,7 @@ def test_pallas_backward_kernels_match_blockwise(causal, block_q, block_k):
 
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip((dq_p, dk_p, dv_p), gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+        np.testing.assert_allclose(np.asarray(_heads(a, h)), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
 
 
@@ -137,21 +152,24 @@ def test_pallas_backward_kernels_with_bias(causal):
 
     rng = np.random.RandomState(7)
     q, k, v = _rand_qkv(rng, s=128)
+    h = q.shape[1]
     k_bias = _padding_bias(rng, q.shape[0], q.shape[2])
     scale = 1.0 / np.sqrt(q.shape[-1])
-    out, lse = fa._fwd_pallas(q, k, v, k_bias, scale, causal, 64, 64,
+    qkv = tuple(_rows(x) for x in (q, k, v))
+    out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, 64, 64,
                               interpret=True)
-    do = jnp.asarray(rng.randn(*out.shape), jnp.float32)
-    res = (q, k, v, out, lse, k_bias)
-    dq_p, dk_p, dv_p = fa._bwd_pallas(res, do, scale=scale, causal=causal,
-                                      block_q=64, block_k=64, interpret=True)
+    do = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    res = (qkv, out, lse, k_bias)
+    grads = fa._bwd_pallas(res, _rows(do), n_heads=h, scale=scale,
+                           causal=causal, block_q=64, block_k=64,
+                           interpret=True)
 
     def loss_ref(q, k, v):
         return jnp.vdot(mha_reference(q, k, v, causal, k_bias=k_bias), do)
 
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip((dq_p, dk_p, dv_p), gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+    for a, b in zip(grads, gr):
+        np.testing.assert_allclose(np.asarray(_heads(a, h)), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
 
 
@@ -308,9 +326,10 @@ def test_flash_bf16():
 
 def _chosen_case(s, d, causal, bias, dtype, b=2, h=3):
     """Seeded (q, k, v, dO, k_bias) in `dtype`, and the same values in f32
-    for the oracle (so the inputs' own rounding is not counted). b*h = 6 and
-    h = 3 are multiples of no power-of-two head group; with a bias the
-    second batch row is padded entirely."""
+    for the oracle (so the inputs' own rounding is not counted). h = 3 is a
+    multiple of no power-of-two head group (three heads of 64 go as one
+    block of 192 lanes); with a bias the second batch row is padded
+    entirely."""
     rng = np.random.RandomState(s + d + causal + 2 * bias)
     q, k, v = _rand_qkv(rng, b=b, h=h, s=s, d=d)
     do = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
@@ -321,45 +340,114 @@ def _chosen_case(s, d, causal, bias, dtype, b=2, h=3):
     return given, tuple(x.astype(jnp.float32) for x in given), k_bias
 
 
-@pytest.mark.parametrize("dtype,tol_fwd,tol_bwd", [
+def _check_chosen_blocks(case, causal, dtype, tol_fwd, tol_bwd, fused):
+    """Forward and dq, dk, dv of the three kernels (interpret mode) at the
+    blocks and head group `_choose_tiles` picks, against the unfused
+    reference in f32. The kernels get (b, s, h*d) arrays: three, or with
+    `fused` the one [q|k|v] array a fused projection writes."""
+    from hetu_tpu.kernels import flash_attention as fa
+
+    (q, k, v, do), (qf, kf, vf, dof), k_bias = case
+    h, d = q.shape[1], q.shape[3]
+    scale = 1.0 / np.sqrt(d)
+    qkv = tuple(_rows(x) for x in (q, k, v))
+    if fused:
+        qkv = jnp.concatenate(qkv, axis=-1)
+    out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, None, None,
+                              interpret=True)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    ref, vjp = jax.vjp(
+        lambda q, k, v: mha_reference(q, k, v, causal, k_bias=k_bias),
+        qf, kf, vf)
+    np.testing.assert_allclose(np.asarray(_heads(out, h), np.float32),
+                               np.asarray(ref), rtol=tol_fwd, atol=tol_fwd)
+    grads = fa._bwd_pallas((qkv, out, lse, k_bias), _rows(do), n_heads=h,
+                           scale=scale, causal=causal, block_q=None,
+                           block_k=None, interpret=True)
+    if fused:
+        assert grads.shape == qkv.shape
+        grads = jnp.split(grads, 3, axis=-1)
+    # A row with every key padded: its forward is the reference's uniform
+    # softmax (checked above), its backward never was: lse = -1e9 + log(l)
+    # rounds to -1e9 in f32, so the rebuilt p is 1 and not 1/l. Such a
+    # row's dO is zero in a real loss; here its gradients must be finite.
+    rows = slice(0, 1) if k_bias is not None else slice(None)
+    for got, want in zip(grads, vjp(dof)):
+        assert got.dtype == dtype
+        got = _heads(got, h)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        np.testing.assert_allclose(np.asarray(got[rows], np.float32),
+                                   np.asarray(want[rows]), rtol=tol_bwd,
+                                   atol=tol_bwd)
+
+
+_DTYPES = pytest.mark.parametrize("dtype,tol_fwd,tol_bwd", [
     (jnp.float32, 2e-5, 2e-4), (jnp.bfloat16, 2e-2, 2e-2)],
     ids=["f32", "bf16"])
+
+
+@_DTYPES
 @pytest.mark.parametrize("causal,bias", [(False, True), (True, False),
                                          (True, True), (False, False)])
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("s", [128, 256, 512, 1024])
 def test_flash_chosen_blocks_match_reference(s, d, causal, bias, dtype,
                                              tol_fwd, tol_bwd):
-    """Forward and dq, dk, dv of the three kernels (interpret mode) at the
-    blocks and head group `_choose_tiles` picks, against the unfused
-    reference in f32."""
+    _check_chosen_blocks(_chosen_case(s, d, causal, bias, dtype), causal,
+                         dtype, tol_fwd, tol_bwd, fused=False)
+
+
+# heads, head_dim, seq -> heads a grid step (and so column blocks a row)
+@_DTYPES
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "three"])
+@pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
+@pytest.mark.parametrize("h,d,s,group", [
+    (2, 64, 128, {2}), (4, 64, 256, {4}), (12, 64, 128, {12}),
+    (12, 64, 512, {4, 6}), (4, 128, 512, {2, 4}), (3, 64, 128, {3})],
+    ids=lambda x: str(x).replace(", ", "or").strip("{}"))
+def test_flash_btd_layout_matches_reference(h, d, s, group, causal, bias,
+                                            fused, dtype, tol_fwd, tol_bwd):
+    """The (batch, seq, heads*head_dim) contract: 2, 4 and 12 heads of 64 a
+    grid step, head size 128, more than one column block a row (12 heads in
+    three blocks or in two, by dtype and mask; 4 heads of 128 in two or in
+    one), q, k and v read out of one fused array or out of three. Three
+    heads of 64 are 192 lanes, not whole tiles: given fused, they are cut
+    in three first."""
     from hetu_tpu.kernels import flash_attention as fa
 
-    (q, k, v, do), (qf, kf, vf, dof), k_bias = _chosen_case(
-        s, d, causal, bias, dtype)
-    scale = 1.0 / np.sqrt(d)
-    out, lse = fa._fwd_pallas(q, k, v, k_bias, scale, causal, None, None,
-                              interpret=True)
-    assert out.dtype == dtype and lse.dtype == jnp.float32
-    ref, vjp = jax.vjp(
-        lambda q, k, v: mha_reference(q, k, v, causal, k_bias=k_bias),
-        qf, kf, vf)
-    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
-                               rtol=tol_fwd, atol=tol_fwd)
-    grads = fa._bwd_pallas((q, k, v, out, lse, k_bias), do, scale=scale,
-                           causal=causal, block_q=None, block_k=None,
-                           interpret=True)
-    # A row with every key padded: its forward is the reference's uniform
-    # softmax (checked above), its backward never was: lse = -1e9 + log(l)
-    # rounds to -1e9 in f32, so the rebuilt p is 1 and not 1/l. Such a
-    # row's dO is zero in a real loss; here its gradients must be finite.
-    rows = slice(0, 1) if bias else slice(None)
-    for got, want in zip(grads, vjp(dof)):
-        assert got.dtype == dtype
-        assert np.isfinite(np.asarray(got, np.float32)).all()
-        np.testing.assert_allclose(np.asarray(got[rows], np.float32),
-                                   np.asarray(want[rows]), rtol=tol_bwd,
-                                   atol=tol_bwd)
+    assert fa._choose_tiles(s, d, dtype, causal, h)[2] in group
+    case = _chosen_case(s, d, causal, bias, dtype, b=2 if s < 512 else 1,
+                        h=h)
+    if bias and s >= 512:       # one batch row: pad its tail, not all of it
+        case = case[:2] + (_padding_bias(np.random.RandomState(s), 1, s),)
+    _check_chosen_blocks(case, causal, dtype, tol_fwd, tol_bwd, fused)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "three"])
+def test_flash_btd_entry_gradients(fused):
+    """`flash_attention_btd` under jax.grad: the gradient comes back in the
+    form qkv went in, one array or three."""
+    from hetu_tpu.kernels.flash_attention import flash_attention_btd
+
+    rng = np.random.RandomState(11)
+    q, k, v = _rand_qkv(rng, b=2, h=4, s=128, d=64)
+    k_bias = _padding_bias(rng, 2, 128)
+    qkv = tuple(_rows(x) for x in (q, k, v))
+    if fused:
+        qkv = jnp.concatenate(qkv, axis=-1)
+
+    def loss(qkv):
+        return jnp.sum(flash_attention_btd(qkv, 4, False, k_bias=k_bias) ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(mha_reference(q, k, v, False, k_bias=k_bias) ** 2)
+
+    got = jax.grad(loss)(qkv)
+    got = jnp.split(got, 3, axis=-1) if fused else got
+    want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(_heads(a, 4)), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -380,16 +468,31 @@ def test_choose_tiles(s, d, causal, dtype):
         block_q, block_k, group = picked
         assert s % block_q == 0 and s % block_k == 0
         assert heads % group == 0 and 1 <= group <= fa._MAX_HEADS
+        # the lane rule: a step's columns are whole 128-lane tiles, or
+        # every head of the array
+        assert (group * d) % 128 == 0 or group == heads
         assert (block_q, block_k) == (s, s) if s < 128 else (
             block_q % 128 == 0 and block_k % 128 == 0)
         # the floor (what every call had before) is taken where nothing
         # fits the count: whole f32 k and v at s = 4096, d = 128
-        assert picked == (128, 128, 1) or fa._vmem_bytes(
+        floor = (128, 128, fa._head_groups(heads, d)[0])
+        assert picked == floor or fa._vmem_bytes(
             s, d, jnp.dtype(dtype).itemsize, block_q, block_k,
             group) <= fa._VMEM_BUDGET
     # blocks the caller passes are kept, and still get a head group
     assert fa._choose_tiles(s, d, dtype, causal, 12, 64, 32)[:2] == (
         min(64, s), min(32, s))
+
+
+@pytest.mark.parametrize("heads,d,groups", [
+    (12, 64, [2, 4, 6, 12]), (3, 64, [3]), (1, 64, [1]), (4, 32, [4]),
+    (16, 128, [1, 2, 4, 8, 16]), (96, 64, [2, 4, 6, 8, 12, 16]),
+    (20, 64, [2, 4, 10]), (17, 64, [17])])
+def test_head_groups_lane_rule(heads, d, groups):
+    """Heads a grid step may take, at most `_MAX_HEADS`: `g * d` whole lane
+    tiles, or every head; every head where that leaves none."""
+    from hetu_tpu.kernels import flash_attention as fa
+    assert fa._head_groups(heads, d) == groups
 
 
 def _dot_generals(jaxpr):
@@ -412,21 +515,32 @@ def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias):
     from hetu_tpu.kernels import flash_attention as fa
 
     (q, k, v, do), _, k_bias = _chosen_case(256, 64, causal, bias, dtype)
-    heads = fa._choose_tiles(256, 64, dtype, causal,
-                             3 if bias else 6)[2]
+    heads = fa._choose_tiles(256, 64, dtype, causal, 3)[2]
 
     def both(q, k, v, do):
-        out, lse = fa._fwd_pallas(q, k, v, k_bias, 0.125, causal, None, None,
+        qkv = (q, k, v)
+        out, lse = fa._fwd_pallas(qkv, 3, k_bias, 0.125, causal, None, None,
                                   interpret=False)
-        return fa._bwd_pallas((q, k, v, out, lse, k_bias), do, scale=0.125,
-                              causal=causal, block_q=None, block_k=None,
-                              interpret=False)
+        return fa._bwd_pallas((qkv, out, lse, k_bias), do, n_heads=3,
+                              scale=0.125, causal=causal, block_q=None,
+                              block_k=None, interpret=False)
 
-    calls = [e for e in jax.make_jaxpr(both)(q, k, v, do).jaxpr.eqns
-             if e.primitive.name == "pallas_call"]
+    calls = [e for e in jax.make_jaxpr(both)(
+        *(_rows(x) for x in (q, k, v, do))).jaxpr.eqns
+        if e.primitive.name == "pallas_call"]
     assert len(calls) == 3
     for call, n_dots in zip(calls, (2, 3, 4)):
         dots = list(_dot_generals(call.params["jaxpr"]))
+        # not of the rule: delta's sums over each head's columns, once a
+        # step of `flash_bwd_dq`: dO * O in two bf16 pieces (three from an
+        # f32 caller) against a 0/1 matrix, exact whatever the dtype
+        sums = [e for e in dots
+                if e.invars[1].aval.shape == (3 * 64, 128)]
+        assert len(sums) == (n_dots == 3) * (3 if dtype == jnp.float32
+                                             else 2)
+        for eqn in sums:
+            assert [x.aval.dtype for x in eqn.invars] == [jnp.bfloat16] * 2
+        dots = [e for e in dots if e not in sums]
         assert len(dots) == n_dots * heads
         for eqn in dots:
             assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]

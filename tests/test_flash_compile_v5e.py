@@ -8,7 +8,10 @@ The topology is described inside a module-scoped fixture, never at import:
 one process at a time may load libtpu, and under xdist every worker imports
 this file. All such compiles stay in this one file and in the test's own
 process."""
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -43,44 +46,186 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-# (batch, heads, seq, head_dim), dtype, causal, key bias
+# (batch, heads, seq, head_dim), dtype, causal, key bias, one fused array
 SHAPES = [
-    pytest.param((128, 12, 512, 64), jnp.bfloat16, False, True,
+    pytest.param((128, 12, 512, 64), jnp.bfloat16, False, True, True,
                  id="bert-seq512"),
-    pytest.param((512, 12, 128, 64), jnp.bfloat16, False, True,
+    pytest.param((512, 12, 128, 64), jnp.bfloat16, False, True, True,
                  id="bert-seq128"),
-    pytest.param((8, 16, 2048, 128), jnp.bfloat16, True, False,
+    pytest.param((8, 16, 2048, 128), jnp.bfloat16, True, False, False,
                  id="causal-2048-d128"),
-    pytest.param((2, 4, 1024, 128), jnp.float32, False, True,
+    pytest.param((2, 4, 1024, 128), jnp.float32, False, True, False,
                  id="f32-1024-d128"),
+    pytest.param((8, 16, 4096, 128), jnp.bfloat16, True, False, False,
+                 id="olmoe-seq4096"),
+    pytest.param((4, 3, 512, 64), jnp.bfloat16, False, True, False,
+                 id="three-heads-192-lanes"),
 ]
 
 
-@pytest.mark.parametrize("shape,dtype,causal,bias", SHAPES)
+def _kernel_calls(text):
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _count_by_name(calls):
+    return {name: sum(name in c.split("=")[0] for c in calls)
+            for name in (fa.FLASH_FWD, fa.FLASH_BWD_DQ, fa.FLASH_BWD_DKV)}
+
+
+@pytest.mark.parametrize("shape,dtype,causal,bias,fused", SHAPES)
 def test_flash_compiles_for_v5e(one_chip, no_compile_cache, shape, dtype,
-                                causal, bias):
-    """Forward and gradient at the blocks the chooser picks: three Mosaic
-    custom calls under the kernels' names in the compiled program."""
-    b, _, s, d = shape
-    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                                causal, bias, fused):
+    """Forward and gradient at the blocks the chooser picks, on the
+    (batch, seq, heads * head_dim) arrays the trunk hands over (one fused
+    [q|k|v] array, or three): three Mosaic custom calls under the kernels'
+    names in the compiled program."""
+    b, h, s, d = shape
+
+    def arr(columns):
+        return jax.ShapeDtypeStruct((b, s, columns), dtype,
+                                    sharding=one_chip)
+
+    qkv = arr(3 * h * d) if fused else (arr(h * d),) * 3
     kb = jax.ShapeDtypeStruct((b, s), jnp.float32, sharding=one_chip)
     scale = 1.0 / d ** 0.5
 
-    # `flash_attention` asks jax.default_backend() which backward to take
-    # and sees the CPU here: compile what it runs on a TPU, kernel by kernel
-    def fwd_and_grads(q, k, v, k_bias, do):
+    # `flash_attention_btd` asks jax.default_backend() which backward to
+    # take and sees the CPU here: compile what it runs on a TPU, kernel by
+    # kernel
+    def fwd_and_grads(qkv, k_bias, do):
         k_bias = k_bias if bias else None
-        out, lse = fa._fwd_pallas(q, k, v, k_bias, scale, causal, None,
-                                  None, interpret=False)
+        out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, None, None,
+                                  interpret=False)
         return out, fa._bwd_pallas(
-            (q, k, v, out, lse, k_bias), do, scale=scale, causal=causal,
-            block_q=None, block_k=None, interpret=False)
+            (qkv, out, lse, k_bias), do, n_heads=h, scale=scale,
+            causal=causal, block_q=None, block_k=None, interpret=False)
 
-    text = jax.jit(fwd_and_grads).lower(qkv, qkv, qkv, kb, qkv) \
+    text = jax.jit(fwd_and_grads).lower(qkv, kb, arr(h * d)) \
         .compile().as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls = _kernel_calls(text)
     assert len(calls) == 3, text
-    for name in (fa.FLASH_FWD, fa.FLASH_BWD_DQ, fa.FLASH_BWD_DKV):
-        assert sum(f"%{name}" in c.split("=")[0] for c in calls) == 1, (
-            name, [c.split("=")[0] for c in calls])
+    assert set(_count_by_name(calls).values()) == {1}, calls
+
+
+# ---------------------------------------------------------------------------
+# One layer of the trunk, forward and gradient, as the step runs it (`remat`
+# on, flash forced on): between the projections' matmuls and the kernels
+# nothing of an activation's size may be copied. Before PR 26 a BERT layer
+# held 23 such copies (q, k, v cut out of the projection and transposed to
+# (B, nh, T, hd), o and every gradient transposed back): 92 ms of a 571 ms
+# step.
+# ---------------------------------------------------------------------------
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r"(?:^|[\s)])([a-z][a-z0-9\-]*)\(([^()]*)\)")
+
+
+def _entry_graph(text):
+    """The entry computation of a compiled program's text as {name:
+    (opcode, operand names, result elements, is a matmul)}. A fusion is a
+    matmul where the computation it calls holds a convolution or a dot."""
+    matmuls, name = set(), None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+        elif re.search(r" (convolution|dot)\(", line):
+            matmuls.add(name)
+    graph = {}
+    entry = text[text.index("\nENTRY "):]
+    for line in entry.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        op = _OPCODE.search(m.group(2))
+        if not op:
+            continue
+        dims = re.match(r"\w+\[([\d,]*)\]", m.group(2))
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        graph[m.group(1)] = (
+            op.group(1), re.findall(r"%([\w.\-]+)", op.group(2)),
+            math.prod(int(x) for x in dims.group(1).split(",") if x)
+            if dims else 0,
+            op.group(1) in ("convolution", "dot")
+            or bool(calls and calls.group(1) in matmuls))
+    return graph
+
+
+def _copies_around_kernels(graph, at_least):
+    """`copy` instructions of `at_least` elements that lie between a Mosaic
+    call and the nearest matmul (or parameter, or result) on either side."""
+    users = {}
+    for name, (_, operands, _, _) in graph.items():
+        for o in operands:
+            users.setdefault(o, []).append(name)
+    found = set()
+    for step in (lambda n: graph[n][1], lambda n: users.get(n, [])):
+        seen = set()
+        todo = [n for n, v in graph.items() if v[0] == "custom-call"]
+        todo = [m for n in todo for m in step(n)]
+        while todo:
+            n = todo.pop()
+            if n in seen or n not in graph:
+                continue
+            seen.add(n)
+            opcode, _, elements, matmul = graph[n]
+            if matmul or opcode == "custom-call":
+                continue
+            if opcode == "copy" and elements >= at_least:
+                found.add(n)
+            todo += step(n)
+    return sorted(found)
+
+
+def _bert_layer():
+    from hetu_tpu.models import bert
+    return bert.BertConfig(attn_impl="flash").trunk()
+
+
+def _olmoe_layer():
+    """OLMoE-1B-7B's attention (16 heads of 128, causal, RoPE, QK-norm,
+    RMSNorm) over a dense SwiGLU: the experts are not attention's."""
+    from hetu_tpu.models import transformer as tfm
+    return tfm.TransformerConfig(
+        d_model=2048, n_heads=16, d_ff=1024, max_seq_len=4096,
+        dtype=jnp.bfloat16, causal=True, attn_impl="flash", norm="rmsnorm",
+        rope=True, mlp="swiglu", use_pos_emb=False, qk_norm=True)
+
+
+@pytest.mark.parametrize("config,batch,seq,bias", [
+    pytest.param(_bert_layer, 128, 512, True, id="bert-seq512"),
+    pytest.param(_bert_layer, 512, 128, True, id="bert-seq128"),
+    pytest.param(_olmoe_layer, 8, 4096, False, id="olmoe-seq4096"),
+])
+def test_layer_copies_nothing_around_attention(one_chip, no_compile_cache,
+                                               monkeypatch, config, batch,
+                                               seq, bias):
+    from hetu_tpu.models import transformer as tfm
+
+    # the trunk and the kernels ask the backend which path to take
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(config(), n_layers=1)
+    layer = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: tfm.init_trunk_params(
+            jax.random.PRNGKey(0), cfg))["blocks"])
+    h = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                             sharding=one_chip)
+    attn_bias = jax.ShapeDtypeStruct((batch, 1, 1, seq), jnp.float32,
+                                     sharding=one_chip)
+
+    def loss(h, layer, attn_bias):
+        block = jax.checkpoint(lambda h, layer: tfm._block(
+            h, layer, cfg, None, attn_bias if bias else None)[0])
+        return jnp.sum(block(h, layer).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        h, layer, attn_bias).compile().as_text()
+    # the forward runs twice: once as it is, once under `remat`
+    assert _count_by_name(_kernel_calls(text)) == {
+        fa.FLASH_FWD: 2, fa.FLASH_BWD_DQ: 1, fa.FLASH_BWD_DKV: 1}
+    graph = _entry_graph(text)
+    copies = _copies_around_kernels(graph, batch * seq * cfg.d_model)
+    assert not copies, [(c, graph[c]) for c in copies]

@@ -25,11 +25,11 @@ def _i32(*shape):
 
 def _flash_bwd(q, k, v, o, lse, do):
     return flash_attention._bwd_pallas(
-        (q, k, v, o, lse, None), do, scale=0.125, causal=False, block_q=128,
-        block_k=128, interpret=True)
+        ((q, k, v), o, lse, None), do, n_heads=2, scale=0.125, causal=False,
+        block_q=128, block_k=128, interpret=True)
 
 
-_QKV = (_f32(1, 2, 128, 64),) * 3
+_QKV = (_f32(1, 128, 128),) * 3     # (batch, seq, 2 heads * 64)
 _SGD = type("Opt", (), {"l2reg": 0.0})()
 _ADAM = type("Opt", (), {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8,
                          "weight_decay": 0.0})()
@@ -37,14 +37,14 @@ _ADAM = type("Opt", (), {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8,
 # kernel name -> (a program that calls it, its argument shapes)
 KERNEL_PROGRAMS = {
     flash_attention.FLASH_FWD: (
-        lambda q, k, v: flash_attention.flash_attention(q, k, v,
-                                                        causal=False), _QKV),
+        lambda q, k, v: flash_attention.flash_attention_btd(
+            (q, k, v), 2, causal=False), _QKV),
     flash_attention.FLASH_BWD_DQ: (
-        _flash_bwd, _QKV + (_f32(1, 2, 128, 64), _f32(1, 2, 128),
-                            _f32(1, 2, 128, 64))),
+        _flash_bwd, _QKV + (_f32(1, 128, 128), _f32(2, 1, 128),
+                            _f32(1, 128, 128))),
     flash_attention.FLASH_BWD_DKV: (
-        _flash_bwd, _QKV + (_f32(1, 2, 128, 64), _f32(1, 2, 128),
-                            _f32(1, 2, 128, 64))),
+        _flash_bwd, _QKV + (_f32(1, 128, 128), _f32(2, 1, 128),
+                            _f32(1, 128, 128))),
     fused_ce.FUSED_CE_FWD: (
         fused_ce.fused_linear_nll,
         (_f32(128, 128), _f32(256, 128), _f32(256), _i32(128))),
