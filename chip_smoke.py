@@ -31,10 +31,6 @@ import time
 
 import numpy as np
 
-# examples/<suite>/models under the bare name ``models``; bench.py is
-# jax-free to import
-from bench import _import_models  # noqa: E402
-
 # Mosaic kernels appear in compiled HLO as this custom-call target; an
 # interpret-mode pallas_call lowers to plain HLO and never does
 _MOSAIC = "tpu_custom_call"
@@ -169,7 +165,8 @@ def phase_executor(*, batch=128, steps=30, n_batches=8, comm_mode=None,
                    chip=True, name="executor"):
     import hetu_tpu as ht
     from hetu_tpu.kernels import registry
-    models = _import_models("cnn")
+    from hetu_tpu.utils import import_example_models
+    models = import_example_models("cnn")
 
     rec = {}
     with _phase(name, rec):
@@ -468,13 +465,14 @@ def phase_ps(*, batch=128, steps=20, feature_dim=100000, embedding_size=16,
     from hetu_tpu.chaos import check_update_accounting
     from hetu_tpu.kernels import registry
     from hetu_tpu.ps.local_cluster import local_cluster
+    from hetu_tpu.utils import import_example_models
 
     rec = {}
     with _phase("ps", rec):
         registry.reset_stats()
         with local_cluster(n_servers=n_servers, n_workers=1):
             import hetu_tpu as ht
-            models = _import_models("ctr")
+            models = import_example_models("ctr")
             from models.load_data import load_criteo_data
             (dense_x, sparse_x, y), _ = load_criteo_data(
                 feature_dimension=feature_dim, n_train=batch * 8, n_test=64)
@@ -636,7 +634,7 @@ def phase_kernels(*, shapes=None, chip=True):
                 ce_and_grads(linear_nll_reference), (hh, ww, bb),
                 atol=2e-2, rtol=2e-2)
 
-        # -- the four registry kernels (bench.py's kernels-cell tolerances)
+        # -- the four registry kernels
         n, d, vocab = shapes["embed_grad"]
         ev = jnp.asarray(rng.randn(n, d).astype(np.float32))
         ei = jnp.asarray((rng.zipf(1.3, n) % vocab).astype(np.int32))

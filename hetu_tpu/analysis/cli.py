@@ -189,8 +189,8 @@ def main(argv=None) -> int:
                          "mesh/quantization/ZeRO-1/remat from the cost "
                          "model instead of linting a declared layout")
     ap.add_argument("--devices", type=int, default=None, metavar="N",
-                    help="device budget for --plan (default 8, the bench "
-                         "virtual-mesh size; pass 1 for single-chip)")
+                    help="device budget for --plan (default 8, the test "
+                         "suite's virtual-mesh size; pass 1 for single-chip)")
     ap.add_argument("--calibrate", metavar="TEL_DIR",
                     help="with --plan: telemetry dir (or hetuprof "
                          "--roofline --json file) whose measured residuals "

@@ -195,9 +195,9 @@ class Calibration:
     source: str = ""
     # single-device uncalibrated compute prediction for the GRAPH THE
     # MEASUREMENT CAME FROM — makes the compute residual a true
-    # graph-independent ratio (the bench cell's cross-size prediction
-    # sets it). Unset, the residual is taken against the planned graph's
-    # own baseline — correct under the documented same-graph contract of
+    # graph-independent ratio (a cross-size calibration sets it). Unset,
+    # the residual is taken against the planned graph's own baseline —
+    # correct under the documented same-graph contract of
     # ``hetulint --plan --calibrate``.
     baseline_compute_ms: Optional[float] = None
 
@@ -532,7 +532,7 @@ class CostModel:
         # family residuals missed (real vs assumed peaks, fusion, runtime
         # drain) and transfers across graph sizes. The baseline is the
         # measured graph's own prediction when the calibration carries it
-        # (bench's cross-size cell); otherwise this graph's — the
+        # (a cross-size calibration); otherwise this graph's — the
         # documented same-graph --calibrate contract.
         if self.calibration and self.calibration.measured_work_ms:
             base = (self.calibration.baseline_compute_ms

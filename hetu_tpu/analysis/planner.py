@@ -30,7 +30,7 @@ layout candidates with :mod:`cost_model` and returns a :class:`Plan`:
 Surfaces: ``hetulint --plan [--devices N] [--calibrate TEL_DIR] [--json]``
 (CLI, findings are note-severity and suppressible like every pass),
 ``Plan.apply(config)`` / ``HetuConfig(plan="auto")`` (executor adoption at
-build), and the ``bench.py`` ``planner`` section (predicted vs measured).
+build).
 """
 from __future__ import annotations
 

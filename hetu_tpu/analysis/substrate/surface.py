@@ -148,7 +148,7 @@ def _code_files(root: str) -> List[str]:
                     continue
                 rel = os.path.relpath(os.path.join(dirpath, fn), root)
                 out.append(rel.replace(os.sep, "/"))
-    # top-level entry points (bench.py, conftest.py) read knobs too
+    # top-level entry points (chip_smoke.py, conftest.py) read knobs too
     for fn in sorted(os.listdir(root)):
         if fn.endswith(".py") and os.path.isfile(os.path.join(root, fn)):
             out.append(fn)

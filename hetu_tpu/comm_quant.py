@@ -241,9 +241,8 @@ def allreduce_wire_report(sizes: dict, policy: QuantPolicy,
     the baseline f32 all-reduce payload (reduce-scatter + all-gather =
     2·N·4 per step), ``wire_bytes`` the quantized decomposition's
     (f32 reduce-scatter + 1-byte all-gather + scales). Exported as the
-    ``hetu_comm_quant_raw_bytes`` / ``_wire_bytes`` gauges and reported by
-    the bench DP cell; the PS path reports *measured* counters instead
-    (worker.h)."""
+    ``hetu_comm_quant_raw_bytes`` / ``_wire_bytes`` gauges; the PS path
+    reports *measured* counters instead (worker.h)."""
     raw = wire = 0
     for n in sizes.values():
         nb = -(-n // policy.block)

@@ -55,7 +55,7 @@ class BertConfig:
     # than the einsum). True forces it (tests), False disables.
     fused_mlm_ce: Any = "auto"
     # Architecture dialect. The default is the modern pre-LN trunk (the
-    # training-throughput configuration every bench/test uses). ``hf()``
+    # training-throughput configuration the benchmark and tests use). ``hf()``
     # flips all four knobs to the canonical Devlin/HuggingFace BERT
     # architecture — post-LN blocks, embedding LayerNorm (the trunk's lnf
     # params, applied after the embedding sum instead of after the last

@@ -71,7 +71,7 @@ def tpu_devices():
     """The devices a ``tpu`` context resolves onto: the chips of the TPU
     backend. With no TPU this raises (the reference hard-fails without
     CUDA the same way) — except in a process pinned to the CPU on purpose
-    (``utils.cpu_pinned``: the test suite, bench smoke mode), where the
+    (``utils.cpu_pinned``: the test suite), where the
     virtual CPU devices stand in so the same script runs unchanged."""
     from .utils import cpu_pinned
     if cpu_pinned():

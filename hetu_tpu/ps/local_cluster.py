@@ -1,8 +1,8 @@
 """Self-provisioned local PS cluster (scheduler + N servers as spawned
 processes, the calling process becomes worker 0).
 
-One shared implementation of the bootstrap that bench.py and the examples
-need when run standalone — outside a ``heturun`` launch (reference: the
+One shared implementation of the bootstrap that ``chip_smoke.py``, the
+benchmark's ``wdl-criteo`` adapter and the examples need when run standalone — outside a ``heturun`` launch (reference: the
 ``tests/*.sh`` scripts' local mpirun clusters). The test suite's
 ``tests/test_ps.run_cluster`` stays separate: it additionally runs worker
 BODIES in subprocesses and collects per-worker results, which this helper
@@ -169,12 +169,10 @@ def local_cluster(n_servers: int = 1, n_workers: int = 1, port: int = None,
         for i in range(n_servers):
             servers_by_id[i] = spawn_light_server(i, base, stopfile)
             procs.append(servers_by_id[i])
-        # fault-injection hook (bench hang-proofing tests): SIGKILL server
-        # <idx> right after spawn, so the caller's RPCs face a cluster
-        # that can never complete registration. The section-subprocess
-        # group-kill is the only thing standing between this and a hung
-        # bench cell — tests/test_bench_driver.py pins that it holds.
-        # Gated on HETU_TEST_MODE + bounds-checked (resolve_test_kill_index).
+        # fault-injection hook: SIGKILL server <idx> right after spawn, so
+        # the caller's RPCs face a cluster that can never complete
+        # registration. Gated on HETU_TEST_MODE + bounds-checked
+        # (resolve_test_kill_index; tests/test_resilience.py).
         kill_idx = resolve_test_kill_index(n_servers)
         if kill_idx is not None:
             victim = servers_by_id[kill_idx]

@@ -528,7 +528,7 @@ def take_job_snapshot(ex, jobdir: str, *,
                     f"{st['drain_needed']} survivors parked after "
                     f"{timeout}s")
             # tight poll: the whole drain window is on the snapshot's
-            # critical path, and the bench's stall budget is single-digit
+            # critical path, and the stall budget is single-digit
             # percent — 2ms keeps the barrier sub-step-scale while still
             # yielding the GIL to the parked commit thread
             time.sleep(0.002)

@@ -63,8 +63,7 @@ _TRUTHY = ("1", "true", "yes", "on")
 
 def env_truthy(name: str) -> bool:
     """The one spelling of 'is this env knob on': explicitly truthy values
-    only, so ``FOO=false`` and ``FOO=0`` mean OFF (bench.py's jax-free
-    driver re-inlines the same tuple rather than import this package)."""
+    only, so ``FOO=false`` and ``FOO=0`` mean OFF."""
     return os.environ.get(name, "").strip().lower() in _TRUTHY
 
 

@@ -21,7 +21,7 @@ import time
 from typing import Optional
 
 from . import story as _story      # shared ledger readers (stdlib-only)
-from .profiler import attn_flops   # stdlib-only module (shared w/ bench.py)
+from .profiler import attn_flops   # stdlib-only module
 
 # metrics snapshots ride only every Nth step record (plus every "final"
 # record) — the per-step cost of percentile math is paid on a cadence

@@ -24,8 +24,7 @@ Three pillars on top of the telemetry bus:
    never read as a win or a loss.
 
 Import contract: module-level imports are **stdlib only**, and there are no
-package-relative imports — ``bench.py``'s jax-free driver parent and
-``bin/hetuprof`` load this file directly via
+package-relative imports — ``bin/hetuprof`` loads this file directly via
 ``importlib.util.spec_from_file_location`` (importing the ``hetu_tpu``
 package would pull jax). Anything that needs the graph/executor imports it
 lazily inside the function that uses it.
@@ -44,10 +43,11 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 # Published per-chip peaks, keyed by the ``device_kind`` jax reports, each
-# with its source: the one table the executor's run_info, hetutop and
-# bench.py read. A kind that is not here has no peak — its MFU and roofline
-# figures are None and run_info says ``peak: unknown``, never another
-# chip's numbers.
+# with its source: the table the executor's run_info and hetutop read
+# (benchmark/reduce/peaks.py holds the benchmark's copy, and
+# tests/test_profiler.py keeps the two equal). A kind that is not here
+# has no peak — its MFU and roofline figures are None and run_info says
+# ``peak: unknown``, never another chip's numbers.
 DEVICE_PEAKS = {
     "TPU v5 lite": {
         "tflops": 197.0, "gbs": 819.0,
@@ -91,7 +91,7 @@ def attn_flops(batch, seq, n_layers, d_model, causal):
     12*B*T^2*d*L for a bidirectional encoder. A causal decoder only
     computes the lower triangle (the flash kernel skips upper blocks), so
     half. Reporting MFU against 6ND alone OVERSTATES utilization at long
-    seq — report both denominators (bench.py and hetutop do)."""
+    seq — report both denominators (hetutop does)."""
     full = 12.0 * batch * seq * seq * d_model * n_layers
     return full / 2.0 if causal else full
 
@@ -929,8 +929,8 @@ def load_summary(path: str) -> Tuple[Dict[str, dict], dict]:
     """Normalize any of the bench artifacts into ``(cells, meta)``:
 
     - the bench final line (``{"metric", ..., "detail": {cell: {...}}}``),
-    - a driver ``BENCH_rNN.json`` wrapper (``{"rc", "parsed": <line>}``),
-    - a ``BENCH_PARTIAL.json`` ledger (``{"cells": {k: {"result": ...}}}``),
+    - a driver's wrapper around one (``{"rc", "parsed": <line>}``),
+    - a cell ledger (``{"cells": {k: {"result": ...}}}``),
     - a bare ``{cell: {...}}`` mapping,
     - or a telemetry DIRECTORY carrying a live hetuwatch residual stream
       (``kind:"watch"`` rows -> a ``plan_watch`` cell whose ``divergence``
@@ -1020,18 +1020,6 @@ def _flatten_cell(cell: dict, prefix: str = "") -> Dict[str, float]:
                 and math.isfinite(v):
             out[key] = float(v)
     return out
-
-
-def summary_has_measurement(cells: Dict[str, dict]) -> bool:
-    """Does this summary contain at least one gateable number? (bench.py's
-    baseline-selection predicate: a round of nothing but errors must not
-    become the trajectory anchor.)"""
-    for data in cells.values():
-        if isinstance(data, dict) and "error" not in data and any(
-                metric_direction(k) is not None
-                for k in _flatten_cell(data)):
-            return True
-    return False
 
 
 @dataclass

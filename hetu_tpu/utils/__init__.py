@@ -1,7 +1,9 @@
 """Shared utilities for examples, tests and the driver entry points."""
 from __future__ import annotations
 
+import importlib
 import os
+import sys
 
 import jax
 
@@ -12,10 +14,28 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def cpu_pinned() -> bool:
     """Was this process pinned to the CPU backend on purpose
     (``JAX_PLATFORMS=cpu`` / ``jax_platforms == "cpu"``, which the test
-    suite and ``HETU_BENCH_SMOKE=1`` do)? Every place that may resolve a
-    ``tpu`` request onto CPU devices asks this first: without the pin a
-    missing TPU is an error, never a quiet CPU run."""
+    suite does)? Every place that may resolve a ``tpu`` request onto CPU
+    devices asks this first: without the pin a missing TPU is an error,
+    never a quiet CPU run."""
     return (jax.config.jax_platforms or "").strip().lower() == "cpu"
+
+
+def import_example_models(suite):
+    """Import ``examples/<suite>/models`` under the bare name ``models``,
+    as the example trainers do. The cnn and ctr suites both use that name,
+    so another suite's cached package is dropped first."""
+    path = os.path.join(_CHECKOUT, "examples", suite)
+    target = os.path.join(path, "models")
+    current = sys.modules.get("models")
+    if current is not None and \
+            os.path.normpath(os.path.dirname(current.__file__)) != target:
+        for k in [k for k in sys.modules
+                  if k == "models" or k.startswith("models.")]:
+            sys.modules.pop(k)
+    if path in sys.path:
+        sys.path.remove(path)
+    sys.path.insert(0, path)
+    return importlib.import_module("models")
 
 
 def compile_cache_path() -> str:

@@ -38,28 +38,6 @@ def feed_helper(shape=None, val=None, seed=0, name="x"):
     return node, val
 
 
-def import_example_models(example):
-    """Import examples/<example>/models under the bare name ``models``,
-    purging any previously-imported zoo (cnn/ctr both use the name).
-    Shared by test_models / test_ctr_models / test_onnx."""
-    import importlib
-    import sys
-    path = os.path.normpath(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "..", "examples",
-        example))
-    target = os.path.join(path, "models")
-    current = sys.modules.get("models")
-    if current is not None and \
-            os.path.normpath(os.path.dirname(current.__file__)) != target:
-        for k in [k for k in sys.modules
-                  if k == "models" or k.startswith("models.")]:
-            sys.modules.pop(k)
-    if path in sys.path:
-        sys.path.remove(path)
-    sys.path.insert(0, path)
-    return importlib.import_module("models")
-
-
 def read_hetu_spans(trace_dir):
     """The `hetu*` host spans of the newest jax.profiler capture under
     `trace_dir`, in time order: [(name, start_ns, end_ns, args)] of the

@@ -14,7 +14,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 from test_ps import run_cluster
 
 
-from conftest import import_example_models as _import_example_models
+from hetu_tpu.utils import import_example_models as _import_example_models
 
 
 DIM = 500  # small feature dimension for synthetic runs

@@ -20,9 +20,7 @@ import numpy as np
 import pytest
 
 from hetu_tpu import elastic
-
-_PORT_BASE = int(os.environ.get("HETU_TEST_ELASTIC_PORT", "14300"))
-_port_iter = iter(range(_PORT_BASE, _PORT_BASE + 10000, 11))
+from test_ps import _port_iter
 
 
 # ---------------------------------------------------------------------------
@@ -760,11 +758,23 @@ def test_scale_up_worker_and_server_join(tmp_path):
             procs.append(p)
             p.start()
 
-        # wait for the founder to make some progress (it drains when the
-        # proposal lands — ordering is handled by the protocol, this sleep
-        # only makes the test exercise a mid-run resize rather than an
-        # immediate one)
-        time.sleep(1.0)
+        # wait until the founder trains against the ONE-server world (it
+        # drains when the proposal lands). A fixed sleep lost to a slow
+        # spawn: a founder that first reads the address book after the
+        # joining server has registered shards its tensors over two servers
+        # under world v1, and the migration then moves half of each.
+        def founder_has_pushed():
+            try:
+                addrs, _ = elastic._query_book("127.0.0.1", cl.port)
+                return bool(addrs) and \
+                    elastic.server_stats_raw(addrs[0])[0] >= 1
+            except OSError:     # scheduler or server not listening yet
+                return False
+
+        deadline = time.time() + 90
+        while not founder_has_pushed():
+            assert time.time() < deadline, "the founder never pushed"
+            time.sleep(0.05)
         report = coord.resize(2, 2, spawn_server=spawn_server,
                               spawn_worker=spawn_worker)
         assert report["migration"] is not None

@@ -260,10 +260,10 @@ def test_phase_spans_in_a_capture(tmp_path):
 
 
 def test_bf16_conv_bn_training():
-    """Regression for the round-2 bench crash: conv under jax.grad in bf16
-    compute mode (the conv transpose rule must see matching dtypes), with
-    BatchNorm running stats staying f32. Exercises exactly the config
-    bench.py runs (conv + BN + pool + matmul, Momentum)."""
+    """Regression for a round-2 crash: conv under jax.grad in bf16 compute
+    mode (the conv transpose rule must see matching dtypes), with
+    BatchNorm running stats staying f32 (conv + BN + pool + matmul,
+    Momentum: the ResNet recipe of chip_smoke.py's executor phase)."""
     import jax
     import jax.numpy as jnp
 

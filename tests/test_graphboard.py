@@ -8,8 +8,7 @@ import numpy as np
 
 import hetu_tpu as ht
 from hetu_tpu import graphboard
-
-from test_models import _import_example_models
+from hetu_tpu.utils import import_example_models as _import_example_models
 
 
 def _resnet_executor():

@@ -8,9 +8,7 @@ import numpy as np
 import pytest
 
 import hetu_tpu as ht
-
-
-from conftest import import_example_models as _import_example_models
+from hetu_tpu.utils import import_example_models as _import_example_models
 
 
 models = None

@@ -267,7 +267,7 @@ def test_recurrent_roundtrip(name, tmp_path):
     slice, fused gate matmuls, sigmoid/tanh, elementwise carries) survive
     export -> import — the reference's recurrent ONNX capability
     (/root/reference/tests/onnx/rnn_hetu_onnx_tf.py:1)."""
-    from conftest import import_example_models
+    from hetu_tpu.utils import import_example_models
     model = getattr(import_example_models("cnn"), name)
 
     B = 4
@@ -290,7 +290,7 @@ def test_recurrent_roundtrip(name, tmp_path):
 def test_vit_roundtrip(tmp_path):
     """Full ViT forward (patch conv, [CLS] BroadcastShape concat, MHA
     blocks, LayerNorm, slice head) survives export -> import."""
-    from conftest import import_example_models
+    from hetu_tpu.utils import import_example_models
     vit = import_example_models("cnn").vit
 
     B = 2

@@ -10,8 +10,7 @@
 - HBM/params/6ND telemetry gauges under ``JAX_PLATFORMS=cpu``
 - the perf-regression gate's exit-code contract for {clean, regressed,
   incomplete-baseline, incomplete-current} + the ``--gate --check`` CLI
-- bench.py satellites: the emergency final line (completed cells +
-  ``incomplete_cells``), baseline-round selection, attn_flops parity
+- parity of ``DEVICE_PEAKS`` / ``mfu`` with ``benchmark/reduce/peaks.py``
 - hetutop's dual-denominator MFU columns
 """
 import gzip
@@ -315,7 +314,7 @@ def test_gate_incomplete_baseline_distinct_code():
             "value": None}
     assert _gate(dead, GOOD).status == prof.GATE_INCOMPLETE_BASELINE
     # the driver's wrapper form of a dead round: rc=124, parsed null
-    wrapper = {"n": 5, "cmd": "python bench.py", "rc": 124, "parsed": None}
+    wrapper = {"n": 5, "cmd": "x", "rc": 124, "parsed": None}
     bc, bm = prof.normalize_summary(wrapper)
     assert bc == {} and bm["incomplete"]
 
@@ -383,57 +382,34 @@ def test_roofline_joins_measured_times():
 
 
 # ---------------------------------------------------------------------------
-# bench.py satellites
+# the benchmark's copy of the peaks (ROADMAP D13)
 # ---------------------------------------------------------------------------
 
-def _bench():
+def test_device_peaks_parity_with_benchmark():
+    """`benchmark/reduce/peaks.py` copied this module's table; until one
+    copy goes, a chip named by both has the same peaks in both, and the
+    two utilization formulas agree."""
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules.setdefault("bench", mod)
-    spec.loader.exec_module(sys.modules["bench"])
-    return sys.modules["bench"]
-
-
-def test_bench_assemble_final_partial_run():
-    bench = _bench()
-    keys = ["resnet18_f32_bs128", "bert_base_pretrain_seq512"]
-    detail = {"resnet18_f32_bs128": {"samples_per_sec": 5000.0,
-                                     "step_ms": 25.6}}
-    line = bench._assemble_final(detail, keys, error="terminated by signal "
-                                 "15 before completion")
-    assert line["value"] == 5000.0                 # completed cell survives
-    assert line["incomplete_cells"] == ["bert_base_pretrain_seq512"]
-    assert "error" in line
-    # the gate reads this as incomplete, never win/loss
-    cells, meta = prof.normalize_summary(line)
-    assert meta["incomplete"]
-    # nothing completed: value is null, every cell incomplete
-    line = bench._assemble_final({}, keys)
-    assert line["value"] is None
-    assert line["incomplete_cells"] == keys
-
-
-def test_bench_latest_good_round_skips_dead_rounds(tmp_path):
-    bench = _bench()
-    (tmp_path / "BENCH_r07.json").write_text(json.dumps(
-        {"n": 7, "rc": 124, "cmd": "x", "parsed": None}))
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(
-        {"n": 6, "rc": 0, "cmd": "x", "parsed": GOOD}))
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps(
-        {"n": 5, "rc": 0, "cmd": "x", "parsed": GOOD}))
-    pick = bench._latest_good_round(str(tmp_path))
-    assert pick is not None and os.path.basename(pick) == "BENCH_r06.json"
-    assert bench._latest_good_round(str(tmp_path / "empty")) is None
-
-
-def test_attn_flops_parity_with_bench():
-    bench = _bench()
-    args = (32, 512, 12, 768, False)
-    assert bench._attn_flops(*args) == prof.attn_flops(*args)
+        "_benchmark_peaks",
+        os.path.join(REPO, "benchmark", "reduce", "peaks.py"))
+    peaks = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(peaks)
+    shared = set(prof.DEVICE_PEAKS) & set(peaks.DEVICE_PEAKS)
+    assert "TPU v5 lite" in shared
+    for kind in shared:
+        for key in ("tflops", "gbs"):
+            assert prof.DEVICE_PEAKS[kind][key] \
+                == peaks.DEVICE_PEAKS[kind][key], (kind, key)
+        # BERT-base at seq 512: 6ND + attention FLOPs a token, 4 chips
+        flops_per_token = 6.0 * 110e6 + prof.attn_flops(
+            1, 512, 12, 768, False) / 512
+        tokens_per_s, chips, step_s = 450_300.0, 4, 0.581
+        flops_per_chip_step = tokens_per_s * step_s * flops_per_token / chips
+        assert prof.mfu(flops_per_chip_step, step_s, kind) == pytest.approx(
+            peaks.utilization(tokens_per_s, flops_per_token, chips, kind))
     assert prof.attn_flops(32, 512, 12, 768, True) \
-        == prof.attn_flops(*args) / 2.0
+        == prof.attn_flops(32, 512, 12, 768, False) / 2.0
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,7 @@ InitTensor/Push/Pull/sparse APIs against numpy oracles. Uses the ``spawn``
 start method (children never touch the parent's JAX runtime — fork with JAX
 threads deadlocks).
 """
+import itertools
 import multiprocessing as mp
 import os
 import queue as pyqueue
@@ -15,11 +16,38 @@ import tempfile
 import time
 
 import numpy as np
+import pytest
 
 NITEM = 200
 ITEM_LEN = 50
-_PORT_BASE = int(os.environ.get("HETU_TEST_PS_PORT", "13700"))
-_port_iter = iter(range(_PORT_BASE, _PORT_BASE + 10000, 7))
+
+# Every live-cluster test of the suite draws its scheduler port here (the
+# servers take port + 1 + idx). Under `-n 6 --dist loadfile` the files run
+# in several processes at once, so each xdist worker owns a block of its
+# own: all of them above the ports other tests hard-code (14310-19997)
+# and below the OS's ephemeral range (32768), which client sockets use.
+_PORT_BASE = 20000
+_PORTS_PER_WORKER = 1000
+_PORTS_PER_CLUSTER = 12
+_MAX_WORKERS = 12
+
+
+def port_blocks(xdist_worker=""):
+    """Endless iterator of scheduler ports for one test process; ``port ..
+    port + _PORTS_PER_CLUSTER - 1`` is that cluster's alone. `xdist_worker`
+    is ``PYTEST_XDIST_WORKER`` ("gw3"; empty without xdist)."""
+    index = int(xdist_worker[2:]) if xdist_worker else 0
+    if not 0 <= index < _MAX_WORKERS:
+        raise RuntimeError(
+            f"xdist worker {xdist_worker!r}: the test ports are laid out "
+            f"for at most {_MAX_WORKERS} workers")
+    start = _PORT_BASE + index * _PORTS_PER_WORKER
+    return itertools.cycle(range(
+        start, start + _PORTS_PER_WORKER - _PORTS_PER_CLUSTER + 1,
+        _PORTS_PER_CLUSTER))
+
+
+_port_iter = port_blocks(os.environ.get("PYTEST_XDIST_WORKER", ""))
 
 
 def _env(role, idx, port, n_workers=2, n_servers=2):
@@ -332,6 +360,26 @@ def _exits_without_reporting(client, rank, tmpdir):
 
 
 # ---------------------------------------------------------------------------
+
+def test_xdist_workers_never_share_a_port():
+    """Two workers drawing at once, each far past its block's wrap, never
+    hold the same port in ``port .. port + n_servers``."""
+    owned = []
+    for worker in ["", "gw1", "gw5", f"gw{_MAX_WORKERS - 1}"]:
+        draws = list(itertools.islice(port_blocks(worker), 200))
+        ports = {p + i for p in draws for i in range(_PORTS_PER_CLUSTER)}
+        # inside one worker a cluster's range meets no other cluster's
+        assert len(ports) == len(set(draws)) * _PORTS_PER_CLUSTER
+        assert len(set(draws)) >= 58        # the suite's draws, run serially
+        assert 19997 < min(ports) and max(ports) < 32768
+        owned.append(ports)
+    assert next(port_blocks("gw0")) == next(port_blocks(""))
+    for i, a in enumerate(owned):
+        for b in owned[i + 1:]:
+            assert not a & b
+    with pytest.raises(RuntimeError, match="at most"):
+        port_blocks(f"gw{_MAX_WORKERS}")
+
 
 def test_ps_dense_ops(tmp_path):
     run_cluster(_dense_ops, tmp_path)

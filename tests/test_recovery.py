@@ -273,11 +273,11 @@ def test_kill_between_publish_and_pointer_restores_previous(tmp_path,
             comm.Wait(0)
             r1 = comm.SnapshotNow(0, epoch=1)
             assert r1["version"] == 1 and r1["counter"] == 1
-            val_v1 = comm.Pull(0, np.empty(16, np.float32)).copy()
-            comm.Wait(0)
+            val_v1 = comm.Pull(0, np.empty(16, np.float32))
+            comm.Wait(0)    # the buffer is filled by now, not before
             comm.Push(0, np.full(16, 1.0, np.float32))
             comm.Wait(0)
-            val_later = comm.Pull(0, np.empty(16, np.float32)).copy()
+            val_later = comm.Pull(0, np.empty(16, np.float32))
             comm.Wait(0)
             assert not np.array_equal(val_v1, val_later)
             # v2: dir publishes, then std::_Exit(137) before the pointer
@@ -314,7 +314,7 @@ def test_kill_between_publish_and_pointer_restores_previous(tmp_path,
                             init_type="constant", init_a=0.0)
             stats = comm.ServerStats(0)
             assert stats["restored_updates"] == 1, stats
-            got = comm.Pull(0, np.empty(16, np.float32)).copy()
+            got = comm.Pull(0, np.empty(16, np.float32))
             comm.Wait(0)
             np.testing.assert_array_equal(got, val_v1)
             assert not np.array_equal(got, val_later)

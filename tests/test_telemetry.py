@@ -462,23 +462,6 @@ def test_auc_degenerate_inputs_nan_with_warning():
     assert not np.isnan(v) and not w
 
 
-def test_bench_telemetry_line(tmp_path):
-    import bench
-    led = bench._Ledger(str(tmp_path / "BENCH_PARTIAL.json"))
-    led.record("resnet18_bf16_bs128",
-               {"samples_per_sec": 10.0, "step_ms": 1.0, "mfu": 0.2},
-               device="fake-v5e")
-    line = json.loads(
-        open(tmp_path / "BENCH_TELEMETRY.jsonl").read().strip())
-    assert line["cell"] == "resnet18_bf16_bs128"
-    assert line["device_kind"] == "fake-v5e"
-    assert line["peak_tflops"] is None     # a kind the table lacks
-    assert line["samples_per_sec"] == 10.0
-    # ledger-less (smoke) mode writes no telemetry line either
-    bench._Ledger("").record("x", {"samples_per_sec": 1.0}, device="d")
-    assert not (tmp_path / "x").exists()
-
-
 def test_heturun_run_summary(tmp_path, monkeypatch):
     from hetu_tpu import runner
     (tmp_path / "metrics-r0.jsonl").write_text("{}\n")

@@ -41,3 +41,17 @@ def test_torch_twin_ddp_two_process():
         capture_output=True, text=True, timeout=300, env=_env())
     assert p.returncode == 0, p.stderr
     assert _final_acc(p.stdout) > 0.85, p.stdout
+
+
+def test_jax_twin_resnet_steps():
+    """The raw-JAX ResNet-18 twin (examples/cnn/jax_twin.py), the other
+    side of the framework-overhead comparison (ROADMAP S3), builds and
+    takes momentum steps in bf16; its time is the chip's to tell."""
+    sys.path.insert(0, os.path.join(REPO, "examples", "cnn"))
+    try:
+        import jax_twin
+    finally:
+        sys.path.pop(0)
+    samples_per_s, step_ms = jax_twin.bench(batch_size=8, dtype="bf16",
+                                            warmup=1, iters=2)
+    assert samples_per_s > 0 and step_ms > 0
