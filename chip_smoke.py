@@ -296,6 +296,13 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                     x, jax.sharding.PartitionSpec)))
             opt = tfm.place_opt_state(opt, specs, mesh)
         step = bert.make_pretrain_step(cfg, mesh=mesh, lr=1e-4)
+        # what the trunk's checkpoint keeps at these shapes on this device
+        names, held, budget = tfm._remat_names(
+            cfg.trunk(), params,
+            jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype), mesh,
+            jax.ShapeDtypeStruct((batch, 1, 1, seq), np.float32))
+        rec["remat"] = {"names": list(names), "held_bytes": held,
+                        "budget_bytes": budget}
         rng = np.random.RandomState(0)
         timings = {}
         for label, padded in (("unpadded", False), ("padded", True)):

@@ -31,8 +31,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..telemetry.tracing import REMAT_ATTN_LSE, REMAT_ATTN_O
 
 # the kernels' names in the device trace (docs/KERNELS.md): one constant a
 # pallas_call site, written as the call's `name=`
@@ -621,6 +624,11 @@ def _scale(qkv, n_heads, scale):
 def _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
     out, lse = _fwd_pallas(qkv, n_heads, k_bias, _scale(qkv, n_heads, scale),
                            causal, block_q, block_k, interpret=not _on_tpu())
+    # named HERE so that the very values a `jax.checkpoint` policy saves are
+    # the backward kernels' residuals: a name on the caller's side of the
+    # custom_vjp would still re-run this kernel for them
+    out = checkpoint_name(out, REMAT_ATTN_O)
+    lse = checkpoint_name(lse, REMAT_ATTN_LSE)
     return out, (qkv, out, lse, k_bias)
 
 

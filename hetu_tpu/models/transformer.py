@@ -27,16 +27,21 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 from typing import Any, Optional
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..telemetry.tracing import (SCOPE_FWD, SCOPE_MOE_COMBINE,
+from ..telemetry.tracing import (REMAT_ATTN_O, REMAT_CANDIDATES, REMAT_X1,
+                                 REMAT_X2, SCOPE_FWD, SCOPE_MOE_COMBINE,
                                  SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
                                  SCOPE_MOE_ROUTE, SCOPE_OPT, scoped)
+
+_log = logging.getLogger(__name__)
 
 # router z-loss weight (ST-MoE, OLMoE: 1e-3); the balance loss keeps
 # ``loss_fn``'s ``aux_weight``
@@ -485,6 +490,10 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
             k, v = (jnp.repeat(x.reshape(B, T, nkv, hd), nh // nkv,
                                axis=2).reshape(B, T, D) for x in (k, v))
         out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias)
+    if impl != "flash":
+        # the flash kernel names its o itself, with its lse, where they
+        # become its backward pass's residuals (`_flash_fwd`)
+        out = checkpoint_name(out, REMAT_ATTN_O)
     out = jnp.einsum("btd,de->bte", out, p["wo"].astype(h.dtype),
                      preferred_element_type=jnp.float32).astype(h.dtype)
     if cfg.attn_proj_bias:
@@ -678,7 +687,7 @@ def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
         h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
     attn_out = _attention(attn_in, layer_params, cfg, mesh, attn_bias)
     attn_out = _dropout(attn_out, cfg.dropout_rate, dropout_rng)
-    h = h + attn_out
+    h = checkpoint_name(h + attn_out, REMAT_X1)
     if post:
         h = _norm(h, layer_params["ln1_scale"],
                   layer_params["ln1_bias"], cfg)
@@ -707,7 +716,7 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     else:
         out = _dense_mlp(mlp_in, layer_params, cfg, mesh)
         aux = jnp.zeros((2,), jnp.float32)
-    h = h + _dropout(out, cfg.dropout_rate, k2)
+    h = checkpoint_name(h + _dropout(out, cfg.dropout_rate, k2), REMAT_X2)
     if cfg.post_ln:
         h = _norm(h, layer_params["ln2_scale"],
                   layer_params["ln2_bias"], cfg)
@@ -743,6 +752,113 @@ def nll_loss(logits, targets):
     return jnp.mean(-jnp.take_along_axis(logp, targets[..., None], -1)[..., 0])
 
 
+# ---------------------------------------------------------------------------
+# what the layer's `jax.checkpoint` keeps
+# ---------------------------------------------------------------------------
+
+# of the device's limit, kept free: the allocator's slack and what `encode`
+# cannot see from where it stands (the loss head's working set)
+_REMAT_MARGIN = 1 / 32
+
+
+def _device_bytes_limit():
+    """What the first local device says it may hold, None where the backend
+    keeps no such count (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def _axes(mesh, *names):
+    """Devices that the mesh axes ``names`` cut an array over."""
+    return 1 if mesh is None else int(np.prod(
+        [mesh.shape.get(n, 1) for n in names]))
+
+
+def _state_bytes(cfg: TransformerConfig, params, mesh):
+    """Weights, their gradient and AdamW's two moments on one device: four
+    times the f32 bytes of ``params``, each leaf over what ``param_specs``
+    shards it by (a leaf the trunk's specs do not know is replicated)."""
+    specs = {jax.tree_util.keystr(k): spec for k, spec in
+             jax.tree_util.tree_leaves_with_path(
+                 param_specs(cfg), is_leaf=lambda x: isinstance(x, P))}
+    return sum(
+        16 * leaf.size // _axes(mesh, *(a for a in specs.get(
+            jax.tree_util.keystr(k), P()) if a is not None))
+        for k, leaf in jax.tree_util.tree_leaves_with_path(params))
+
+
+def _block_residual_bytes(cfg: TransformerConfig, mesh, h, blocks,
+                          attn_bias):
+    """Bytes that ONE block's backward pass reads from its forward pass: the
+    residuals of ``jax.vjp`` of ``_block`` itself, traced abstractly at the
+    real shapes (nothing is compiled or run). That is the working set a
+    layer's recomputation fills before its backward pass drains it, and it
+    follows the block: a MoE block's rows a pick count here, an unfused
+    attention's scores too. The compiler fuses some of them away, so this
+    reads high (BERT-base at 65,536 tokens: 3.96 GiB)."""
+    def residuals(h, layer, attn_bias):
+        return jax.vjp(
+            lambda h, layer: _block(h, layer, cfg, mesh, attn_bias),
+            h, layer)[1]
+
+    def shape(x, cut=0):
+        return jax.ShapeDtypeStruct(x.shape[cut:], x.dtype)
+
+    shapes = jax.eval_shape(
+        residuals, shape(h), jax.tree.map(lambda x: shape(x, 1), blocks),
+        None if attn_bias is None else shape(attn_bias))
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+
+
+def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
+                 bytes_limit=None):
+    """Which named values of a block the trunk's ``jax.checkpoint`` keeps
+    for the backward pass -> (names, bytes held, bytes budget), the bytes on
+    one device. A pure function of what ``encode`` sees at trace time:
+    shapes, the parameter tree, the mesh, and the device's own limit
+    (``bytes_limit`` stands in for it in tests; the CPU reports none, and
+    nothing is kept there).
+
+    The candidates of ``REMAT_CANDIDATES`` are admitted in their order
+    while ``n_layers`` times their bytes stay within the budget: the limit
+    less what the step holds whatever is kept (``_state_bytes``, the stack
+    of layer inputs the scan keeps, ``_block_residual_bytes`` of one block,
+    ``_REMAT_MARGIN``). A later candidate never gets in without the earlier
+    ones."""
+    if bytes_limit is None:
+        bytes_limit = _device_bytes_limit()
+    if bytes_limit is None:
+        return (), 0, 0
+    B, T, D = h.shape
+    dp, sp, tp = (_axes(mesh, name) for name in ("dp", "sp", "tp"))
+    # one (B, T, D) activation on one device, cut by sequence or by head
+    act = B * T * D * jnp.dtype(h.dtype).itemsize // dp
+    by_seq, by_head = act // sp, act // tp
+    lse = B * T * cfg.n_heads * 4 // (dp * tp)
+    budget = int(
+        bytes_limit * (1 - _REMAT_MARGIN) - _state_bytes(cfg, params, mesh)
+        - cfg.n_layers * by_seq
+        # activations carry the batch: dp cuts them, and maybe more
+        - _block_residual_bytes(cfg, mesh, h, params["blocks"], attn_bias)
+        // dp)
+    # {x1, x2} (pre-LN: x2 is the block's output, which the scan keeps
+    # anyway), then {o, lse}: a layer's bytes of each
+    costs = (by_seq * (2 if cfg.post_ln else 1), by_head + lse)
+    names, held = (), 0
+    for candidate, cost in zip(REMAT_CANDIDATES, costs):
+        if held + cfg.n_layers * cost > budget:
+            break
+        names, held = names + candidate, held + cfg.n_layers * cost
+    return names, held, budget
+
+
+@functools.lru_cache(maxsize=None)
+def _log_remat(names, held, budget):
+    """Once a distinct choice, at trace time."""
+    _log.info("remat keeps %s: %.2f GiB of a budget of %.2f GiB a device",
+              list(names) or "nothing", held / 2**30, budget / 2**30)
+
+
 def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
            attn_bias=None, dropout_rng=None):
     """Run the block stack on embedded input h (B, T, D) -> (h, aux_sum
@@ -750,10 +866,18 @@ def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     The trunk shared by the causal LM and the bidirectional encoder (BERT);
     ``attn_bias`` (a padding mask, constant across layers) is a scan
     constant via closure. ``dropout_rng``: training-time dropout when
-    ``cfg.dropout_rate > 0`` — omit for deterministic eval."""
+    ``cfg.dropout_rate > 0`` — omit for deterministic eval.
+
+    Under ``cfg.remat`` the backward pass of a layer recomputes its forward
+    pass but for the named values ``_remat_names`` finds room to keep."""
     block_fn = functools.partial(_block, cfg=cfg, mesh=mesh)
     if cfg.remat:
-        block_fn = jax.checkpoint(block_fn)
+        names, held, budget = _remat_names(cfg, params, h, mesh, attn_bias)
+        _log_remat(names, held, budget)
+        # no name admitted: the bare checkpoint, the very program it was
+        block_fn = jax.checkpoint(
+            block_fn, policy=jax.checkpoint_policies.save_only_these_names(
+                *names) if names else None)
     L = cfg.n_layers
 
     def scan_body(carry, xs):

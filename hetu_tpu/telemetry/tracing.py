@@ -56,6 +56,18 @@ SCOPE_MOE_EXPERTS = "hetu_moe_experts"    # grouped matmuls + activation
 SCOPE_MOE_COMBINE = "hetu_moe_combine"    # un-permute, weight, sum over k
 MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
               SCOPE_MOE_COMBINE)
+# what the trunk's `jax.checkpoint` may keep of a layer's forward pass
+# (`jax.ad_checkpoint.checkpoint_name`; the identity outside a checkpoint).
+# Each name sits where the value is made; `transformer._remat_names` admits
+# them by bytes, `transformer.encode` hands the admitted ones to the policy
+REMAT_X1 = "hetu_x1"      # h + attention's output: what the next norm reads
+REMAT_X2 = "hetu_x2"      # x1 + the MLP's output (post-LN: ln2's input)
+REMAT_ATTN_O = "hetu_attn_o"      # attention's output, `wo`'s input
+REMAT_ATTN_LSE = "hetu_attn_lse"  # the flash kernel's row statistic
+# in the order `_remat_names` admits them. The fused q|k|v projection is NOT
+# among them: kept, it cost the v5e as much to write and read back as its
+# matmul costs to run again, for 3.4 GiB (PERF.md, PR 28)
+REMAT_CANDIDATES = ((REMAT_X1, REMAT_X2), (REMAT_ATTN_O, REMAT_ATTN_LSE))
 # host spans inside SubExecutor.run, children of STEP, in call order
 (BOUNDARY, FEED, DL_WAIT, PS_PULL, BUILD, DISPATCH, PREFETCH, PS_PUSH,
  POSTSTEP) = STEP_SPANS = (

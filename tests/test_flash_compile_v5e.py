@@ -183,6 +183,12 @@ def _bert_layer():
     return bert.BertConfig(attn_impl="flash").trunk()
 
 
+def _bert_hf_layer():
+    """BERT as the benchmark's cells build it: post-LN, biases, erf GELU."""
+    from hetu_tpu.models import bert
+    return bert.BertConfig.hf(dtype=jnp.bfloat16, attn_impl="flash").trunk()
+
+
 def _olmoe_layer():
     """OLMoE-1B-7B's attention (16 heads of 128, causal, RoPE, QK-norm,
     RMSNorm) over a dense SwiGLU: the experts are not attention's."""
@@ -193,15 +199,24 @@ def _olmoe_layer():
         rope=True, mlp="swiglu", use_pos_emb=False, qk_norm=True)
 
 
+def _entry_matmuls(graph):
+    return sum(matmul for _, _, _, matmul in graph.values())
+
+
 @pytest.mark.parametrize("config,batch,seq,bias", [
     pytest.param(_bert_layer, 128, 512, True, id="bert-seq512"),
     pytest.param(_bert_layer, 512, 128, True, id="bert-seq128"),
     pytest.param(_olmoe_layer, 8, 4096, False, id="olmoe-seq4096"),
+    pytest.param(_bert_hf_layer, 128, 512, True, id="bert-hf-seq512"),
 ])
 def test_layer_copies_nothing_around_attention(one_chip, no_compile_cache,
                                                monkeypatch, config, batch,
                                                seq, bias):
+    """The layer under a BARE `jax.checkpoint` (what the trunk runs where
+    `_remat_names` admits nothing), then under the trunk's own policy with
+    every name admitted."""
     from hetu_tpu.models import transformer as tfm
+    from hetu_tpu.telemetry.tracing import REMAT_CANDIDATES
 
     # the trunk and the kernels ask the backend which path to take
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -216,16 +231,31 @@ def test_layer_copies_nothing_around_attention(one_chip, no_compile_cache,
     attn_bias = jax.ShapeDtypeStruct((batch, 1, 1, seq), jnp.float32,
                                      sharding=one_chip)
 
-    def loss(h, layer, attn_bias):
-        block = jax.checkpoint(lambda h, layer: tfm._block(
-            h, layer, cfg, None, attn_bias if bias else None)[0])
-        return jnp.sum(block(h, layer).astype(jnp.float32) ** 2)
+    def compiled(policy):
+        def loss(h, layer, attn_bias):
+            block = jax.checkpoint(lambda h, layer: tfm._block(
+                h, layer, cfg, None, attn_bias if bias else None)[0],
+                policy=policy)
+            return jnp.sum(block(h, layer).astype(jnp.float32) ** 2)
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        h, layer, attn_bias).compile().as_text()
-    # the forward runs twice: once as it is, once under `remat`
-    assert _count_by_name(_kernel_calls(text)) == {
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            h, layer, attn_bias).compile().as_text()
+        graph = _entry_graph(text)
+        copies = _copies_around_kernels(graph, batch * seq * cfg.d_model)
+        assert not copies, [(c, graph[c]) for c in copies]
+        return _count_by_name(_kernel_calls(text)), _entry_matmuls(graph)
+
+    # the bare policy's contract: the forward runs twice, once as it is,
+    # once under `remat`
+    bare_kernels, bare_matmuls = compiled(None)
+    assert bare_kernels == {
         fa.FLASH_FWD: 2, fa.FLASH_BWD_DQ: 1, fa.FLASH_BWD_DKV: 1}
-    graph = _entry_graph(text)
-    copies = _copies_around_kernels(graph, batch * seq * cfg.d_model)
-    assert not copies, [(c, graph[c]) for c in copies]
+    # every name kept: the forward kernel runs once, and the recomputed
+    # wo (and w2, where a norm reads its sum) is gone
+    kernels, matmuls = compiled(
+        jax.checkpoint_policies.save_only_these_names(
+            *sum(REMAT_CANDIDATES, ())))
+    assert kernels == {
+        fa.FLASH_FWD: 1, fa.FLASH_BWD_DQ: 1, fa.FLASH_BWD_DKV: 1}
+    assert matmuls <= bare_matmuls - (2 if cfg.post_ln else 1), (
+        matmuls, bare_matmuls)

@@ -1,0 +1,220 @@
+"""What the trunk's ``jax.checkpoint`` keeps (``transformer._remat_names``,
+``transformer.encode``): the rule as a pure function of shapes, parameter
+bytes, the block and a device limit passed in, and the mechanism read from
+the backward scan's jaxpr. CPU: counts and gradients, no time."""
+import dataclasses
+import functools
+import json
+import os
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from hetu_tpu.models import bert, hf_olmoe
+from hetu_tpu.models import transformer as tfm
+from hetu_tpu.parallel import mesh as meshlib
+from hetu_tpu.telemetry.tracing import (REMAT_ATTN_LSE, REMAT_ATTN_O,
+                                        REMAT_CANDIDATES, REMAT_X1, REMAT_X2)
+
+from test_transformer import tiny_cfg
+
+GiB = 2 ** 30
+ALL = (REMAT_X1, REMAT_X2, REMAT_ATTN_O, REMAT_ATTN_LSE)
+OLMOE_JSON = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                          "configs", "olmoe-1b-7b", "config.json")
+
+
+def _bert():
+    """BERT-base as the benchmark's adapter builds it, flash attention as
+    the TPU resolves it."""
+    cfg = bert.BertConfig.hf(dtype=jnp.bfloat16, attn_impl="flash")
+    return cfg.trunk(), jax.eval_shape(
+        lambda: bert.init_params(jax.random.PRNGKey(0), cfg))
+
+
+def _olmoe():
+    """The cell olmoe-1b-7b.pretrain-seq4096: one layer of 64 experts."""
+    with open(OLMOE_JSON) as f:
+        cfg = hf_olmoe.config_from_hf(json.load(f), dtype=jnp.bfloat16,
+                                      attn_impl="flash")
+    assert (cfg.n_layers, cfg.n_experts, cfg.n_experts_per_tok) == (1, 64, 8)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    assert 620e6 < tfm.count_params(params) < 632e6
+    return cfg, params
+
+
+def _rule(model, batch, seq, dp, limit_gib, bias=True):
+    cfg, params = model()
+    mesh = (meshlib.make_mesh(dp=dp, devices=jax.devices()[:dp])
+            if dp > 1 else None)
+    h = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype)
+    attn_bias = (jax.ShapeDtypeStruct((batch, 1, 1, seq), jnp.float32)
+                 if bias else None)
+    return tfm._remat_names(
+        cfg, params, h, mesh, attn_bias,
+        bytes_limit=None if limit_gib is None else int(limit_gib * GiB))
+
+
+@pytest.mark.parametrize("model,batch,seq,dp,limit_gib,names,held_gib", [
+    pytest.param(_bert, 128, 512, 1, 16, ALL, (3.3, 3.5), id="bert-seq512"),
+    pytest.param(_bert, 512, 128, 1, 16, ALL, (3.3, 3.5), id="bert-seq128"),
+    pytest.param(_bert, 512, 512, 4, 16, ALL, (3.3, 3.5),
+                 id="bert-seq512-dp4"),
+    # the limit a v5e reports: what the chip runs are held to
+    pytest.param(_bert, 128, 512, 1, 15.75, ALL, (3.3, 3.5),
+                 id="bert-seq512-v5e-limit"),
+    pytest.param(_olmoe, 8, 4096, 1, 16, (), (0, 0), id="olmoe-seq4096"),
+    pytest.param(_bert, 128, 512, 1, None, (), (0, 0), id="bert-no-limit"),
+    pytest.param(_olmoe, 8, 4096, 1, None, (), (0, 0), id="olmoe-no-limit"),
+])
+def test_remat_names_by_bytes(model, batch, seq, dp, limit_gib, names,
+                              held_gib):
+    got, held, budget = _rule(model, batch, seq, dp, limit_gib,
+                              bias=model is _bert)
+    assert got == names
+    assert held_gib[0] * GiB <= held <= held_gib[1] * GiB
+    assert held <= max(budget, 0)
+    if model is _olmoe and limit_gib:
+        # not by a hair: the block's own residuals put it far under water
+        assert budget < -1.5 * GiB
+    if names and limit_gib:
+        assert budget - held > 1.5 * GiB
+
+
+PREFIXES = [(), ALL[:2], ALL]
+
+
+@pytest.mark.parametrize("limit_gib", [16, 14, 12, 11, 10.5, 10, 9.5, 9,
+                                       8.5, 8, 6, 2])
+def test_remat_names_shrink_as_a_prefix(limit_gib):
+    """As the limit falls the set shrinks in the stated order, {x1, x2},
+    then o and lse: never the later candidate without the earlier."""
+    assert sum(REMAT_CANDIDATES, ()) == ALL
+    got, held, budget = _rule(_bert, 128, 512, 1, limit_gib)
+    assert got in PREFIXES
+    more, held_more, budget_more = _rule(_bert, 128, 512, 1, limit_gib + 1)
+    assert len(more) >= len(got) and held_more >= held
+    assert budget_more - budget == pytest.approx(
+        GiB * (1 - tfm._REMAT_MARGIN), abs=2)
+    if limit_gib == 2:
+        assert got == ()
+
+
+def test_remat_names_reach_every_prefix():
+    seen = {_rule(_bert, 128, 512, 1, g / 2)[0] for g in range(4, 33)}
+    assert seen == set(PREFIXES)
+
+
+# ---------------------------------------------------------------------------
+# the mechanism: what the backward scan of `encode` holds
+# ---------------------------------------------------------------------------
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (list, tuple)) else [v]):
+            if hasattr(x, "jaxpr") and hasattr(x, "consts"):
+                yield x.jaxpr
+            elif hasattr(x, "eqns"):
+                yield x
+
+
+def _count(jaxpr, into):
+    """Matmuls and kernel calls of a jaxpr, nested calls included (not the
+    kernels' own bodies)."""
+    for e in jaxpr.eqns:
+        if e.primitive.name in ("dot_general", "pallas_call"):
+            into[e.primitive.name] = into.get(e.primitive.name, 0) + 1
+        if e.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(e):
+                _count(sub, into)
+    return into
+
+
+def _backward_scan_counts(fn, *args):
+    """{primitive: count} inside the reversed scan over the layers of
+    ``jax.grad(fn)``: one layer's backward pass and what it recomputes."""
+    def scans(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "scan" and e.params["reverse"]:
+                yield e
+            else:
+                for sub in _sub_jaxprs(e):
+                    yield from scans(sub)
+
+    found = list(scans(jax.make_jaxpr(jax.grad(fn))(*args).jaxpr))
+    assert len(found) == 1, found
+    return _count(found[0].params["jaxpr"].jaxpr,
+                  {"dot_general": 0, "pallas_call": 0})
+
+
+def _post_ln_dense():
+    return tiny_cfg(d_model=128, n_heads=2, n_layers=2, d_ff=256,
+                    max_seq_len=128, attn_impl="flash", post_ln=True,
+                    attn_proj_bias=True, causal=False, gelu_exact=True)
+
+
+def _pre_ln_rope_qknorm():
+    return tiny_cfg(d_model=128, n_heads=2, n_layers=2, d_ff=256,
+                    max_seq_len=128, attn_impl="flash", norm="rmsnorm",
+                    rope=True, qk_norm=True, mlp="swiglu", use_pos_emb=False)
+
+
+@pytest.mark.parametrize("dp", [1, 4], ids=["one-device", "dp4-shard_map"])
+@pytest.mark.parametrize("config,recomputed", [
+    pytest.param(_post_ln_dense, 2, id="post-ln-dense"),    # wqkv, w1
+    pytest.param(_pre_ln_rope_qknorm, 3, id="pre-ln-rope-qknorm-swiglu"),
+])                                                          # wqkv, w1, w3
+def test_named_checkpoint_skips_kernel_and_output_matmuls(monkeypatch, config,
+                                                recomputed, dp):
+    cfg = config()
+    mesh = (meshlib.make_mesh(dp=dp, devices=jax.devices()[:dp])
+            if dp > 1 else None)
+    params = tfm.init_trunk_params(jax.random.PRNGKey(0), cfg)
+    h = jax.random.normal(jax.random.PRNGKey(1), (4, 128, cfg.d_model),
+                          cfg.dtype)
+
+    def loss(params, h, cfg):
+        out, _ = tfm.encode(params, h, cfg, mesh)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    plain = functools.partial(loss, cfg=cfg)              # remat=False
+    remat = functools.partial(loss, cfg=dataclasses.replace(cfg, remat=True))
+    # the CPU reports no limit: nothing is named, the bare checkpoint
+    assert tfm._remat_names(remat.keywords["cfg"], params, h, mesh)[0] == ()
+    counts = {"plain": _backward_scan_counts(plain, params, h),
+              "bare": _backward_scan_counts(remat, params, h)}
+    grads = {"plain": jax.jit(jax.grad(plain, argnums=(0, 1)))(params, h),
+             "bare": jax.jit(jax.grad(remat, argnums=(0, 1)))(params, h)}
+    monkeypatch.setattr(tfm, "_device_bytes_limit", lambda: 64 * GiB)
+    assert tfm._remat_names(remat.keywords["cfg"], params, h, mesh)[0] == ALL
+    counts["named"] = _backward_scan_counts(remat, params, h)
+    grads["named"] = jax.jit(jax.grad(remat, argnums=(0, 1)))(params, h)
+
+    # the bare checkpoint runs the layer's forward again, kernel and all:
+    # wqkv, wo, w1 (w3), and w2 where a norm reads its sum (post-LN)
+    assert counts["bare"]["pallas_call"] == counts["plain"]["pallas_call"] + 1
+    assert (counts["bare"]["dot_general"] == counts["plain"]["dot_general"]
+            + (2 if cfg.post_ln else 1) + recomputed)
+    # with the four names kept: no attention kernel, no wo, no w2
+    assert counts["named"]["pallas_call"] == counts["plain"]["pallas_call"]
+    assert (counts["named"]["dot_general"]
+            == counts["plain"]["dot_general"] + recomputed)
+    for other in ("bare", "named"):
+        for got, want in zip(jax.tree.leaves(grads[other]),
+                             jax.tree.leaves(grads["plain"])):
+            np.testing.assert_allclose(
+                got, want, rtol=1e-4,
+                atol=1e-5 * float(jnp.max(jnp.abs(want))), err_msg=other)
+
+
+def test_names_are_the_identity_outside_a_checkpoint():
+    """`remat=False` callers see the same program with or without names."""
+    cfg = _post_ln_dense()
+    params = tfm.init_trunk_params(jax.random.PRNGKey(0), cfg)
+    h = jnp.ones((2, 128, cfg.d_model), cfg.dtype)
+    text = jax.jit(lambda p, h: tfm.encode(p, h, cfg)[0]).lower(
+        params, h).as_text()
+    assert "hetu_x1" not in text and "hetu_attn_o" not in text
