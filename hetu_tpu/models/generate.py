@@ -20,7 +20,7 @@ thing is one compiled program.
 
 Dense MLP blocks only (the switch MoE flagship path is a training
 configuration; decode asserts ``n_experts == 0`` and refuses
-``qk_norm``). Decode runs single-program (``mesh=None``) or distributed: with a mesh, params keep
+``qk_norm``, ``n_loops > 1`` and ``sandwich_norm``). Decode runs single-program (``mesh=None``) or distributed: with a mesh, params keep
 their Megatron tp layout, the KV cache shards batch-over-dp and
 heads-over-tp, and GSPMD inserts the collectives (see
 ``make_generate_fn``).
@@ -166,6 +166,9 @@ def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
                        top_k: int) -> None:
     assert cfg.n_experts == 0, "decode supports dense blocks (no MoE)"
     assert not cfg.qk_norm, "decode does not mirror qk_norm (_decode_layer)"
+    assert cfg.n_loops == 1 and not cfg.sandwich_norm, (
+        f"decode does not mirror n_loops={cfg.n_loops} (a cache entry a pass "
+        f"and layer) or sandwich_norm={cfg.sandwich_norm} (_decode_layer)")
     assert cfg.causal, "decode is autoregressive — causal configs only"
     assert max_len <= cfg.max_seq_len
     assert 0 <= top_k <= cfg.vocab_size, (

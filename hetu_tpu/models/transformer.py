@@ -37,15 +37,19 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry.tracing import (REMAT_ATTN_O, REMAT_CANDIDATES, REMAT_X1,
-                                 REMAT_X2, SCOPE_FWD, SCOPE_MOE_COMBINE,
-                                 SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
-                                 SCOPE_MOE_ROUTE, SCOPE_OPT, scoped)
+                                 REMAT_X2, SCOPE_EXIT, SCOPE_FWD,
+                                 SCOPE_MOE_COMBINE, SCOPE_MOE_DISPATCH,
+                                 SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE,
+                                 SCOPE_OPT, scoped)
 
 _log = logging.getLogger(__name__)
 
 # router z-loss weight (ST-MoE, OLMoE: 1e-3); the balance loss keeps
 # ``loss_fn``'s ``aux_weight``
 Z_LOSS_WEIGHT = 1e-3
+# entropy bonus on a looped model's exit distribution (Ouro stage I,
+# arXiv:2510.25741: 0.1 early, 0.05 later; a uniform prior over exit steps)
+EXIT_ENTROPY_WEIGHT = 0.05
 
 
 class MoEConfigError(ValueError):
@@ -118,6 +122,15 @@ class TransformerConfig:
     qk_norm: bool = False       # OLMoE: RMSNorm (``q_norm``/``k_norm``
                                 # scales, ``ln_eps``) over the whole q and
                                 # k projections, before the head split
+    # Looped-LM dialect knobs (models/hf_ouro.py sets both):
+    n_loops: int = 1            # > 1: the block stack runs this many times
+                                # over ONE set of weights, the final norm
+                                # after each pass; every pass's state is an
+                                # exit that ``loss_fn`` weights by a learned
+                                # gate (``exit_gate_w``/``exit_gate_b``)
+    sandwich_norm: bool = False  # a norm after each sublayer too, before
+                                 # the residual add (``ln1_post_*`` /
+                                 # ``ln2_post_*``); pre-LN only
 
     def __post_init__(self):
         if self.n_experts and not (
@@ -125,6 +138,12 @@ class TransformerConfig:
             raise MoEConfigError(
                 f"n_experts_per_tok={self.n_experts_per_tok} of "
                 f"n_experts={self.n_experts}")
+        if self.n_loops < 1 or (self.post_ln and (
+                self.n_loops > 1 or self.sandwich_norm)):
+            raise ValueError(
+                f"n_loops={self.n_loops}, sandwich_norm={self.sandwich_norm}"
+                f", post_ln={self.post_ln}: loops and sandwich norms are "
+                "pre-LN, n_loops >= 1")
 
     @property
     def kv_heads(self):
@@ -176,6 +195,10 @@ def _init_trunk(ks, cfg: TransformerConfig):
                                     jnp.float32)
         blocks["k_norm"] = jnp.ones((L, cfg.kv_heads * cfg.head_dim),
                                     jnp.float32)
+    if cfg.sandwich_norm:
+        for name in ("ln1_post", "ln2_post"):
+            blocks[name + "_scale"] = jnp.ones((L, D), jnp.float32)
+            blocks[name + "_bias"] = jnp.zeros((L, D), jnp.float32)
     if cfg.mlp == "swiglu":
         blocks["w3"] = norm(ks[8], (L, E, D, F) if E > 0 else (L, D, F),
                             0.02)
@@ -210,6 +233,9 @@ def init_params(rng, cfg: TransformerConfig):
         params["pos"] = _init_normal(ks[6], (cfg.max_seq_len, D), 0.02)
     if not cfg.tied_head:
         params["head"] = _init_normal(ks[7], (D, V), 0.02)
+    if cfg.n_loops > 1:
+        params["exit_gate_w"] = _init_normal(ks[9], (D,), 0.02)
+        params["exit_gate_b"] = jnp.zeros((), jnp.float32)
     return params
 
 
@@ -231,6 +257,9 @@ def param_specs(cfg: TransformerConfig):
     if cfg.qk_norm:
         blocks["q_norm"] = P(None, "tp")
         blocks["k_norm"] = P(None, "tp")
+    if cfg.sandwich_norm:
+        for name in ("ln1_post", "ln2_post"):
+            blocks[name + "_scale"] = blocks[name + "_bias"] = P(None, None)
     if cfg.mlp == "swiglu":
         blocks["w3"] = (P(None, "ep", None, "tp") if moe
                         else P(None, None, "tp"))
@@ -259,6 +288,8 @@ def param_specs(cfg: TransformerConfig):
         specs["pos"] = P(None, "tp")
     if not cfg.tied_head:
         specs["head"] = P(None, "tp")
+    if cfg.n_loops > 1:
+        specs["exit_gate_w"], specs["exit_gate_b"] = P(None), P()
     return specs
 
 
@@ -686,6 +717,9 @@ def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
     attn_in = h if post else _norm(
         h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
     attn_out = _attention(attn_in, layer_params, cfg, mesh, attn_bias)
+    if cfg.sandwich_norm:
+        attn_out = _norm(attn_out, layer_params["ln1_post_scale"],
+                         layer_params["ln1_post_bias"], cfg)
     attn_out = _dropout(attn_out, cfg.dropout_rate, dropout_rng)
     h = checkpoint_name(h + attn_out, REMAT_X1)
     if post:
@@ -703,7 +737,8 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     z] losses, zeros for a dense MLP). Pre-LN (flagship default): LN ->
     sublayer -> residual. Post-LN (``cfg.post_ln``, canonical BERT /
     original Transformer): sublayer -> residual -> LN, with ln1 after
-    attention and ln2 after the MLP.
+    attention and ln2 after the MLP. Sandwich (``cfg.sandwich_norm``,
+    Ouro): LN -> sublayer -> LN -> residual.
 
     LOCKSTEP CONTRACT: any new dialect knob added here must be mirrored
     in ``generate._decode_layer`` (the KV-cache form of this block) or
@@ -716,6 +751,9 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     else:
         out = _dense_mlp(mlp_in, layer_params, cfg, mesh)
         aux = jnp.zeros((2,), jnp.float32)
+    if cfg.sandwich_norm:
+        out = _norm(out, layer_params["ln2_post_scale"],
+                    layer_params["ln2_post_bias"], cfg)
     h = checkpoint_name(h + _dropout(out, cfg.dropout_rate, k2), REMAT_X2)
     if cfg.post_ln:
         h = _norm(h, layer_params["ln2_scale"],
@@ -736,9 +774,10 @@ def embed_tokens(params, tokens, cfg: TransformerConfig):
 def lm_head(params, h, cfg: TransformerConfig):
     """Final norm + vocab projection -> f32 logits. In post-LN mode the
     blocks already end LayerNormed and canonical post-LN has no final LN,
-    so only the projection applies. Tied configs project against the token
-    embedding itself (no transposed copy is materialized)."""
-    if not cfg.post_ln:
+    so only the projection applies, as on a looped model's exit states,
+    which ``encode`` normed pass by pass. Tied configs project against the
+    token embedding itself (no transposed copy is materialized)."""
+    if not cfg.post_ln and cfg.n_loops == 1:
         h = _norm(h, params["lnf_scale"], params["lnf_bias"], cfg)
     if cfg.tied_head:
         return jnp.einsum("btd,vd->btv", h, params["embed"].astype(h.dtype),
@@ -820,11 +859,12 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     nothing is kept there).
 
     The candidates of ``REMAT_CANDIDATES`` are admitted in their order
-    while ``n_layers`` times their bytes stay within the budget: the limit
-    less what the step holds whatever is kept (``_state_bytes``, the stack
-    of layer inputs the scan keeps, ``_block_residual_bytes`` of one block,
-    ``_REMAT_MARGIN``). A later candidate never gets in without the earlier
-    ones."""
+    while their bytes, times the block applications of a step (``n_layers``
+    x ``n_loops``: a looped model keeps every pass's), stay within the
+    budget: the limit less what the step holds whatever is kept
+    (``_state_bytes``, the stack of layer inputs the scans keep, one an
+    application, ``_block_residual_bytes`` of one block, ``_REMAT_MARGIN``).
+    A later candidate never gets in without the earlier ones."""
     if bytes_limit is None:
         bytes_limit = _device_bytes_limit()
     if bytes_limit is None:
@@ -835,9 +875,10 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     act = B * T * D * jnp.dtype(h.dtype).itemsize // dp
     by_seq, by_head = act // sp, act // tp
     lse = B * T * cfg.n_heads * 4 // (dp * tp)
+    applications = cfg.n_layers * cfg.n_loops
     budget = int(
         bytes_limit * (1 - _REMAT_MARGIN) - _state_bytes(cfg, params, mesh)
-        - cfg.n_layers * by_seq
+        - applications * by_seq
         # activations carry the batch: dp cuts them, and maybe more
         - _block_residual_bytes(cfg, mesh, h, params["blocks"], attn_bias)
         // dp)
@@ -846,9 +887,9 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     costs = (by_seq * (2 if cfg.post_ln else 1), by_head + lse)
     names, held = (), 0
     for candidate, cost in zip(REMAT_CANDIDATES, costs):
-        if held + cfg.n_layers * cost > budget:
+        if held + applications * cost > budget:
             break
-        names, held = names + candidate, held + cfg.n_layers * cost
+        names, held = names + candidate, held + applications * cost
     return names, held, budget
 
 
@@ -868,6 +909,13 @@ def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     constant via closure. ``dropout_rng``: training-time dropout when
     ``cfg.dropout_rate > 0`` — omit for deterministic eval.
 
+    A looped model (``cfg.n_loops > 1``) runs the stack that many times over
+    the same ``params["blocks"]``, applies the final norm after each pass and
+    feeds the normed state to the next: -> (exits (n_loops, B, T, D), every
+    pass's normed state, aux_sum over all passes). The passes are an outer
+    ``lax.scan`` that closes over the weights: no copy of them a pass, and
+    their gradient is the backward scan's running sum over passes.
+
     Under ``cfg.remat`` the backward pass of a layer recomputes its forward
     pass but for the named values ``_remat_names`` finds room to keep."""
     block_fn = functools.partial(_block, cfg=cfg, mesh=mesh)
@@ -880,24 +928,39 @@ def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
                 *names) if names else None)
     L = cfg.n_layers
 
-    def scan_body(carry, xs):
-        h, aux_sum = carry
-        layer_params, li = xs
-        rng = (None if dropout_rng is None
-               else jax.random.fold_in(dropout_rng, li))
-        h, aux = block_fn(h, layer_params, attn_bias=attn_bias,
-                          dropout_rng=rng)
-        return (h, aux_sum + aux), None
+    def stack(h, aux_sum, dropout_rng):
+        def scan_body(carry, xs):
+            h, aux_sum = carry
+            layer_params, li = xs
+            rng = (None if dropout_rng is None
+                   else jax.random.fold_in(dropout_rng, li))
+            h, aux = block_fn(h, layer_params, attn_bias=attn_bias,
+                              dropout_rng=rng)
+            return (h, aux_sum + aux), None
 
-    (h, aux_sum), _ = jax.lax.scan(
-        scan_body, (h, jnp.zeros((2,), jnp.float32)),
-        (params["blocks"], jnp.arange(L)))
-    return h, aux_sum
+        (h, aux_sum), _ = jax.lax.scan(
+            scan_body, (h, aux_sum), (params["blocks"], jnp.arange(L)))
+        return h, aux_sum
+
+    no_aux = jnp.zeros((2,), jnp.float32)
+    if cfg.n_loops == 1:
+        return stack(h, no_aux, dropout_rng)
+
+    def one_pass(carry, t):
+        h, aux_sum = stack(*carry, None if dropout_rng is None
+                           else jax.random.fold_in(dropout_rng, L + t))
+        h = _norm(h, params["lnf_scale"], params["lnf_bias"], cfg)
+        return (h, aux_sum), h
+
+    (_, aux_sum), exits = jax.lax.scan(one_pass, (h, no_aux),
+                                       jnp.arange(cfg.n_loops))
+    return exits, aux_sum
 
 
 def forward_hidden(params, tokens, cfg: TransformerConfig,
                    mesh: Optional[Mesh] = None, dropout_rng=None):
-    """tokens (B, T) int32 -> (hidden (B, T, D), aux) before the LM head."""
+    """tokens (B, T) int32 -> (hidden (B, T, D), aux) before the LM head;
+    a looped model's hidden is its (n_loops, B, T, D) exit states."""
     h = embed_tokens(params, tokens, cfg)
     h = _constrain(h, mesh, "dp", "sp", None)
     return encode(params, h, cfg, mesh, dropout_rng=dropout_rng)
@@ -905,9 +968,14 @@ def forward_hidden(params, tokens, cfg: TransformerConfig,
 
 def forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             dropout_rng=None):
-    """tokens (B, T) int32 -> logits (B, T, V)."""
+    """tokens (B, T) int32 -> logits (B, T, V); a looped model's logits at
+    every exit, (n_loops, B, T, V)."""
     h, aux_sum = forward_hidden(params, tokens, cfg, mesh,
                                 dropout_rng=dropout_rng)
+    if cfg.n_loops > 1:
+        n, B, T, D = h.shape
+        logits = lm_head(params, h.reshape(n * B, T, D), cfg)
+        return logits.reshape(n, B, T, -1), aux_sum
     return lm_head(params, h, cfg), aux_sum
 
 
@@ -948,32 +1016,105 @@ def aux_weights(aux_weight=0.01):
     return jnp.array([aux_weight, Z_LOSS_WEIGHT], jnp.float32)
 
 
+def _fused_head_nll(params, h, targets, cfg: TransformerConfig):
+    """NLL of every row of hidden ``h`` (..., D) against ``targets`` (...)
+    through the vocabulary head by the fused linear+CE kernel -> (N,): the
+    (N, V) logits never exist in HBM, and both weight orientations are
+    kernel-native (no vocab-sized transpose): tied configs stream the (V, D)
+    embedding, untied the (D, V) head."""
+    from ..kernels.fused_ce import fused_linear_nll
+    if cfg.tied_head:
+        w, layout = params["embed"].astype(h.dtype), "vd"
+    else:
+        w, layout = params["head"].astype(h.dtype), "dv"
+    V = w.shape[0] if layout == "vd" else w.shape[1]
+    return fused_linear_nll(h.reshape(-1, h.shape[-1]), w,
+                            jnp.zeros((V,), jnp.float32),
+                            targets.reshape(-1), w_layout=layout)
+
+
+def _exit_log_q(params, exits):
+    """Exit states (n_loops, ..., D) -> log q (n_loops, ...), float32: the
+    gate's stop probability at exit t is sigmoid(w . h_t + b), q(t) = stop_t
+    x prod_{j<t} (1 - stop_j), and the last exit takes what is left, so that
+    q sums to 1 over exits (its own gate output is not read)."""
+    z = jnp.einsum("n...d,d->n...", exits.astype(jnp.float32),
+                   params["exit_gate_w"].astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST) + params["exit_gate_b"]
+    go = jax.nn.log_sigmoid(-z)                    # log (1 - stop_t)
+    passed = jnp.cumsum(go, 0) - go                # log prod_{j<t} (1 - stop_j)
+    return jnp.concatenate(
+        [(passed + jax.nn.log_sigmoid(z))[:-1], passed[-1:]], 0)
+
+
+def _exit_loss(params, exits, aux, targets, cfg: TransformerConfig, mesh,
+               aux_weight):
+    """``exit_loss_terms`` from the exit states on."""
+    from ..kernels.fused_ce import should_fuse
+    n, B, T, D = exits.shape
+    with jax.named_scope(SCOPE_EXIT):
+        log_q = _exit_log_q(params, exits)
+        q = jnp.exp(log_q)
+        if should_fuse(cfg.fused_lm_ce, mesh):
+            nll = _fused_head_nll(params, exits, jnp.tile(targets, (n, 1)),
+                                  cfg)
+        else:
+            logp = jax.nn.log_softmax(
+                lm_head(params, exits.reshape(n * B, T, D), cfg), -1)
+            nll = -jnp.take_along_axis(
+                logp, jnp.tile(targets, (n, 1))[..., None], -1)
+        nll = nll.reshape(n, B, T)
+        per = jnp.sum(q * nll + EXIT_ENTROPY_WEIGHT * q * log_q, 0)
+        loss = jnp.mean(per) + aux_weights(aux_weight) @ aux
+    return loss, {"nll": nll, "q": q, "log_q": log_q, "exits": exits}
+
+
+def exit_loss_terms(params, tokens, targets, cfg: TransformerConfig,
+                    mesh=None, aux_weight=0.01, dropout_rng=None):
+    """A looped model's training loss -> (loss, {"nll" (n_loops, B, T)
+    next-token NLL at every exit, "q" (n_loops, B, T) the exit distribution
+    and "log_q" its logarithm, "exits" (n_loops, B, T, D)}). The expected
+    loss over exit steps less an entropy bonus (Ouro stage I): mean over
+    tokens of sum_t q(t) NLL_t - ``EXIT_ENTROPY_WEIGHT`` x H(q), plus the MoE
+    terms of ``loss_fn``. The n_loops head passes are ONE call of the fused
+    kernel on the stacked rows (four calls of a pass's rows take the v5e the
+    same 548.7 ms a step; PERF.md, PR 29); gate, q, entropy and the
+    weighting are float32."""
+    exits, aux = forward_hidden(params, tokens, cfg, mesh,
+                                dropout_rng=dropout_rng)
+    return _exit_loss(params, exits, aux, targets, cfg, mesh, aux_weight)
+
+
+def exit_stats(params, tokens, cfg: TransformerConfig):
+    """Where a looped model's gate would leave ``tokens`` (B, T): a pure
+    function beside the step, for counters and checks (no mesh).
+    ``q_mean`` (n_loops,) the mean exit probability of each exit over the
+    tokens, ``expected_exit_step`` sum_t t x q_mean(t), exits counted from
+    1."""
+    if cfg.n_loops == 1:
+        raise ValueError("exit_stats: n_loops = 1, a model with one exit")
+    exits, _ = forward_hidden(params, tokens, cfg)
+    q_mean = jnp.mean(jnp.exp(_exit_log_q(params, exits)), (1, 2))
+    return {"q_mean": q_mean, "expected_exit_step":
+            jnp.sum(q_mean * jnp.arange(1, cfg.n_loops + 1))}
+
+
 def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
             aux_weight=0.01, dropout_rng=None):
     """Next-token cross-entropy + ``aux_weight`` x the MoE balance loss +
     ``Z_LOSS_WEIGHT`` x the router z-loss, both summed over layers (zero
-    for dense blocks)."""
+    for dense blocks). A looped model (``cfg.n_loops > 1``) takes the
+    expected loss over its exits instead (``exit_loss_terms``)."""
     from ..kernels.fused_ce import should_fuse
+    if cfg.n_loops > 1:
+        return exit_loss_terms(params, tokens, targets, cfg, mesh, aux_weight,
+                               dropout_rng)[0]
     if should_fuse(cfg.fused_lm_ce, mesh):
-        # fused linear+CE: the (B*T, V) logits never exist in HBM; the
-        # head keeps its native (D, V) orientation (no transpose copy)
-        from ..kernels.fused_ce import fused_linear_nll
         h, aux = forward_hidden(params, tokens, cfg, mesh,
                                 dropout_rng=dropout_rng)
         if not cfg.post_ln:
             h = _norm(h, params["lnf_scale"], params["lnf_bias"], cfg)
-        B, T, D = h.shape
-        # both weight orientations are kernel-native (no vocab-sized
-        # transpose): tied configs stream the (V, D) embedding, untied the
-        # (D, V) head
-        if cfg.tied_head:
-            w, layout = params["embed"].astype(h.dtype), "vd"
-        else:
-            w, layout = params["head"].astype(h.dtype), "dv"
-        V = w.shape[0] if layout == "vd" else w.shape[1]
-        per = fused_linear_nll(h.reshape(B * T, D), w,
-                               jnp.zeros((V,), jnp.float32),
-                               targets.reshape(-1), w_layout=layout)
+        per = _fused_head_nll(params, h, targets, cfg)
         return jnp.mean(per) + aux_weights(aux_weight) @ aux
     logits, aux = forward(params, tokens, cfg, mesh, dropout_rng=dropout_rng)
     return nll_loss(logits, targets) + aux_weights(aux_weight) @ aux

@@ -60,6 +60,11 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
     off). Each layer folds in its GLOBAL index, so key(mb, layer)
     matches the non-pipelined trunk's grad-accumulation schedule
     (make_train_step: fold_in(rng, mi) then encode's fold_in(·, li))."""
+    if cfg.n_loops > 1:
+        raise NotImplementedError(
+            f"n_loops={cfg.n_loops}: the pipeline schedules run the stack "
+            "once; a stage boundary inside a loop is undefined")
+
     def stage_fn(h, stage_blocks, stage, rng_mb):
         block = functools.partial(tfm._block, cfg=cfg, mesh=None)
         if cfg.remat:

@@ -56,6 +56,11 @@ SCOPE_MOE_EXPERTS = "hetu_moe_experts"    # grouped matmuls + activation
 SCOPE_MOE_COMBINE = "hetu_moe_combine"    # un-permute, weight, sum over k
 MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
               SCOPE_MOE_COMBINE)
+# a looped model's exit head (transformer.loss_fn at n_loops > 1), nested
+# under SCOPE_FWD: the exit gate, the n_loops head passes, the exit
+# distribution q and its entropy. The counter beside it is the pure function
+# `transformer.exit_stats` (mean q(t) an exit, the expected exit step)
+SCOPE_EXIT = "hetu_exit"
 # what the trunk's `jax.checkpoint` may keep of a layer's forward pass
 # (`jax.ad_checkpoint.checkpoint_name`; the identity outside a checkpoint).
 # Each name sits where the value is made; `transformer._remat_names` admits

@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from hetu_tpu.models import bert, hf_olmoe
+from hetu_tpu.models import bert, hf_olmoe, hf_ouro
 from hetu_tpu.models import transformer as tfm
 from hetu_tpu.parallel import mesh as meshlib
 from hetu_tpu.telemetry.tracing import (REMAT_ATTN_LSE, REMAT_ATTN_O,
@@ -46,6 +46,21 @@ def _olmoe():
     return cfg, params
 
 
+OURO_JSON = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                         "configs", "ouro-2.6b", "config.json")
+
+
+def _ouro(**overrides):
+    """The cell ouro-2.6b.pretrain-seq4096-b1: 6 layers applied 4 times."""
+    with open(OURO_JSON) as f:
+        cfg = hf_ouro.config_from_hf(json.load(f), dtype=jnp.bfloat16,
+                                     attn_impl="flash")
+    assert (cfg.n_layers, cfg.n_loops, cfg.sandwich_norm) == (6, 4, True)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    return dataclasses.replace(cfg, **overrides), params
+
+
 def _rule(model, batch, seq, dp, limit_gib, bias=True):
     cfg, params = model()
     mesh = (meshlib.make_mesh(dp=dp, devices=jax.devices()[:dp])
@@ -67,6 +82,10 @@ def _rule(model, batch, seq, dp, limit_gib, bias=True):
     pytest.param(_bert, 128, 512, 1, 15.75, ALL, (3.3, 3.5),
                  id="bert-seq512-v5e-limit"),
     pytest.param(_olmoe, 8, 4096, 1, 16, (), (0, 0), id="olmoe-seq4096"),
+    # between the two: 7.6 GiB of state, 24 block applications of 16 MiB
+    pytest.param(_ouro, 1, 4096, 1, 15.75, ALL, (0.75, 0.77),
+                 id="ouro-seq4096-b1-v5e-limit"),
+    pytest.param(_ouro, 1, 4096, 1, None, (), (0, 0), id="ouro-no-limit"),
     pytest.param(_bert, 128, 512, 1, None, (), (0, 0), id="bert-no-limit"),
     pytest.param(_olmoe, 8, 4096, 1, None, (), (0, 0), id="olmoe-no-limit"),
 ])
@@ -82,6 +101,27 @@ def test_remat_names_by_bytes(model, batch, seq, dp, limit_gib, names,
         assert budget < -1.5 * GiB
     if names and limit_gib:
         assert budget - held > 1.5 * GiB
+
+
+def test_remat_names_count_every_application_of_a_looped_model():
+    """n_layers x n_loops block applications a step: the stack of layer
+    inputs and each kept name, against the same weights run once."""
+    act = 1 * 4096 * 2048 * 2                # one (B, T, D) bf16 activation
+    lse = 4096 * 16 * 4
+    names4, held4, budget4 = _rule(_ouro, 1, 4096, 1, 15.75, bias=False)
+    names1, held1, budget1 = _rule(functools.partial(_ouro, n_loops=1),
+                                   1, 4096, 1, 15.75, bias=False)
+    assert names4 == names1 == ALL
+    assert held1 == 6 * (act + act + lse)
+    assert held4 == 24 * (act + act + lse) == 4 * held1
+    # the same state (the params handed in are the looped model's): the
+    # budgets differ by the 18 more layer inputs the scans keep
+    assert budget1 - budget4 == 18 * act
+    # a limit at which one pass keeps everything and four passes do not
+    for limit_gib, looped, once in ((10, ALL[:2], ALL), (9.5, (), ALL)):
+        assert _rule(_ouro, 1, 4096, 1, limit_gib, bias=False)[0] == looped
+        assert _rule(functools.partial(_ouro, n_loops=1), 1, 4096, 1,
+                     limit_gib, bias=False)[0] == once
 
 
 PREFIXES = [(), ALL[:2], ALL]
