@@ -1,5 +1,5 @@
-"""The three flash kernels compiled for a v5e that is described, not
-attached (the TPU's compiler is installed here): what Mosaic refuses at the
+"""The three flash kernels and the three of the fused CE compiled for a v5e
+that is described, not attached (the TPU's compiler is installed here): what Mosaic refuses at the
 real shapes — a misaligned slice, too much VMEM, a transpose it does not
 take — fails here and costs no chip time. Nothing runs, so nothing here is a
 result or a time.
@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from hetu_tpu.kernels import flash_attention as fa
+from hetu_tpu.kernels import fused_ce as fc
 
 
 @pytest.fixture(scope="module")
@@ -68,9 +69,10 @@ def _kernel_calls(text):
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
-def _count_by_name(calls):
+def _count_by_name(calls, names=(fa.FLASH_FWD, fa.FLASH_BWD_DQ,
+                                 fa.FLASH_BWD_DKV)):
     return {name: sum(name in c.split("=")[0] for c in calls)
-            for name in (fa.FLASH_FWD, fa.FLASH_BWD_DQ, fa.FLASH_BWD_DKV)}
+            for name in names}
 
 
 @pytest.mark.parametrize("shape,dtype,causal,bias,fused", SHAPES)
@@ -106,6 +108,42 @@ def test_flash_compiles_for_v5e(one_chip, no_compile_cache, shape, dtype,
     calls = _kernel_calls(text)
     assert len(calls) == 3, text
     assert set(_count_by_name(calls).values()) == {1}, calls
+
+
+# (rows, vocabulary, width) of the calls the benchmark's cells make, bf16
+CE_SHAPES = [
+    pytest.param(8 * 4096, 50304, 2048, id="olmoe-1b-7b.pretrain-seq4096"),
+    pytest.param(4 * 4096, 49152, 2048, id="ouro-2.6b.pretrain-seq4096-b1"),
+    pytest.param(128 * 80, 30522, 768, id="bert-base.pretrain-seq512"),
+    pytest.param(512 * 20, 30522, 768, id="bert-base.pretrain-seq128"),
+]
+
+
+@pytest.mark.parametrize("layout", ["dv", "vd"])
+@pytest.mark.parametrize("n,v,d", CE_SHAPES)
+def test_fused_ce_compiles_for_v5e(one_chip, no_compile_cache, monkeypatch,
+                                   n, v, d, layout):
+    """Value and the three gradients at the blocks `_choose_blocks` picks,
+    in both weight orientations (the decoders send `dv`, BERT `vd`): three
+    Mosaic custom calls under the kernels' names, within the
+    `vmem_limit_bytes` the calls ask for."""
+    monkeypatch.setattr(fc, "_on_tpu", lambda: True)   # not interpret mode
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(h, w, b, t):
+        return jnp.sum(fc.fused_linear_nll(h, w, b, t, w_layout=layout))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        arr((n, d), jnp.bfloat16),
+        arr((d, v) if layout == "dv" else (v, d), jnp.bfloat16),
+        arr((v,), jnp.float32), arr((n,), jnp.int32)).compile().as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 3, text
+    names = (fc.FUSED_CE_FWD, fc.FUSED_CE_BWD_DH, fc.FUSED_CE_BWD_DW)
+    # "fused_ce_bwd_dw" and "_dh" do not contain "fused_ce_fwd" or each other
+    assert set(_count_by_name(calls, names).values()) == {1}, calls
 
 
 # ---------------------------------------------------------------------------

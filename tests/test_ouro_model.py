@@ -220,10 +220,12 @@ def test_a_block_weight_gradient_is_the_sum_over_passes_of_the_untied_model(
 
 # the lowered text of value_and_grad(loss) at the parent commit (251f49b,
 # jax 0.9.0), made by this very function there: a model with one exit and no
-# sandwich norm runs the program it ran before the loop existed
+# sandwich norm runs the program it ran before the loop existed.
+# "fused-small" was made again in PR 30, which changed the fused CE's
+# kernels on purpose (before: a5899be7c4b2d5d0, 2883 lines).
 GOLDEN = {"bert-small": ("8a382cdc3978a109", 1237),
           "olmoe-small": ("a29bf60f74c5cd33", 1733),
-          "fused-small": ("a5899be7c4b2d5d0", 2883)}
+          "fused-small": ("d9ea68b963874d0f", 2902)}
 
 
 def _lowered_digest(which):
