@@ -169,6 +169,11 @@ def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
     assert cfg.n_loops == 1 and not cfg.sandwich_norm, (
         f"decode does not mirror n_loops={cfg.n_loops} (a cache entry a pass "
         f"and layer) or sandwich_norm={cfg.sandwich_norm} (_decode_layer)")
+    assert "mamba" not in cfg.layer_types, (
+        f"decode has no recurrent-state cache: layer_types={cfg.layer_types} "
+        "holds mamba layers (_decode_layer mirrors the attention block)")
+    assert cfg.multipliers == tfm.Multipliers(), (
+        f"decode does not mirror {cfg.multipliers} (_decode_layer)")
     assert cfg.causal, "decode is autoregressive — causal configs only"
     assert max_len <= cfg.max_seq_len
     assert 0 <= top_k <= cfg.vocab_size, (

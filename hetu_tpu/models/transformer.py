@@ -40,7 +40,8 @@ from ..telemetry.tracing import (REMAT_ATTN_O, REMAT_CANDIDATES, REMAT_X1,
                                  REMAT_X2, SCOPE_EXIT, SCOPE_FWD,
                                  SCOPE_MOE_COMBINE, SCOPE_MOE_DISPATCH,
                                  SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE,
-                                 SCOPE_OPT, scoped)
+                                 SCOPE_OPT, SCOPE_SSM_CONV, SCOPE_SSM_GATE,
+                                 SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, scoped)
 
 _log = logging.getLogger(__name__)
 
@@ -54,6 +55,39 @@ EXIT_ENTROPY_WEIGHT = 0.05
 
 class MoEConfigError(ValueError):
     """A MoE setting the chosen layout does not implement."""
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """The six sizes of a Mamba-2 mixer (Dao & Gu 2024, arXiv:2405.21060;
+    ``_mamba``): ``n_heads`` heads of ``head_dim`` channels, each head a
+    (head_dim, d_state) state; B and C are shared by the heads of a group."""
+    n_heads: int = 64
+    head_dim: int = 64
+    d_state: int = 128
+    n_groups: int = 1
+    d_conv: int = 4             # width of the causal depthwise convolution
+    chunk: int = 256            # positions a chunk of the SSD form
+
+    @property
+    def d_inner(self):
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self):
+        """Channels the convolution runs over: [x | B | C]."""
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """Granite's four scalars (``models/hf_granite.py``); the defaults are
+    neutral and leave the program what it is without them."""
+    embedding: float = 1.0      # on the token embeddings
+    residual: float = 1.0       # on each sublayer's output, before its add
+    attention: Optional[float] = None   # the softmax scale; None =
+                                        # 1/sqrt(head_dim)
+    logits: float = 1.0         # the logits are DIVIDED by it
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,8 +165,25 @@ class TransformerConfig:
     sandwich_norm: bool = False  # a norm after each sublayer too, before
                                  # the residual add (``ln1_post_*`` /
                                  # ``ln2_post_*``); pre-LN only
+    # Hybrid stacks (models/hf_granite.py sets all three):
+    layer_types: tuple = ()     # a kind of ``_KINDS`` a layer ("attention",
+                                # "mamba"); () = ``n_layers`` of attention.
+                                # ``encode`` scans each run of one kind;
+                                # ``params["blocks"]`` is the stacked dict
+                                # of a stack with one run, else a tuple of
+                                # them, one a run (``layer_runs``)
+    ssm: Optional[SSMConfig] = None     # the "mamba" layers' sizes
+    multipliers: Multipliers = Multipliers()
 
     def __post_init__(self):
+        if self.layer_types:
+            unknown = set(self.layer_types) - set(_KINDS)
+            if unknown or len(self.layer_types) != self.n_layers or (
+                    "mamba" in self.layer_types and (
+                        self.ssm is None or self.post_ln)):
+                raise ValueError(
+                    f"layer_types={self.layer_types}: {self.n_layers} kinds "
+                    f"of {sorted(_KINDS)} (mamba: pre-LN, with `ssm` sizes)")
         if self.n_experts and not (
                 1 <= self.n_experts_per_tok <= self.n_experts):
             raise MoEConfigError(
@@ -173,54 +224,128 @@ def init_trunk_params(rng, cfg: TransformerConfig):
     return _init_trunk(jax.random.split(rng, 12), cfg)
 
 
-def _init_trunk(ks, cfg: TransformerConfig):
-    L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
-    E = cfg.n_experts
-    norm = _init_normal
+def layer_runs(cfg: TransformerConfig):
+    """The stack as runs of one kind, in order: ((kind, layers), ...)."""
+    runs = []
+    for kind in cfg.layer_types or ("attention",) * cfg.n_layers:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return tuple((kind, n) for kind, n in runs)
 
+
+def run_blocks(cfg: TransformerConfig, blocks):
+    """``params["blocks"]`` (or a tree shaped like it) as a tuple with one
+    stacked entry a run of ``layer_runs``."""
+    return (blocks,) if len(layer_runs(cfg)) == 1 else tuple(blocks)
+
+
+def blocks_of_runs(runs):
+    """The inverse of ``run_blocks``: one stacked entry a run ->
+    ``params["blocks"]`` (the entry itself where the stack is one run)."""
+    return runs[0] if len(runs) == 1 else tuple(runs)
+
+
+def _init_attention(ks, cfg: TransformerConfig, n):
+    D = cfg.d_model
     qkv_width = (cfg.n_heads + 2 * cfg.kv_heads) * cfg.head_dim
-    blocks = {
-        "ln1_scale": jnp.ones((L, D), jnp.float32),
-        "ln1_bias": jnp.zeros((L, D), jnp.float32),
-        "wqkv": norm(ks[0], (L, D, qkv_width), 0.02),
-        "wo": norm(ks[1], (L, D, D), 0.02 / np.sqrt(2 * L)),
-        "ln2_scale": jnp.ones((L, D), jnp.float32),
-        "ln2_bias": jnp.zeros((L, D), jnp.float32),
-    }
+    p = {"wqkv": _init_normal(ks[0], (n, D, qkv_width), 0.02),
+         "wo": _init_normal(ks[1], (n, D, D),
+                            0.02 / np.sqrt(2 * cfg.n_layers))}
     if cfg.attn_proj_bias:
-        blocks["bqkv"] = jnp.zeros((L, qkv_width), jnp.float32)
-        blocks["bo"] = jnp.zeros((L, D), jnp.float32)
+        p["bqkv"] = jnp.zeros((n, qkv_width), jnp.float32)
+        p["bo"] = jnp.zeros((n, D), jnp.float32)
     if cfg.qk_norm:
-        blocks["q_norm"] = jnp.ones((L, cfg.n_heads * cfg.head_dim),
-                                    jnp.float32)
-        blocks["k_norm"] = jnp.ones((L, cfg.kv_heads * cfg.head_dim),
-                                    jnp.float32)
+        p["q_norm"] = jnp.ones((n, cfg.n_heads * cfg.head_dim), jnp.float32)
+        p["k_norm"] = jnp.ones((n, cfg.kv_heads * cfg.head_dim), jnp.float32)
+    return p
+
+
+def _attention_specs(cfg: TransformerConfig):
+    p = {"wqkv": P(None, None, "tp"), "wo": P(None, "tp", None)}
+    if cfg.attn_proj_bias:
+        p["bqkv"], p["bo"] = P(None, "tp"), P(None, None)
+    if cfg.qk_norm:
+        p["q_norm"] = p["k_norm"] = P(None, "tp")
+    return p
+
+
+def _init_mamba(ks, cfg: TransformerConfig, n):
+    """HF ``GraniteMoeHybridPreTrainedModel._init_weights``: A = 1..H a
+    head, dt_bias = D = 1, the gated norm's scale 1, the convolution's bias
+    0, normal(0.02) elsewhere."""
+    m, D = cfg.ssm, cfg.d_model
+    ones = lambda *shape: jnp.ones((n,) + shape, jnp.float32)
+    return {
+        "w_in": _init_normal(
+            ks[0], (n, D, m.d_inner + m.conv_dim + m.n_heads), 0.02),
+        "conv_w": _init_normal(ks[11], (n, m.d_conv, m.conv_dim), 0.02),
+        "conv_b": jnp.zeros((n, m.conv_dim), jnp.float32),
+        "dt_bias": ones(m.n_heads),
+        "A_log": jnp.tile(jnp.log(jnp.arange(1, m.n_heads + 1,
+                                             dtype=jnp.float32)), (n, 1)),
+        "D": ones(m.n_heads),
+        "ssm_norm": ones(m.d_inner),
+        "w_out": _init_normal(ks[1], (n, m.d_inner, D), 0.02)}
+
+
+def _mamba_specs(cfg: TransformerConfig):
+    """Replicated: the in-projection's columns are [z | x | B | C | dt] and
+    B, C are shared by the heads, so no column cut is a head cut (a ``tp``
+    axis computes the mixer on every device)."""
+    return {name: P() for name in ("w_in", "conv_w", "conv_b", "dt_bias",
+                                   "A_log", "D", "ssm_norm", "w_out")}
+
+
+def _init_run(ks, cfg: TransformerConfig, kind, n):
+    """``n`` stacked layers of one kind: the two norms, the kind's mixer
+    (``_KINDS``) and the MLP every kind shares."""
+    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    norm = _init_normal
+    blocks = {
+        "ln1_scale": jnp.ones((n, D), jnp.float32),
+        "ln1_bias": jnp.zeros((n, D), jnp.float32),
+        **_KINDS[kind].init(ks, cfg, n),
+        "ln2_scale": jnp.ones((n, D), jnp.float32),
+        "ln2_bias": jnp.zeros((n, D), jnp.float32),
+    }
     if cfg.sandwich_norm:
         for name in ("ln1_post", "ln2_post"):
-            blocks[name + "_scale"] = jnp.ones((L, D), jnp.float32)
-            blocks[name + "_bias"] = jnp.zeros((L, D), jnp.float32)
+            blocks[name + "_scale"] = jnp.ones((n, D), jnp.float32)
+            blocks[name + "_bias"] = jnp.zeros((n, D), jnp.float32)
     if cfg.mlp == "swiglu":
-        blocks["w3"] = norm(ks[8], (L, E, D, F) if E > 0 else (L, D, F),
+        blocks["w3"] = norm(ks[8], (n, E, D, F) if E > 0 else (n, D, F),
                             0.02)
+    out_scale = 0.02 / np.sqrt(2 * cfg.n_layers)
     if E > 0:
         blocks.update({
-            "router": norm(ks[2], (L, D, E), 0.02),
-            "w1": norm(ks[3], (L, E, D, F), 0.02),
-            "b1": jnp.zeros((L, E, F), jnp.float32),
-            "w2": norm(ks[4], (L, E, F, D), 0.02 / np.sqrt(2 * L)),
-            "b2": jnp.zeros((L, E, D), jnp.float32),
+            "router": norm(ks[2], (n, D, E), 0.02),
+            "w1": norm(ks[3], (n, E, D, F), 0.02),
+            "b1": jnp.zeros((n, E, F), jnp.float32),
+            "w2": norm(ks[4], (n, E, F, D), out_scale),
+            "b2": jnp.zeros((n, E, D), jnp.float32),
         })
     else:
         blocks.update({
-            "w1": norm(ks[3], (L, D, F), 0.02),
-            "b1": jnp.zeros((L, F), jnp.float32),
-            "w2": norm(ks[4], (L, F, D), 0.02 / np.sqrt(2 * L)),
-            "b2": jnp.zeros((L, D), jnp.float32),
+            "w1": norm(ks[3], (n, D, F), 0.02),
+            "b1": jnp.zeros((n, F), jnp.float32),
+            "w2": norm(ks[4], (n, F, D), out_scale),
+            "b2": jnp.zeros((n, D), jnp.float32),
         })
+    return blocks
+
+
+def _init_trunk(ks, cfg: TransformerConfig):
+    """Run 0 draws from ``ks`` as the homogeneous stack always has; a later
+    run from its own fold of the spare ``ks[10]``."""
     return {
-        "blocks": blocks,
-        "lnf_scale": jnp.ones((D,), jnp.float32),
-        "lnf_bias": jnp.zeros((D,), jnp.float32),
+        "blocks": blocks_of_runs([
+            _init_run(ks if r == 0 else jax.random.split(
+                jax.random.fold_in(ks[10], r), 12), cfg, kind, n)
+            for r, (kind, n) in enumerate(layer_runs(cfg))]),
+        "lnf_scale": jnp.ones((cfg.d_model,), jnp.float32),
+        "lnf_bias": jnp.zeros((cfg.d_model,), jnp.float32),
     }
 
 
@@ -239,24 +364,15 @@ def init_params(rng, cfg: TransformerConfig):
     return params
 
 
-def param_specs(cfg: TransformerConfig):
-    """PartitionSpecs: Megatron tp sharding; experts over ep; rest replicated
-    (dp/sp shard activations, not weights)."""
+def _run_specs(cfg: TransformerConfig, kind):
     moe = cfg.n_experts > 0
     blocks = {
         "ln1_scale": P(None, None),
         "ln1_bias": P(None, None),
-        "wqkv": P(None, None, "tp"),
-        "wo": P(None, "tp", None),
+        **_KINDS[kind].specs(cfg),
         "ln2_scale": P(None, None),
         "ln2_bias": P(None, None),
     }
-    if cfg.attn_proj_bias:
-        blocks["bqkv"] = P(None, "tp")
-        blocks["bo"] = P(None, None)
-    if cfg.qk_norm:
-        blocks["q_norm"] = P(None, "tp")
-        blocks["k_norm"] = P(None, "tp")
     if cfg.sandwich_norm:
         for name in ("ln1_post", "ln2_post"):
             blocks[name + "_scale"] = blocks[name + "_bias"] = P(None, None)
@@ -278,9 +394,16 @@ def param_specs(cfg: TransformerConfig):
             "w2": P(None, "tp", None),
             "b2": P(None, None),
         })
+    return blocks
+
+
+def param_specs(cfg: TransformerConfig):
+    """PartitionSpecs: Megatron tp sharding; experts over ep; rest replicated
+    (dp/sp shard activations, not weights)."""
     specs = {
         "embed": P(None, "tp"),
-        "blocks": blocks,
+        "blocks": blocks_of_runs(
+            [_run_specs(cfg, kind) for kind, _ in layer_runs(cfg)]),
         "lnf_scale": P(None),
         "lnf_bias": P(None),
     }
@@ -495,7 +618,8 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
         qkv = qkv + p["bqkv"].astype(h.dtype)
     tp = 1 if mesh is None else mesh.shape.get("tp", 1)
     if impl == "flash" and nkv == nh and tp == 1 and not (
-            cfg.qk_norm or cfg.rope):
+            cfg.qk_norm or cfg.rope
+            or cfg.multipliers.attention is not None):
         # nothing touches q or k on the way and the [q|k|v] columns lie on
         # one shard: the kernels read the projection where it stands
         out = _flash(qkv, cfg, mesh, _key_bias(attn_bias, B))
@@ -515,6 +639,11 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
             # rotate BEFORE any gqa broadcast (rope is per-kv-head)
             q = _rope(q, 0, cfg.rope_theta, hd)
             k = _rope(k, 0, cfg.rope_theta, hd)
+        if cfg.multipliers.attention is not None:
+            # every impl scales its scores by 1/sqrt(hd): q carries the
+            # rest (Granite: 1/64 at hd = 64, so q * 0.125, exact)
+            q = q * jnp.asarray(cfg.multipliers.attention * np.sqrt(hd),
+                                q.dtype)
         if nkv != nh:
             # grouped-query: broadcast each kv head to its query group;
             # every attention impl then sees matching head counts
@@ -530,6 +659,158 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     if cfg.attn_proj_bias:
         out = out + p["bo"].astype(h.dtype)
     return out
+
+
+def _ssm_dt(dt_raw, dt_bias):
+    """The step sizes, float32: softplus(raw + bias) a head, limit (0, inf)
+    as published (no clamp)."""
+    return jax.nn.softplus(dt_raw.astype(jnp.float32) + dt_bias)
+
+
+def _ssm_log_decay(dt, A_log):
+    """(B, c, Q, H) step sizes -> the log-decay dt * A a step, A = -exp(A_log)
+    a head, cumulated over each chunk's positions, float32."""
+    return jnp.cumsum(dt * -jnp.exp(A_log.astype(jnp.float32)), axis=2)
+
+
+def _ssm_states(Bm, xd, chunk_decay):
+    """-> (the state each chunk's own positions build, (B, c, G, R, P, N):
+    sum_s B_s (x) xd_s with xd = x dt decayed to the chunk's end, bf16 operands,
+    float32 sums; the state ENTERING each chunk, same shape: the recurrence
+    S_c = chunk_decay_c S_{c-1} + local_c over the chunks, float32)."""
+    local = jnp.einsum("bcsgn,bcsgrp->bcgrpn", Bm, xd,
+                       preferred_element_type=jnp.float32)
+
+    def carry_on(S, xs):
+        decay, new = xs
+        return decay[..., None, None] * S + new, S
+
+    _, entering = jax.lax.scan(
+        carry_on, jnp.zeros_like(local[:, 0]),
+        (jnp.moveaxis(chunk_decay, 1, 0), jnp.moveaxis(local, 1, 0)))
+    return local, jnp.moveaxis(entering, 0, 1)
+
+
+def _ssd_chunks(x, dt, A_log, Bm, Cm, chunk):
+    """x (B, T, H, P), dt (B, T, H), Bm/Cm (B, T, G, N) cut into T / chunk
+    chunks of Q positions, the heads as (G groups, R heads a group) -> (x (B,
+    c, Q, G, R, P), dt and the cumulative log-decay (B, c, Q, G, R), Bm, Cm
+    (B, c, Q, G, N))."""
+    B, T, H, P = x.shape
+    G, N = Bm.shape[2:]
+    if T % chunk:
+        raise ValueError(
+            f"seq_len={T} is not a multiple of the ssm chunk={chunk}: the "
+            "chunked scan pads nothing")
+    c, Q, R = T // chunk, chunk, H // G
+    dt = dt.reshape(B, c, Q, H)
+    acs = _ssm_log_decay(dt, A_log).reshape(B, c, Q, G, R)
+    return (x.reshape(B, c, Q, G, R, P), dt.reshape(B, c, Q, G, R), acs,
+            Bm.reshape(B, c, Q, G, N), Cm.reshape(B, c, Q, G, N))
+
+
+def _ssd_states(x, dt, acs, Bm):
+    """Parts 2 and 3 of ``_ssd`` on ``_ssd_chunks``' arrays -> (xd = x dt
+    decayed to its chunk's end, as the matmul reads it; the chunks' own
+    states; the states entering them)."""
+    last = acs[:, :, -1]                                  # (B, c, G, R)
+    xd = (x * (dt * jnp.exp(last[:, :, None] - acs))[..., None]
+          ).astype(x.dtype)
+    return (xd,) + _ssm_states(Bm, xd, jnp.exp(last))
+
+
+def _ssd(x, dt, A_log, Bm, Cm, chunk):
+    """The state-space recurrence of Mamba-2 in its chunked SSD form (Dao &
+    Gu 2024, section 6): per head, S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x)
+    B_t, y_t = S_t C_t. x (B, T, H, P), dt (B, T, H) float32, Bm/Cm (B, T, G,
+    N) -> y (B, T, H, P) float32, without the D skip.
+
+    Four parts: inside a chunk of Q positions the masked product
+    (C B^T . L) (x dt), L[l, s] the decay from s to l; the state each chunk
+    builds; the recurrence over the T/Q chunk states; the entering state's
+    part C S decayed to each position. dt, the cumulative log-decay, L and
+    the states are float32; the matmuls read bf16 (``x.dtype``) operands and
+    sum in float32. The backward pass is the compiler's transpose."""
+    shape = x.shape
+    x, dt, acs, Bm, Cm = _ssd_chunks(x, dt, A_log, Bm, Cm, chunk)
+    Q = x.shape[2]
+    # 1. inside a chunk
+    a = jnp.moveaxis(acs, 2, -1)                          # (B, c, G, R, Q)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    L = jnp.exp(jnp.where(causal, a[..., :, None] - a[..., None, :],
+                          -jnp.inf))
+    CB = jnp.einsum("bclgn,bcsgn->bcgls", Cm, Bm,
+                    preferred_element_type=jnp.float32)
+    y = jnp.einsum("bcgrls,bcsgrp->bclgrp",
+                   (CB[:, :, :, None] * L).astype(x.dtype),
+                   (x * dt[..., None]).astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    # 2. and 3. the chunks' states and the recurrence over them
+    _, _, entering = _ssd_states(x, dt, acs, Bm)
+    # 4. the entering state's part
+    y = y + jnp.exp(acs)[..., None] * jnp.einsum(
+        "bclgn,bcgrpn->bclgrp", Cm, entering.astype(x.dtype),
+        preferred_element_type=jnp.float32)
+    return y.reshape(shape)
+
+
+def _mamba_inputs(h, p, cfg: TransformerConfig):
+    """The mixer up to its scan: [z | xBC | dt] = h W_in, xBC through the
+    causal depthwise convolution, bias and SiLU -> (z (B, T, d_inner), x (B,
+    T, H, P), Bm, Cm (B, T, G, N), dt_raw (B, T, H) float32 as summed)."""
+    m = cfg.ssm
+    B, T, _ = h.shape
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        proj = jnp.einsum("btd,de->bte", h, p["w_in"].astype(h.dtype),
+                          preferred_element_type=jnp.float32)
+        z, xBC = jnp.split(proj[..., :-m.n_heads].astype(h.dtype),
+                           [m.d_inner], axis=-1)
+        dt_raw = proj[..., -m.n_heads:]
+    with jax.named_scope(SCOPE_SSM_CONV):
+        padded = jnp.pad(xBC, ((0, 0), (m.d_conv - 1, 0), (0, 0)))
+        w = p["conv_w"].astype(jnp.float32)
+        conv = sum(padded[:, k:k + T] * w[k] for k in range(m.d_conv))
+        xBC = jax.nn.silu(conv + p["conv_b"]).astype(h.dtype)
+    x, Bm, Cm = jnp.split(
+        xBC, [m.d_inner, m.d_inner + m.n_groups * m.d_state], axis=-1)
+    GN = (B, T, m.n_groups, m.d_state)
+    return (z, x.reshape(B, T, m.n_heads, m.head_dim), Bm.reshape(GN),
+            Cm.reshape(GN), dt_raw)
+
+
+def _mamba(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+    """The Mamba-2 mixer as HF ``GraniteMoeHybridMambaLayer`` computes it:
+    [z | xBC | dt] = h W_in; xBC = SiLU(causal depthwise conv(xBC) + b);
+    [x | B | C] = xBC; the recurrence (``_ssd``) + D x; RMSNorm(y SiLU(z))
+    over all channels; W_out. ``attn_bias`` (a padding mask) is refused: the
+    recurrence reads every position."""
+    if attn_bias is not None:
+        raise NotImplementedError("a mamba layer takes no attention bias")
+    z, x, Bm, Cm, dt_raw = _mamba_inputs(h, p, cfg)
+    with jax.named_scope(SCOPE_SSM_SCAN):
+        y = _ssd(x, _ssm_dt(dt_raw, p["dt_bias"]), p["A_log"], Bm, Cm,
+                 cfg.ssm.chunk)
+        y = y + p["D"][:, None] * x
+    with jax.named_scope(SCOPE_SSM_GATE):
+        y = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+        y = _rms_norm(y, p["ssm_norm"], cfg.ln_eps).astype(h.dtype)
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        return jnp.einsum("bte,ed->btd", y, p["w_out"].astype(h.dtype),
+                          preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Kind:
+    """What a kind of layer brings: its stacked weights, their
+    PartitionSpecs and its mixer ``(h, layer_params, cfg, mesh, attn_bias)
+    -> (B, T, D)``; ``_block`` is the one body around it."""
+    init: Any
+    specs: Any
+    mixer: Any
+
+
+_KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
+          "mamba": _Kind(_init_mamba, _mamba_specs, _mamba)}
 
 
 def _dense_mlp(h, p, cfg, mesh):
@@ -708,19 +989,28 @@ def _moe_mlp_capacity(h, p, cfg: TransformerConfig, mesh):
     return out.reshape(B, T, D), aux
 
 
+def _residual(out, cfg: TransformerConfig):
+    """A sublayer's output as its residual add takes it."""
+    r = cfg.multipliers.residual
+    # in float32: bf16(0.22) is 0.1 % off, the same way every layer
+    return out if r == 1.0 else (out.astype(jnp.float32) * r).astype(
+        out.dtype)
+
+
 def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
-                dropout_rng):
-    """The attention half of ``_block`` -> (h after the residual, the MLP
-    half's input)."""
+                dropout_rng, kind="attention"):
+    """The mixer half of ``_block`` (attention, or what ``kind`` names in
+    ``_KINDS``) -> (h after the residual, the MLP half's input)."""
     post = cfg.post_ln
     h = _constrain(h, mesh, "dp", "sp", None)
     attn_in = h if post else _norm(
         h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
-    attn_out = _attention(attn_in, layer_params, cfg, mesh, attn_bias)
+    attn_out = _KINDS[kind].mixer(attn_in, layer_params, cfg, mesh, attn_bias)
     if cfg.sandwich_norm:
         attn_out = _norm(attn_out, layer_params["ln1_post_scale"],
                          layer_params["ln1_post_bias"], cfg)
-    attn_out = _dropout(attn_out, cfg.dropout_rate, dropout_rng)
+    attn_out = _dropout(_residual(attn_out, cfg), cfg.dropout_rate,
+                        dropout_rng)
     h = checkpoint_name(h + attn_out, REMAT_X1)
     if post:
         h = _norm(h, layer_params["ln1_scale"],
@@ -732,20 +1022,22 @@ def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
 
 
 def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
-           dropout_rng=None):
-    """One transformer block -> (h, aux (2,) = the MoE block's [balance,
+           dropout_rng=None, kind="attention"):
+    """One block of any kind -> (h, aux (2,) = the MoE block's [balance,
     z] losses, zeros for a dense MLP). Pre-LN (flagship default): LN ->
     sublayer -> residual. Post-LN (``cfg.post_ln``, canonical BERT /
     original Transformer): sublayer -> residual -> LN, with ln1 after
     attention and ln2 after the MLP. Sandwich (``cfg.sandwich_norm``,
-    Ouro): LN -> sublayer -> LN -> residual.
+    Ouro): LN -> sublayer -> LN -> residual. The first sublayer is
+    ``kind``'s mixer (``_KINDS``: attention, or a Mamba-2 mixer); each
+    sublayer's output takes ``cfg.multipliers.residual`` before its add.
 
     LOCKSTEP CONTRACT: any new dialect knob added here must be mirrored
     in ``generate._decode_layer`` (the KV-cache form of this block) or
     decode silently diverges from training for that config."""
     k1, k2 = (None, None) if dropout_rng is None else jax.random.split(
         dropout_rng)
-    h, mlp_in = _block_attn(h, layer_params, cfg, mesh, attn_bias, k1)
+    h, mlp_in = _block_attn(h, layer_params, cfg, mesh, attn_bias, k1, kind)
     if cfg.n_experts > 0:
         out, aux = _moe_mlp(mlp_in, layer_params, cfg, mesh)
     else:
@@ -754,7 +1046,8 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     if cfg.sandwich_norm:
         out = _norm(out, layer_params["ln2_post_scale"],
                     layer_params["ln2_post_bias"], cfg)
-    h = checkpoint_name(h + _dropout(out, cfg.dropout_rate, k2), REMAT_X2)
+    h = checkpoint_name(
+        h + _dropout(_residual(out, cfg), cfg.dropout_rate, k2), REMAT_X2)
     if cfg.post_ln:
         h = _norm(h, layer_params["ln2_scale"],
                   layer_params["ln2_bias"], cfg)
@@ -765,10 +1058,20 @@ def embed_tokens(params, tokens, cfg: TransformerConfig):
     """(..., T) int32 -> (..., T, D) embeddings (+ learned positions,
     unless the dialect carries positions via rope)."""
     T = tokens.shape[-1]
-    h = params["embed"][tokens].astype(cfg.dtype)
+    h = params["embed"][tokens]
+    if cfg.multipliers.embedding != 1.0:
+        h = h * cfg.multipliers.embedding       # in the weights' float32
+    h = h.astype(cfg.dtype)
     if cfg.use_pos_emb:
         h = h + params["pos"][:T].astype(cfg.dtype)
     return h
+
+
+def _logit_scaled(h, cfg: TransformerConfig):
+    """The head's input rows over ``cfg.multipliers.logits``: the logits
+    divided by it, without a pass over them (Granite's 8 is exact)."""
+    d = cfg.multipliers.logits
+    return h if d == 1.0 else h * jnp.asarray(1.0 / d, h.dtype)
 
 
 def lm_head(params, h, cfg: TransformerConfig):
@@ -779,6 +1082,7 @@ def lm_head(params, h, cfg: TransformerConfig):
     token embedding itself (no transposed copy is materialized)."""
     if not cfg.post_ln and cfg.n_loops == 1:
         h = _norm(h, params["lnf_scale"], params["lnf_bias"], cfg)
+    h = _logit_scaled(h, cfg)
     if cfg.tied_head:
         return jnp.einsum("btd,vd->btv", h, params["embed"].astype(h.dtype),
                           preferred_element_type=jnp.float32)
@@ -827,8 +1131,9 @@ def _state_bytes(cfg: TransformerConfig, params, mesh):
 
 
 def _block_residual_bytes(cfg: TransformerConfig, mesh, h, blocks,
-                          attn_bias):
-    """Bytes that ONE block's backward pass reads from its forward pass: the
+                          attn_bias, kind="attention"):
+    """Bytes that ONE block of ``kind``, with one run's stacked weights
+    ``blocks``, has its backward pass read from its forward pass: the
     residuals of ``jax.vjp`` of ``_block`` itself, traced abstractly at the
     real shapes (nothing is compiled or run). That is the working set a
     layer's recomputation fills before its backward pass drains it, and it
@@ -837,7 +1142,8 @@ def _block_residual_bytes(cfg: TransformerConfig, mesh, h, blocks,
     reads high (BERT-base at 65,536 tokens: 3.96 GiB)."""
     def residuals(h, layer, attn_bias):
         return jax.vjp(
-            lambda h, layer: _block(h, layer, cfg, mesh, attn_bias),
+            lambda h, layer: _block(h, layer, cfg, mesh, attn_bias,
+                                    kind=kind),
             h, layer)[1]
 
     def shape(x, cut=0):
@@ -859,12 +1165,14 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     nothing is kept there).
 
     The candidates of ``REMAT_CANDIDATES`` are admitted in their order
-    while their bytes, times the block applications of a step (``n_layers``
-    x ``n_loops``: a looped model keeps every pass's), stay within the
-    budget: the limit less what the step holds whatever is kept
-    (``_state_bytes``, the stack of layer inputs the scans keep, one an
-    application, ``_block_residual_bytes`` of one block, ``_REMAT_MARGIN``).
-    A later candidate never gets in without the earlier ones."""
+    while their bytes, times the block applications of a step that write
+    them (``n_layers`` x ``n_loops``: a looped model keeps every pass's; o
+    and lse: the attention layers alone), stay within the budget: the limit
+    less what the step holds whatever is kept (``_state_bytes``, the stack of
+    layer inputs the scans keep, one an application,
+    ``_block_residual_bytes`` of one block, the largest among the kinds of
+    the stack, ``_REMAT_MARGIN``). A later candidate never gets in without
+    the earlier ones."""
     if bytes_limit is None:
         bytes_limit = _device_bytes_limit()
     if bytes_limit is None:
@@ -876,20 +1184,27 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     by_seq, by_head = act // sp, act // tp
     lse = B * T * cfg.n_heads * 4 // (dp * tp)
     applications = cfg.n_layers * cfg.n_loops
+    # one run's stacked weights stand for its kind's shapes
+    by_kind = {kind: blocks for (kind, _), blocks in zip(
+        layer_runs(cfg), run_blocks(cfg, params["blocks"]))}
     budget = int(
         bytes_limit * (1 - _REMAT_MARGIN) - _state_bytes(cfg, params, mesh)
         - applications * by_seq
         # activations carry the batch: dp cuts them, and maybe more
-        - _block_residual_bytes(cfg, mesh, h, params["blocks"], attn_bias)
-        // dp)
-    # {x1, x2} (pre-LN: x2 is the block's output, which the scan keeps
-    # anyway), then {o, lse}: a layer's bytes of each
-    costs = (by_seq * (2 if cfg.post_ln else 1), by_head + lse)
+        - max(_block_residual_bytes(cfg, mesh, h, blocks, attn_bias, kind)
+              for kind, blocks in by_kind.items()) // dp)
+    attention = cfg.n_loops * sum(
+        n for kind, n in layer_runs(cfg) if kind == "attention")
+    # {x1, x2} of every block (pre-LN: x2 is the block's output, which the
+    # scan keeps anyway), then {o, lse} of an attention block: the bytes of
+    # each, times the applications that write them
+    costs = (applications * by_seq * (2 if cfg.post_ln else 1),
+             attention * (by_head + lse))
     names, held = (), 0
     for candidate, cost in zip(REMAT_CANDIDATES, costs):
-        if held + applications * cost > budget:
+        if held + cost > budget:
             break
-        names, held = names + candidate, held + applications * cost
+        names, held = names + candidate, held + cost
     return names, held, budget
 
 
@@ -918,28 +1233,37 @@ def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
 
     Under ``cfg.remat`` the backward pass of a layer recomputes its forward
     pass but for the named values ``_remat_names`` finds room to keep."""
-    block_fn = functools.partial(_block, cfg=cfg, mesh=mesh)
+    runs = layer_runs(cfg)
+    block_fns = {kind: functools.partial(_block, cfg=cfg, mesh=mesh,
+                                         kind=kind) for kind, _ in runs}
     if cfg.remat:
         names, held, budget = _remat_names(cfg, params, h, mesh, attn_bias)
         _log_remat(names, held, budget)
         # no name admitted: the bare checkpoint, the very program it was
-        block_fn = jax.checkpoint(
-            block_fn, policy=jax.checkpoint_policies.save_only_these_names(
-                *names) if names else None)
+        policy = (jax.checkpoint_policies.save_only_these_names(*names)
+                  if names else None)
+        block_fns = {kind: jax.checkpoint(fn, policy=policy)
+                     for kind, fn in block_fns.items()}
     L = cfg.n_layers
 
     def stack(h, aux_sum, dropout_rng):
-        def scan_body(carry, xs):
-            h, aux_sum = carry
-            layer_params, li = xs
-            rng = (None if dropout_rng is None
-                   else jax.random.fold_in(dropout_rng, li))
-            h, aux = block_fn(h, layer_params, attn_bias=attn_bias,
-                              dropout_rng=rng)
-            return (h, aux_sum + aux), None
+        """Every run of one kind is a scan over that run's stacked weights;
+        a layer's index counts through the whole stack."""
+        first = 0
+        for (kind, n), blocks in zip(runs, run_blocks(cfg, params["blocks"])):
+            def scan_body(carry, xs, block_fn=block_fns[kind]):
+                h, aux_sum = carry
+                layer_params, li = xs
+                rng = (None if dropout_rng is None
+                       else jax.random.fold_in(dropout_rng, li))
+                h, aux = block_fn(h, layer_params, attn_bias=attn_bias,
+                                  dropout_rng=rng)
+                return (h, aux_sum + aux), None
 
-        (h, aux_sum), _ = jax.lax.scan(
-            scan_body, (h, aux_sum), (params["blocks"], jnp.arange(L)))
+            (h, aux_sum), _ = jax.lax.scan(
+                scan_body, (h, aux_sum),
+                (blocks, jnp.arange(first, first + n)))
+            first += n
         return h, aux_sum
 
     no_aux = jnp.zeros((2,), jnp.float32)
@@ -1010,6 +1334,30 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig):
     return stats
 
 
+def ssm_scan_terms(params, tokens, cfg: TransformerConfig):
+    """The float32 parts of the FIRST mamba layer's scan on ``tokens`` (B,
+    T), with what each was computed from: a pure function beside the step,
+    for checks (no mesh). ``dt_raw`` (B, T, H) and ``dt`` = softplus(raw +
+    ``dt_bias``); ``log_decay`` (B, c, Q, G, R) the cumulative dt * A over a
+    chunk; ``B`` (B, c, Q, G, N) and ``xd`` (B, c, Q, G, R, P) the state
+    matmul's operands; ``local`` and ``entering`` (B, c, G, R, P, N) the
+    chunks' own states and the recurrence's; ``dt_bias``, ``A_log``."""
+    if layer_runs(cfg)[0][0] != "mamba":
+        raise ValueError("ssm_scan_terms: the stack's first layer is not a "
+                         f"mamba layer (layer_types={cfg.layer_types})")
+    p = jax.tree.map(lambda x: x[0], run_blocks(cfg, params["blocks"])[0])
+    h = embed_tokens(params, tokens, cfg)
+    _, x, Bm, Cm, dt_raw = _mamba_inputs(
+        _norm(h, p["ln1_scale"], p["ln1_bias"], cfg), p, cfg)
+    dt = _ssm_dt(dt_raw, p["dt_bias"])
+    x, dt_c, acs, Bm, _ = _ssd_chunks(x, dt, p["A_log"], Bm, Cm,
+                                      cfg.ssm.chunk)
+    xd, local, entering = _ssd_states(x, dt_c, acs, Bm)
+    return {"dt_raw": dt_raw, "dt": dt, "log_decay": acs, "B": Bm, "xd": xd,
+            "local": local, "entering": entering, "dt_bias": p["dt_bias"],
+            "A_log": p["A_log"]}
+
+
 def aux_weights(aux_weight=0.01):
     """Weights of ``encode``'s aux (2,): the balance loss takes the
     caller's ``aux_weight``, the router z-loss ``Z_LOSS_WEIGHT``."""
@@ -1028,6 +1376,7 @@ def _fused_head_nll(params, h, targets, cfg: TransformerConfig):
     else:
         w, layout = params["head"].astype(h.dtype), "dv"
     V = w.shape[0] if layout == "vd" else w.shape[1]
+    h = _logit_scaled(h, cfg)
     return fused_linear_nll(h.reshape(-1, h.shape[-1]), w,
                             jnp.zeros((V,), jnp.float32),
                             targets.reshape(-1), w_layout=layout)

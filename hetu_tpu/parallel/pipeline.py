@@ -64,6 +64,11 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
         raise NotImplementedError(
             f"n_loops={cfg.n_loops}: the pipeline schedules run the stack "
             "once; a stage boundary inside a loop is undefined")
+    if len(tfm.layer_runs(cfg)) > 1:
+        raise NotImplementedError(
+            f"layer_types={cfg.layer_types}: no stage rule for layers of "
+            "unequal kinds (mamba beside attention); the pipeline stacks "
+            "ONE kind of block a stage")
 
     def stage_fn(h, stage_blocks, stage, rng_mb):
         block = functools.partial(tfm._block, cfg=cfg, mesh=None)

@@ -61,6 +61,15 @@ MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
 # distribution q and its entropy. The counter beside it is the pure function
 # `transformer.exit_stats` (mean q(t) an exit, the expected exit step)
 SCOPE_EXIT = "hetu_exit"
+# the four parts of a Mamba-2 mixer (transformer._mamba), nested under
+# SCOPE_FWD like the MoE scopes; benchmark/reduce/ssm.py reads them
+SCOPE_SSM_PROJ = "hetu_ssm_proj"  # the in- and the out-projection
+SCOPE_SSM_CONV = "hetu_ssm_conv"  # causal depthwise convolution, bias, SiLU
+SCOPE_SSM_SCAN = "hetu_ssm_scan"  # from the convolution's output to the
+                                  # gate: dt, the log-decay, the chunked
+                                  # recurrence (`_ssd`), the D skip
+SCOPE_SSM_GATE = "hetu_ssm_gate"  # y SiLU(z) and its RMSNorm
+SSM_SCOPES = (SCOPE_SSM_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE)
 # what the trunk's `jax.checkpoint` may keep of a layer's forward pass
 # (`jax.ad_checkpoint.checkpoint_name`; the identity outside a checkpoint).
 # Each name sits where the value is made; `transformer._remat_names` admits
