@@ -8,10 +8,15 @@ only (block_q, block_k) tiles ever exist:
 - forward: a Pallas kernel — q/k/v tiles stream HBM->VMEM, scores hit the
   MXU, the running (max, sum) rescale keeps the softmax exact. Falls back to
   interpreter mode off-TPU so the same code runs in CPU-mesh tests.
-- backward: Pallas kernels both directions on TPU (a dq kernel over q blocks
-  and a fused dk+dv kernel over k blocks, each recomputing its probability
-  tile from (q, k, lse) — no (S,S) materialization); off-TPU, a blockwise
-  `lax.scan` recomputation in XLA serves as fallback and numerical oracle.
+- backward: Pallas kernels on TPU, each recomputing its probability tile
+  from (q, k, lse) — no (S,S) materialization. Which ones, the call's shapes
+  say (`_kernels_of`): a sequence that is ONE tile (up to 512 positions:
+  BERT) has nothing to sum over blocks and runs `flash_bwd`, which makes dq,
+  dk and dv from one rebuilt tile (five matmuls, one exp pass); a sequence
+  of several tiles (the decoders at 4,096 and 8,192) runs `flash_bwd_dq`
+  over q blocks and `flash_bwd_dkv` (dk+dv) over k blocks (seven matmuls,
+  two exp passes). Off-TPU, a blockwise `lax.scan` recomputation in XLA
+  serves as fallback and numerical oracle.
 
 Public entry: ``flash_attention_btd(qkv, n_heads, causal=True)`` on the
 projections' own layout, (batch, seq, heads * head_dim) in and out (``qkv``
@@ -40,11 +45,12 @@ from ..telemetry.tracing import REMAT_ATTN_LSE, REMAT_ATTN_O
 # the kernels' names in the device trace (docs/KERNELS.md): one constant a
 # pallas_call site, written as the call's `name=`
 FLASH_FWD = "flash_fwd"
+FLASH_BWD = "flash_bwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
 
-_NEG_INF = -1e30
 _LANES = 128
+_NEG_INF = -1e30
 
 # What one grid step may hold in VMEM, by `_vmem_bytes`' count: three
 # quarters of the 16 MiB Mosaic gives a kernel by default on a v5e. The
@@ -56,24 +62,60 @@ _VMEM_BUDGET = 12 * 1024 * 1024
 # half of the array), against the ~0.35 us a grid step costs whatever it
 # does. On the chip (PR 24) 2, 4 and 6 heads of 512 x 512 x 64 a step ran
 # the three kernels in 7.09, 6.92 and 6.74 ms a layer: flat beyond this.
+# Every kernel of a many-tile call, and the backward kernel of a one-tile
+# call, takes the fewest heads that carry it: alone on the chip (PR 33;
+# bf16, ms a call at 2 / 4 / 6 / 12 heads a step, the two passes that set
+# dq and dv into a fused gradient included) `flash_bwd` ran 512 rows of
+# 128 x 128 x 64 in 3.59 / 2.64 / 2.37 / 2.00, 256 rows of 256 x 256 x 64
+# in 3.14 / 2.59 / 2.43 / 2.27, and 128 rows of 512 x 512 x 64, where four
+# heads carry it, in 3.55 / 3.40 / 3.36 / 3.35.
 _STEP_FLOPS = 256e6
 _MAX_HEADS = 16     # the head loop of a grid step is unrolled
+# Lanes a grid step of a ONE-TILE call's forward takes at most where heads
+# are narrower than a lane tile (four heads of 64). Such heads share their
+# tiles: every other one is shifted along the lanes on its way in and its
+# o is stored under a mask, and unrolled past two tiles the step slows
+# down however little it carries (PR 33, ms a call at 2 / 4 / 6 / 12 heads
+# a step): 512 rows of 128 x 128 x 64 in 2.20 / 1.89 / 2.14 / 2.48 (three
+# arrays or one, with a key bias or none; 16 heads at 2 / 4 / 8 / 16: 1.45
+# / 1.26 / 1.48 / 1.80), 256 rows of 256 x 256 x 64 in 2.22 / 2.01 / 1.99
+# / 2.16, 128 rows of 512 x 512 x 64 flat (1.54 / 1.60 / 1.70 / 1.60).
+# Heads of whole lane tiles only gain by it (12 of 128 x 128 x 128 at 1 /
+# 2 / 4 / 6 / 12: 1.80 / 1.13 / 0.87 / 0.79 / 0.72), and so does the
+# backward kernel at either head size (above). Why, no profile here shows
+# (PERF.md section 7); a many-tile forward was not measured and keeps the
+# group of its backward kernels.
+_ONE_TILE_FWD_LANES = 2 * _LANES
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _vmem_bytes(s, d, itemsize, block_q, block_k, heads):
-    """VMEM one grid step of the hungriest of the three kernels holds:
-    two operands whole (k, v; or q, dO in `flash_bwd_dkv`) and at most
-    four blocks (k, v, dk, dv there), the lse and delta rows padded to
-    eight sublanes, all double-buffered, and five f32 arrays of the score
-    tile's size (s, p, dp, ds and one in flight) for the one head being
-    worked on."""
-    blocks = 2 * heads * d * itemsize * (2 * s + 4 * max(block_q, block_k))
-    rows = 2 * 2 * heads * 8 * s * 4
-    return blocks + rows + 5 * block_q * block_k * 4
+def _vmem_bytes(s, d, itemsize, block_q, block_k, heads, kernel):
+    """VMEM one grid step of `kernel` holds, `heads` heads a step: its
+    operand and result blocks (`heads * d` columns wide) and its rows of lse
+    and delta (padded to eight sublanes), all double-buffered, and the f32
+    arrays of the score tile's size of the ONE head being worked on.
+    - `flash_fwd`: k and v whole, a block of q and of o, a block of lse;
+      s, p and one tile in flight, and the head's accumulator.
+    - `flash_bwd` (the whole sequence one tile): q, k, v, dO, o in and dq,
+      dk, dv out, eight blocks of the whole sequence, and lse; s, p, dp, ds
+      and one in flight; the f32 product dO * O of every head of the step,
+      with the piece being split off it, for delta.
+    - `flash_bwd_dq` and `flash_bwd_dkv`, counted as the pair they run as,
+      by the hungrier of the two in each part: two operands whole (k, v;
+      or q, dO) and four blocks of the longer side (q, dO, o, dq; or k, v,
+      dk, dv), lse and delta whole, the same five tiles."""
+    cols = 2 * heads * d * itemsize     # a position of one operand block
+    rows = 2 * heads * 8 * 4            # a position of one statistics row
+    tile = block_q * block_k * 4
+    if kernel == FLASH_FWD:
+        return (cols * (2 * s + 2 * block_q) + rows * block_q + 3 * tile
+                + block_q * d * 4)
+    if kernel == FLASH_BWD:
+        return cols * 8 * s + rows * s + 5 * tile + 2 * s * heads * d * 4
+    return cols * (2 * s + 4 * max(block_q, block_k)) + 2 * rows * s + 5 * tile
 
 
 def _head_groups(heads, d):
@@ -89,19 +131,34 @@ def _head_groups(heads, d):
     return groups or [heads]
 
 
-def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
-    """-> (block_q, block_k, heads a grid step), from the call's shapes alone.
+def _kernels_of(s, block_q, block_k):
+    """The kernels a call runs: where the whole sequence is one tile
+    nothing is summed over blocks, and ONE backward kernel makes dq, dk and
+    dv from one rebuilt score tile; else dq sums over key blocks and dk, dv
+    over query blocks, a kernel each."""
+    if block_q == block_k == s:
+        return FLASH_FWD, FLASH_BWD
+    return FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV
 
-    `heads` is the head count: a grid step takes `g` consecutive heads of
-    one batch row, `g` one of `_head_groups`. A `block_q`/`block_k` the
-    caller passed is kept.
+
+def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
+    """-> (block_q, block_k, {kernel: heads a grid step}) for the kernels
+    the call will run (`_kernels_of`), from the call's shapes alone.
+
+    `heads` is the head count: a grid step of a kernel takes `g` consecutive
+    heads of one batch row, `g` one of `_head_groups`. A `block_q`/`block_k`
+    the caller passed is kept.
 
     Blocks: the largest of 512/256/128 that divides `s` (the whole sequence
     below 128), for causal calls too: on the chip a 512 x 512 tile that
     computes its masked half beat 256 x 256 tiles that skip more (PR 24).
-    Heads: the fewest that give a step `_STEP_FLOPS` of work. Both give
-    way, heads first, until `_vmem_bytes` fits `_VMEM_BUDGET`; the floor is
-    the smallest group and the smallest blocks."""
+    Heads: the fewest that give a step `_STEP_FLOPS` of work, kernel by
+    kernel in a one-tile call (its forward at most `_ONE_TILE_FWD_LANES`
+    of heads narrower than a lane tile); the kernels of a many-tile call
+    take one group, the hungriest's (`flash_bwd_dq` + `_dkv`). Blocks and
+    heads give way, heads first, until `_vmem_bytes` of every kernel fits
+    `_VMEM_BUDGET`; the floor is the smallest group and the smallest
+    blocks."""
     itemsize = jnp.dtype(dtype).itemsize
 
     def sizes(given):
@@ -109,18 +166,31 @@ def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
             return [min(given, s)]
         return [b for b in (512, 256, 128) if s % b == 0] or [s]
 
+    def fits(kernel, bq, bk, g):
+        return _vmem_bytes(s, d, itemsize, bq, bk, g, kernel) <= _VMEM_BUDGET
+
     picks = [(bq, bk) for bq in sizes(block_q) for bk in sizes(block_k)]
     picks.sort(key=lambda p: -p[0] * p[1])
     groups = _head_groups(heads, d)
     for bq, bk in picks:
+        kernels = _kernels_of(s, bq, bk)
         step = 4.0 * bq * s * d * (0.5 if causal else 1.0)
         want = next((g for g in groups if g * step >= _STEP_FLOPS),
                     groups[-1])
-        for g in reversed([g for g in groups if g <= want]):
-            if _vmem_bytes(s, d, itemsize, bq, bk, g) <= _VMEM_BUDGET:
-                return bq, bk, g
+        most = dict.fromkeys(kernels, want)
+        if FLASH_BWD in most and d % _LANES:
+            most[FLASH_FWD] = min(want, max(_ONE_TILE_FWD_LANES // d,
+                                            groups[0]))
+        chosen = {kernel: next((g for g in reversed(groups) if g <= limit
+                                and fits(kernel, bq, bk, g)), None)
+                  for kernel, limit in most.items()}
+        if None in chosen.values():
+            continue
+        if FLASH_BWD not in chosen:
+            chosen = dict.fromkeys(kernels, min(chosen.values()))
+        return bq, bk, chosen
     bq, bk = picks[-1]
-    return bq, bk, groups[0]
+    return bq, bk, dict.fromkeys(_kernels_of(s, bq, bk), groups[0])
 
 
 def _is_fused(qkv):
@@ -140,17 +210,18 @@ def _three(qkv):
 
 
 def _tiles_for(qkv, n_heads, causal, block_q, block_k):
-    """-> (qkv, block_q, block_k, heads a step, d). Blocks that do not
-    divide the sequence (only ones the caller passed can) raise. A fused
-    array whose head group is not whole lane tiles (three heads of 64)
-    cannot be indexed by column block: it is cut in three here."""
+    """-> (qkv, block_q, block_k, {kernel: heads a step}, d). Blocks that
+    do not divide the sequence (only ones the caller passed can) raise. A
+    fused array whose head group is not whole lane tiles (three heads of
+    64: then every kernel takes every head) cannot be indexed by column
+    block: it is cut in three here."""
     q = qkv if _is_fused(qkv) else qkv[0]
     s, d = q.shape[1], _width(qkv) // n_heads
     block_q, block_k, heads = _choose_tiles(s, d, q.dtype, causal, n_heads,
                                             block_q, block_k)
     if s % block_q or s % block_k:
         raise ValueError(f"seq_len {s} must divide blocks ({block_q},{block_k})")
-    if _is_fused(qkv) and (heads * d) % _LANES:
+    if _is_fused(qkv) and (heads[FLASH_FWD] * d) % _LANES:
         qkv = _three(qkv)
     return qkv, block_q, block_k, heads, d
 
@@ -162,6 +233,12 @@ def _dot(a, b):
 def _dot_nt(a, b):
     """a @ b.T on the MXU: operands as they come, f32 out."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a.T @ b on the MXU: the contraction runs down both operands' rows."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
 
@@ -242,7 +319,7 @@ def _optional_bias(kernel, n_before, use_bias):
 
 
 # ---------------------------------------------------------------------------
-# The tile program, common to the three kernels. q, k, v, o and their
+# The tile program, common to the four kernels. q, k, v, o and their
 # gradients lie in HBM as the projections write and read them: (batch, seq,
 # heads * d), a head's d columns side by side on the lane axis. A grid step
 # owns one batch row, `heads` consecutive heads (a block of heads * d
@@ -254,9 +331,9 @@ def _optional_bias(kernel, n_before, use_bias):
 # - per-row statistics (lse, delta) and the key bias live in HBM with the
 #   sequence on the lane axis, (batch*heads, 1, s) and (batch, 1, s). Where
 #   a kernel needs one as a column it transposes it once a step, never in
-#   the loop; `flash_bwd_dkv` works on the transposed score tile (keys down
-#   the sublanes) so that lse and delta broadcast as rows and P^T, dS^T
-#   feed dV and dK without a transposed matmul.
+#   the loop; `flash_bwd_dkv` and `flash_bwd` work on the transposed score
+#   tile (keys down the sublanes) so that lse and delta broadcast as rows
+#   and P^T, dS^T feed dV and dK without a transposed matmul.
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
@@ -264,9 +341,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
     # grid: (batch, head groups, q blocks); one q block, the whole k and v
     block_q = q_ref.shape[0]
     q_start = pl.program_id(2) * block_q
-    # causal: skip key blocks entirely above the diagonal
-    upper = (_causal_upper_kb(q_start, block_q, block_k) if causal
-             else k_ref.shape[0] // block_k)
+    # causal: skip key blocks entirely above the diagonal. A lone key block
+    # never is: one pass, written out, and not a loop to a bound read at
+    # run time (causal, four heads a step, ms a call on the chip, PR 33:
+    # 512 rows of 12 x 128 x 128 x 64 3.71 -> 1.85, 32 rows of 16 x 512 x
+    # 512 x 64 0.80 -> 0.54)
+    key_blocks = k_ref.shape[0] // block_k
+    upper = (_causal_upper_kb(q_start, block_q, block_k)
+             if causal and key_blocks > 1 else key_blocks)
 
     for g in range(q_ref.shape[1] // d):
         lanes = _block(g, d)
@@ -347,6 +429,7 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
     """-> o (batch, seq, heads * d), lse (batch * heads, 1, seq)."""
     qkv, block_q, block_k, heads, d = _tiles_for(qkv, n_heads, causal,
                                                  block_q, block_k)
+    heads = heads[FLASH_FWD]
     ops = _operands(qkv)
     b, s, _ = ops[0].shape
     use_bias = k_bias is not None
@@ -370,12 +453,13 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# backward Pallas kernels (dq; dk+dv) — flash backward both directions:
-# each tile recomputes its probability block from (q, k, lse), so nothing
-# (S, S)-shaped ever exists. delta = rowsum(dO * O) over a head's columns is
-# made by `flash_bwd_dq`, which needs it as the columns it falls out as, and
-# written as rows like lse for `flash_bwd_dkv` (left to XLA, the sum over a
-# (batch, seq, heads, d) view cost a relayout of the whole f32 product).
+# backward Pallas kernels (dq; dk+dv; all three where the sequence is one
+# tile): each tile recomputes its probability block from (q, k, lse), so
+# nothing (S, S)-shaped ever exists. delta = rowsum(dO * O) over a head's
+# columns is made by `flash_bwd_dq`, which needs it as the columns it falls
+# out as, and written as rows like lse for `flash_bwd_dkv`; `flash_bwd`
+# makes it the same way and keeps it (left to XLA, the sum over a (batch,
+# seq, heads, d) view cost a relayout of the whole f32 product).
 # `scale` multiplies the f32 scores on the way in and the f32 accumulators
 # of dq and dk on the way out (d x block elements, not block x block).
 # ---------------------------------------------------------------------------
@@ -458,53 +542,103 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[:, lanes] = dv.astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
+                dq_ref, dv_ref, dk_ref, *, scale, causal, d):
+    # grid: (batch, head groups, 1); the whole sequence is ONE tile, so
+    # nothing is summed over blocks and one rebuilt tile serves dq, dk and
+    # dv. The tile is (keys, queries) as in `flash_bwd_dkv`: lse and delta
+    # broadcast as rows, P^T and dS^T feed dV and dK as they stand, and dQ
+    # alone contracts over the rows of dS^T.
+    heads = q_ref.shape[1] // d
+    deltas = _head_sums(
+        do_ref[...].astype(jnp.float32) * o_ref[...].astype(jnp.float32), d,
+        pieces=2 if do_ref.dtype == jnp.bfloat16 else 3).T   # (128, seq)
+    bias = None if bias_ref is None else _row_to_col(bias_ref[0])
+
+    for g in range(heads):
+        lanes = _block(g, d)
+        q = q_ref[:, lanes]                           # (seq, d)
+        k = k_ref[:, lanes]
+        do = do_ref[:, lanes]
+        st = _dot_nt(k, q) * scale                    # (keys, queries)
+        if bias is not None:
+            st = st + bias
+        if causal:
+            st = _causal_mask(st, 0, 0, keys_first=True)
+        pt = jnp.exp(st - lse_ref[g])
+        dv_ref[:, lanes] = _dot(pt.astype(do.dtype), do).astype(dv_ref.dtype)
+        dpt = _dot_nt(v_ref[:, lanes], do)
+        dst = (pt * (dpt - deltas[g:g + 1])).astype(q.dtype)
+        dk_ref[:, lanes] = (_dot(dst, q) * scale).astype(dk_ref.dtype)
+        dq_ref[:, lanes] = (_dot_tn(dst, k) * scale).astype(dq_ref.dtype)
+
+
 def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
                 interpret):
     """-> dq, dk, dv in the form qkv came in: three arrays where it was
     three; where it was one, one (batch, seq, 3 * heads * d) array, which
-    `flash_bwd_dkv` makes and writes the k columns of, and dq and dv are
-    set into: two passes over a third of it each, that transpose nothing.
-    (The first result of every kernel stays an array of o's shape: the
-    benchmark's reader takes a call's FLOPs from it.)"""
+    the kernel that makes dk (`flash_bwd`; `flash_bwd_dkv`) makes and
+    writes the k columns of, and dq and dv are set into: two passes over a
+    third of it each, that transpose nothing. (The first result of every
+    kernel stays an array of o's shape: the benchmark's reader takes a
+    call's FLOPs from it.) One kernel where the sequence is one tile, two
+    where it is cut (`_kernels_of`), each with its own heads a step."""
     given, o, lse, k_bias = res
     qkv, block_q, block_k, heads, d = _tiles_for(given, n_heads, causal,
                                                  block_q, block_k)
     b, s, width = o.shape
     use_bias = k_bias is not None
     bias = [_bias_rows(k_bias)] if use_bias else []
-    grid = (b, n_heads // heads)
     grad = jax.ShapeDtypeStruct(o.shape, o.dtype)
-
-    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_q)
-    dq_kern = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                block_k=block_k, d=d)
-    dq, delta = pl.pallas_call(
-        _optional_bias(dq_kern, 6, use_bias),
-        grid=grid + (s // block_q,),
-        in_specs=[cols("q"), cols("k", True), cols("v", True), cols("o"),
-                  cols("o"), stats()] + [bias_spec(True)] * use_bias,
-        out_specs=[cols("o"), stats()],
-        out_shape=[grad, jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
-        interpret=interpret,
-        name=FLASH_BWD_DQ,
-    )(*_operands(qkv), do, o, lse, *bias)
-
-    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_k)
-    dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                 causal=causal, block_q=block_q, d=d)
     # dk goes where k came from: the same columns of an array of qkv's
     # shape, or an array of its own
-    dv, dk = pl.pallas_call(
-        _optional_bias(dkv_kern, 6, use_bias),
-        grid=grid + (s // block_k,),
-        in_specs=[cols("q", True), cols("k"), cols("v"), cols("o", True),
-                  stats(True), stats(True)] + [bias_spec()] * use_bias,
-        out_specs=[cols("o"), cols("k")],
-        out_shape=[grad, jax.ShapeDtypeStruct(qkv.shape, o.dtype)
-                   if _is_fused(qkv) else grad],
-        interpret=interpret,
-        name=FLASH_BWD_DKV,
-    )(*_operands(qkv), do, lse, delta, *bias)
+    dk_shape = (jax.ShapeDtypeStruct(qkv.shape, o.dtype) if _is_fused(qkv)
+                else grad)
+
+    if FLASH_BWD in heads:
+        group = heads[FLASH_BWD]
+        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, s)
+        kern = functools.partial(_bwd_kernel, scale=scale, causal=causal, d=d)
+        dq, dv, dk = pl.pallas_call(
+            _optional_bias(kern, 6, use_bias),
+            grid=(b, n_heads // group, 1),
+            in_specs=[cols("q"), cols("k"), cols("v"), cols("o"), cols("o"),
+                      stats()] + [bias_spec()] * use_bias,
+            out_specs=[cols("o"), cols("o"), cols("k")],
+            out_shape=[grad, grad, dk_shape],
+            interpret=interpret,
+            name=FLASH_BWD,
+        )(*_operands(qkv), do, o, lse, *bias)
+    else:
+        group = heads[FLASH_BWD_DQ]
+        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, block_q)
+        dq_kern = functools.partial(_bwd_dq_kernel, scale=scale,
+                                    causal=causal, block_k=block_k, d=d)
+        dq, delta = pl.pallas_call(
+            _optional_bias(dq_kern, 6, use_bias),
+            grid=(b, n_heads // group, s // block_q),
+            in_specs=[cols("q"), cols("k", True), cols("v", True), cols("o"),
+                      cols("o"), stats()] + [bias_spec(True)] * use_bias,
+            out_specs=[cols("o"), stats()],
+            out_shape=[grad, jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+            interpret=interpret,
+            name=FLASH_BWD_DQ,
+        )(*_operands(qkv), do, o, lse, *bias)
+
+        group = heads[FLASH_BWD_DKV]
+        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, block_k)
+        dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale,
+                                     causal=causal, block_q=block_q, d=d)
+        dv, dk = pl.pallas_call(
+            _optional_bias(dkv_kern, 6, use_bias),
+            grid=(b, n_heads // group, s // block_k),
+            in_specs=[cols("q", True), cols("k"), cols("v"), cols("o", True),
+                      stats(True), stats(True)] + [bias_spec()] * use_bias,
+            out_specs=[cols("o"), cols("k")],
+            out_shape=[grad, dk_shape],
+            interpret=interpret,
+            name=FLASH_BWD_DKV,
+        )(*_operands(qkv), do, lse, delta, *bias)
 
     if _is_fused(qkv):
         out = jax.lax.dynamic_update_slice_in_dim(dk, dq, 0, axis=2)

@@ -402,8 +402,9 @@ def test_flash_chosen_blocks_match_reference(s, d, causal, bias, dtype,
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "three"])
 @pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
 @pytest.mark.parametrize("h,d,s,group", [
-    (2, 64, 128, {2}), (4, 64, 256, {4}), (12, 64, 128, {12}),
-    (12, 64, 512, {4, 6}), (4, 128, 512, {2, 4}), (3, 64, 128, {3})],
+    (2, 64, 128, {2}), (4, 64, 256, {4}), (12, 64, 128, {4, 12}),
+    (12, 64, 512, {2, 4, 6}), (4, 128, 512, {1, 2, 4}), (3, 64, 128, {3}),
+    (12, 64, 1024, {2, 4}), (2, 128, 256, {1, 2})],
     ids=lambda x: str(x).replace(", ", "or").strip("{}"))
 def test_flash_btd_layout_matches_reference(h, d, s, group, causal, bias,
                                             fused, dtype, tol_fwd, tol_bwd):
@@ -415,7 +416,7 @@ def test_flash_btd_layout_matches_reference(h, d, s, group, causal, bias,
     in three first."""
     from hetu_tpu.kernels import flash_attention as fa
 
-    assert fa._choose_tiles(s, d, dtype, causal, h)[2] in group
+    assert set(fa._choose_tiles(s, d, dtype, causal, h)[2].values()) <= group
     case = _chosen_case(s, d, causal, bias, dtype, b=2 if s < 512 else 1,
                         h=h)
     if bias and s >= 512:       # one batch row: pad its tail, not all of it
@@ -465,20 +466,27 @@ def test_choose_tiles(s, d, causal, dtype):
     for heads in (1, 3, 12, 16, 96):
         picked = fa._choose_tiles(s, d, dtype, causal, heads)
         assert picked == fa._choose_tiles(s, d, dtype, causal, heads)
-        block_q, block_k, group = picked
+        block_q, block_k, groups = picked
         assert s % block_q == 0 and s % block_k == 0
-        assert heads % group == 0 and 1 <= group <= fa._MAX_HEADS
-        # the lane rule: a step's columns are whole 128-lane tiles, or
-        # every head of the array
-        assert (group * d) % 128 == 0 or group == heads
         assert (block_q, block_k) == (s, s) if s < 128 else (
             block_q % 128 == 0 and block_k % 128 == 0)
-        # the floor (what every call had before) is taken where nothing
-        # fits the count: whole f32 k and v at s = 4096, d = 128
-        floor = (128, 128, fa._head_groups(heads, d)[0])
-        assert picked == floor or fa._vmem_bytes(
-            s, d, jnp.dtype(dtype).itemsize, block_q, block_k,
-            group) <= fa._VMEM_BUDGET
+        # a group for each kernel the call runs: one backward kernel iff
+        # the sequence is one tile
+        assert tuple(groups) == fa._kernels_of(s, block_q, block_k)
+        assert (fa.FLASH_BWD in groups) == (block_q == block_k == s)
+        if fa.FLASH_BWD not in groups:
+            assert len(set(groups.values())) == 1
+        for kernel, group in groups.items():
+            assert heads % group == 0 and 1 <= group <= fa._MAX_HEADS
+            # the lane rule: a step's columns are whole 128-lane tiles, or
+            # every head of the array
+            assert (group * d) % 128 == 0 or group == heads
+            # the floor (what every call had before) is taken where nothing
+            # fits the count: whole f32 k and v at s = 4096, d = 128
+            floor = (128, 128, fa._head_groups(heads, d)[0])
+            assert (block_q, block_k, group) == floor or fa._vmem_bytes(
+                s, d, jnp.dtype(dtype).itemsize, block_q, block_k, group,
+                kernel) <= fa._VMEM_BUDGET
     # blocks the caller passes are kept, and still get a head group
     assert fa._choose_tiles(s, d, dtype, causal, 12, 64, 32)[:2] == (
         min(64, s), min(32, s))
@@ -507,15 +515,19 @@ def _dot_generals(jaxpr):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
-def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias):
+@pytest.mark.parametrize("s,n_dots", [(256, (2, 5)), (1024, (2, 3, 4))],
+                         ids=["one-tile", "two-tiles"])
+def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias, s, n_dots):
     """The dtype rule, pinned on the kernels' jaxprs: every matmul of the
-    three kernels takes both operands in the caller's dtype (bf16 in, bf16
-    on the MXU; f32 in, f32) and accumulates in f32. Two matmuls in the
-    forward, three in dq, four in dk+dv, times the heads of a step."""
+    kernels takes both operands in the caller's dtype (bf16 in, bf16 on the
+    MXU; f32 in, f32) and accumulates in f32. Two matmuls in the forward;
+    FIVE in the one backward kernel of a one-tile sequence; three in dq and
+    four in dk+dv where the sequence is cut; times the heads of a step."""
     from hetu_tpu.kernels import flash_attention as fa
 
-    (q, k, v, do), _, k_bias = _chosen_case(256, 64, causal, bias, dtype)
-    heads = fa._choose_tiles(256, 64, dtype, causal, 3)[2]
+    (q, k, v, do), _, k_bias = _chosen_case(s, 64, causal, bias, dtype)
+    heads = fa._choose_tiles(s, 64, dtype, causal, 3)[2]
+    assert len(heads) == len(n_dots)
 
     def both(q, k, v, do):
         qkv = (q, k, v)
@@ -528,21 +540,161 @@ def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias):
     calls = [e for e in jax.make_jaxpr(both)(
         *(_rows(x) for x in (q, k, v, do))).jaxpr.eqns
         if e.primitive.name == "pallas_call"]
-    assert len(calls) == 3
-    for call, n_dots in zip(calls, (2, 3, 4)):
+    assert len(calls) == len(n_dots)
+    for call, n, kernel in zip(calls, n_dots, heads):
         dots = list(_dot_generals(call.params["jaxpr"]))
         # not of the rule: delta's sums over each head's columns, once a
-        # step of `flash_bwd_dq`: dO * O in two bf16 pieces (three from an
-        # f32 caller) against a 0/1 matrix, exact whatever the dtype
+        # step of the kernel that makes delta (`flash_bwd`, `flash_bwd_dq`):
+        # dO * O in two bf16 pieces (three from an f32 caller) against a
+        # 0/1 matrix, exact whatever the dtype
         sums = [e for e in dots
                 if e.invars[1].aval.shape == (3 * 64, 128)]
-        assert len(sums) == (n_dots == 3) * (3 if dtype == jnp.float32
-                                             else 2)
+        makes_delta = kernel in (fa.FLASH_BWD, fa.FLASH_BWD_DQ)
+        assert len(sums) == makes_delta * (3 if dtype == jnp.float32 else 2)
         for eqn in sums:
             assert [x.aval.dtype for x in eqn.invars] == [jnp.bfloat16] * 2
         dots = [e for e in dots if e not in sums]
-        assert len(dots) == n_dots * heads
+        assert len(dots) == n * heads[kernel]
         for eqn in dots:
             assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]
             assert eqn.outvars[0].aval.dtype == jnp.float32
             assert eqn.params["preferred_element_type"] == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# one backward kernel where the sequence is one tile (`flash_bwd`), two
+# where it is cut (`flash_bwd_dq` + `flash_bwd_dkv`); heads a step by kernel
+# ---------------------------------------------------------------------------
+
+def _pallas_calls(fn, *args):
+    """The names of the pallas_calls `fn` makes, in order."""
+    return [e.params["name"]
+            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+
+
+@_DTYPES
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "three"])
+@pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
+@pytest.mark.parametrize("h,d,s,group", [
+    (2, 64, 128, 2), (4, 64, 256, 4), (12, 64, 128, 12), (12, 64, 512, 4),
+    (12, 64, 512, 2), (4, 128, 128, 4), (2, 128, 512, 1), (4, 128, 256, 2)],
+    ids=lambda x: str(x))
+def test_one_tile_backward_is_one_kernel(h, d, s, group, causal, bias, fused,
+                                         dtype, tol_fwd, tol_bwd,
+                                         monkeypatch):
+    """`flash_bwd` (interpret mode) with `group` heads a step against the
+    XLA blockwise backward and the autodiff of the unfused reference, at the
+    tolerances the two-kernel path has: one pallas_call under that name, the
+    gradient in the form qkv came in, a fully padded batch row finite."""
+    from hetu_tpu.kernels import flash_attention as fa
+
+    b = 2 if s < 512 else 1
+    (q, k, v, do), (qf, kf, vf, dof), k_bias = _chosen_case(
+        s, d, causal, bias, dtype, b=b, h=h)
+    if bias and b == 1:         # one batch row: pad its tail, not all of it
+        k_bias = _padding_bias(np.random.RandomState(s), 1, s)
+    chosen = fa._choose_tiles(s, d, dtype, causal, h)
+    assert chosen[:2] == (s, s) and fa.FLASH_BWD in chosen[2]
+    monkeypatch.setattr(fa, "_choose_tiles", lambda *a, **kw: (
+        s, s, {fa.FLASH_FWD: group, fa.FLASH_BWD: group}))
+    scale = 1.0 / np.sqrt(d)
+    qkv = tuple(_rows(x) for x in (q, k, v))
+    if fused:
+        qkv = jnp.concatenate(qkv, axis=-1)
+    out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, None, None,
+                              interpret=True)
+    res = (qkv, out, lse, k_bias)
+    kw = dict(n_heads=h, scale=scale, causal=causal)
+
+    def backward(res, do):
+        return fa._bwd_pallas(res, do, block_q=None, block_k=None,
+                              interpret=True, **kw)
+
+    assert _pallas_calls(backward, res, _rows(do)) == [fa.FLASH_BWD]
+    got = backward(res, _rows(do))
+    oracle = fa._bwd_blockwise(res, _rows(do), block_k=s, **kw)
+    if fused:
+        assert got.shape == qkv.shape
+        got, oracle = (jnp.split(x, 3, axis=-1) for x in (got, oracle))
+    want = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal,
+                                                 k_bias=k_bias),
+                   qf, kf, vf)[1](dof)
+    rows = slice(0, 1) if k_bias is not None else slice(None)
+    for a, o, w in zip(got, oracle, want):
+        assert a.dtype == dtype
+        a, o = (np.asarray(_heads(x, h), np.float32) for x in (a, o))
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a[rows], o[rows], rtol=tol_bwd,
+                                   atol=tol_bwd)
+        np.testing.assert_allclose(a[rows], np.asarray(w[rows]),
+                                   rtol=tol_bwd, atol=tol_bwd)
+
+
+@pytest.mark.parametrize("s,block_q,block_k,names", [
+    (256, None, None, ["flash_bwd"]),
+    (512, 512, 512, ["flash_bwd"]),
+    (64, None, None, ["flash_bwd"]),
+    (1024, None, None, ["flash_bwd_dq", "flash_bwd_dkv"]),
+    (256, 128, None, ["flash_bwd_dq", "flash_bwd_dkv"]),
+    (256, None, 128, ["flash_bwd_dq", "flash_bwd_dkv"]),
+    (384, None, None, ["flash_bwd_dq", "flash_bwd_dkv"])],
+    ids=lambda x: str(x))
+def test_backward_kernels_by_tiles(s, block_q, block_k, names):
+    """One backward kernel iff the call's sequence is one tile, by the
+    shapes alone: a sequence of two tiles, or one a caller's block cuts,
+    keeps `flash_bwd_dq` + `flash_bwd_dkv`, and matches the oracle."""
+    from hetu_tpu.kernels import flash_attention as fa
+
+    (q, k, v, do), _, k_bias = _chosen_case(s, 64, False, True, jnp.float32,
+                                            b=1, h=2)
+    k_bias = _padding_bias(np.random.RandomState(s), 1, s)
+    qkv = jnp.concatenate([_rows(x) for x in (q, k, v)], axis=-1)
+    out, lse = fa._fwd_pallas(qkv, 2, k_bias, 0.125, False, block_q, block_k,
+                              interpret=True)
+    res = (qkv, out, lse, k_bias)
+    kw = dict(n_heads=2, scale=0.125, causal=False)
+
+    def backward(res, do):
+        return fa._bwd_pallas(res, do, block_q=block_q, block_k=block_k,
+                              interpret=True, **kw)
+
+    assert _pallas_calls(backward, res, _rows(do)) == names
+    np.testing.assert_allclose(
+        np.asarray(backward(res, _rows(do))),
+        np.asarray(fa._bwd_blockwise(res, _rows(do), block_k=min(s, 128),
+                                     **kw)), rtol=2e-4, atol=2e-4)
+
+
+# (seq, head_dim, heads, causal) of the attention calls the benchmark's
+# cells make, bf16 -> (block_q, block_k, heads a step of each kernel)
+@pytest.mark.parametrize("s,d,heads,causal,blocks,groups", [
+    pytest.param(128, 64, 12, False, (128, 128),
+                 {"flash_fwd": 4, "flash_bwd": 12},
+                 id="bert-base.pretrain-seq128"),
+    pytest.param(512, 64, 12, False, (512, 512),
+                 {"flash_fwd": 4, "flash_bwd": 4},
+                 id="bert-base.pretrain-seq512"),
+    pytest.param(4096, 128, 16, True, (512, 512),
+                 {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1},
+                 id="olmoe-1b-7b.pretrain-seq4096"),
+    pytest.param(4096, 128, 16, True, (512, 512),
+                 {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1},
+                 id="ouro-2.6b.pretrain-seq4096-b1"),
+    pytest.param(8192, 64, 32, True, (256, 256),
+                 {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2},
+                 id="granite-4.0-h-micro.pretrain-seq8192-b1"),
+    # one tile at head size 128: whole lane tiles, no cap on the forward
+    pytest.param(128, 128, 16, False, (128, 128),
+                 {"flash_fwd": 16, "flash_bwd": 16}, id="one-tile-d128"),
+    pytest.param(256, 64, 12, False, (256, 256),
+                 {"flash_fwd": 4, "flash_bwd": 12}, id="one-tile-256-d64"),
+])
+def test_choose_tiles_at_the_cells_shapes(s, d, heads, causal, blocks,
+                                          groups):
+    """What the per-kernel rule picks at the shapes the cells run: the
+    one-tile BERT shapes as the chip had them best (PR 33), the many-tile
+    decoders exactly what they had before it."""
+    from hetu_tpu.kernels import flash_attention as fa
+    assert fa._choose_tiles(s, d, jnp.bfloat16, causal, heads) == (
+        *blocks, groups)

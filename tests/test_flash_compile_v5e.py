@@ -1,4 +1,4 @@
-"""The three flash kernels and the three of the fused CE compiled for a v5e
+"""The four flash kernels and the three of the fused CE compiled for a v5e
 that is described, not attached (the TPU's compiler is installed here): what Mosaic refuses at the
 real shapes — a misaligned slice, too much VMEM, a transpose it does not
 take — fails here and costs no chip time. Nothing runs, so nothing here is a
@@ -8,7 +8,9 @@ The topology is described inside a module-scoped fixture, never at import:
 one process at a time may load libtpu, and under xdist every worker imports
 this file. All such compiles stay in this one file and in the test's own
 process."""
+import base64
 import dataclasses
+import hashlib
 import math
 import os
 import re
@@ -61,7 +63,21 @@ SHAPES = [
                  id="olmoe-seq4096"),
     pytest.param((4, 3, 512, 64), jnp.bfloat16, False, True, False,
                  id="three-heads-192-lanes"),
+    # one tile, so one backward kernel, beyond BERT's two shapes
+    pytest.param((128, 12, 512, 64), jnp.bfloat16, False, False, False,
+                 id="one-tile-512-three-arrays"),
+    pytest.param((16, 16, 512, 128), jnp.bfloat16, True, False, False,
+                 id="one-tile-512-causal-d128"),
+    pytest.param((256, 12, 256, 64), jnp.bfloat16, True, True, True,
+                 id="one-tile-256-causal-bias"),
+    pytest.param((4, 4, 512, 128), jnp.float32, False, True, False,
+                 id="one-tile-512-f32-d128"),
+    pytest.param((8, 16, 64, 64), jnp.bfloat16, True, False, False,
+                 id="one-tile-64"),
 ]
+
+FLASH_KERNELS = (fa.FLASH_FWD, fa.FLASH_BWD, fa.FLASH_BWD_DQ,
+                 fa.FLASH_BWD_DKV)
 
 
 def _kernel_calls(text):
@@ -69,19 +85,19 @@ def _kernel_calls(text):
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
-def _count_by_name(calls, names=(fa.FLASH_FWD, fa.FLASH_BWD_DQ,
-                                 fa.FLASH_BWD_DKV)):
-    return {name: sum(name in c.split("=")[0] for c in calls)
-            for name in names}
+def _count_by_name(calls, names=FLASH_KERNELS):
+    """Calls by kernel: the instruction is named after the kernel, wrapped
+    where jax wrapped its scope (`%jvp_flash_fwd_.1`); `flash_bwd` is not
+    `flash_bwd_dq`."""
+    return {name: sum(bool(re.search(name + "(?!_?[a-z])", c.split("=")[0]))
+                      for c in calls) for name in names}
 
 
-@pytest.mark.parametrize("shape,dtype,causal,bias,fused", SHAPES)
-def test_flash_compiles_for_v5e(one_chip, no_compile_cache, shape, dtype,
-                                causal, bias, fused):
-    """Forward and gradient at the blocks the chooser picks, on the
-    (batch, seq, heads * head_dim) arrays the trunk hands over (one fused
-    [q|k|v] array, or three): three Mosaic custom calls under the kernels'
-    names in the compiled program."""
+def _flash_fwd_and_grads(one_chip, shape, dtype, causal, bias, fused):
+    """-> (the function, its abstract arguments): forward and gradient at
+    the blocks the chooser picks. `flash_attention_btd` asks
+    jax.default_backend() which backward to take and sees the CPU here:
+    this is what it runs on a TPU, kernel by kernel."""
     b, h, s, d = shape
 
     def arr(columns):
@@ -92,9 +108,6 @@ def test_flash_compiles_for_v5e(one_chip, no_compile_cache, shape, dtype,
     kb = jax.ShapeDtypeStruct((b, s), jnp.float32, sharding=one_chip)
     scale = 1.0 / d ** 0.5
 
-    # `flash_attention_btd` asks jax.default_backend() which backward to
-    # take and sees the CPU here: compile what it runs on a TPU, kernel by
-    # kernel
     def fwd_and_grads(qkv, k_bias, do):
         k_bias = k_bias if bias else None
         out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, None, None,
@@ -103,11 +116,62 @@ def test_flash_compiles_for_v5e(one_chip, no_compile_cache, shape, dtype,
             (qkv, out, lse, k_bias), do, n_heads=h, scale=scale,
             causal=causal, block_q=None, block_k=None, interpret=False)
 
-    text = jax.jit(fwd_and_grads).lower(qkv, kb, arr(h * d)) \
-        .compile().as_text()
-    calls = _kernel_calls(text)
-    assert len(calls) == 3, text
-    assert set(_count_by_name(calls).values()) == {1}, calls
+    return fwd_and_grads, (qkv, kb, arr(h * d))
+
+
+@pytest.mark.parametrize("shape,dtype,causal,bias,fused", SHAPES)
+def test_flash_compiles_for_v5e(one_chip, no_compile_cache, shape, dtype,
+                                causal, bias, fused):
+    """Forward and gradient at the blocks the chooser picks, on the
+    (batch, seq, heads * head_dim) arrays the trunk hands over (one fused
+    [q|k|v] array, or three): a Mosaic custom call for each kernel of the
+    call, under its name, in the compiled program: `flash_fwd` and
+    `flash_bwd` where the sequence is one tile, else `flash_fwd`,
+    `flash_bwd_dq` and `flash_bwd_dkv`."""
+    _, _, s, d = shape
+    fn, args = _flash_fwd_and_grads(one_chip, shape, dtype, causal, bias,
+                                    fused)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = fa._choose_tiles(s, d, dtype, causal, shape[1])[2]
+    assert (fa.FLASH_BWD in kernels) == (s <= 512)
+    assert _count_by_name(_kernel_calls(text)) == {
+        name: int(name in kernels) for name in FLASH_KERNELS}, text
+
+
+# The kernels of a many-tile call are what they were before `flash_bwd`
+# (PR 33): the lowered forward and backward of the three decoders' calls,
+# the Mosaic modules inside read back as text WITHOUT their locations (a
+# line number of this repo's files is in every one), digests made on bfe0a72.
+_BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+MANY_TILE = {
+    "olmoe-1b-7b": ((8, 16, 4096, 128), "2303c79152d6cfb4"),
+    "ouro-2.6b": ((1, 16, 4096, 128), "f97651100e9adad2"),
+    "granite-4.0-h-micro": ((1, 32, 8192, 64), "b3ff387cdcdffd38"),
+}
+
+
+def _lowered_without_locations(text):
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    kernels = []
+    for body in _BODY.findall(text):
+        with mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(body))
+            kernels.append(module.operation.get_asm(enable_debug_info=False))
+    return _BODY.sub("BODY", text) + "\n".join(kernels), len(kernels)
+
+
+@pytest.mark.parametrize("cell", sorted(MANY_TILE))
+def test_many_tile_kernels_lower_to_what_they_were(one_chip, cell):
+    shape, digest = MANY_TILE[cell]
+    fn, args = _flash_fwd_and_grads(one_chip, shape, jnp.bfloat16, True,
+                                    False, False)
+    text, kernels = _lowered_without_locations(
+        jax.jit(fn).lower(*args).as_text())
+    assert kernels == 3
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
 # (rows, vocabulary, width) of the calls the benchmark's cells make, bf16
@@ -284,16 +348,18 @@ def test_layer_copies_nothing_around_attention(one_chip, no_compile_cache,
         return _count_by_name(_kernel_calls(text)), _entry_matmuls(graph)
 
     # the bare policy's contract: the forward runs twice, once as it is,
-    # once under `remat`
+    # once under `remat`; a sequence of one tile (BERT's) has one backward
+    # kernel, OLMoE's eight tiles two
+    backward = ({fa.FLASH_BWD: 1} if seq <= 512 else
+                {fa.FLASH_BWD_DQ: 1, fa.FLASH_BWD_DKV: 1})
+    none = dict.fromkeys(FLASH_KERNELS, 0)
     bare_kernels, bare_matmuls = compiled(None)
-    assert bare_kernels == {
-        fa.FLASH_FWD: 2, fa.FLASH_BWD_DQ: 1, fa.FLASH_BWD_DKV: 1}
+    assert bare_kernels == {**none, fa.FLASH_FWD: 2, **backward}
     # every name kept: the forward kernel runs once, and the recomputed
     # wo (and w2, where a norm reads its sum) is gone
     kernels, matmuls = compiled(
         jax.checkpoint_policies.save_only_these_names(
             *sum(REMAT_CANDIDATES, ())))
-    assert kernels == {
-        fa.FLASH_FWD: 1, fa.FLASH_BWD_DQ: 1, fa.FLASH_BWD_DKV: 1}
+    assert kernels == {**none, fa.FLASH_FWD: 1, **backward}
     assert matmuls <= bare_matmuls - (2 if cfg.post_ln else 1), (
         matmuls, bare_matmuls)

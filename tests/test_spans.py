@@ -30,6 +30,9 @@ def _flash_bwd(q, k, v, o, lse, do):
 
 
 _QKV = (_f32(1, 128, 128),) * 3     # (batch, seq, 2 heads * 64)
+# one tile of 128 has ONE backward kernel; two tiles have the two
+_BWD_1 = _QKV + (_f32(1, 128, 128), _f32(2, 1, 128), _f32(1, 128, 128))
+_BWD_2 = (_f32(1, 256, 128),) * 4 + (_f32(2, 1, 256), _f32(1, 256, 128))
 _SGD = type("Opt", (), {"l2reg": 0.0})()
 _ADAM = type("Opt", (), {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8,
                          "weight_decay": 0.0})()
@@ -39,12 +42,9 @@ KERNEL_PROGRAMS = {
     flash_attention.FLASH_FWD: (
         lambda q, k, v: flash_attention.flash_attention_btd(
             (q, k, v), 2, causal=False), _QKV),
-    flash_attention.FLASH_BWD_DQ: (
-        _flash_bwd, _QKV + (_f32(1, 128, 128), _f32(2, 1, 128),
-                            _f32(1, 128, 128))),
-    flash_attention.FLASH_BWD_DKV: (
-        _flash_bwd, _QKV + (_f32(1, 128, 128), _f32(2, 1, 128),
-                            _f32(1, 128, 128))),
+    flash_attention.FLASH_BWD: (_flash_bwd, _BWD_1),
+    flash_attention.FLASH_BWD_DQ: (_flash_bwd, _BWD_2),
+    flash_attention.FLASH_BWD_DKV: (_flash_bwd, _BWD_2),
     fused_ce.FUSED_CE_FWD: (
         fused_ce.fused_linear_nll,
         (_f32(128, 128), _f32(256, 128), _f32(256), _i32(128))),
