@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..telemetry.tracing import SCOPE_FWD, SCOPE_OPT, scoped
+from ..telemetry.tracing import (SCOPE_BLK_NORM, SCOPE_EMBED, SCOPE_FWD,
+                                 SCOPE_HEAD, SCOPE_OPT, scoped)
 from . import transformer as tfm
 
 
@@ -136,10 +137,11 @@ def encode(params, input_ids, segment_ids, cfg: BertConfig,
     (each block already ends in a LayerNorm)."""
     trunk = cfg.trunk()
     h = tfm.embed_tokens(params, input_ids, trunk)
-    h = h + params["type_emb"][segment_ids].astype(h.dtype)
-    if cfg.post_ln:
-        h = tfm._layer_norm(h, params["lnf_scale"], params["lnf_bias"],
-                            cfg.ln_eps)
+    with jax.named_scope(SCOPE_EMBED):
+        h = h + params["type_emb"][segment_ids].astype(h.dtype)
+        if cfg.post_ln:
+            h = tfm._layer_norm(h, params["lnf_scale"], params["lnf_bias"],
+                                cfg.ln_eps)
     attn_bias = None
     if input_mask is not None:
         # (B, T) 1/0 -> additive (B, 1, 1, T): padded keys get -1e30
@@ -148,8 +150,9 @@ def encode(params, input_ids, segment_ids, cfg: BertConfig,
     h, _aux = tfm.encode(params, h, trunk, mesh, attn_bias)
     if cfg.post_ln:
         return h
-    return tfm._layer_norm(h, params["lnf_scale"], params["lnf_bias"],
-                           cfg.ln_eps)
+    with jax.named_scope(SCOPE_BLK_NORM):
+        return tfm._layer_norm(h, params["lnf_scale"], params["lnf_bias"],
+                               cfg.ln_eps)
 
 
 def mlm_transform(params, h, positions, cfg: BertConfig):
@@ -192,6 +195,13 @@ def pretrain_loss(params, batch, cfg: BertConfig, mesh=None):
     where mlm is averaged over real (weighted) prediction slots."""
     h = encode(params, batch["input_ids"], batch["segment_ids"], cfg, mesh,
                batch.get("input_mask"))
+    with jax.named_scope(SCOPE_HEAD):
+        return _pretrain_heads(params, h, batch, cfg, mesh)
+
+
+def _pretrain_heads(params, h, batch, cfg: BertConfig, mesh):
+    """``pretrain_loss`` from the final hidden states on: the MLM transform,
+    the tied decoder (fused or einsum) and the NSP head, with both losses."""
     from ..kernels.fused_ce import should_fuse
     if should_fuse(cfg.fused_mlm_ce, mesh):
         from ..kernels.fused_ce import fused_linear_nll
@@ -273,7 +283,8 @@ def init_classifier_params(rng, cfg: BertConfig, n_classes: int,
 def classify_logits(params, input_ids, segment_ids, cfg: BertConfig,
                     mesh=None, input_mask=None):
     h = encode(params, input_ids, segment_ids, cfg, mesh, input_mask)
-    return _pool(params, h) @ params["cls_w"] + params["cls_b"]
+    with jax.named_scope(SCOPE_HEAD):
+        return _pool(params, h) @ params["cls_w"] + params["cls_b"]
 
 
 def make_finetune_step(cfg: BertConfig, lr: float = 2e-5, mesh=None):

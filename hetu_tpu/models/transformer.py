@@ -37,10 +37,15 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry.tracing import (REMAT_ATTN_O, REMAT_CANDIDATES, REMAT_X1,
-                                 REMAT_X2, SCOPE_EXIT, SCOPE_FWD,
+                                 REMAT_X2, SCOPE_BLK_ATTN, SCOPE_BLK_MLP_DOWN,
+                                 SCOPE_BLK_MLP_UP, SCOPE_BLK_NORM,
+                                 SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_EMBED,
+                                 SCOPE_EXIT, SCOPE_FWD, SCOPE_HEAD,
                                  SCOPE_MOE_COMBINE, SCOPE_MOE_DISPATCH,
                                  SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE,
-                                 SCOPE_OPT, SCOPE_SSM_CONV, SCOPE_SSM_GATE,
+                                 SCOPE_OPT, SCOPE_SSD_ENTER,
+                                 SCOPE_SSD_INCHUNK, SCOPE_SSD_STATES,
+                                 SCOPE_SSM_CONV, SCOPE_SSM_GATE,
                                  SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, scoped)
 
 _log = logging.getLogger(__name__)
@@ -456,10 +461,13 @@ def _rms_norm(x, scale, eps):
 
 def _norm(x, scale, bias, cfg: TransformerConfig):
     """Dialect-dispatched normalization: LayerNorm (default) or RMSNorm
-    (Llama family — ``bias`` exists in the pytree but is ignored)."""
-    if cfg.norm == "rmsnorm":
-        return _rms_norm(x, scale, cfg.ln_eps)
-    return _layer_norm(x, scale, bias, cfg.ln_eps)
+    (Llama family — ``bias`` exists in the pytree but is ignored). The norm
+    of the residual stream, wherever a block, ``encode`` or the head calls
+    it: ``SCOPE_BLK_NORM``."""
+    with jax.named_scope(SCOPE_BLK_NORM):
+        if cfg.norm == "rmsnorm":
+            return _rms_norm(x, scale, cfg.ln_eps)
+        return _layer_norm(x, scale, bias, cfg.ln_eps)
 
 
 def _rope(x, pos0, theta, hd):
@@ -607,57 +615,71 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
     return out.reshape(B, T, D)
 
 
+def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
+    """The (B, T, (nh + 2 nkv) hd) projection -> q, k, v (B, T, D) as every
+    attention impl takes them: cut, QK-normed, rotated, scaled and the kv
+    heads broadcast to their query groups."""
+    B, T, _ = qkv.shape
+    nh, hd, nkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    # cut along the columns; a head stays hd columns of its array
+    q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+    if cfg.qk_norm:
+        # the statistic runs over every head of the projection at once
+        q = _rms_norm(q, p["q_norm"], cfg.ln_eps)
+        k = _rms_norm(k, p["k_norm"], cfg.ln_eps)
+    if impl != "ring":
+        # Ulysses-style: gather k/v over sp, heads stay tp-sharded
+        # (the ring keeps them sequence-sharded and rotates chunks)
+        k = _constrain(k, mesh, "dp", None, "tp")
+        v = _constrain(v, mesh, "dp", None, "tp")
+    if cfg.rope:
+        # rotate BEFORE any gqa broadcast (rope is per-kv-head)
+        q = _rope(q, 0, cfg.rope_theta, hd)
+        k = _rope(k, 0, cfg.rope_theta, hd)
+    if cfg.multipliers.attention is not None:
+        # every impl scales its scores by 1/sqrt(hd): q carries the
+        # rest (Granite: 1/64 at hd = 64, so q * 0.125, exact)
+        q = q * jnp.asarray(cfg.multipliers.attention * np.sqrt(hd),
+                            q.dtype)
+    if nkv != nh:
+        # grouped-query: broadcast each kv head to its query group;
+        # every attention impl then sees matching head counts
+        k, v = (jnp.repeat(x.reshape(B, T, nkv, hd), nh // nkv,
+                           axis=2).reshape(B, T, nh * hd) for x in (k, v))
+    return q, k, v
+
+
 def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
-    B, T, D = h.shape
-    nh, hd = cfg.n_heads, cfg.head_dim
-    nkv = cfg.kv_heads
+    B, T, _ = h.shape
+    nh, nkv = cfg.n_heads, cfg.kv_heads
     impl = _resolve_attn_impl(cfg, mesh, T, attn_bias)
-    qkv = jnp.einsum("btd,de->bte", h, p["wqkv"].astype(h.dtype),
-                     preferred_element_type=jnp.float32).astype(h.dtype)
-    if cfg.attn_proj_bias:
-        qkv = qkv + p["bqkv"].astype(h.dtype)
+    with jax.named_scope(SCOPE_BLK_QKV):
+        qkv = jnp.einsum("btd,de->bte", h, p["wqkv"].astype(h.dtype),
+                         preferred_element_type=jnp.float32).astype(h.dtype)
+        if cfg.attn_proj_bias:
+            qkv = qkv + p["bqkv"].astype(h.dtype)
     tp = 1 if mesh is None else mesh.shape.get("tp", 1)
     if impl == "flash" and nkv == nh and tp == 1 and not (
             cfg.qk_norm or cfg.rope
             or cfg.multipliers.attention is not None):
         # nothing touches q or k on the way and the [q|k|v] columns lie on
         # one shard: the kernels read the projection where it stands
-        out = _flash(qkv, cfg, mesh, _key_bias(attn_bias, B))
+        with jax.named_scope(SCOPE_BLK_ATTN):
+            out = _flash(qkv, cfg, mesh, _key_bias(attn_bias, B))
     else:
-        # cut along the columns; a head stays hd columns of its array
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-        if cfg.qk_norm:
-            # the statistic runs over every head of the projection at once
-            q = _rms_norm(q, p["q_norm"], cfg.ln_eps)
-            k = _rms_norm(k, p["k_norm"], cfg.ln_eps)
-        if impl != "ring":
-            # Ulysses-style: gather k/v over sp, heads stay tp-sharded
-            # (the ring keeps them sequence-sharded and rotates chunks)
-            k = _constrain(k, mesh, "dp", None, "tp")
-            v = _constrain(v, mesh, "dp", None, "tp")
-        if cfg.rope:
-            # rotate BEFORE any gqa broadcast (rope is per-kv-head)
-            q = _rope(q, 0, cfg.rope_theta, hd)
-            k = _rope(k, 0, cfg.rope_theta, hd)
-        if cfg.multipliers.attention is not None:
-            # every impl scales its scores by 1/sqrt(hd): q carries the
-            # rest (Granite: 1/64 at hd = 64, so q * 0.125, exact)
-            q = q * jnp.asarray(cfg.multipliers.attention * np.sqrt(hd),
-                                q.dtype)
-        if nkv != nh:
-            # grouped-query: broadcast each kv head to its query group;
-            # every attention impl then sees matching head counts
-            k, v = (jnp.repeat(x.reshape(B, T, nkv, hd), nh // nkv,
-                               axis=2).reshape(B, T, D) for x in (k, v))
-        out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias)
+        with jax.named_scope(SCOPE_BLK_QKV):
+            q, k, v = _split_heads(qkv, p, cfg, mesh, impl)
+        with jax.named_scope(SCOPE_BLK_ATTN):
+            out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias)
     if impl != "flash":
         # the flash kernel names its o itself, with its lse, where they
         # become its backward pass's residuals (`_flash_fwd`)
         out = checkpoint_name(out, REMAT_ATTN_O)
-    out = jnp.einsum("btd,de->bte", out, p["wo"].astype(h.dtype),
-                     preferred_element_type=jnp.float32).astype(h.dtype)
-    if cfg.attn_proj_bias:
-        out = out + p["bo"].astype(h.dtype)
+    with jax.named_scope(SCOPE_BLK_WO):
+        out = jnp.einsum("btd,de->bte", out, p["wo"].astype(h.dtype),
+                         preferred_element_type=jnp.float32).astype(h.dtype)
+        if cfg.attn_proj_bias:
+            out = out + p["bo"].astype(h.dtype)
     return out
 
 
@@ -732,25 +754,28 @@ def _ssd(x, dt, A_log, Bm, Cm, chunk):
     the states are float32; the matmuls read bf16 (``x.dtype``) operands and
     sum in float32. The backward pass is the compiler's transpose."""
     shape = x.shape
-    x, dt, acs, Bm, Cm = _ssd_chunks(x, dt, A_log, Bm, Cm, chunk)
-    Q = x.shape[2]
-    # 1. inside a chunk
-    a = jnp.moveaxis(acs, 2, -1)                          # (B, c, G, R, Q)
-    causal = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.exp(jnp.where(causal, a[..., :, None] - a[..., None, :],
-                          -jnp.inf))
-    CB = jnp.einsum("bclgn,bcsgn->bcgls", Cm, Bm,
-                    preferred_element_type=jnp.float32)
-    y = jnp.einsum("bcgrls,bcsgrp->bclgrp",
-                   (CB[:, :, :, None] * L).astype(x.dtype),
-                   (x * dt[..., None]).astype(x.dtype),
-                   preferred_element_type=jnp.float32)
+    with jax.named_scope(SCOPE_SSD_INCHUNK):
+        x, dt, acs, Bm, Cm = _ssd_chunks(x, dt, A_log, Bm, Cm, chunk)
+        Q = x.shape[2]
+        # 1. inside a chunk
+        a = jnp.moveaxis(acs, 2, -1)                      # (B, c, G, R, Q)
+        causal = jnp.tril(jnp.ones((Q, Q), bool))
+        L = jnp.exp(jnp.where(causal, a[..., :, None] - a[..., None, :],
+                              -jnp.inf))
+        CB = jnp.einsum("bclgn,bcsgn->bcgls", Cm, Bm,
+                        preferred_element_type=jnp.float32)
+        y = jnp.einsum("bcgrls,bcsgrp->bclgrp",
+                       (CB[:, :, :, None] * L).astype(x.dtype),
+                       (x * dt[..., None]).astype(x.dtype),
+                       preferred_element_type=jnp.float32)
     # 2. and 3. the chunks' states and the recurrence over them
-    _, _, entering = _ssd_states(x, dt, acs, Bm)
+    with jax.named_scope(SCOPE_SSD_STATES):
+        _, _, entering = _ssd_states(x, dt, acs, Bm)
     # 4. the entering state's part
-    y = y + jnp.exp(acs)[..., None] * jnp.einsum(
-        "bclgn,bcgrpn->bclgrp", Cm, entering.astype(x.dtype),
-        preferred_element_type=jnp.float32)
+    with jax.named_scope(SCOPE_SSD_ENTER):
+        y = y + jnp.exp(acs)[..., None] * jnp.einsum(
+            "bclgn,bcgrpn->bclgrp", Cm, entering.astype(x.dtype),
+            preferred_element_type=jnp.float32)
     return y.reshape(shape)
 
 
@@ -790,7 +815,8 @@ def _mamba(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     with jax.named_scope(SCOPE_SSM_SCAN):
         y = _ssd(x, _ssm_dt(dt_raw, p["dt_bias"]), p["A_log"], Bm, Cm,
                  cfg.ssm.chunk)
-        y = y + p["D"][:, None] * x
+        with jax.named_scope(SCOPE_SSD_ENTER):
+            y = y + p["D"][:, None] * x
     with jax.named_scope(SCOPE_SSM_GATE):
         y = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
         y = _rms_norm(y, p["ssm_norm"], cfg.ln_eps).astype(h.dtype)
@@ -817,19 +843,24 @@ def _dense_mlp(h, p, cfg, mesh):
     if cfg.mlp == "swiglu":
         # Llama MLP: down(silu(gate(x)) * up(x)); the b1/b2 params exist
         # but are zero/unused in this dialect (no biases in the family)
-        gate = jnp.einsum("btd,df->btf", h, p["w1"].astype(h.dtype),
-                          preferred_element_type=jnp.float32)
-        up = jnp.einsum("btd,df->btf", h, p["w3"].astype(h.dtype),
-                        preferred_element_type=jnp.float32)
-        u = (jax.nn.silu(gate) * up).astype(h.dtype)
-        return jnp.einsum("btf,fd->btd", u, p["w2"].astype(h.dtype),
-                          preferred_element_type=jnp.float32).astype(h.dtype)
-    u = jnp.einsum("btd,df->btf", h, p["w1"].astype(h.dtype),
-                   preferred_element_type=jnp.float32).astype(h.dtype)
-    u = _gelu(u + p["b1"].astype(h.dtype), cfg)
-    out = jnp.einsum("btf,fd->btd", u, p["w2"].astype(h.dtype),
-                     preferred_element_type=jnp.float32).astype(h.dtype)
-    return out + p["b2"].astype(h.dtype)
+        with jax.named_scope(SCOPE_BLK_MLP_UP):
+            gate = jnp.einsum("btd,df->btf", h, p["w1"].astype(h.dtype),
+                              preferred_element_type=jnp.float32)
+            up = jnp.einsum("btd,df->btf", h, p["w3"].astype(h.dtype),
+                            preferred_element_type=jnp.float32)
+            u = (jax.nn.silu(gate) * up).astype(h.dtype)
+        with jax.named_scope(SCOPE_BLK_MLP_DOWN):
+            return jnp.einsum(
+                "btf,fd->btd", u, p["w2"].astype(h.dtype),
+                preferred_element_type=jnp.float32).astype(h.dtype)
+    with jax.named_scope(SCOPE_BLK_MLP_UP):
+        u = jnp.einsum("btd,df->btf", h, p["w1"].astype(h.dtype),
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+        u = _gelu(u + p["b1"].astype(h.dtype), cfg)
+    with jax.named_scope(SCOPE_BLK_MLP_DOWN):
+        out = jnp.einsum("btf,fd->btd", u, p["w2"].astype(h.dtype),
+                         preferred_element_type=jnp.float32).astype(h.dtype)
+        return out + p["b2"].astype(h.dtype)
 
 
 def _route(x, router, cfg: TransformerConfig):
@@ -1058,13 +1089,14 @@ def embed_tokens(params, tokens, cfg: TransformerConfig):
     """(..., T) int32 -> (..., T, D) embeddings (+ learned positions,
     unless the dialect carries positions via rope)."""
     T = tokens.shape[-1]
-    h = params["embed"][tokens]
-    if cfg.multipliers.embedding != 1.0:
-        h = h * cfg.multipliers.embedding       # in the weights' float32
-    h = h.astype(cfg.dtype)
-    if cfg.use_pos_emb:
-        h = h + params["pos"][:T].astype(cfg.dtype)
-    return h
+    with jax.named_scope(SCOPE_EMBED):
+        h = params["embed"][tokens]
+        if cfg.multipliers.embedding != 1.0:
+            h = h * cfg.multipliers.embedding   # in the weights' float32
+        h = h.astype(cfg.dtype)
+        if cfg.use_pos_emb:
+            h = h + params["pos"][:T].astype(cfg.dtype)
+        return h
 
 
 def _logit_scaled(h, cfg: TransformerConfig):
@@ -1082,17 +1114,21 @@ def lm_head(params, h, cfg: TransformerConfig):
     token embedding itself (no transposed copy is materialized)."""
     if not cfg.post_ln and cfg.n_loops == 1:
         h = _norm(h, params["lnf_scale"], params["lnf_bias"], cfg)
-    h = _logit_scaled(h, cfg)
-    if cfg.tied_head:
-        return jnp.einsum("btd,vd->btv", h, params["embed"].astype(h.dtype),
+    with jax.named_scope(SCOPE_HEAD):
+        h = _logit_scaled(h, cfg)
+        if cfg.tied_head:
+            return jnp.einsum("btd,vd->btv", h,
+                              params["embed"].astype(h.dtype),
+                              preferred_element_type=jnp.float32)
+        return jnp.einsum("btd,dv->btv", h, params["head"].astype(h.dtype),
                           preferred_element_type=jnp.float32)
-    return jnp.einsum("btd,dv->btv", h, params["head"].astype(h.dtype),
-                      preferred_element_type=jnp.float32)
 
 
 def nll_loss(logits, targets):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-    return jnp.mean(-jnp.take_along_axis(logp, targets[..., None], -1)[..., 0])
+    with jax.named_scope(SCOPE_HEAD):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        return jnp.mean(
+            -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0])
 
 
 # ---------------------------------------------------------------------------
@@ -1371,15 +1407,16 @@ def _fused_head_nll(params, h, targets, cfg: TransformerConfig):
     kernel-native (no vocab-sized transpose): tied configs stream the (V, D)
     embedding, untied the (D, V) head."""
     from ..kernels.fused_ce import fused_linear_nll
-    if cfg.tied_head:
-        w, layout = params["embed"].astype(h.dtype), "vd"
-    else:
-        w, layout = params["head"].astype(h.dtype), "dv"
-    V = w.shape[0] if layout == "vd" else w.shape[1]
-    h = _logit_scaled(h, cfg)
-    return fused_linear_nll(h.reshape(-1, h.shape[-1]), w,
-                            jnp.zeros((V,), jnp.float32),
-                            targets.reshape(-1), w_layout=layout)
+    with jax.named_scope(SCOPE_HEAD):
+        if cfg.tied_head:
+            w, layout = params["embed"].astype(h.dtype), "vd"
+        else:
+            w, layout = params["head"].astype(h.dtype), "dv"
+        V = w.shape[0] if layout == "vd" else w.shape[1]
+        h = _logit_scaled(h, cfg)
+        return fused_linear_nll(h.reshape(-1, h.shape[-1]), w,
+                                jnp.zeros((V,), jnp.float32),
+                                targets.reshape(-1), w_layout=layout)
 
 
 def _exit_log_q(params, exits):

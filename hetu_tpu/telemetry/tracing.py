@@ -70,6 +70,44 @@ SCOPE_SSM_SCAN = "hetu_ssm_scan"  # from the convolution's output to the
                                   # recurrence (`_ssd`), the D skip
 SCOPE_SSM_GATE = "hetu_ssm_gate"  # y SiLU(z) and its RMSNorm
 SSM_SCOPES = (SCOPE_SSM_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE)
+# the three parts of the chunked scan (transformer._ssd, _mamba), nested
+# INSIDE SCOPE_SSM_SCAN: `.../hetu_ssm_scan/hetu_ssd_inchunk/...`; dt's
+# softplus stays directly under the outer scope
+SCOPE_SSD_INCHUNK = "hetu_ssd_inchunk"  # the chunks cut, the log-decay, the
+                                        # decay matrix, masked C B^T, its
+                                        # product with x dt
+SCOPE_SSD_STATES = "hetu_ssd_states"    # each chunk's own state and the
+                                        # recurrence over the chunk states
+SCOPE_SSD_ENTER = "hetu_ssd_enter"      # the entering state's part C S, and
+                                        # the D skip
+SSD_SCOPES = (SCOPE_SSD_INCHUNK, SCOPE_SSD_STATES, SCOPE_SSD_ENTER)
+# the ordinary parts of a block (transformer._attention, _dense_mlp, _norm),
+# nested under SCOPE_FWD; benchmark/reduce/block.py reads them, an op under
+# the innermost scope of its path. A MoE block keeps MOE_SCOPES for its MLP
+# and a mamba layer SSM_SCOPES for its mixer. Residual adds, checkpoint
+# names and the residual stream's sharding constraints are in none of them
+SCOPE_BLK_QKV = "hetu_blk_qkv"    # the fused wqkv projection and its bias,
+                                  # the split, QK-norm, RoPE, the attention
+                                  # multiplier, the grouped-query repeat
+SCOPE_BLK_ATTN = "hetu_blk_attn"  # scores, softmax, P V: the flash kernels
+                                  # (`.../hetu_blk_attn/flash_fwd/...`) or
+                                  # the dot / ring path
+SCOPE_BLK_WO = "hetu_blk_wo"      # the output projection and its bias
+SCOPE_BLK_MLP_UP = "hetu_blk_mlp_up"      # w1 (and w3), bias, activation
+SCOPE_BLK_MLP_DOWN = "hetu_blk_mlp_down"  # w2 and its bias
+SCOPE_BLK_NORM = "hetu_blk_norm"  # every LayerNorm / RMSNorm of the residual
+                                  # stream (`_norm`: pre, post, sandwich,
+                                  # final); QK-norm stays with SCOPE_BLK_QKV
+BLOCK_SCOPES = (SCOPE_BLK_QKV, SCOPE_BLK_ATTN, SCOPE_BLK_WO,
+                SCOPE_BLK_MLP_UP, SCOPE_BLK_MLP_DOWN, SCOPE_BLK_NORM)
+# the two outside the block
+SCOPE_EMBED = "hetu_embed"  # token (position, segment) lookups, BERT's
+                            # embedding LayerNorm, the embedding multiplier;
+                            # backward: the scatter-add into the table
+SCOPE_HEAD = "hetu_head"    # the vocabulary head and its loss, fused or
+                            # einsum (under SCOPE_EXIT on a looped model:
+                            # `hetu_exit/hetu_head/...`); BERT's MLM
+                            # transform, decoder, NSP head and both losses
 # what the trunk's `jax.checkpoint` may keep of a layer's forward pass
 # (`jax.ad_checkpoint.checkpoint_name`; the identity outside a checkpoint).
 # Each name sits where the value is made; `transformer._remat_names` admits

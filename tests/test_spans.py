@@ -2,6 +2,8 @@
 every Pallas kernel's name and every step's phase scopes are in the lowered
 program, and the `hetu.*` spans of `SubExecutor.run` land in any
 jax.profiler capture, with no switch, on the profiler's clock."""
+import contextlib
+import os
 import re
 
 import jax
@@ -278,3 +280,184 @@ def test_timed_step_takes_its_stamps_from_the_spans(tmp_path, monkeypatch):
         assert phases["compile_ms"] > 0 and phases["prestep_ms"] >= 0
     finally:
         telemetry.shutdown()
+
+
+# -- the trunk's parts: block, embedding, head and scan scopes (PR 34) ---------
+
+NEW_SCOPES = tr.BLOCK_SCOPES + (tr.SCOPE_EMBED, tr.SCOPE_HEAD) + tr.SSD_SCOPES
+OLD_NAMES = (
+    (tr.STEP, tr.SCOPE_FWD, tr.SCOPE_OPT, tr.SCOPE_EXIT) + tr.MOE_SCOPES
+    + tr.SSM_SCOPES + tr.STEP_SPANS
+    + tuple(n for pair in tr.REMAT_CANDIDATES for n in pair)
+    + tuple(KERNEL_PROGRAMS) + ("flash_bwd_dq", "flash_bwd_dkv"))
+
+_MLP = (tr.SCOPE_BLK_MLP_UP, tr.SCOPE_BLK_MLP_DOWN)
+# dialect -> the block scopes its layers' `jax.checkpoint` recomputes (every
+# applicable one), the scopes outside the layers, and the older scopes the
+# new ones must sit beside
+DIALECTS = {
+    "bert": (tr.BLOCK_SCOPES, (tr.SCOPE_EMBED, tr.SCOPE_HEAD), ()),
+    "olmoe": (tuple(s for s in tr.BLOCK_SCOPES if s not in _MLP),
+              (tr.SCOPE_EMBED, tr.SCOPE_HEAD), tr.MOE_SCOPES),
+    "ouro": (tr.BLOCK_SCOPES, (tr.SCOPE_EMBED, tr.SCOPE_HEAD),
+             (tr.SCOPE_EXIT,)),
+    "granite": (tr.BLOCK_SCOPES + tr.SSD_SCOPES,
+                (tr.SCOPE_EMBED, tr.SCOPE_HEAD), tr.SSM_SCOPES),
+}
+
+
+def _lower_dialect(which):
+    """The train step of one small model of the dialect, lowered."""
+    from hetu_tpu.models import (hf_granite, hf_olmoe, hf_ouro,
+                                 transformer as tfm)
+    # the small published-shape configs test_granite_model.py trains on
+    from test_granite_model import HF, OLMOE_HF, OURO_HF
+    if which == "bert":
+        return _lower_bert_pretrain()
+    cfg = {"olmoe": lambda: hf_olmoe.config_from_hf(OLMOE_HF),
+           "ouro": lambda: hf_ouro.config_from_hf(OURO_HF),
+           "granite": lambda: hf_granite.config_from_hf(HF)}[which]()
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    opt = jax.eval_shape(tfm.init_opt_state, params)
+    return tfm.make_train_step(cfg).lower(params, opt, _i32(2, 32),
+                                          _i32(2, 32))
+
+
+def _segments(op_name):
+    return [re.sub(r"^(?:\w+\()+|\)+$", "", s) for s in op_name.split("/")]
+
+
+def test_new_scope_names_are_documented_and_collide_with_no_old_name():
+    """Each constant is a row of docs/OBSERVABILITY.md; no new name is a
+    substring of an old one or contains one (reduce/inside.py, moe.py,
+    ssm.py and loop.py match by substring), nor of another new one."""
+    doc = open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "OBSERVABILITY.md")).read()
+    assert len(set(NEW_SCOPES)) == len(NEW_SCOPES) == 11
+    for new in NEW_SCOPES:
+        assert f"`{new}`" in doc, new
+        for old in OLD_NAMES:
+            assert new not in old and old not in new, (new, old)
+        for other in NEW_SCOPES:
+            assert other == new or new not in other, (new, other)
+
+
+@pytest.mark.parametrize("which", sorted(DIALECTS))
+def test_block_scopes_in_the_compiled_step(which):
+    """The compiled step's `op_name` paths carry each applicable scope as a
+    plain segment in forward, recomputed (`rematted_computation`) and
+    backward (`transpose(`) form; the new scopes nest where the older ones
+    already stood (what benchmark/reduce/block.py reads)."""
+    in_block, outside, older = DIALECTS[which]
+    text = _lower_dialect(which).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    paths = {n: _segments(n) for n in names if tr.SCOPE_FWD in n}
+
+    def forms(scope):
+        under = [n for n, segs in paths.items() if scope in segs]
+        return (any("transpose(" not in n for n in under),
+                any("rematted_computation" in n for n in under),
+                any("transpose(" in n and "rematted_computation" not in n
+                    for n in under))
+
+    for scope in in_block:
+        # a pre-LN block's last matmul feeds nothing its backward pass
+        # reads: the compiler drops its recomputation (sandwich and post-LN
+        # norms read it, so BERT and Ouro run `w2` again)
+        again = not (which == "granite" and scope == tr.SCOPE_BLK_MLP_DOWN)
+        assert forms(scope) == (True, again, True), scope
+    for scope in outside:
+        # outside the layers' checkpoint: nothing is recomputed
+        assert forms(scope) == (True, False, True), scope
+    for scope in older:
+        assert any(scope in segs for segs in paths.values()), scope
+    for scope in set(NEW_SCOPES) - set(in_block) - set(outside):
+        assert not any(scope in segs for segs in paths.values()), scope
+    # nothing of the optimizer is under a part's name
+    assert not [n for n in names if tr.SCOPE_OPT in n
+                and set(_segments(n)) & set(NEW_SCOPES)]
+    if which == "ouro":
+        # the head passes are the exit head's: hetu_exit/hetu_head/...
+        head = [segs for segs in paths.values() if tr.SCOPE_HEAD in segs]
+        assert head and all(
+            tr.SCOPE_EXIT in segs
+            and segs.index(tr.SCOPE_EXIT) < segs.index(tr.SCOPE_HEAD)
+            for segs in head)
+    if which == "granite":
+        for segs in paths.values():
+            for scope in set(tr.SSD_SCOPES) & set(segs):
+                assert tr.SCOPE_SSM_SCAN in segs[:segs.index(scope)], segs
+    if which == "olmoe":
+        # a MoE block keeps its four scopes
+        assert not any(set(_MLP) & set(segs) for segs in paths.values())
+
+
+@pytest.mark.parametrize("which", sorted(DIALECTS))
+def test_the_scopes_leave_the_lowered_program_as_it_was(which, monkeypatch):
+    """Scopes are locations: with each new scope patched to a
+    `contextlib.nullcontext` the step lowers to the same StableHLO, byte for
+    byte, once locations are stripped (`as_text()` without debug info). The
+    program did not change, so no end-to-end metric can."""
+    with_scopes = _lower_dialect(which)
+    assert any(s in with_scopes.as_text(debug_info=True) for s in NEW_SCOPES)
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope",
+        lambda name: (contextlib.nullcontext() if name in NEW_SCOPES
+                      else real(name)))
+    without = _lower_dialect(which)
+    assert not any(s in without.as_text(debug_info=True) for s in NEW_SCOPES)
+    assert without.as_text() == with_scopes.as_text()
+
+
+def test_a_compile_cache_serves_the_names_it_was_filled_with(tmp_path):
+    """The trap docs/OBSERVABILITY.md warns of: jax's persistent cache keys
+    a program AFTER stripping debug info, and a named scope is debug info.
+    A program compiled without a scope and then WITH one against the same
+    cache directory is one cache entry, and the second load carries the
+    first's `op_name`s: the new scope is absent from the executable (and so
+    from a trace) until the cache is cleared."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def program(scope):
+        def f(x, w):
+            with scope():
+                y = jnp.dot(x, w)
+            return jnp.tanh(y).sum()
+        return jax.jit(f).lower(_f32(8, 16), _f32(16, 4))
+
+    named = lambda: jax.named_scope(tr.SCOPE_BLK_QKV)
+    old = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs,
+           jax.config.jax_persistent_cache_min_entry_size_bytes)
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+        bare = program(contextlib.nullcontext)
+        scoped_ = program(named)
+        # the same program once locations are stripped, another with them
+        assert bare.as_text() == scoped_.as_text()
+        assert tr.SCOPE_BLK_QKV in scoped_.as_text(debug_info=True)
+        first = bare.compile().as_text()
+        entries = sorted(p.name for p in tmp_path.iterdir()
+                         if not p.name.endswith("-atime"))
+        assert len(entries) == 1
+        second = scoped_.compile().as_text()
+        assert sorted(p.name for p in tmp_path.iterdir()
+                      if not p.name.endswith("-atime")) == entries
+        assert tr.SCOPE_BLK_QKV not in second
+        assert (re.findall(r'op_name="([^"]+)"', second)
+                == re.findall(r'op_name="([^"]+)"', first))
+        # a cleared cache compiles the names in
+        for p in tmp_path.iterdir():
+            p.unlink()
+        cc.reset_cache()
+        assert tr.SCOPE_BLK_QKV in program(named).compile().as_text()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", old[1])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", old[2])
+        cc.reset_cache()
