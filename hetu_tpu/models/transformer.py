@@ -36,7 +36,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..telemetry.tracing import (REMAT_ATTN_O, REMAT_CANDIDATES, REMAT_X1,
+from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
+                                 REMAT_ATTN_V, REMAT_CANDIDATES,
+                                 REMAT_NORM1_IN, REMAT_NORM2_IN, REMAT_X1,
                                  REMAT_X2, SCOPE_BLK_ATTN, SCOPE_BLK_MLP_DOWN,
                                  SCOPE_BLK_MLP_UP, SCOPE_BLK_NORM,
                                  SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_EMBED,
@@ -617,8 +619,8 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
 
 def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
     """The (B, T, (nh + 2 nkv) hd) projection -> q, k, v (B, T, D) as every
-    attention impl takes them: cut, QK-normed, rotated, scaled and the kv
-    heads broadcast to their query groups."""
+    attention impl takes them: cut, QK-normed, rotated, scaled, named for
+    the trunk's ``remat`` and the kv heads broadcast to their query groups."""
     B, T, _ = qkv.shape
     nh, hd, nkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
     # cut along the columns; a head stays hd columns of its array
@@ -641,6 +643,13 @@ def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
         # rest (Granite: 1/64 at hd = 64, so q * 0.125, exact)
         q = q * jnp.asarray(cfg.multipliers.attention * np.sqrt(hd),
                             q.dtype)
+    # what the attention's backward pass reads again. They are the flash
+    # `custom_vjp`'s INPUTS, so its residual IS the named value and a name
+    # on the caller's side is enough (o and lse are made inside the call and
+    # had to be named in its forward rule, `_flash_fwd`). Before the repeat:
+    # k and v are kept at `kv_heads`, the broadcast is a copy to run again
+    q, k, v = (checkpoint_name(x, name) for x, name in (
+        (q, REMAT_ATTN_Q), (k, REMAT_ATTN_K), (v, REMAT_ATTN_V)))
     if nkv != nh:
         # grouped-query: broadcast each kv head to its query group;
         # every attention impl then sees matching head counts
@@ -649,21 +658,27 @@ def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
     return q, k, v
 
 
+def _projection_in_place(cfg: TransformerConfig, mesh, impl):
+    """Whether the flash kernels read the fused [q | k | v] projection where
+    it stands: nothing touches q or k on the way and the columns lie on one
+    shard. Otherwise ``_split_heads`` makes q, k, v arrays of their own."""
+    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+    return (impl == "flash" and cfg.kv_heads == cfg.n_heads and tp == 1
+            and not (cfg.qk_norm or cfg.rope
+                     or cfg.multipliers.attention is not None))
+
+
 def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     B, T, _ = h.shape
-    nh, nkv = cfg.n_heads, cfg.kv_heads
     impl = _resolve_attn_impl(cfg, mesh, T, attn_bias)
     with jax.named_scope(SCOPE_BLK_QKV):
         qkv = jnp.einsum("btd,de->bte", h, p["wqkv"].astype(h.dtype),
                          preferred_element_type=jnp.float32).astype(h.dtype)
         if cfg.attn_proj_bias:
             qkv = qkv + p["bqkv"].astype(h.dtype)
-    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
-    if impl == "flash" and nkv == nh and tp == 1 and not (
-            cfg.qk_norm or cfg.rope
-            or cfg.multipliers.attention is not None):
-        # nothing touches q or k on the way and the [q|k|v] columns lie on
-        # one shard: the kernels read the projection where it stands
+    if _projection_in_place(cfg, mesh, impl):
+        # no name for `remat` here: a kept projection would be copied out
+        # of its stack a layer for the kernels (tracing.REMAT_CANDIDATES)
         with jax.named_scope(SCOPE_BLK_ATTN):
             out = _flash(qkv, cfg, mesh, _key_bias(attn_bias, B))
     else:
@@ -1038,7 +1053,10 @@ def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
         h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
     attn_out = _KINDS[kind].mixer(attn_in, layer_params, cfg, mesh, attn_bias)
     if cfg.sandwich_norm:
-        attn_out = _norm(attn_out, layer_params["ln1_post_scale"],
+        # the norm's backward pass reads its input: kept, the mixer's last
+        # matmul (`wo`) is not run again for it
+        attn_out = _norm(checkpoint_name(attn_out, REMAT_NORM1_IN),
+                         layer_params["ln1_post_scale"],
                          layer_params["ln1_post_bias"], cfg)
     attn_out = _dropout(_residual(attn_out, cfg), cfg.dropout_rate,
                         dropout_rng)
@@ -1075,7 +1093,9 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
         out = _dense_mlp(mlp_in, layer_params, cfg, mesh)
         aux = jnp.zeros((2,), jnp.float32)
     if cfg.sandwich_norm:
-        out = _norm(out, layer_params["ln2_post_scale"],
+        # as for the mixer's output: `w2` (a MoE block: the combine)
+        out = _norm(checkpoint_name(out, REMAT_NORM2_IN),
+                    layer_params["ln2_post_scale"],
                     layer_params["ln2_post_bias"], cfg)
     h = checkpoint_name(
         h + _dropout(_residual(out, cfg), cfg.dropout_rate, k2), REMAT_X2)
@@ -1203,12 +1223,18 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     The candidates of ``REMAT_CANDIDATES`` are admitted in their order
     while their bytes, times the block applications of a step that write
     them (``n_layers`` x ``n_loops``: a looped model keeps every pass's; o
-    and lse: the attention layers alone), stay within the budget: the limit
-    less what the step holds whatever is kept (``_state_bytes``, the stack of
-    layer inputs the scans keep, one an application,
+    and lse: the attention layers alone; q, k, v: the attention layers whose
+    ``_split_heads`` makes them, k and v at ``kv_heads``; the sandwich
+    norms' inputs: under ``cfg.sandwich_norm``), stay within the budget: the
+    limit less what the step holds whatever is kept (``_state_bytes``, the
+    stack of layer inputs the scans keep, one an application,
     ``_block_residual_bytes`` of one block, the largest among the kinds of
-    the stack, ``_REMAT_MARGIN``). A later candidate never gets in without
-    the earlier ones."""
+    the stack, ``_REMAT_MARGIN``). A candidate that no application writes
+    (the kernels read the projection in place; no sandwich norm) costs
+    nothing and adds no name; of the others a later one never gets in
+    without the earlier ones. The order of the last two is their rank by ms
+    of the step saved a GiB kept, each measured alone on the v5e
+    (``tracing.REMAT_CANDIDATES``; PERF.md, PR 36)."""
     if bytes_limit is None:
         bytes_limit = _device_bytes_limit()
     if bytes_limit is None:
@@ -1231,13 +1257,20 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
               for kind, blocks in by_kind.items()) // dp)
     attention = cfg.n_loops * sum(
         n for kind, n in layer_runs(cfg) if kind == "attention")
+    split = 0 if _projection_in_place(
+        cfg, mesh, _resolve_attn_impl(cfg, mesh, T, attn_bias)) else attention
     # {x1, x2} of every block (pre-LN: x2 is the block's output, which the
-    # scan keeps anyway), then {o, lse} of an attention block: the bytes of
-    # each, times the applications that write them
+    # scan keeps anyway), {o, lse} of an attention block, {q, k, v} of one on
+    # the split path, the two sandwich norms' inputs of every block: the
+    # bytes of each, times the applications that write them
     costs = (applications * by_seq * (2 if cfg.post_ln else 1),
-             attention * (by_head + lse))
+             attention * (by_head + lse),
+             split * (by_head + 2 * by_head * cfg.kv_heads // cfg.n_heads),
+             applications * 2 * by_seq if cfg.sandwich_norm else 0)
     names, held = (), 0
     for candidate, cost in zip(REMAT_CANDIDATES, costs):
+        if not cost:
+            continue
         if held + cost > budget:
             break
         names, held = names + candidate, held + cost

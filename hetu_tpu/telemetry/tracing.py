@@ -116,10 +116,32 @@ REMAT_X1 = "hetu_x1"      # h + attention's output: what the next norm reads
 REMAT_X2 = "hetu_x2"      # x1 + the MLP's output (post-LN: ln2's input)
 REMAT_ATTN_O = "hetu_attn_o"      # attention's output, `wo`'s input
 REMAT_ATTN_LSE = "hetu_attn_lse"  # the flash kernel's row statistic
-# in the order `_remat_names` admits them. The fused q|k|v projection is NOT
-# among them: kept, it cost the v5e as much to write and read back as its
-# matmul costs to run again, for 3.4 GiB (PERF.md, PR 28)
-REMAT_CANDIDATES = ((REMAT_X1, REMAT_X2), (REMAT_ATTN_O, REMAT_ATTN_LSE))
+# q, k, v as the attention kernels take them on the SPLIT path
+# (`transformer._split_heads`: after QK-norm, RoPE and the multiplier, before
+# the grouped-query repeat, so k and v at `kv_heads`): what the backward
+# kernels read again, and with them `wqkv` and RoPE's rolls
+REMAT_ATTN_Q = "hetu_attn_q"
+REMAT_ATTN_K = "hetu_attn_k"
+REMAT_ATTN_V = "hetu_attn_v"
+# the sandwich norms' inputs (`cfg.sandwich_norm` alone: elsewhere the value
+# feeds a residual add, whose backward pass reads nothing): the mixer's
+# output, `wo`'s on an attention layer, and the MLP's, `w2`'s
+REMAT_NORM1_IN = "hetu_norm1_in"
+REMAT_NORM2_IN = "hetu_norm2_in"
+# in the order `_remat_names` admits them; the last two groups by ms of the
+# step saved a GiB kept on the v5e, each measured alone on Ouro's 24 block
+# applications: q, k, v 20.8 ms for 1.125 GiB, the norms' inputs 6.3 for
+# 0.75 (PERF.md, PR 36). The rule on q, k, v is BY PATH, read from the code
+# and not from the model. Where the kernels read the fused [q | k | v]
+# projection IN PLACE (`transformer._attention`: nothing touches q or k on
+# the way; BERT) nothing of it is a candidate: a layer's slice of the kept
+# `[layers, B, T, 3 D]` stack has to be copied out for them, which cost the
+# v5e as much as the matmul costs to run again, for 3.4 GiB (PERF.md, PR
+# 28). On the split path q, k and v are three arrays of their own already,
+# and running them again is RoPE's rolls on top of the matmul
+REMAT_CANDIDATES = ((REMAT_X1, REMAT_X2), (REMAT_ATTN_O, REMAT_ATTN_LSE),
+                    (REMAT_ATTN_Q, REMAT_ATTN_K, REMAT_ATTN_V),
+                    (REMAT_NORM1_IN, REMAT_NORM2_IN))
 # host spans inside SubExecutor.run, children of STEP, in call order
 (BOUNDARY, FEED, DL_WAIT, PS_PULL, BUILD, DISPATCH, PREFETCH, PS_PUSH,
  POSTSTEP) = STEP_SPANS = (

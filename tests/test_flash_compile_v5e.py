@@ -363,3 +363,66 @@ def test_layer_copies_nothing_around_attention(one_chip, no_compile_cache,
     assert kernels == {**none, fa.FLASH_FWD: 1, **backward}
     assert matmuls <= bare_matmuls - (2 if cfg.post_ln else 1), (
         matmuls, bare_matmuls)
+
+
+def _recomputed_matmuls(text):
+    """{block scope: matmuls} among the instructions of a compiled program
+    that a `jax.checkpoint` runs again (`rematted_computation` in their
+    `op_name`), inside fusions and loop bodies too."""
+    found = {}
+    for line in text.splitlines():
+        op_name = re.search(r'op_name="([^"]+)"', line)
+        if (op_name and "rematted_computation" in op_name.group(1)
+                and re.search(r" (convolution|dot)\(", line)):
+            scope = re.findall(r"hetu_blk_\w+", op_name.group(1))[-1]
+            found[scope] = found.get(scope, 0) + 1
+    return found
+
+
+def test_ouro_trunk_keeps_what_a_norm_or_a_kernel_reads(one_chip,
+                                                        no_compile_cache,
+                                                        monkeypatch):
+    """`encode` at the Ouro cell's shapes, 24 block applications, under the
+    trunk's own policy at the limit a v5e reports: every candidate is
+    admitted, the backward scan runs `w1` and `w3` again and no other
+    matmul, the forward kernel once, and what keeping adds to the plan's
+    scratch stays under the budget `_remat_names` found for it."""
+    from hetu_tpu.models import transformer as tfm
+    from hetu_tpu.telemetry import tracing as tr
+    from test_remat import _ouro    # the cell's config, abstract parameters
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params = _ouro()
+    assert cfg.rope and cfg.remat
+    h = jax.ShapeDtypeStruct((1, 4096, cfg.d_model), cfg.dtype,
+                             sharding=one_chip)
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), params)
+
+    def loss(params, h):
+        exits, _ = tfm.encode(params, h, cfg)
+        return jnp.sum(exits.astype(jnp.float32) ** 2)
+
+    def compiled(limit):
+        monkeypatch.setattr(tfm, "_device_bytes_limit", lambda: limit)
+        c = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, h).compile()
+        text = c.as_text()
+        return (_recomputed_matmuls(text), _count_by_name(_kernel_calls(text)),
+                c.memory_analysis().temp_size_in_bytes)
+
+    up, down = tr.SCOPE_BLK_MLP_UP, tr.SCOPE_BLK_MLP_DOWN
+    # no limit reported: the bare checkpoint, the whole layer again
+    again, kernels, bare_temp = compiled(None)
+    assert again == {tr.SCOPE_BLK_QKV: 1, tr.SCOPE_BLK_WO: 1, up: 2, down: 1}
+    assert kernels[fa.FLASH_FWD] == 2
+
+    names, held, budget = tfm._remat_names(
+        cfg, params, h, None, bytes_limit=int(15.75 * 2 ** 30))
+    assert names == sum(tr.REMAT_CANDIDATES, ())
+    again, kernels, temp = compiled(int(15.75 * 2 ** 30))
+    assert again == {up: 2}                    # w1 and w3: SiLU(gate) * up
+    assert kernels == {fa.FLASH_FWD: 1, fa.FLASH_BWD: 0,
+                       fa.FLASH_BWD_DQ: 1, fa.FLASH_BWD_DKV: 1}
+    # the plan holds more than the stacks themselves (a custom call's
+    # operand is copied out of its stack), and still fits
+    assert held <= temp - bare_temp <= budget, (held, temp - bare_temp, budget)

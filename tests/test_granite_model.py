@@ -389,8 +389,9 @@ def test_ssm_scopes_in_the_compiled_program_forward_and_backward():
 
 
 def test_remat_counts_bytes_by_kind(monkeypatch):
-    """x1 and x2 cost every layer's (B, T, D); o and lse the ONE attention
-    layer's; the working set is the larger kind's (the Mamba block's)."""
+    """x1 and x2 cost every layer's (B, T, D); o, lse, q, k and v the ONE
+    attention layer's; the working set is the larger kind's (the Mamba
+    block's)."""
     cfg, params = _seeded(HF, 20)
     cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
     h = jax.ShapeDtypeStruct((2, T, 64), jnp.bfloat16)
@@ -404,8 +405,10 @@ def test_remat_counts_bytes_by_kind(monkeypatch):
     limit = int((state + 4 * act + by_kind["mamba"] + 10 * act) * 32 / 31) + 64
     names, held, budget = tfm._remat_names(cfg, params, h, None,
                                            bytes_limit=limit)
-    assert names == tracing.REMAT_CANDIDATES[0] + tracing.REMAT_CANDIDATES[1]
-    assert held == 4 * act + 1 * (act + lse)
+    # and its q with k and v at 2 of 4 heads (the split path: grouped
+    # heads and a multiplier); no sandwich norm, so the last group is skipped
+    assert names == sum(tracing.REMAT_CANDIDATES[:3], ())
+    assert held == 4 * act + 1 * (act + lse) + 1 * (act + 2 * act * 2 // 4)
     names, held, _ = tfm._remat_names(
         cfg, params, h, None, bytes_limit=limit - 10 * act + 4 * act + 8)
     assert names == tracing.REMAT_CANDIDATES[0] and held == 4 * act

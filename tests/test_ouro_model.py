@@ -222,10 +222,14 @@ def test_a_block_weight_gradient_is_the_sum_over_passes_of_the_untied_model(
 # jax 0.9.0), made by this very function there: a model with one exit and no
 # sandwich norm runs the program it ran before the loop existed.
 # "fused-small" was made again in PR 30, which changed the fused CE's
-# kernels on purpose (before: a5899be7c4b2d5d0, 2883 lines).
-GOLDEN = {"bert-small": ("8a382cdc3978a109", 1237),
-          "olmoe-small": ("a29bf60f74c5cd33", 1733),
-          "fused-small": ("d9ea68b963874d0f", 2902)}
+# kernels on purpose (before: a5899be7c4b2d5d0, 2883 lines). PR 36 made all
+# three again AT ITS PARENT (5438e58) with the symbols' counters cut off:
+# its three names in `_split_heads` renumber the private functions and move
+# nothing else (with the counters: 8a382cdc3978a109, a29bf60f74c5cd33,
+# d9ea68b963874d0f, the same lines).
+GOLDEN = {"bert-small": ("617e8252fb383233", 1237),
+          "olmoe-small": ("ce3bd8fa8df83045", 1733),
+          "fused-small": ("b3d89febeac34c1c", 2902)}
 
 
 def _lowered_digest(which):
@@ -258,6 +262,9 @@ def _lowered_digest(which):
         fn = jax.value_and_grad(
             lambda p, x, y: tfm.loss_fn(p, x, y, cfg))
         text = jax.jit(fn).lower(params, tok, tok).as_text()
+    # a private function's symbol ends in a counter that every traced
+    # equation moves, a `checkpoint_name` too, which lowers to nothing
+    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
     return hashlib.sha256(text.encode()).hexdigest()[:16], text.count("\n")
 
 

@@ -12,18 +12,32 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from hetu_tpu.models import bert, hf_olmoe, hf_ouro
+from hetu_tpu.models import bert, hf_granite, hf_olmoe, hf_ouro
 from hetu_tpu.models import transformer as tfm
 from hetu_tpu.parallel import mesh as meshlib
-from hetu_tpu.telemetry.tracing import (REMAT_ATTN_LSE, REMAT_ATTN_O,
-                                        REMAT_CANDIDATES, REMAT_X1, REMAT_X2)
+from hetu_tpu.telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_LSE,
+                                        REMAT_ATTN_O, REMAT_ATTN_Q,
+                                        REMAT_ATTN_V, REMAT_CANDIDATES,
+                                        REMAT_NORM1_IN, REMAT_NORM2_IN,
+                                        REMAT_X1, REMAT_X2)
 
 from test_transformer import tiny_cfg
 
 GiB = 2 ** 30
 ALL = (REMAT_X1, REMAT_X2, REMAT_ATTN_O, REMAT_ATTN_LSE)
-OLMOE_JSON = os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                          "configs", "olmoe-1b-7b", "config.json")
+# what the split path (RoPE, QK-norm, a multiplier, grouped heads) and the
+# sandwich norms add (PR 36): BERT writes neither
+QKV = (REMAT_ATTN_Q, REMAT_ATTN_K, REMAT_ATTN_V)
+SANDWICH = (REMAT_NORM1_IN, REMAT_NORM2_IN)
+EVERY = ALL + QKV + SANDWICH
+
+
+def _published(config):
+    """The `config.json` a benchmark cell of ``config`` runs."""
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", config, "config.json")
+    with open(path) as f:
+        return json.load(f)
 
 
 def _bert():
@@ -36,9 +50,8 @@ def _bert():
 
 def _olmoe():
     """The cell olmoe-1b-7b.pretrain-seq4096: one layer of 64 experts."""
-    with open(OLMOE_JSON) as f:
-        cfg = hf_olmoe.config_from_hf(json.load(f), dtype=jnp.bfloat16,
-                                      attn_impl="flash")
+    cfg = hf_olmoe.config_from_hf(_published("olmoe-1b-7b"),
+                                  dtype=jnp.bfloat16, attn_impl="flash")
     assert (cfg.n_layers, cfg.n_experts, cfg.n_experts_per_tok) == (1, 64, 8)
     params = jax.eval_shape(
         lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
@@ -46,19 +59,26 @@ def _olmoe():
     return cfg, params
 
 
-OURO_JSON = os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                         "configs", "ouro-2.6b", "config.json")
-
-
 def _ouro(**overrides):
     """The cell ouro-2.6b.pretrain-seq4096-b1: 6 layers applied 4 times."""
-    with open(OURO_JSON) as f:
-        cfg = hf_ouro.config_from_hf(json.load(f), dtype=jnp.bfloat16,
-                                     attn_impl="flash")
+    cfg = hf_ouro.config_from_hf(_published("ouro-2.6b"), dtype=jnp.bfloat16,
+                                 attn_impl="flash")
     assert (cfg.n_layers, cfg.n_loops, cfg.sandwich_norm) == (6, 4, True)
     params = jax.eval_shape(
         lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
     return dataclasses.replace(cfg, **overrides), params
+
+
+def _granite():
+    """The cell granite-4.0-h-micro.pretrain-seq8192-b1: five Mamba-2 layers,
+    then ONE attention layer of 32 query heads on 8 k/v heads, no RoPE (the
+    split path by its multiplier and its grouped heads)."""
+    cfg = hf_granite.config_from_hf(_published("granite-4.0-h-micro"),
+                                    dtype=jnp.bfloat16, attn_impl="flash")
+    assert (cfg.n_heads, cfg.kv_heads, cfg.rope) == (32, 8, False)
+    assert tfm.layer_runs(cfg) == (("mamba", 5), ("attention", 1))
+    return cfg, jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
 
 
 def _rule(model, batch, seq, dp, limit_gib, bias=True):
@@ -74,17 +94,35 @@ def _rule(model, batch, seq, dp, limit_gib, bias=True):
 
 
 @pytest.mark.parametrize("model,batch,seq,dp,limit_gib,names,held_gib", [
-    pytest.param(_bert, 128, 512, 1, 16, ALL, (3.3, 3.5), id="bert-seq512"),
-    pytest.param(_bert, 512, 128, 1, 16, ALL, (3.3, 3.5), id="bert-seq128"),
-    pytest.param(_bert, 512, 512, 4, 16, ALL, (3.3, 3.5),
+    # BERT reads its projection in place and has no sandwich norm: the four
+    # names and the 3,492 MiB they were before PR 36 (24 x 96 + 12 x 99)
+    pytest.param(_bert, 128, 512, 1, 16, ALL, (3.41, 3.4102),
+                 id="bert-seq512"),
+    pytest.param(_bert, 512, 128, 1, 16, ALL, (3.41, 3.4102),
+                 id="bert-seq128"),
+    pytest.param(_bert, 512, 512, 4, 16, ALL, (3.41, 3.4102),
                  id="bert-seq512-dp4"),
     # the limit a v5e reports: what the chip runs are held to
-    pytest.param(_bert, 128, 512, 1, 15.75, ALL, (3.3, 3.5),
+    pytest.param(_bert, 128, 512, 1, 15.75, ALL, (3.41, 3.4102),
                  id="bert-seq512-v5e-limit"),
+    pytest.param(_bert, 512, 128, 1, 15.75, ALL, (3.41, 3.4102),
+                 id="bert-seq128-v5e-limit"),
     pytest.param(_olmoe, 8, 4096, 1, 16, (), (0, 0), id="olmoe-seq4096"),
-    # between the two: 7.6 GiB of state, 24 block applications of 16 MiB
-    pytest.param(_ouro, 1, 4096, 1, 15.75, ALL, (0.75, 0.77),
+    pytest.param(_olmoe, 8, 4096, 1, 15.75, (), (0, 0),
+                 id="olmoe-seq4096-v5e-limit"),
+    # between the two: 7.6 GiB of state, 24 block applications of 16 MiB.
+    # The split path under sandwich norms: 0.76 GiB of x1, o, lse, then q, k,
+    # v (1.125) and the two norm inputs (0.75)
+    pytest.param(_ouro, 1, 4096, 1, 15.75, EVERY, (2.62, 2.66),
                  id="ouro-seq4096-b1-v5e-limit"),
+    # under water by the Mamba block's residuals: nothing, as before PR 36
+    pytest.param(_granite, 1, 8192, 1, 15.75, (), (0, 0),
+                 id="granite-seq8192-b1-v5e-limit"),
+    # given room: six x1 of 32 MiB, ONE attention layer's o + lse (33), and
+    # its q (32) with k and v at 8 of 32 heads (8 each): 273 MiB. At
+    # `n_heads` k and v would make it 321
+    pytest.param(_granite, 1, 8192, 1, 24, ALL + QKV, (0.2666, 0.2667),
+                 id="granite-seq8192-b1-room"),
     pytest.param(_ouro, 1, 4096, 1, None, (), (0, 0), id="ouro-no-limit"),
     pytest.param(_bert, 128, 512, 1, None, (), (0, 0), id="bert-no-limit"),
     pytest.param(_olmoe, 8, 4096, 1, None, (), (0, 0), id="olmoe-no-limit"),
@@ -111,31 +149,41 @@ def test_remat_names_count_every_application_of_a_looped_model():
     names4, held4, budget4 = _rule(_ouro, 1, 4096, 1, 15.75, bias=False)
     names1, held1, budget1 = _rule(functools.partial(_ouro, n_loops=1),
                                    1, 4096, 1, 15.75, bias=False)
-    assert names4 == names1 == ALL
-    assert held1 == 6 * (act + act + lse)
-    assert held4 == 24 * (act + act + lse) == 4 * held1
+    assert names4 == names1 == EVERY
+    # x1; o and lse; q, k, v (k and v at all 16 heads); the two norms' inputs
+    assert held1 == 6 * (act + (act + lse) + 3 * act + 2 * act)
+    assert held4 == 24 * (7 * act + lse) == 4 * held1
     # the same state (the params handed in are the looped model's): the
     # budgets differ by the 18 more layer inputs the scans keep
     assert budget1 - budget4 == 18 * act
-    # a limit at which one pass keeps everything and four passes do not
-    for limit_gib, looped, once in ((10, ALL[:2], ALL), (9.5, (), ALL)):
+    # limits at which one pass keeps more of the chain than four passes do
+    for limit_gib, looped, once in ((12, ALL + QKV, EVERY),
+                                    (10, ALL[:2], EVERY), (9.5, (), ALL)):
         assert _rule(_ouro, 1, 4096, 1, limit_gib, bias=False)[0] == looped
         assert _rule(functools.partial(_ouro, n_loops=1), 1, 4096, 1,
                      limit_gib, bias=False)[0] == once
 
 
-PREFIXES = [(), ALL[:2], ALL]
+# the chain a model can write, shortest first: BERT (in place, no sandwich
+# norm) skips the last two groups, Ouro (RoPE under sandwich norms) none
+CHAINS = {"bert": (_bert, 128, 512, [(), ALL[:2], ALL]),
+          "ouro": (_ouro, 1, 4096, [(), ALL[:2], ALL, ALL + QKV, EVERY])}
 
 
 @pytest.mark.parametrize("limit_gib", [16, 14, 12, 11, 10.5, 10, 9.5, 9,
                                        8.5, 8, 6, 2])
-def test_remat_names_shrink_as_a_prefix(limit_gib):
+@pytest.mark.parametrize("chain", list(CHAINS))
+def test_remat_names_shrink_as_a_prefix(chain, limit_gib):
     """As the limit falls the set shrinks in the stated order, {x1, x2},
-    then o and lse: never the later candidate without the earlier."""
-    assert sum(REMAT_CANDIDATES, ()) == ALL
-    got, held, budget = _rule(_bert, 128, 512, 1, limit_gib)
-    assert got in PREFIXES
-    more, held_more, budget_more = _rule(_bert, 128, 512, 1, limit_gib + 1)
+    o and lse, then (where the model writes them) q, k, v and the sandwich
+    norms' inputs: never the later candidate without the earlier."""
+    assert sum(REMAT_CANDIDATES, ()) == EVERY
+    model, batch, seq, prefixes = CHAINS[chain]
+    rule = functools.partial(_rule, model, batch, seq, 1,
+                             bias=model is _bert)
+    got, held, budget = rule(limit_gib)
+    assert got in prefixes
+    more, held_more, budget_more = rule(limit_gib + 1)
     assert len(more) >= len(got) and held_more >= held
     assert budget_more - budget == pytest.approx(
         GiB * (1 - tfm._REMAT_MARGIN), abs=2)
@@ -143,9 +191,12 @@ def test_remat_names_shrink_as_a_prefix(limit_gib):
         assert got == ()
 
 
-def test_remat_names_reach_every_prefix():
-    seen = {_rule(_bert, 128, 512, 1, g / 2)[0] for g in range(4, 33)}
-    assert seen == set(PREFIXES)
+@pytest.mark.parametrize("chain", list(CHAINS))
+def test_remat_names_reach_every_prefix(chain):
+    model, batch, seq, prefixes = CHAINS[chain]
+    seen = {_rule(model, batch, seq, 1, g / 4, bias=model is _bert)[0]
+            for g in range(8, 65)}
+    assert seen == set(prefixes)
 
 
 # ---------------------------------------------------------------------------
@@ -202,13 +253,31 @@ def _pre_ln_rope_qknorm():
                     rope=True, qk_norm=True, mlp="swiglu", use_pos_emb=False)
 
 
+def _sandwich_rope():
+    """Ouro's block: RoPE (so the split path), a norm on either side of each
+    sublayer, SwiGLU."""
+    return tiny_cfg(d_model=128, n_heads=2, n_layers=2, d_ff=256,
+                    max_seq_len=128, attn_impl="flash", norm="rmsnorm",
+                    rope=True, mlp="swiglu", use_pos_emb=False,
+                    sandwich_norm=True)
+
+
 @pytest.mark.parametrize("dp", [1, 4], ids=["one-device", "dp4-shard_map"])
-@pytest.mark.parametrize("config,recomputed", [
-    pytest.param(_post_ln_dense, 2, id="post-ln-dense"),    # wqkv, w1
-    pytest.param(_pre_ln_rope_qknorm, 3, id="pre-ln-rope-qknorm-swiglu"),
-])                                                          # wqkv, w1, w3
+@pytest.mark.parametrize("config,bare,recomputed,kept", [
+    # the bare checkpoint runs wqkv, wo, w1, w2 (ln2 reads its sum) again;
+    # named: wqkv (the kernels read it in place: not a candidate) and w1
+    pytest.param(_post_ln_dense, 4, 2, ALL, id="post-ln-dense"),
+    # bare: wqkv, wo, w1, w3. Named: w1, w3 and STILL wqkv: q, k and v are
+    # kept, so RoPE does not run again, but QK-norm's backward pass reads
+    # the q and k the projection wrote, which no name covers
+    pytest.param(_pre_ln_rope_qknorm, 4, 3, ALL + QKV,
+                 id="pre-ln-rope-qknorm-swiglu"),
+    # bare: wqkv, wo, w1, w3, w2 (a norm reads wo's and w2's outputs).
+    # Named: w1 and w3 alone
+    pytest.param(_sandwich_rope, 5, 2, EVERY, id="sandwich-rope-swiglu"),
+])
 def test_named_checkpoint_skips_kernel_and_output_matmuls(monkeypatch, config,
-                                                recomputed, dp):
+                                                bare, recomputed, kept, dp):
     cfg = config()
     mesh = (meshlib.make_mesh(dp=dp, devices=jax.devices()[:dp])
             if dp > 1 else None)
@@ -229,16 +298,16 @@ def test_named_checkpoint_skips_kernel_and_output_matmuls(monkeypatch, config,
     grads = {"plain": jax.jit(jax.grad(plain, argnums=(0, 1)))(params, h),
              "bare": jax.jit(jax.grad(remat, argnums=(0, 1)))(params, h)}
     monkeypatch.setattr(tfm, "_device_bytes_limit", lambda: 64 * GiB)
-    assert tfm._remat_names(remat.keywords["cfg"], params, h, mesh)[0] == ALL
+    assert tfm._remat_names(remat.keywords["cfg"], params, h, mesh)[0] == kept
     counts["named"] = _backward_scan_counts(remat, params, h)
     grads["named"] = jax.jit(jax.grad(remat, argnums=(0, 1)))(params, h)
 
-    # the bare checkpoint runs the layer's forward again, kernel and all:
-    # wqkv, wo, w1 (w3), and w2 where a norm reads its sum (post-LN)
+    # the bare checkpoint runs the layer's forward again, kernel and all
     assert counts["bare"]["pallas_call"] == counts["plain"]["pallas_call"] + 1
-    assert (counts["bare"]["dot_general"] == counts["plain"]["dot_general"]
-            + (2 if cfg.post_ln else 1) + recomputed)
-    # with the four names kept: no attention kernel, no wo, no w2
+    assert (counts["bare"]["dot_general"]
+            == counts["plain"]["dot_general"] + bare)
+    # with every name the config writes kept: no attention kernel, no wo,
+    # no w2, and on the split path without QK-norm no wqkv
     assert counts["named"]["pallas_call"] == counts["plain"]["pallas_call"]
     assert (counts["named"]["dot_general"]
             == counts["plain"]["dot_general"] + recomputed)
@@ -250,11 +319,15 @@ def test_named_checkpoint_skips_kernel_and_output_matmuls(monkeypatch, config,
                 atol=1e-5 * float(jnp.max(jnp.abs(want))), err_msg=other)
 
 
-def test_names_are_the_identity_outside_a_checkpoint():
+@pytest.mark.parametrize("config", [
+    pytest.param(_post_ln_dense, id="post-ln-dense"),
+    pytest.param(_sandwich_rope, id="sandwich-rope-swiglu"),
+])
+def test_names_are_the_identity_outside_a_checkpoint(config):
     """`remat=False` callers see the same program with or without names."""
-    cfg = _post_ln_dense()
+    cfg = config()
     params = tfm.init_trunk_params(jax.random.PRNGKey(0), cfg)
     h = jnp.ones((2, 128, cfg.d_model), cfg.dtype)
     text = jax.jit(lambda p, h: tfm.encode(p, h, cfg)[0]).lower(
         params, h).as_text()
-    assert "hetu_x1" not in text and "hetu_attn_o" not in text
+    assert not [name for name in EVERY if name in text]
