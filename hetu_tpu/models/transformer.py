@@ -28,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import math
 from typing import Any, Optional
 
 import numpy as np
@@ -1159,28 +1160,22 @@ def _router_normalize(top_p):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_rows(x, order, inv, k, held=None):
+def _dispatch_rows(x, order, inv, k):
     """Token rows (S, D) -> pick rows (S*k, D) in sorted order: pick
     ``order[i]`` belongs to token ``order[i] // k``. ``inv`` is ``order``'s
     inverse: the cotangent is a gather and a sum over the k picks, where
-    autodiff would emit a scatter-add of S*k rows. ``held`` (S, k) bool, of
-    a share: the picks whose expert is here; the others' rows lie past the
-    grouped matmuls' groups, which write nothing there, so their cotangent
-    rows are not read."""
+    autodiff would emit a scatter-add of S*k rows."""
     return x[order // k]
 
 
-def _dispatch_rows_fwd(x, order, inv, k, held=None):
-    return x[order // k], (inv, held)
+def _dispatch_rows_fwd(x, order, inv, k):
+    return x[order // k], inv
 
 
-def _dispatch_rows_bwd(k, res, g):
-    inv, held = res
+def _dispatch_rows_bwd(k, inv, g):
     S = g.shape[0] // k
     picks = g[inv].reshape(S, k, -1).astype(jnp.float32)
-    if held is not None:
-        picks = jnp.where(held[..., None], picks, 0.0)
-    return jnp.sum(picks, 1).astype(g.dtype), None, None, None
+    return jnp.sum(picks, 1).astype(g.dtype), None, None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
@@ -1212,6 +1207,231 @@ def _grouped_matmul(xs, w, group_sizes):
                               preferred_element_type=xs.dtype)
 
 
+def _swiglu(gate, up):
+    """silu(gate) * up, in float32, at ``gate``'s dtype."""
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+# -- a share's row loops ----------------------------------------------------------
+# On a share (``Router.width``) the held picks sort FIRST, so the rows that
+# matter are [0, n) of the sorted order, n = the held experts' picks, a
+# number on the device. Every pass around the grouped matmuls cuts the rows
+# into chunks of R and runs ``ceil(n / R)`` of them, a run-time trip count:
+# no host read, one program at any load, exact at any load (all chunks with
+# every pick here, none with none). Rows past the last chunk run are never
+# written and never read: the grouped matmuls read their groups only.
+
+# bytes of (., d_model) rows in the compute dtype a chunk covers: 1,024 rows
+# of 4 KB. On the v5e a loop step costs what its rows cost (512 to 4,096 rows
+# a step gather alike, PERF.md PR 38), and a chunk more in a layer ~0.4 ms
+# over all passes, 0.05 % of the lfm2 cell's step and less than a tenth of
+# what a point of held share does: where a load's edge falls among the
+# chunks does not show in the step time
+_ROW_CHUNK_BYTES = 4 << 20
+
+
+def _row_chunk(S, D, dtype):
+    """Rows a chunk of a share's loops covers, from the shapes alone: what
+    ``_ROW_CHUNK_BYTES`` hold of (., D) rows, as a power of two that divides
+    S (and so S*k); S whole where S has no such divisor of 8 or more."""
+    want = max(8, _ROW_CHUNK_BYTES // (D * jnp.dtype(dtype).itemsize))
+    R = math.gcd(S, 1 << (want.bit_length() - 1))
+    return R if R >= 8 else S
+
+
+def _rows_run(n, R, N):
+    """Rows the loops cover when ``n`` of N matter: whole chunks of R. The
+    loops' trip count is this over R, and ``moe_routing_stats`` reports it."""
+    return jnp.minimum(-(-n // R) * R, N)
+
+
+def _loop_rows(rows, R, body, init):
+    """``carry = body(start, carry)`` for start = 0, R, ... below ``rows``
+    (a run-time multiple of R)."""
+    return jax.lax.fori_loop(0, rows // R,
+                             lambda c, carry: body(c * R, carry), init)
+
+
+def _cut(a, start, R):
+    return jax.lax.dynamic_slice_in_dim(a, start, R)
+
+
+def _put(buf, rows, start):
+    return jax.lax.dynamic_update_slice_in_dim(buf, rows, start, 0)
+
+
+def _share_plan(order, inv, held, counts, R):
+    """What a share's loops read beside their rows, from the routing alone
+    (integers; no gradient): ``order`` / ``inv`` / ``held`` as given;
+    ``rows_run`` the sorted rows to cover. For the passes from sorted rows
+    back to tokens (gathers only, no scatter): ``into`` (S, k, k) puts a
+    token's held picks into its first slots, ``by_held`` orders tokens by
+    how many picks they hold, most first, ``rank`` undoes it, ``rows``
+    (S, k) the sorted row of slot j of token ``by_held[i]``, ``n_held`` (S,)
+    theirs in that order, ``tokens_run`` the tokens that hold any. Slot j
+    then is a PREFIX of that order, so its rows are gathered in chunks while
+    tokens remain, sum over j = the held rows, and one gather of S rows
+    returns the sums to token order."""
+    S, k = held.shape
+    slot = jnp.cumsum(held, -1) - 1
+    into = held[:, :, None] & (slot[:, :, None] == jnp.arange(k))
+    rows = jnp.sum(jnp.where(into, inv.reshape(S, k, 1), 0), 1)
+    # ONE sort carries the tokens' rows along: no gather of S small rows
+    fewest_last, by_held, *rows = jax.lax.sort(
+        (-jnp.sum(held, -1, dtype=jnp.int32), jnp.arange(S, dtype=jnp.int32))
+        + tuple(rows[:, j] for j in range(k)), num_keys=1, is_stable=True)
+    return {"order": order, "inv": inv, "held": held,
+            "rows_run": _rows_run(jnp.sum(counts), R, S * k),
+            "into": into, "by_held": by_held,
+            "rank": jnp.argsort(by_held).astype(jnp.int32),
+            "rows": jnp.stack(rows, -1), "n_held": -fewest_last,
+            "tokens_run": _rows_run(jnp.sum(fewest_last < 0), R, S)}
+
+
+def _rows_to_tokens(src, plan, weights, R):
+    """Sorted rows ``src`` (S*k, D) -> (S, D): a token's sum over its held
+    picks of (``weights`` (S, k), by pick, times) the pick's row, summed in
+    float32. The tokens' order and slots are ``_share_plan``'s."""
+    rows, n_held = plan["rows"], plan["n_held"]
+    (S, k), dtype = rows.shape, src.dtype
+    if weights is not None:
+        weights = jnp.sum(jnp.where(plan["into"], weights[:, :, None], 0.0),
+                          1)[plan["by_held"]]
+
+    def body(start, buf):
+        r, n = _cut(rows, start, R), _cut(n_held, start, R)
+        w = None if weights is None else _cut(weights, start, R)
+
+        def add(acc, j):
+            y = src[r[:, j]].astype(jnp.float32)
+            if w is not None:
+                y = y * w[:, j, None]
+            return acc + jnp.where((n > j)[:, None], y, 0.0)
+
+        acc = add(jnp.zeros((R, src.shape[1]), jnp.float32), 0)
+        for j in range(1, k):       # tokens hold fewer picks further on
+            acc = jax.lax.cond(n[0] > j, functools.partial(add, j=j),
+                               lambda acc: acc, acc)
+        return _put(buf, acc.astype(dtype), start)
+
+    buf = _loop_rows(plan["tokens_run"], R, body,
+                     jax.lax.empty((S, src.shape[1]), dtype))
+    return jnp.where(jnp.any(plan["held"], -1, keepdims=True),
+                     buf[plan["rank"]], jnp.zeros((), dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _share_dispatch(x, plan, R):
+    """``_dispatch_rows`` on a share: rows [0, ``plan["rows_run"]``) of the
+    sorted order are gathered, a chunk a step; the rest is not written. The
+    cotangent sums a token's HELD picks' rows (``_rows_to_tokens``)."""
+    order, k = plan["order"], plan["held"].shape[1]
+
+    def body(start, xs):
+        return _put(xs, x[_cut(order, start, R) // k], start)
+
+    return _loop_rows(plan["rows_run"], R, body,
+                      jax.lax.empty((order.shape[0], x.shape[1]), x.dtype))
+
+
+def _share_dispatch_fwd(x, plan, R):
+    return _share_dispatch(x, plan, R), plan
+
+
+def _share_dispatch_bwd(R, plan, g):
+    return _rows_to_tokens(g, plan, None, R), None
+
+
+_share_dispatch.defvjp(_share_dispatch_fwd, _share_dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_twice(xs, rows, R):
+    """``xs`` for its two readers (the gate's and the up projection's
+    grouped matmul): their cotangents are summed on the first ``rows`` rows,
+    a chunk a step, where autodiff would add all of both arrays."""
+    return xs, xs
+
+
+def _rows_twice_fwd(xs, rows, R):
+    return (xs, xs), rows
+
+
+def _rows_twice_bwd(R, rows, g):
+    def body(start, acc):        # the sum written over the first
+        return _put(acc, _cut(acc, start, R) + _cut(g[1], start, R), start)
+
+    return _loop_rows(rows, R, body, g[0]), None
+
+
+_rows_twice.defvjp(_rows_twice_fwd, _rows_twice_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _swiglu_rows(gate, up, rows, R):
+    """``_swiglu`` on the first ``rows`` rows, a chunk a step."""
+    def body(start, out):
+        return _put(out, _swiglu(_cut(gate, start, R), _cut(up, start, R)),
+                    start)
+
+    return _loop_rows(rows, R, body, jax.lax.empty(gate.shape, gate.dtype))
+
+
+def _swiglu_rows_fwd(gate, up, rows, R):
+    return _swiglu_rows(gate, up, rows, R), (gate, up, rows)
+
+
+def _swiglu_rows_bwd(R, res, g):
+    gate, up, rows = res
+
+    def body(start, grads):      # written over gate and up, a chunk read
+        _, pull = jax.vjp(_swiglu, *(_cut(a, start, R) for a in grads))
+        return tuple(_put(a, d, start) for a, d in
+                     zip(grads, pull(_cut(g, start, R))))
+
+    return _loop_rows(rows, R, body, (gate, up)) + (None,)
+
+
+_swiglu_rows.defvjp(_swiglu_rows_fwd, _swiglu_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _share_combine(ys, top_p, plan, R):
+    """The experts' rows ``ys`` (S*k, D), sorted -> (S, D): a token's held
+    picks' rows weighted by ``top_p`` (S, k) and summed in float32. The
+    cotangent needs ``ys`` and nothing of this pass: a chunk of sorted rows
+    a step, it gathers the tokens' cotangent rows ONCE for both the rows'
+    (weight . g[token]) and the weights' (<row, g[token]>)."""
+    return _rows_to_tokens(ys, plan, top_p, R)
+
+
+def _share_combine_fwd(ys, top_p, plan, R):
+    return _share_combine(ys, top_p, plan, R), (ys, top_p, plan)
+
+
+def _share_combine_bwd(R, res, g):
+    ys, top_p, plan = res
+    order, held = plan["order"], plan["held"]
+    weights, k = top_p.reshape(-1), held.shape[1]
+
+    def body(start, carry):      # the rows' cotangent written over the rows
+        ys, dots = carry
+        picks = _cut(order, start, R)
+        of_token = g[picks // k].astype(jnp.float32)
+        d = of_token * weights[picks][:, None]
+        dot = jnp.sum(_cut(ys, start, R).astype(jnp.float32) * of_token, -1)
+        return _put(ys, d.astype(ys.dtype), start), _put(dots, dot, start)
+
+    d_ys, dots = _loop_rows(plan["rows_run"], R, body,
+                            (ys, jnp.zeros(ys.shape[:1], jnp.float32)))
+    d_p = jnp.where(held, dots[plan["inv"]].reshape(held.shape), 0.0)
+    return d_ys, d_p.astype(top_p.dtype), None
+
+
+_share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
+
+
 def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     """Dropless top-k MoE: every pick on an expert held here is computed.
     The S*k picks are sorted by expert (stable), token rows gathered in that
@@ -1229,9 +1449,15 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     Held picks sort first, by expert, the others after them, in no group:
     the grouped matmuls get the held experts' group sizes and spend no row
     on the rest. The arrays keep S*k rows, the worst case (every pick here),
-    so no imbalance can drop a pick; past the groups a row is gathered, runs
-    through the activation and is masked out of the sums (``held``), which
-    is memory traffic and no matmul work.
+    so no imbalance can drop a pick, but only the rows HELD are worked on:
+    dispatch, the sum of the two projections' cotangents, activation and
+    combine, forward and backward, loop over chunks of ``_row_chunk`` rows
+    to a bound the routing sets on the device ("a share's row loops" above;
+    one ``ragged_dot`` a projection still covers the whole array and spends
+    its groups). Past that bound a row is never written and never read.
+    Without a share the bound would be S*k and the passes are the
+    whole-array gathers they were: two implementations until ROADMAP
+    S15(e) runs every config through the loops.
 
     Under a mesh with ``ep > 1`` the older top-1 capacity form runs instead
     (``_moe_mlp_capacity``): experts over ``ep`` by all-to-all for this
@@ -1252,7 +1478,6 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     with jax.named_scope(SCOPE_MOE_ROUTE):
         top_p, top_e, counts, _, aux = _route(x, p, cfg)
         flat_e = top_e.reshape(-1)
-        held = None
         if share:
             held = (top_e >= first) & (top_e < first + E)
             # held picks first, by expert; the rest behind them
@@ -1261,28 +1486,40 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
         order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
         inv = jnp.argsort(order).astype(jnp.int32)
         group_sizes = counts       # picks an expert = rows of its group
-    with jax.named_scope(SCOPE_MOE_DISPATCH):
-        xs = _dispatch_rows(x, order, inv, k, held)        # (S*k, D)
-    with jax.named_scope(SCOPE_MOE_EXPERTS):
-        u = _grouped_matmul(xs, p["w1"], group_sizes)
-        if cfg.mlp == "swiglu":
-            up = _grouped_matmul(xs, p["w3"], group_sizes)
-            u = (jax.nn.silu(u.astype(jnp.float32))
-                 * up.astype(jnp.float32)).astype(x.dtype)
-            ys = _grouped_matmul(u, p["w2"], group_sizes)
-        else:
-            sorted_e = flat_e[order]
-            if share:   # a row past the groups: any held expert's biases
-                sorted_e = jnp.minimum(sorted_e, E - 1)
-            u = _gelu(u + p["b1"].astype(x.dtype)[sorted_e], cfg)
-            ys = (_grouped_matmul(u, p["w2"], group_sizes)
-                  + p["b2"].astype(x.dtype)[sorted_e])
-    with jax.named_scope(SCOPE_MOE_COMBINE):
-        y = _permute_rows(ys, inv, order).reshape(B * T, k, D)
-        y = y.astype(jnp.float32)
         if share:
-            y = jnp.where(held[..., None], y, 0.0)
-        out = jnp.sum(y * top_p[..., None], 1)
+            R = _row_chunk(B * T, D, x.dtype)
+            plan = _share_plan(order, inv, held, counts, R)
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        xs = (_share_dispatch(x, plan, R) if share
+              else _dispatch_rows(x, order, inv, k))         # (S*k, D)
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        if cfg.mlp == "swiglu":
+            xs, xs_up = (_rows_twice(xs, plan["rows_run"], R) if share
+                         else (xs, xs))
+            u = _grouped_matmul(xs, p["w1"], group_sizes)
+            up = _grouped_matmul(xs_up, p["w3"], group_sizes)
+            u = (_swiglu_rows(u, up, plan["rows_run"], R) if share
+                 else _swiglu(u, up))
+            ys = _grouped_matmul(u, p["w2"], group_sizes)
+        else:   # biased GELU experts: over every row, on a share too
+            u = _grouped_matmul(xs, p["w1"], group_sizes)
+            sorted_e = flat_e[order]
+            b1, b2 = p["b1"], p["b2"]
+            if share:
+                # a row past the groups has no expert (``sorted_e`` is E)
+                # and takes a bias row of zeros: the biases' gradient is a
+                # sum over ALL rows, and what the loops left past the groups,
+                # values or cotangents, lands in the row that is cut off
+                b1, b2 = (jnp.pad(b, ((0, 1), (0, 0))) for b in (b1, b2))
+            b1, b2 = (b.astype(x.dtype)[sorted_e] for b in (b1, b2))
+            u = _gelu(u + b1, cfg)
+            ys = _grouped_matmul(u, p["w2"], group_sizes) + b2
+    with jax.named_scope(SCOPE_MOE_COMBINE):
+        if share:
+            out = _share_combine(ys, top_p, plan, R)
+        else:
+            y = _permute_rows(ys, inv, order).reshape(B * T, k, D)
+            out = jnp.sum(y.astype(jnp.float32) * top_p[..., None], 1)
     return out.astype(h.dtype).reshape(B, T, D), aux
 
 
@@ -1690,6 +1927,9 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig):
     ``held`` the picks on experts held here (all B*T*k unless the config is
     a share, ``Router.width``), ``dropped`` the held picks that the grouped
     matmuls' group sizes do not cover (0: ``_moe_mlp`` is dropless),
+    ``rows_run`` the pick rows that dispatch, activation and combine cover
+    for this input (a share: ``held`` up to whole chunks, by the helper the
+    step's loops take their trip count from; else all B*T*k),
     ``entropy`` the mean entropy of a token's scores as a distribution over
     the experts (the softmax itself; sigmoid scores over their sum), in
     nats."""
@@ -1706,11 +1946,15 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig):
         if r.score != "softmax":
             probs = probs / jnp.sum(probs, -1, keepdims=True)
         held = jnp.sum((top_e >= first) & (top_e < first + n_held))
+        covered = jnp.sum(counts[first:first + n_held])
         stats = {
             "picks": counts, "experts": top_e,
             "max_over_mean": jnp.max(counts) * E / (S * k),
             "held": held,
-            "dropped": held - jnp.sum(counts[first:first + n_held]),
+            "dropped": held - covered,
+            # not a share: every pick is covered, so all S * k
+            "rows_run": _rows_run(covered, _row_chunk(
+                S, mlp_in.shape[-1], mlp_in.dtype), S * k),
             "entropy": -jnp.mean(jnp.sum(
                 probs * jnp.log(jnp.maximum(probs, 1e-30)), -1))}
         h, _ = _block(h, layer_params, cfg, None, kind=kind)

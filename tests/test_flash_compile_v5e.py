@@ -426,3 +426,45 @@ def test_ouro_trunk_keeps_what_a_norm_or_a_kernel_reads(one_chip,
     # the plan holds more than the stacks themselves (a custom call's
     # operand is copied out of its stack), and still fits
     assert held <= temp - bare_temp <= budget, (held, temp - bare_temp, budget)
+
+
+def test_share_layer_loops_over_its_rows_in_place(one_chip, no_compile_cache):
+    """One expert layer of the `lfm2-8b-a1b` cell (8 of 32 experts held,
+    32,768 tokens x 4 picks), forward and gradient, compiled for the v5e:
+    the grouped matmuls calls over the whole arrays (three projections,
+    forward and both cotangents, not a call a chunk), the passes around them
+    loops to a run-time bound (a `while` whose trip count is no constant),
+    and no whole-array pass beside them: no gather and no copy of all
+    131,072 rows (the loops write their chunks in place)."""
+    import json
+    from hetu_tpu.models import hf_lfm2, transformer as tfm
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/lfm2-8b-a1b/config.json")
+              ) as f:
+        cfg = hf_lfm2.config_from_hf(json.load(f), dtype=jnp.bfloat16)
+    assert tfm._row_chunk(4 * 8192, cfg.d_model, cfg.dtype) == 1024
+    blocks = tfm.run_blocks(cfg, jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))["blocks"])[1]
+    p = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape[1:], x.dtype, sharding=one_chip), blocks)
+    h = jax.ShapeDtypeStruct((4, 8192, cfg.d_model), cfg.dtype,
+                             sharding=one_chip)
+
+    def loss(h, p):
+        out, _ = tfm._moe_mlp(h, p, cfg, None)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(h, p).compile().as_text()
+    rows = 4 * 8192 * cfg.n_experts_per_tok
+    whole = [l for l in text.splitlines()
+             if re.search(rf" = \w+\[{rows},\d+\]\S* (gather|copy)\(", l)]
+    assert not whole, whole[:3]
+    # the grouped matmuls stay whole-array calls, three projections, forward
+    # and both cotangents, and never a call a chunk: by the name that
+    # `benchmark/reduce/moe.py` finds them by in a trace
+    assert len(re.findall(r"ragged-dot-none[.\d]* = ", text)) == 9
+    # dispatch, activation, combine forward; combine, activation, the sum
+    # of the two projections' cotangents, dispatch backward: each a loop
+    # whose trip count the compiler does not know
+    loops = [l for l in text.splitlines() if re.search(r" while\(", l)]
+    assert sum("known_trip_count" not in l for l in loops) >= 7, loops
