@@ -255,14 +255,15 @@ def _bert_batch(rng, cfg, batch, seq, n_pred, padded):
 
 def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                    ref_batch=2, mesh_axes=None, chip=True, name="flagship",
-                   moe=None, moe_batch=2):
+                   moe=None, moe_batch=2, mla=None, mla_batch=1):
     """BERT pretrain steps on an unpadded then a padded batch; the loss
     must stay finite (at lr 1e-4 without warm-up AdamW's first steps
     overshoot at BERT-base size, so "falling" is not asked here). On one
     chip the loss of the kernel path (flash + fused CE) is also compared
     with the unfused dot/einsum reference on ``ref_batch`` rows, and the
     ``moe`` row runs one MoE layer of sizes ``moe`` or ``MOE_ROW``
-    (``_moe_row``)."""
+    (``_moe_row``) and the ``mla`` row one train step of a latent-attention
+    model with a shared expert, ``mla`` or ``MLA_ROW`` (``_mla_row``)."""
     import jax
     from hetu_tpu.kernels.fused_ce import should_fuse
     from hetu_tpu.models import bert
@@ -362,6 +363,7 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                                    "hidden_max_abs_err": round(h_err, 5),
                                    "hidden_max_abs": round(h_scale, 3)}
             rec["moe"] = _moe_row(moe or MOE_ROW, moe_batch, chip)
+            rec["mla"] = _mla_row(mla or MLA_ROW, mla_batch, chip)
         rec.update({"model": "bert", "d_model": cfg.d_model,
                     "n_heads": cfg.n_heads, "n_layers": cfg.n_layers,
                     "d_ff": cfg.d_ff, "vocab": cfg.vocab_size, "seq": seq,
@@ -460,6 +462,73 @@ def _moe_row(sizes, batch, chip):
             "dropped_picks": dropped, "tokens": int(tokens.size),
             "experts": cfg.n_experts, "per_tok": cfg.n_experts_per_tok,
             "max_over_mean": round(float(np.max(stats["max_over_mean"])), 3)}
+
+
+# kanana-2-30b-a3b's two kinds of layer (models/hf_deepseek_v3.py) at the
+# published widths, one layer of each, 8 of 32 experts held, a small
+# vocabulary: the `mla` row of the flagship phase
+MLA_ROW = dict(
+    hidden_size=2048, intermediate_size=6144, moe_intermediate_size=768,
+    num_attention_heads=32, num_key_value_heads=32, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    first_k_dense_replace=1, num_hidden_layers=2, n_routed_experts=8,
+    num_routed_experts=32, first_expert_held=0, n_shared_experts=2,
+    num_experts_per_tok=6, norm_topk_prob=True, routed_scaling_factor=2.448,
+    rms_norm_eps=1e-6, rope_theta=1e6, vocab_size=1024,
+    max_position_embeddings=1024)
+
+
+def _mla_row(sizes, batch, chip):
+    """One train step of a latent-attention model with a shared expert
+    (one dense layer, one expert layer, a share of the experts held) through
+    `make_train_step`: on the chip the kernels run q . k and p . v at two
+    head widths; the loss before the step agrees with the unfused `dot`
+    path's, the step's loss is finite and no held pick is dropped."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.models import hf_deepseek_v3, transformer as tfm
+    dtype = sizes.get("dtype", jnp.bfloat16)
+    cfg = hf_deepseek_v3.config_from_hf(
+        {k: v for k, v in sizes.items() if k != "dtype"}, dtype=dtype,
+        router_bias_rate=1e-2)
+    params = tfm.init_params(jax.random.PRNGKey(2), cfg)
+    ids = jnp.asarray(np.random.RandomState(2).randint(
+        0, cfg.vocab_size, (batch, cfg.max_seq_len + 1)), jnp.int32)
+    tokens, targets = ids[:, :-1], ids[:, 1:]
+    impl = tfm._resolve_attn_impl(cfg, None, cfg.max_seq_len)
+    if chip:
+        _check(impl == "flash", f"mla: attn_impl resolved to {impl!r}")
+    loss_of = lambda c: float(jax.jit(
+        lambda p: tfm.loss_fn(p, tokens, targets, c))(params))
+    got = loss_of(cfg)
+    want = loss_of(dataclasses.replace(cfg, attn_impl="dot",
+                                       fused_lm_ce=False))
+    _check(abs(got - want) <= 1e-2 * abs(want),
+           f"mla: kernel-path loss {got} vs dot path {want}")
+    stats = jax.jit(lambda p: tfm.moe_routing_stats(p, tokens, cfg))(params)
+    dropped = int(np.sum(stats["dropped"]))
+    _check(dropped == 0, f"mla: {dropped} dropped picks")
+    opt = tfm.init_opt_state(params)
+    # compiled once: the text is read from the program that then runs
+    step = tfm.make_train_step(cfg, lr=3e-6).lower(
+        params, opt, tokens, targets).compile()
+    if chip:
+        hlo = step.as_text()
+        _check(all(k in hlo for k in ("flash_fwd", "flash_bwd_dq",
+                                      "flash_bwd_dkv", "ragged-dot")),
+               "mla: a kernel is missing from the compiled step")
+    loss, params, opt = step(params, opt, tokens, targets)
+    _check(_finite(loss), f"mla: step loss {float(loss)}")
+    picks = np.asarray(opt["m"]["blocks"][1][tfm.ROUTER_BIAS])
+    _check(picks.sum() == tokens.size * cfg.n_experts_per_tok,
+           "mla: the step's counter does not hold tokens x k picks")
+    return {"loss": round(got, 5), "dot_loss": round(want, 5),
+            "step_loss": round(float(loss), 5), "attn_impl": impl,
+            "heads": cfg.n_heads, "qk_dim": cfg.mla.qk_dim,
+            "v_dim": cfg.mla.v_dim, "d_ff_shared": cfg.d_ff_shared,
+            "held_picks": int(np.sum(stats["held"])),
+            "dropped_picks": dropped, "tokens": int(tokens.size)}
 
 
 # ---------------------------------------------------------------------------

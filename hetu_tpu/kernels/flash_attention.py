@@ -21,7 +21,9 @@ only (block_q, block_k) tiles ever exist:
 Public entry: ``flash_attention_btd(qkv, n_heads, causal=True)`` on the
 projections' own layout, (batch, seq, heads * head_dim) in and out (``qkv``
 one fused [q | k | v] array or three arrays), differentiable via custom_vjp:
-nothing is transposed around the kernels. ``flash_attention(q, k, v)`` on
+nothing is transposed around the kernels. Three arrays may bring TWO head
+widths, q's and k's and another for v (so for o): latent attention's q . k
+is 192 wide and its p . v 128, and each product runs at its own width. ``flash_attention(q, k, v)`` on
 (batch, heads, seq, head_dim) wraps it with XLA transposes. An optional
 ``k_bias`` (batch, seq) float is ADDED to every score column — the key-
 padding mask form (0 valid / -1e9 padded) the BERT encoder uses — so masked
@@ -32,6 +34,7 @@ form (softmax is shift-invariant), so the semantics match the dot path.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 
 import jax
@@ -57,6 +60,15 @@ _NEG_INF = -1e30
 # count follows the compiler's own: choices it puts at 14.8 and 15.5 MiB
 # compile for the v5e, at 17.8 and more they are refused (PR 24).
 _VMEM_BUDGET = 12 * 1024 * 1024
+# A call whose smallest choice is over that asks Mosaic for more
+# (`vmem_limit_bytes`; the chip has 128 MiB) and chooses within three
+# quarters of what it asks for, as above. k and v lie whole in VMEM, so it is
+# long sequences of wide heads that ask: two heads of 192 / 128 columns (the
+# narrowest group whose blocks are whole lane tiles on both arrays) at 8,192
+# positions count 25 MiB forward and 30 MiB backward. Asking is not free
+# (`fused_ce._VMEM_LIMIT`: HBM held beside), so no call that fits asks.
+_VMEM_LIMIT = 64 * 1024 * 1024
+_VMEM_BUDGET_ASKED = 48 * 1024 * 1024
 # FLOPs of the forward a grid step should carry at least: 1.3 us of the
 # MXU at the v5e's 197 TFLOP/s (twice that at head size 64, which fills
 # half of the array), against the ~0.35 us a grid step costs whatever it
@@ -92,11 +104,13 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _vmem_bytes(s, d, itemsize, block_q, block_k, heads, kernel):
+def _vmem_bytes(s, d, itemsize, block_q, block_k, heads, kernel, dv=None):
     """VMEM one grid step of `kernel` holds, `heads` heads a step: its
-    operand and result blocks (`heads * d` columns wide) and its rows of lse
-    and delta (padded to eight sublanes), all double-buffered, and the f32
-    arrays of the score tile's size of the ONE head being worked on.
+    operand and result blocks (`heads * d` columns wide for q, k and their
+    gradients, `heads * dv` for v, o and theirs; `dv` is `d` unless given)
+    and its rows of lse and delta (padded to eight sublanes), all
+    double-buffered, and the f32 arrays of the score tile's size of the ONE
+    head being worked on.
     - `flash_fwd`: k and v whole, a block of q and of o, a block of lse;
       s, p and one tile in flight, and the head's accumulator.
     - `flash_bwd` (the whole sequence one tile): q, k, v, dO, o in and dq,
@@ -107,27 +121,34 @@ def _vmem_bytes(s, d, itemsize, block_q, block_k, heads, kernel):
       by the hungrier of the two in each part: two operands whole (k, v;
       or q, dO) and four blocks of the longer side (q, dO, o, dq; or k, v,
       dk, dv), lse and delta whole, the same five tiles."""
-    cols = 2 * heads * d * itemsize     # a position of one operand block
+    dv = d if dv is None else dv
+    # a position of one operand block of each width, the two side by side
+    cols = 2 * heads * (d + dv) * itemsize
     rows = 2 * heads * 8 * 4            # a position of one statistics row
     tile = block_q * block_k * 4
     if kernel == FLASH_FWD:
-        return (cols * (2 * s + 2 * block_q) + rows * block_q + 3 * tile
-                + block_q * d * 4)
+        return (cols * (s + block_q) + rows * block_q + 3 * tile
+                + block_q * dv * 4)
     if kernel == FLASH_BWD:
-        return cols * 8 * s + rows * s + 5 * tile + 2 * s * heads * d * 4
-    return cols * (2 * s + 4 * max(block_q, block_k)) + 2 * rows * s + 5 * tile
+        return cols * 4 * s + rows * s + 5 * tile + 2 * s * heads * dv * 4
+    return cols * (s + 2 * max(block_q, block_k)) + 2 * rows * s + 5 * tile
 
 
-def _head_groups(heads, d):
+def _head_groups(heads, d, dv=None):
     """How many heads a grid step may take. Its block is `g * d` columns of
     a (batch, seq, heads * d) array, and Mosaic takes a block whose last
     axis is whole 128-lane tiles or the whole axis: the divisors `g` of
     `heads`, up to `_MAX_HEADS`, with `g * d` a multiple of 128 (2, 4, 6,
     12 of BERT's twelve heads of 64; any at d = 128) or `g` every head (3
     heads of 64 go as one block of the full 192). A head count that leaves
-    none of these (17 heads of 64) goes whole."""
+    none of these (17 heads of 64) goes whole. With `dv` (v and o of another
+    head width than q and k) the blocks of BOTH arrays are whole tiles: heads
+    of 192 / 128 go in twos (384 and 256 lanes)."""
+    dv = d if dv is None else dv
     groups = [g for g in range(1, min(heads, _MAX_HEADS) + 1)
-              if heads % g == 0 and ((g * d) % _LANES == 0 or g == heads)]
+              if heads % g == 0 and (
+                  ((g * d) % _LANES == 0 and (g * dv) % _LANES == 0)
+                  or g == heads)]
     return groups or [heads]
 
 
@@ -141,7 +162,8 @@ def _kernels_of(s, block_q, block_k):
     return FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV
 
 
-def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
+def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None,
+                  dv=None):
     """-> (block_q, block_k, {kernel: heads a grid step}) for the kernels
     the call will run (`_kernels_of`), from the call's shapes alone.
 
@@ -157,29 +179,34 @@ def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None):
     of heads narrower than a lane tile); the kernels of a many-tile call
     take one group, the hungriest's (`flash_bwd_dq` + `_dkv`). Blocks and
     heads give way, heads first, until `_vmem_bytes` of every kernel fits
-    `_VMEM_BUDGET`; the floor is the smallest group and the smallest
-    blocks."""
+    `_VMEM_BUDGET`, or, where no choice does, `_VMEM_BUDGET_ASKED` (the
+    call then asks for `_VMEM_LIMIT`: `_asking`); the floor is the smallest
+    group and the smallest blocks. `dv`: the head width of v and o where it
+    is not q's and k's `d`."""
     itemsize = jnp.dtype(dtype).itemsize
+    dv = d if dv is None else dv
 
     def sizes(given):
         if given is not None:
             return [min(given, s)]
         return [b for b in (512, 256, 128) if s % b == 0] or [s]
 
-    def fits(kernel, bq, bk, g):
-        return _vmem_bytes(s, d, itemsize, bq, bk, g, kernel) <= _VMEM_BUDGET
-
     picks = [(bq, bk) for bq in sizes(block_q) for bk in sizes(block_k)]
     picks.sort(key=lambda p: -p[0] * p[1])
-    groups = _head_groups(heads, d)
-    for bq, bk in picks:
+    groups = _head_groups(heads, d, dv)
+    for budget, (bq, bk) in itertools.product(
+            (_VMEM_BUDGET, _VMEM_BUDGET_ASKED), picks):
+        def fits(kernel, bq, bk, g):
+            return _vmem_bytes(s, d, itemsize, bq, bk, g, kernel,
+                               dv) <= budget
+
         kernels = _kernels_of(s, bq, bk)
-        step = 4.0 * bq * s * d * (0.5 if causal else 1.0)
+        step = 2.0 * bq * s * (d + dv) * (0.5 if causal else 1.0)
         want = next((g for g in groups if g * step >= _STEP_FLOPS),
                     groups[-1])
         most = dict.fromkeys(kernels, want)
-        if FLASH_BWD in most and d % _LANES:
-            most[FLASH_FWD] = min(want, max(_ONE_TILE_FWD_LANES // d,
+        if FLASH_BWD in most and (d % _LANES or dv % _LANES):
+            most[FLASH_FWD] = min(want, max(_ONE_TILE_FWD_LANES // max(d, dv),
                                             groups[0]))
         chosen = {kernel: next((g for g in reversed(groups) if g <= limit
                                 and fits(kernel, bq, bk, g)), None)
@@ -200,8 +227,14 @@ def _is_fused(qkv):
 
 
 def _width(qkv):
-    """heads * d, the columns of q (and of o)."""
+    """heads * d, the columns of q (and of k)."""
     return qkv.shape[-1] // 3 if _is_fused(qkv) else qkv[0].shape[-1]
+
+
+def _v_width(qkv):
+    """heads * dv, the columns of v (and of o): q's in a fused array, which
+    is cut in three alike; three arrays may bring a v of its own width."""
+    return qkv.shape[-1] // 3 if _is_fused(qkv) else qkv[2].shape[-1]
 
 
 def _three(qkv):
@@ -210,20 +243,32 @@ def _three(qkv):
 
 
 def _tiles_for(qkv, n_heads, causal, block_q, block_k):
-    """-> (qkv, block_q, block_k, {kernel: heads a step}, d). Blocks that
-    do not divide the sequence (only ones the caller passed can) raise. A
-    fused array whose head group is not whole lane tiles (three heads of
-    64: then every kernel takes every head) cannot be indexed by column
-    block: it is cut in three here."""
+    """-> (qkv, block_q, block_k, {kernel: heads a step}, d, dv): `d` the
+    head width of q and k, `dv` of v and o (the same unless three arrays
+    say otherwise). Blocks that do not divide the sequence (only ones the
+    caller passed can) raise. A fused array whose head group is not whole
+    lane tiles (three heads of 64: then every kernel takes every head)
+    cannot be indexed by column block: it is cut in three here."""
     q = qkv if _is_fused(qkv) else qkv[0]
-    s, d = q.shape[1], _width(qkv) // n_heads
+    s, d, dv = q.shape[1], _width(qkv) // n_heads, _v_width(qkv) // n_heads
     block_q, block_k, heads = _choose_tiles(s, d, q.dtype, causal, n_heads,
-                                            block_q, block_k)
+                                            block_q, block_k, dv)
     if s % block_q or s % block_k:
         raise ValueError(f"seq_len {s} must divide blocks ({block_q},{block_k})")
     if _is_fused(qkv) and (heads[FLASH_FWD] * d) % _LANES:
         qkv = _three(qkv)
-    return qkv, block_q, block_k, heads, d
+    return qkv, block_q, block_k, heads, d, dv
+
+
+def _asking(kernel, s, d, dv, dtype, block_q, block_k, heads):
+    """`pallas_call` keywords: `_VMEM_LIMIT` asked of Mosaic where the
+    step's count is over what it gives unasked, nothing otherwise (a call
+    that fits lowers to what it always did)."""
+    if _vmem_bytes(s, d, jnp.dtype(dtype).itemsize, block_q, block_k, heads,
+                   kernel, dv) <= _VMEM_BUDGET:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_VMEM_LIMIT)}
 
 
 def _dot(a, b):
@@ -321,10 +366,13 @@ def _optional_bias(kernel, n_before, use_bias):
 # ---------------------------------------------------------------------------
 # The tile program, common to the four kernels. q, k, v, o and their
 # gradients lie in HBM as the projections write and read them: (batch, seq,
-# heads * d), a head's d columns side by side on the lane axis. A grid step
-# owns one batch row, `heads` consecutive heads (a block of heads * d
-# columns, whole lane tiles) and one block of the sequence, and loops over
-# the blocks of the other side; a head is a static slice of the lanes.
+# heads * d), a head's d columns side by side on the lane axis (v, o and
+# their gradients: heads * dv, `dv` = `d` but for latent attention, whose
+# q . k is 192 wide and whose p . v is 128: no product runs on a padded
+# column). A grid step owns one batch row, `heads` consecutive heads (a
+# block of heads * d columns, and one of heads * dv, whole lane tiles both)
+# and one block of the sequence, and loops over the blocks of the other
+# side; a head is a static slice of the lanes of either block.
 # - MXU operands go in as the caller gave them (bf16 from a bf16 model, f32
 #   from an f32 caller), every dot accumulates in f32, and everything else
 #   is f32: scores, bias add, m, l, exp, lse, delta, the accumulators.
@@ -337,7 +385,7 @@ def _optional_bias(kernel, n_before, use_bias):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
-                causal, block_k, d):
+                causal, block_k, d, dv):
     # grid: (batch, head groups, q blocks); one q block, the whole k and v
     block_q = q_ref.shape[0]
     q_start = pl.program_id(2) * block_q
@@ -351,13 +399,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
              if causal and key_blocks > 1 else key_blocks)
 
     for g in range(q_ref.shape[1] // d):
-        lanes = _block(g, d)
+        lanes, v_lanes = _block(g, d), _block(g, dv)
         q = q_ref[:, lanes]                           # (block_q, d)
 
         def body(kj, carry):
             acc, m_prev, l_prev = carry
             keys = _block(kj, block_k)
-            v_blk = v_ref[keys, lanes]
+            v_blk = v_ref[keys, v_lanes]
             s = _dot_nt(q, k_ref[keys, lanes]) * scale  # (block_q, block_k)
             if bias_ref is not None:
                 s = s + bias_ref[0, :, keys]
@@ -371,33 +419,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
             return acc, m_new, l_new
 
         acc, m, l = _loop(0, upper, body, (
-            jnp.zeros((block_q, d), jnp.float32),
+            jnp.zeros((block_q, dv), jnp.float32),
             jnp.full((block_q, 1), _NEG_INF, jnp.float32),
             jnp.zeros((block_q, 1), jnp.float32)))
         l = jnp.maximum(l, 1e-30)
-        o_ref[:, lanes] = (acc / l).astype(o_ref.dtype)
+        o_ref[:, v_lanes] = (acc / l).astype(o_ref.dtype)
         lse_ref[g] = _col_to_row(m + jnp.log(l))
 
 
-def _specs(qkv, heads, d, n_heads, block):
+def _specs(qkv, heads, d, n_heads, block, dv):
     """BlockSpec makers for a grid (batch, head groups, blocks of the
     sequence), each for one block of the sequence or, with `whole`, all of
-    it: `cols(name)` the `heads * d` columns of head group `j` in q, k, v
-    (column block `j`, `groups + j`, `2 * groups + j` of a fused array) or
-    in "o" (any array of q's width alone: o, dO, dq, dv); `stats()` the
-    group's rows of lse or delta; `bias()` the batch row's key bias."""
+    it: `cols(name)` the columns of head group `j`, `heads * d` of them in
+    q, k or "dq", `heads * dv` in v or "o" (column block `j`, `groups + j`,
+    `2 * groups + j` of a fused array for q, k, v; "dq" and "o" are arrays
+    of their own: dq; o, dO, dv); `stats()` the group's rows of lse or
+    delta; `bias()` the batch row's key bias."""
     groups = n_heads // heads
     s = (qkv if _is_fused(qkv) else qkv[0]).shape[1]
-    first = dict(q=0, k=0, v=0, o=0)
+    first = dict(q=0, k=0, v=0, o=0, dq=0)
     if _is_fused(qkv):
         first.update(k=groups, v=2 * groups)
+    width = dict(q=d, k=d, dq=d, v=dv, o=dv)
 
     def along(whole):
         return (s, lambda i: 0) if whole else (block, lambda i: i)
 
     def cols(name, whole=False):
         n, at = along(whole)
-        return pl.BlockSpec((None, n, heads * d),
+        return pl.BlockSpec((None, n, heads * width[name]),
                             lambda b, j, i: (b, at(i), first[name] + j))
 
     def stats(whole=False):
@@ -427,16 +477,16 @@ def _bias_rows(k_bias):
 def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
                 interpret):
     """-> o (batch, seq, heads * d), lse (batch * heads, 1, seq)."""
-    qkv, block_q, block_k, heads, d = _tiles_for(qkv, n_heads, causal,
-                                                 block_q, block_k)
+    qkv, block_q, block_k, heads, d, dv = _tiles_for(qkv, n_heads, causal,
+                                                     block_q, block_k)
     heads = heads[FLASH_FWD]
     ops = _operands(qkv)
     b, s, _ = ops[0].shape
     use_bias = k_bias is not None
     bias = [_bias_rows(k_bias)] if use_bias else []
-    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_q)
+    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_q, dv)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_k=block_k, d=d)
+                             block_k=block_k, d=d, dv=dv)
     return pl.pallas_call(
         _optional_bias(kern, 3, use_bias),
         grid=(b, n_heads // heads, s // block_q),
@@ -444,11 +494,12 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
         + [bias_spec(True)] * use_bias,
         out_specs=[cols("o"), stats()],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, n_heads * d), ops[0].dtype),
+            jax.ShapeDtypeStruct((b, s, n_heads * dv), ops[0].dtype),
             jax.ShapeDtypeStruct((b * n_heads, 1, s), jnp.float32),
         ],
         interpret=interpret,
         name=FLASH_FWD,
+        **_asking(FLASH_FWD, s, d, dv, ops[0].dtype, block_q, block_k, heads),
     )(*ops, *bias)
 
 
@@ -465,7 +516,7 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
-                   dq_ref, delta_ref, *, scale, causal, block_k, d):
+                   dq_ref, delta_ref, *, scale, causal, block_k, d, dv):
     # grid: (batch, head groups, q blocks); owns one q block, loops over k
     block_q = q_ref.shape[0]
     q_start = pl.program_id(2) * block_q
@@ -474,14 +525,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
 
     heads = q_ref.shape[1] // d
     deltas = _head_sums(
-        do_ref[...].astype(jnp.float32) * o_ref[...].astype(jnp.float32), d,
+        do_ref[...].astype(jnp.float32) * o_ref[...].astype(jnp.float32), dv,
         pieces=2 if do_ref.dtype == jnp.bfloat16 else 3)
     delta_ref[:, 0, :] = deltas.T[:heads]
 
     for g in range(heads):
-        lanes = _block(g, d)
+        lanes, v_lanes = _block(g, d), _block(g, dv)
         q = q_ref[:, lanes]                           # (block_q, d)
-        do = do_ref[:, lanes]
+        do = do_ref[:, v_lanes]
         lse = _row_to_col(lse_ref[g])                 # (block_q, 1)
         delta = deltas[:, g:g + 1]
 
@@ -494,7 +545,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
             if causal:
                 s = _causal_mask(s, q_start, kj * block_k)
             p = jnp.exp(s - lse)
-            dp = _dot_nt(do, v_ref[keys, lanes])
+            dp = _dot_nt(do, v_ref[keys, v_lanes])
             ds = p * (dp - delta)
             return dq + _dot(ds.astype(q.dtype), k_blk)
 
@@ -503,7 +554,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    bias_ref, dv_ref, dk_ref, *, scale, causal, block_q, d):
+                    bias_ref, dv_ref, dk_ref, *, scale, causal, block_q, d,
+                    dv):
     # grid: (batch, head groups, k blocks); owns one k/v block, loops over
     # q blocks. Tiles are (block_k, block_q): S^T, P^T, dP^T, dS^T.
     block_k = k_ref.shape[0]
@@ -515,35 +567,37 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     bias = None if bias_ref is None else _row_to_col(bias_ref[0])
 
     for g in range(k_ref.shape[1] // d):
-        lanes = _block(g, d)
+        lanes, v_lanes = _block(g, d), _block(g, dv)
         k_blk = k_ref[:, lanes]                       # (block_k, d)
-        v_blk = v_ref[:, lanes]
+        v_blk = v_ref[:, v_lanes]
 
         def body(qi, carry):
-            dk, dv = carry
+            dk, d_v = carry
             rows = _block(qi, block_q)
             q = q_ref[rows, lanes]
-            do = do_ref[rows, lanes]
+            do = do_ref[rows, v_lanes]
             st = _dot_nt(k_blk, q) * scale
             if bias is not None:
                 st = st + bias
             if causal:
                 st = _causal_mask(st, qi * block_q, k_start, keys_first=True)
             pt = jnp.exp(st - lse_ref[g, :, rows])    # (block_k, block_q)
-            dv = dv + _dot(pt.astype(do.dtype), do)
+            d_v = d_v + _dot(pt.astype(do.dtype), do)
             dpt = _dot_nt(v_blk, do)
             dst = pt * (dpt - delta_ref[g, :, rows])
             dk = dk + _dot(dst.astype(q.dtype), q)
-            return dk, dv
+            return dk, d_v
 
         zeros = jnp.zeros((block_k, d), jnp.float32)
-        dk, dv = _loop(lower, upper, body, (zeros, zeros))
+        dk, d_v = _loop(lower, upper, body, (
+            zeros, zeros if dv == d else jnp.zeros((block_k, dv),
+                                                   jnp.float32)))
         dk_ref[:, lanes] = (dk * scale).astype(dk_ref.dtype)
-        dv_ref[:, lanes] = dv.astype(dv_ref.dtype)
+        dv_ref[:, v_lanes] = d_v.astype(dv_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
-                dq_ref, dv_ref, dk_ref, *, scale, causal, d):
+                dq_ref, dv_ref, dk_ref, *, scale, causal, d, dv):
     # grid: (batch, head groups, 1); the whole sequence is ONE tile, so
     # nothing is summed over blocks and one rebuilt tile serves dq, dk and
     # dv. The tile is (keys, queries) as in `flash_bwd_dkv`: lse and delta
@@ -551,23 +605,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
     # alone contracts over the rows of dS^T.
     heads = q_ref.shape[1] // d
     deltas = _head_sums(
-        do_ref[...].astype(jnp.float32) * o_ref[...].astype(jnp.float32), d,
+        do_ref[...].astype(jnp.float32) * o_ref[...].astype(jnp.float32), dv,
         pieces=2 if do_ref.dtype == jnp.bfloat16 else 3).T   # (128, seq)
     bias = None if bias_ref is None else _row_to_col(bias_ref[0])
 
     for g in range(heads):
-        lanes = _block(g, d)
+        lanes, v_lanes = _block(g, d), _block(g, dv)
         q = q_ref[:, lanes]                           # (seq, d)
         k = k_ref[:, lanes]
-        do = do_ref[:, lanes]
+        do = do_ref[:, v_lanes]
         st = _dot_nt(k, q) * scale                    # (keys, queries)
         if bias is not None:
             st = st + bias
         if causal:
             st = _causal_mask(st, 0, 0, keys_first=True)
         pt = jnp.exp(st - lse_ref[g])
-        dv_ref[:, lanes] = _dot(pt.astype(do.dtype), do).astype(dv_ref.dtype)
-        dpt = _dot_nt(v_ref[:, lanes], do)
+        dv_ref[:, v_lanes] = _dot(pt.astype(do.dtype),
+                                  do).astype(dv_ref.dtype)
+        dpt = _dot_nt(v_ref[:, v_lanes], do)
         dst = (pt * (dpt - deltas[g:g + 1])).astype(q.dtype)
         dk_ref[:, lanes] = (_dot(dst, q) * scale).astype(dk_ref.dtype)
         dq_ref[:, lanes] = (_dot_tn(dst, k) * scale).astype(dq_ref.dtype)
@@ -580,56 +635,65 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
     the kernel that makes dk (`flash_bwd`; `flash_bwd_dkv`) makes and
     writes the k columns of, and dq and dv are set into: two passes over a
     third of it each, that transpose nothing. (The first result of every
-    kernel stays an array of o's shape: the benchmark's reader takes a
-    call's FLOPs from it.) One kernel where the sequence is one tile, two
+    kernel stays an array of q's shape, which is o's too unless v brought a
+    width of its own: the benchmark's reader takes a call's FLOPs from it.)
+    One kernel where the sequence is one tile, two
     where it is cut (`_kernels_of`), each with its own heads a step."""
     given, o, lse, k_bias = res
-    qkv, block_q, block_k, heads, d = _tiles_for(given, n_heads, causal,
-                                                 block_q, block_k)
+    qkv, block_q, block_k, heads, d, dv = _tiles_for(given, n_heads, causal,
+                                                     block_q, block_k)
     b, s, width = o.shape
     use_bias = k_bias is not None
     bias = [_bias_rows(k_bias)] if use_bias else []
-    grad = jax.ShapeDtypeStruct(o.shape, o.dtype)
+    grad = jax.ShapeDtypeStruct(o.shape, o.dtype)           # dv; o's shape
+    grad_q = jax.ShapeDtypeStruct((b, s, n_heads * d), o.dtype)
     # dk goes where k came from: the same columns of an array of qkv's
     # shape, or an array of its own
     dk_shape = (jax.ShapeDtypeStruct(qkv.shape, o.dtype) if _is_fused(qkv)
-                else grad)
+                else grad_q)
+    asking = functools.partial(_asking, s=s, d=d, dv=dv, dtype=o.dtype,
+                               block_q=block_q, block_k=block_k)
 
     if FLASH_BWD in heads:
         group = heads[FLASH_BWD]
-        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, s)
-        kern = functools.partial(_bwd_kernel, scale=scale, causal=causal, d=d)
-        dq, dv, dk = pl.pallas_call(
+        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, s, dv)
+        kern = functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                                 d=d, dv=dv)
+        dq, d_v, dk = pl.pallas_call(
             _optional_bias(kern, 6, use_bias),
             grid=(b, n_heads // group, 1),
             in_specs=[cols("q"), cols("k"), cols("v"), cols("o"), cols("o"),
                       stats()] + [bias_spec()] * use_bias,
-            out_specs=[cols("o"), cols("o"), cols("k")],
-            out_shape=[grad, grad, dk_shape],
+            out_specs=[cols("dq"), cols("o"), cols("k")],
+            out_shape=[grad_q, grad, dk_shape],
             interpret=interpret,
             name=FLASH_BWD,
+            **asking(FLASH_BWD, heads=group),
         )(*_operands(qkv), do, o, lse, *bias)
     else:
         group = heads[FLASH_BWD_DQ]
-        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, block_q)
+        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, block_q, dv)
         dq_kern = functools.partial(_bwd_dq_kernel, scale=scale,
-                                    causal=causal, block_k=block_k, d=d)
+                                    causal=causal, block_k=block_k, d=d,
+                                    dv=dv)
         dq, delta = pl.pallas_call(
             _optional_bias(dq_kern, 6, use_bias),
             grid=(b, n_heads // group, s // block_q),
             in_specs=[cols("q"), cols("k", True), cols("v", True), cols("o"),
                       cols("o"), stats()] + [bias_spec(True)] * use_bias,
-            out_specs=[cols("o"), stats()],
-            out_shape=[grad, jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+            out_specs=[cols("dq"), stats()],
+            out_shape=[grad_q, jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
             interpret=interpret,
             name=FLASH_BWD_DQ,
+            **asking(FLASH_BWD_DQ, heads=group),
         )(*_operands(qkv), do, o, lse, *bias)
 
         group = heads[FLASH_BWD_DKV]
-        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, block_k)
+        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, block_k, dv)
         dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                     causal=causal, block_q=block_q, d=d)
-        dv, dk = pl.pallas_call(
+                                     causal=causal, block_q=block_q, d=d,
+                                     dv=dv)
+        d_v, dk = pl.pallas_call(
             _optional_bias(dkv_kern, 6, use_bias),
             grid=(b, n_heads // group, s // block_k),
             in_specs=[cols("q", True), cols("k"), cols("v"), cols("o", True),
@@ -638,14 +702,15 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
             out_shape=[grad, dk_shape],
             interpret=interpret,
             name=FLASH_BWD_DKV,
+            **asking(FLASH_BWD_DKV, heads=group),
         )(*_operands(qkv), do, lse, delta, *bias)
 
     if _is_fused(qkv):
         out = jax.lax.dynamic_update_slice_in_dim(dk, dq, 0, axis=2)
-        return jax.lax.dynamic_update_slice_in_dim(out, dv, 2 * width, axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(out, d_v, 2 * width, axis=2)
     if _is_fused(given):    # cut in three by `_tiles_for`: three heads of 64
-        return jnp.concatenate([dq, dk, dv], axis=-1)
-    return dq, dk, dv
+        return jnp.concatenate([dq, dk, d_v], axis=-1)
+    return dq, dk, d_v
 
 
 # ---------------------------------------------------------------------------
@@ -696,7 +761,7 @@ def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k):
     dq, (dk_blocks, dv_blocks) = jax.lax.scan(
         scan_body, jnp.zeros(q_f.shape, jnp.float32), jnp.arange(nkb))
     grads = [dq] + [jnp.moveaxis(x, 0, 1) for x in (dk_blocks, dv_blocks)]
-    grads = [x.reshape(o.shape).astype(o.dtype) for x in grads]
+    grads = [x.reshape(b, s, -1).astype(o.dtype) for x in grads]
     if _is_fused(qkv):
         return jnp.concatenate(grads, axis=-1)
     return tuple(grads)
@@ -720,7 +785,8 @@ def flash_attention_btd(qkv, n_heads, causal=True, scale=None, block_q=None,
     ``qkv``: one (batch, seq, 3 * heads * head_dim) array, [q | k | v] along
     the columns as a fused projection writes it (the kernels read the three
     out of it in place), or a tuple of three (batch, seq, heads * head_dim)
-    arrays where q and k are touched in between. -> (batch, seq, heads *
+    arrays where q and k are touched in between; v of the three may have a
+    head width of its own, which is o's. -> (batch, seq, heads * v's
     head_dim), what the output projection reads; the gradient comes back in
     the form ``qkv`` had.
 
@@ -741,12 +807,11 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
     """`flash_attention_btd` for q/k/v of (batch, heads, seq, head_dim):
     transposed in XLA on the way in and out, so a caller that holds that
     layout pays four passes a call that the trunk does not."""
-    b, h, s, d = q.shape
+    b, h, s, _ = q.shape
     out = flash_attention_btd(
-        tuple(x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-              for x in (q, k, v)),
+        tuple(x.transpose(0, 2, 1, 3).reshape(b, s, -1) for x in (q, k, v)),
         h, causal, scale, block_q, block_k, k_bias)
-    return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, s, h, -1).transpose(0, 2, 1, 3)
 
 
 def _scale(qkv, n_heads, scale):

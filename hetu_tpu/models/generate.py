@@ -20,8 +20,9 @@ thing is one compiled program.
 
 Dense MLP blocks only (the switch MoE flagship path is a training
 configuration; decode asserts ``n_experts == 0`` and refuses
-``qk_norm``, ``n_loops > 1``, ``sandwich_norm``, mamba and conv layers, a
-sigmoid router and a share of an expert layer). Decode runs single-program (``mesh=None``) or distributed: with a mesh, params keep
+``qk_norm``, ``n_loops > 1``, ``sandwich_norm``, mamba, conv and latent-
+attention layers, a shared expert, a sigmoid router and a share of an expert
+layer). Decode runs single-program (``mesh=None``) or distributed: with a mesh, params keep
 their Megatron tp layout, the KV cache shards batch-over-dp and
 heads-over-tp, and GSPMD inserts the collectives (see
 ``make_generate_fn``).
@@ -165,6 +166,14 @@ def _prefill_prefix(params, cfg, prompt, kcache, vcache, enabled,
 
 def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
                        top_k: int) -> None:
+    assert "mla" not in cfg.layer_types, (
+        f"decode has no latent cache: layer_types={cfg.layer_types} holds "
+        "latent-attention (mla) layers, whose cache is the 512-wide latent "
+        "and one rotary key a token, not k and v (_decode_layer mirrors the "
+        "attention block)")
+    assert not cfg.d_ff_shared, (
+        f"decode does not mirror a shared expert (d_ff_shared="
+        f"{cfg.d_ff_shared}: the always-on branch of an expert layer)")
     assert cfg.n_experts == 0, "decode supports dense blocks (no MoE)"
     assert not cfg.qk_norm, "decode does not mirror qk_norm (_decode_layer)"
     assert cfg.n_loops == 1 and not cfg.sandwich_norm, (

@@ -39,13 +39,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  REMAT_ATTN_V, REMAT_CANDIDATES,
-                                 REMAT_NORM1_IN, REMAT_NORM2_IN, REMAT_X1,
-                                 REMAT_X2, SCOPE_BLK_ATTN, SCOPE_BLK_MLP_DOWN,
+                                 REMAT_MLA_LATENT, REMAT_NORM1_IN,
+                                 REMAT_NORM2_IN, REMAT_X1, REMAT_X2,
+                                 SCOPE_BLK_ATTN, SCOPE_BLK_MLP_DOWN,
                                  SCOPE_BLK_MLP_UP, SCOPE_BLK_NORM,
                                  SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_EMBED,
                                  SCOPE_EXIT, SCOPE_FWD, SCOPE_HEAD,
-                                 SCOPE_MOE_COMBINE, SCOPE_MOE_DISPATCH,
-                                 SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE,
+                                 SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP,
+                                 SCOPE_MLA_Q, SCOPE_MOE_COMBINE,
+                                 SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
+                                 SCOPE_MOE_ROUTE, SCOPE_MOE_SHARED,
                                  SCOPE_OPT, SCOPE_SCONV_CONV,
                                  SCOPE_SCONV_PROJ, SCOPE_SSD_ENTER,
                                  SCOPE_SSD_INCHUNK, SCOPE_SSD_STATES,
@@ -97,6 +100,24 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """The four sizes of latent attention (DeepSeek-V2, arXiv:2405.04434,
+    section 2.1; ``_mla``): keys and values come up from ONE latent of
+    ``kv_rank`` columns a token; a head's q and k are ``nope_dim`` columns
+    without position beside ``rope_dim`` rotary ones, and the rotary key is
+    one a token, shared by the heads; a head's v is ``v_dim`` columns. The
+    scores are ``qk_dim`` wide and the values ``v_dim``: two head widths."""
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+
+    @property
+    def qk_dim(self):
+        return self.nope_dim + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class Multipliers:
     """Granite's four scalars (``models/hf_granite.py``); the defaults are
     neutral and leave the program what it is without them."""
@@ -117,7 +138,9 @@ class Router:
     bias: bool = False          # a per-expert bias (leaf ``router_bias``)
                                 # added to the scores for the SELECTION
                                 # only; no gradient moves it: ``bias_rate``
-    normalize: bool = False     # the picks' weights over (their sum + 1e-6)
+    normalize: bool = False     # the picks' weights over (their sum +
+                                # ``normalize_eps``)
+    normalize_eps: float = 1e-6     # LFM2's; DeepSeek-V3's is 1e-20
     scale: float = 1.0          # on the picks' weights, after that
     aux_losses: bool = True     # the balance and z losses (``loss_fn``);
                                 # False: ``aux`` is zeros
@@ -218,8 +241,8 @@ class TransformerConfig:
                                  # ``ln2_post_*``); pre-LN only
     # Hybrid stacks (models/hf_granite.py sets all three):
     layer_types: tuple = ()     # a mixer of ``_KINDS`` a layer ("attention",
-                                # "mamba", "conv"); () = ``n_layers`` of
-                                # attention. ``encode`` scans each run of
+                                # "mamba", "conv", "mla"); () = ``n_layers``
+                                # of attention. ``encode`` scans each run of
                                 # one kind; ``params["blocks"]`` is the
                                 # stacked dict of a stack with one run,
                                 # else a tuple of them, one a run
@@ -236,6 +259,15 @@ class TransformerConfig:
     conv_width: int = 3         # taps of a "conv" layer's causal depthwise
                                 # convolution (LFM2 ``conv_L_cache``)
     router: Router = Router()
+    # Latent attention and a shared expert (models/hf_deepseek_v3.py sets
+    # both):
+    mla: Optional[MLAConfig] = None     # the "mla" layers' sizes
+    d_ff_shared: int = 0        # > 0: an expert layer has an always-on
+                                # branch too, ONE SwiGLU MLP of this width on
+                                # every token beside the routed picks
+                                # (``ws1`` / ``ws3`` / ``ws2``; DeepSeek's
+                                # shared experts, side by side); a share
+                                # (``router.width``) computes it whole
 
     def __post_init__(self):
         if self.layer_types:
@@ -246,6 +278,19 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_types={self.layer_types}: {self.n_layers} kinds "
                     f"of {sorted(_KINDS)} (mamba: pre-LN, with `ssm` sizes)")
+        if "mla" in self.layer_types and (
+                self.mla is None or self.post_ln or self.attn_proj_bias
+                or self.qk_norm or self.n_kv_heads
+                or self.multipliers.attention is not None):
+            raise ValueError(
+                f"layer_types={self.layer_types}: an mla layer takes `mla` "
+                "sizes, pre-LN, and no projection bias, QK-norm, grouped "
+                "heads or attention multiplier")
+        if self.d_ff_shared and not (self.n_experts
+                                     and self.mlp == "swiglu"):
+            raise MoEConfigError(
+                f"d_ff_shared={self.d_ff_shared}: the shared expert of an "
+                "expert model (`n_experts` > 0) with SwiGLU experts")
         r = self.router
         if self.n_experts and not (
                 1 <= self.n_experts_per_tok <= (r.width or self.n_experts)):
@@ -434,6 +479,31 @@ def _short_conv_specs(cfg: TransformerConfig):
     return {name: P() for name in ("w_in", "conv_w", "w_out")}
 
 
+def _init_mla(ks, cfg: TransformerConfig, n):
+    """HF ``PreTrainedModel._init_weights`` (``deepseek_v3``): normal(0.02)
+    for every Linear, the latent's norm 1; `wo` takes the trunk's depth
+    scaling as every mixer's output projection here does. ``wkv_b``'s
+    columns are [every head's k_nope | every head's v] (``_mla``)."""
+    m, D, nh = cfg.mla, cfg.d_model, cfg.n_heads
+    return {
+        "wq": _init_normal(ks[0], (n, D, nh * m.qk_dim), 0.02),
+        "wkv_a": _init_normal(ks[11], (n, D, m.kv_rank + m.rope_dim), 0.02),
+        "kv_norm": jnp.ones((n, m.kv_rank), jnp.float32),
+        "wkv_b": _init_normal(jax.random.fold_in(ks[11], 1),
+                              (n, m.kv_rank, nh * (m.nope_dim + m.v_dim)),
+                              0.02),
+        "wo": _init_normal(ks[1], (n, nh * m.v_dim, D),
+                           0.02 / np.sqrt(2 * cfg.n_layers))}
+
+
+def _mla_specs(cfg: TransformerConfig):
+    """Replicated: the latent and the rotary key are every head's, and
+    ``wkv_b``'s columns are two runs of heads, so no one column cut is a head
+    cut of all five (a ``tp`` axis computes the projections on every device;
+    the kernels still run a shard of the heads each, ``_flash``)."""
+    return {name: P() for name in ("wq", "wkv_a", "kv_norm", "wkv_b", "wo")}
+
+
 def _init_run(ks, cfg: TransformerConfig, kind, n):
     """``n`` stacked layers of one kind: the two norms, the kind's mixer
     (``_KINDS``) and its MLP half (``experts_of``: dense at ``d_ff``, or the
@@ -467,6 +537,12 @@ def _init_run(ks, cfg: TransformerConfig, kind, n):
         if cfg.router.bias:
             blocks[ROUTER_BIAS] = jnp.zeros(
                 (n, cfg.router.width or E), jnp.float32)
+        if cfg.d_ff_shared:
+            Fs = cfg.d_ff_shared
+            k1, k3, k2 = jax.random.split(jax.random.fold_in(ks[3], 1), 3)
+            blocks.update({"ws1": norm(k1, (n, D, Fs), 0.02),
+                           "ws3": norm(k3, (n, D, Fs), 0.02),
+                           "ws2": norm(k2, (n, Fs, D), out_scale)})
     else:
         blocks.update({
             "w1": norm(ks[3], (n, D, F), 0.02),
@@ -530,6 +606,10 @@ def _run_specs(cfg: TransformerConfig, kind):
         })
         if cfg.router.bias:
             blocks[ROUTER_BIAS] = P(None, None)
+        if cfg.d_ff_shared:
+            blocks.update({"ws1": P(None, None, "tp"),
+                           "ws3": P(None, None, "tp"),
+                           "ws2": P(None, "tp", None)})
     else:
         blocks.update({
             "w1": P(None, None, "tp"),
@@ -591,10 +671,16 @@ def _gelu(x, cfg: TransformerConfig):
     return jax.nn.gelu(x, approximate=not cfg.gelu_exact)
 
 
-def _rms_norm(x, scale, eps):
+def _rms_norm32(x, scale, eps):
+    """RMSNorm in float32 as computed: the statistic, the scaling and the
+    result before any cast."""
     x32 = x.astype(jnp.float32)
     x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (x32 * scale).astype(x.dtype)
+    return x32 * scale
+
+
+def _rms_norm(x, scale, eps):
+    return _rms_norm32(x, scale, eps).astype(x.dtype)
 
 
 def _rms_norm_heads(x, scale, eps):
@@ -639,6 +725,38 @@ def _rope(x, pos0, theta, hd):
     first = jnp.arange(W) % hd < hd // 2
     partner = jnp.where(first, jnp.roll(x32, -(hd // 2), -1),
                         jnp.roll(x32, hd // 2, -1))
+    return (x32 * cos + partner * sin).astype(x.dtype)
+
+
+def _rope_interleaved(x, pos0, theta, hd, first):
+    """Rotary position embeddings on PART of each head, in the interleaved
+    convention (DeepSeek's ``rope_interleave``): x (B, T, heads*hd), the
+    heads side by side; of a head's hd columns those from ``first`` on are
+    rotary, in adjacent pairs (2i, 2i+1) turned by the angle of frequency i
+    of hd - first; the columns before ``first`` pass as they are. A column's
+    partner lies one column to its right (even) or left (odd): two rolls of
+    the whole axis by one, and no (B, T, heads, hd) array is made.
+
+    HF's ``apply_rotary_pos_emb_interleave`` moves a head's even columns to
+    its first half and the odd ones to the second and rotates halves; here a
+    pair stays where it is. The two results are one permutation of a head's
+    rotary columns apart, the same for q and k, so every q . k is the same
+    sum in another order."""
+    B, T, W = x.shape
+    rot = hd - first
+    inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    t = pos0 + jnp.arange(T, dtype=jnp.float32)
+    freqs = jnp.repeat(jnp.outer(t, inv), 2, axis=-1)         # (T, rot)
+    # out[2i] = x[2i] cos - x[2i+1] sin, out[2i+1] = x[2i+1] cos + x[2i] sin
+    sign = jnp.tile(jnp.array([-1.0, 1.0], jnp.float32), rot // 2)
+    cos = jnp.concatenate([jnp.ones((T, first), jnp.float32),
+                           jnp.cos(freqs)], -1)
+    sin = jnp.concatenate([jnp.zeros((T, first), jnp.float32),
+                           jnp.sin(freqs) * sign], -1)
+    cos, sin = jnp.tile(cos, W // hd), jnp.tile(sin, W // hd)
+    x32 = x.astype(jnp.float32)
+    even = (jnp.arange(W) % hd - first) % 2 == 0
+    partner = jnp.where(even, jnp.roll(x32, -1, -1), jnp.roll(x32, 1, -1))
     return (x32 * cos + partner * sin).astype(x.dtype)
 
 
@@ -721,20 +839,22 @@ def _flash(qkv, cfg: TransformerConfig, mesh, kb):
 
 def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
                     attn_bias=None):
-    """q/k/v: (B, T, D), every head's columns side by side -> (B, T, D).
-    Three paths:
+    """q/k/v: (B, T, heads * width), every head's columns side by side ->
+    (B, T, heads * v's width): D everywhere but for latent attention, whose
+    q and k are wider than its v. Three paths:
     - ring: sequence-parallel exact attention over the sp axis (shard_map +
       ppermute ring, hetu_tpu/parallel/ring_attention.py)
     - flash: fused Pallas online-softmax kernel (hetu_tpu/kernels); folds a
       key-padding ``attn_bias`` (B, 1, 1, T) into its score blocks
     - dot: unfused reference form (the reference framework's
       BatchMatMul+Softmax attention); applies any additive ``attn_bias``"""
-    B, T, D = q.shape
-    nh, hd = cfg.n_heads, cfg.head_dim
+    B, T, _ = q.shape
+    nh = cfg.n_heads
+    hd = q.shape[-1] // nh
     kb = _key_bias(attn_bias, B)
     if impl == "flash":
         return _flash((q, k, v), cfg, mesh, kb)
-    q, k, v = (x.reshape(B, T, nh, hd) for x in (q, k, v))
+    q, k, v = (x.reshape(B, T, nh, -1) for x in (q, k, v))
     if impl == "ring":
         from ..parallel.ring_attention import ring_attention
         # the ring works on (B, nh, T, hd) chunks: transposed here, locally
@@ -749,7 +869,7 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
             in_specs=(spec,) * 3 + (P("dp", "sp"),) * len(bias),
             out_specs=spec)
         out = fn(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)), *bias)
-        return out.transpose(0, 2, 1, 3).reshape(B, T, D)
+        return out.transpose(0, 2, 1, 3).reshape(B, T, -1)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) / np.sqrt(hd)
     if cfg.causal:
@@ -761,7 +881,7 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
                      preferred_element_type=jnp.float32).astype(q.dtype)
-    return out.reshape(B, T, D)
+    return out.reshape(B, T, -1)
 
 
 def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
@@ -846,6 +966,72 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
         if cfg.attn_proj_bias:
             out = out + p["bo"].astype(h.dtype)
     return out
+
+
+def _mla_keys(k_nope, k_rope, nh):
+    """Every head's k: its own ``k_nope`` columns beside the ONE rotary key
+    of the token, (B, T, nh * nope) and (B, T, rope) -> (B, T, nh * (nope +
+    rope)). One concatenation of column slices along the lanes: no (B, T,
+    heads, hd) array; the cotangent is the slices back and a sum over the
+    heads for the shared key."""
+    nope = k_nope.shape[-1] // nh
+    return jnp.concatenate(
+        [part for i in range(nh)
+         for part in (k_nope[..., i * nope:(i + 1) * nope], k_rope)], -1)
+
+
+def _mla_qkv(h, p, cfg: TransformerConfig):
+    """Latent attention's projections -> (q (B, T, nh * qk_dim), k the same,
+    v (B, T, nh * v_dim), (the latent (B, T, kv_rank) as ``wkv_a`` writes
+    it, its RMSNorm in float32 before ``wkv_b``'s cast)) as
+    HF ``DeepseekV3Attention`` computes them (``q_lora_rank`` None):
+    q = h Wq, a head [q_nope | q_rope]; [c | k_rope] = h Wkv_a; [k_nope | v]
+    = RMSNorm(c) Wkv_b; q_rope and the one k_rope a token rotated
+    (``_rope_interleaved``); k = [k_nope | k_rope], the rotary key every
+    head's. The matmuls read bf16 operands; the latent's norm is float32."""
+    m, nh = cfg.mla, cfg.n_heads
+    proj = lambda x, w: jnp.einsum(
+        "btd,de->bte", x, w.astype(h.dtype),
+        preferred_element_type=jnp.float32).astype(h.dtype)
+    with jax.named_scope(SCOPE_MLA_Q):
+        q = _rope_interleaved(proj(h, p["wq"]), 0, cfg.rope_theta, m.qk_dim,
+                              m.nope_dim)
+    with jax.named_scope(SCOPE_MLA_KV_DOWN):
+        raw, k_rope = jnp.split(proj(h, p["wkv_a"]), [m.kv_rank], axis=-1)
+        latent = _rms_norm32(raw, p["kv_norm"], cfg.ln_eps)
+        c = checkpoint_name(latent.astype(h.dtype), REMAT_MLA_LATENT)
+        k_rope = checkpoint_name(
+            _rope_interleaved(k_rope, 0, cfg.rope_theta, m.rope_dim, 0),
+            REMAT_MLA_LATENT)
+    with jax.named_scope(SCOPE_MLA_KV_UP):
+        k_nope, v = jnp.split(proj(c, p["wkv_b"]), [nh * m.nope_dim], axis=-1)
+        k = _mla_keys(k_nope, k_rope, nh)
+    return q, k, v, (raw, latent)
+
+
+def _mla(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+    """Multi-head latent attention (``cfg.mla``): ``_mla_qkv``, then softmax(
+    q k^T / sqrt(qk_dim)) v a head at the two widths (scores qk_dim wide,
+    values v_dim: the flash kernels take both, the ``dot`` path reshapes each
+    array by its own), then `wo` from nh * v_dim. The three projections'
+    scopes nest inside ``SCOPE_BLK_QKV``."""
+    impl = _resolve_attn_impl(cfg, mesh, h.shape[1], attn_bias)
+    if impl == "ring":
+        raise NotImplementedError(
+            "latent attention on a mesh that shards the sequence (sp > 1): "
+            "the ring takes one head width for q, k and v")
+    with jax.named_scope(SCOPE_BLK_QKV):
+        q, k, v, _ = _mla_qkv(h, p, cfg)
+        # the flash `custom_vjp`'s inputs, as on `_split_heads`' path
+        q, k, v = (checkpoint_name(x, name) for x, name in (
+            (q, REMAT_ATTN_Q), (k, REMAT_ATTN_K), (v, REMAT_ATTN_V)))
+    with jax.named_scope(SCOPE_BLK_ATTN):
+        out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias)
+    if impl != "flash":
+        out = checkpoint_name(out, REMAT_ATTN_O)
+    with jax.named_scope(SCOPE_BLK_WO):
+        return jnp.einsum("bte,ed->btd", out, p["wo"].astype(h.dtype),
+                          preferred_element_type=jnp.float32).astype(h.dtype)
 
 
 def _ssm_dt(dt_raw, dt_bias):
@@ -1050,7 +1236,8 @@ class _Kind:
 
 _KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
           "mamba": _Kind(_init_mamba, _mamba_specs, _mamba),
-          "conv": _Kind(_init_short_conv, _short_conv_specs, _short_conv)}
+          "conv": _Kind(_init_short_conv, _short_conv_specs, _short_conv),
+          "mla": _Kind(_init_mla, _mla_specs, _mla)}
 
 
 def _dense_mlp(h, p, cfg, mesh):
@@ -1110,8 +1297,8 @@ def _route(x, p, cfg: TransformerConfig):
     - the picks: the k largest scores; with ``router.bias`` the k largest of
       score + ``p[ROUTER_BIAS]``, the bias entering nowhere else;
     - weights: the picks' scores as they stand (OLMoE: UNnormalised softmax
-      probabilities), or over (their sum + 1e-6) (``router.normalize``),
-      times ``router.scale``.
+      probabilities), or over (their sum + ``router.normalize_eps``)
+      (``router.normalize``), times ``router.scale``.
 
     balance = E * sum_e f_e P_e with f_e the picks of expert e over tokens
     (they sum to k) and P_e its mean probability: Switch Transformer eq. 4
@@ -1133,7 +1320,7 @@ def _route(x, p, cfg: TransformerConfig):
     if r.bias:
         top_p = _noting_picks(top_p, p[ROUTER_BIAS], counts)
     if r.normalize:
-        top_p = _router_normalize(top_p)
+        top_p = _router_normalize(top_p, r.normalize_eps)
     if r.scale != 1.0:
         top_p = top_p * r.scale
     if not r.aux_losses:
@@ -1154,9 +1341,9 @@ def _router_select(scores, bias):
     return scores + bias.astype(jnp.float32)
 
 
-def _router_normalize(top_p):
+def _router_normalize(top_p, eps=1e-6):
     """The picks' weights over their sum, float32."""
-    return top_p / (jnp.sum(top_p, -1, keepdims=True) + 1e-6)
+    return top_p / (jnp.sum(top_p, -1, keepdims=True) + eps)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -1433,6 +1620,23 @@ _share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
 
 
 def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
+    """An expert layer's MLP half -> (out, aux (2,)): the routed picks
+    (``_routed_experts``) and, under ``cfg.d_ff_shared``, the shared expert
+    beside them: ONE SwiGLU MLP on every token, added to the routed sum as
+    HF ``DeepseekV3MoE.forward`` adds it. It is no expert of the router's:
+    a share (``cfg.router.width``) computes it whole, once, whatever it
+    holds, so the parts of the members of a group add up to the layer only
+    with the shared expert counted once."""
+    out, aux = _routed_experts(h, p, cfg, mesh)
+    if cfg.d_ff_shared:
+        with jax.named_scope(SCOPE_MOE_SHARED):
+            out = out + _dense_mlp(
+                h, {"w1": p["ws1"], "w3": p["ws3"], "w2": p["ws2"]}, cfg,
+                mesh)
+    return out, aux
+
+
+def _routed_experts(h, p, cfg: TransformerConfig, mesh):
     """Dropless top-k MoE: every pick on an expert held here is computed.
     The S*k picks are sorted by expert (stable), token rows gathered in that
     order, each projection is one grouped matmul over the uneven groups, and
@@ -1758,10 +1962,13 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     nothing is kept there).
 
     The candidates of ``REMAT_CANDIDATES`` are admitted in their order
+    (latent attention's ``REMAT_MLA_LATENT`` after o and lse)
     while their bytes, times the block applications of a step that write
     them (``n_layers`` x ``n_loops``: a looped model keeps every pass's; o
-    and lse: the attention layers alone; q, k, v: the attention layers whose
-    ``_split_heads`` makes them, k and v at ``kv_heads``; the sandwich
+    and lse: the attention layers alone, an mla layer's o at ITS width, nh *
+    v_dim; the latent and the rotary key: the mla layers; q, k, v: the
+    attention layers whose ``_split_heads`` makes them, k and v at
+    ``kv_heads``, and the mla layers at their two widths; the sandwich
     norms' inputs: under ``cfg.sandwich_norm``), stay within the budget: the
     limit less what the step holds whatever is kept (``_state_bytes``, the
     stack of layer inputs the scans keep, one an application,
@@ -1792,20 +1999,32 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
         # activations carry the batch: dp cuts them, and maybe more
         - max(_block_residual_bytes(cfg, mesh, h, blocks, attn_bias, kind)
               for kind, blocks in by_kind.items()) // dp)
-    attention = cfg.n_loops * sum(
-        n for kind, n in layer_runs(cfg) if mixer_of(kind) == "attention")
+    attention, mla = (cfg.n_loops * sum(
+        n for kind, n in layer_runs(cfg) if mixer_of(kind) == mixer)
+        for mixer in ("attention", "mla"))
     split = 0 if _projection_in_place(
         cfg, mesh, _resolve_attn_impl(cfg, mesh, T, attn_bias)) else attention
+    # one mla layer's arrays, in columns a token over the stream's D
+    m = cfg.mla
+    mla_o, mla_latent, mla_qkv = ((0, 0, 0) if not mla else (
+        by_head * cfg.n_heads * m.v_dim // D,
+        act * (m.kv_rank + m.rope_dim) // D,
+        by_head * cfg.n_heads * (2 * m.qk_dim + m.v_dim) // D))
     # {x1, x2} of every block (pre-LN: x2 is the block's output, which the
-    # scan keeps anyway), {o, lse} of an attention block, {q, k, v} of one on
-    # the split path, the two sandwich norms' inputs of every block: the
-    # bytes of each, times the applications that write them
+    # scan keeps anyway), {o, lse} of an attention block of either kind, the
+    # latent of an mla block, {q, k, v} of an attention block on the split
+    # path and of an mla block, the two sandwich norms' inputs of every
+    # block: the bytes of each, times the applications that write them
     costs = (applications * by_seq * (2 if cfg.post_ln else 1),
-             attention * (by_head + lse),
-             split * (by_head + 2 * by_head * cfg.kv_heads // cfg.n_heads),
+             attention * (by_head + lse) + mla * (mla_o + lse),
+             mla * mla_latent,
+             split * (by_head + 2 * by_head * cfg.kv_heads // cfg.n_heads)
+             + mla * mla_qkv,
              applications * 2 * by_seq if cfg.sandwich_norm else 0)
+    order = (REMAT_CANDIDATES[:2] + ((REMAT_MLA_LATENT,),)
+             + REMAT_CANDIDATES[2:])
     names, held = (), 0
-    for candidate, cost in zip(REMAT_CANDIDATES, costs):
+    for candidate, cost in zip(order, costs):
         if not cost:
             continue
         if held + cost > budget:
@@ -2038,6 +2257,22 @@ def short_conv_terms(params, tokens, cfg: TransformerConfig):
         _norm(h, p["ln1_scale"], p["ln1_bias"], cfg), p)
     return {"B": Bg, "C": Cg, "x": x, "conv_w": p["conv_w"],
             "y": _short_conv_gates(Bg, Cg, x, p["conv_w"])}
+
+
+def mla_terms(params, tokens, cfg: TransformerConfig):
+    """The FIRST mla layer's projections on ``tokens`` (B, T) with what the
+    float32 part was computed from: ``x`` (B, T, D) the rows the projections
+    read (the compute dtype), ``c`` (B, T, kv_rank) the latent as ``wkv_a``
+    writes it, ``kv_norm`` its scale, ``latent`` float32 = RMSNorm(c) as
+    ``wkv_b``'s cast reads it, and ``q``, ``k``, ``v`` as the attention
+    kernels take them (their float32 part, the softmax statistic, is the
+    kernels' own output: ``kernels.flash_attention._fwd_pallas``)."""
+    h, p, _ = _first_layer_of(params, tokens, cfg,
+                              lambda kind: mixer_of(kind) == "mla")
+    x = _norm(h, p["ln1_scale"], p["ln1_bias"], cfg)
+    q, k, v, (c, latent) = _mla_qkv(x, p, cfg)
+    return {"x": x, "c": c, "kv_norm": p["kv_norm"], "latent": latent,
+            "q": q, "k": k, "v": v}
 
 
 def aux_weights(aux_weight=0.01):

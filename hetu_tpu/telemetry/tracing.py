@@ -60,6 +60,12 @@ SCOPE_MOE_EXPERTS = "hetu_moe_experts"    # grouped matmuls + activation
 SCOPE_MOE_COMBINE = "hetu_moe_combine"    # un-permute, weight, sum over k
 MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
               SCOPE_MOE_COMBINE)
+# the always-on branch of an expert layer (`cfg.d_ff_shared`: the shared
+# expert, a SwiGLU on every token beside the routed picks; transformer.
+# _moe_mlp), a FIFTH part beside the four and inside none of them: a reader
+# of the four (benchmark/reduce/moe.py) does not count it, benchmark/reduce/
+# mla.py reads it. Its two halves keep SCOPE_BLK_MLP_UP / _DOWN inside it
+SCOPE_MOE_SHARED = "hetu_moe_shared"
 # a looped model's exit head (transformer.loss_fn at n_loops > 1), nested
 # under SCOPE_FWD: the exit gate, the n_loops head passes, the exit
 # distribution q and its entropy. The counter beside it is the pure function
@@ -114,6 +120,17 @@ SCOPE_BLK_NORM = "hetu_blk_norm"  # every LayerNorm / RMSNorm of the residual
                                   # final); QK-norm stays with SCOPE_BLK_QKV
 BLOCK_SCOPES = (SCOPE_BLK_QKV, SCOPE_BLK_ATTN, SCOPE_BLK_WO,
                 SCOPE_BLK_MLP_UP, SCOPE_BLK_MLP_DOWN, SCOPE_BLK_NORM)
+# the three projections of latent attention (transformer._mla), each nested
+# INSIDE SCOPE_BLK_QKV (`.../hetu_blk_qkv/hetu_mla_kv_up/...`), so a reader of
+# the block's parts (reduce/block.py) counts them as projection time, and
+# benchmark/reduce/mla.py, which takes the innermost of these, tells them
+# apart; the kernels stay under SCOPE_BLK_ATTN and `wo` under SCOPE_BLK_WO
+SCOPE_MLA_Q = "hetu_mla_q"              # Wq and the rotary columns of q
+SCOPE_MLA_KV_DOWN = "hetu_mla_kv_down"  # Wkv_a, the latent's RMSNorm, the
+                                        # one rotary key's rotation
+SCOPE_MLA_KV_UP = "hetu_mla_kv_up"      # Wkv_b and the assembly of k: every
+                                        # head's k_nope beside the one k_rope
+MLA_SCOPES = (SCOPE_MLA_Q, SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP)
 # the two outside the block
 SCOPE_EMBED = "hetu_embed"  # token (position, segment) lookups, BERT's
                             # embedding LayerNorm, the embedding multiplier;
@@ -156,6 +173,14 @@ REMAT_NORM2_IN = "hetu_norm2_in"
 REMAT_CANDIDATES = ((REMAT_X1, REMAT_X2), (REMAT_ATTN_O, REMAT_ATTN_LSE),
                     (REMAT_ATTN_Q, REMAT_ATTN_K, REMAT_ATTN_V),
                     (REMAT_NORM1_IN, REMAT_NORM2_IN))
+# latent attention's own (`transformer._mla`): the normed latent and the one
+# rotated rotary key a token, 512 + 64 columns where q, k and v are 6,144 +
+# 6,144 + 4,096: what `Wkv_b`'s backward pass reads again, and with it
+# `Wkv_a`, the latent's norm and the key's rotation. One name on both
+# values. A stack with such layers admits it after o and lse and BEFORE q,
+# k, v (`transformer._remat_names`): by bytes it is the cheapest thing an
+# attention layer of any kind can keep. Not measured alone on the chip
+REMAT_MLA_LATENT = "hetu_mla_latent"
 # host spans inside SubExecutor.run, children of STEP, in call order
 (BOUNDARY, FEED, DL_WAIT, PS_PULL, BUILD, DISPATCH, PREFETCH, PS_PUSH,
  POSTSTEP) = STEP_SPANS = (

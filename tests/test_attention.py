@@ -481,12 +481,18 @@ def test_choose_tiles(s, d, causal, dtype):
             # the lane rule: a step's columns are whole 128-lane tiles, or
             # every head of the array
             assert (group * d) % 128 == 0 or group == heads
-            # the floor (what every call had before) is taken where nothing
-            # fits the count: whole f32 k and v at s = 4096, d = 128
+            # what fits unasked is taken before anything else; where
+            # nothing does (whole f32 k and v at s = 4096, d = 128) the call
+            # asks Mosaic for `_VMEM_LIMIT` and chooses within its budget;
+            # the floor is taken where that fits nothing either
             floor = (128, 128, fa._head_groups(heads, d)[0])
-            assert (block_q, block_k, group) == floor or fa._vmem_bytes(
-                s, d, jnp.dtype(dtype).itemsize, block_q, block_k, group,
-                kernel) <= fa._VMEM_BUDGET
+            count = fa._vmem_bytes(s, d, jnp.dtype(dtype).itemsize, block_q,
+                                   block_k, group, kernel)
+            unasked = fa._asking(kernel, s, d, d, dtype, block_q, block_k,
+                                 group) == {}
+            assert unasked == (count <= fa._VMEM_BUDGET)
+            assert (block_q, block_k, group) == floor or count <= (
+                fa._VMEM_BUDGET if unasked else fa._VMEM_BUDGET_ASKED)
     # blocks the caller passes are kept, and still get a head group
     assert fa._choose_tiles(s, d, dtype, causal, 12, 64, 32)[:2] == (
         min(64, s), min(32, s))

@@ -49,10 +49,23 @@ def test_flagship_phase_tiny(capsys):
     moe = dict(chip_smoke.MOE_ROW, vocab_size=64, d_model=64, n_heads=4,
                d_ff=32, max_seq_len=32, n_experts=8, n_experts_per_tok=2,
                dtype=jnp.float32)
+    mla = dict(chip_smoke.MLA_ROW, hidden_size=64, intermediate_size=128,
+               moe_intermediate_size=32, num_attention_heads=4,
+               num_key_value_heads=4, kv_lora_rank=32, qk_nope_head_dim=32,
+               qk_rope_head_dim=16, v_head_dim=24, n_routed_experts=2,
+               num_routed_experts=8, first_expert_held=2,
+               num_experts_per_tok=2, vocab_size=64,
+               max_position_embeddings=32, dtype=jnp.float32)
     rec = chip_smoke.phase_flagship(cfg=cfg, batch=4, seq=128, n_pred=8,
-                                    steps=3, chip=False, moe=moe)
+                                    steps=3, chip=False, moe=moe, mla=mla,
+                                    mla_batch=2)
     line = _last_json(capsys)
     assert line["phase"] == "flagship"
+    assert line["mla"]["dropped_picks"] == 0 and line["mla"]["tokens"] == 64
+    assert (line["mla"]["qk_dim"], line["mla"]["v_dim"],
+            line["mla"]["d_ff_shared"]) == (48, 24, 64)
+    assert abs(line["mla"]["loss"] - line["mla"]["dot_loss"]) < 1e-4
+    assert 0 < line["mla"]["held_picks"] < 64 * 2
     assert line["moe"]["dropped_picks"] == 0
     assert line["moe"]["tokens"] == 64 and line["moe"]["experts"] == 8
     assert max(line["moe"]["rel_rms_err"].values()) < 1e-4

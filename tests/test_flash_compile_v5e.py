@@ -138,6 +138,51 @@ def test_flash_compiles_for_v5e(one_chip, no_compile_cache, shape, dtype,
         name: int(name in kernels) for name in FLASH_KERNELS}, text
 
 
+# latent attention (PR 39): q and k of one head width, v and o of another
+TWO_WIDTHS = [
+    pytest.param((4, 32, 8192, 192, 128), True, id="kanana-seq8192"),
+    pytest.param((2, 4, 512, 192, 128), True, id="one-tile-512"),
+    pytest.param((2, 6, 1024, 64, 128), False, id="v-wider-than-q"),
+]
+
+
+@pytest.mark.parametrize("shape,causal", TWO_WIDTHS)
+def test_flash_compiles_for_v5e_at_two_head_widths(one_chip,
+                                                   no_compile_cache, shape,
+                                                   causal):
+    """q . k at `d` columns a head and p . v at `dv`, forward and gradient
+    on three arrays: Mosaic takes the head groups (whole lane tiles on both
+    arrays), the lane slice of a head that starts mid-tile and the 192-deep
+    contraction; the cell's call, whose whole k and v are over what Mosaic
+    gives unasked, compiles under the VMEM it asks for."""
+    b, h, s, d, dv = shape
+
+    def arr(columns):
+        return jax.ShapeDtypeStruct((b, s, columns), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    scale = 1.0 / d ** 0.5
+
+    def fwd_and_grads(qkv, do):
+        out, lse = fa._fwd_pallas(qkv, h, None, scale, causal, None, None,
+                                  interpret=False)
+        return out, fa._bwd_pallas(
+            (qkv, out, lse, None), do, n_heads=h, scale=scale, causal=causal,
+            block_q=None, block_k=None, interpret=False)
+
+    compiled = jax.jit(fwd_and_grads).lower(
+        (arr(h * d), arr(h * d), arr(h * dv)), arr(h * dv)).compile()
+    out, (dq, dk, d_v) = compiled.out_info
+    assert out.shape == d_v.shape == (b, s, h * dv)
+    assert dq.shape == dk.shape == (b, s, h * d)
+    bq, bk, kernels = fa._choose_tiles(s, d, jnp.bfloat16, causal, h, dv=dv)
+    assert _count_by_name(_kernel_calls(compiled.as_text())) == {
+        name: int(name in kernels) for name in FLASH_KERNELS}
+    asked = [bool(fa._asking(k, s, d, dv, jnp.bfloat16, bq, bk, g))
+             for k, g in kernels.items()]
+    assert all(asked) == (s == 8192) and any(asked) == (s == 8192)
+
+
 # The kernels of a many-tile call are what they were before `flash_bwd`
 # (PR 33): the lowered forward and backward of the three decoders' calls,
 # the Mosaic modules inside read back as text WITHOUT their locations (a

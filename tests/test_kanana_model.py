@@ -1,0 +1,592 @@
+"""kanana-2-30b-a3b (a DeepSeek-V3 dialect) on the flagship trunk (ISSUE 39),
+on the CPU at a small size with the real structure (latent attention whose
+keys are wider than its values, 1 dense layer + 2 expert layers with a shared
+expert; 8 experts, top 2): the system against the float32 reference
+(benchmark/configs/kanana-2-30b-a3b/reference.py), the reference against
+`transformers`' `DeepseekV3ForCausalLM` on copied weights, the eight shares
+of an expert layer adding up to the whole with the shared expert counted
+once, the flash kernels at unequal head widths, the rotary columns, what
+`remat` may keep by kind, the fifth other flagship cell's tree and lowered
+program, the scopes, and the refusals by name."""
+import dataclasses
+import hashlib
+import importlib.util
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hetu_tpu.kernels import flash_attention as fa
+from hetu_tpu.models import (generate, hf_deepseek_v3 as hd, hf_lfm2,
+                             transformer as tfm)
+from hetu_tpu.parallel import pipeline
+from hetu_tpu.telemetry import tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT,
+                                                                     path))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+reference = _load("benchmark/configs/kanana-2-30b-a3b/reference.py",
+                  "kanana_reference")
+
+# the published keys at a small size, every expert held
+HF = dict(
+    attention_bias=False, first_k_dense_replace=1, head_dim=16,
+    hidden_act="silu", hidden_size=64, intermediate_size=128,
+    kv_lora_rank=32, max_position_embeddings=64, model_type="deepseek_v3",
+    moe_intermediate_size=48, moe_layer_freq=1, n_group=1,
+    n_routed_experts=8, n_shared_experts=2, norm_topk_prob=True,
+    num_attention_heads=4, num_experts_per_tok=2, num_hidden_layers=3,
+    num_key_value_heads=4, q_lora_rank=None, qk_head_dim=48,
+    qk_nope_head_dim=32, qk_rope_head_dim=16, rms_norm_eps=1e-6,
+    rope_interleave=True, rope_scaling=None, rope_theta=1000000,
+    routed_scaling_factor=2.448, scoring_func="sigmoid",
+    tie_word_embeddings=False, topk_group=1, topk_method="noaux_tc",
+    v_head_dim=24, vocab_size=256)
+# one chip's share: experts 2 and 3 of the 8
+SHARE = {**HF, "n_routed_experts": 2, "num_routed_experts": 8,
+         "first_expert_held": 2}
+CONFIGS = {"whole": HF, "share": SHARE}
+
+
+def _data(hf, seed, B=2, T=32):
+    ids = jax.random.randint(jax.random.PRNGKey(seed), (B, T + 1), 0,
+                             hf["vocab_size"])
+    return ids[:, :-1], ids[:, 1:]
+
+
+def _params(cfg, seed=0, bias=0.05):
+    """Seeded weights, the selection bias moved off zero so that it matters
+    to the picks, the norms' scales off one."""
+    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
+    key = jax.random.PRNGKey(seed + 100)
+
+    def off(path, x):
+        if tfm._is_router_bias(path):
+            return bias * jax.random.normal(key, x.shape)
+        if path[-1].key in ("kv_norm", "ln1_scale", "ln2_scale"):
+            return x + 0.1 * jax.random.normal(key, x.shape)
+        return x
+
+    return jax.tree_util.tree_map_with_path(off, params)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / max(np.sqrt(np.mean(want ** 2)), 1e-30))
+
+
+# -- the loader ------------------------------------------------------------------
+
+def test_config_from_hf_reads_every_key_of_the_row():
+    cfg = hd.config_from_hf(SHARE, router_bias_rate=1e-3)
+    assert tfm.layer_runs(cfg) == (("mla" + tfm.DENSE, 1), ("mla", 2))
+    assert (cfg.d_ff, cfg.d_ff_expert, cfg.d_ff_shared) == (128, 48, 96)
+    assert cfg.mla == tfm.MLAConfig(kv_rank=32, nope_dim=32, rope_dim=16,
+                                    v_dim=24) and cfg.mla.qk_dim == 48
+    assert cfg.rope and cfg.rope_theta == 1e6 and not cfg.tied_head
+    assert cfg.ln_eps == 1e-6 and cfg.norm == "rmsnorm"
+    assert cfg.router == tfm.Router(
+        score="sigmoid", bias=True, normalize=True, normalize_eps=1e-20,
+        scale=2.448, aux_losses=False, bias_rate=1e-3, width=8, first_held=2)
+    assert (cfg.n_experts, cfg.n_experts_per_tok) == (2, 2)
+    whole = hd.config_from_hf(HF)
+    assert whole.router.width == 0 and whole.n_experts == 8
+    # the published file itself: layers 0-4, 16 of 128 experts from expert 0
+    with open(os.path.join(
+            ROOT, "benchmark/configs/kanana-2-30b-a3b/config.json")) as f:
+        cell = hd.config_from_hf(json.load(f))
+    assert tfm.layer_runs(cell) == (("mla+dense", 1), ("mla", 4))
+    assert (cell.n_heads, cell.mla.qk_dim, cell.mla.v_dim,
+            cell.mla.kv_rank) == (32, 192, 128, 512)
+    assert (cell.n_experts, cell.router.width, cell.router.first_held,
+            cell.n_experts_per_tok, cell.d_ff_shared) == (16, 128, 0, 6, 1536)
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cell))
+    moe = shapes["blocks"][1]
+    assert moe["wq"].shape == (4, 2048, 32 * 192)
+    assert moe["wkv_a"].shape == (4, 2048, 576)
+    assert moe["wkv_b"].shape == (4, 512, 32 * 256)
+    assert moe["wo"].shape == (4, 32 * 128, 2048)
+    assert moe["router"].shape == (4, 2048, 128)
+    assert moe["w1"].shape == (4, 16, 2048, 768)
+    assert moe["ws1"].shape == (4, 2048, 1536)
+    assert moe["ws2"].shape == (4, 1536, 2048)
+    assert shapes["blocks"][0]["w1"].shape == (1, 2048, 6144)
+    assert "ws1" not in shapes["blocks"][0]
+    assert shapes["head"].shape == (2048, 16128)
+    # the ISSUE's count: 576.4M parameters beside 640 selection-bias entries
+    assert round(tfm.count_params(shapes) / 1e6, 1) == 576.6
+
+
+@pytest.mark.parametrize("key,value,named", [
+    ("n_group", 2, "group-limited"), ("topk_group", 2, "group-limited"),
+    ("q_lora_rank", 1536, "low-rank q"), ("rope_scaling", {"factor": 4},
+                                         "scaled rotary"),
+    ("moe_layer_freq", 2, "moe_layer_freq"),
+    ("scoring_func", "softmax", "sigmoid"),
+    ("rope_interleave", False, "interleaved"),
+    ("num_key_value_heads", 2, "every head's own"),
+    ("qk_head_dim", 64, "qk_nope_head_dim +")])
+def test_loader_refuses_by_name(key, value, named):
+    with pytest.raises(NotImplementedError, match=re.escape(named)):
+        hd.config_from_hf({**HF, key: value})
+
+
+def test_state_dict_round_trip_and_names():
+    cfg = hd.config_from_hf(SHARE)
+    params = _params(cfg)
+    sd = hd.state_dict_from_params(params, cfg)
+    assert sd["model.layers.1.mlp.gate.e_score_correction_bias"].shape == (8,)
+    assert sd["model.layers.0.self_attn.kv_b_proj.weight"].shape == (
+        4 * (32 + 24), 32)
+    assert sd["model.layers.2.mlp.shared_experts.down_proj.weight"].shape == (
+        64, 96)
+    assert "model.layers.1.mlp.experts.2.gate_proj.weight" in sd
+    assert "model.layers.1.mlp.experts.0.gate_proj.weight" not in sd
+    back = hd.params_from_state_dict(sd, cfg, xp=jnp)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(back)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # kv_b_proj's rows are a head [k_nope | v]; the trunk's columns are
+    # [every head's k_nope | every head's v]
+    w = np.asarray(sd["model.layers.0.self_attn.kv_b_proj.weight"])
+    ours = np.asarray(params["blocks"][0]["wkv_b"][0])
+    np.testing.assert_array_equal(ours[:, 32:64], w[56:88].T)     # head 1 k
+    np.testing.assert_array_equal(ours[:, 128 + 24:128 + 48],
+                                  w[56 + 32:112].T)               # head 1 v
+
+
+# -- the system against the reference ---------------------------------------------
+
+@pytest.mark.parametrize("which", sorted(CONFIGS))
+def test_system_matches_reference_loss_hidden_picks_and_gradients(which):
+    hf = CONFIGS[which]
+    cfg = hd.config_from_hf(hf, router_bias_rate=1e-3)
+    params = _params(cfg)
+    tokens, targets = _data(hf, 1)
+    sd = hd.state_dict_from_params(params, cfg)
+    want_loss, want = reference.loss_terms(sd, tokens, targets, hf)
+    loss = tfm.loss_fn(params, tokens, targets, cfg)
+    assert abs(float(loss) - float(want_loss)) < 2e-6
+    hidden, _ = tfm.forward_hidden(params, tokens, cfg)
+    assert _rel(hidden, want["hidden"][-1]) < 2e-6
+    stats = tfm.moe_routing_stats(params, tokens, cfg)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(stats["experts"]), -1),
+        np.sort(np.asarray(want["experts"]), -1))
+    np.testing.assert_array_equal(np.asarray(stats["picks"]),
+                                  np.asarray(want["counts"]))
+    assert int(stats["dropped"].sum()) == 0
+    grads = hd.state_dict_from_params(
+        jax.grad(tfm.loss_fn)(params, tokens, targets, cfg), cfg)
+    names = [n for n in sd if "e_score" not in n]
+    _, want_grads = reference.grads_of(names)(sd, tokens, targets, hf)
+    for n in names:
+        assert _rel(grads[n], want_grads[n]) < 2e-5, n
+    # the lean gradient is jax.grad of the plain forward
+    few = ["model.layers.1.self_attn.kv_a_layernorm.weight",
+           "model.layers.1.mlp.gate.weight",
+           "model.layers.2.mlp.shared_experts.up_proj.weight"]
+    plain = jax.grad(lambda part: reference.loss_terms(
+        {**sd, **part}, tokens, targets, hf)[0])({n: sd[n] for n in few})
+    for n in few:
+        assert _rel(want_grads[n], plain[n]) < 1e-5, n
+
+
+def test_flash_path_is_the_dot_path():
+    """The trunk with the kernels forced on (interpreted here): the same
+    loss and gradients, at 48 / 24 columns a head."""
+    cfg = hd.config_from_hf(SHARE)
+    params = _params(cfg)
+    tokens, targets = _data(SHARE, 2)
+    flash = dataclasses.replace(cfg, attn_impl="flash")
+    a, ga = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, cfg)
+    b, gb = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, flash)
+    assert abs(float(a) - float(b)) < 1e-6
+    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-6)
+
+
+def test_bias_moves_by_the_sign_rule_and_adamw_leaves_it():
+    cfg = hd.config_from_hf(HF, router_bias_rate=1e-2)
+    params = _params(cfg, bias=0.0)
+    tokens, targets = _data(HF, 3)
+    opt = tfm.init_opt_state(params)
+    want = reference.loss_terms(hd.state_dict_from_params(params, cfg),
+                                tokens, targets, HF)[1]["counts"]
+    _, new, opt = tfm.make_train_step(cfg, lr=1e-3)(
+        jax.tree.map(jnp.copy, params), opt, tokens, targets)
+    np.testing.assert_allclose(
+        np.asarray(new["blocks"][1][tfm.ROUTER_BIAS]),
+        reference.bias_after_step(np.zeros((2, 8)), want, 1e-2), atol=1e-7)
+    np.testing.assert_array_equal(
+        np.asarray(opt["m"]["blocks"][1][tfm.ROUTER_BIAS]), np.asarray(want))
+
+
+# -- the reference against transformers --------------------------------------------
+
+def test_reference_matches_transformers_deepseek_v3():
+    """`DeepseekV3ForCausalLM` (eager attention, float32) on copied weights,
+    every expert held, the selection bias off zero: the reference's logits
+    are HF's."""
+    torch = pytest.importorskip("torch")
+    transformers = pytest.importorskip("transformers")
+    keys = {k: v for k, v in HF.items() if k not in ("qk_head_dim",)}
+    config = transformers.DeepseekV3Config(**keys,
+                                           attn_implementation="eager")
+    torch.manual_seed(0)
+    model = transformers.DeepseekV3ForCausalLM(config).eval().float()
+    with torch.no_grad():
+        for name, buf in model.named_buffers():
+            if name.endswith("e_score_correction_bias"):
+                buf.copy_(0.05 * torch.randn_like(buf))
+        for name, p in model.named_parameters():
+            if name.endswith("layernorm.weight") or name == "model.norm.weight":
+                p.add_(0.1 * torch.randn_like(p))
+    sd = {k: v.detach().numpy() for k, v in model.state_dict().items()
+          if "rotary_emb" not in k}
+    tokens, _ = _data(HF, 4)
+    with torch.no_grad():
+        want = model(torch.tensor(np.asarray(tokens))).logits.numpy()
+    got = reference.logits(sd, tokens, HF)
+    assert _rel(got, want) < 2e-5
+    # and the trunk loads the same checkpoint to the same logits
+    cfg = hd.config_from_hf(config)
+    assert cfg.mla.qk_dim == 48 and cfg.d_ff_shared == 96
+    params = hd.params_from_hf(model.state_dict(), cfg)
+    ours, _ = tfm.forward(params, tokens, cfg)
+    assert _rel(ours, want) < 2e-5
+
+
+def test_rope_interleaved_is_hfs_up_to_one_permutation_of_the_pairs():
+    """`_rope_interleaved` leaves a pair where it is; HF moves the even
+    columns to the first half: the same numbers, and every q . k the same."""
+    B, T, H, nope, rope = 2, 16, 3, 8, 6
+    hdim = nope + rope
+    x = jax.random.normal(jax.random.PRNGKey(0), (B, T, H * hdim))
+    got = tfm._rope_interleaved(x, 0, 1e4, hdim, nope).reshape(B, T, H, hdim)
+    x4 = x.reshape(B, T, H, hdim).transpose(0, 2, 1, 3)
+    want = reference._rope_interleave(x4[..., nope:], 1e4).transpose(
+        0, 2, 1, 3)
+    np.testing.assert_allclose(np.asarray(got[..., :nope]),
+                               np.asarray(x.reshape(B, T, H, hdim)[..., :nope]))
+    np.testing.assert_allclose(np.asarray(got[..., nope::2]),
+                               np.asarray(want[..., :rope // 2]), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got[..., nope + 1::2]),
+                               np.asarray(want[..., rope // 2:]), atol=1e-6)
+    # the one rotary key of a token: a "head" that is all rotary
+    k = jax.random.normal(jax.random.PRNGKey(1), (B, T, rope))
+    got_k = tfm._rope_interleaved(k, 0, 1e4, rope, 0)
+    want_k = reference._rope_interleave(k[:, None], 1e4)[:, 0]
+    np.testing.assert_allclose(np.asarray(got_k[..., 0::2]),
+                               np.asarray(want_k[..., :rope // 2]), atol=1e-6)
+
+
+# -- the shares add up -------------------------------------------------------------
+
+def test_the_eight_shares_of_an_expert_layer_add_up_to_the_whole():
+    """One expert layer of 16 experts cut in EIGHT shares of 2: the routed
+    parts of the eight (the system's `_moe_mlp` told its share, less the
+    shared expert every member computes alike) and the shared expert ONCE
+    sum to the UNCUT reference's layer; each share's own output is its
+    routed part plus the shared expert, and the reference given the same
+    share says the same."""
+    hf = {**HF, "n_routed_experts": 16, "num_hidden_layers": 2}
+    whole_cfg = hd.config_from_hf(hf)
+    params = _params(whole_cfg)
+    p = jax.tree.map(lambda x: x[0], params["blocks"][1])
+    sd = hd.state_dict_from_params(params, whole_cfg)
+    w = {n[len("model.layers.1."):]: v for n, v in sd.items()
+         if n.startswith("model.layers.1.")}
+    m = jax.random.normal(jax.random.PRNGKey(5), (2, 32, 64))
+    rows = m.reshape(-1, 64)
+    want, _ = reference._experts_math(rows, w, hf, 0)
+    whole, _ = tfm._moe_mlp(m, p, whole_cfg, None)
+    np.testing.assert_allclose(np.asarray(whole.reshape(-1, 64)),
+                               np.asarray(want), atol=2e-6)
+    shared = reference._swiglu(rows, w, "mlp.shared_experts.")
+    assert float(jnp.max(jnp.abs(shared))) > 1e-3
+    routed = []
+    for first in range(0, 16, 2):
+        share = {**hf, "n_routed_experts": 2, "num_routed_experts": 16,
+                 "first_expert_held": first}
+        cfg = hd.config_from_hf(share)
+        held = {**p, **{k: p[k][first:first + 2] for k in
+                        ("w1", "w3", "w2", "b1", "b2")}}
+        out, _ = tfm._moe_mlp(m, held, cfg, None)
+        same, _ = reference._experts_math(rows, w, share, first)
+        np.testing.assert_allclose(np.asarray(out.reshape(-1, 64)),
+                                   np.asarray(same), atol=2e-6)
+        # what this member adds that no other does
+        part, _ = tfm._moe_mlp(m, held, dataclasses.replace(
+            cfg, d_ff_shared=0), None)
+        np.testing.assert_allclose(
+            np.asarray(out - part).reshape(-1, 64), np.asarray(shared),
+            atol=2e-6)
+        routed.append(part.reshape(-1, 64))
+    np.testing.assert_allclose(np.asarray(sum(routed) + shared),
+                               np.asarray(want), atol=4e-6)
+    # counted eight times it would be off by seven shared experts
+    assert float(jnp.max(jnp.abs(7 * shared))) > 1e-2
+
+
+def test_default_config_has_no_shared_expert_and_the_old_epsilon():
+    olmoe = tfm.TransformerConfig(n_experts=4, n_experts_per_tok=2,
+                                  n_layers=2, mlp="swiglu")
+    blocks = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), olmoe))["blocks"]
+    assert not {"ws1", "ws2", "ws3", "wq", "wkv_a"} & set(blocks)
+    assert tfm.Router().normalize_eps == 1e-6 and olmoe.mla is None
+    with pytest.raises(tfm.MoEConfigError, match="shared expert"):
+        tfm.TransformerConfig(d_ff_shared=64)
+    with pytest.raises(ValueError, match="mla layer"):
+        tfm.TransformerConfig(n_layers=1, layer_types=("mla",))
+
+
+# -- the kernels at two widths -----------------------------------------------------
+
+def _heads(x, h):
+    b, s, _ = x.shape
+    return x.reshape(b, s, h, -1).transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("blocks", [(None, None), (128, 128), (64, 128)],
+                         ids=["one-tile", "tiles-128", "uneven"])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_at_unequal_widths_matches_the_dot_path(blocks, causal):
+    """q . k at 48 columns a head, p . v at 32: forward through the public
+    entry, the three gradients through the Pallas backward kernels
+    (interpreted; the public entry takes the XLA fallback off the chip) and
+    through that fallback, against the unfused reference."""
+    b, h, s, d, dv = 2, 4, 256, 48, 32
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k = (jax.random.normal(ks[i], (b, s, h * d)) for i in (0, 1))
+    v, do = (jax.random.normal(ks[i], (b, s, h * dv)) for i in (2, 3))
+    bq, bk = blocks
+
+    def ref(q, k, v):
+        out = fa.mha_reference(_heads(q, h), _heads(k, h), _heads(v, h),
+                               causal)
+        return out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+
+    out = fa.flash_attention_btd((q, k, v), h, causal, block_q=bq,
+                                 block_k=bk)
+    assert out.shape == (b, s, h * dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               atol=2e-6)
+    want = jax.vjp(ref, q, k, v)[1](do)
+    scale = 1.0 / d ** 0.5
+    o, lse = fa._fwd_pallas((q, k, v), h, None, scale, causal, bq, bk,
+                            interpret=True)
+    got = fa._bwd_pallas(((q, k, v), o, lse, None), do, n_heads=h,
+                         scale=scale, causal=causal, block_q=bq, block_k=bk,
+                         interpret=True)
+    kernels = fa._choose_tiles(s, d, q.dtype, causal, h, bq, bk, dv)[2]
+    assert (fa.FLASH_BWD in kernels) == (blocks == (None, None))
+    fallback = jax.vjp(lambda q, k, v: fa.flash_attention_btd(
+        (q, k, v), h, causal, block_q=bq, block_k=bk), q, k, v)[1](do)
+    for name, a, c, w in zip("qkv", got, fallback, want):
+        assert a.shape == w.shape and c.shape == w.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(c), np.asarray(w), atol=1e-5)
+
+
+@pytest.mark.parametrize("s,d,heads", [(512, 64, 12), (128, 64, 12),
+                                       (4096, 128, 16), (8192, 64, 32)])
+def test_equal_widths_choose_what_they_chose(s, d, heads):
+    """A v as wide as q changes nothing: the same blocks, groups and VMEM
+    count, and no call asks Mosaic for more than it gave."""
+    for causal in (True, False):
+        base = fa._choose_tiles(s, d, jnp.bfloat16, causal, heads)
+        assert base == fa._choose_tiles(s, d, jnp.bfloat16, causal, heads,
+                                        dv=d)
+        bq, bk, groups = base
+        for kernel, g in groups.items():
+            assert fa._vmem_bytes(s, d, 2, bq, bk, g, kernel) == \
+                fa._vmem_bytes(s, d, 2, bq, bk, g, kernel, d)
+            assert fa._asking(kernel, s, d, d, jnp.bfloat16, bq, bk, g) == {}
+    assert fa._head_groups(heads, d) == fa._head_groups(heads, d, d)
+
+
+def test_latent_attention_tiles_at_192_and_128_lanes():
+    """The cell's call: 32 heads of 192 / 128 columns at 8,192 positions.
+    Heads go in twos (384 and 256 lanes, whole tiles on both arrays); k and
+    v whole in VMEM are over what Mosaic gives unasked, so the calls ask."""
+    assert fa._head_groups(32, 192, 128) == [2, 4, 8, 16]
+    assert fa._head_groups(32, 192) == [2, 4, 8, 16]
+    assert fa._head_groups(3, 192, 128) == [3]
+    bq, bk, groups = fa._choose_tiles(8192, 192, jnp.bfloat16, True, 32,
+                                      dv=128)
+    assert (bq, bk) == (512, 512) and set(groups.values()) == {2}
+    for kernel in groups:
+        count = fa._vmem_bytes(8192, 192, 2, bq, bk, 2, kernel, 128)
+        assert fa._VMEM_BUDGET < count <= fa._VMEM_BUDGET_ASKED
+        asked = fa._asking(kernel, 8192, 192, 128, jnp.bfloat16, bq, bk, 2)
+        assert asked["compiler_params"].vmem_limit_bytes == fa._VMEM_LIMIT
+    # k and v of both widths: 2 heads x (192 + 128) columns x 2 bytes,
+    # double-buffered, whole; a block of q and of o; lse; three tiles; acc
+    assert fa._vmem_bytes(8192, 192, 2, 512, 512, 2, fa.FLASH_FWD, 128) == (
+        2 * 2 * 320 * 2 * (8192 + 512) + 2 * 2 * 8 * 4 * 512
+        + 3 * 512 * 512 * 4 + 512 * 128 * 4)
+
+
+# -- what remat may keep, by kind ---------------------------------------------------
+
+def test_remat_names_size_latent_attention_by_its_own_widths():
+    cfg = dataclasses.replace(hd.config_from_hf(SHARE), dtype=jnp.bfloat16,
+                              attn_impl="flash")
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    B, T, D = 2, 128, 64
+    h = jax.ShapeDtypeStruct((B, T, D), jnp.bfloat16)
+    act, lse = B * T * D * 2, B * T * 4 * 4
+    every = tfm._remat_names(cfg, params, h, None, bytes_limit=1 << 40)
+    assert every[0] == (
+        tracing.REMAT_CANDIDATES[0] + tracing.REMAT_CANDIDATES[1]
+        + (tracing.REMAT_MLA_LATENT,) + tracing.REMAT_CANDIDATES[2])
+    o, latent = act * 4 * 24 // D, act * (32 + 16) // D
+    qkv = act * 4 * (48 + 48 + 24) // D
+    assert every[1] == 3 * act + 3 * (o + lse) + 3 * latent + 3 * qkv
+    # as the limit falls the latent goes before o and lse do, q, k, v first
+    state = tfm._state_bytes(cfg, params, None)
+    fixed = state + 3 * act + max(
+        tfm._block_residual_bytes(cfg, None, h, b, None, kind)
+        for (kind, _), b in zip(tfm.layer_runs(cfg), params["blocks"]))
+    for room, names in (
+            (3 * act + 3 * (o + lse) + 3 * latent + 8,
+             tracing.REMAT_CANDIDATES[0] + tracing.REMAT_CANDIDATES[1]
+             + (tracing.REMAT_MLA_LATENT,)),
+            (3 * act + 3 * (o + lse) + 8,
+             tracing.REMAT_CANDIDATES[0] + tracing.REMAT_CANDIDATES[1]),
+            (3 * act + 8, tracing.REMAT_CANDIDATES[0])):
+        limit = int((fixed + room) * 32 / 31) + 64
+        assert tfm._remat_names(cfg, params, h, None,
+                                bytes_limit=limit)[0] == names
+    # the latent's name is on both values the down projection makes
+    jaxpr = str(jax.make_jaxpr(lambda x, p: tfm._mla_qkv(x, p, cfg))(
+        jnp.zeros((B, T, D), jnp.bfloat16),
+        jax.tree.map(lambda x: jnp.zeros(x.shape[1:], x.dtype),
+                     params["blocks"][0])))
+    assert jaxpr.count(f"name={tracing.REMAT_MLA_LATENT}") == 2
+
+
+# -- the other flagship cells stay what they were ------------------------------
+
+# computed at the PARENT of ISSUE 39 (commit aed9040) by
+# test_lfm2_model._cell_digest's recipe; the other four cells' digests stand
+# in test_lfm2_model.py and still hold
+LFM2_PARENT = (("8c8e334e63485216", 7026), "df8cd1acd6687a54")
+
+
+def test_lfm2_cells_tree_and_lowered_program_are_the_parents():
+    """The fifth other flagship cell, the one that shares `_route`, the
+    share's row loops and `move_router_bias` with this one: its parameter
+    tree and whole lowered train step are, to the character, the parent's."""
+    with open(os.path.join(
+            ROOT, "benchmark/configs/lfm2-8b-a1b/config.json")) as f:
+        c = json.load(f)
+    with open(os.path.join(
+            ROOT, "benchmark/traffic/pretrain-seq8192-ep4load.json")) as f:
+        t = json.load(f)
+    B, T = t["sequences"], t["seq_len"]
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    cfg = hf_lfm2.config_from_hf(
+        c, dtype=jnp.bfloat16,
+        router_bias_rate=c["assumed"]["expert_bias_update_rate"])
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    opt = jax.eval_shape(tfm.init_opt_state, params)
+    text = tfm.make_train_step(cfg, lr=1e-4).lower(
+        params, opt, i32(B, T), i32(B, T)).as_text()
+    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+    tree = str(jax.tree_util.tree_map(lambda x: (x.shape, str(x.dtype)),
+                                      params))
+    assert ((hashlib.sha256(text.encode()).hexdigest()[:16],
+             text.count("\n")),
+            hashlib.sha256(tree.encode()).hexdigest()[:16]) == LFM2_PARENT
+
+
+# -- scopes ----------------------------------------------------------------------
+
+def test_scopes_of_latent_attention_and_the_shared_expert_in_the_step():
+    cfg = hd.config_from_hf(SHARE, router_bias_rate=1e-3)
+    params = _params(cfg)
+    tokens, targets = _data(SHARE, 8)
+    text = tfm.make_train_step(cfg).lower(
+        params, tfm.init_opt_state(params), tokens,
+        targets).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    for scope in tracing.MLA_SCOPES:
+        under = [n for n in names if f"/{scope}/" in n]
+        assert any(f"/jvp({tracing.SCOPE_FWD})/" in n for n in under), scope
+        assert any(f"/transpose(jvp({tracing.SCOPE_FWD}))/" in n
+                   for n in under), scope
+        # always INSIDE the block's projection scope
+        assert all(f"{tracing.SCOPE_BLK_QKV}/{scope}/" in n
+                   for n in under), scope
+    shared = [n for n in names if f"/{tracing.SCOPE_MOE_SHARED}/" in n]
+    assert any(f"/{tracing.SCOPE_MOE_SHARED}/{tracing.SCOPE_BLK_MLP_UP}/"
+               in n for n in shared)
+    assert any("transpose(" in n for n in shared)
+    # a fifth part: inside none of the four
+    assert not [n for n in shared
+                if any(f"/{s}/" in n for s in tracing.MOE_SCOPES)]
+    assert tracing.MLA_SCOPES == ("hetu_mla_q", "hetu_mla_kv_down",
+                                  "hetu_mla_kv_up")
+    doc = open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")).read()
+    old = ((tracing.STEP, tracing.SCOPE_FWD, tracing.SCOPE_OPT,
+            tracing.SCOPE_EXIT) + tracing.MOE_SCOPES + tracing.SSM_SCOPES
+           + tracing.SCONV_SCOPES + tracing.SSD_SCOPES + tracing.BLOCK_SCOPES
+           + (tracing.SCOPE_EMBED, tracing.SCOPE_HEAD)
+           + sum(tracing.REMAT_CANDIDATES, ()))
+    new = tracing.MLA_SCOPES + (tracing.SCOPE_MOE_SHARED,
+                                tracing.REMAT_MLA_LATENT)
+    for name in new:
+        assert f"`{name}`" in doc, name
+        # readers match by substring: no new name in an old one or the
+        # other way round, nor in another new one
+        for other in old + new:
+            assert other == name or (name not in other
+                                     and other not in name), (name, other)
+
+
+# -- refusals by name -------------------------------------------------------------
+
+def test_decode_and_pipeline_refuse_by_name():
+    cfg = hd.config_from_hf(HF)
+    with pytest.raises(AssertionError, match="latent cache"):
+        generate._check_decode_args(cfg, 16, 0)
+    no_mla = dataclasses.replace(cfg, layer_types=(), mla=None)
+    with pytest.raises(AssertionError, match="shared expert"):
+        generate._check_decode_args(no_mla, 16, 0)
+    share = dataclasses.replace(
+        hd.config_from_hf(SHARE), layer_types=(), mla=None, d_ff_shared=0,
+        n_experts=0, n_dense_layers=0,
+        router=tfm.Router(width=8, first_held=2))
+    with pytest.raises(AssertionError, match="share of an expert layer"):
+        generate._check_decode_args(share, 16, 0)
+    with pytest.raises(NotImplementedError, match="unequal kinds"):
+        pipeline._make_stage_fn(cfg, 1)
+    one_kind = dataclasses.replace(cfg, n_dense_layers=0)
+    assert tfm.layer_runs(one_kind) == (("mla", 3),)
+    with pytest.raises(NotImplementedError, match=r"latent attention \(mla\)"):
+        pipeline._make_stage_fn(one_kind, 1)
+
+
+def test_latent_attention_refuses_a_sequence_sharded_mesh():
+    cfg = dataclasses.replace(hd.config_from_hf(HF), attn_impl="ring")
+    p = jax.tree.map(lambda x: x[0], _params(cfg)["blocks"][0])
+    with pytest.raises(NotImplementedError, match="one head width"):
+        tfm._mla(jnp.zeros((1, 8, 64)), p, cfg, None)
