@@ -604,6 +604,7 @@ KERNEL_SHAPES = {
     "csr_spmm": (4096, 1024, 1024, 128),  # nnz, nrow, K, F
     "quant": (512 * 512, 256),           # the comm_quant_dp MLP grad, block
     "opt": (512, 512, 3, 3),             # ResNet-18's largest conv weight
+    "rope": (2, 1024, 4, 128, 64),       # kanana's q: heads of 128 + 64, bf16
 }
 
 
@@ -617,6 +618,8 @@ def phase_kernels(*, shapes=None, chip=True):
                                                   mha_reference)
     from hetu_tpu.kernels.fused_ce import (fused_linear_nll,
                                            linear_nll_reference)
+    from hetu_tpu.kernels.rope import rope_interleaved
+    from hetu_tpu.models.transformer import _rope_interleaved
 
     shapes = {**KERNEL_SHAPES, **(shapes or {})}
     rng = np.random.RandomState(0)
@@ -709,6 +712,25 @@ def phase_kernels(*, shapes=None, chip=True):
         compare("fused_ce", ce_and_grads(fused_linear_nll),
                 ce_and_grads(linear_nll_reference), (hh, ww, bb),
                 atol=2e-2, rtol=2e-2)
+
+        # -- q's rotary columns in one pass, forward and transposed, bf16:
+        # the float32 products and sum of `_rope_interleaved` in its order,
+        # so on the chip to the bit (this host's compiler contracts the sum
+        # differently in a few entries of 100,000: one bf16 unit there)
+        b, s, h, nope, rot = shapes["rope"]
+        rx, rg = (jnp.asarray(rng.randn(b, s, h * (nope + rot)),
+                              jnp.bfloat16) for _ in range(2))
+
+        def rotated_and_cotangent(fn):
+            def run(x, g):
+                out, vjp = jax.vjp(
+                    lambda x: fn(x, 0, 1e6, nope + rot, nope), x)
+                return out, vjp(g)[0]
+            return run
+
+        compare("rope_pairs", rotated_and_cotangent(rope_interleaved),
+                rotated_and_cotangent(_rope_interleaved), (rx, rg),
+                atol=2 ** -5, exact=chip)
 
         # -- the four registry kernels
         n, d, vocab = shapes["embed_grad"]

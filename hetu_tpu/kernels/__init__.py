@@ -15,6 +15,8 @@ Layout:
 - :mod:`flash_attention` / :mod:`fused_ce` — the two pre-tier kernels
   (their ``should_fuse``-style gating predates the registry and is
   documented in docs/KERNELS.md).
+- :mod:`rope` — latent attention's partial interleaved rotation of q in
+  one pass through VMEM, gated the pre-tier way (``rope.takes``).
 
 Importing this package registers the four tier kernels; the graph ops
 import it lazily inside their compute fns so jax-free tools never pay

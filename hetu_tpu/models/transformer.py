@@ -742,18 +742,9 @@ def _rope_interleaved(x, pos0, theta, hd, first):
     pair stays where it is. The two results are one permutation of a head's
     rotary columns apart, the same for q and k, so every q . k is the same
     sum in another order."""
+    from ..kernels.rope import tables
     B, T, W = x.shape
-    rot = hd - first
-    inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
-    t = pos0 + jnp.arange(T, dtype=jnp.float32)
-    freqs = jnp.repeat(jnp.outer(t, inv), 2, axis=-1)         # (T, rot)
-    # out[2i] = x[2i] cos - x[2i+1] sin, out[2i+1] = x[2i+1] cos + x[2i] sin
-    sign = jnp.tile(jnp.array([-1.0, 1.0], jnp.float32), rot // 2)
-    cos = jnp.concatenate([jnp.ones((T, first), jnp.float32),
-                           jnp.cos(freqs)], -1)
-    sin = jnp.concatenate([jnp.zeros((T, first), jnp.float32),
-                           jnp.sin(freqs) * sign], -1)
-    cos, sin = jnp.tile(cos, W // hd), jnp.tile(sin, W // hd)
+    cos, sin = tables(T, pos0, theta, hd, first, W // hd)
     x32 = x.astype(jnp.float32)
     even = (jnp.arange(W) % hd - first) % 2 == 0
     partner = jnp.where(even, jnp.roll(x32, -1, -1), jnp.roll(x32, 1, -1))
@@ -980,22 +971,36 @@ def _mla_keys(k_nope, k_rope, nh):
          for part in (k_nope[..., i * nope:(i + 1) * nope], k_rope)], -1)
 
 
-def _mla_qkv(h, p, cfg: TransformerConfig):
+def _rope_q(q, cfg: TransformerConfig, mesh):
+    """The rotary columns of latent attention's q (B, T, nh * qk_dim), each
+    head's from ``nope_dim`` on: in one pass through VMEM where the kernel
+    serves the call (``kernels/rope.py``: a TPU, one program, a shape its
+    blocks divide), else ``_rope_interleaved``, the expression the kernel is
+    held to."""
+    from ..kernels import rope
+    m = cfg.mla
+    rotate = (rope.rope_interleaved
+              if rope.takes(q, m.qk_dim, m.nope_dim, mesh)
+              else _rope_interleaved)
+    return rotate(q, 0, cfg.rope_theta, m.qk_dim, m.nope_dim)
+
+
+def _mla_qkv(h, p, cfg: TransformerConfig, mesh=None):
     """Latent attention's projections -> (q (B, T, nh * qk_dim), k the same,
     v (B, T, nh * v_dim), (the latent (B, T, kv_rank) as ``wkv_a`` writes
     it, its RMSNorm in float32 before ``wkv_b``'s cast)) as
     HF ``DeepseekV3Attention`` computes them (``q_lora_rank`` None):
     q = h Wq, a head [q_nope | q_rope]; [c | k_rope] = h Wkv_a; [k_nope | v]
-    = RMSNorm(c) Wkv_b; q_rope and the one k_rope a token rotated
-    (``_rope_interleaved``); k = [k_nope | k_rope], the rotary key every
-    head's. The matmuls read bf16 operands; the latent's norm is float32."""
+    = RMSNorm(c) Wkv_b; q_rope (``_rope_q``) and the one k_rope a token
+    (``_rope_interleaved``: 64 columns, narrower than a lane tile) rotated;
+    k = [k_nope | k_rope], the rotary key every head's. The matmuls read
+    bf16 operands; the latent's norm is float32."""
     m, nh = cfg.mla, cfg.n_heads
     proj = lambda x, w: jnp.einsum(
         "btd,de->bte", x, w.astype(h.dtype),
         preferred_element_type=jnp.float32).astype(h.dtype)
     with jax.named_scope(SCOPE_MLA_Q):
-        q = _rope_interleaved(proj(h, p["wq"]), 0, cfg.rope_theta, m.qk_dim,
-                              m.nope_dim)
+        q = _rope_q(proj(h, p["wq"]), cfg, mesh)
     with jax.named_scope(SCOPE_MLA_KV_DOWN):
         raw, k_rope = jnp.split(proj(h, p["wkv_a"]), [m.kv_rank], axis=-1)
         latent = _rms_norm32(raw, p["kv_norm"], cfg.ln_eps)
@@ -1021,7 +1026,7 @@ def _mla(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
             "latent attention on a mesh that shards the sequence (sp > 1): "
             "the ring takes one head width for q, k and v")
     with jax.named_scope(SCOPE_BLK_QKV):
-        q, k, v, _ = _mla_qkv(h, p, cfg)
+        q, k, v, _ = _mla_qkv(h, p, cfg, mesh)
         # the flash `custom_vjp`'s inputs, as on `_split_heads`' path
         q, k, v = (checkpoint_name(x, name) for x, name in (
             (q, REMAT_ATTN_Q), (k, REMAT_ATTN_K), (v, REMAT_ATTN_V)))

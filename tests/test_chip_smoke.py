@@ -87,9 +87,10 @@ def test_kernels_phase_tiny(capsys):
     rec = chip_smoke.phase_kernels(chip=False, shapes={
         "flash": (1, 2, 128, 32), "fused_ce": (32, 64, 300),
         "embed_grad": (128, 128, 1000), "csr_spmm": (300, 16, 16, 128),
-        "quant": (4096, 256), "opt": (300, 700)})
+        "quant": (4096, 256), "opt": (300, 700),
+        "rope": (1, 32, 2, 128, 64)})
     assert _last_json(capsys)["phase"] == "kernels"
     assert set(rec["kernels"]) == {
         "flash_causal", "flash_key_padding", "fused_ce", "fused_embed_grad",
         "csr_spmm", "quant_blocks", "dequant_blocks", "fused_adam",
-        "fused_sgd"}
+        "fused_sgd", "rope_pairs"}

@@ -1,6 +1,6 @@
-"""The four flash kernels and the three of the fused CE compiled for a v5e
-that is described, not attached (the TPU's compiler is installed here): what Mosaic refuses at the
-real shapes — a misaligned slice, too much VMEM, a transpose it does not
+"""The four flash kernels, the three of the fused CE and the rotary kernel
+compiled for a v5e that is described, not attached (the TPU's compiler is
+installed here): what Mosaic refuses at the real shapes — a misaligned slice, too much VMEM, a transpose it does not
 take — fails here and costs no chip time. Nothing runs, so nothing here is a
 result or a time.
 
@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels import fused_ce as fc
+from hetu_tpu.kernels import rope
 
 
 @pytest.fixture(scope="module")
@@ -253,6 +254,33 @@ def test_fused_ce_compiles_for_v5e(one_chip, no_compile_cache, monkeypatch,
     names = (fc.FUSED_CE_FWD, fc.FUSED_CE_BWD_DH, fc.FUSED_CE_BWD_DW)
     # "fused_ce_bwd_dw" and "_dh" do not contain "fused_ce_fwd" or each other
     assert set(_count_by_name(calls, names).values()) == {1}, calls
+
+
+def test_rope_pairs_compiles_for_v5e_at_kananas_q(one_chip, no_compile_cache,
+                                                  monkeypatch):
+    """The rotation of latent attention's q at the cell's shape, (4, 8192,
+    6144) bfloat16 in heads of 128 + 64, forward and transposed: two Mosaic
+    calls under the kernel's name inside the VMEM Mosaic gives unasked, and
+    around them no float32 array of q's size and no table wider than one
+    period of 384 columns."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
+    q = jax.ShapeDtypeStruct((4, 8192, 6144), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def forward_and_transposed(x, g):
+        out, vjp = jax.vjp(
+            lambda x: rope.rope_interleaved(x, 0, 1e6, 192, 128), x)
+        return out, vjp(g)[0]
+
+    assert rope._blocks(q.shape, 192, 128, 2) == (512, 768)
+    text = jax.jit(forward_and_transposed).lower(q, q).compile().as_text()
+    assert _count_by_name(_kernel_calls(text), (rope.ROPE_PAIRS,)) == {
+        rope.ROPE_PAIRS: 2}, text
+    assert "vmem_limit_bytes" not in text
+    shapes = set(re.findall(r"(f32|bf16)\[([\d,]+)\]", text))
+    assert ("bf16", "4,8192,6144") in shapes
+    assert max(math.prod(map(int, dims.split(",")))
+               for kind, dims in shapes if kind == "f32") == 8192 * 384
 
 
 # ---------------------------------------------------------------------------

@@ -5,9 +5,10 @@ expert; 8 experts, top 2): the system against the float32 reference
 (benchmark/configs/kanana-2-30b-a3b/reference.py), the reference against
 `transformers`' `DeepseekV3ForCausalLM` on copied weights, the eight shares
 of an expert layer adding up to the whole with the shared expert counted
-once, the flash kernels at unequal head widths, the rotary columns, what
-`remat` may keep by kind, the fifth other flagship cell's tree and lowered
-program, the scopes, and the refusals by name."""
+once, the flash kernels at unequal head widths, the rotary columns and the
+kernel that turns q's in one pass (ISSUE 40), what `remat` may keep by kind,
+the fifth other flagship cell's tree and lowered program, the scopes, and
+the refusals by name."""
 import dataclasses
 import hashlib
 import importlib.util
@@ -21,6 +22,7 @@ import numpy as np
 import pytest
 
 from hetu_tpu.kernels import flash_attention as fa
+from hetu_tpu.kernels import rope as rope_kernel
 from hetu_tpu.models import (generate, hf_deepseek_v3 as hd, hf_lfm2,
                              transformer as tfm)
 from hetu_tpu.parallel import pipeline
@@ -57,7 +59,29 @@ HF = dict(
 # one chip's share: experts 2 and 3 of the 8
 SHARE = {**HF, "n_routed_experts": 2, "num_routed_experts": 8,
          "first_expert_held": 2}
-CONFIGS = {"whole": HF, "share": SHARE}
+# the share at the published head widths, two heads of 128 + 64: a width the
+# one-pass rotation of q serves (one period of 384 columns); run with it
+# taken (interpreted here), where a TPU would take it
+ROTATED = {**SHARE, "num_attention_heads": 2, "num_key_value_heads": 2,
+           "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "head_dim": 64,
+           "qk_head_dim": 192}
+CONFIGS = {"whole": HF, "share": SHARE, "rope-kernel": ROTATED}
+
+
+@pytest.fixture()
+def rope_kernel_taken(monkeypatch):
+    """What `transformer._rope_q` does on a TPU: the kernel wherever its
+    blocks divide the shape. -> the list of the shapes it was called at."""
+    seen = []
+    rotate = rope_kernel._rotate
+
+    def noting(x, *rest):
+        seen.append(x.shape)
+        return rotate(x, *rest)
+
+    monkeypatch.setattr(rope_kernel, "_on_tpu", lambda: True)
+    monkeypatch.setattr(rope_kernel, "_rotate", noting)
+    return seen
 
 
 def _data(hf, seed, B=2, T=32):
@@ -171,8 +195,11 @@ def test_state_dict_round_trip_and_names():
 # -- the system against the reference ---------------------------------------------
 
 @pytest.mark.parametrize("which", sorted(CONFIGS))
-def test_system_matches_reference_loss_hidden_picks_and_gradients(which):
+def test_system_matches_reference_loss_hidden_picks_and_gradients(
+        which, request):
     hf = CONFIGS[which]
+    taken = (request.getfixturevalue("rope_kernel_taken")
+             if which == "rope-kernel" else None)
     cfg = hd.config_from_hf(hf, router_bias_rate=1e-3)
     params = _params(cfg)
     tokens, targets = _data(hf, 1)
@@ -203,17 +230,27 @@ def test_system_matches_reference_loss_hidden_picks_and_gradients(which):
         {**sd, **part}, tokens, targets, hf)[0])({n: sd[n] for n in few})
     for n in few:
         assert _rel(want_grads[n], plain[n]) < 1e-5, n
+    if taken is not None:
+        # forward, `jax.grad`'s forward and its transpose, a layer each
+        assert taken and set(taken) == {(2, 32, 2 * 192)}
 
 
-def test_flash_path_is_the_dot_path():
+@pytest.mark.parametrize("which", ["share", "rope-kernel"])
+def test_flash_path_is_the_dot_path(which, request):
     """The trunk with the kernels forced on (interpreted here): the same
-    loss and gradients, at 48 / 24 columns a head."""
-    cfg = hd.config_from_hf(SHARE)
+    loss and gradients, at 48 / 24 columns a head; and at 192 / 24 with q's
+    rotary columns turned by the kernel too, against `_rope_interleaved` on
+    the dot path."""
+    cfg = hd.config_from_hf(CONFIGS[which])
     params = _params(cfg)
     tokens, targets = _data(SHARE, 2)
     flash = dataclasses.replace(cfg, attn_impl="flash")
     a, ga = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, cfg)
+    taken = (request.getfixturevalue("rope_kernel_taken")
+             if which == "rope-kernel" else None)
     b, gb = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, flash)
+    if taken is not None:
+        assert len(taken) == 2 * cfg.n_layers   # forward and transposed
     assert abs(float(a) - float(b)) < 1e-6
     for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-6)
@@ -292,6 +329,96 @@ def test_rope_interleaved_is_hfs_up_to_one_permutation_of_the_pairs():
     want_k = reference._rope_interleave(k[:, None], 1e4)[:, 0]
     np.testing.assert_allclose(np.asarray(got_k[..., 0::2]),
                                np.asarray(want_k[..., :rope // 2]), atol=1e-6)
+
+
+def _forward_and_cotangent(rotate, x, g, pos0, hdim, nope):
+    out, vjp = jax.vjp(lambda x: rotate(x, pos0, 1e6, hdim, nope), x)
+    return out, vjp(g)[0]
+
+
+@pytest.mark.parametrize("pos0", [0, 7])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads,nope,rot", [
+    pytest.param(2, 128, 64, id="kanana-widths"),
+    pytest.param(4, 128, 64, id="two-column-blocks"),
+    pytest.param(2, 0, 128, id="all-rotary")])
+def test_rope_kernel_is_rope_interleaved_forward_and_transposed(
+        monkeypatch, heads, nope, rot, dtype, pos0):
+    """The one-pass kernel (interpreted) against the expression it replaces,
+    both compiled: the forward result (the same float32 products and sum in
+    the same order) and the cotangent of the same g (`jax.vjp` of the
+    reference scatters two rolls, the kernel turns by the opposite angle)
+    within one unit of the output dtype, the columns that pass EXACTLY the
+    input; three row blocks, and one period of columns a block so that four
+    heads are two column blocks. On the chip both are equal to the bit at
+    (4, 8192, 6144) (PERF.md, PR 40); this host's compiler contracts a
+    product and the sum into one rounding in one program and not the other
+    on a few entries in 100,000."""
+    hdim, B, T = nope + rot, 2, 96
+    monkeypatch.setattr(rope_kernel, "_BLOCK_BYTES", 1)
+    x = jax.random.normal(jax.random.PRNGKey(0), (B, T, heads * hdim),
+                          jnp.float32).astype(dtype)
+    g = jax.random.normal(jax.random.PRNGKey(1), x.shape,
+                          jnp.float32).astype(dtype)
+    period = rope_kernel._period(hdim)
+    assert rope_kernel._blocks(x.shape, hdim, nope, x.dtype.itemsize) == (
+        32, period)
+    assert heads * hdim // period == (2 if heads == 4 or nope == 0 else 1)
+    want, dwant = jax.jit(lambda x, g: _forward_and_cotangent(
+        tfm._rope_interleaved, x, g, pos0, hdim, nope))(x, g)
+    got, dgot = jax.jit(lambda x, g: _forward_and_cotangent(
+        rope_kernel.rope_interleaved, x, g, pos0, hdim, nope))(x, g)
+    assert got.dtype == dgot.dtype == dtype
+    passes = np.arange(heads * hdim) % hdim < nope
+    for a, b, of in ((got, want, x), (dgot, dwant, g)):
+        a, b, of = (np.asarray(v, np.float64) for v in (a, b, of))
+        np.testing.assert_array_equal(a[..., passes], of[..., passes])
+        # one unit of the output dtype at the size of the two terms summed
+        # (a sum that cancels keeps its terms' rounding)
+        terms = np.abs(of) + np.abs(of).reshape(B, T, -1, 2)[
+            ..., ::-1].reshape(of.shape)
+        assert np.all(np.abs(a - b) <= float(jnp.finfo(dtype).eps) * terms)
+    # forward the order of operations is the reference's
+    assert np.mean(np.asarray(got != want)) < 1e-3
+    assert rope_kernel.ROPE_PAIRS in str(jax.make_jaxpr(
+        lambda x: rope_kernel.rope_interleaved(x, pos0, 1e6, hdim, nope))(x))
+
+
+@pytest.mark.parametrize("heads,nope,rot,T,mesh_size,kernel", [
+    pytest.param(2, 128, 64, 32, 1, True, id="served"),
+    pytest.param(3, 128, 64, 32, 1, False, id="width-no-period-divides"),
+    pytest.param(4, 32, 16, 32, 1, False, id="toy-widths"),
+    pytest.param(2, 128, 64, 24, 1, False, id="rows-no-block-divides"),
+    pytest.param(2, 128, 64, 32, 4, False, id="under-a-mesh")])
+def test_rope_q_takes_the_kernel_by_backend_and_shape(
+        rope_kernel_taken, heads, nope, rot, T, mesh_size, kernel):
+    """`_rope_q` on a TPU (the fixture's patch): the kernel where its blocks
+    divide the call, `_rope_interleaved` anywhere else, and the same array
+    either way; off a TPU always the reference."""
+    import types
+    cfg = dataclasses.replace(
+        hd.config_from_hf(SHARE),
+        mla=tfm.MLAConfig(kv_rank=32, nope_dim=nope, rope_dim=rot, v_dim=24))
+    mesh = None if mesh_size == 1 else types.SimpleNamespace(size=mesh_size)
+    q = jax.random.normal(jax.random.PRNGKey(2),
+                          (2, T, heads * (nope + rot))).astype(jnp.bfloat16)
+    want = tfm._rope_interleaved(q, 0, cfg.rope_theta, nope + rot, nope)
+    got = tfm._rope_q(q, cfg, mesh)
+    assert rope_kernel_taken == [q.shape] * kernel
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_rope_q_off_a_tpu_is_rope_interleaved(monkeypatch):
+    monkeypatch.setattr(rope_kernel, "_rotate", None)   # never reached
+    cfg = hd.config_from_hf(ROTATED)
+    q = jnp.ones((2, 32, 2 * 192), jnp.bfloat16)
+    assert not rope_kernel.takes(q, 192, 128)
+    np.testing.assert_array_equal(
+        np.asarray(tfm._rope_q(q, cfg, None), np.float32),
+        np.asarray(tfm._rope_interleaved(q, 0, cfg.rope_theta, 192, 128),
+                   np.float32))
 
 
 # -- the shares add up -------------------------------------------------------------
@@ -520,14 +647,29 @@ def test_lfm2_cells_tree_and_lowered_program_are_the_parents():
 
 # -- scopes ----------------------------------------------------------------------
 
-def test_scopes_of_latent_attention_and_the_shared_expert_in_the_step():
-    cfg = hd.config_from_hf(SHARE, router_bias_rate=1e-3)
+@pytest.mark.parametrize("which", ["share", "rope-kernel"])
+def test_scopes_of_latent_attention_and_the_shared_expert_in_the_step(
+        which, request):
+    cfg = hd.config_from_hf(CONFIGS[which], router_bias_rate=1e-3)
     params = _params(cfg)
     tokens, targets = _data(SHARE, 8)
+    if which == "rope-kernel":
+        request.getfixturevalue("rope_kernel_taken")
     text = tfm.make_train_step(cfg).lower(
         params, tfm.init_opt_state(params), tokens,
         targets).compile().as_text()
     names = set(re.findall(r'op_name="([^"]+)"', text))
+    # the rotation's kernel where it is taken: forward, run again under
+    # `remat` and transposed, each call under q's scope, by its name
+    # (`reduce/mla.py` and `reduce/block.py` find it there, by phase)
+    kernel = [n for n in names if f"/{rope_kernel.ROPE_PAIRS}/" in n]
+    assert all(f"/{tracing.SCOPE_MLA_Q}/{rope_kernel.ROPE_PAIRS}/" in n
+               for n in kernel)
+    phases = {("recompute" if "/rematted_computation/" in n else "bwd")
+              if "transpose(" in n else "fwd" for n in kernel}
+    assert phases == ({"fwd", "recompute", "bwd"} if which == "rope-kernel"
+                      else set())
+    assert "flash_" not in rope_kernel.ROPE_PAIRS
     for scope in tracing.MLA_SCOPES:
         under = [n for n in names if f"/{scope}/" in n]
         assert any(f"/jvp({tracing.SCOPE_FWD})/" in n for n in under), scope

@@ -13,7 +13,7 @@ import pytest
 
 import hetu_tpu as ht
 from hetu_tpu.kernels import (csr_spmm, embed_grad, flash_attention,
-                              fused_ce, fused_opt, quant_comm, registry)
+                              fused_ce, fused_opt, quant_comm, registry, rope)
 from hetu_tpu.telemetry import tracing as tr
 
 
@@ -72,6 +72,9 @@ KERNEL_PROGRAMS = {
         lambda vals, rows, cols, b: csr_spmm.coo_matmat(vals, rows, cols,
                                                         8, b),
         (_f32(1024), _i32(1024), _i32(1024), _f32(8, 128))),
+    rope.ROPE_PAIRS: (
+        lambda x: rope.rope_interleaved(x, 0, 1e6, 192, 128),
+        (_f32(1, 32, 384),)),
     quant_comm.QUANT_BLOCKS: (
         lambda x: quant_comm.quantize_blocks(x, 128, "int8"),
         (_f32(1024),)),
