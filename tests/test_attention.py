@@ -6,91 +6,41 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
+from flash_harness import (padding_bias, pallas_calls, rand_qkv, to_heads,
+                           to_rows)
 from hetu_tpu.kernels.flash_attention import flash_attention, mha_reference
 from hetu_tpu.parallel.ring_attention import ring_attention
 
 
-def _rand_qkv(rng, b=2, h=2, s=256, d=64):
-    q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32) * 0.3
-    k = jnp.asarray(rng.randn(b, h, s, d), jnp.float32) * 0.3
-    v = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
-    return q, k, v
-
-
-def _rows(x):
-    """(b, h, s, d) -> (b, s, h*d): the layout the kernels index."""
-    b, h, s, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-
-
-def _heads(x, h):
-    """(b, s, h*d) -> (b, h, s, d): the layout `mha_reference` takes."""
-    b, s, w = x.shape
-    return x.reshape(b, s, h, w // h).transpose(0, 2, 1, 3)
-
-
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_forward_matches_reference(causal):
-    q, k, v = _rand_qkv(np.random.RandomState(0))
+    q, k, v = rand_qkv(np.random.RandomState(0))
     out = flash_attention(q, k, v, causal=causal)
     ref = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
+def _assert_same_grads(attend, qkv, causal, k_bias=None):
+    """d sum(out ** 2) / d(q, k, v) of `attend(q, k, v)` against the unfused
+    reference's. Jitted: an eager `shard_map` runs a ring op by op on every
+    device."""
+    def grads(fn):
+        return jax.jit(jax.grad(lambda *qkv: jnp.sum(fn(*qkv) ** 2),
+                                argnums=(0, 1, 2)))(*qkv)
+
+    want = grads(lambda q, k, v: mha_reference(q, k, v, causal,
+                                               k_bias=k_bias))
+    for a, b in zip(grads(attend), want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
 def test_flash_backward_matches_reference():
-    q, k, v = _rand_qkv(np.random.RandomState(1), s=128)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, True) ** 2)
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("block_q,block_k", [(64, 64), (32, 64), (64, 32)])
-def test_pallas_backward_kernels_match_blockwise(causal, block_q, block_k):
-    """The TPU backward path (dq + fused dk/dv Pallas kernels, run here in
-    interpret mode) must match the XLA blockwise backward (the oracle) and
-    the autodiff of the unfused reference."""
-    from hetu_tpu.kernels import flash_attention as fa
-
-    q, k, v = _rand_qkv(np.random.RandomState(2), s=128)
-    h = q.shape[1]
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    qkv = tuple(_rows(x) for x in (q, k, v))
-    out, lse = fa._fwd_pallas(qkv, h, None, scale, causal, block_q, block_k,
-                              interpret=True)
-    rng = np.random.RandomState(3)
-    do = jnp.asarray(rng.randn(*q.shape), jnp.float32)
-    res = (qkv, out, lse, None)
-
-    dq_p, dk_p, dv_p = fa._bwd_pallas(res, _rows(do), n_heads=h, scale=scale,
-                                      causal=causal, block_q=block_q,
-                                      block_k=block_k, interpret=True)
-    dq_b, dk_b, dv_b = fa._bwd_blockwise(res, _rows(do), n_heads=h,
-                                         scale=scale, causal=causal,
-                                         block_k=block_k)
-    for a, b in zip((dq_p, dk_p, dv_p), (dq_b, dk_b, dv_b)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-    def loss_ref(q, k, v):
-        return jnp.vdot(mha_reference(q, k, v, causal), do)
-
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip((dq_p, dk_p, dv_p), gr):
-        np.testing.assert_allclose(np.asarray(_heads(a, h)), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
+    _assert_same_grads(lambda q, k, v: flash_attention(q, k, v, True),
+                       rand_qkv(np.random.RandomState(1), s=128), True)
 
 
 @pytest.mark.parametrize("block_q,block_k", [(64, 128), (32, 256), (128, 64)])
@@ -98,7 +48,7 @@ def test_flash_causal_uneven_blocks(block_q, block_k):
     """block_q != block_k regression: the causal key-block bound must use
     ceil division — flooring drops the diagonal block when block_q < block_k
     and the first query rows silently output zeros."""
-    q, k, v = _rand_qkv(np.random.RandomState(3))
+    q, k, v = rand_qkv(np.random.RandomState(3))
     out = flash_attention(q, k, v, causal=True,
                           block_q=block_q, block_k=block_k)
     ref = mha_reference(q, k, v, causal=True)
@@ -106,20 +56,13 @@ def test_flash_causal_uneven_blocks(block_q, block_k):
                                rtol=2e-5, atol=2e-5)
 
 
-def _padding_bias(rng, b, s, min_valid=8):
-    """(b, s) key-padding bias: 0 for valid keys, -1e9 for a padded tail."""
-    lens = rng.randint(min_valid, s + 1, b)
-    pos = np.arange(s)[None, :]
-    return jnp.asarray(np.where(pos < lens[:, None], 0.0, -1e9), jnp.float32)
-
-
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_key_bias_matches_reference(causal):
     """The fused kernel must fold a key-padding bias exactly like the
     unfused form — masked BERT batches no longer leave the flash path."""
     rng = np.random.RandomState(5)
-    q, k, v = _rand_qkv(rng, s=256)
-    k_bias = _padding_bias(rng, q.shape[0], q.shape[2])
+    q, k, v = rand_qkv(rng, s=256)
+    k_bias = padding_bias(rng, q.shape[0], q.shape[2])
     out = flash_attention(q, k, v, causal=causal, k_bias=k_bias)
     ref = mha_reference(q, k, v, causal=causal, k_bias=k_bias)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -128,49 +71,10 @@ def test_flash_key_bias_matches_reference(causal):
 
 def test_flash_key_bias_gradients():
     rng = np.random.RandomState(6)
-    q, k, v = _rand_qkv(rng, s=128)
-    k_bias = _padding_bias(rng, q.shape[0], q.shape[2])
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, False, k_bias=k_bias) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, False, k_bias=k_bias) ** 2)
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-def test_pallas_backward_kernels_with_bias(causal):
-    """The TPU backward kernels (interpret mode) must handle the key bias
-    identically to the blockwise oracle and the reference autodiff."""
-    from hetu_tpu.kernels import flash_attention as fa
-
-    rng = np.random.RandomState(7)
-    q, k, v = _rand_qkv(rng, s=128)
-    h = q.shape[1]
-    k_bias = _padding_bias(rng, q.shape[0], q.shape[2])
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    qkv = tuple(_rows(x) for x in (q, k, v))
-    out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, 64, 64,
-                              interpret=True)
-    do = jnp.asarray(rng.randn(*q.shape), jnp.float32)
-    res = (qkv, out, lse, k_bias)
-    grads = fa._bwd_pallas(res, _rows(do), n_heads=h, scale=scale,
-                           causal=causal, block_q=64, block_k=64,
-                           interpret=True)
-
-    def loss_ref(q, k, v):
-        return jnp.vdot(mha_reference(q, k, v, causal, k_bias=k_bias), do)
-
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(grads, gr):
-        np.testing.assert_allclose(np.asarray(_heads(a, h)), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
+    qkv = rand_qkv(rng, s=128)
+    k_bias = padding_bias(rng, 2, 128)
+    _assert_same_grads(lambda q, k, v: flash_attention(
+        q, k, v, False, k_bias=k_bias), qkv, False, k_bias)
 
 
 def test_masked_bert_encoder_flash_matches_dot():
@@ -216,27 +120,26 @@ def test_nonpadding_bias_still_falls_back_to_dot():
 
 
 def test_flash_nondivisible_raises():
-    q, k, v = _rand_qkv(np.random.RandomState(2), s=96)
+    q, k, v = rand_qkv(np.random.RandomState(2), s=96)
     with pytest.raises(ValueError):
         flash_attention(q, k, v, True, None, 128, 64)
 
 
-def _sp_mesh(n=4):
-    devs = jax.devices()[:n]
-    return Mesh(np.array(devs), ("sp",))
+def _ring(causal, bias=False):
+    """Ring attention over four devices along the sequence, jitted (an eager
+    `shard_map` runs the ring op by op on every device); with `bias`, a
+    fourth argument: the (batch, seq) key-padding bias."""
+    return jax.jit(jax.shard_map(
+        functools.partial(ring_attention, axis_name="sp", causal=causal),
+        mesh=Mesh(np.array(jax.devices()[:4]), ("sp",)),
+        in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),) * bias,
+        out_specs=P(None, None, "sp", None)))
 
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_attention_matches_full(causal):
-    mesh = _sp_mesh(4)
-    q, k, v = _rand_qkv(np.random.RandomState(3), b=1, h=2, s=128, d=32)
-
-    ring = jax.shard_map(
-        functools.partial(ring_attention, axis_name="sp", causal=causal),
-        mesh=mesh,
-        in_specs=(P(None, None, "sp", None),) * 3,
-        out_specs=P(None, None, "sp", None))
-    out = ring(q, k, v)
+    q, k, v = rand_qkv(np.random.RandomState(3), b=1, h=2, s=128, d=32)
+    out = _ring(causal)(q, k, v)
     ref = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -246,182 +149,37 @@ def test_ring_attention_matches_full(causal):
 def test_ring_attention_key_bias_matches_full(causal):
     """The key-padding bias rotates with its k/v chunk around the ring and
     must reproduce the full-attention oracle, padded tails included."""
-    mesh = _sp_mesh(4)
     rng = np.random.RandomState(9)
-    q, k, v = _rand_qkv(rng, b=2, h=2, s=128, d=32)
-    k_bias = _padding_bias(rng, 2, 128)
-
-    ring = jax.shard_map(
-        functools.partial(ring_attention, axis_name="sp", causal=causal),
-        mesh=mesh,
-        in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),),
-        out_specs=P(None, None, "sp", None))
-    out = ring(q, k, v, k_bias)
+    q, k, v = rand_qkv(rng, b=2, h=2, s=128, d=32)
+    k_bias = padding_bias(rng, 2, 128)
+    out = _ring(causal, bias=True)(q, k, v, k_bias)
     ref = mha_reference(q, k, v, causal=causal, k_bias=k_bias)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
 def test_ring_attention_key_bias_gradients():
-    mesh = _sp_mesh(4)
     rng = np.random.RandomState(10)
-    q, k, v = _rand_qkv(rng, b=1, h=2, s=64, d=16)
-    k_bias = _padding_bias(rng, 1, 64)
-
-    ring = jax.shard_map(
-        functools.partial(ring_attention, axis_name="sp", causal=False),
-        mesh=mesh,
-        in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),),
-        out_specs=P(None, None, "sp", None))
-
-    def loss_ring(q, k, v):
-        return jnp.sum(ring(q, k, v, k_bias) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, False, k_bias=k_bias) ** 2)
-
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gr, gf):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
+    qkv = rand_qkv(rng, b=1, h=2, s=64, d=16)
+    k_bias = padding_bias(rng, 1, 64)
+    ring = _ring(False, bias=True)
+    _assert_same_grads(lambda q, k, v: ring(q, k, v, k_bias), qkv, False,
+                       k_bias)
 
 
 def test_ring_attention_gradients():
-    mesh = _sp_mesh(4)
-    q, k, v = _rand_qkv(np.random.RandomState(4), b=1, h=1, s=64, d=16)
-
-    ring = jax.shard_map(
-        functools.partial(ring_attention, axis_name="sp", causal=True),
-        mesh=mesh,
-        in_specs=(P(None, None, "sp", None),) * 3,
-        out_specs=P(None, None, "sp", None))
-
-    def loss_ring(q, k, v):
-        return jnp.sum(ring(q, k, v) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, True) ** 2)
-
-    gf = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
+    _assert_same_grads(_ring(True), rand_qkv(np.random.RandomState(4), b=1,
+                                             h=1, s=64, d=16), True)
 
 
 def test_flash_bf16():
-    q, k, v = _rand_qkv(np.random.RandomState(5), s=128)
+    q, k, v = rand_qkv(np.random.RandomState(5), s=128)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
     out = flash_attention(qb, kb, vb, causal=True)
     ref = mha_reference(q, k, v, causal=True)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                rtol=2e-2, atol=2e-2)
-
-
-# ---------------------------------------------------------------------------
-# the tile program with the blocks it chooses itself (no block_q/block_k)
-# ---------------------------------------------------------------------------
-
-def _chosen_case(s, d, causal, bias, dtype, b=2, h=3):
-    """Seeded (q, k, v, dO, k_bias) in `dtype`, and the same values in f32
-    for the oracle (so the inputs' own rounding is not counted). h = 3 is a
-    multiple of no power-of-two head group (three heads of 64 go as one
-    block of 192 lanes); with a bias the second batch row is padded
-    entirely."""
-    rng = np.random.RandomState(s + d + causal + 2 * bias)
-    q, k, v = _rand_qkv(rng, b=b, h=h, s=s, d=d)
-    do = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
-    k_bias = None
-    if bias:
-        k_bias = _padding_bias(rng, b, s).at[1].set(-1e9)
-    given = tuple(x.astype(dtype) for x in (q, k, v, do))
-    return given, tuple(x.astype(jnp.float32) for x in given), k_bias
-
-
-def _check_chosen_blocks(case, causal, dtype, tol_fwd, tol_bwd, fused):
-    """Forward and dq, dk, dv of the three kernels (interpret mode) at the
-    blocks and head group `_choose_tiles` picks, against the unfused
-    reference in f32. The kernels get (b, s, h*d) arrays: three, or with
-    `fused` the one [q|k|v] array a fused projection writes."""
-    from hetu_tpu.kernels import flash_attention as fa
-
-    (q, k, v, do), (qf, kf, vf, dof), k_bias = case
-    h, d = q.shape[1], q.shape[3]
-    scale = 1.0 / np.sqrt(d)
-    qkv = tuple(_rows(x) for x in (q, k, v))
-    if fused:
-        qkv = jnp.concatenate(qkv, axis=-1)
-    out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, None, None,
-                              interpret=True)
-    assert out.dtype == dtype and lse.dtype == jnp.float32
-    ref, vjp = jax.vjp(
-        lambda q, k, v: mha_reference(q, k, v, causal, k_bias=k_bias),
-        qf, kf, vf)
-    np.testing.assert_allclose(np.asarray(_heads(out, h), np.float32),
-                               np.asarray(ref), rtol=tol_fwd, atol=tol_fwd)
-    grads = fa._bwd_pallas((qkv, out, lse, k_bias), _rows(do), n_heads=h,
-                           scale=scale, causal=causal, block_q=None,
-                           block_k=None, interpret=True)
-    if fused:
-        assert grads.shape == qkv.shape
-        grads = jnp.split(grads, 3, axis=-1)
-    # A row with every key padded: its forward is the reference's uniform
-    # softmax (checked above), its backward never was: lse = -1e9 + log(l)
-    # rounds to -1e9 in f32, so the rebuilt p is 1 and not 1/l. Such a
-    # row's dO is zero in a real loss; here its gradients must be finite.
-    rows = slice(0, 1) if k_bias is not None else slice(None)
-    for got, want in zip(grads, vjp(dof)):
-        assert got.dtype == dtype
-        got = _heads(got, h)
-        assert np.isfinite(np.asarray(got, np.float32)).all()
-        np.testing.assert_allclose(np.asarray(got[rows], np.float32),
-                                   np.asarray(want[rows]), rtol=tol_bwd,
-                                   atol=tol_bwd)
-
-
-_DTYPES = pytest.mark.parametrize("dtype,tol_fwd,tol_bwd", [
-    (jnp.float32, 2e-5, 2e-4), (jnp.bfloat16, 2e-2, 2e-2)],
-    ids=["f32", "bf16"])
-
-
-@_DTYPES
-@pytest.mark.parametrize("causal,bias", [(False, True), (True, False),
-                                         (True, True), (False, False)])
-@pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("s", [128, 256, 512, 1024])
-def test_flash_chosen_blocks_match_reference(s, d, causal, bias, dtype,
-                                             tol_fwd, tol_bwd):
-    _check_chosen_blocks(_chosen_case(s, d, causal, bias, dtype), causal,
-                         dtype, tol_fwd, tol_bwd, fused=False)
-
-
-# heads, head_dim, seq -> heads a grid step (and so column blocks a row)
-@_DTYPES
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "three"])
-@pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
-@pytest.mark.parametrize("h,d,s,group", [
-    (2, 64, 128, {2}), (4, 64, 256, {4}), (12, 64, 128, {4, 12}),
-    (12, 64, 512, {2, 4, 6}), (4, 128, 512, {1, 2, 4}), (3, 64, 128, {3}),
-    (12, 64, 1024, {2, 4}), (2, 128, 256, {1, 2})],
-    ids=lambda x: str(x).replace(", ", "or").strip("{}"))
-def test_flash_btd_layout_matches_reference(h, d, s, group, causal, bias,
-                                            fused, dtype, tol_fwd, tol_bwd):
-    """The (batch, seq, heads*head_dim) contract: 2, 4 and 12 heads of 64 a
-    grid step, head size 128, more than one column block a row (12 heads in
-    three blocks or in two, by dtype and mask; 4 heads of 128 in two or in
-    one), q, k and v read out of one fused array or out of three. Three
-    heads of 64 are 192 lanes, not whole tiles: given fused, they are cut
-    in three first."""
-    from hetu_tpu.kernels import flash_attention as fa
-
-    assert set(fa._choose_tiles(s, d, dtype, causal, h)[2].values()) <= group
-    case = _chosen_case(s, d, causal, bias, dtype, b=2 if s < 512 else 1,
-                        h=h)
-    if bias and s >= 512:       # one batch row: pad its tail, not all of it
-        case = case[:2] + (_padding_bias(np.random.RandomState(s), 1, s),)
-    _check_chosen_blocks(case, causal, dtype, tol_fwd, tol_bwd, fused)
 
 
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "three"])
@@ -431,9 +189,9 @@ def test_flash_btd_entry_gradients(fused):
     from hetu_tpu.kernels.flash_attention import flash_attention_btd
 
     rng = np.random.RandomState(11)
-    q, k, v = _rand_qkv(rng, b=2, h=4, s=128, d=64)
-    k_bias = _padding_bias(rng, 2, 128)
-    qkv = tuple(_rows(x) for x in (q, k, v))
+    q, k, v = rand_qkv(rng, b=2, h=4, s=128, d=64)
+    k_bias = padding_bias(rng, 2, 128)
+    qkv = tuple(to_rows(x) for x in (q, k, v))
     if fused:
         qkv = jnp.concatenate(qkv, axis=-1)
 
@@ -447,7 +205,7 @@ def test_flash_btd_entry_gradients(fused):
     got = jnp.split(got, 3, axis=-1) if fused else got
     want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(_heads(a, 4)), np.asarray(b),
+        np.testing.assert_allclose(np.asarray(to_heads(a, 4)), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
 
 
@@ -558,11 +316,12 @@ def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias, s, n_dots):
     traced once); times the heads of a step."""
     from hetu_tpu.kernels import flash_attention as fa
 
-    (q, k, v, do), _, k_bias = _chosen_case(s, 64, causal, bias, dtype)
+    x = jax.ShapeDtypeStruct((2, s, 3 * 64), dtype)     # three heads of 64
+    k_bias = jax.ShapeDtypeStruct((2, s), jnp.float32) if bias else None
     heads = fa._choose_tiles(s, 64, dtype, causal, 3)[2]
     assert len(heads) == len(n_dots)
 
-    def both(q, k, v, do):
+    def both(q, k, v, do, k_bias):
         qkv = (q, k, v)
         out, lse = fa._fwd_pallas(qkv, 3, k_bias, 0.125, causal, None, None,
                                   interpret=False)
@@ -570,9 +329,7 @@ def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias, s, n_dots):
                               scale=0.125, causal=causal, block_q=None,
                               block_k=None, interpret=False)
 
-    calls = [e for e in jax.make_jaxpr(both)(
-        *(_rows(x) for x in (q, k, v, do))).jaxpr.eqns
-        if e.primitive.name == "pallas_call"]
+    calls = pallas_calls(jax.make_jaxpr(both)(x, x, x, x, k_bias).jaxpr)
     assert len(calls) == len(n_dots)
     for call, n, kernel in zip(calls, n_dots, heads):
         dots = list(_dot_generals(call.params["jaxpr"]))
@@ -592,180 +349,6 @@ def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias, s, n_dots):
             assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]
             assert eqn.outvars[0].aval.dtype == jnp.float32
             assert eqn.params["preferred_element_type"] == jnp.float32
-
-
-# ---------------------------------------------------------------------------
-# one backward kernel: `flash_bwd` where the sequence is one tile,
-# `flash_bwd_dqkv` where it is cut; heads a step by kernel
-# ---------------------------------------------------------------------------
-
-def _pallas_calls(fn, *args):
-    """The names of the pallas_calls `fn` makes, in order."""
-    return [e.params["name"]
-            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
-            if e.primitive.name == "pallas_call"]
-
-
-@_DTYPES
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "three"])
-@pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
-@pytest.mark.parametrize("h,d,s,group", [
-    (2, 64, 128, 2), (4, 64, 256, 4), (12, 64, 128, 12), (12, 64, 512, 4),
-    (12, 64, 512, 2), (4, 128, 128, 4), (2, 128, 512, 1), (4, 128, 256, 2)],
-    ids=lambda x: str(x))
-def test_one_tile_backward_is_one_kernel(h, d, s, group, causal, bias, fused,
-                                         dtype, tol_fwd, tol_bwd,
-                                         monkeypatch):
-    """`flash_bwd` (interpret mode) with `group` heads a step against the
-    XLA blockwise backward and the autodiff of the unfused reference, at the
-    tolerances the two-kernel path has: one pallas_call under that name, the
-    gradient in the form qkv came in, a fully padded batch row finite."""
-    from hetu_tpu.kernels import flash_attention as fa
-
-    b = 2 if s < 512 else 1
-    (q, k, v, do), (qf, kf, vf, dof), k_bias = _chosen_case(
-        s, d, causal, bias, dtype, b=b, h=h)
-    if bias and b == 1:         # one batch row: pad its tail, not all of it
-        k_bias = _padding_bias(np.random.RandomState(s), 1, s)
-    chosen = fa._choose_tiles(s, d, dtype, causal, h)
-    assert chosen[:2] == (s, s) and fa.FLASH_BWD in chosen[2]
-    monkeypatch.setattr(fa, "_choose_tiles", lambda *a, **kw: (
-        s, s, {fa.FLASH_FWD: group, fa.FLASH_BWD: group}))
-    scale = 1.0 / np.sqrt(d)
-    qkv = tuple(_rows(x) for x in (q, k, v))
-    if fused:
-        qkv = jnp.concatenate(qkv, axis=-1)
-    out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, None, None,
-                              interpret=True)
-    res = (qkv, out, lse, k_bias)
-    kw = dict(n_heads=h, scale=scale, causal=causal)
-
-    def backward(res, do):
-        return fa._bwd_pallas(res, do, block_q=None, block_k=None,
-                              interpret=True, **kw)
-
-    assert _pallas_calls(backward, res, _rows(do)) == [fa.FLASH_BWD]
-    got = backward(res, _rows(do))
-    oracle = fa._bwd_blockwise(res, _rows(do), block_k=s, **kw)
-    if fused:
-        assert got.shape == qkv.shape
-        got, oracle = (jnp.split(x, 3, axis=-1) for x in (got, oracle))
-    want = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal,
-                                                 k_bias=k_bias),
-                   qf, kf, vf)[1](dof)
-    rows = slice(0, 1) if k_bias is not None else slice(None)
-    for a, o, w in zip(got, oracle, want):
-        assert a.dtype == dtype
-        a, o = (np.asarray(_heads(x, h), np.float32) for x in (a, o))
-        assert np.isfinite(a).all()
-        np.testing.assert_allclose(a[rows], o[rows], rtol=tol_bwd,
-                                   atol=tol_bwd)
-        np.testing.assert_allclose(a[rows], np.asarray(w[rows]),
-                                   rtol=tol_bwd, atol=tol_bwd)
-
-
-# heads, q/k width, v/o width, seq, block_q, block_k, heads a step, fused
-@_DTYPES
-@pytest.mark.parametrize("causal,bias", [(False, True), (True, False)])
-@pytest.mark.parametrize("h,d,dv,s,block_q,block_k,group,fused", [
-    (2, 64, 64, 256, 128, 128, 2, True),       # two key blocks, heads paired
-    (2, 64, 64, 256, 128, 128, 2, False),
-    (4, 64, 64, 512, 128, 64, 2, True),        # two column blocks a row
-    (4, 64, 64, 512, 64, 128, 4, False),       # block_q != block_k
-    (2, 192, 128, 256, 128, 128, 2, False),    # latent attention's widths
-    (2, 128, 128, 2048, 128, 128, 1, True),    # sixteen key blocks
-    (2, 128, 128, 2048, 128, 128, 2, False)],
-    ids=lambda x: str(x))
-def test_many_tile_backward_is_one_kernel(h, d, dv, s, block_q, block_k,
-                                          group, fused, causal, bias, dtype,
-                                          tol_fwd, tol_bwd, monkeypatch):
-    """`flash_bwd_dqkv` (interpret mode) against the XLA blockwise backward
-    and the autodiff of the unfused reference, at the tolerances the pair of
-    kernels it replaced had: ONE pallas_call under that name, whose first
-    result is dq; dq summed over 2, 4, 8 and 16 key blocks in its scratch
-    (zeroed at the first, written out at the last, anew for the next head
-    group and batch row); the gradient in the form qkv came in."""
-    from hetu_tpu.kernels import flash_attention as fa
-
-    b = 2 if s < 2048 else 1
-    rng = np.random.RandomState(s + d + causal)
-    q, k = (jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
-            for _ in range(2))
-    v, do = (jnp.asarray(rng.randn(b, h, s, dv), jnp.float32)
-             for _ in range(2))
-    k_bias = _padding_bias(rng, b, s) if bias else None
-    given = tuple(x.astype(dtype) for x in (q, k, v, do))
-    qf, kf, vf, dof = (x.astype(jnp.float32) for x in given)
-    monkeypatch.setattr(fa, "_choose_tiles", lambda *a, **kw: (
-        block_q, block_k, {fa.FLASH_FWD: group, fa.FLASH_BWD_DQKV: group}))
-    scale = 1.0 / np.sqrt(d)
-    qkv = tuple(_rows(x) for x in given[:3])
-    if fused:
-        qkv = jnp.concatenate(qkv, axis=-1)
-    out, lse = fa._fwd_pallas(qkv, h, k_bias, scale, causal, block_q,
-                              block_k, interpret=True)
-    res = (qkv, out, lse, k_bias)
-    kw = dict(n_heads=h, scale=scale, causal=causal)
-
-    def backward(res, do):
-        return fa._bwd_pallas(res, do, block_q=block_q, block_k=block_k,
-                              interpret=True, **kw)
-
-    calls = [e for e in jax.make_jaxpr(backward)(res, _rows(given[3])).eqns
-             if e.primitive.name == "pallas_call"]
-    assert [e.params["name"] for e in calls] == [fa.FLASH_BWD_DQKV]
-    assert calls[0].outvars[0].aval.shape == (b, s, h * d)      # dq first
-    assert calls[0].params["grid_mapping"].grid == (b, h // group,
-                                                    s // block_k)
-    got = backward(res, _rows(given[3]))
-    oracle = fa._bwd_blockwise(res, _rows(given[3]), block_k=block_k, **kw)
-    if fused:
-        assert got.shape == qkv.shape
-        got, oracle = (jnp.split(x, 3, axis=-1) for x in (got, oracle))
-    want = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal,
-                                                 k_bias=k_bias),
-                   qf, kf, vf)[1](dof)
-    for a, o, w in zip(got, oracle, want):
-        assert a.dtype == dtype
-        a, o = (np.asarray(_heads(x, h), np.float32) for x in (a, o))
-        np.testing.assert_allclose(a, o, rtol=tol_bwd, atol=tol_bwd)
-        np.testing.assert_allclose(a, np.asarray(w), rtol=tol_bwd,
-                                   atol=tol_bwd)
-
-
-@pytest.mark.parametrize("s,block_q,block_k,names", [
-    (256, None, None, ["flash_bwd"]),
-    (512, 512, 512, ["flash_bwd"]),
-    (64, None, None, ["flash_bwd"]),
-    (1024, None, None, ["flash_bwd_dqkv"]),
-    (256, 128, None, ["flash_bwd_dqkv"]),
-    (256, None, 128, ["flash_bwd_dqkv"]),
-    (384, None, None, ["flash_bwd_dqkv"])],
-    ids=lambda x: str(x))
-def test_backward_kernels_by_tiles(s, block_q, block_k, names):
-    """Which backward kernel, by the shapes alone: `flash_bwd` iff the
-    call's sequence is one tile; a sequence of two tiles, or one a caller's
-    block cuts, runs `flash_bwd_dqkv`, and matches the oracle."""
-    from hetu_tpu.kernels import flash_attention as fa
-
-    (q, k, v, do), _, k_bias = _chosen_case(s, 64, False, True, jnp.float32,
-                                            b=1, h=2)
-    k_bias = _padding_bias(np.random.RandomState(s), 1, s)
-    qkv = jnp.concatenate([_rows(x) for x in (q, k, v)], axis=-1)
-    out, lse = fa._fwd_pallas(qkv, 2, k_bias, 0.125, False, block_q, block_k,
-                              interpret=True)
-    res = (qkv, out, lse, k_bias)
-    kw = dict(n_heads=2, scale=0.125, causal=False)
-
-    def backward(res, do):
-        return fa._bwd_pallas(res, do, block_q=block_q, block_k=block_k,
-                              interpret=True, **kw)
-
-    assert _pallas_calls(backward, res, _rows(do)) == names
-    np.testing.assert_allclose(
-        np.asarray(backward(res, _rows(do))),
-        np.asarray(fa._bwd_blockwise(res, _rows(do), block_k=min(s, 128),
-                                     **kw)), rtol=2e-4, atol=2e-4)
 
 
 # (seq, head_dim, heads, causal) of the attention calls the benchmark's

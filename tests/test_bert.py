@@ -169,13 +169,16 @@ def test_fused_mlm_ce_matches_materializing_form():
     on = dataclasses.replace(TINY, fused_mlm_ce=True)   # force off-TPU
     off = dataclasses.replace(TINY, fused_mlm_ce=False)
 
-    lf, (mf, _) = bert.pretrain_loss(params, b, on)
-    lo, (mo, _) = bert.pretrain_loss(params, b, off)
+    # loss, its parts and `jax.grad` in one compiled program a form: run
+    # eagerly, each is compiled op by op, the gradient a second time
+    def loss_and_grads(cfg):
+        return jax.jit(jax.value_and_grad(
+            lambda p: bert.pretrain_loss(p, b, cfg), has_aux=True))(params)
+
+    (lf, (mf, _)), gf = loss_and_grads(on)
+    (lo, (mo, _)), go = loss_and_grads(off)
     assert float(lf) == pytest.approx(float(lo), rel=1e-5)
     assert float(mf) == pytest.approx(float(mo), rel=1e-5)
-
-    gf = jax.grad(lambda p: bert.pretrain_loss(p, b, on)[0])(params)
-    go = jax.grad(lambda p: bert.pretrain_loss(p, b, off)[0])(params)
     for k in ("embed", "mlm_dense", "mlm_bias", "mlm_ln_scale"):
         np.testing.assert_allclose(np.asarray(gf[k]), np.asarray(go[k]),
                                    rtol=2e-4, atol=2e-5, err_msg=k)

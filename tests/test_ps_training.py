@@ -227,7 +227,15 @@ def _shared_table_two_lookups(client, rank, tmpdir):
     index sets) — the reference accumulates such grads as IndexedSlices
     (optimizer.py:64-82). Momentum runs server-side, so this also proves the
     host-side dedup-sum: the optimizer state must advance once per row per
-    step regardless of how many lookups/slots referenced the row."""
+    step regardless of how many lookups/slots referenced the row.
+
+    Both executors run BSP (`bsp=True`, as `_server_opt_schedule_sparse`
+    does): the pull stream is then the push stream, so step N+1's rows are
+    pulled after step N's push has landed, in both. Under ASP the two
+    streams race and a pull may or may not see the last push (staleness of
+    up to a step, by design), so two ASP runs agree step by step only when
+    the machine is idle: under six test workers step 11 read 0.670046
+    against 0.668759 (ISSUE 42)."""
     import os
     import hetu_tpu as ht
     S1, S2 = 2, 3
@@ -258,7 +266,7 @@ def _shared_table_two_lookups(client, rank, tmpdir):
         opt = ht.optim.MomentumOptimizer(0.1, momentum=0.9)
         train_op = opt.minimize(loss)
         ex = ht.Executor({"train": [loss, train_op]}, ctx=ht.cpu(0),
-                         comm_mode="Hybrid")
+                         comm_mode="Hybrid", bsp=True)
         return ex, feeds, y_, embed
 
     os.environ["HETU_PS_ID_BASE"] = "0"

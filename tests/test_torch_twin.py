@@ -12,8 +12,14 @@ TWIN = os.path.join(REPO, "examples", "cnn", "torch_main.py")
 
 
 def _env():
+    """One OpenMP thread, as `torch.distributed.run` gives each of its
+    processes: torch's pool of a thread a core, spinning beside the other
+    test workers' on the same cores, made the same epoch 76-91 s under
+    `-n 6` where it is 15 s alone (ISSUE 42); one thread is 15 s either
+    way."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["OMP_NUM_THREADS"] = "1"
     return env
 
 
