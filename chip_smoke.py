@@ -255,7 +255,8 @@ def _bert_batch(rng, cfg, batch, seq, n_pred, padded):
 
 def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                    ref_batch=2, mesh_axes=None, chip=True, name="flagship",
-                   moe=None, moe_batch=2, mla=None, mla_batch=1):
+                   moe=None, moe_batch=2, mla=None, mla_batch=1, dsa=None,
+                   dsa_batch=1):
     """BERT pretrain steps on an unpadded then a padded batch; the loss
     must stay finite (at lr 1e-4 without warm-up AdamW's first steps
     overshoot at BERT-base size, so "falling" is not asked here). On one
@@ -263,7 +264,9 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
     with the unfused dot/einsum reference on ``ref_batch`` rows, and the
     ``moe`` row runs one MoE layer of sizes ``moe`` or ``MOE_ROW``
     (``_moe_row``) and the ``mla`` row one train step of a latent-attention
-    model with a shared expert, ``mla`` or ``MLA_ROW`` (``_mla_row``)."""
+    model with a shared expert, ``mla`` or ``MLA_ROW`` (``_mla_row``), the
+    ``dsa`` row one of a model with learned sparse attention, ``dsa`` or
+    ``DSA_ROW`` (``_dsa_row``)."""
     import jax
     from hetu_tpu.kernels.fused_ce import should_fuse
     from hetu_tpu.models import bert
@@ -364,6 +367,7 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                                    "hidden_max_abs": round(h_scale, 3)}
             rec["moe"] = _moe_row(moe or MOE_ROW, moe_batch, chip)
             rec["mla"] = _mla_row(mla or MLA_ROW, mla_batch, chip)
+            rec["dsa"] = _dsa_row(dsa or DSA_ROW, dsa_batch, chip)
         rec.update({"model": "bert", "d_model": cfg.d_model,
                     "n_heads": cfg.n_heads, "n_layers": cfg.n_layers,
                     "d_ff": cfg.d_ff, "vocab": cfg.vocab_size, "seq": seq,
@@ -529,6 +533,76 @@ def _mla_row(sizes, batch, chip):
             "v_dim": cfg.mla.v_dim, "d_ff_shared": cfg.d_ff_shared,
             "held_picks": int(np.sum(stats["held"])),
             "dropped_picks": dropped, "tokens": int(tokens.size)}
+
+
+# Keye-VL-2.0-30B-A3B's layer (models/hf_keye.py) at the published widths, 8
+# of 32 experts held, a small vocabulary, 2,048 tokens of which a query keeps
+# 512: the `dsa` row of the flagship phase
+DSA_ROW = dict(
+    hidden_size=2048, intermediate_size=6144, moe_intermediate_size=768,
+    num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+    num_hidden_layers=1, num_experts=8, num_routed_experts=32,
+    first_expert_held=0, num_experts_per_tok=8, norm_topk_prob=True,
+    rms_norm_eps=1e-6, rope_theta=1e7, vocab_size=1024,
+    max_position_embeddings=2048,
+    sa_config=dict(indexer_num_heads=16, indexer_head_dim=64,
+                   indexer_num_kv_heads=1, topk=512))
+
+
+def _dsa_row(sizes, batch, chip):
+    """One train step of a model with learned sparse attention (an indexer
+    picks `topk` keys a query; a share of the experts held) through
+    `make_train_step`: on the chip the flash kernels attend under the packed
+    row masks; the loss before the step agrees with the unfused `dot` path's
+    under the boolean mask, the counter reads the closed form of the kept
+    pairs, the indexer's loss is positive and the step's loss finite."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.models import hf_keye, transformer as tfm
+    dtype = sizes.get("dtype", jnp.bfloat16)
+    cfg = hf_keye.config_from_hf(
+        {k: v for k, v in sizes.items() if k != "dtype"}, dtype=dtype)
+    params = tfm.init_params(jax.random.PRNGKey(3), cfg)
+    T, k = cfg.max_seq_len, cfg.dsa.top_k
+    ids = jnp.asarray(np.random.RandomState(3).randint(
+        0, cfg.vocab_size, (batch, T + 1)), jnp.int32)
+    tokens, targets = ids[:, :-1], ids[:, 1:]
+    impl = tfm._resolve_attn_impl(cfg, None, T)
+    if chip:
+        _check(impl == "flash", f"dsa: attn_impl resolved to {impl!r}")
+    loss_of = lambda c: float(jax.jit(
+        lambda p: tfm.loss_fn(p, tokens, targets, c))(params))
+    got = loss_of(cfg)
+    want = loss_of(dataclasses.replace(cfg, attn_impl="dot",
+                                       fused_lm_ce=False))
+    _check(abs(got - want) <= 1e-2 * abs(want),
+           f"dsa: kernel-path loss {got} vs dot path {want}")
+    stats = jax.jit(lambda p: tfm.dsa_stats(p, tokens, cfg))(params)
+    kept = int(np.sum(stats["kept"]))
+    closed = batch * cfg.n_layers * (
+        min(T, k) * (min(T, k) + 1) // 2 + max(T - k, 0) * k)
+    _check(kept == closed, f"dsa: {kept} kept pairs, closed form {closed}")
+    _check(float(np.min(stats["loss"])) > 0,
+           f"dsa: indexer loss {np.asarray(stats['loss']).tolist()}")
+    opt = tfm.init_opt_state(params)
+    step = tfm.make_train_step(cfg, lr=3e-6).lower(
+        params, opt, tokens, targets).compile()
+    if chip:
+        hlo = step.as_text()
+        _check(all(k in hlo for k in ("flash_fwd", "flash_bwd_dqkv",
+                                      "ragged-dot")),
+               "dsa: a kernel is missing from the compiled step")
+    loss, params, opt = step(params, opt, tokens, targets)
+    _check(_finite(loss), f"dsa: step loss {float(loss)}")
+    return {"loss": round(got, 5), "dot_loss": round(want, 5),
+            "step_loss": round(float(loss), 5), "attn_impl": impl,
+            "heads": cfg.n_heads, "kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim, "top_k": k, "kept_pairs": kept,
+            "kept_pair_pct": round(
+                100.0 * kept / (batch * cfg.n_layers * T * (T + 1) // 2), 2),
+            "index_loss": round(float(np.sum(stats["loss"])), 5),
+            "tokens": int(tokens.size)}
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,16 @@ padding mask form (0 valid / -1e9 padded) the BERT encoder uses — so masked
 batches keep the fused kernel instead of falling back to the unfused path.
 All-padded rows degenerate to a uniform softmax, exactly like the unfused
 form (softmax is shift-invariant), so the semantics match the dot path.
+
+A selection that differs by QUERY ROW (learned sparse attention: a query
+keeps the keys its indexer ranks highest) comes as ``row_mask``, a
+``pack_row_mask`` pair: one bit a (query, key) pair, 32 keys (or queries) of
+bit planes a 32-bit word, so that a tile's bits are ONE block of whole lanes
+shifted by its plane. The kernels put -inf where the bit is 0, compute every
+tile below the diagonal as they do without it (a dense kernel under a mask:
+the same numbers as a gather over the kept keys), and such a call returns
+its row statistic ``lse`` beside ``o``. A call without it traces the
+program it always did.
 """
 from __future__ import annotations
 
@@ -38,6 +48,7 @@ import functools
 import itertools
 import math
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -109,7 +120,8 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _vmem_bytes(s, d, itemsize, block_q, block_k, heads, kernel, dv=None):
+def _vmem_bytes(s, d, itemsize, block_q, block_k, heads, kernel, dv=None,
+                mask_w=0):
     """VMEM one grid step of `kernel` holds, `heads` heads a step: its
     operand and result blocks (`heads * d` columns wide for q, k and their
     gradients, `heads * dv` for v, o and theirs; `dv` is `d` unless given)
@@ -125,19 +137,24 @@ def _vmem_bytes(s, d, itemsize, block_q, block_k, heads, kernel, dv=None):
     - `flash_bwd_dqkv` (a key block against the whole sequence): q, dO, o
       in and dq out whole, k, v in and dk, dv out a block, lse whole; the
       f32 sum of dq and the rows of delta, once each (scratch); s, p, dp, ds
-      and dS as the MXU takes it, turned for dq."""
+      and dS as the MXU takes it, turned for dq.
+    `mask_w`: the words a row of a packed row mask has (0: no mask); a step
+    holds one block of rows of it, double-buffered."""
     dv = d if dv is None else dv
+    mask = 2 * mask_w * 4 * (block_k if kernel == FLASH_BWD_DQKV
+                             else block_q)
     # a position of one operand block of each width, the two side by side
     cols = 2 * heads * (d + dv) * itemsize
     rows = 2 * heads * 8 * 4            # a position of one statistics row
     tile = block_q * block_k * 4
     if kernel == FLASH_FWD:
         return (cols * (s + block_q) + rows * block_q + 3 * tile
-                + block_q * dv * 4)
+                + block_q * dv * 4 + mask)
     if kernel == FLASH_BWD:
-        return cols * 4 * s + rows * s + 5 * tile + 2 * s * heads * dv * 4
+        return (cols * 4 * s + rows * s + 5 * tile + 2 * s * heads * dv * 4
+                + mask)
     return (2 * cols * (s + block_k) + s * heads * d * 4 + rows * s * 3 // 2
-            + 5 * tile)
+            + 5 * tile + mask)
 
 
 def _blocks_bytes(s, d, itemsize, block_q, block_k, heads, dv):
@@ -320,13 +337,13 @@ def _tiles_for(qkv, n_heads, causal, block_q, block_k):
     return qkv, block_q, block_k, heads, d, dv
 
 
-def _vmem_limit(kernel, s, d, dv, dtype, block_q, block_k, heads):
+def _vmem_limit(kernel, s, d, dv, dtype, block_q, block_k, heads, mask_w=0):
     """What the call asks of Mosaic (`vmem_limit_bytes`): the least of
     `_VMEM_LIMITS` whose budget holds the step's count (the most there is
     where none does), None where the count is within what Mosaic gives
     unasked."""
     count = _vmem_bytes(s, d, jnp.dtype(dtype).itemsize, block_q, block_k,
-                        heads, kernel, dv)
+                        heads, kernel, dv, mask_w)
     if count <= _VMEM_BUDGET:
         return None
     return next((limit for limit, budget in zip(_VMEM_LIMITS, _VMEM_BUDGETS)
@@ -427,12 +444,80 @@ def _causal_upper_kb(q_start, block_q, block_k):
     return (q_start + block_q + block_k - 1) // block_k
 
 
-def _optional_bias(kernel, n_before, use_bias):
-    """pallas hands a kernel its refs in order, inputs then outputs; the
-    bias is the input after the first `n_before`, or absent."""
-    if use_bias:
-        return kernel
-    return lambda *refs: kernel(*refs[:n_before], None, *refs[n_before:])
+def _optional(kernel, n_before, *present):
+    """pallas hands a kernel its refs in order, inputs then outputs; the key
+    bias and the row mask are the inputs after the first `n_before`, each
+    there or absent (`present`): the kernel gets None for an absent one."""
+    def call(*refs):
+        rest = iter(refs[n_before:])
+        return kernel(*refs[:n_before],
+                      *(next(rest) if there else None for there in present),
+                      *rest)
+    return kernel if all(present) else call
+
+
+# ---------------------------------------------------------------------------
+# A selection by query row. `keep` (batch, seq, seq) bool says which keys a
+# query attends to. The kernels get it packed, one bit a pair: the key axis
+# (for the forward kernel, whose tiles are queries x keys) or the query axis
+# (for the backward kernels, whose tiles are keys x queries) is cut into P
+# planes of W = seq / P positions, and bit p of word [row, c] is the pair
+# (row, p * W + c). W is a multiple of every block a kernel may take (512,
+# or the whole sequence below that), so a tile lies in ONE plane: its bits
+# are a block of whole lanes, shifted by the plane and masked to one bit.
+# At 16,384 positions P is 32 and the pair of arrays is 64 MiB a sequence,
+# where a float32 (seq, seq) array is 1 GiB.
+# ---------------------------------------------------------------------------
+
+def mask_planes(s):
+    """Bit planes of a packed row mask over `s` positions: the most, up to
+    the 32 of a word, whose planes are whole blocks of 512 (of `s` below
+    that: one plane, a word a pair)."""
+    planes = 32
+    while planes > 1 and (s % planes or (s // planes) % min(512, s)):
+        planes //= 2
+    return planes
+
+
+def pack_bits(keep, planes):
+    """(..., n) bool -> (..., n / planes) int32: bit p of word c is position
+    p * (n / planes) + c of the last axis."""
+    *lead, n = keep.shape
+    bit = jnp.arange(planes, dtype=jnp.int32)[:, None]
+    bits = keep.reshape(*lead, planes, n // planes).astype(jnp.int32)
+    return jnp.sum(bits << bit, axis=-2)        # distinct bits: their OR
+
+
+def unpack_bits(packed, planes):
+    """The inverse of ``pack_bits``."""
+    bit = jnp.arange(planes, dtype=jnp.int32)[:, None]
+    return ((packed[..., None, :] >> bit) & 1).reshape(
+        *packed.shape[:-1], planes * packed.shape[-1]) != 0
+
+
+def pack_row_mask(keep):
+    """`keep` (batch, seq, seq) bool, [query, key] -> (by_query, by_key),
+    each (batch, seq, seq / P) int32: `by_query[b, t, c]` holds the keys
+    p * W + c of query t, `by_key[b, s, c]` the queries p * W + c of key s."""
+    planes = mask_planes(keep.shape[-1])
+    return pack_bits(keep, planes), pack_bits(keep.swapaxes(1, 2), planes)
+
+
+def unpack_row_mask(packed):
+    """One array of a `pack_row_mask` pair -> the (batch, rows, seq) bool it
+    packs (the XLA oracle's form)."""
+    return unpack_bits(packed, packed.shape[1] // packed.shape[2])
+
+
+def _kept(mask_ref, start, n):
+    """The tile of a packed row mask block (rows, W) whose `n` columns start
+    at position `start` of the packed axis -> (rows, n) bool."""
+    w = mask_ref.shape[-1]
+    if isinstance(start, int):
+        plane, cols = start // w, pl.ds(start % w, n)
+    else:
+        plane, cols = start // w, pl.ds(pl.multiple_of(start % w, n), n)
+    return (mask_ref[:, cols] >> plane) & 1 != 0
 
 
 # ---------------------------------------------------------------------------
@@ -456,8 +541,8 @@ def _optional_bias(kernel, n_before, use_bias):
 #   and P^T, dS^T feed dV and dK without a transposed matmul.
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
-                causal, block_k, d, dv):
+def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref, *,
+                scale, causal, block_k, d, dv):
     # grid: (batch, head groups, q blocks); one q block, the whole k and v
     block_q = q_ref.shape[0]
     q_start = pl.program_id(2) * block_q
@@ -481,7 +566,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
             s = _dot_nt(q, k_ref[keys, lanes]) * scale  # (block_q, block_k)
             if bias_ref is not None:
                 s = s + bias_ref[0, :, keys]
-            if causal:
+            if mask_ref is not None:    # a causal selection: the mask says
+                s = jnp.where(_kept(mask_ref, kj * block_k, block_k), s,
+                              _NEG_INF)
+            elif causal:
                 s = _causal_mask(s, q_start, kj * block_k)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -506,7 +594,8 @@ def _specs(qkv, heads, d, n_heads, block, dv):
     q, k or "dq", `heads * dv` in v or "o" (column block `j`, `groups + j`,
     `2 * groups + j` of a fused array for q, k, v; "dq" and "o" are arrays
     of their own: dq; o, dO, dv); `stats()` the group's rows of lse or
-    delta; `bias()` the batch row's key bias."""
+    delta; `bias()` the batch row's key bias; `mask(w)` the block's rows of
+    a packed row mask of `w` words a row."""
     groups = n_heads // heads
     s = (qkv if _is_fused(qkv) else qkv[0]).shape[1]
     first = dict(q=0, k=0, v=0, o=0, dq=0)
@@ -531,13 +620,24 @@ def _specs(qkv, heads, d, n_heads, block, dv):
         n, at = along(whole)
         return pl.BlockSpec((1, 1, n), lambda b, j, i: (b, 0, at(i)))
 
-    return cols, stats, bias
+    def mask(w):
+        return pl.BlockSpec((None, block, w), lambda b, j, i: (b, i, 0))
+
+    return cols, stats, bias, mask
 
 
 def _operands(qkv):
     """q, k and v as the kernels take them: the same array three times
     where they are one, under three index maps (`_specs`)."""
     return [qkv] * 3 if _is_fused(qkv) else list(qkv)
+
+
+def _mask_of(row_mask, side):
+    """-> ([the array of a `pack_row_mask` pair a kernel reads: 0 by query,
+    1 by key], its words a row), ([], 0) without a mask."""
+    if row_mask is None:
+        return [], 0
+    return [row_mask[side]], row_mask[side].shape[-1]
 
 
 def _bias_rows(k_bias):
@@ -547,7 +647,7 @@ def _bias_rows(k_bias):
 
 
 def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
-                interpret):
+                interpret, row_mask=None):
     """-> o (batch, seq, heads * d), lse (batch * heads, 1, seq)."""
     qkv, block_q, block_k, heads, d, dv = _tiles_for(qkv, n_heads, causal,
                                                      block_q, block_k)
@@ -556,14 +656,16 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
     b, s, _ = ops[0].shape
     use_bias = k_bias is not None
     bias = [_bias_rows(k_bias)] if use_bias else []
-    cols, stats, bias_spec = _specs(qkv, heads, d, n_heads, block_q, dv)
+    masks, mask_w = _mask_of(row_mask, 0)
+    cols, stats, bias_spec, mask_spec = _specs(qkv, heads, d, n_heads,
+                                               block_q, dv)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              block_k=block_k, d=d, dv=dv)
     return pl.pallas_call(
-        _optional_bias(kern, 3, use_bias),
+        _optional(kern, 3, use_bias, bool(masks)),
         grid=(b, n_heads // heads, s // block_q),
         in_specs=[cols("q"), cols("k", True), cols("v", True)]
-        + [bias_spec(True)] * use_bias,
+        + [bias_spec(True)] * use_bias + [mask_spec(mask_w)] * len(masks),
         out_specs=[cols("o"), stats()],
         out_shape=[
             jax.ShapeDtypeStruct((b, s, n_heads * dv), ops[0].dtype),
@@ -571,8 +673,9 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
         ],
         interpret=interpret,
         name=FLASH_FWD,
-        **_asking(FLASH_FWD, s, d, dv, ops[0].dtype, block_q, block_k, heads),
-    )(*ops, *bias)
+        **_asking(FLASH_FWD, s, d, dv, ops[0].dtype, block_q, block_k, heads,
+                  *[mask_w] * len(masks)),
+    )(*ops, *bias, *masks)
 
 
 # ---------------------------------------------------------------------------
@@ -594,8 +697,8 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
-                     dq_ref, dv_ref, dk_ref, dq_acc, delta_ref, *, scale,
-                     causal, block_q, d, dv):
+                     mask_ref, dq_ref, dv_ref, dk_ref, dq_acc, delta_ref, *,
+                     scale, causal, block_q, d, dv):
     # grid: (batch, head groups, k blocks), the last in order; owns one k/v
     # block, loops over q blocks. Tiles are (block_k, block_q): S^T, P^T,
     # dP^T, dS^T, and dQ alone contracts over the rows of dS^T.
@@ -636,7 +739,10 @@ def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
             st = _dot_nt(k_blk, q) * scale
             if bias is not None:
                 st = st + bias
-            if causal:
+            if mask_ref is not None:
+                st = jnp.where(_kept(mask_ref, qi * block_q, block_q), st,
+                               _NEG_INF)
+            elif causal:
                 st = _causal_mask(st, qi * block_q, k_start, keys_first=True)
             pt = jnp.exp(st - lse_ref[g, :, rows])    # (block_k, block_q)
             d_v = d_v + _dot(pt.astype(do.dtype), do)
@@ -663,7 +769,7 @@ def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
-                dq_ref, dv_ref, dk_ref, *, scale, causal, d, dv):
+                mask_ref, dq_ref, dv_ref, dk_ref, *, scale, causal, d, dv):
     # grid: (batch, head groups, 1); the whole sequence is ONE tile, so
     # nothing is summed over blocks and one rebuilt tile serves dq, dk and
     # dv. The tile is (keys, queries) as in `flash_bwd_dqkv`: lse and delta
@@ -683,7 +789,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
         st = _dot_nt(k, q) * scale                    # (keys, queries)
         if bias is not None:
             st = st + bias
-        if causal:
+        if mask_ref is not None:
+            st = jnp.where(_kept(mask_ref, 0, st.shape[1]), st, _NEG_INF)
+        elif causal:
             st = _causal_mask(st, 0, 0, keys_first=True)
         pt = jnp.exp(st - lse_ref[g])
         dv_ref[:, v_lanes] = _dot(pt.astype(do.dtype),
@@ -705,7 +813,8 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
     call's FLOPs from it.) `flash_bwd` where the sequence is one tile,
     `flash_bwd_dqkv` where it is cut (`_kernels_of`), each with its own
     heads a step."""
-    given, o, lse, k_bias = res
+    given, o, lse, k_bias, *row_mask = res
+    masks, mask_w = _mask_of(row_mask[0] if row_mask else None, 1)
     given_blocks = block_q, block_k
     qkv, block_q, block_k, heads, d, dv = _tiles_for(given, n_heads, causal,
                                                      block_q, block_k)
@@ -723,32 +832,37 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
     if kernel == FLASH_BWD_DQKV:
         block_q, block_k = _bwd_blocks(s, d, o.dtype, group, *given_blocks,
                                        dv)
-    call = (kernel, s, d, dv, o.dtype, block_q, block_k, group)
+    call = (kernel, s, d, dv, o.dtype, block_q, block_k, group,
+            *[mask_w] * len(masks))
 
     if kernel == FLASH_BWD:
-        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, s, dv)
+        cols, stats, bias_spec, mask_spec = _specs(qkv, group, d, n_heads, s,
+                                                   dv)
         kern = functools.partial(_bwd_kernel, scale=scale, causal=causal,
                                  d=d, dv=dv)
         dq, d_v, dk = pl.pallas_call(
-            _optional_bias(kern, 6, use_bias),
+            _optional(kern, 6, use_bias, bool(masks)),
             grid=(b, n_heads // group, 1),
             in_specs=[cols("q"), cols("k"), cols("v"), cols("o"), cols("o"),
-                      stats()] + [bias_spec()] * use_bias,
+                      stats()] + [bias_spec()] * use_bias
+            + [mask_spec(mask_w)] * len(masks),
             out_specs=[cols("dq"), cols("o"), cols("k")],
             out_shape=[grad_q, grad, dk_shape],
             interpret=interpret,
             name=FLASH_BWD,
             **_asking(*call),
-        )(*_operands(qkv), do, o, lse, *bias)
+        )(*_operands(qkv), do, o, lse, *bias, *masks)
     else:
-        cols, stats, bias_spec = _specs(qkv, group, d, n_heads, block_k, dv)
+        cols, stats, bias_spec, mask_spec = _specs(qkv, group, d, n_heads,
+                                                   block_k, dv)
         kern = functools.partial(_bwd_dqkv_kernel, scale=scale, causal=causal,
                                  block_q=block_q, d=d, dv=dv)
         dq, d_v, dk = pl.pallas_call(
-            _optional_bias(kern, 6, use_bias),
+            _optional(kern, 6, use_bias, bool(masks)),
             grid=(b, n_heads // group, s // block_k),
             in_specs=[cols("q", True), cols("k"), cols("v"), cols("o", True),
-                      cols("o", True), stats(True)] + [bias_spec()] * use_bias,
+                      cols("o", True), stats(True)] + [bias_spec()] * use_bias
+            + [mask_spec(mask_w)] * len(masks),
             out_specs=[cols("dq", True), cols("o"), cols("k")],
             out_shape=[grad_q, grad, dk_shape],
             scratch_shapes=[pltpu.VMEM((s, group * d), jnp.float32),
@@ -758,7 +872,7 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=_vmem_limit(*call)),
-        )(*_operands(qkv), do, o, lse, *bias)
+        )(*_operands(qkv), do, o, lse, *bias, *masks)
 
     if _is_fused(qkv):
         out = jax.lax.dynamic_update_slice_in_dim(dk, dq, 0, axis=2)
@@ -774,7 +888,8 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k):
-    qkv, o, lse, k_bias = res
+    qkv, o, lse, k_bias, *row_mask = res
+    keep = unpack_row_mask(row_mask[0][0]) if row_mask else None
     b, s, _ = o.shape
     nkb = s // block_k
 
@@ -798,7 +913,10 @@ def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k):
             kb = jax.lax.dynamic_slice_in_dim(
                 k_bias.astype(jnp.float32), ks, block_k, 1)
             s_blk = s_blk + kb[:, None, None, :]
-        if causal:
+        if keep is not None:
+            s_blk = jnp.where(jax.lax.dynamic_slice_in_dim(
+                keep, ks, block_k, 2)[:, None], s_blk, _NEG_INF)
+        elif causal:
             mask = q_pos[:, None] >= (ks + jnp.arange(block_k))[None, :]
             s_blk = jnp.where(mask, s_blk, _NEG_INF)
         p = jnp.exp(s_blk - lse[..., None])                    # (b,h,s,bk)
@@ -834,7 +952,7 @@ def _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
 
 
 def flash_attention_btd(qkv, n_heads, causal=True, scale=None, block_q=None,
-                        block_k=None, k_bias=None):
+                        block_k=None, k_bias=None, row_mask=None):
     """Fused attention in the projections' own layout: the kernels' entry.
 
     ``qkv``: one (batch, seq, 3 * heads * head_dim) array, [q | k | v] along
@@ -851,9 +969,19 @@ def flash_attention_btd(qkv, n_heads, causal=True, scale=None, block_q=None,
 
     ``k_bias``: optional (batch, seq) float added to every score column —
     the key-padding mask form (0 valid / -1e9 padded). Non-trainable: its
-    cotangent is zero."""
+    cotangent is zero.
+
+    ``row_mask``: optional ``pack_row_mask`` pair, the keys each query keeps
+    (a selection inside the causal triangle: pass ``causal=True`` with it,
+    which skips the tiles above the diagonal). Such a call returns (o, lse):
+    ``lse`` (batch * heads, 1, seq) float32, the logarithm of each row's
+    softmax denominator over its kept keys, for a caller that rebuilds the
+    probabilities; it carries no gradient."""
     if not _is_fused(qkv):
         qkv = tuple(qkv)
+    if row_mask is not None:
+        return _flash_rows(qkv, n_heads, tuple(row_mask), causal, scale,
+                           block_q, block_k)
     return _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k)
 
 
@@ -887,6 +1015,7 @@ def _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
 
 
 def _flash_bwd(n_heads, causal, scale, block_q, block_k, res, do):
+    """`res`: (qkv, o, lse, k_bias) and, under a row mask, the pair."""
     qkv, k_bias = res[0], res[3]
     kw = dict(n_heads=n_heads, scale=_scale(qkv, n_heads, scale),
               causal=causal)
@@ -903,9 +1032,37 @@ def _flash_bwd(n_heads, causal, scale, block_q, block_k, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def mha_reference(q, k, v, causal=True, scale=None, k_bias=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 3, 4, 5, 6))
+def _flash_rows(qkv, n_heads, row_mask, causal, scale, block_q, block_k):
+    """`_flash` under a row mask -> (o, lse). A `custom_vjp` of its own: the
+    calls without a mask keep theirs, and with it their traced programs."""
+    return _flash_rows_fwd(qkv, n_heads, row_mask, causal, scale, block_q,
+                           block_k)[0]
+
+
+def _flash_rows_fwd(qkv, n_heads, row_mask, causal, scale, block_q, block_k):
+    out, lse = _fwd_pallas(qkv, n_heads, None, _scale(qkv, n_heads, scale),
+                           causal, block_q, block_k, interpret=not _on_tpu(),
+                           row_mask=row_mask)
+    out = checkpoint_name(out, REMAT_ATTN_O)
+    lse = checkpoint_name(lse, REMAT_ATTN_LSE)
+    return (out, lse), (qkv, out, lse, None, row_mask)
+
+
+def _flash_rows_bwd(n_heads, causal, scale, block_q, block_k, res, cts):
+    grads, _ = _flash_bwd(n_heads, causal, scale, block_q, block_k, res,
+                          cts[0])       # lse's cotangent: it has no gradient
+    return grads, jax.tree.map(
+        lambda m: np.zeros(m.shape, jax.dtypes.float0), res[4])
+
+
+_flash_rows.defvjp(_flash_rows_fwd, _flash_rows_bwd)
+
+
+def mha_reference(q, k, v, causal=True, scale=None, k_bias=None, keep=None):
     """Unfused reference (the reference framework's BatchMatMul+Softmax
-    attention) — used as the numerical oracle in tests."""
+    attention) — used as the numerical oracle in tests. ``keep`` (batch,
+    seq, seq) bool: the keys each query keeps, every head alike."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -915,6 +1072,8 @@ def mha_reference(q, k, v, causal=True, scale=None, k_bias=None):
         n = q.shape[2]
         mask = jnp.tril(jnp.ones((n, n), bool))
         s = jnp.where(mask, s, _NEG_INF)
+    if keep is not None:
+        s = jnp.where(keep[:, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)

@@ -171,6 +171,12 @@ def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
         "latent-attention (mla) layers, whose cache is the 512-wide latent "
         "and one rotary key a token, not k and v (_decode_layer mirrors the "
         "attention block)")
+    assert "dsa" not in cfg.layer_types and not cfg.d_head, (
+        f"decode has no indexer cache: layer_types={cfg.layer_types} holds "
+        "learned-sparse-attention (dsa) layers, whose decode step ranks the "
+        "cached index keys and attends to the kept ones, or d_head="
+        f"{cfg.d_head} is a head width of its own (_decode_layer mirrors "
+        "the attention block at d_model // n_heads)")
     assert not cfg.d_ff_shared, (
         f"decode does not mirror a shared expert (d_ff_shared="
         f"{cfg.d_ff_shared}: the always-on branch of an expert layer)")

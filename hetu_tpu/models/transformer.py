@@ -43,8 +43,10 @@ from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  REMAT_NORM2_IN, REMAT_X1, REMAT_X2,
                                  SCOPE_BLK_ATTN, SCOPE_BLK_MLP_DOWN,
                                  SCOPE_BLK_MLP_UP, SCOPE_BLK_NORM,
-                                 SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_EMBED,
-                                 SCOPE_EXIT, SCOPE_FWD, SCOPE_HEAD,
+                                 SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_DSA_LOSS,
+                                 SCOPE_DSA_PROJ, SCOPE_DSA_SELECT,
+                                 SCOPE_EMBED, SCOPE_EXIT, SCOPE_FWD,
+                                 SCOPE_HEAD,
                                  SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP,
                                  SCOPE_MLA_Q, SCOPE_MOE_COMBINE,
                                  SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
@@ -60,6 +62,10 @@ _log = logging.getLogger(__name__)
 # router z-loss weight (ST-MoE, OLMoE: 1e-3); the balance loss keeps
 # ``loss_fn``'s ``aux_weight``
 Z_LOSS_WEIGHT = 1e-3
+# the indexer's own loss of a learned-sparse-attention layer (``_dsa``): it
+# moves the indexer's leaves alone, so the weight is that module's own
+# learning rate scale (DeepSeek-V3.2-Exp's sparse training stage: 1)
+DSA_LOSS_WEIGHT = 1.0
 # entropy bonus on a looped model's exit distribution (Ouro stage I,
 # arXiv:2510.25741: 0.1 early, 0.05 later; a uniform prior over exit steps)
 EXIT_ENTROPY_WEIGHT = 0.05
@@ -115,6 +121,17 @@ class MLAConfig:
     @property
     def qk_dim(self):
         return self.nope_dim + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class DSAConfig:
+    """The three sizes of a learned sparse attention's indexer (DeepSeek-
+    V3.2-Exp's lightning indexer, arXiv:2512.02556; ``_dsa``): ``n_heads``
+    index heads of ``head_dim`` columns score every key against ONE index key
+    a token, and a query attends to the ``top_k`` keys of largest score."""
+    n_heads: int = 16
+    head_dim: int = 64
+    top_k: int = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -241,7 +258,8 @@ class TransformerConfig:
                                  # ``ln2_post_*``); pre-LN only
     # Hybrid stacks (models/hf_granite.py sets all three):
     layer_types: tuple = ()     # a mixer of ``_KINDS`` a layer ("attention",
-                                # "mamba", "conv", "mla"); () = ``n_layers``
+                                # "mamba", "conv", "mla", "dsa"); () =
+                                # ``n_layers``
                                 # of attention. ``encode`` scans each run of
                                 # one kind; ``params["blocks"]`` is the
                                 # stacked dict of a stack with one run,
@@ -268,6 +286,11 @@ class TransformerConfig:
                                 # (``ws1`` / ``ws3`` / ``ws2``; DeepSeek's
                                 # shared experts, side by side); a share
                                 # (``router.width``) computes it whole
+    # A head width of its own and learned sparse attention
+    # (models/hf_keye.py sets both):
+    d_head: int = 0             # columns a head of q, k and v; 0 = ``d_model
+                                # // n_heads`` (``head_dim`` is what is read)
+    dsa: Optional[DSAConfig] = None     # the "dsa" layers' indexer
 
     def __post_init__(self):
         if self.layer_types:
@@ -286,6 +309,13 @@ class TransformerConfig:
                 f"layer_types={self.layer_types}: an mla layer takes `mla` "
                 "sizes, pre-LN, and no projection bias, QK-norm, grouped "
                 "heads or attention multiplier")
+        if "dsa" in self.layer_types and (
+                self.dsa is None or self.post_ln or not self.causal
+                or not self.rope):
+            raise ValueError(
+                f"layer_types={self.layer_types}: a dsa layer takes `dsa` "
+                "sizes, pre-LN, causal attention and RoPE (the indexer "
+                "rotates its queries and keys too)")
         if self.d_ff_shared and not (self.n_experts
                                      and self.mlp == "swiglu"):
             raise MoEConfigError(
@@ -331,6 +361,8 @@ class TransformerConfig:
 
     @property
     def head_dim(self):
+        if self.d_head:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -412,7 +444,7 @@ def _init_attention(ks, cfg: TransformerConfig, n):
     D = cfg.d_model
     qkv_width = (cfg.n_heads + 2 * cfg.kv_heads) * cfg.head_dim
     p = {"wqkv": _init_normal(ks[0], (n, D, qkv_width), 0.02),
-         "wo": _init_normal(ks[1], (n, D, D),
+         "wo": _init_normal(ks[1], (n, cfg.n_heads * cfg.head_dim, D),
                             0.02 / np.sqrt(2 * cfg.n_layers))}
     if cfg.attn_proj_bias:
         p["bqkv"] = jnp.zeros((n, qkv_width), jnp.float32)
@@ -502,6 +534,32 @@ def _mla_specs(cfg: TransformerConfig):
     cut of all five (a ``tp`` axis computes the projections on every device;
     the kernels still run a shard of the heads each, ``_flash``)."""
     return {name: P() for name in ("wq", "wkv_a", "kv_norm", "wkv_b", "wo")}
+
+
+# the indexer's leaves of a "dsa" layer, beside the attention's own
+DSA_LEAVES = ("wq_idx", "wk_idx", "k_idx_norm_scale", "k_idx_norm_bias",
+              "ww_idx")
+
+
+def _init_dsa(ks, cfg: TransformerConfig, n):
+    """Grouped-query attention's leaves (``_init_attention``) and the
+    indexer's: normal(0.02) for its three Linears, its key LayerNorm 1 and
+    0."""
+    m, D = cfg.dsa, cfg.d_model
+    kq, kk, kw = jax.random.split(jax.random.fold_in(ks[11], 2), 3)
+    return {
+        **_init_attention(ks, cfg, n),
+        "wq_idx": _init_normal(kq, (n, D, m.n_heads * m.head_dim), 0.02),
+        "wk_idx": _init_normal(kk, (n, D, m.head_dim), 0.02),
+        "k_idx_norm_scale": jnp.ones((n, m.head_dim), jnp.float32),
+        "k_idx_norm_bias": jnp.zeros((n, m.head_dim), jnp.float32),
+        "ww_idx": _init_normal(kw, (n, D, m.n_heads), 0.02)}
+
+
+def _dsa_specs(cfg: TransformerConfig):
+    """The attention's own, and the indexer replicated: its one key is
+    every index head's (the mixer runs on one program, ``_dsa``)."""
+    return {**_attention_specs(cfg), **{name: P() for name in DSA_LEAVES}}
 
 
 def _init_run(ks, cfg: TransformerConfig, kind, n):
@@ -1039,6 +1097,103 @@ def _mla(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
                           preferred_element_type=jnp.float32).astype(h.dtype)
 
 
+def _dsa_index(h, p, cfg: TransformerConfig):
+    """The indexer's three projections of a layer's normed input ``h`` (B,
+    T, D), DETACHED from the trunk -> (qI (B, T, J * c) rotated, kI (B, T,
+    c) LayerNormed then rotated, w (B, T, J) float32 = h Ww / sqrt(J c)):
+    DeepSeek-V3.2-Exp's, whose q comes from a low-rank latent this model has
+    not: RoPE (``_rope``, the model's theta) on all c columns, every head's
+    the same way."""
+    m = cfg.dsa
+    x = jax.lax.stop_gradient(h)
+    proj = lambda w: jnp.einsum("btd,de->bte", x, w.astype(x.dtype),
+                                preferred_element_type=jnp.float32)
+    qI = _rope(proj(p["wq_idx"]).astype(x.dtype), 0, cfg.rope_theta,
+               m.head_dim)
+    kI = _rope(_layer_norm(proj(p["wk_idx"]).astype(x.dtype),
+                           p["k_idx_norm_scale"], p["k_idx_norm_bias"],
+                           cfg.ln_eps), 0, cfg.rope_theta, m.head_dim)
+    w = proj(p["ww_idx"]) * (m.n_heads * m.head_dim) ** -0.5
+    return qI, kI, w
+
+
+def _dsa_parts(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+    """Learned sparse attention (``cfg.dsa``) on a layer's normed input ->
+    (the mixer's output (B, T, D), L_I the indexer's loss of the layer, kept
+    (B,) the (query, key) pairs kept a sequence).
+
+    Grouped-query attention as ``_attention`` computes it (``_split_heads``:
+    QK-norm, RoPE, the kv heads broadcast), but a query attends to the
+    ``top_k`` keys s <= t its indexer ranks highest (``kernels/dsa.py``): the
+    selection is exact and has no gradient, so the trunk's gradients are
+    those of attention under a constant mask; the indexer reads the layer's
+    input detached and learns from L_I alone, whose target, the attention's
+    head-summed probabilities, is detached too. The flash kernels take the
+    kept set one bit a pair and compute every tile below the diagonal (a
+    dense kernel under a mask); off the chip the ``dot`` path adds the same
+    mask as a bias. With ``top_k`` >= T the kept set is the causal triangle
+    and the output is ``_attention``'s."""
+    from ..kernels import dsa
+    from ..kernels.flash_attention import (flash_attention_btd,
+                                           unpack_row_mask)
+    if attn_bias is not None or (mesh is not None and mesh.size > 1):
+        raise NotImplementedError(
+            "learned sparse attention (dsa) on a mesh or under a padding "
+            "mask: the selection, its packed masks and the indexer's loss "
+            "run on one program over whole causal sequences")
+    B, T, _ = h.shape
+    nh, hd = cfg.n_heads, cfg.head_dim
+    impl = _resolve_attn_impl(cfg, mesh, T)
+    with jax.named_scope(SCOPE_BLK_QKV):
+        qkv = jnp.einsum("btd,de->bte", h, p["wqkv"].astype(h.dtype),
+                         preferred_element_type=jnp.float32).astype(h.dtype)
+        if cfg.attn_proj_bias:
+            qkv = qkv + p["bqkv"].astype(h.dtype)
+        q, k, v = _split_heads(qkv, p, cfg, mesh, impl)
+    with jax.named_scope(SCOPE_DSA_PROJ):
+        qI, kI, w = _dsa_index(h, p, cfg)
+    with jax.named_scope(SCOPE_DSA_SELECT):
+        row_mask, kept = dsa.select(qI, kI, w, cfg.dsa.top_k)
+    with jax.named_scope(SCOPE_BLK_ATTN):
+        if impl == "flash":
+            out, lse = flash_attention_btd((q, k, v), nh, True,
+                                           row_mask=row_mask)
+        else:
+            # `_attention_core`'s dot path, the kept set where it has the
+            # causal triangle: the same numbers while they are the same set
+            keep = unpack_row_mask(row_mask[0])[:, None]
+            scores = jnp.where(keep, jnp.einsum(
+                "bqhd,bkhd->bhqk", q.reshape(B, T, nh, hd),
+                k.reshape(B, T, nh, hd),
+                preferred_element_type=jnp.float32) / np.sqrt(hd), -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            out = checkpoint_name(jnp.einsum(
+                "bhqk,bkhd->bqhd", probs, v.reshape(B, T, nh, hd),
+                preferred_element_type=jnp.float32).astype(q.dtype).reshape(
+                    B, T, -1), REMAT_ATTN_O)
+            lse = jax.nn.logsumexp(scores, axis=-1).reshape(B * nh, 1, T)
+    with jax.named_scope(SCOPE_DSA_LOSS):
+        stop = jax.lax.stop_gradient
+        # k at the k/v heads again: `_split_heads` broadcast each to its
+        # query group, side by side
+        k_kv = k.reshape(B, T, cfg.kv_heads, -1, hd)[:, :, :, 0].reshape(
+            B, T, -1)
+        loss = dsa.indexer_loss(qI, kI, w, stop(q), stop(k_kv), stop(lse),
+                                row_mask[0], nh, 1.0 / np.sqrt(hd))
+    with jax.named_scope(SCOPE_BLK_WO):
+        out = jnp.einsum("btd,de->bte", out, p["wo"].astype(h.dtype),
+                         preferred_element_type=jnp.float32).astype(h.dtype)
+        if cfg.attn_proj_bias:
+            out = out + p["bo"].astype(h.dtype)
+    return out, loss, kept
+
+
+def _dsa(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+    """The "dsa" mixer -> (output, L_I): a mixer WITH a loss of its own
+    (``_Kind.side_loss``)."""
+    return _dsa_parts(h, p, cfg, mesh, attn_bias)[:2]
+
+
 def _ssm_dt(dt_raw, dt_bias):
     """The step sizes, float32: softplus(raw + bias) a head, limit (0, inf)
     as published (no clamp)."""
@@ -1233,16 +1388,20 @@ def _short_conv_gates(Bg, Cg, x, conv_w):
 class _Kind:
     """What a kind of mixer brings: its stacked weights, their
     PartitionSpecs and the mixer ``(h, layer_params, cfg, mesh, attn_bias)
-    -> (B, T, D)``; ``_block`` is the one body around it."""
+    -> (B, T, D)``; ``_block`` is the one body around it. ``side_loss``: the
+    mixer returns (output, a float32 scalar) and the scalar is a loss of the
+    layer's own, which ``_block`` carries out beside the router's two."""
     init: Any
     specs: Any
     mixer: Any
+    side_loss: bool = False
 
 
 _KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
           "mamba": _Kind(_init_mamba, _mamba_specs, _mamba),
           "conv": _Kind(_init_short_conv, _short_conv_specs, _short_conv),
-          "mla": _Kind(_init_mla, _mla_specs, _mla)}
+          "mla": _Kind(_init_mla, _mla_specs, _mla),
+          "dsa": _Kind(_init_dsa, _dsa_specs, _dsa, side_loss=True)}
 
 
 def _dense_mlp(h, p, cfg, mesh):
@@ -1778,6 +1937,12 @@ def _moe_mlp_capacity(h, p, cfg: TransformerConfig, mesh):
     return out.reshape(B, T, D), aux
 
 
+def _aux_size(cfg: TransformerConfig):
+    """Entries of a block's aux: the router's [balance, z], and in a stack
+    with learned sparse attention the indexer's loss after them."""
+    return 3 if "dsa" in cfg.layer_types else 2
+
+
 def _residual(out, cfg: TransformerConfig):
     """A sublayer's output as its residual add takes it."""
     r = cfg.multipliers.residual
@@ -1790,12 +1955,23 @@ def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
                 dropout_rng, kind="attention"):
     """The mixer half of ``_block`` (attention, or what ``kind``'s mixer
     names in ``_KINDS``) -> (h after the residual, the MLP half's input)."""
+    return _block_mixer(h, layer_params, cfg, mesh, attn_bias, dropout_rng,
+                        kind)[:2]
+
+
+def _block_mixer(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
+                 dropout_rng, kind):
+    """``_block_attn`` and, third, the mixer's own loss (None without:
+    ``_Kind.side_loss``)."""
     post = cfg.post_ln
+    mixer = _KINDS[mixer_of(kind)]
     h = _constrain(h, mesh, "dp", "sp", None)
     attn_in = h if post else _norm(
         h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
-    attn_out = _KINDS[mixer_of(kind)].mixer(attn_in, layer_params, cfg, mesh,
-                                            attn_bias)
+    attn_out = mixer.mixer(attn_in, layer_params, cfg, mesh, attn_bias)
+    side = None
+    if mixer.side_loss:
+        attn_out, side = attn_out
     if cfg.sandwich_norm:
         # the norm's backward pass reads its input: kept, the mixer's last
         # matmul (`wo`) is not run again for it
@@ -1811,13 +1987,14 @@ def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
     h = _constrain(h, mesh, "dp", "sp", None)
     mlp_in = h if post else _norm(
         h, layer_params["ln2_scale"], layer_params["ln2_bias"], cfg)
-    return h, mlp_in
+    return h, mlp_in, side
 
 
 def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
            dropout_rng=None, kind="attention"):
     """One block of any kind -> (h, aux (2,) = the MoE block's [balance,
-    z] losses, zeros for a dense MLP). Pre-LN (flagship default): LN ->
+    z] losses, zeros for a dense MLP; in a stack with "dsa" layers (3,), the
+    third the indexer's loss of the layer, ``_aux_size``). Pre-LN (flagship default): LN ->
     sublayer -> residual. Post-LN (``cfg.post_ln``, canonical BERT /
     original Transformer): sublayer -> residual -> LN, with ln1 after
     attention and ln2 after the MLP. Sandwich (``cfg.sandwich_norm``,
@@ -1832,12 +2009,16 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     decode silently diverges from training for that config."""
     k1, k2 = (None, None) if dropout_rng is None else jax.random.split(
         dropout_rng)
-    h, mlp_in = _block_attn(h, layer_params, cfg, mesh, attn_bias, k1, kind)
+    h, mlp_in, side = _block_mixer(h, layer_params, cfg, mesh, attn_bias, k1,
+                                   kind)
     if experts_of(cfg, kind) > 0:
         out, aux = _moe_mlp(mlp_in, layer_params, cfg, mesh)
     else:
         out = _dense_mlp(mlp_in, layer_params, cfg, mesh)
         aux = jnp.zeros((2,), jnp.float32)
+    if _aux_size(cfg) > 2:
+        # a stack with dsa layers: every layer's aux has the third entry
+        aux = jnp.append(aux, 0.0 if side is None else side)
     if cfg.sandwich_norm:
         # as for the mixer's output: `w2` (a MoE block: the combine)
         out = _norm(checkpoint_name(out, REMAT_NORM2_IN),
@@ -2005,8 +2186,10 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
         - max(_block_residual_bytes(cfg, mesh, h, blocks, attn_bias, kind)
               for kind, blocks in by_kind.items()) // dp)
     attention, mla = (cfg.n_loops * sum(
-        n for kind, n in layer_runs(cfg) if mixer_of(kind) == mixer)
-        for mixer in ("attention", "mla"))
+        n for kind, n in layer_runs(cfg) if mixer_of(kind) in mixers)
+        for mixers in (("attention", "dsa"), ("mla",)))
+    # q and o at the heads' own width (`cfg.d_head`): D unless it says more
+    by_head = by_head * cfg.n_heads * cfg.head_dim // D
     split = 0 if _projection_in_place(
         cfg, mesh, _resolve_attn_impl(cfg, mesh, T, attn_bias)) else attention
     # one mla layer's arrays, in columns a token over the stream's D
@@ -2096,7 +2279,7 @@ def encode(params, h, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             first += n
         return h, aux_sum
 
-    no_aux = jnp.zeros((2,), jnp.float32)
+    no_aux = jnp.zeros((_aux_size(cfg),), jnp.float32)
     if cfg.n_loops == 1:
         return stack(h, no_aux, dropout_rng)
 
@@ -2141,7 +2324,7 @@ def _through_run(h, blocks, cfg: TransformerConfig, kind):
     return h
 
 
-def moe_routing_stats(params, tokens, cfg: TransformerConfig):
+def moe_routing_stats(params, tokens, cfg: TransformerConfig, terms=False):
     """What the routers of a MoE model do with ``tokens`` (B, T): a pure
     function beside the step, for counters and checks (no mesh). It walks
     the runs of ``layer_runs`` and reports the EXPERT layers, in order
@@ -2156,7 +2339,9 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig):
     step's loops take their trip count from; else all B*T*k),
     ``entropy`` the mean entropy of a token's scores as a distribution over
     the experts (the softmax itself; sigmoid scores over their sum), in
-    nats."""
+    nats. With ``terms`` also what the picks were computed from:
+    ``router_in`` (B*T, D) the rows the router read (its weights are
+    ``params["blocks"]["router"]``)."""
     if not cfg.n_experts:
         raise MoEConfigError("moe_routing_stats: a dense config")
     k, r = cfg.n_experts_per_tok, cfg.router
@@ -2181,6 +2366,8 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig):
                 S, mlp_in.shape[-1], mlp_in.dtype), S * k),
             "entropy": -jnp.mean(jnp.sum(
                 probs * jnp.log(jnp.maximum(probs, 1e-30)), -1))}
+        if terms:
+            stats["router_in"] = mlp_in.reshape(S, -1)
         h, _ = _block(h, layer_params, cfg, None, kind=kind)
         return h, stats
 
@@ -2280,10 +2467,55 @@ def mla_terms(params, tokens, cfg: TransformerConfig):
             "q": q, "k": k, "v": v}
 
 
-def aux_weights(aux_weight=0.01):
-    """Weights of ``encode``'s aux (2,): the balance loss takes the
-    caller's ``aux_weight``, the router z-loss ``Z_LOSS_WEIGHT``."""
-    return jnp.array([aux_weight, Z_LOSS_WEIGHT], jnp.float32)
+def dsa_stats(params, tokens, cfg: TransformerConfig, terms=False):
+    """What the indexers of a model with learned sparse attention do with
+    ``tokens`` (B, T): the forward pass of the step itself (``_dsa_parts``,
+    the function ``_block`` runs), layer by layer, for counters and checks
+    (no mesh). Leading axis: the "dsa" layers, in order. ``kept`` (B,) the
+    (query, key) pairs a sequence keeps, ``causal`` the pairs it has (T (T +
+    1) / 2: the kept share is 100 % while ``top_k`` >= T), ``loss`` the
+    layer's L_I. With ``terms`` also what the selection was computed from
+    and what it gave: ``qI`` (B, T, J * c), ``kI`` (B, T, c) and ``w`` (B, T,
+    J) the indexer's rotated queries, key and weights, ``by_query`` (B, T,
+    W) the kept set's packed mask (``flash_attention.pack_row_mask``)."""
+    from ..kernels import dsa
+    if "dsa" not in cfg.layer_types:
+        raise ValueError("dsa_stats: no dsa layer among "
+                         f"{layer_kinds(cfg)}")
+    T = tokens.shape[1]
+
+    def body(h, layer_params, kind):
+        x = _norm(h, layer_params["ln1_scale"], layer_params["ln1_bias"],
+                  cfg)
+        _, loss, kept = _dsa_parts(x, layer_params, cfg, None)
+        stats = {"kept": kept, "causal": jnp.asarray(T * (T + 1) // 2),
+                 "loss": loss}
+        if terms:
+            qI, kI, w = _dsa_index(x, layer_params, cfg)
+            stats.update(qI=qI, kI=kI, w=w, by_query=dsa.select(
+                qI, kI, w, cfg.dsa.top_k)[0][0])
+        h, _ = _block(h, layer_params, cfg, None, kind=kind)
+        return h, stats
+
+    h, stats = embed_tokens(params, tokens, cfg), []
+    for (kind, _), blocks in zip(layer_runs(cfg),
+                                 run_blocks(cfg, params["blocks"])):
+        if mixer_of(kind) == "dsa":
+            h, of_run = jax.lax.scan(
+                functools.partial(body, kind=kind), h, blocks)
+            stats.append(of_run)
+        else:
+            h = _through_run(h, blocks, cfg, kind)
+    return stats[0] if len(stats) == 1 else jax.tree.map(
+        lambda *runs: jnp.concatenate(runs), *stats)
+
+
+def aux_weights(aux_weight=0.01, size=2):
+    """Weights of ``encode``'s aux (``size``,): the balance loss takes the
+    caller's ``aux_weight``, the router z-loss ``Z_LOSS_WEIGHT``, the
+    indexers' loss (``_aux_size``: a third entry) ``DSA_LOSS_WEIGHT``."""
+    return jnp.array([aux_weight, Z_LOSS_WEIGHT, DSA_LOSS_WEIGHT][:size],
+                     jnp.float32)
 
 
 def _fused_head_nll(params, h, targets, cfg: TransformerConfig):
@@ -2337,7 +2569,7 @@ def _exit_loss(params, exits, aux, targets, cfg: TransformerConfig, mesh,
                 logp, jnp.tile(targets, (n, 1))[..., None], -1)
         nll = nll.reshape(n, B, T)
         per = jnp.sum(q * nll + EXIT_ENTROPY_WEIGHT * q * log_q, 0)
-        loss = jnp.mean(per) + aux_weights(aux_weight) @ aux
+        loss = jnp.mean(per) + aux_weights(aux_weight, aux.size) @ aux
     return loss, {"nll": nll, "q": q, "log_q": log_q, "exits": exits}
 
 
@@ -2375,7 +2607,10 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
             aux_weight=0.01, dropout_rng=None):
     """Next-token cross-entropy + ``aux_weight`` x the MoE balance loss +
     ``Z_LOSS_WEIGHT`` x the router z-loss, both summed over layers (zero
-    for dense blocks). A looped model (``cfg.n_loops > 1``) takes the
+    for dense blocks), + ``DSA_LOSS_WEIGHT`` x the indexers' loss of the
+    "dsa" layers, summed over layers (``_dsa_parts``: ONE scalar, whose
+    gradient gives the trunk's leaves the first three terms' and the
+    indexers' leaves the last's alone). A looped model (``cfg.n_loops > 1``) takes the
     expected loss over its exits instead (``exit_loss_terms``)."""
     from ..kernels.fused_ce import should_fuse
     if cfg.n_loops > 1:
@@ -2387,9 +2622,9 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
         if not cfg.post_ln:
             h = _norm(h, params["lnf_scale"], params["lnf_bias"], cfg)
         per = _fused_head_nll(params, h, targets, cfg)
-        return jnp.mean(per) + aux_weights(aux_weight) @ aux
+        return jnp.mean(per) + aux_weights(aux_weight, aux.size) @ aux
     logits, aux = forward(params, tokens, cfg, mesh, dropout_rng=dropout_rng)
-    return nll_loss(logits, targets) + aux_weights(aux_weight) @ aux
+    return nll_loss(logits, targets) + aux_weights(aux_weight, aux.size) @ aux
 
 
 # ---------------------------------------------------------------------------

@@ -74,8 +74,8 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
     if tfm.mixer_of(kind) != "attention":
         raise NotImplementedError(
             f"layer kind {kind!r}: a stage's body is the attention block; "
-            "latent attention (mla), mamba and conv mixers have no stage "
-            "rule")
+            "latent attention (mla), learned sparse attention (dsa: a loss "
+            "of its own a layer), mamba and conv mixers have no stage rule")
 
     def stage_fn(h, stage_blocks, stage, rng_mb):
         block = functools.partial(tfm._block, cfg=cfg, mesh=None)

@@ -133,6 +133,21 @@ SCOPE_MLA_KV_DOWN = "hetu_mla_kv_down"  # Wkv_a, the latent's RMSNorm, the
 SCOPE_MLA_KV_UP = "hetu_mla_kv_up"      # Wkv_b and the assembly of k: every
                                         # head's k_nope beside the one k_rope
 MLA_SCOPES = (SCOPE_MLA_Q, SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP)
+# learned sparse attention (transformer._dsa; kernels/dsa.py): the indexer
+# beside a grouped-query layer's own projections. The innermost of the four
+# names an op (benchmark/reduce/dsa.py): the index scores run once for the
+# selection and once more inside the loss, and both are SCOPE_DSA_SCORES.
+# The masked attention itself stays under SCOPE_BLK_ATTN
+SCOPE_DSA_PROJ = "hetu_dsa_index_proj"      # Wq_idx, Wk_idx and its
+                                            # LayerNorm, Ww_idx, RoPE
+SCOPE_DSA_SCORES = "hetu_dsa_index_scores"  # I = sum_j w_j ReLU(qI_j . kI)
+SCOPE_DSA_SELECT = "hetu_dsa_select"        # the k-th largest of a row, the
+                                            # kept set, its packed masks
+SCOPE_DSA_LOSS = "hetu_dsa_loss"    # the head-summed probabilities rebuilt
+                                    # from q, k and lse, the KL against
+                                    # softmax(I), and its gradient on I
+DSA_SCOPES = (SCOPE_DSA_PROJ, SCOPE_DSA_SCORES, SCOPE_DSA_SELECT,
+              SCOPE_DSA_LOSS)
 # the two outside the block
 SCOPE_EMBED = "hetu_embed"  # token (position, segment) lookups, BERT's
                             # embedding LayerNorm, the embedding multiplier;

@@ -73,6 +73,9 @@ class Case(NamedTuple):
     dtype: type
     seed: int
     qk_std: float = 0.3
+    rows: Optional[int] = None  # a selection by query row: every query keeps
+                                # itself and up to `rows` - 1 seeded others
+                                # of the keys before it (causal cases)
 
 
 def chosen_case(s, d, causal, bias, dtype, b=2, h=3):
@@ -93,6 +96,7 @@ class Inputs(NamedTuple):
     k_bias: Optional[jax.Array]
     ref: jax.Array      # (b, h, s, dv) float32: the reference's output
     ref_grads: tuple    # its vjp at dO: dq, dk, dv in (b, h, s, .) float32
+    row_mask: Optional[tuple] = None    # the `pack_row_mask` pair
 
 
 # LLVM at -O0 for what the harness compiles: each program runs once at a toy
@@ -108,17 +112,30 @@ def _compiled(fn, *args):
 
 
 @functools.lru_cache(maxsize=None)
-def _reference(causal, has_bias, b, h, s, d, dv):
+def _reference(causal, has_bias, b, h, s, d, dv, has_rows=False):
     """The float32 reference's output and its vjp at dO, compiled once a
     shape: a case in bfloat16 runs the program its float32 twin built."""
-    def out_and_grads(q, k, v, do, k_bias):
+    def out_and_grads(q, k, v, do, k_bias, keep):
         out, vjp = jax.vjp(lambda q, k, v: mha_reference(
-            q, k, v, causal, k_bias=k_bias if has_bias else None), q, k, v)
+            q, k, v, causal, k_bias=k_bias if has_bias else None,
+            keep=keep if has_rows else None), q, k, v)
         return out, vjp(do)
 
     qk, v = (jax.ShapeDtypeStruct((b, h, s, w), jnp.float32) for w in (d, dv))
     return _compiled(out_and_grads, qk, qk, v, v,
-                     jax.ShapeDtypeStruct((b, s), jnp.float32))
+                     jax.ShapeDtypeStruct((b, s), jnp.float32),
+                     jax.ShapeDtypeStruct((b, s, s), jnp.bool_))
+
+
+def kept_rows(rng, b, s, rows):
+    """(b, s, s) bool inside the causal triangle: query t keeps itself and
+    the `rows` - 1 keys before it of largest seeded score (all while t <
+    `rows`), so no two queries' sets need be alike."""
+    score = rng.rand(b, s, s)
+    score[:, np.arange(s), np.arange(s)] = 2.0
+    score = np.where(np.tril(np.ones((s, s), bool)), score, -1.0)
+    kth = -np.sort(-score, axis=-1)[..., min(rows, s) - 1:min(rows, s)]
+    return jnp.asarray((score >= kth) & (score >= 0))
 
 
 @functools.lru_cache(maxsize=None)
@@ -136,12 +153,15 @@ def make(case: Case) -> Inputs:
         k_bias = padding_bias(rng, b, s)
         if case.bias == "row":
             k_bias = k_bias.at[1].set(-1e9)
+    keep = kept_rows(rng, b, s, case.rows) if case.rows else None
     given = tuple(x.astype(case.dtype) for x in (q, k, v, do))
     ref, ref_grads = _reference(case.causal, k_bias is not None, b, h, s,
-                                case.d, case.dv)(
+                                case.d, case.dv, keep is not None)(
         *(x.astype(jnp.float32) for x in given),
-        jnp.zeros((b, s)) if k_bias is None else k_bias)
-    return Inputs(*(to_rows(x) for x in given), k_bias, ref, ref_grads)
+        jnp.zeros((b, s)) if k_bias is None else k_bias,
+        jnp.ones((b, s, s), bool) if keep is None else keep)
+    return Inputs(*(to_rows(x) for x in given), k_bias, ref, ref_grads,
+                  None if keep is None else fa.pack_row_mask(keep))
 
 
 def _scale(case):
@@ -171,16 +191,18 @@ def _forcing(case, tiles):
 
 @functools.lru_cache(maxsize=None)
 def forward(case, fused, tiles=None):
-    """-> (qkv, out, lse, k_bias), what the backward is given, of
-    `_fwd_pallas` at the blocks of `tiles` (None: the chooser's)."""
+    """-> (qkv, out, lse, k_bias) and, under a selection by row, its packed
+    pair: what the backward is given, of `_fwd_pallas` at the blocks of
+    `tiles` (None: the chooser's)."""
     block_q, block_k = (None, None) if tiles is None else tiles[:2]
     qkv = _qkv(case, fused)
-    k_bias = make(case).k_bias
+    k_bias, row_mask = make(case).k_bias, make(case).row_mask
     with _forcing(case, tiles):
-        out, lse = _compiled(lambda qkv, k_bias: fa._fwd_pallas(
+        out, lse = _compiled(lambda qkv, k_bias, row_mask: fa._fwd_pallas(
             qkv, case.heads, k_bias, _scale(case), case.causal, block_q,
-            block_k, interpret=True), qkv, k_bias)(qkv, k_bias)
-    return qkv, out, lse, k_bias
+            block_k, interpret=True, row_mask=row_mask), qkv, k_bias,
+            row_mask)(qkv, k_bias, row_mask)
+    return (qkv, out, lse, k_bias) + ((row_mask,) if case.rows else ())
 
 
 @functools.lru_cache(maxsize=None)
@@ -208,7 +230,8 @@ def check(case, fused, tol_fwd, tol_bwd, *, tiles=None, kernel=None,
     the reference's gradient, at `tol_bwd`."""
     x = make(case)
     b, h, s = case.batch, case.heads, case.s
-    res = qkv, out, lse, k_bias = forward(case, fused, tiles)
+    res = forward(case, fused, tiles)
+    qkv, out, lse = res[:3]
     assert out.dtype == case.dtype and lse.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(to_heads(out, h), np.float32),
                                np.asarray(x.ref), rtol=tol_fwd, atol=tol_fwd)

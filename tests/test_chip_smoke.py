@@ -56,11 +56,24 @@ def test_flagship_phase_tiny(capsys):
                num_routed_experts=8, first_expert_held=2,
                num_experts_per_tok=2, vocab_size=64,
                max_position_embeddings=32, dtype=jnp.float32)
+    dsa = dict(chip_smoke.DSA_ROW, hidden_size=32, intermediate_size=64,
+               moe_intermediate_size=24, num_attention_heads=4,
+               num_key_value_heads=2, head_dim=16, num_experts=2,
+               num_routed_experts=8, first_expert_held=2,
+               num_experts_per_tok=2, vocab_size=64,
+               max_position_embeddings=32, dtype=jnp.float32,
+               sa_config=dict(indexer_num_heads=4, indexer_head_dim=8,
+                              indexer_num_kv_heads=1, topk=8))
     rec = chip_smoke.phase_flagship(cfg=cfg, batch=4, seq=128, n_pred=8,
                                     steps=3, chip=False, moe=moe, mla=mla,
-                                    mla_batch=2)
+                                    mla_batch=2, dsa=dsa, dsa_batch=2)
     line = _last_json(capsys)
     assert line["phase"] == "flagship"
+    assert (line["dsa"]["heads"], line["dsa"]["kv_heads"],
+            line["dsa"]["head_dim"], line["dsa"]["top_k"]) == (4, 2, 16, 8)
+    assert line["dsa"]["kept_pairs"] == 2 * (36 + 24 * 8)
+    assert line["dsa"]["index_loss"] > 0
+    assert abs(line["dsa"]["loss"] - line["dsa"]["dot_loss"]) < 1e-4
     assert line["mla"]["dropped_picks"] == 0 and line["mla"]["tokens"] == 64
     assert (line["mla"]["qk_dim"], line["mla"]["v_dim"],
             line["mla"]["d_ff_shared"]) == (48, 24, 64)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from hetu_tpu.kernels import dsa
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels import fused_ce as fc
 from hetu_tpu.kernels import rope
@@ -344,6 +345,62 @@ def test_fused_ce_compiles_for_v5e(one_chip, no_compile_cache, monkeypatch,
     names = (fc.FUSED_CE_FWD, fc.FUSED_CE_BWD_DH, fc.FUSED_CE_BWD_DW)
     # "fused_ce_bwd_dw" and "_dh" do not contain "fused_ce_fwd" or each other
     assert set(_count_by_name(calls, names).values()) == {1}, calls
+
+
+def test_flash_compiles_for_v5e_under_a_row_mask_at_keyes_shape(
+        one_chip, no_compile_cache):
+    """keye-vl-2.0-30b-a3b's call: 2 x 16,384 tokens, 32 heads of 128,
+    bfloat16, the kept set a packed pair of (2, 16384, 512) int32 (32 planes
+    of 512): `flash_fwd` reads it by query and `flash_bwd_dqkv` by key, each
+    one Mosaic call; the pair is 64 MiB a sequence."""
+    b, h, s, d = 2, 32, 16384, 128
+    assert fa.mask_planes(s) == 32
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    qkv = (arr((b, s, h * d)),) * 3
+    pair = (arr((b, s, s // 32), jnp.int32),) * 2
+    scale = 1.0 / d ** 0.5
+
+    def fwd_and_grads(qkv, pair, do):
+        out, lse = fa._fwd_pallas(qkv, h, None, scale, True, None, None,
+                                  interpret=False, row_mask=pair)
+        return out, lse, fa._bwd_pallas(
+            (qkv, out, lse, None, pair), do, n_heads=h, scale=scale,
+            causal=True, block_q=None, block_k=None, interpret=False)
+
+    text = jax.jit(fwd_and_grads).lower(
+        qkv, pair, arr((b, s, h * d))).compile().as_text()
+    assert _count_by_name(_kernel_calls(text)) == {
+        fa.FLASH_FWD: 1, fa.FLASH_BWD: 0, fa.FLASH_BWD_DQKV: 1}, text
+    assert 2 * s * (s // 32) * 4 == 64 << 20
+
+
+def test_dsa_kernels_compile_for_v5e_at_keyes_shape(one_chip,
+                                                    no_compile_cache):
+    """The indexer's three kernels on a block of 512 rows against 16,384
+    keys: 16 index heads of 64 columns, 32 query heads of 128 on 4 k/v
+    heads, bfloat16 operands, the block's first row a scalar in SMEM: one
+    Mosaic call each under its name."""
+    R, T = dsa.row_block(16384), 16384
+    assert R == 512
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def all_three(qI, w, kI, first, d_scores, q, k, lse):
+        return (dsa._index_scores_pallas(qI, w, kI, first),
+                dsa._index_bwd_pallas(qI, w, kI, first, d_scores),
+                dsa._head_probs_pallas(q, k, lse, 128 ** -0.5, first))
+
+    text = jax.jit(all_three).lower(
+        arr((R, 16 * 64)), arr((R, 16), jnp.float32), arr((T, 64)),
+        arr((), jnp.int32), arr((R, T), jnp.float32), arr((R, 32 * 128)),
+        arr((T, 4 * 128)), arr((R, 32), jnp.float32)).compile().as_text()
+    names = (dsa.DSA_INDEX, dsa.DSA_INDEX_BWD, dsa.DSA_PROBS)
+    assert _count_by_name(_kernel_calls(text), names) == dict.fromkeys(
+        names, 1), text
 
 
 def test_rope_pairs_compiles_for_v5e_at_kananas_q(one_chip, no_compile_cache,
