@@ -268,18 +268,47 @@ def test_choose_tiles(s, d, causal, dtype):
             assert (*blocks, group) == floor or count <= (
                 fa._VMEM_BUDGET if asked is None else fa._VMEM_BUDGET_ASKED)
         if fa.FLASH_BWD not in groups:
-            # the forward's blocks: what the count they were measured under
-            # fits (`_blocks_bytes`), which is never under the forward's own
-            group = groups[fa.FLASH_FWD]
-            by_blocks = fa._blocks_bytes(s, d, itemsize, block_q, block_k,
-                                         group, d)
-            assert by_blocks >= fa._vmem_bytes(s, d, itemsize, block_q,
-                                               block_k, group, fa.FLASH_FWD)
-            assert (block_q, block_k, group) == floor or (
-                by_blocks <= fa._VMEM_BUDGET_ASKED)
+            # the forward's blocks by the forward's OWN count (PR 46; the
+            # loop above held it to its budget): no larger tile of `_picks`
+            # fits where this one was taken, at any head group
+            budget = (fa._VMEM_BUDGET if fa._asking(
+                fa.FLASH_FWD, s, d, d, dtype, block_q, block_k,
+                groups[fa.FLASH_FWD]) == {} else fa._VMEM_BUDGET_ASKED)
+            assert all(fa._vmem_bytes(s, d, itemsize, *p, g, fa.FLASH_FWD)
+                       > budget for p in fa._picks(s, None, None)
+                       if p[0] * p[1] > block_q * block_k
+                       for g in fa._head_groups(heads, d))
     # blocks the caller passes are kept, and still get a head group
     assert fa._choose_tiles(s, d, dtype, causal, 12, 64, 32)[:2] == (
         min(64, s), min(32, s))
+
+
+@pytest.mark.parametrize("dtype,s,d,dv,heads,blocks,asked", [
+    # the one family the forward's own count would have sent to a SMALLER
+    # tile than the count of PR 41's pair did: 128 x 512 fits unasked (0.903
+    # ms a call alone on the chip, PR 46), 512 x 512 asks and ran in 0.574
+    (jnp.float32, 2048, 192, 128, 8, (512, 512), True),
+    (jnp.bfloat16, 3072, 192, 128, 3, (512, 512), True),
+    # half the largest side is still taken unasked
+    (jnp.bfloat16, 4096, 192, 128, 16, (256, 256), False),
+    (jnp.bfloat16, 4096, 256, 256, 8, (512, 256), False),
+    # a sequence whose largest pick is 128 or 256 a side keeps its 128
+    (jnp.float32, 384, 128, 128, 4, (128, 128), False),
+    (jnp.float32, 1280, 512, 512, 4, (128, 256), False),
+])
+def test_many_tile_forward_takes_no_quarter_side_unasked(dtype, s, d, dv,
+                                                         heads, blocks,
+                                                         asked):
+    """A many-tile call's forward blocks by the forward's own count: a pick
+    whose shorter side is under half the largest pick's is not taken for
+    fitting what Mosaic gives unasked; the larger tile that asks is."""
+    from hetu_tpu.kernels import flash_attention as fa
+    block_q, block_k, groups = fa._choose_tiles(s, d, dtype, True, heads,
+                                                dv=dv)
+    assert (block_q, block_k) == blocks
+    limit = fa._vmem_limit(fa.FLASH_FWD, s, d, dv, dtype, block_q, block_k,
+                           groups[fa.FLASH_FWD])
+    assert limit == (fa._VMEM_LIMITS[0] if asked else None)
 
 
 @pytest.mark.parametrize("heads,d,groups", [
@@ -366,10 +395,10 @@ def test_flash_mxu_operands_follow_the_caller(dtype, causal, bias, s, n_dots):
     pytest.param(4096, 128, 16, True, (512, 512),
                  {"flash_fwd": 1, "flash_bwd_dqkv": 1},
                  id="ouro-2.6b.pretrain-seq4096-b1"),
-    pytest.param(8192, 64, 32, True, (256, 256),
+    pytest.param(8192, 64, 32, True, (512, 512),
                  {"flash_fwd": 2, "flash_bwd_dqkv": 2},
                  id="granite-4.0-h-micro.pretrain-seq8192-b1"),
-    pytest.param(8192, 64, 32, True, (256, 256),
+    pytest.param(8192, 64, 32, True, (512, 512),
                  {"flash_fwd": 2, "flash_bwd_dqkv": 2},
                  id="lfm2-8b-a1b.pretrain-seq8192-ep4load"),
     # one tile at head size 128: whole lane tiles, no cap on the forward
@@ -382,9 +411,10 @@ def test_choose_tiles_at_the_cells_shapes(s, d, heads, causal, blocks,
                                           groups):
     """What the per-kernel rule picks at the shapes the cells run: the
     one-tile BERT shapes as the chip had them best (PR 33), the many-tile
-    decoders' forward exactly what it had before it; their one backward
-    kernel takes the forward's heads and 512 x 512 blocks whatever the
-    forward's, under VMEM it asks for (PR 41)."""
+    decoders' forward the largest tile its own count fits (PR 46: 512 x 512
+    at two heads of 64 over 8,192 keys too, 11.7 MiB); their one backward
+    kernel takes the forward's heads and 512 x 512 blocks of its own, under
+    VMEM it asks for (PR 41)."""
     from hetu_tpu.kernels import flash_attention as fa
     assert fa._choose_tiles(s, d, jnp.bfloat16, causal, heads) == (
         *blocks, groups)

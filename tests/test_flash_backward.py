@@ -40,7 +40,9 @@ def test_pallas_backward_kernels_with_bias(causal):
     (4, 64, 64, 512, 64, 128, 4, False),       # block_q != block_k
     (2, 192, 128, 256, 128, 128, 2, False),    # latent attention's widths
     (2, 128, 128, 2048, 128, 128, 1, True),    # sixteen key blocks
-    (2, 128, 128, 2048, 128, 128, 2, False)],
+    (2, 128, 128, 2048, 128, 128, 2, False),
+    # the forward's tiles at heads of 64 since PR 46: 512 x 512, heads paired
+    (2, 64, 64, 1024, 512, 512, 2, False)],
     ids=lambda x: str(x))
 def test_many_tile_backward_is_one_kernel(h, d, dv, s, block_q, block_k,
                                           group, fused, causal, bias, dtype,

@@ -248,17 +248,27 @@ def test_one_backward_kernel_fits_what_it_counts(one_chip, no_compile_cache,
 
 # The FORWARD kernel of a many-tile call is what it was before `flash_bwd`
 # (PR 33) and before `flash_bwd_dqkv` (PR 41): the lowered forward of the
-# three decoders' calls, the Mosaic module inside read back as text WITHOUT
-# its locations (a line number of this repo's files is in every one). The
-# forward's digests were made on 2679af4, PR 41's parent, and hold on PR 41's
-# tree; the backward's, one Mosaic module where the pair was two, on PR 41's
-# tree, and change only with the kernel or with what chooses its blocks.
+# decoders' calls, the Mosaic module inside read back as text WITHOUT its
+# locations (a line number of this repo's files is in every one). OLMoE's and
+# Ouro's forward digests were made on 2679af4, PR 41's parent, and hold since;
+# the backward's, one Mosaic module where the pair was two, on PR 41's tree,
+# and change only with the kernel or with what chooses its blocks. Heads of 64
+# at 8,192 keys take 512 x 512 forward tiles since PR 46 (the forward's own
+# VMEM count): Granite's and lfm2's forward digests are of that tree (62f352d,
+# its parent, reads b977b04d7446a74f and f7bb1d8146b9cc2a); their backward
+# digests, and both of kanana's and of keye's masked call, read the same on
+# 62f352d and on PR 46's tree: those cells' kernels are the parent's.
 _BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
 MANY_TILE = {
-    "olmoe-1b-7b": ((8, 16, 4096, 128), "def97c0e0b7be1c8", "a81a4518f460617a"),
-    "ouro-2.6b": ((1, 16, 4096, 128), "a882d7247355e90b", "96ec2fdeafd0ad37"),
-    "granite-4.0-h-micro": ((1, 32, 8192, 64), "b977b04d7446a74f", "5d5c44bf5ea770e4"),
+    "olmoe-1b-7b": ("def97c0e0b7be1c8", "a81a4518f460617a"),
+    "ouro-2.6b": ("a882d7247355e90b", "96ec2fdeafd0ad37"),
+    "granite-4.0-h-micro": ("fc93facf10f45c07", "5d5c44bf5ea770e4"),
+    "lfm2-8b-a1b": ("51ac672fb96f2d70", "aab8b6895a095154"),
+    "kanana-2-30b-a3b": ("950277e43efc72e2", "17d9d5847e06a17e"),
+    "keye-vl-2.0-30b-a3b": ("7a8ee9c33115df00", "bef6e18c3f1b3b2a"),
 }
+# keye's call (`DECODERS` has the other five) comes under a packed row mask
+KEYE = {"keye-vl-2.0-30b-a3b": (2, 32, 16384, 128, 128)}
 
 
 def _lowered_without_locations(text):
@@ -274,41 +284,46 @@ def _lowered_without_locations(text):
     return _BODY.sub("BODY", text) + "\n".join(kernels), len(kernels)
 
 
-def _many_tile_halves(one_chip, shape):
+def _many_tile_halves(one_chip, shape, masked=False):
     """-> (forward, its arguments), (backward, its arguments) of a causal
-    bf16 call on three arrays, each a program of ONE kernel."""
-    b, h, s, d = shape
+    bf16 call on three arrays, each a program of ONE kernel; `masked`: under
+    a packed row mask."""
+    b, h, s, d, dv = shape
 
     def arr(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    qkv = (arr(b, s, h * d),) * 3
+    qkv = (arr(b, s, h * d), arr(b, s, h * d), arr(b, s, h * dv))
+    pair = ((arr(b, s, s // fa.mask_planes(s), dtype=jnp.int32),) * 2
+            if masked else None)
     scale = 1.0 / d ** 0.5
 
-    def forward(qkv):
+    def forward(qkv, pair):
         return fa._fwd_pallas(qkv, h, None, scale, True, None, None,
-                              interpret=False)
+                              interpret=False, row_mask=pair)
 
-    def backward(qkv, out, lse, do):
-        return fa._bwd_pallas((qkv, out, lse, None), do, n_heads=h,
-                              scale=scale, causal=True, block_q=None,
-                              block_k=None, interpret=False)
+    def backward(qkv, out, lse, do, pair):
+        return fa._bwd_pallas(
+            (qkv, out, lse, None) + ((pair,) if masked else ()), do,
+            n_heads=h, scale=scale, causal=True, block_q=None, block_k=None,
+            interpret=False)
 
-    return (forward, (qkv,)), (backward, (
-        qkv, arr(b, s, h * d), arr(b * h, 1, s, dtype=jnp.float32),
-        arr(b, s, h * d)))
+    return (forward, (qkv, pair)), (backward, (
+        qkv, arr(b, s, h * dv), arr(b * h, 1, s, dtype=jnp.float32),
+        arr(b, s, h * dv), pair))
 
 
 @pytest.mark.parametrize("half", ["forward", "backward"])
 @pytest.mark.parametrize("cell", sorted(MANY_TILE))
 def test_many_tile_kernels_lower_to_what_they_were(one_chip, cell, half):
-    shape, *digests = MANY_TILE[cell]
     at = ("forward", "backward").index(half)
-    fn, args = _many_tile_halves(one_chip, shape)[at]
+    fn, args = _many_tile_halves(one_chip, {**DECODERS, **KEYE}[cell],
+                                 cell in KEYE)[at]
     text, kernels = _lowered_without_locations(
         jax.jit(fn).lower(*args).as_text())
     assert kernels == 1
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digests[at]
+    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert digest == MANY_TILE[cell][at]
 
 
 # (rows, vocabulary, width) of the calls the benchmark's cells make, bf16
