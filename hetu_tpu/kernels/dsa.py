@@ -33,9 +33,14 @@ The loss's target needs a[t, h, s] summed over the heads, which no flash
 kernel returns: it is rebuilt from q, k and the kernel's saved row statistic
 ``lse`` (exp(q . k * scale - lse)), a block of rows at a time, and its
 gradient on I, (softmax(I) - p) / rows, goes back through the index scores to
-qI, kI and w IN THE SAME PASS (``indexer_loss`` is a ``custom_vjp`` whose
-forward rule returns them as its residuals): a (T, T, H) array never exists
-and the backward pass of the loss is three multiplications.
+qI, kI and w and on through the indexer's projections to its LEAVES, all in
+the forward pass: ``indexer_loss`` is a ``custom_vjp`` around the indexer as a
+whole (it reads the layer's input detached, so its gradient ends at its own
+weights), whose forward rule returns the leaves' gradients as its residuals
+under one name (``tracing.REMAT_DSA_GRADS``) and whose backward rule is ``g *``
+each. A (T, T, H) array never exists, and a trunk under ``jax.checkpoint``
+that keeps the name (a few MiB a layer) runs the chain once a step: its
+recomputation holds nothing of the loss.
 """
 from __future__ import annotations
 
@@ -43,10 +48,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..telemetry.tracing import SCOPE_DSA_SCORES
+from ..telemetry.tracing import (REMAT_DSA_GRADS, SCOPE_DSA_PROJ,
+                                 SCOPE_DSA_SCORES)
 from . import flash_attention as fa
 
 # query rows a block: a (ROWS, T) float32 array is 32 MiB at T = 16,384
@@ -447,28 +454,41 @@ def _loss_and_grads(qI, kI, w, q, k, lse, by_query, n_heads, scale, grads):
                                   (dk * rows).astype(kI.dtype), dw * rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def indexer_loss(qI, kI, w, q, k, lse, by_query, n_heads, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 7, 8))
+def indexer_loss(index, leaves, x, q, k, lse, by_query, n_heads, scale):
     """L_I, a float32 scalar: the mean over all B * T queries of KL(p ||
     softmax of the index scores over the kept keys), p the mean over the
     ``n_heads`` heads of softmax(q . k * ``scale``) over the same keys,
     rebuilt from ``q`` (B, T, H * d), ``k`` (B, T, G * d) at the k/v heads
-    and the attention's row statistic ``lse`` (B * H, 1, T). Its gradient reaches ``qI``, ``kI`` and ``w``
-    alone: q, k and lse are the TARGET, detached, and ``by_query`` (the first
-    array of ``select``'s pair) a constant."""
+    and the attention's row statistic ``lse`` (B * H, 1, T). The index scores
+    are those of ``index(x, leaves)`` -> (qI, kI, w) as ``select`` takes them:
+    the indexer's projections of the layer's input ``x``. Its gradient
+    reaches ``leaves`` (any tree) alone: x is read DETACHED, q, k and lse are
+    the TARGET, detached, and ``by_query`` (the first array of ``select``'s
+    pair) a constant. The gradient is made where the loss is, in the forward
+    rule, and kept under ``tracing.REMAT_DSA_GRADS``."""
+    with jax.named_scope(SCOPE_DSA_PROJ):
+        qI, kI, w = index(x, leaves)
     return _loss_and_grads(qI, kI, w, q, k, lse, by_query, n_heads, scale,
                            grads=False)[0]
 
 
-def _indexer_loss_fwd(qI, kI, w, q, k, lse, by_query, n_heads, scale):
-    return _loss_and_grads(qI, kI, w, q, k, lse, by_query, n_heads, scale,
-                           grads=True)
+def _indexer_loss_fwd(index, leaves, x, q, k, lse, by_query, n_heads, scale):
+    # the projections' scope around both halves of their vjp: the
+    # pull-back's ops are the projections' too, wherever they run
+    with jax.named_scope(SCOPE_DSA_PROJ):
+        (qI, kI, w), to_leaves = jax.vjp(functools.partial(index, x), leaves)
+    loss, cotangents = _loss_and_grads(qI, kI, w, q, k, lse, by_query,
+                                       n_heads, scale, grads=True)
+    with jax.named_scope(SCOPE_DSA_PROJ):
+        grads, = to_leaves(cotangents)
+    return loss, jax.tree.map(
+        lambda g: checkpoint_name(g, REMAT_DSA_GRADS), grads)
 
 
-def _indexer_loss_bwd(n_heads, scale, grads, g):
-    dq, dk, dw = grads
-    return ((g * dq).astype(dq.dtype), (g * dk).astype(dk.dtype), g * dw,
-            None, None, None, None)
+def _indexer_loss_bwd(index, n_heads, scale, grads, g):
+    return (jax.tree.map(lambda d: (g * d).astype(d.dtype), grads),
+            None, None, None, None, None)
 
 
 indexer_loss.defvjp(_indexer_loss_fwd, _indexer_loss_bwd)

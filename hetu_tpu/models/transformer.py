@@ -39,8 +39,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  REMAT_ATTN_V, REMAT_CANDIDATES,
-                                 REMAT_MLA_LATENT, REMAT_NORM1_IN,
-                                 REMAT_NORM2_IN, REMAT_X1, REMAT_X2,
+                                 REMAT_DSA_GRADS, REMAT_MLA_LATENT,
+                                 REMAT_NORM1_IN, REMAT_NORM2_IN, REMAT_X1,
+                                 REMAT_X2,
                                  SCOPE_BLK_ATTN, SCOPE_BLK_MLP_DOWN,
                                  SCOPE_BLK_MLP_UP, SCOPE_BLK_NORM,
                                  SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_DSA_LOSS,
@@ -1128,7 +1129,9 @@ def _dsa_parts(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     selection is exact and has no gradient, so the trunk's gradients are
     those of attention under a constant mask; the indexer reads the layer's
     input detached and learns from L_I alone, whose target, the attention's
-    head-summed probabilities, is detached too. The flash kernels take the
+    head-summed probabilities, is detached too: L_I's gradient ends at the
+    indexer's leaves and is made with it, in the forward pass
+    (``dsa.indexer_loss``). The flash kernels take the
     kept set one bit a pair and compute every tile below the diagonal (a
     dense kernel under a mask); off the chip the ``dot`` path adds the same
     mask as a bias. With ``top_k`` >= T the kept set is the causal triangle
@@ -1178,8 +1181,13 @@ def _dsa_parts(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
         # query group, side by side
         k_kv = k.reshape(B, T, cfg.kv_heads, -1, hd)[:, :, :, 0].reshape(
             B, T, -1)
-        loss = dsa.indexer_loss(qI, kI, w, stop(q), stop(k_kv), stop(lse),
-                                row_mask[0], nh, 1.0 / np.sqrt(hd))
+        # the indexer again, as a whole (one computation with the
+        # selection's to the compiler): the loss's rule makes its leaves'
+        # gradient where it makes the loss
+        loss = dsa.indexer_loss(
+            functools.partial(_dsa_index, cfg=cfg),
+            {name: p[name] for name in DSA_LEAVES}, h, stop(q), stop(k_kv),
+            stop(lse), row_mask[0], nh, 1.0 / np.sqrt(hd))
     with jax.named_scope(SCOPE_BLK_WO):
         out = jnp.einsum("btd,de->bte", out, p["wo"].astype(h.dtype),
                          preferred_element_type=jnp.float32).astype(h.dtype)
@@ -2164,7 +2172,14 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     nothing and adds no name; of the others a later one never gets in
     without the earlier ones. The order of the last two is their rank by ms
     of the step saved a GiB kept, each measured alone on the v5e
-    (``tracing.REMAT_CANDIDATES``; PERF.md, PR 36)."""
+    (``tracing.REMAT_CANDIDATES``; PERF.md, PR 36).
+
+    A stack with "dsa" layers keeps ``REMAT_DSA_GRADS`` first and whatever
+    the budget reads, a negative one too: the name is on the residuals of the
+    indexer's loss, the gradient of its five leaves (their own bytes a layer,
+    counted in the bytes held), and a residual is held either way: unnamed,
+    the recomputation makes it again, the loss and all. Keeping it costs
+    nothing of what the budget counts."""
     if bytes_limit is None:
         bytes_limit = _device_bytes_limit()
     if bytes_limit is None:
@@ -2212,6 +2227,12 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     order = (REMAT_CANDIDATES[:2] + ((REMAT_MLA_LATENT,),)
              + REMAT_CANDIDATES[2:])
     names, held = (), 0
+    for kind, n in layer_runs(cfg):
+        if mixer_of(kind) == "dsa":
+            names = (REMAT_DSA_GRADS,)
+            held += cfg.n_loops * n * sum(
+                leaf.size // leaf.shape[0] * leaf.dtype.itemsize
+                for leaf in map(by_kind[kind].get, DSA_LEAVES))
     for candidate, cost in zip(order, costs):
         if not cost:
             continue

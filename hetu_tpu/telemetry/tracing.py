@@ -198,6 +198,19 @@ REMAT_CANDIDATES = ((REMAT_X1, REMAT_X2), (REMAT_ATTN_O, REMAT_ATTN_LSE),
 # k, v (`transformer._remat_names`): by bytes it is the cheapest thing an
 # attention layer of any kind can keep. Not measured alone on the chip
 REMAT_MLA_LATENT = "hetu_mla_latent"
+# learned sparse attention's own (`kernels/dsa.indexer_loss`): the gradient
+# of a layer's L_I on the indexer's five leaves, made in the loss's forward
+# rule. The indexer reads the layer's input detached, so these ARE the
+# leaves' whole gradient up to the loss's weight, and the name is on what
+# would otherwise be the rule's residuals (qI's, kI's and w's cotangents, 70
+# MiB a layer at 2 x 16,384 tokens, where the five leaves are 8.7 MiB in
+# float32). A stack with a dsa layer always keeps it
+# (`transformer._remat_names`: it REPLACES a larger residual, so no budget is
+# asked): the recomputation then runs nothing of the loss. On the v5e, keye's
+# four layers at 2 x 16,384 tokens (PERF.md, PR 48): 34.5 MiB kept,
+# `recompute_ms_per_step` 636.3 -> 428.3, `hetu_dsa_loss` 194.3 -> 101.8 ms a
+# step, `tokens_per_s` +6.2 %, the step's own peak 14.74 -> 14.60 GiB
+REMAT_DSA_GRADS = "hetu_dsa_idx_grads"
 # host spans inside SubExecutor.run, children of STEP, in call order
 (BOUNDARY, FEED, DL_WAIT, PS_PULL, BUILD, DISPATCH, PREFETCH, PS_PUSH,
  POSTSTEP) = STEP_SPANS = (

@@ -28,6 +28,8 @@ from hetu_tpu.models import (generate, hf_deepseek_v3, hf_keye,
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
 
+from test_remat import _sub_jaxprs
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -279,6 +281,69 @@ def test_indexer_learns_from_its_loss_alone_and_the_trunk_not_from_it():
     for w, r, i in zip(*(jax.tree.leaves(t) for t in (whole, rest, index))):
         np.testing.assert_allclose(np.asarray(w), np.asarray(r + i),
                                    atol=1e-7)
+
+
+def _rematted_ops(jaxpr, scope):
+    """Equations under ``scope`` inside a checkpoint's rematted computation,
+    nested jaxprs included."""
+    n = 0
+    for e in jaxpr.eqns:
+        stack = str(e.source_info.name_stack).split("/")
+        n += "rematted_computation" in stack and scope in stack
+        n += sum(_rematted_ops(sub, scope) for sub in _sub_jaxprs(e))
+    return n
+
+
+@pytest.mark.parametrize("remat,limit,loss_again", [
+    pytest.param(False, None, False, id="no-remat"),
+    # the CPU reports no limit: the bare checkpoint runs the chain again
+    pytest.param(True, None, True, id="bare-checkpoint"),
+    # any limit, one that leaves no budget too: the leaves' gradient is kept
+    # by name, the chain runs once
+    pytest.param(True, 4096, False, id="checkpoint-keeps-the-gradient"),
+])
+def test_indexer_loss_and_every_gradient_under_remat(monkeypatch, remat,
+                                                     limit, loss_again):
+    """L_I and the gradient of every leaf, the indexer's five and the
+    trunk's, against AUTODIFF of the loss's own expressions (the rule taken
+    off: ``indexer_loss``'s primal), with and without the trunk's checkpoint;
+    and what the checkpoint runs again: the loss's whole chain when bare,
+    nothing under ``hetu_dsa_loss`` once the name is kept (the selection's
+    projections and index scores it still runs: the flash call's mask)."""
+    cfg = hf_keye.config_from_hf(HF)
+    params = _params(cfg)
+    tokens, targets = _data(HF, 5)
+
+    def value(params, cfg):
+        _, aux = tfm.forward_hidden(params, tokens, cfg)
+        return tfm.loss_fn(params, tokens, targets, cfg), aux[2]
+
+    with monkeypatch.context() as m:
+        m.setattr(dsa, "indexer_loss", dsa.indexer_loss.fun)
+        (_, want_index), want = jax.value_and_grad(value, has_aux=True)(
+            params, cfg)
+    monkeypatch.setattr(tfm, "_device_bytes_limit", lambda: limit)
+    run = dataclasses.replace(cfg, remat=remat)
+    names, held, budget = tfm._remat_names(
+        run, params, tfm.embed_tokens(params, tokens, cfg), None)
+    assert names == ((tracing.REMAT_DSA_GRADS,) if limit else ())
+    assert not limit or budget < 0 < held
+    (_, index), got = jax.value_and_grad(value, has_aux=True)(params, run)
+    np.testing.assert_allclose(float(index), float(want_index), rtol=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        name = path[-1].key
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=1e-4,
+            atol=2e-6 * float(jnp.abs(w).max()), err_msg=name)
+        if name in tfm.DSA_LEAVES:
+            assert float(jnp.abs(w).max()) > 1e-3, name
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: value(p, run)[0]))(params)
+    again = {scope: _rematted_ops(jaxpr.jaxpr, scope)
+             for scope in (tracing.SCOPE_DSA_LOSS, tracing.SCOPE_DSA_SELECT)}
+    assert (again[tracing.SCOPE_DSA_LOSS] > 0) == loss_again, again
+    assert (again[tracing.SCOPE_DSA_SELECT] > 0) == remat, again
 
 
 def test_top_k_of_all_keys_is_the_dense_attention_kind_bit_for_bit():

@@ -12,14 +12,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from hetu_tpu.models import bert, hf_granite, hf_olmoe, hf_ouro
+from hetu_tpu.models import bert, hf_granite, hf_keye, hf_olmoe, hf_ouro
 from hetu_tpu.models import transformer as tfm
 from hetu_tpu.parallel import mesh as meshlib
 from hetu_tpu.telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_LSE,
                                         REMAT_ATTN_O, REMAT_ATTN_Q,
                                         REMAT_ATTN_V, REMAT_CANDIDATES,
-                                        REMAT_NORM1_IN, REMAT_NORM2_IN,
-                                        REMAT_X1, REMAT_X2)
+                                        REMAT_DSA_GRADS, REMAT_NORM1_IN,
+                                        REMAT_NORM2_IN, REMAT_X1, REMAT_X2)
 
 from test_transformer import tiny_cfg
 
@@ -81,6 +81,16 @@ def _granite():
         lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
 
 
+def _keye():
+    """The cell keye-vl-2.0-30b-a3b.pretrain-seq16384-ep8share: four layers
+    of learned sparse attention, an indexer of five leaves each."""
+    cfg = hf_keye.config_from_hf(_published("keye-vl-2.0-30b-a3b"),
+                                 dtype=jnp.bfloat16, attn_impl="flash")
+    assert tfm.layer_runs(cfg) == (("dsa", 4),)
+    return cfg, jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+
+
 def _rule(model, batch, seq, dp, limit_gib, bias=True):
     cfg, params = model()
     mesh = (meshlib.make_mesh(dp=dp, devices=jax.devices()[:dp])
@@ -126,6 +136,18 @@ def _rule(model, batch, seq, dp, limit_gib, bias=True):
     pytest.param(_ouro, 1, 4096, 1, None, (), (0, 0), id="ouro-no-limit"),
     pytest.param(_bert, 128, 512, 1, None, (), (0, 0), id="bert-no-limit"),
     pytest.param(_olmoe, 8, 4096, 1, None, (), (0, 0), id="olmoe-no-limit"),
+    # a dsa stack keeps its indexers' gradient at ANY limit, the v5e's, whose
+    # budget reads negative here, and one of no room at all: four layers x
+    # (2,048 x (1,024 + 64 + 16) + 2 x 64) float32 = 34.5 MiB, and nothing
+    # else until the budget has room: then four x1 of 128 MiB, o + lse (256
+    # + 4) and q, k, v (256 + 2 x 32) a layer behind it
+    pytest.param(_keye, 2, 16384, 1, 15.75, (REMAT_DSA_GRADS,),
+                 (0.03369, 0.0337), id="keye-seq16384-v5e-limit"),
+    pytest.param(_keye, 2, 16384, 1, 1, (REMAT_DSA_GRADS,),
+                 (0.03369, 0.0337), id="keye-seq16384-no-room"),
+    pytest.param(_keye, 2, 16384, 1, 24, (REMAT_DSA_GRADS,) + ALL + QKV,
+                 (2.799, 2.8), id="keye-seq16384-room"),
+    pytest.param(_keye, 2, 16384, 1, None, (), (0, 0), id="keye-no-limit"),
 ])
 def test_remat_names_by_bytes(model, batch, seq, dp, limit_gib, names,
                               held_gib):
@@ -133,6 +155,12 @@ def test_remat_names_by_bytes(model, batch, seq, dp, limit_gib, names,
                               bias=model is _bert)
     assert got == names
     assert held_gib[0] * GiB <= held <= held_gib[1] * GiB
+    if model is _keye:
+        # the one name no budget is asked for: on a full chip it is alone
+        assert REMAT_DSA_GRADS not in sum(REMAT_CANDIDATES, ())
+        assert (budget < 0) == (names == (REMAT_DSA_GRADS,))
+        return
+    assert REMAT_DSA_GRADS not in got
     assert held <= max(budget, 0)
     if model is _olmoe and limit_gib:
         # not by a hair: the block's own residuals put it far under water
