@@ -200,7 +200,7 @@ def _picks(s, block_q, block_k):
 
 
 def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None,
-                  dv=None):
+                  dv=None, window=None):
     """-> (block_q, block_k, {kernel: heads a grid step}) for the kernels
     the call will run (`_kernels_of`), from the call's shapes alone.
 
@@ -226,7 +226,12 @@ def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None,
     step, ran in 20.65 at the 512 x 512 tiles that count admits (11.7 MiB)
     and in 42.87 at 256 x 256, 1 x 32 in 5.16 and 10.71; 1 x 16 heads of 128
     in 2.51 at 512 x 512 and 6.50 at 512 x 128 (docs/KERNELS.md).
-    `dv`: the head width of v and o where it is not q's and k's `d`."""
+    `dv`: the head width of v and o where it is not q's and k's `d`.
+    `window`: a query block of a window call meets `window + block_q` keys
+    at most, and its step's work is counted so; what a step HOLDS is the
+    call's without a window, since k and v still lie whole in VMEM (one copy
+    a head group, fetched once: the loop's bounds, not the blocks, are what
+    the window cuts)."""
     itemsize = jnp.dtype(dtype).itemsize
     dv = d if dv is None else dv
     picks = _picks(s, block_q, block_k)
@@ -242,7 +247,12 @@ def _choose_tiles(s, d, dtype, causal, heads, block_q=None, block_k=None,
     for budget, (bq, bk) in itertools.product(
             (_VMEM_BUDGET, _VMEM_BUDGET_ASKED), picks):
         kernels = _kernels_of(s, bq, bk)
-        step = 2.0 * bq * s * (d + dv) * (0.5 if causal else 1.0)
+        # the keys a query block meets: half of them under the diagonal, or
+        # the window's and the block's own where that is fewer
+        keys = s * (0.5 if causal else 1.0)
+        if window is not None:
+            keys = min(keys, window + bq)
+        step = 2.0 * bq * keys * (d + dv)
         want = next((g for g in groups if g * step >= _STEP_FLOPS),
                     groups[-1])
         if FLASH_BWD not in kernels:
@@ -312,7 +322,7 @@ def _three(qkv):
     return jnp.split(qkv, 3, axis=-1) if _is_fused(qkv) else tuple(qkv)
 
 
-def _tiles_for(qkv, n_heads, causal, block_q, block_k):
+def _tiles_for(qkv, n_heads, causal, block_q, block_k, window=None):
     """-> (qkv, block_q, block_k, {kernel: heads a step}, d, dv): `d` the
     head width of q and k, `dv` of v and o (the same unless three arrays
     say otherwise). Blocks that do not divide the sequence (only ones the
@@ -322,7 +332,7 @@ def _tiles_for(qkv, n_heads, causal, block_q, block_k):
     q = qkv if _is_fused(qkv) else qkv[0]
     s, d, dv = q.shape[1], _width(qkv) // n_heads, _v_width(qkv) // n_heads
     block_q, block_k, heads = _choose_tiles(s, d, q.dtype, causal, n_heads,
-                                            block_q, block_k, dv)
+                                            block_q, block_k, dv, window)
     if s % block_q or s % block_k:
         raise ValueError(f"seq_len {s} must divide blocks ({block_q},{block_k})")
     if _is_fused(qkv) and (heads[FLASH_FWD] * d) % _LANES:
@@ -418,15 +428,17 @@ def _loop(lower, upper, body, init):
     return jax.lax.fori_loop(lower, upper, body, init)
 
 
-def _causal_mask(s, q_start, k_start, keys_first=False):
+def _causal_mask(s, q_start, k_start, keys_first=False, window=None):
     """Mask scores above the diagonal for one tile: (q rows, k columns), or
-    (k rows, q columns) with `keys_first`."""
+    (k rows, q columns) with `keys_first`; with `window`, the scores of keys
+    at or before the query's position less `window` too."""
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    if keys_first:
-        keep = q_start + cols >= k_start + rows
-    else:
-        keep = q_start + rows >= k_start + cols
+    q_pos, k_pos = ((q_start + cols, k_start + rows) if keys_first
+                    else (q_start + rows, k_start + cols))
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep &= k_pos > q_pos - window
     return jnp.where(keep, s, _NEG_INF)
 
 
@@ -435,6 +447,41 @@ def _causal_upper_kb(q_start, block_q, block_k):
     flooring would drop the diagonal block whenever block_q < block_k
     (regression guard: test_flash_causal_uneven_blocks)."""
     return (q_start + block_q + block_k - 1) // block_k
+
+
+def _window_lower_kb(q_start, window, block_k):
+    """First key block that holds a key inside ANY row's window of the query
+    block that starts at `q_start`: its first row's earliest key, q_start -
+    window + 1."""
+    first = q_start - window + 1
+    first = max(first, 0) if isinstance(first, int) else jnp.maximum(first, 0)
+    return first // block_k
+
+
+def _window_upper_qb(k_start, block_k, window, block_q, q_blocks):
+    """One past the last query block with a row that keeps a key of the key
+    block that starts at `k_start`: its last key, k_start + block_k - 1, is
+    kept up to the query `window` - 1 positions on."""
+    last = (k_start + block_k + window - 2) // block_q + 1
+    return (min(last, q_blocks) if isinstance(last, int)
+            else jnp.minimum(last, q_blocks))
+
+
+def window_bounds(s, window, block_q, block_k):
+    """The tiles a causal call of `window` visits, by the bounds its kernels'
+    loops run to: -> (forward [(first, one past the last key block) a query
+    block], backward [(first, one past the last query block) a key block])
+    as `flash_fwd` at (block_q, block_k) and `flash_bwd_dqkv` at the same
+    walk them; `window` None: the causal call's."""
+    q_blocks, key_blocks = s // block_q, s // block_k
+    fwd = [(0 if window is None else
+            _window_lower_kb(i * block_q, window, block_k),
+            min(_causal_upper_kb(i * block_q, block_q, block_k), key_blocks))
+           for i in range(q_blocks)]
+    bwd = [(j * block_k // block_q, q_blocks if window is None else
+            _window_upper_qb(j * block_k, block_k, window, block_q, q_blocks))
+           for j in range(key_blocks)]
+    return fwd, bwd
 
 
 def _optional(kernel, n_before, *present):
@@ -535,7 +582,7 @@ def _kept(mask_ref, start, n):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref, *,
-                scale, causal, block_k, d, dv):
+                scale, causal, block_k, d, dv, window=None):
     # grid: (batch, head groups, q blocks); one q block, the whole k and v
     block_q = q_ref.shape[0]
     q_start = pl.program_id(2) * block_q
@@ -547,6 +594,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref, *,
     key_blocks = k_ref.shape[0] // block_k
     upper = (_causal_upper_kb(q_start, block_q, block_k)
              if causal and key_blocks > 1 else key_blocks)
+    # a window: nor the key blocks wholly before every row's window
+    lower = (_window_lower_kb(q_start, window, block_k)
+             if window is not None and key_blocks > 1 else 0)
 
     for g in range(q_ref.shape[1] // d):
         lanes, v_lanes = _block(g, d), _block(g, dv)
@@ -563,7 +613,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref, *,
                 s = jnp.where(_kept(mask_ref, kj * block_k, block_k), s,
                               _NEG_INF)
             elif causal:
-                s = _causal_mask(s, q_start, kj * block_k)
+                s = _causal_mask(s, q_start, kj * block_k, window=window)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
@@ -571,7 +621,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, mask_ref, o_ref, lse_ref, *,
             acc = acc * alpha + _dot(p.astype(v_blk.dtype), v_blk)
             return acc, m_new, l_new
 
-        acc, m, l = _loop(0, upper, body, (
+        acc, m, l = _loop(lower, upper, body, (
             jnp.zeros((block_q, dv), jnp.float32),
             jnp.full((block_q, 1), _NEG_INF, jnp.float32),
             jnp.zeros((block_q, 1), jnp.float32)))
@@ -619,6 +669,12 @@ def _specs(qkv, heads, d, n_heads, block, dv):
     return cols, stats, bias, mask
 
 
+def _windowed(window):
+    """A kernel's keyword of a call's window: none without one (a call
+    without a window traces the kernel it always did)."""
+    return {} if window is None else {"window": window}
+
+
 def _operands(qkv):
     """q, k and v as the kernels take them: the same array three times
     where they are one, under three index maps (`_specs`)."""
@@ -640,10 +696,10 @@ def _bias_rows(k_bias):
 
 
 def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
-                interpret, row_mask=None):
+                interpret, row_mask=None, window=None):
     """-> o (batch, seq, heads * d), lse (batch * heads, 1, seq)."""
-    qkv, block_q, block_k, heads, d, dv = _tiles_for(qkv, n_heads, causal,
-                                                     block_q, block_k)
+    qkv, block_q, block_k, heads, d, dv = _tiles_for(
+        qkv, n_heads, causal, block_q, block_k, window)
     heads = heads[FLASH_FWD]
     ops = _operands(qkv)
     b, s, _ = ops[0].shape
@@ -653,7 +709,8 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
     cols, stats, bias_spec, mask_spec = _specs(qkv, heads, d, n_heads,
                                                block_q, dv)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_k=block_k, d=d, dv=dv)
+                             block_k=block_k, d=d, dv=dv,
+                             **_windowed(window))
     return pl.pallas_call(
         _optional(kern, 3, use_bias, bool(masks)),
         grid=(b, n_heads // heads, s // block_q),
@@ -691,7 +748,7 @@ def _fwd_pallas(qkv, n_heads, k_bias, scale, causal, block_q, block_k,
 
 def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
                      mask_ref, dq_ref, dv_ref, dk_ref, dq_acc, delta_ref, *,
-                     scale, causal, block_q, d, dv):
+                     scale, causal, block_q, d, dv, window=None):
     # grid: (batch, head groups, k blocks), the last in order; owns one k/v
     # block, loops over q blocks. Tiles are (block_k, block_q): S^T, P^T,
     # dP^T, dS^T, and dQ alone contracts over the rows of dS^T.
@@ -701,6 +758,9 @@ def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
     q_blocks = q_ref.shape[0] // block_q
     # causal: q blocks strictly before this k block contribute nothing
     lower = (k_start // block_q) if causal else 0
+    # a window: nor those whose every row is past this k block's last key
+    upper = (q_blocks if window is None else _window_upper_qb(
+        k_start, block_k, window, block_q, q_blocks))
     heads = k_ref.shape[1] // d
     # this kernel owns ONE k block: its bias column is constant
     bias = None if bias_ref is None else _row_to_col(bias_ref[0])
@@ -736,7 +796,8 @@ def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
                 st = jnp.where(_kept(mask_ref, qi * block_q, block_q), st,
                                _NEG_INF)
             elif causal:
-                st = _causal_mask(st, qi * block_q, k_start, keys_first=True)
+                st = _causal_mask(st, qi * block_q, k_start, keys_first=True,
+                                  window=window)
             pt = jnp.exp(st - lse_ref[g, :, rows])    # (block_k, block_q)
             d_v = d_v + _dot(pt.astype(do.dtype), do)
             dpt = _dot_nt(v_blk, do)
@@ -746,7 +807,7 @@ def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
             return dk, d_v
 
         zeros = jnp.zeros((block_k, d), jnp.float32)
-        dk, d_v = _loop(lower, q_blocks, body, (
+        dk, d_v = _loop(lower, upper, body, (
             zeros, zeros if dv == d else jnp.zeros((block_k, dv),
                                                    jnp.float32)))
         dk_ref[:, lanes] = (dk * scale).astype(dk_ref.dtype)
@@ -762,7 +823,8 @@ def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
-                mask_ref, dq_ref, dv_ref, dk_ref, *, scale, causal, d, dv):
+                mask_ref, dq_ref, dv_ref, dk_ref, *, scale, causal, d, dv,
+                window=None):
     # grid: (batch, head groups, 1); the whole sequence is ONE tile, so
     # nothing is summed over blocks and one rebuilt tile serves dq, dk and
     # dv. The tile is (keys, queries) as in `flash_bwd_dqkv`: lse and delta
@@ -785,7 +847,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
         if mask_ref is not None:
             st = jnp.where(_kept(mask_ref, 0, st.shape[1]), st, _NEG_INF)
         elif causal:
-            st = _causal_mask(st, 0, 0, keys_first=True)
+            st = _causal_mask(st, 0, 0, keys_first=True, window=window)
         pt = jnp.exp(st - lse_ref[g])
         dv_ref[:, v_lanes] = _dot(pt.astype(do.dtype),
                                   do).astype(dv_ref.dtype)
@@ -796,7 +858,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, bias_ref,
 
 
 def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
-                interpret):
+                interpret, window=None):
     """-> dq, dk, dv in the form qkv came in: three arrays where it was
     three; where it was one, one (batch, seq, 3 * heads * d) array, which
     the kernel makes and writes the k columns of, and dq and dv are set
@@ -809,8 +871,8 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
     given, o, lse, k_bias, *row_mask = res
     masks, mask_w = _mask_of(row_mask[0] if row_mask else None, 1)
     given_blocks = block_q, block_k
-    qkv, block_q, block_k, heads, d, dv = _tiles_for(given, n_heads, causal,
-                                                     block_q, block_k)
+    qkv, block_q, block_k, heads, d, dv = _tiles_for(
+        given, n_heads, causal, block_q, block_k, window)
     b, s, width = o.shape
     use_bias = k_bias is not None
     bias = [_bias_rows(k_bias)] if use_bias else []
@@ -832,7 +894,7 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
         cols, stats, bias_spec, mask_spec = _specs(qkv, group, d, n_heads, s,
                                                    dv)
         kern = functools.partial(_bwd_kernel, scale=scale, causal=causal,
-                                 d=d, dv=dv)
+                                 d=d, dv=dv, **_windowed(window))
         dq, d_v, dk = pl.pallas_call(
             _optional(kern, 6, use_bias, bool(masks)),
             grid=(b, n_heads // group, 1),
@@ -849,7 +911,8 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
         cols, stats, bias_spec, mask_spec = _specs(qkv, group, d, n_heads,
                                                    block_k, dv)
         kern = functools.partial(_bwd_dqkv_kernel, scale=scale, causal=causal,
-                                 block_q=block_q, d=d, dv=dv)
+                                 block_q=block_q, d=d, dv=dv,
+                                 **_windowed(window))
         dq, d_v, dk = pl.pallas_call(
             _optional(kern, 6, use_bias, bool(masks)),
             grid=(b, n_heads // group, s // block_k),
@@ -880,7 +943,7 @@ def _bwd_pallas(res, do, *, n_heads, scale, causal, block_q, block_k,
 # (off-TPU fallback and the Pallas backward's numerical oracle)
 # ---------------------------------------------------------------------------
 
-def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k):
+def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k, window=None):
     qkv, o, lse, k_bias, *row_mask = res
     keep = unpack_row_mask(row_mask[0][0]) if row_mask else None
     b, s, _ = o.shape
@@ -910,7 +973,10 @@ def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k):
             s_blk = jnp.where(jax.lax.dynamic_slice_in_dim(
                 keep, ks, block_k, 2)[:, None], s_blk, _NEG_INF)
         elif causal:
-            mask = q_pos[:, None] >= (ks + jnp.arange(block_k))[None, :]
+            k_pos = (ks + jnp.arange(block_k))[None, :]
+            mask = q_pos[:, None] >= k_pos
+            if window is not None:
+                mask &= k_pos > q_pos[:, None] - window
             s_blk = jnp.where(mask, s_blk, _NEG_INF)
         p = jnp.exp(s_blk - lse[..., None])                    # (b,h,s,bk)
         dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p, do_f)
@@ -937,15 +1003,15 @@ def _bwd_blockwise(res, do, *, n_heads, scale, causal, block_k):
 # public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 3, 4, 5, 6))
-def _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 3, 4, 5, 6, 7))
+def _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k, window):
     out, _ = _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q,
-                        block_k)
+                        block_k, window)
     return out
 
 
 def flash_attention_btd(qkv, n_heads, causal=True, scale=None, block_q=None,
-                        block_k=None, k_bias=None, row_mask=None):
+                        block_k=None, k_bias=None, row_mask=None, window=None):
     """Fused attention in the projections' own layout: the kernels' entry.
 
     ``qkv``: one (batch, seq, 3 * heads * head_dim) array, [q | k | v] along
@@ -969,13 +1035,24 @@ def flash_attention_btd(qkv, n_heads, causal=True, scale=None, block_q=None,
     which skips the tiles above the diagonal). Such a call returns (o, lse):
     ``lse`` (batch * heads, 1, seq) float32, the logarithm of each row's
     softmax denominator over its kept keys, for a caller that rebuilds the
-    probabilities; it carries no gradient."""
+    probabilities; it carries no gradient.
+
+    ``window``: optional int W, a causal call's sliding window: query t keeps
+    the keys t - W < s <= t (its own among them). The kernels' loops stop at
+    the window's edge: no tile that lies wholly outside every row's window is
+    computed (`window_bounds`)."""
+    if window is not None and (not causal or row_mask is not None
+                               or window < 1):
+        raise ValueError(
+            f"window={window}: a positive count of keys of a causal call "
+            "without a row mask")
     if not _is_fused(qkv):
         qkv = tuple(qkv)
     if row_mask is not None:
         return _flash_rows(qkv, n_heads, tuple(row_mask), causal, scale,
                            block_q, block_k)
-    return _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k)
+    return _flash(qkv, n_heads, k_bias, causal, scale, block_q, block_k,
+                  window)
 
 
 def flash_attention(q, k, v, causal=True, scale=None, block_q=None,
@@ -996,9 +1073,11 @@ def _scale(qkv, n_heads, scale):
     return 1.0 / math.sqrt(_width(qkv) // n_heads)
 
 
-def _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
+def _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q, block_k,
+               window=None):
     out, lse = _fwd_pallas(qkv, n_heads, k_bias, _scale(qkv, n_heads, scale),
-                           causal, block_q, block_k, interpret=not _on_tpu())
+                           causal, block_q, block_k, interpret=not _on_tpu(),
+                           window=window)
     # named HERE so that the very values a `jax.checkpoint` policy saves are
     # the backward kernels' residuals: a name on the caller's side of the
     # custom_vjp would still re-run this kernel for them
@@ -1007,16 +1086,17 @@ def _flash_fwd(qkv, n_heads, k_bias, causal, scale, block_q, block_k):
     return out, (qkv, out, lse, k_bias)
 
 
-def _flash_bwd(n_heads, causal, scale, block_q, block_k, res, do):
+def _flash_bwd(n_heads, causal, scale, block_q, block_k, window, res, do):
     """`res`: (qkv, o, lse, k_bias) and, under a row mask, the pair."""
     qkv, k_bias = res[0], res[3]
     kw = dict(n_heads=n_heads, scale=_scale(qkv, n_heads, scale),
-              causal=causal)
+              causal=causal, window=window)
     if _on_tpu():
         grads = _bwd_pallas(res, do, block_q=block_q, block_k=block_k,
                             interpret=False, **kw)
     else:
-        block_k = _tiles_for(qkv, n_heads, causal, block_q, block_k)[2]
+        block_k = _tiles_for(qkv, n_heads, causal, block_q, block_k,
+                             window)[2]
         grads = _bwd_blockwise(res, do, block_k=block_k, **kw)
     dbias = None if k_bias is None else jnp.zeros_like(k_bias)
     return grads, dbias
@@ -1043,8 +1123,8 @@ def _flash_rows_fwd(qkv, n_heads, row_mask, causal, scale, block_q, block_k):
 
 
 def _flash_rows_bwd(n_heads, causal, scale, block_q, block_k, res, cts):
-    grads, _ = _flash_bwd(n_heads, causal, scale, block_q, block_k, res,
-                          cts[0])       # lse's cotangent: it has no gradient
+    grads, _ = _flash_bwd(n_heads, causal, scale, block_q, block_k, None,
+                          res, cts[0])  # lse's cotangent: it has no gradient
     return grads, jax.tree.map(
         lambda m: np.zeros(m.shape, jax.dtypes.float0), res[4])
 
@@ -1052,10 +1132,12 @@ def _flash_rows_bwd(n_heads, causal, scale, block_q, block_k, res, cts):
 _flash_rows.defvjp(_flash_rows_fwd, _flash_rows_bwd)
 
 
-def mha_reference(q, k, v, causal=True, scale=None, k_bias=None, keep=None):
+def mha_reference(q, k, v, causal=True, scale=None, k_bias=None, keep=None,
+                  window=None):
     """Unfused reference (the reference framework's BatchMatMul+Softmax
     attention) — used as the numerical oracle in tests. ``keep`` (batch,
-    seq, seq) bool: the keys each query keeps, every head alike."""
+    seq, seq) bool: the keys each query keeps, every head alike. ``window``:
+    query t keeps the keys t - window < s <= t."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -1065,6 +1147,10 @@ def mha_reference(q, k, v, causal=True, scale=None, k_bias=None, keep=None):
         n = q.shape[2]
         mask = jnp.tril(jnp.ones((n, n), bool))
         s = jnp.where(mask, s, _NEG_INF)
+    if window is not None:
+        n = q.shape[2]
+        s = jnp.where(jnp.triu(jnp.ones((n, n), bool), 1 - window), s,
+                      _NEG_INF)
     if keep is not None:
         s = jnp.where(keep[:, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)

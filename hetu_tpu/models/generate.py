@@ -171,6 +171,15 @@ def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
         "latent-attention (mla) layers, whose cache is the 512-wide latent "
         "and one rotary key a token, not k and v (_decode_layer mirrors the "
         "attention block)")
+    assert "window" not in cfg.layer_types and not (
+            cfg.rope_dim or cfg.rope_yarn or cfg.attn_gate), (
+        f"decode has one cache shape a model and no window eviction: "
+        f"layer_types={cfg.layer_types} holds window layers (a cache of the "
+        "last `window` keys beside the full layers' whole one, at another "
+        f"head count), or rope_dim={cfg.rope_dim} / rope_yarn="
+        f"{cfg.rope_yarn} / attn_gate={cfg.attn_gate} (a partial or scaled "
+        "rotary table, a gate on attention's output: _decode_layer mirrors "
+        "none of them)")
     assert "dsa" not in cfg.layer_types and not cfg.d_head, (
         f"decode has no indexer cache: layer_types={cfg.layer_types} holds "
         "learned-sparse-attention (dsa) layers, whose decode step ranks the "

@@ -42,6 +42,7 @@ from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  REMAT_DSA_GRADS, REMAT_MLA_LATENT,
                                  REMAT_NORM1_IN, REMAT_NORM2_IN, REMAT_X1,
                                  REMAT_X2,
+                                 SCOPE_ATTN_GATE, SCOPE_ATTN_ROPE,
                                  SCOPE_BLK_ATTN, SCOPE_BLK_MLP_DOWN,
                                  SCOPE_BLK_MLP_UP, SCOPE_BLK_NORM,
                                  SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_DSA_LOSS,
@@ -56,6 +57,7 @@ from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  SCOPE_SCONV_PROJ, SCOPE_SSD_ENTER,
                                  SCOPE_SSD_INCHUNK, SCOPE_SSD_STATES,
                                  SCOPE_SSM_CONV, SCOPE_SSM_GATE,
+                                 SCOPE_SWA_ATTN,
                                  SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, scoped)
 
 _log = logging.getLogger(__name__)
@@ -133,6 +135,34 @@ class DSAConfig:
     n_heads: int = 16
     head_dim: int = 64
     top_k: int = 2048
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowConfig:
+    """The sizes of a "window" layer, where they are its own and not the
+    model's (``_window``): grouped-query attention in which query t keeps
+    the ``window`` keys t - window < s <= t, with ``n_heads`` query heads
+    (on the model's ``kv_heads``, at its ``head_dim``) and rotate-half RoPE
+    at ``rope_theta`` on ALL of a head's columns, no frequency scaling,
+    whatever ``rope_dim`` and ``rope_yarn`` say of the "attention" layers."""
+    window: int = 512
+    n_heads: int = 64
+    rope_theta: float = 10000.0
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN's frequency scaling (Peng et al. 2023, arXiv:2309.00071;
+    ``transformers`` ``_compute_yarn_parameters``; ``yarn_inv_freq``): of a
+    rotary table's frequencies those that turn fewer than ``beta_slow``
+    times in ``original_max_len`` positions are divided by ``factor``, those
+    that turn more than ``beta_fast`` times stay, a linear ramp between;
+    cos and sin are multiplied by ``attention_factor``."""
+    factor: float = 1.0
+    original_max_len: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -259,7 +289,8 @@ class TransformerConfig:
                                  # ``ln2_post_*``); pre-LN only
     # Hybrid stacks (models/hf_granite.py sets all three):
     layer_types: tuple = ()     # a mixer of ``_KINDS`` a layer ("attention",
-                                # "mamba", "conv", "mla", "dsa"); () =
+                                # "mamba", "conv", "mla", "dsa", "window");
+                                # () =
                                 # ``n_layers``
                                 # of attention. ``encode`` scans each run of
                                 # one kind; ``params["blocks"]`` is the
@@ -292,6 +323,21 @@ class TransformerConfig:
     d_head: int = 0             # columns a head of q, k and v; 0 = ``d_model
                                 # // n_heads`` (``head_dim`` is what is read)
     dsa: Optional[DSAConfig] = None     # the "dsa" layers' indexer
+    # Window and full attention in one stack, each with a head count and a
+    # rotary form of its own, and a gate on attention's output
+    # (models/hf_laguna.py sets all four):
+    window: Optional[WindowConfig] = None   # the "window" layers' sizes;
+                                            # "attention" layers keep
+                                            # ``n_heads`` / ``rope_theta``
+    rope_dim: int = 0           # columns of a head that turn in an
+                                # "attention" layer, its first ones; the
+                                # others pass (HF ``partial_rotary_factor``);
+                                # 0 = ``head_dim``
+    rope_yarn: Optional[YarnConfig] = None  # the "attention" layers' rotary
+                                            # table scaled by YaRN
+    attn_gate: bool = False     # a per-head sigmoid gate (leaf ``wg``, (D,
+                                # heads)) from the layer's normed input on
+                                # attention's output, before ``wo``
 
     def __post_init__(self):
         if self.layer_types:
@@ -317,6 +363,22 @@ class TransformerConfig:
                 f"layer_types={self.layer_types}: a dsa layer takes `dsa` "
                 "sizes, pre-LN, causal attention and RoPE (the indexer "
                 "rotates its queries and keys too)")
+        if "window" in self.layer_types and (
+                self.window is None or self.post_ln or not self.causal
+                or not self.rope or self.window.window < 1
+                or self.window.n_heads % self.kv_heads):
+            raise ValueError(
+                f"layer_types={self.layer_types}: a window layer takes "
+                "`window` sizes (a positive window, a head count that the "
+                "k/v heads divide), pre-LN, causal attention and RoPE")
+        if (self.rope_dim or self.rope_yarn or self.attn_gate) and (
+                {"mla", "dsa"} & set(self.layer_types)
+                or self.rope_dim % 2 or self.rope_dim > self.head_dim):
+            raise ValueError(
+                f"rope_dim={self.rope_dim}, rope_yarn={self.rope_yarn}, "
+                f"attn_gate={self.attn_gate}: of attention and window "
+                "layers (mla and dsa layers have neither), rope_dim an even "
+                "count of a head's columns")
         if self.d_ff_shared and not (self.n_experts
                                      and self.mlp == "swiglu"):
             raise MoEConfigError(
@@ -456,11 +518,16 @@ def _init_attention(ks, cfg: TransformerConfig, n):
     elif cfg.qk_norm:
         p["q_norm"] = jnp.ones((n, cfg.n_heads * cfg.head_dim), jnp.float32)
         p["k_norm"] = jnp.ones((n, cfg.kv_heads * cfg.head_dim), jnp.float32)
+    if cfg.attn_gate:
+        p["wg"] = _init_normal(jax.random.fold_in(ks[11], 3),
+                               (n, D, cfg.n_heads), 0.02)
     return p
 
 
 def _attention_specs(cfg: TransformerConfig):
     p = {"wqkv": P(None, None, "tp"), "wo": P(None, "tp", None)}
+    if cfg.attn_gate:
+        p["wg"] = P(None, None, "tp")       # a head's gate with the head
     if cfg.attn_proj_bias:
         p["bqkv"], p["bo"] = P(None, "tp"), P(None, None)
     if cfg.qk_norm:
@@ -468,6 +535,31 @@ def _attention_specs(cfg: TransformerConfig):
         p["q_norm"] = p["k_norm"] = (P(None, None) if cfg.qk_norm == "head"
                                      else P(None, "tp"))
     return p
+
+
+@functools.lru_cache(maxsize=None)
+def _window_view(cfg: TransformerConfig):
+    """The config a "window" layer's attention reads: the model's, with the
+    window kind's own head count and rotary form (``WindowConfig``) where
+    ``_init_attention``, ``_split_heads`` and ``_flash`` read the
+    "attention" layers'."""
+    w = cfg.window
+    return dataclasses.replace(cfg, n_heads=w.n_heads,
+                               rope_theta=w.rope_theta, rope_dim=0,
+                               rope_yarn=None)
+
+
+def _mixer_view(cfg: TransformerConfig, mixer):
+    """The config the attention of mixer kind ``mixer`` reads."""
+    return _window_view(cfg) if mixer == "window" else cfg
+
+
+def _init_window(ks, cfg: TransformerConfig, n):
+    return _init_attention(ks, _window_view(cfg), n)
+
+
+def _window_specs(cfg: TransformerConfig):
+    return _attention_specs(_window_view(cfg))
 
 
 def _init_mamba(ks, cfg: TransformerConfig, n):
@@ -764,26 +856,65 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
         return _layer_norm(x, scale, bias, cfg.ln_eps)
 
 
-def _rope(x, pos0, theta, hd):
+def yarn_inv_freq(theta, dim, yarn: YarnConfig):
+    """The ``dim`` / 2 inverse frequencies of a rotary table scaled by YaRN,
+    float64 (``transformers`` ``_compute_yarn_parameters``): with f_i =
+    theta^(-2i / dim) and c(n) = dim ln(original_max_len / (2 pi n)) / (2 ln
+    theta), the index of the frequency that turns n times in the original
+    length, low = floor(c(beta_fast)) and high = ceil(c(beta_slow)), clipped
+    to the table (refused where they meet), ramp_i = clip((i - low)
+    / (high - low), 0, 1): inv_i = (1 - ramp_i) f_i + ramp_i f_i / factor."""
+    f = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    c = lambda n: (dim * math.log(yarn.original_max_len / (2 * math.pi * n))
+                   / (2 * math.log(theta)))
+    low = max(math.floor(c(yarn.beta_fast)), 0)
+    high = min(math.ceil(c(yarn.beta_slow)), dim - 1)
+    if low >= high:
+        raise ValueError(f"{yarn} on {dim} columns at theta {theta}: the "
+                         f"ramp's ends are {low} and {high}, no ramp")
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (1.0 - ramp) * f + ramp * f / yarn.factor
+
+
+def _rope(x, pos0, theta, hd, rot=0, yarn=None):
     """Rotary position embeddings, HF rotate_half convention: x (B, T,
     heads*hd), the heads side by side as the projection writes them, at
     absolute positions pos0..pos0+T-1; each head's hd columns split into
     two halves rotated by position-dependent angles. A column's partner
     lies hd/2 columns to its right (first half) or left (second half), in
     the same head, so the halves swap by two rolls of the whole axis and no
-    (B, T, heads, hd) array, which the TPU would lay out anew, is made."""
+    (B, T, heads, hd) array, which the TPU would lay out anew, is made.
+
+    ``rot`` (0 = hd): only a head's FIRST ``rot`` columns turn, rotate-half
+    inside them (partners rot/2 apart), and the others pass (HF
+    ``partial_rotary_factor``): their cos is 1 and their sin 0. ``yarn``:
+    the table's frequencies are ``yarn_inv_freq``'s (made in float64, cast)
+    and cos and sin carry its ``attention_factor``. Tables and rotation are
+    float32 either way; with the defaults this is the program it was."""
     B, T, W = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    rot = rot or hd
+    if yarn is None or yarn.factor == 1.0:      # nothing to scale
+        inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32)
+                               / rot))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(theta, rot, yarn), jnp.float32)
     t = pos0 + jnp.arange(T, dtype=jnp.float32)
-    freqs = jnp.outer(t, inv)                       # (T, hd/2)
+    freqs = jnp.outer(t, inv)                       # (T, rot/2)
     cos, sin = jnp.cos(freqs), jnp.sin(freqs)
-    cos = jnp.tile(jnp.concatenate([cos, cos], -1), W // hd)    # (T, W)
+    if yarn is not None and yarn.attention_factor != 1.0:
+        cos, sin = (c * jnp.float32(yarn.attention_factor)
+                    for c in (cos, sin))
+    still = ([jnp.ones((T, hd - rot), jnp.float32)],
+             [jnp.zeros((T, hd - rot), jnp.float32)]) if rot < hd else ([], [])
+    cos = jnp.tile(jnp.concatenate([cos, cos] + still[0], -1),
+                   W // hd)                                     # (T, W)
     # rotate_half is [-x2, x1]: the sign rides on the sine
-    sin = jnp.tile(jnp.concatenate([-sin, sin], -1), W // hd)
+    sin = jnp.tile(jnp.concatenate([-sin, sin] + still[1], -1), W // hd)
     x32 = x.astype(jnp.float32)
-    first = jnp.arange(W) % hd < hd // 2
-    partner = jnp.where(first, jnp.roll(x32, -(hd // 2), -1),
-                        jnp.roll(x32, hd // 2, -1))
+    first = jnp.arange(W) % hd < rot // 2
+    partner = jnp.where(first, jnp.roll(x32, -(rot // 2), -1),
+                        jnp.roll(x32, rot // 2, -1))
     return (x32 * cos + partner * sin).astype(x.dtype)
 
 
@@ -862,13 +993,15 @@ def _key_bias(attn_bias, B):
     return kb
 
 
-def _flash(qkv, cfg: TransformerConfig, mesh, kb):
+def _flash(qkv, cfg: TransformerConfig, mesh, kb, window=None):
     """The fused Pallas kernels on the projection's own layout: ``qkv`` is
     the (B, T, 3*D) projection as it stands, or three (B, T, D) arrays;
-    -> (B, T, D), what ``wo`` reads. Nothing is transposed either way."""
+    -> (B, T, D), what ``wo`` reads. Nothing is transposed either way.
+    ``window``: a "window" layer's (``flash_attention_btd``)."""
     from ..kernels.flash_attention import flash_attention_btd
     if mesh is None or mesh.size == 1:
-        return flash_attention_btd(qkv, cfg.n_heads, cfg.causal, k_bias=kb)
+        return flash_attention_btd(qkv, cfg.n_heads, cfg.causal, k_bias=kb,
+                                   window=window)
     # A Mosaic kernel has no partitioning rule and only lowers in a fully
     # manual context, so under a mesh it runs per shard: attention is
     # independent per (batch, head), which is how the arrays are laid out
@@ -878,7 +1011,8 @@ def _flash(qkv, cfg: TransformerConfig, mesh, kb):
 
     def per_shard(qkv, *kb):
         return flash_attention_btd(qkv, heads, cfg.causal,
-                                   k_bias=kb[0] if kb else None)
+                                   k_bias=kb[0] if kb else None,
+                                   window=window)
 
     bias = () if kb is None else (kb,)
     return jax.shard_map(
@@ -888,7 +1022,7 @@ def _flash(qkv, cfg: TransformerConfig, mesh, kb):
 
 
 def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
-                    attn_bias=None):
+                    attn_bias=None, window=None):
     """q/k/v: (B, T, heads * width), every head's columns side by side ->
     (B, T, heads * v's width): D everywhere but for latent attention, whose
     q and k are wider than its v. Three paths:
@@ -897,15 +1031,24 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
     - flash: fused Pallas online-softmax kernel (hetu_tpu/kernels); folds a
       key-padding ``attn_bias`` (B, 1, 1, T) into its score blocks
     - dot: unfused reference form (the reference framework's
-      BatchMatMul+Softmax attention); applies any additive ``attn_bias``"""
+      BatchMatMul+Softmax attention); applies any additive ``attn_bias``
+    ``window``: query t keeps the keys t - window < s <= t. The flash
+    kernels stop their loops at its edge, the dot path masks; the ring, whose
+    exchange a window would cut short, refuses."""
     B, T, _ = q.shape
     nh = cfg.n_heads
     hd = q.shape[-1] // nh
     kb = _key_bias(attn_bias, B)
     if impl == "flash":
-        return _flash((q, k, v), cfg, mesh, kb)
+        return _flash((q, k, v), cfg, mesh, kb, window)
     q, k, v = (x.reshape(B, T, nh, -1) for x in (q, k, v))
     if impl == "ring":
+        if window is not None:
+            raise NotImplementedError(
+                f"a window layer (window={window}) on a mesh that shards "
+                "the sequence (sp > 1): the ring passes every k/v chunk to "
+                "every device; a window's exchange is with the neighbours "
+                "that hold its keys alone, and is not written")
         from ..parallel.ring_attention import ring_attention
         # the ring works on (B, nh, T, hd) chunks: transposed here, locally
         spec = P("dp", "tp", "sp", None)
@@ -926,12 +1069,23 @@ def _attention_core(q, k, v, cfg: TransformerConfig, mesh, impl,
         qpos = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
         kpos = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
         scores = jnp.where(kpos <= qpos, scores, -1e30)
+        if window is not None:
+            scores = jnp.where(kpos > qpos - window, scores, -1e30)
     if attn_bias is not None:
         scores = scores + attn_bias.astype(jnp.float32)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
                      preferred_element_type=jnp.float32).astype(q.dtype)
     return out.reshape(B, T, -1)
+
+
+# the bytes of q-wide rotary tables (cos and sin, float32), held through the
+# whole step, PAST which `_split_heads` turns q in k-wide column groups: a
+# 32nd of a v5e's 16 GiB. The widest of the benchmark's rotary cells before
+# PR 49 holds exactly this (32 heads of 128 at 16,384 tokens) and, like the
+# narrower ones, lowers as it did; a `perf_opt` PR that measures those cells
+# may lower the bound
+ROPE_TABLE_BYTES = 512 << 20
 
 
 def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
@@ -955,9 +1109,25 @@ def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
         k = _constrain(k, mesh, "dp", None, "tp")
         v = _constrain(v, mesh, "dp", None, "tp")
     if cfg.rope:
-        # rotate BEFORE any gqa broadcast (rope is per-kv-head)
-        q = _rope(q, 0, cfg.rope_theta, hd)
-        k = _rope(k, 0, cfg.rope_theta, hd)
+        rope = (0, cfg.rope_theta, hd, cfg.rope_dim, cfg.rope_yarn)
+        if nh > nkv and 2 * 4 * T * nh * hd > ROPE_TABLE_BYTES:
+            # `_rope` tiles its float32 cos and sin tables to its input's
+            # width, and XLA hoists them out of the layer scan and holds them
+            # for the whole step: 2 x 512 MiB for 64 heads of 128 at 16,384
+            # tokens, where k's are 2 x 64 MiB. Past the bound q turns a
+            # group of k's width at a time, so that q and k read ONE pair of
+            # (T, kv_heads * hd) tables, under a scope of the rotation's own
+            # (PERF.md section 6, PR 49). Under it the whole-width call
+            # stays: the rotary cells the benchmark had lower as they did
+            with jax.named_scope(SCOPE_ATTN_ROPE):
+                q = jnp.concatenate(
+                    [_rope(q[..., i:i + nkv * hd], *rope)
+                     for i in range(0, nh * hd, nkv * hd)], -1)
+                k = _rope(k, *rope)
+        else:
+            # rotate BEFORE any gqa broadcast (rope is per-kv-head)
+            q = _rope(q, *rope)
+            k = _rope(k, *rope)
     if cfg.multipliers.attention is not None:
         # every impl scales its scores by 1/sqrt(hd): q carries the
         # rest (Granite: 1/64 at hd = 64, so q * 0.125, exact)
@@ -988,7 +1158,11 @@ def _projection_in_place(cfg: TransformerConfig, mesh, impl):
                      or cfg.multipliers.attention is not None))
 
 
-def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None,
+               window=None):
+    """Grouped-query attention on a layer's normed input. ``window``: a
+    "window" layer's (``_window``), whose core runs under ``SCOPE_SWA_ATTN``
+    where a full layer's runs under ``SCOPE_BLK_ATTN``."""
     B, T, _ = h.shape
     impl = _resolve_attn_impl(cfg, mesh, T, attn_bias)
     with jax.named_scope(SCOPE_BLK_QKV):
@@ -1004,18 +1178,43 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     else:
         with jax.named_scope(SCOPE_BLK_QKV):
             q, k, v = _split_heads(qkv, p, cfg, mesh, impl)
-        with jax.named_scope(SCOPE_BLK_ATTN):
-            out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias)
+        with jax.named_scope(SCOPE_BLK_ATTN if window is None
+                             else SCOPE_SWA_ATTN):
+            out = _attention_core(q, k, v, cfg, mesh, impl, attn_bias,
+                                  window)
     if impl != "flash":
         # the flash kernel names its o itself, with its lse, where they
         # become its backward pass's residuals (`_flash_fwd`)
         out = checkpoint_name(out, REMAT_ATTN_O)
+    if cfg.attn_gate:
+        with jax.named_scope(SCOPE_ATTN_GATE):
+            out = _gate_heads(out, h, p["wg"], cfg.head_dim)
     with jax.named_scope(SCOPE_BLK_WO):
         out = jnp.einsum("btd,de->bte", out, p["wo"].astype(h.dtype),
                          preferred_element_type=jnp.float32).astype(h.dtype)
         if cfg.attn_proj_bias:
             out = out + p["bo"].astype(h.dtype)
     return out
+
+
+def _gate_heads(o, x, wg, hd):
+    """The per-head gate on attention's output: o (B, T, heads * hd) times
+    sigmoid(x Wg) (B, T, heads), a head's one gate on its hd columns; x the
+    layer's normed input. The logits accumulate in float32 and the sigmoid
+    and the product are float32; the result is o's dtype."""
+    g = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x, wg.astype(x.dtype),
+                                  preferred_element_type=jnp.float32))
+    return (o.astype(jnp.float32) * jnp.repeat(g, hd, axis=-1)).astype(
+        o.dtype)
+
+
+def _window(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+    """A sliding-window layer (``cfg.window``): ``_attention`` at the window
+    kind's own head count and rotary form, in which query t keeps the keys t
+    - window < s <= t. With window >= T it is ``_attention`` at those
+    sizes."""
+    return _attention(h, p, _window_view(cfg), mesh, attn_bias,
+                      window=cfg.window.window)
 
 
 def _mla_keys(k_nope, k_rope, nh):
@@ -1409,7 +1608,8 @@ _KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
           "mamba": _Kind(_init_mamba, _mamba_specs, _mamba),
           "conv": _Kind(_init_short_conv, _short_conv_specs, _short_conv),
           "mla": _Kind(_init_mla, _mla_specs, _mla),
-          "dsa": _Kind(_init_dsa, _dsa_specs, _dsa, side_loss=True)}
+          "dsa": _Kind(_init_dsa, _dsa_specs, _dsa, side_loss=True),
+          "window": _Kind(_init_window, _window_specs, _window)}
 
 
 def _dense_mlp(h, p, cfg, mesh):
@@ -2162,7 +2362,8 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     and lse: the attention layers alone, an mla layer's o at ITS width, nh *
     v_dim; the latent and the rotary key: the mla layers; q, k, v: the
     attention layers whose ``_split_heads`` makes them, k and v at
-    ``kv_heads``, and the mla layers at their two widths; the sandwich
+    ``kv_heads``, and the mla layers at their two widths; a window layer's
+    o, lse, q, k and v at the window kind's own head count; the sandwich
     norms' inputs: under ``cfg.sandwich_norm``), stay within the budget: the
     limit less what the step holds whatever is kept (``_state_bytes``, the
     stack of layer inputs the scans keep, one an application,
@@ -2218,11 +2419,22 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     # latent of an mla block, {q, k, v} of an attention block on the split
     # path and of an mla block, the two sandwich norms' inputs of every
     # block: the bytes of each, times the applications that write them
+    # a window layer's o, lse, q, k, v at ITS head count (always split:
+    # its q and k are rotated)
+    window, w_head, w_lse, w_kv = 0, 0, 0, 0
+    if "window" in cfg.layer_types:
+        wn = cfg.window.n_heads
+        window = cfg.n_loops * sum(n for kind, n in layer_runs(cfg)
+                                   if mixer_of(kind) == "window")
+        w_head = act // tp * wn * cfg.head_dim // D
+        w_lse = B * T * wn * 4 // (dp * tp)
+        w_kv = 2 * w_head * cfg.kv_heads // wn
     costs = (applications * by_seq * (2 if cfg.post_ln else 1),
-             attention * (by_head + lse) + mla * (mla_o + lse),
+             attention * (by_head + lse) + mla * (mla_o + lse)
+             + window * (w_head + w_lse),
              mla * mla_latent,
              split * (by_head + 2 * by_head * cfg.kv_heads // cfg.n_heads)
-             + mla * mla_qkv,
+             + mla * mla_qkv + window * (w_head + w_kv),
              applications * 2 * by_seq if cfg.sandwich_norm else 0)
     order = (REMAT_CANDIDATES[:2] + ((REMAT_MLA_LATENT,),)
              + REMAT_CANDIDATES[2:])
@@ -2488,6 +2700,58 @@ def mla_terms(params, tokens, cfg: TransformerConfig):
             "q": q, "k": k, "v": v}
 
 
+def attention_terms(params, tokens, cfg: TransformerConfig, mixer):
+    """The FIRST layer of mixer ``mixer`` ("attention" or "window") on
+    ``tokens`` (B, T), with what its float32 parts were computed from: a pure
+    function beside the step, for checks (no mesh). ``x`` (B, T, D) the rows
+    the projections read (the compute dtype), ``q_raw``, ``k_raw`` (B, T,
+    heads * hd) and (B, T, kv_heads * hd) the projection's q and k columns
+    before anything touches them, ``q``, ``k``, ``v`` (B, T, heads * hd) as
+    the attention kernels take them (``_split_heads``: rotated, the k/v
+    heads broadcast), ``wg`` (D, heads) and ``wo`` (heads * hd, D) the gate's
+    and the output projection's weights, and ``out`` (B, T, D) what the layer's OWN mixer (``_KINDS``: the function
+    ``_block`` calls, with the window it hands its kernels) makes of ``x``
+    and adds to the stream."""
+    h, p, _ = _first_layer_of(params, tokens, cfg,
+                              lambda kind: mixer_of(kind) == mixer)
+    view = _mixer_view(cfg, mixer)
+    x = _norm(h, p["ln1_scale"], p["ln1_bias"], cfg)
+    qkv = jnp.einsum("btd,de->bte", x, p["wqkv"].astype(x.dtype),
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    nh, hd = view.n_heads, view.head_dim
+    q_raw, k_raw, _ = jnp.split(qkv, [nh * hd, (nh + view.kv_heads) * hd],
+                                axis=-1)
+    q, k, v = _split_heads(qkv, p, view, None, "flash")
+    return {"x": x, "q_raw": q_raw, "k_raw": k_raw, "q": q, "k": k, "v": v,
+            "wg": p.get("wg"), "wo": p["wo"],
+            "out": _KINDS[mixer].mixer(x, p, cfg, None, None)}
+
+
+def attention_visits(params, tokens, cfg: TransformerConfig, mixer, chunk):
+    """The (query, key) pairs the FIRST layer of mixer ``mixer`` COMPUTES on
+    ``tokens`` (1, T), MEASURED through the layer's own mixer (``_KINDS``)
+    -> int32 (T // chunk,): the query rows that READ each chunk of ``chunk``
+    keys; their sum x ``chunk`` is the pairs computed (exact where ``chunk``
+    divides the kernels' key tile). One chunk of the layer's input rows at a
+    time is made NaN: a score the mask drops becomes a probability of 0, and
+    0 x NaN in the product with v is NaN, so every row of a query tile that
+    VISITS the tile holding the chunk comes out NaN whatever the mask says,
+    and a row whose pass never reads the chunk does not (its own rows read
+    it on the diagonal). T / chunk passes of one layer: for checks."""
+    h, p, _ = _first_layer_of(params, tokens, cfg,
+                              lambda kind: mixer_of(kind) == mixer)
+    x = _norm(h, p["ln1_scale"], p["ln1_bias"], cfg)
+    T = x.shape[1]
+
+    def rows_that_read(c):
+        bad = (jnp.arange(T) // chunk == c)[None, :, None]
+        out = _KINDS[mixer].mixer(jnp.where(bad, jnp.nan, x), p, cfg, None,
+                                  None)
+        return jnp.sum(jnp.any(jnp.isnan(out), -1), dtype=jnp.int32)
+
+    return jax.lax.map(rows_that_read, jnp.arange(T // chunk))
+
+
 def dsa_stats(params, tokens, cfg: TransformerConfig, terms=False):
     """What the indexers of a model with learned sparse attention do with
     ``tokens`` (B, T): the forward pass of the step itself (``_dsa_parts``,
@@ -2529,6 +2793,45 @@ def dsa_stats(params, tokens, cfg: TransformerConfig, terms=False):
             h = _through_run(h, blocks, cfg, kind)
     return stats[0] if len(stats) == 1 else jax.tree.map(
         lambda *runs: jnp.concatenate(runs), *stats)
+
+
+def attention_pairs(cfg: TransformerConfig, T):
+    """The (query, key) pairs a sequence of ``T`` tokens costs one layer of
+    each attention kind of the stack, by the bounds the step's own kernels
+    loop to -> {"attention" | "window": {``layers``, ``heads``, ``causal``
+    T (T + 1) / 2, ``kept`` the pairs the mathematics keeps (the causal ones;
+    under a window W, sum over t of min(t + 1, W)), ``computed`` the pairs
+    the forward pass computes, ``tiles`` its (block_q, block_k)}}, Python
+    ints. On the flash path ``computed`` is whole tiles visited x tile size,
+    the tiles those ``flash_attention.window_bounds`` gives for the call's
+    chosen tiles (the loops' bounds are functions of position alone, so the
+    count at trace time IS the forward pass's); the dot path computes every
+    pair under its mask, T x T. A pair is counted once a head group, as one
+    head's: every head of a layer does the same."""
+    from ..kernels import flash_attention as fa
+    impl = _resolve_attn_impl(cfg, None, T)
+    out = {}
+    for mixer in ("attention", "window"):
+        layers = sum(n for kind, n in layer_runs(cfg)
+                     if mixer_of(kind) == mixer)
+        if not layers:
+            continue
+        view = _mixer_view(cfg, mixer)
+        W = cfg.window.window if mixer == "window" else None
+        w = min(W or T, T)
+        stats = {"layers": layers, "heads": view.n_heads,
+                 "causal": T * (T + 1) // 2,
+                 "kept": w * (w + 1) // 2 + (T - w) * w}
+        if impl == "flash":
+            bq, bk, _ = fa._choose_tiles(T, view.head_dim, cfg.dtype, True,
+                                         view.n_heads, window=W)
+            fwd, _ = fa.window_bounds(T, W, bq, bk)
+            stats.update(computed=sum(hi - lo for lo, hi in fwd) * bq * bk,
+                         tiles=(bq, bk))
+        else:
+            stats.update(computed=T * T, tiles=(T, T))
+        out[mixer] = stats
+    return out
 
 
 def aux_weights(aux_weight=0.01, size=2):

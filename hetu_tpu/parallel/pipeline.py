@@ -67,15 +67,17 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
     if len(tfm.layer_runs(cfg)) > 1:
         raise NotImplementedError(
             f"layer_runs={tfm.layer_runs(cfg)}: no stage rule for layers "
-            "of unequal kinds (mamba or conv beside attention, a dense MLP "
-            "beside experts); the pipeline stacks ONE kind of block a stage")
+            "of unequal kinds (mamba, conv or window layers beside "
+            "attention, a dense MLP beside experts); the pipeline stacks ONE "
+            "kind of block a stage")
 
     kind = tfm.layer_runs(cfg)[0][0]
     if tfm.mixer_of(kind) != "attention":
         raise NotImplementedError(
             f"layer kind {kind!r}: a stage's body is the attention block; "
             "latent attention (mla), learned sparse attention (dsa: a loss "
-            "of its own a layer), mamba and conv mixers have no stage rule")
+            "of its own a layer), window, mamba and conv mixers have no "
+            "stage rule")
 
     def stage_fn(h, stage_blocks, stage, rng_mb):
         block = functools.partial(tfm._block, cfg=cfg, mesh=None)

@@ -148,6 +148,22 @@ SCOPE_DSA_LOSS = "hetu_dsa_loss"    # the head-summed probabilities rebuilt
                                     # softmax(I), and its gradient on I
 DSA_SCOPES = (SCOPE_DSA_PROJ, SCOPE_DSA_SCORES, SCOPE_DSA_SELECT,
               SCOPE_DSA_LOSS)
+# window and full attention in one stack (transformer._window, _attention).
+# No one of the three names contains another, nor a name above. A window
+# layer's attention core runs under SCOPE_SWA_ATTN INSTEAD of SCOPE_BLK_ATTN
+# (a full layer's stays there); the other two open only where they are named
+# below, so no other model's projection time moves
+# (benchmark/reduce/swa.py reads the three)
+SCOPE_SWA_ATTN = "hetu_swa_attn"    # a window layer's scores, softmax, P V:
+                                    # the flash calls with a window
+                                    # (`.../hetu_swa_attn/flash_fwd/...`) or
+                                    # the dot path under the window's mask
+SCOPE_ATTN_ROPE = "hetu_attn_rope"  # the rotation of q and k, either form,
+                                    # INSIDE SCOPE_BLK_QKV, in a model with
+                                    # window layers only (`cfg.window`)
+SCOPE_ATTN_GATE = "hetu_attn_gate"  # the per-head gate: Wg, the sigmoid,
+                                    # the product with o (`cfg.attn_gate`)
+SWA_SCOPES = (SCOPE_SWA_ATTN, SCOPE_ATTN_ROPE, SCOPE_ATTN_GATE)
 # the two outside the block
 SCOPE_EMBED = "hetu_embed"  # token (position, segment) lookups, BERT's
                             # embedding LayerNorm, the embedding multiplier;

@@ -5,7 +5,8 @@ once a (case, form of qkv, tiles), and ONE check holds a backward kernel to
 them. A new kernel's cases are one more `parametrize` table over `check`,
 not a copy of its body. Not collected: the tables are in
 `test_flash_chosen_tiles.py`, `test_flash_btd_layout.py`,
-`test_flash_backward_one_tile.py` and `test_flash_backward.py`."""
+`test_flash_backward_one_tile.py`, `test_flash_backward.py`,
+`test_flash_row_selection.py` and `test_flash_window.py`."""
 import contextlib
 import functools
 from typing import NamedTuple, Optional
@@ -76,6 +77,8 @@ class Case(NamedTuple):
     rows: Optional[int] = None  # a selection by query row: every query keeps
                                 # itself and up to `rows` - 1 seeded others
                                 # of the keys before it (causal cases)
+    window: Optional[int] = None    # a sliding window: query t keeps the
+                                    # keys t - window < s <= t (causal cases)
 
 
 def chosen_case(s, d, causal, bias, dtype, b=2, h=3):
@@ -154,6 +157,16 @@ def make(case: Case) -> Inputs:
         if case.bias == "row":
             k_bias = k_bias.at[1].set(-1e9)
     keep = kept_rows(rng, b, s, case.rows) if case.rows else None
+    if case.window:     # to the reference a window is a boolean mask too
+        pos = np.arange(s)
+        keep = jnp.broadcast_to(jnp.asarray(
+            (pos[None, :] <= pos[:, None])
+            & (pos[None, :] > pos[:, None] - case.window)), (b, s, s))
+        if k_bias is not None:
+            # a row whose whole window is padding is a fully padded row (see
+            # `Case.bias`): its dO is zero, as in a real loss
+            seen = jnp.any(keep & (k_bias == 0)[:, None, :], -1)
+            do = do * seen[:, None, :, None]
     given = tuple(x.astype(case.dtype) for x in (q, k, v, do))
     ref, ref_grads = _reference(case.causal, k_bias is not None, b, h, s,
                                 case.d, case.dv, keep is not None)(
@@ -161,7 +174,7 @@ def make(case: Case) -> Inputs:
         jnp.zeros((b, s)) if k_bias is None else k_bias,
         jnp.ones((b, s, s), bool) if keep is None else keep)
     return Inputs(*(to_rows(x) for x in given), k_bias, ref, ref_grads,
-                  None if keep is None else fa.pack_row_mask(keep))
+                  fa.pack_row_mask(keep) if case.rows else None)
 
 
 def _scale(case):
@@ -200,8 +213,8 @@ def forward(case, fused, tiles=None):
     with _forcing(case, tiles):
         out, lse = _compiled(lambda qkv, k_bias, row_mask: fa._fwd_pallas(
             qkv, case.heads, k_bias, _scale(case), case.causal, block_q,
-            block_k, interpret=True, row_mask=row_mask), qkv, k_bias,
-            row_mask)(qkv, k_bias, row_mask)
+            block_k, interpret=True, row_mask=row_mask, window=case.window),
+            qkv, k_bias, row_mask)(qkv, k_bias, row_mask)
     return (qkv, out, lse, k_bias) + ((row_mask,) if case.rows else ())
 
 
@@ -215,7 +228,8 @@ def oracle(case, fused, tiles=None):
     args = forward(case, fused, tiles), make(case).do
     return _compiled(functools.partial(
         fa._bwd_blockwise, n_heads=case.heads, scale=_scale(case),
-        causal=case.causal, block_k=min(block_k, case.s)), *args)(*args)
+        causal=case.causal, block_k=min(block_k, case.s),
+        window=case.window), *args)(*args)
 
 
 def check(case, fused, tol_fwd, tol_bwd, *, tiles=None, kernel=None,
@@ -241,7 +255,8 @@ def check(case, fused, tol_fwd, tol_bwd, *, tiles=None, kernel=None,
     with _forcing(case, tiles):
         traced = jax.jit(functools.partial(
             fa._bwd_pallas, n_heads=h, scale=_scale(case), causal=case.causal,
-            block_q=block_q, block_k=block_k, interpret=True)).trace(res, x.do)
+            block_q=block_q, block_k=block_k, interpret=True,
+            window=case.window)).trace(res, x.do)
     calls = pallas_calls(traced.jaxpr)
     if kernel is None:
         kernel = fa._kernels_of(s, *fa._choose_tiles(

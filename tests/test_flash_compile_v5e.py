@@ -326,6 +326,34 @@ def test_many_tile_kernels_lower_to_what_they_were(one_chip, cell, half):
     assert digest == MANY_TILE[cell][at]
 
 
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
+                         ids=["window-64-heads", "full-48-heads"])
+def test_flash_compiles_for_v5e_at_lagunas_two_head_counts(
+        one_chip, no_compile_cache, heads, window):
+    """laguna-xs.2's two calls: 1 x 16,384 tokens of 128-column heads,
+    bfloat16, three arrays; a window layer's 64 heads under a window of 512
+    (the loops' bounds read at run time: a lower one forward, an upper one
+    backward) and a full layer's 48. One kernel each way, at 512 x 512
+    tiles, k and v whole in the VMEM the call asks for."""
+    # (qkv, o, lse, dO): the backward half's abstract arguments
+    args = _many_tile_halves(one_chip, (1, heads, 16384, 128, 128))[1][1][:4]
+    scale = 1.0 / 128 ** 0.5
+
+    def fwd_and_grads(qkv, out, lse, do):
+        o, _ = fa._fwd_pallas(qkv, heads, None, scale, True, None, None,
+                              interpret=False, window=window)
+        return o, fa._bwd_pallas(
+            (qkv, out, lse, None), do, n_heads=heads, scale=scale,
+            causal=True, block_q=None, block_k=None, interpret=False,
+            window=window)
+
+    text = jax.jit(fwd_and_grads).lower(*args).compile().as_text()
+    assert _count_by_name(_kernel_calls(text)) == {
+        fa.FLASH_FWD: 1, fa.FLASH_BWD: 0, fa.FLASH_BWD_DQKV: 1}
+    assert fa._choose_tiles(16384, 128, jnp.bfloat16, True, heads,
+                            window=window)[:2] == (512, 512)
+
+
 # (rows, vocabulary, width) of the calls the benchmark's cells make, bf16
 CE_SHAPES = [
     pytest.param(8 * 4096, 50304, 2048, id="olmoe-1b-7b.pretrain-seq4096"),

@@ -5,11 +5,10 @@ expert; 8 experts, top 2): the system against the float32 reference
 (benchmark/configs/kanana-2-30b-a3b/reference.py), the reference against
 `transformers`' `DeepseekV3ForCausalLM` on copied weights, the eight shares
 of an expert layer adding up to the whole with the shared expert counted
-once, the fifth other flagship cell's tree and lowered program, the scopes,
-and the refusals by name. Its kernels (rotation, flash at two widths, remat's
+once, the scopes, and the refusals by name (the other cells' lowered steps:
+test_lfm2_model.py's one table of digests). Its kernels (rotation, flash at two widths, remat's
 names), which need no trained system, are in test_kanana_kernels.py."""
 import dataclasses
-import hashlib
 import importlib.util
 import json
 import os
@@ -21,7 +20,7 @@ import numpy as np
 import pytest
 
 from hetu_tpu.kernels import rope as rope_kernel
-from hetu_tpu.models import (generate, hf_deepseek_v3 as hd, hf_lfm2,
+from hetu_tpu.models import (generate, hf_deepseek_v3 as hd,
                              transformer as tfm)
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
@@ -364,42 +363,6 @@ def test_default_config_has_no_shared_expert_and_the_old_epsilon():
         tfm.TransformerConfig(d_ff_shared=64)
     with pytest.raises(ValueError, match="mla layer"):
         tfm.TransformerConfig(n_layers=1, layer_types=("mla",))
-
-
-# -- the other flagship cells stay what they were ------------------------------
-
-# computed at the PARENT of ISSUE 39 (commit aed9040) by
-# test_lfm2_model._cell_digest's recipe; the other four cells' digests stand
-# in test_lfm2_model.py and still hold
-LFM2_PARENT = (("8c8e334e63485216", 7026), "df8cd1acd6687a54")
-
-
-def test_lfm2_cells_tree_and_lowered_program_are_the_parents():
-    """The fifth other flagship cell, the one that shares `_route`, the
-    share's row loops and `move_router_bias` with this one: its parameter
-    tree and whole lowered train step are, to the character, the parent's."""
-    with open(os.path.join(
-            ROOT, "benchmark/configs/lfm2-8b-a1b/config.json")) as f:
-        c = json.load(f)
-    with open(os.path.join(
-            ROOT, "benchmark/traffic/pretrain-seq8192-ep4load.json")) as f:
-        t = json.load(f)
-    B, T = t["sequences"], t["seq_len"]
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    cfg = hf_lfm2.config_from_hf(
-        c, dtype=jnp.bfloat16,
-        router_bias_rate=c["assumed"]["expert_bias_update_rate"])
-    params = jax.eval_shape(
-        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-    opt = jax.eval_shape(tfm.init_opt_state, params)
-    text = tfm.make_train_step(cfg, lr=1e-4).lower(
-        params, opt, i32(B, T), i32(B, T)).as_text()
-    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
-    tree = str(jax.tree_util.tree_map(lambda x: (x.shape, str(x.dtype)),
-                                      params))
-    assert ((hashlib.sha256(text.encode()).hexdigest()[:16],
-             text.count("\n")),
-            hashlib.sha256(tree.encode()).hexdigest()[:16]) == LFM2_PARENT
 
 
 # -- scopes ----------------------------------------------------------------------

@@ -20,8 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu.models import (bert, generate, hf_granite, hf_lfm2, hf_olmoe,
-                             hf_ouro, transformer as tfm)
+from hetu_tpu.models import (bert, generate, hf_deepseek_v3, hf_granite,
+                             hf_keye, hf_lfm2, hf_olmoe, hf_ouro,
+                             transformer as tfm)
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
 
@@ -457,8 +458,15 @@ def test_the_bias_evens_a_skewed_load_over_steps():
 
 # (sha256[:16] of the LOWERED train step at the cell's own config and traffic
 # shapes with the counters cut off private symbols, its lines; sha256[:16] of
-# the parameter tree's shapes): computed at the PARENT of ISSUE 37 (commit
-# a622bb9) by this same function
+# the parameter tree's shapes) of BERT and the six decoder cells: computed at
+# the PARENT of ISSUE 49 (commit d8625bc) by this same function, each in a
+# process of its own. The first four read what they read at the parent of
+# ISSUE 37, lfm2's what it read at ISSUE 39's and kanana's what it read at
+# ISSUE 44's (where `test_kanana_model.py` and `test_keye_model.py` pinned
+# them, each with a copy of this recipe): no PR since has changed what any of
+# them lowers to. The digests are of the CPU's lowering, where the `dot` path
+# stands for the kernels; the kernels' own are
+# `test_flash_compile_v5e.py::test_many_tile_kernels_lower_to_what_they_were`.
 PARENT = {
     ("bert-base", "pretrain-seq512"):
         (("5dd9818f8e559ca8", 2401), "0ca3cf6cdc80eded"),
@@ -468,7 +476,17 @@ PARENT = {
         (("1e39cfb68388bbb3", 2434), "bdd3f4f2570a57aa"),
     ("granite-4.0-h-micro", "pretrain-seq8192-b1"):
         (("fbbf3f6e6fc2fcb1", 3519), "68b156d54bca4aa7"),
+    ("lfm2-8b-a1b", "pretrain-seq8192-ep4load"):
+        (("8c8e334e63485216", 7026), "df8cd1acd6687a54"),
+    ("kanana-2-30b-a3b", "pretrain-seq8192-ep8share"):
+        (("aabae1f6455f1620", 6215), "c0f6aef309cbdd46"),
+    ("keye-vl-2.0-30b-a3b", "pretrain-seq16384-ep8share"):
+        (("fc6934dddd62afe8", 6636), "c287774ff5bcf2b1"),
 }
+LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
+           "granite-4.0-h-micro": hf_granite, "lfm2-8b-a1b": hf_lfm2,
+           "kanana-2-30b-a3b": hf_deepseek_v3,
+           "keye-vl-2.0-30b-a3b": hf_keye}
 
 
 def _cell_digest(config, traffic):
@@ -480,6 +498,12 @@ def _cell_digest(config, traffic):
     B, T = t["sequences"], t["seq_len"]
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    # what a fresh process lowers: jax emits a jitted helper it has traced
+    # before (`_where`, `_roll_static`) under another private name and
+    # another count of them, so the text would follow the tests that ran
+    # earlier in this worker (kanana's step read 6,223 lines for 6,215 after
+    # the rest of test_keye_model.py: the failure ISSUE 49 found)
+    jax.clear_caches()
     if config == "bert-base":
         cfg = bert.BertConfig.hf(
             vocab_size=c["vocab_size"], d_model=c["hidden_size"],
@@ -498,9 +522,11 @@ def _cell_digest(config, traffic):
         text = bert.make_pretrain_step(cfg, lr=1e-4).lower(
             params, opt, batch).as_text()
     else:
-        loader = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
-                  "granite-4.0-h-micro": hf_granite}[config]
-        cfg = loader.config_from_hf(c, dtype=jnp.bfloat16)
+        # the bias's rate where the cell's file gives one (lfm2, kanana)
+        rate = c.get("assumed", {}).get("expert_bias_update_rate")
+        cfg = LOADERS[config].config_from_hf(
+            c, dtype=jnp.bfloat16,
+            **({} if rate is None else {"router_bias_rate": rate}))
         params = jax.eval_shape(
             lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
         opt = jax.eval_shape(tfm.init_opt_state, params)
@@ -517,8 +543,10 @@ def _cell_digest(config, traffic):
 @pytest.mark.parametrize("cell", sorted(PARENT), ids=".".join)
 def test_other_cells_tree_and_lowered_program_are_the_parents(cell):
     """No option an existing configuration has to set: the parameter tree
-    and the whole lowered train step (loss, gradients, AdamW) of each other
-    flagship cell are, to the character, what the parent commit lowers."""
+    and the whole lowered train step (loss, gradients, AdamW, the bias's
+    rule) of BERT and of each of the six decoder cells are, to the character,
+    what the parent commit lowers. ONE recipe and one table: a PR that adds a
+    cell adds a line and records every digest at ITS parent."""
     assert _cell_digest(*cell) == PARENT[cell]
 
 
