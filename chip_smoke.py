@@ -686,6 +686,15 @@ KERNEL_SHAPES = {
     "quant": (512 * 512, 256),           # the comm_quant_dp MLP grad, block
     "opt": (512, 512, 3, 3),             # ResNet-18's largest conv weight
     "rope": (2, 1024, 4, 128, 64),       # kanana's q: heads of 128 + 64, bf16
+    # (batch, seq, heads, k/v heads, head width, rotary width (0 = all),
+    # YaRN) of the rotate-half cells' q and k, bf16, read out of the fused
+    # [q | k | v] projection: Ouro's one sequence, a laguna-xs.2 full layer
+    # (half a head turns, YaRN's table) and a window layer (64 heads on 8),
+    # lfm2's heads of 64
+    "rope_halves": {"ouro": (1, 4096, 16, 16, 128, 0, False),
+                    "laguna-full": (1, 16384, 48, 8, 128, 64, True),
+                    "laguna-window": (1, 16384, 64, 8, 128, 0, False),
+                    "lfm2": (4, 8192, 32, 8, 64, 0, False)},
 }
 
 
@@ -700,8 +709,9 @@ def phase_kernels(*, shapes=None, chip=True):
                                                   mha_reference)
     from hetu_tpu.kernels.fused_ce import (fused_linear_nll,
                                            linear_nll_reference)
-    from hetu_tpu.kernels.rope import rope_interleaved
-    from hetu_tpu.models.transformer import _rope_interleaved
+    from hetu_tpu.kernels.rope import rope_halves, rope_interleaved
+    from hetu_tpu.models.transformer import (YarnConfig, _rope,
+                                             _rope_interleaved)
 
     shapes = {**KERNEL_SHAPES, **(shapes or {})}
     rng = np.random.RandomState(0)
@@ -881,6 +891,30 @@ def phase_kernels(*, shapes=None, chip=True):
         compare("rope_pairs", rotated_and_cotangent(rope_interleaved),
                 rotated_and_cotangent(_rope_interleaved), (rx, rg),
                 atol=2 ** -5, exact=chip)
+
+        # -- the same kernel on rotate-half columns: q and k where they
+        # stand in the projection against `_rope` of their slices, forward
+        # and transposed (the cotangent laid into the projection's width)
+        for cell, (b, s, h, kv, d, rot, yarn) in shapes["rope_halves"].items():
+            yarn = YarnConfig(64.0, 4096, 64.0, 1.0, 1.4158883) if yarn else None
+            cut = ((0, h * d), (h * d, kv * d))
+            rx = jnp.asarray(rng.randn(b, s, (h + 2 * kv) * d), jnp.bfloat16)
+            rg = tuple(jnp.asarray(rng.randn(b, s, w), jnp.bfloat16)
+                       for _, w in cut)
+
+            def q_k_and_cotangent(fn):
+                def run(x, g):
+                    out, vjp = jax.vjp(lambda x: tuple(
+                        fn(x, at, 0, 5e5, d, rot, yarn) for at in cut), x)
+                    return out, vjp(g)[0]
+                return run
+
+            compare(f"rope_halves:{cell}",
+                    q_k_and_cotangent(lambda x, at, *rope: rope_halves(
+                        x, *rope, at=at)),
+                    q_k_and_cotangent(lambda x, at, *rope: _rope(
+                        x[..., at[0]:at[0] + at[1]], *rope)), (rx, rg),
+                    atol=2 ** -5, exact=chip)
 
         # -- the four registry kernels
         n, d, vocab = shapes["embed_grad"]

@@ -15,8 +15,9 @@ Layout:
 - :mod:`flash_attention` / :mod:`fused_ce` — the two pre-tier kernels
   (their ``should_fuse``-style gating predates the registry and is
   documented in docs/KERNELS.md).
-- :mod:`rope` — latent attention's partial interleaved rotation of q in
-  one pass through VMEM, gated the pre-tier way (``rope.takes``).
+- :mod:`rope` — the rotation of q and k in one pass through VMEM, latent
+  attention's interleaved pairs and every other model's rotate-half
+  columns, gated the pre-tier way (``rope.takes``).
 
 Importing this package registers the four tier kernels; the graph ops
 import it lazily inside their compute fns so jax-free tools never pay

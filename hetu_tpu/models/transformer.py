@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kernels import rope as rope_kernel
 from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  REMAT_ATTN_V, REMAT_CANDIDATES,
                                  REMAT_DSA_GRADS, REMAT_MLA_LATENT,
@@ -153,11 +154,12 @@ class WindowConfig:
 @dataclasses.dataclass(frozen=True)
 class YarnConfig:
     """YaRN's frequency scaling (Peng et al. 2023, arXiv:2309.00071;
-    ``transformers`` ``_compute_yarn_parameters``; ``yarn_inv_freq``): of a
-    rotary table's frequencies those that turn fewer than ``beta_slow``
-    times in ``original_max_len`` positions are divided by ``factor``, those
-    that turn more than ``beta_fast`` times stay, a linear ramp between;
-    cos and sin are multiplied by ``attention_factor``."""
+    ``transformers`` ``_compute_yarn_parameters``; ``kernels/rope.py``:
+    ``yarn_inv_freq``): of a rotary table's frequencies those that turn
+    fewer than ``beta_slow`` times in ``original_max_len`` positions are
+    divided by ``factor``, those that turn more than ``beta_fast`` times
+    stay, a linear ramp between; cos and sin are multiplied by
+    ``attention_factor``."""
     factor: float = 1.0
     original_max_len: int = 4096
     beta_fast: float = 32.0
@@ -856,27 +858,6 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
         return _layer_norm(x, scale, bias, cfg.ln_eps)
 
 
-def yarn_inv_freq(theta, dim, yarn: YarnConfig):
-    """The ``dim`` / 2 inverse frequencies of a rotary table scaled by YaRN,
-    float64 (``transformers`` ``_compute_yarn_parameters``): with f_i =
-    theta^(-2i / dim) and c(n) = dim ln(original_max_len / (2 pi n)) / (2 ln
-    theta), the index of the frequency that turns n times in the original
-    length, low = floor(c(beta_fast)) and high = ceil(c(beta_slow)), clipped
-    to the table (refused where they meet), ramp_i = clip((i - low)
-    / (high - low), 0, 1): inv_i = (1 - ramp_i) f_i + ramp_i f_i / factor."""
-    f = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    c = lambda n: (dim * math.log(yarn.original_max_len / (2 * math.pi * n))
-                   / (2 * math.log(theta)))
-    low = max(math.floor(c(yarn.beta_fast)), 0)
-    high = min(math.ceil(c(yarn.beta_slow)), dim - 1)
-    if low >= high:
-        raise ValueError(f"{yarn} on {dim} columns at theta {theta}: the "
-                         f"ramp's ends are {low} and {high}, no ramp")
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / (high - low), 0.0, 1.0)
-    return (1.0 - ramp) * f + ramp * f / yarn.factor
-
-
 def _rope(x, pos0, theta, hd, rot=0, yarn=None):
     """Rotary position embeddings, HF rotate_half convention: x (B, T,
     heads*hd), the heads side by side as the projection writes them, at
@@ -890,27 +871,14 @@ def _rope(x, pos0, theta, hd, rot=0, yarn=None):
     inside them (partners rot/2 apart), and the others pass (HF
     ``partial_rotary_factor``): their cos is 1 and their sin 0. ``yarn``:
     the table's frequencies are ``yarn_inv_freq``'s (made in float64, cast)
-    and cos and sin carry its ``attention_factor``. Tables and rotation are
-    float32 either way; with the defaults this is the program it was."""
+    and cos and sin carry its ``attention_factor`` (``kernels/rope.py``:
+    ``tables_halves``, the one expression this and the kernel that turns a
+    block in VMEM read). Tables and rotation are float32 either way; with
+    the defaults this is the program it was."""
     B, T, W = x.shape
     rot = rot or hd
-    if yarn is None or yarn.factor == 1.0:      # nothing to scale
-        inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32)
-                               / rot))
-    else:
-        inv = jnp.asarray(yarn_inv_freq(theta, rot, yarn), jnp.float32)
-    t = pos0 + jnp.arange(T, dtype=jnp.float32)
-    freqs = jnp.outer(t, inv)                       # (T, rot/2)
-    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
-    if yarn is not None and yarn.attention_factor != 1.0:
-        cos, sin = (c * jnp.float32(yarn.attention_factor)
-                    for c in (cos, sin))
-    still = ([jnp.ones((T, hd - rot), jnp.float32)],
-             [jnp.zeros((T, hd - rot), jnp.float32)]) if rot < hd else ([], [])
-    cos = jnp.tile(jnp.concatenate([cos, cos] + still[0], -1),
-                   W // hd)                                     # (T, W)
-    # rotate_half is [-x2, x1]: the sign rides on the sine
-    sin = jnp.tile(jnp.concatenate([-sin, sin] + still[1], -1), W // hd)
+    cos, sin = rope_kernel.tables_halves(T, pos0, theta, hd, rot, yarn,
+                                        W // hd)                # (T, W)
     x32 = x.astype(jnp.float32)
     first = jnp.arange(W) % hd < rot // 2
     partner = jnp.where(first, jnp.roll(x32, -(rot // 2), -1),
@@ -932,9 +900,8 @@ def _rope_interleaved(x, pos0, theta, hd, first):
     pair stays where it is. The two results are one permutation of a head's
     rotary columns apart, the same for q and k, so every q . k is the same
     sum in another order."""
-    from ..kernels.rope import tables
     B, T, W = x.shape
-    cos, sin = tables(T, pos0, theta, hd, first, W // hd)
+    cos, sin = rope_kernel.tables(T, pos0, theta, hd, first, W // hd)
     x32 = x.astype(jnp.float32)
     even = (jnp.arange(W) % hd - first) % 2 == 0
     partner = jnp.where(even, jnp.roll(x32, -1, -1), jnp.roll(x32, 1, -1))
@@ -1110,7 +1077,19 @@ def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
         v = _constrain(v, mesh, "dp", None, "tp")
     if cfg.rope:
         rope = (0, cfg.rope_theta, hd, cfg.rope_dim, cfg.rope_yarn)
-        if nh > nkv and 2 * 4 * T * nh * hd > ROPE_TABLE_BYTES:
+        # q and k where they stand in the projection, if nothing has touched
+        # them: the kernel reads a column range and no slice is copied
+        cut = ((q, None), (k, None)) if cfg.qk_norm else (
+            (qkv, (0, nh * hd)), (qkv, (nh * hd, nkv * hd)))
+        if all(rope_kernel.takes(x, hd, mesh=mesh, rot=cfg.rope_dim, at=at)
+               for x, at in cut):
+            # one pass through VMEM each, q whole (``kernels/rope.py``: a
+            # TPU, one program, a shape its blocks divide; its tables are a
+            # lane tile wide), under the rotation's own scope in every model
+            with jax.named_scope(SCOPE_ATTN_ROPE):
+                q, k = (rope_kernel.rope_halves(x, *rope, at=at)
+                        for x, at in cut)
+        elif nh > nkv and 2 * 4 * T * nh * hd > ROPE_TABLE_BYTES:
             # `_rope` tiles its float32 cos and sin tables to its input's
             # width, and XLA hoists them out of the layer scan and holds them
             # for the whole step: 2 x 512 MiB for 64 heads of 128 at 16,384
@@ -1235,10 +1214,9 @@ def _rope_q(q, cfg: TransformerConfig, mesh):
     serves the call (``kernels/rope.py``: a TPU, one program, a shape its
     blocks divide), else ``_rope_interleaved``, the expression the kernel is
     held to."""
-    from ..kernels import rope
     m = cfg.mla
-    rotate = (rope.rope_interleaved
-              if rope.takes(q, m.qk_dim, m.nope_dim, mesh)
+    rotate = (rope_kernel.rope_interleaved
+              if rope_kernel.takes(q, m.qk_dim, m.nope_dim, mesh)
               else _rope_interleaved)
     return rotate(q, 0, cfg.rope_theta, m.qk_dim, m.nope_dim)
 
@@ -1308,11 +1286,17 @@ def _dsa_index(h, p, cfg: TransformerConfig):
     x = jax.lax.stop_gradient(h)
     proj = lambda w: jnp.einsum("btd,de->bte", x, w.astype(x.dtype),
                                 preferred_element_type=jnp.float32)
-    qI = _rope(proj(p["wq_idx"]).astype(x.dtype), 0, cfg.rope_theta,
-               m.head_dim)
-    kI = _rope(_layer_norm(proj(p["wk_idx"]).astype(x.dtype),
-                           p["k_idx_norm_scale"], p["k_idx_norm_bias"],
-                           cfg.ln_eps), 0, cfg.rope_theta, m.head_dim)
+    # in one pass through VMEM where the kernel serves the call (the J
+    # query heads side by side; the ONE key head of 64 columns is narrower
+    # than a lane tile and takes the reference). No mesh: `_dsa_parts`
+    rotate = lambda y: (
+        rope_kernel.rope_halves
+        if rope_kernel.takes(y, m.head_dim, rot=0) else _rope)(
+            y, 0, cfg.rope_theta, m.head_dim)
+    qI = rotate(proj(p["wq_idx"]).astype(x.dtype))
+    kI = rotate(_layer_norm(proj(p["wk_idx"]).astype(x.dtype),
+                            p["k_idx_norm_scale"], p["k_idx_norm_bias"],
+                            cfg.ln_eps))
     w = proj(p["ww_idx"]) * (m.n_heads * m.head_dim) ** -0.5
     return qI, kI, w
 

@@ -41,7 +41,10 @@ _T0_UNIX = time.time()
 # Each name is written by one module and read by the metrics PERF.md section
 # 3 lists; the Pallas kernels' names live with the kernels (docs/KERNELS.md:
 # `flash_fwd`, `flash_bwd` for a sequence of one tile and `flash_bwd_dqkv`
-# for one of several, whose calls a step say which backward a cell takes).
+# for one of several, whose calls a step say which backward a cell takes;
+# `rope_pairs` and `rope_halves`, the rotation of q and k in one pass, whose
+# calls a step say that a cell's rotation runs in the kernel and not in
+# `transformer._rope` / `_rope_interleaved`).
 STEP = "hetu_step"        # one SubExecutor.run call, step_num=<step>
 # phase scopes in the compiled program (HLO metadata `op_name`): a device op
 # under SCOPE_OPT is optimizer work, one under `transpose(` backward (its
@@ -159,8 +162,10 @@ SCOPE_SWA_ATTN = "hetu_swa_attn"    # a window layer's scores, softmax, P V:
                                     # (`.../hetu_swa_attn/flash_fwd/...`) or
                                     # the dot path under the window's mask
 SCOPE_ATTN_ROPE = "hetu_attn_rope"  # the rotation of q and k, either form,
-                                    # INSIDE SCOPE_BLK_QKV, in a model with
-                                    # window layers only (`cfg.window`)
+                                    # INSIDE SCOPE_BLK_QKV: around the
+                                    # `rope_halves` kernel in every model it
+                                    # serves, and around `_rope`'s grouped
+                                    # form (`transformer.ROPE_TABLE_BYTES`)
 SCOPE_ATTN_GATE = "hetu_attn_gate"  # the per-head gate: Wg, the sigmoid,
                                     # the product with o (`cfg.attn_gate`)
 SWA_SCOPES = (SCOPE_SWA_ATTN, SCOPE_ATTN_ROPE, SCOPE_ATTN_GATE)

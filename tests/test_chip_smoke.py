@@ -104,10 +104,13 @@ def test_kernels_phase_tiny(capsys):
         "flash_window": (1, 2, 512, 128, 100),
         "embed_grad": (128, 128, 1000), "csr_spmm": (300, 16, 16, 128),
         "quant": (4096, 256), "opt": (300, 700),
-        "rope": (1, 32, 2, 128, 64)})
+        "rope": (1, 32, 2, 128, 64),
+        "rope_halves": {"half-a-head-under-yarn": (1, 32, 4, 2, 128, 64, True),
+                        "heads-of-64": (2, 32, 4, 2, 64, 0, False)}})
     assert _last_json(capsys)["phase"] == "kernels"
     assert set(rec["kernels"]) == {
         "flash_causal", "flash_key_padding", "fused_ce", "fused_embed_grad",
         "csr_spmm", "quant_blocks", "dequant_blocks", "fused_adam",
         "fused_sgd", "rope_pairs", "flash_bwd_dqkv:two-widths",
-        "flash_bwd_dqkv:pairs-of-64", "flash_window"}
+        "flash_bwd_dqkv:pairs-of-64", "flash_window",
+        "rope_halves:half-a-head-under-yarn", "rope_halves:heads-of-64"}

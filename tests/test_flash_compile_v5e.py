@@ -473,6 +473,49 @@ def test_rope_pairs_compiles_for_v5e_at_kananas_q(one_chip, no_compile_cache,
                for kind, dims in shapes if kind == "f32") == 8192 * 384
 
 
+@pytest.mark.parametrize("B,T,nh,nkv,rot,yarn,blocks", [
+    pytest.param(1, 16384, 64, 8, 0, None, ((512, 512), (512, 512)),
+                 id="laguna-window-64-heads"),
+    pytest.param(1, 16384, 48, 8, 64, True, ((512, 768), (512, 512)),
+                 id="laguna-full-half-a-head-under-yarn"),
+    pytest.param(1, 4096, 16, 16, 0, None, ((512, 512), (512, 512)),
+                 id="ouro-one-sequence")])
+def test_rope_halves_compiles_for_v5e_in_the_projection(
+        one_chip, no_compile_cache, monkeypatch, B, T, nh, nkv, rot, yarn,
+        blocks):
+    """The rotate-half rotation of q and k at the cells' shapes, heads of
+    128 bfloat16 read out of the fused [q | k | v] projection where it
+    stands, forward and transposed: four Mosaic calls under the kernel's
+    name inside the VMEM Mosaic gives unasked; around them no float32 array
+    of q's size and no table wider than a lane tile."""
+    from hetu_tpu.models import transformer as tfm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
+    hd = 128
+    yarn = tfm.YarnConfig(64.0, 4096, 64.0, 1.0, 1.4158883) if yarn else None
+    qkv = jax.ShapeDtypeStruct((B, T, (nh + 2 * nkv) * hd), jnp.bfloat16,
+                               sharding=one_chip)
+    cut = ((0, nh * hd), (nh * hd, nkv * hd))
+    assert blocks == tuple(rope._blocks(qkv.shape, hd, 0, 2, rot, at)
+                           for at in cut)
+
+    def forward_and_transposed(x, gq, gk):
+        (q, k), vjp = jax.vjp(lambda x: tuple(
+            rope.rope_halves(x, 0, 5e5, hd, rot, yarn, at) for at in cut), x)
+        return q, k, vjp((gq, gk))[0]
+
+    q, k = (jax.ShapeDtypeStruct((B, T, w), jnp.bfloat16, sharding=one_chip)
+            for _, w in cut)
+    text = jax.jit(forward_and_transposed).lower(qkv, q, k).compile().as_text()
+    assert _count_by_name(_kernel_calls(text), (rope.ROPE_HALVES,)) == {
+        rope.ROPE_HALVES: 4}, text
+    assert "vmem_limit_bytes" not in text
+    # what the program holds in memory: its entry computation's arrays (the
+    # sum of q's and k's cotangents adds in float32 inside one fusion)
+    shapes = re.findall(r"(f32|bf16)\[([\d,]+)\]", text[text.index("ENTRY"):])
+    assert max(math.prod(map(int, dims.split(",")))
+               for kind, dims in shapes if kind == "f32") == T * hd
+
+
 # ---------------------------------------------------------------------------
 # One layer of the trunk, forward and gradient, as the step runs it (`remat`
 # on, flash forced on): between the projections' matmuls and the kernels

@@ -3,6 +3,7 @@ test_kanana_model.py, ISSUE 42): the rotary columns against HF's and the
 kernel that turns q's in one pass (ISSUE 40), the flash kernels at unequal
 head widths, latent attention's tiles, and what `remat` may keep by kind."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,6 +133,249 @@ def test_rope_q_off_a_tpu_is_rope_interleaved(monkeypatch):
         np.asarray(tfm._rope_q(q, cfg, None), np.float32),
         np.asarray(tfm._rope_interleaved(q, 0, cfg.rope_theta, 192, 128),
                    np.float32))
+
+
+# -- the same kernel on rotate-half columns (ISSUE 50) -----------------------------
+
+LAGUNA_YARN = tfm.YarnConfig(factor=64.0, original_max_len=4096,
+                             beta_fast=64.0, beta_slow=1.0,
+                             attention_factor=1.4158883083359672)
+
+
+@pytest.mark.parametrize("B,heads,hdim,rot,yarn,dtype,pos0,at", [
+    pytest.param(2, 16, 128, 0, None, jnp.bfloat16, 0, None,
+                 id="olmoe-16-heads-of-128"),
+    pytest.param(1, 16, 128, 0, None, jnp.bfloat16, 0, None,
+                 id="ouro-one-sequence"),
+    pytest.param(1, 48, 128, 64, LAGUNA_YARN, jnp.bfloat16, 0, None,
+                 id="laguna-full-half-a-head-under-yarn"),
+    pytest.param(1, 64, 128, 0, None, jnp.bfloat16, 0, None,
+                 id="laguna-window-64-heads"),
+    pytest.param(2, 32, 64, 0, None, jnp.bfloat16, 0, None,
+                 id="lfm2-heads-of-64"),
+    pytest.param(2, 16, 64, 0, None, jnp.bfloat16, 0, None,
+                 id="keye-index-query"),
+    pytest.param(2, 4, 128, 0, None, jnp.float32, 0, None, id="f32"),
+    pytest.param(2, 4, 128, 64, LAGUNA_YARN, jnp.bfloat16, 7, None,
+                 id="pos0-7"),
+    pytest.param(1, 2, 256, 128, None, jnp.bfloat16, 0, None,
+                 id="two-lane-tiles-a-head-one-passes"),
+    pytest.param(2, 8, 128, 0, None, jnp.bfloat16, 0, (0, 4 * 128),
+                 id="q-in-the-fused-projection"),
+    pytest.param(2, 8, 128, 64, LAGUNA_YARN, jnp.bfloat16, 0,
+                 (4 * 128, 2 * 128), id="k-in-the-fused-projection")])
+def test_rope_kernel_is_rope_forward_and_transposed(
+        B, heads, hdim, rot, yarn, dtype, pos0, at):
+    """The one-pass kernel (interpreted) against `_rope`, both compiled, at
+    the rotary cells' head sizes: the forward result (the same float32
+    products and sum in the same order, a passing column EXACTLY its input)
+    and the cotangent of the same g (`jax.vjp` of the reference scatters two
+    rolls, the kernel turns by the opposite angle) within one unit of the
+    output dtype: this host's compiler contracts a product and the sum into
+    one rounding in one program and not the other on a few entries in
+    100,000; on the chip both are equal to the bit (`chip_smoke.py`'s
+    kernels phase asserts it). Three row blocks. `at`: a column
+    range of a wider array, read where it stands, its cotangent laid into
+    the array's width."""
+    T, theta = 96, 5e5
+    x = jax.random.normal(jax.random.PRNGKey(0), (B, T, heads * hdim),
+                          jnp.float32).astype(dtype)
+    lo, width = at or (0, x.shape[-1])
+    g = jax.random.normal(jax.random.PRNGKey(1), (B, T, width),
+                          jnp.float32).astype(dtype)
+    assert rope_kernel._blocks(x.shape, hdim, 0, x.dtype.itemsize, rot,
+                               at)[0] == 32
+
+    def both(rotate):
+        out, vjp = jax.vjp(rotate, x)
+        return out, vjp(g)[0]
+    want, dwant = jax.jit(lambda: both(lambda x: tfm._rope(
+        x[..., lo:lo + width], pos0, theta, hdim, rot, yarn)))()
+    got, dgot = jax.jit(lambda: both(lambda x: rope_kernel.rope_halves(
+        x, pos0, theta, hdim, rot, yarn, at)))()
+    assert got.dtype == dgot.dtype == dtype and dgot.shape == x.shape
+    dist = (rot or hdim) // 2
+    x64 = np.asarray(x, np.float64)[..., lo:lo + width]
+    for a, b, of in ((got, want, x64),
+                     (dgot[..., lo:lo + width], dwant[..., lo:lo + width],
+                      np.asarray(g, np.float64))):
+        a, b = (np.asarray(v, np.float64) for v in (a, b))
+        # one unit of the output dtype at the size of the two terms summed
+        # (a sum that cancels keeps its terms' rounding; under YaRN the
+        # terms carry its attention factor)
+        terms = (np.abs(of) + np.maximum(np.abs(np.roll(of, dist, -1)),
+                                         np.abs(np.roll(of, -dist, -1)))) * (
+            yarn.attention_factor if yarn else 1.0)
+        assert np.all(np.abs(a - b) <= float(jnp.finfo(dtype).eps) * terms)
+    # forward the order of operations is the reference's
+    assert np.mean(np.asarray(got != want)) < 1e-3
+    outside = np.ones(x.shape[-1], bool)
+    outside[lo:lo + width] = False
+    assert not np.asarray(dgot, np.float32)[..., outside].any()
+    passes = np.arange(width) % hdim >= (rot or hdim)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32)[..., passes],
+        np.asarray(x, np.float32)[..., lo:lo + width][..., passes])
+    text = str(jax.make_jaxpr(lambda x: rope_kernel.rope_halves(
+        x, pos0, theta, hdim, rot, yarn, at))(x))
+    assert rope_kernel.ROPE_HALVES in text
+    assert rope_kernel.ROPE_PAIRS not in text
+
+
+@pytest.mark.parametrize("shape,hdim,rot,kw,served", [
+    pytest.param((2, 32, 16 * 128), 128, 0, {}, True, id="olmoe"),
+    pytest.param((1, 32, 48 * 128), 128, 64, {}, True, id="laguna-full"),
+    pytest.param((2, 32, 32 * 64), 64, 0, {}, True, id="heads-of-64"),
+    pytest.param((2, 32, 2 * 256), 256, 128, {}, True,
+                 id="a-head-of-two-lane-tiles"),
+    pytest.param((1, 32, 64 * 128), 128, 0, {"at": (48 * 128, 8 * 128)},
+                 True, id="k-in-the-projection"),
+    pytest.param((2, 32, 16 * 128), 128, 0, {"mesh": 4}, False,
+                 id="under-a-mesh"),
+    pytest.param((2, 32, 64), 64, 0, {}, False,
+                 id="keye-index-key-64-wide"),
+    pytest.param((2, 32, 4 * 96), 96, 0, {}, False, id="a-head-of-96"),
+    pytest.param((2, 32, 2 * 256), 256, 0, {}, False,
+                 id="partner-in-another-lane-tile"),
+    pytest.param((2, 32, 16 * 128), 128, 0, {"pos0": "traced"}, False,
+                 id="traced-pos0"),
+    pytest.param((2, 1, 16 * 128), 128, 0, {}, False, id="decode-T-1"),
+    pytest.param((2, 24, 16 * 128), 128, 0, {}, False,
+                 id="rows-no-block-divides"),
+    pytest.param((1, 32, 64 * 128), 128, 0, {"at": (64, 8 * 128)}, False,
+                 id="a-range-off-the-period"),
+    pytest.param((32, 16 * 128), 128, 0, {}, False, id="no-batch-axis")])
+def test_takes_serves_halves_by_backend_and_shape(
+        rope_kernel_taken, monkeypatch, shape, hdim, rot, kw, served):
+    """`rope.takes` for rotate-half columns: on a TPU (the fixture's patch)
+    by the shape alone, the partner in the column's own lane tile; a mesh, a
+    traced position, decode's one row and every shape the blocks do not
+    divide take `_rope`; off a TPU everything does."""
+    import types
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kw = dict(kw)
+    if "mesh" in kw:
+        kw["mesh"] = types.SimpleNamespace(size=kw["mesh"])
+    seen = []
+    if kw.get("pos0") == "traced":
+        jax.make_jaxpr(lambda p: seen.append(rope_kernel.takes(
+            x, hdim, rot=rot, **dict(kw, pos0=p))) or p)(0)
+    else:
+        seen.append(rope_kernel.takes(x, hdim, rot=rot, **kw))
+    assert seen == [served]
+    monkeypatch.setattr(rope_kernel, "_on_tpu", lambda: False)
+    if kw.get("pos0") != "traced":
+        assert not rope_kernel.takes(x, hdim, rot=rot, **kw)
+
+
+def _heads_cfg(**kw):
+    return tfm.TransformerConfig(**{**dict(
+        vocab_size=64, d_model=64, n_heads=4, n_kv_heads=2, d_head=128,
+        n_layers=1, d_ff=64, max_seq_len=64, rope=True, use_pos_emb=False,
+        causal=True, norm="rmsnorm", dtype=jnp.bfloat16), **kw})
+
+
+def _split_heads_as_it_was(qkv, p, cfg):
+    """`_split_heads` of a rotary stack without the kernel: the rotation in
+    `_rope`'s whole-width form (no mesh, no multiplier, small tables)."""
+    nh, hd, nkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    B, T, _ = qkv.shape
+    q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+    if cfg.qk_norm == "head":
+        q = tfm._rms_norm_heads(q, p["q_norm"], cfg.ln_eps)
+        k = tfm._rms_norm_heads(k, p["k_norm"], cfg.ln_eps)
+    rope = (0, cfg.rope_theta, hd, cfg.rope_dim, cfg.rope_yarn)
+    q, k = tfm._rope(q, *rope), tfm._rope(k, *rope)
+    k, v = (jnp.repeat(x.reshape(B, T, nkv, hd), nh // nkv,
+                       axis=2).reshape(B, T, nh * hd) for x in (k, v))
+    return q, k, v
+
+
+@pytest.mark.parametrize("kw,T,calls", [
+    pytest.param({}, 32, [(2, 32, 8 * 128)] * 2, id="in-the-projection"),
+    pytest.param({"rope_dim": 64, "rope_yarn": LAGUNA_YARN}, 32,
+                 [(2, 32, 8 * 128)] * 2, id="half-a-head-under-yarn"),
+    pytest.param({"qk_norm": "head"}, 32,
+                 [(2, 32, 4 * 128), (2, 32, 2 * 128)], id="after-qk-norm"),
+    pytest.param({"d_head": 96}, 32, [], id="a-head-of-96"),
+    pytest.param({}, 24, [], id="rows-no-block-divides")])
+def test_split_heads_takes_the_kernel_for_q_and_k_or_for_neither(
+        rope_kernel_taken, kw, T, calls):
+    """`_split_heads` on a TPU (the fixture's patch): q and k in one pass
+    each, read out of the fused projection where nothing has touched them
+    (the array the kernel is handed is the projection itself) and after
+    QK-norm as arrays of their own, under the rotation's scope; a shape the
+    blocks do not divide keeps `_rope` and its scope. The same q, k, v to
+    the bit either way, and their cotangents within a unit."""
+    cfg = _heads_cfg(**kw)
+    nh, hd, nkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    qkv = jax.random.normal(jax.random.PRNGKey(3), (2, T, (nh + 2 * nkv) * hd),
+                            jnp.float32).astype(jnp.bfloat16)
+    p = {"q_norm": 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(4), (hd,)),
+         "k_norm": 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(5), (hd,))}
+    new = lambda qkv: tfm._split_heads(qkv, p, cfg, None, "flash")
+    old = lambda qkv: _split_heads_as_it_was(qkv, p, cfg)
+    got, vjp = jax.vjp(new, qkv)
+    want, vjp_was = jax.vjp(old, qkv)
+    assert rope_kernel_taken == calls
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    ct = tuple(jax.random.normal(jax.random.PRNGKey(6 + i), a.shape,
+                                 jnp.float32).astype(a.dtype)
+               for i, a in enumerate(got))
+    np.testing.assert_allclose(np.asarray(vjp(ct)[0], np.float32),
+                               np.asarray(vjp_was(ct)[0], np.float32),
+                               atol=0.07, rtol=0.01)
+    scoped = tracing.SCOPE_ATTN_ROPE in jax.jit(new).lower(qkv).as_text(
+        debug_info=True)
+    assert scoped == bool(calls)
+
+
+def test_the_indexers_query_takes_the_kernel_and_its_key_does_not(
+        rope_kernel_taken, monkeypatch):
+    """keye's indexer on a TPU (the fixture's patch): the 16 query heads of
+    64 columns side by side turn in the kernel, the ONE key head of 64
+    columns, half a lane tile, in `_rope`; both are `_rope`'s arrays."""
+    cfg = _heads_cfg(dsa=tfm.DSAConfig(n_heads=16, head_dim=64, top_k=8))
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    h = jax.random.normal(ks[0], (2, 32, 64), jnp.float32).astype(cfg.dtype)
+    p = {"wq_idx": jax.random.normal(ks[1], (64, 16 * 64)),
+         "wk_idx": jax.random.normal(ks[2], (64, 64)),
+         "ww_idx": jax.random.normal(ks[3], (64, 16)),
+         "k_idx_norm_scale": jnp.ones((64,)),
+         "k_idx_norm_bias": jnp.zeros((64,))}
+    got = tfm._dsa_index(h, p, cfg)
+    assert rope_kernel_taken == [(2, 32, 16 * 64)]
+    rope_kernel_taken.clear()
+    monkeypatch.setattr(rope_kernel, "_on_tpu", lambda: False)
+    want = tfm._dsa_index(h, p, cfg)
+    assert rope_kernel_taken == []
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("kw", [
+    pytest.param({}, id="whole-heads"),
+    pytest.param({"rope_dim": 64, "rope_yarn": LAGUNA_YARN}, id="yarn"),
+    pytest.param({"qk_norm": "head"}, id="qk-norm")])
+def test_split_heads_off_a_tpu_lowers_to_what_it_did(monkeypatch, kw):
+    """Off a TPU the kernel is never asked for (`_rotate` is not reached) and
+    `_split_heads` lowers to the text of the expression it was, to the
+    character."""
+    monkeypatch.setattr(rope_kernel, "_rotate", None)
+    cfg = _heads_cfg(**kw)
+    nh, hd, nkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    qkv = jax.ShapeDtypeStruct((2, 32, (nh + 2 * nkv) * hd), jnp.bfloat16)
+    p = {"q_norm": jnp.ones((hd,)), "k_norm": jnp.ones((hd,))}
+    text = lambda f: re.sub(r"@(\w+?)_\d+\b", r"@\1", jax.jit(f).lower(
+        qkv).as_text()).replace("jit__lambda_", "jit_f")
+    jax.clear_caches()
+    was = text(lambda qkv: _split_heads_as_it_was(qkv, p, cfg))
+    jax.clear_caches()
+    now = text(lambda qkv: tfm._split_heads(qkv, p, cfg, None, "flash"))
+    assert now == was and "rope" not in now
 
 
 # -- the kernels at two widths -----------------------------------------------------

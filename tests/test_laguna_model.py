@@ -22,9 +22,11 @@ import pytest
 from jax.sharding import Mesh
 
 from hetu_tpu.kernels import flash_attention as fa
+from hetu_tpu.kernels import rope as rope_kernel
 from hetu_tpu.models import generate, hf_laguna as hl, transformer as tfm
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
+from test_kanana_model import rope_kernel_taken  # noqa: F401
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -309,6 +311,29 @@ def test_flash_path_is_the_dot_path():
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-6)
 
 
+def test_the_rotation_kernel_in_the_trunk_is_rope(rope_kernel_taken,
+                                                   monkeypatch):
+    """The trunk at heads of 128 on a TPU (the fixture's patch: the kernel
+    interpreted), `remat` on: q and k of every layer turn in the kernel,
+    read out of the projection, forward, recomputed and transposed, both
+    rotary forms; loss and gradients are `_rope`'s."""
+    hf = {**SHARE, "head_dim": 128}
+    cfg = dataclasses.replace(hl.config_from_hf(hf), remat=True)
+    params = _params(cfg)
+    tokens, targets = _data(hf, 2)
+    b, gb = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, cfg)
+    widths = {(2, 32, (h + 4) * 128) for h in (4, 6)}   # [q | k | v]
+    assert len(rope_kernel_taken) >= 2 * 2 * cfg.n_layers
+    assert widths == {s for s in rope_kernel_taken if s[-1] > 6 * 128}
+    taken = len(rope_kernel_taken)
+    monkeypatch.setattr(rope_kernel, "_on_tpu", lambda: False)
+    a, ga = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, cfg)
+    assert len(rope_kernel_taken) == taken
+    assert abs(float(a) - float(b)) < 1e-6
+    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-6)
+
+
 def test_bias_moves_by_the_sign_rule_and_adamw_leaves_it():
     cfg = hl.config_from_hf(HF, router_bias_rate=1e-2)
     params = _params(cfg, bias=0.0)
@@ -404,7 +429,7 @@ def test_yarn_table_is_the_float64_formulas_and_half_a_head_passes():
     ramp = np.clip((i - 5) / 11, 0, 1)
     want = (1 - ramp) * f + ramp * f / 64
     yarn = tfm.YarnConfig(64.0, 4096, 64.0, 1.0, r["attention_factor"])
-    np.testing.assert_allclose(tfm.yarn_inv_freq(5e5, 64, yarn), want,
+    np.testing.assert_allclose(rope_kernel.yarn_inv_freq(5e5, 64, yarn), want,
                                rtol=1e-15)
     np.testing.assert_allclose(reference.yarn_table(r, 64)[0], want,
                                rtol=1e-15)
@@ -414,7 +439,7 @@ def test_yarn_table_is_the_float64_formulas_and_half_a_head_passes():
     # ends that meet (every frequency turns more than beta_fast times in an
     # original length this long) are refused, not divided by
     with pytest.raises(ValueError, match="no ramp"):
-        tfm.yarn_inv_freq(5e5, 64, dataclasses.replace(
+        rope_kernel.yarn_inv_freq(5e5, 64, dataclasses.replace(
             yarn, original_max_len=10 ** 15))
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 24, 3 * 128))
     got = np.asarray(tfm._rope(x, 0, 5e5, 128, 64, yarn)).reshape(

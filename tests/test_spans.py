@@ -74,6 +74,9 @@ KERNEL_PROGRAMS = {
     rope.ROPE_PAIRS: (
         lambda x: rope.rope_interleaved(x, 0, 1e6, 192, 128),
         (_f32(1, 32, 384),)),
+    rope.ROPE_HALVES: (
+        lambda x: rope.rope_halves(x, 0, 1e4, 128, 64, at=(128, 256)),
+        (_f32(1, 32, 512),)),
     quant_comm.QUANT_BLOCKS: (
         lambda x: quant_comm.quantize_blocks(x, 128, "int8"),
         (_f32(1024),)),
