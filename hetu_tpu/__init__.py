@@ -12,6 +12,10 @@ code written against the reference imports unchanged:
     executor = ht.Executor({'train': [loss, train_op]}, ctx=ht.tpu(0))
     executor.run('train', feed_dict={...})
 """
+import sys as _sys
+import time as _time
+_IMPORT_T0 = _time.perf_counter()       # `hetu.import` starts here
+_JAX_PRELOADED = "jax" in _sys.modules
 from .graph.ops import *  # noqa: F401,F403 — the ~55-op registry
 from .graph.node import Variable, placeholder_op, Op, find_topo_sort
 from .graph.gradients import gradients
@@ -44,3 +48,5 @@ from . import telemetry
 from . import tokenizers
 
 __version__ = "0.1.0"
+telemetry.tracing.note_import(telemetry.tracing.IMPORT, _IMPORT_T0,
+                              _JAX_PRELOADED)

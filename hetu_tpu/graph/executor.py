@@ -924,8 +924,19 @@ class SubExecutor:
         phases = {"prestep_ms": (t_pre - t0) * 1e3,
                   "dispatch_ms": (t_d1 - t_d0) * 1e3,
                   "poststep_ms": (t_end - t_d1) * 1e3}
+        tel.record_compiles()
+        t_x = t_c1      # where the step's compile ends and its compute starts
         if compiled_now:
-            phases["compile_ms"] = (t_c1 - t_c0) * 1e3
+            # jax.jit compiles lazily: the step-fn build is `hetu.build`, and
+            # the first dispatch carries jax's trace, lowering and compile
+            # (or cache read). The compile log has them by program
+            # (tracing.compile_log); their union inside the dispatch's stamps
+            # is the rest of this step's compile
+            inside = _tr.clip_spans(
+                _tr.compile_spans(_tr.compile_log(since=t_d0)), t_d0, t_d1)
+            phases["compile_ms"] = (
+                t_c1 - t_c0 + _tr.span_union(inside)) * 1e3
+            t_x = max([t_d0] + [e for _, e in inside])
         if ps_comm_ms is not None:
             phases["ps_comm_ms"] = ps_comm_ms
             phases["ps_pull_ms"] = ps_pull_ms
@@ -938,13 +949,10 @@ class SubExecutor:
                             args={"step": int(step)})
             tracer.complete("feed", t0, t_pre)
             if compiled_now:
-                tracer.complete("compile", t_c0, t_c1)
-            # jax.jit compiles lazily: on a compiled_now step the first
-            # dispatch below carries the actual XLA trace+compile, so the
-            # "compile" span above is only the step-fn build
-            tracer.complete("compute", t_d0, t_d1,
-                            args={"includes_compile": True}
-                            if compiled_now else None)
+                tracer.complete("compile", t_c0, t_x)
+            # on a compiled_now step `compute` is the dispatch less the
+            # compile: from the last compiled program's end
+            tracer.complete("compute", t_x if compiled_now else t_d0, t_d1)
             tracer.complete("poststep", t_d1, t_end)
         tm = ex._tel_metrics
         if not self.training:

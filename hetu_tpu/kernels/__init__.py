@@ -23,6 +23,10 @@ Importing this package registers the four tier kernels; the graph ops
 import it lazily inside their compute fns so jax-free tools never pay
 for it.
 """
+import sys as _sys
+import time as _time
+_IMPORT_T0 = _time.perf_counter()       # `hetu.import.kernels` starts here
+_PALLAS_PRELOADED = "jax.experimental.pallas" in _sys.modules
 from . import registry                            # noqa: F401
 from .registry import (                           # noqa: F401
     KernelEligibilityError, KernelSpec, active, current_mode, dispatch,
@@ -30,3 +34,5 @@ from .registry import (                           # noqa: F401
     registered_kernels, reset_stats, resolve_mode,
 )
 from . import embed_grad, csr_spmm, quant_comm, fused_opt  # noqa: F401
+from ..telemetry import tracing as _tracing
+_tracing.note_import(_tracing.IMPORT_KERNELS, _IMPORT_T0, _PALLAS_PRELOADED)

@@ -46,6 +46,7 @@ from typing import Optional
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,  # noqa: F401
                        JsonlSink, DEFAULT_BUCKETS_MS)
 from .tracing import Tracer, XlaTraceWindow  # noqa: F401
+from . import tracing as _tracing
 
 MODES = ("off", "metrics", "trace")
 
@@ -135,6 +136,8 @@ class Telemetry:
         # step/step_ms/phases.
         self._snapshot_every = max(1, int(os.environ.get(
             "HETU_TELEMETRY_SNAPSHOT_EVERY", "20")))
+        # programs compiled before telemetry came on are not written
+        self._compile_seq = _tracing.compile_count()
         self._closed = False
 
     # -- tracing -----------------------------------------------------------
@@ -186,6 +189,20 @@ class Telemetry:
     def record(self, kind: str, **fields) -> None:
         """Free-form record (``ps_server`` health rows etc.)."""
         self.sink.write({"kind": kind, **fields})
+
+    def record_compiles(self) -> None:
+        """One ``compile`` record a program the process has compiled since
+        the last call, whichever Executor's step (or none) compiled it: the
+        compile log's fields. An int compare where nothing compiled."""
+        seen, self._compile_seq = self._compile_seq, _tracing.compile_count()
+        if seen == self._compile_seq:
+            return
+        for r in _tracing.compile_log():
+            if r["seq"] > seen:
+                self.sink.write({"kind": "compile", **{
+                    k: r[k] for k in ("fun_name", "trace_s", "lower_s",
+                                      "backend_s", "cache", "cache_read_s",
+                                      "thread", *_tracing.COMPILE_PARTS)}})
 
     # -- lifecycle ---------------------------------------------------------
     def flush(self) -> None:

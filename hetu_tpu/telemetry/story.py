@@ -215,7 +215,8 @@ class LedgerFollower:
 # under the telemetry dir.
 LEDGER_KINDS = {
     "metrics": ("step", "event", "final", "ps_server", "scope", "watch",
-                "plan", "model_info", "run_info", "xla_trace", "finding"),
+                "plan", "model_info", "run_info", "xla_trace", "finding",
+                "compile"),
     "trail_client": ("rpc", "anchor", "dropped"),
     "trail_server": ("srv", "anchor", "dropped"),
     "trail_events": ("straggler",),

@@ -20,8 +20,10 @@ steps, so a production job can capture an XLA-level trace of steps
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
+import sys
 import threading
 import time
 from typing import Optional
@@ -245,6 +247,30 @@ REMAT_DSA_GRADS = "hetu_dsa_idx_grads"
     "hetu.ps_push",       # gradient push issue, next-batch prefetch pulls
     "hetu.poststep",      # state commit, guard read, hetuscope, telemetry
 )
+
+# start-up, measured inside the program (the compile log below). One record
+# a process each: `import hetu_tpu` from the package's first line to its
+# last, and the first `import hetu_tpu.kernels`, which that package defers
+# (it brings `jax.experimental.pallas`, several times the package itself)
+IMPORT = "hetu.import"
+IMPORT_KERNELS = "hetu.import.kernels"
+# one record a COMPILED PROGRAM, from jax's own `monitoring` events (jax
+# 0.9.0: jax/_src/dispatch.py:60-62, jax/_src/compiler.py:435-452): the three
+# parts of a program as time spans carrying `fun_name`, and the persistent
+# cache's answer between the last two. In a profiler capture jax annotates
+# the same work itself (`@profiler.annotate_function`): an idle gap of the
+# device under a compile is labelled `lower_sharding_computation` (pxla.py)
+# and `backend_compile_and_load` (compiler.py) on the capture's own clock,
+# so no span of ours stands beside them
+EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"           # -> trace_s
+EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"  # -> lower_s
+EV_BACKEND = "/jax/core/compile/backend_compile_duration"     # -> backend_s
+EV_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"            # cache: "hit"
+EV_CACHE_MISS = "/jax/compilation_cache/cache_misses"         # cache: "miss"
+EV_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_PARTS = ("trace", "lower", "backend")
+COMPILE_LOG_BOUND = 1024   # programs kept, the newest; `dropped` beyond
 
 # jax.profiler.TraceAnnotation, resolved lazily on first use (None =
 # unresolved; _NoSpan where jax is unavailable — stay stdlib-importable)
@@ -535,3 +561,256 @@ def scoped(name: str, fn):
     (``SCOPE_OPT``). Trace-time only: HLO metadata, no run-time cost."""
     import jax
     return jax.named_scope(name)(fn)
+
+
+# -- start-up: the compile log -------------------------------------------------
+
+def span_union(spans) -> float:
+    """Seconds covered by ``(start, end)`` spans, counted once where they
+    overlap: a jitted function traced inside a jitted function reports its
+    trace twice, and every start-up total is this union, never the sum."""
+    total, edge = 0.0, float("-inf")
+    for s, e in sorted(spans):
+        if e > edge:
+            total += e - max(s, edge)
+            edge = e
+    return total
+
+
+def clip_spans(spans, since: float, until: float) -> list:
+    """The parts of ``spans`` that lie inside ``[since, until]``."""
+    return [(max(s, since), min(e, until)) for s, e in spans
+            if e > since and s < until]
+
+
+def compile_spans(records) -> list:
+    """The ``(start, end)`` spans of every part ``records`` reached."""
+    return [r[p] for r in records for p in COMPILE_PARTS
+            if r.get(p) is not None]
+
+
+class _ThreadCompiles:
+    """What one thread has traced and lowered and not yet compiled."""
+
+    __slots__ = ("tid", "pending", "open", "cache", "cache_read_s", "asked",
+                 "calls", "listener_s", "unclaimed_s")
+
+    def __init__(self):
+        self.tid = threading.get_ident()
+        self.pending = []       # outermost trace spans: (start, end, name)
+        self.open = None        # the lowered program waiting for its backend
+        self.calls = 0
+        self.listener_s = 0.0
+        self.unclaimed_s = 0.0  # pending traces dropped past the cap
+        self._no_cache()
+
+    def _no_cache(self):
+        self.cache = self.asked = None
+        self.cache_read_s = 0.0
+
+
+class CompileLog:
+    """The newest ``COMPILE_LOG_BOUND`` compiled programs of the process, one
+    record each, fed by jax's ``monitoring`` events (:func:`_listen`). The
+    events of one program arrive in order on the thread that compiles it:
+    trace spans innermost first and the outermost last (``fun_name`` bare),
+    then the lowering (``jit(<name>)``), then the cache's hit or miss, then
+    the backend span that closes the record. Trace spans are not kept one a
+    record: a thread keeps only its outermost pending ones (an arriving span
+    swallows every pending one that starts inside it), and the program that
+    opens next under that name takes its own."""
+
+    _PENDING_CAP = 1 << 16  # a decoder's step is thousands of sibling
+                            # traces until its own arrives; beyond the cap
+                            # are traces no program claims (eval_shape)
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._records = collections.deque(maxlen=COMPILE_LOG_BOUND)
+        self._threads = []          # every _ThreadCompiles made, for stats
+        self._local = threading.local()
+        self.dropped = 0
+        self.seq = 0                # programs closed so far; a record's `seq`
+
+    def _state(self) -> _ThreadCompiles:
+        st = getattr(self._local, "st", None)
+        if st is None:
+            st = self._local.st = _ThreadCompiles()
+            with self._lock:
+                self._threads.append(st)
+        return st
+
+    # jax's stamps are time.time(); the log keeps perf_counter's clock
+    @staticmethod
+    def _perf(t_unix: float) -> float:
+        return _T0_PERF + (t_unix - _T0_UNIX)
+
+    def on_span(self, event, start, end, fun_name="", **_kw) -> None:
+        t_in = time.perf_counter()
+        st = self._state()
+        s, e = self._perf(start), self._perf(end)
+        pend = st.pending
+        if event != EV_BACKEND:
+            # a lowering swallows too: a lowering rule that traces a helper
+            # (threefry's) reports it before the lowering's own span arrives
+            while pend and pend[-1][0] >= s:
+                pend.pop()
+        if event == EV_TRACE:
+            pend.append((s, e, fun_name))
+            if len(pend) > self._PENDING_CAP:
+                half = self._PENDING_CAP // 2
+                st.unclaimed_s += sum(b - a for a, b, _ in pend[:half])
+                del pend[:half]
+        elif event == EV_LOWER:
+            rec = self._open(st, fun_name)
+            rec.update(lower=(s, e), lower_s=e - s, end=e)
+            if pend and fun_name.endswith("(" + pend[-1][2] + ")"):
+                a, b, _ = pend.pop()
+                rec["trace"], rec["trace_s"] = (a, b), b - a
+        elif event == EV_BACKEND:
+            rec = st.open
+            if rec is None or rec["fun_name"] != fun_name:
+                # compiled from a lowering this log no longer holds open
+                rec = self._open(st, fun_name)
+            rec["backend"], rec["backend_s"], rec["end"] = (s, e), e - s, e
+            # asked and not found is a miss, written back or not
+            rec["cache"] = st.cache or ("miss" if st.asked else None)
+            rec["cache_read_s"] = st.cache_read_s
+            st._no_cache()
+            self._close(st)
+        st.calls += 1
+        st.listener_s += time.perf_counter() - t_in
+
+    def on_event(self, event, **_kw) -> None:
+        if event == EV_CACHE_HIT:
+            self._state().cache = "hit"
+        elif event == EV_CACHE_MISS:
+            self._state().cache = "miss"
+        elif event == EV_CACHE_ASKED:
+            self._state().asked = True
+        else:
+            return
+        self._local.st.calls += 1
+
+    def on_duration(self, event, duration, **_kw) -> None:
+        if event == EV_CACHE_READ:
+            st = self._state()
+            st.cache_read_s = duration
+            st.calls += 1
+
+    def _open(self, st, fun_name) -> dict:
+        """A new open record on ``st``; one still open there was lowered
+        and never compiled, and is kept as it is."""
+        self._close(st)
+        st.open = {"fun_name": fun_name, "thread": st.tid,
+                   "trace": None, "trace_s": 0.0,
+                   "lower": None, "lower_s": 0.0,
+                   "backend": None, "backend_s": 0.0,
+                   "cache": None, "cache_read_s": 0.0}
+        return st.open
+
+    def _close(self, st) -> None:
+        rec, st.open = st.open, None
+        if rec is None:
+            return
+        with self._lock:
+            if len(self._records) == self._records.maxlen:
+                self.dropped += 1
+            self.seq = rec["seq"] = self.seq + 1
+            self._records.append(rec)
+
+    def records(self, since=None, until=None) -> list:
+        """Copies of the kept records, oldest first, whose ``end`` (the end
+        of the last part a program reached) lies in ``[since, until]``."""
+        with self._lock:
+            out = [dict(r) for r in self._records
+                   if (since is None or r["end"] >= since)
+                   and (until is None or r["end"] <= until)]
+        return out
+
+    def stats(self) -> dict:
+        """What the log cost and what it lost: whether jax's events reach it
+        at all (``listening``), listener calls and the seconds spent inside
+        them (the time-span listener's; the two plain ones are a dict
+        lookup), programs dropped past the bound, and the outermost traces
+        of this thread that no program has claimed."""
+        with self._lock:
+            threads = list(self._threads)
+            kept, dropped = len(self._records), self.dropped
+        st = self._state()
+        return {"listening": _LISTENING,
+                "calls": sum(t.calls for t in threads),
+                "listener_s": sum(t.listener_s for t in threads),
+                "programs": kept, "dropped": dropped,
+                "pending_traces": len(st.pending),
+                "pending_trace_s": st.unclaimed_s + span_union(
+                    [(a, b) for a, b, _ in st.pending])}
+
+
+_LOG = CompileLog()
+_LISTENING = False
+_IMPORTS = []       # the IMPORT and IMPORT_KERNELS records, one a name
+
+
+def _listen() -> bool:
+    """Hand ``_LOG`` to jax's ``monitoring``, once a process. A process that
+    has not imported jax compiles nothing, and this module stays
+    stdlib-importable for it (the launcher's parent, ``bin/hetutrail``).
+    With jax loaded, a jax that lacks one of the three hooks RAISES here: a
+    log that hears nothing would read as a start-up that compiled nothing."""
+    global _LISTENING
+    if _LISTENING or "jax" not in sys.modules:
+        return _LISTENING
+    from jax import monitoring
+    monitoring.register_event_time_span_listener(_LOG.on_span)
+    monitoring.register_event_listener(_LOG.on_event)
+    monitoring.register_event_duration_secs_listener(_LOG.on_duration)
+    _LISTENING = True
+    return _LISTENING
+
+
+def compile_log(since: Optional[float] = None,
+                until: Optional[float] = None) -> list:
+    """The process's compiled programs, oldest first, one dict each:
+    ``fun_name`` (``jit(<name>)``), ``trace_s`` / ``lower_s`` /
+    ``backend_s`` with ``trace`` / ``lower`` / ``backend`` as ``(start,
+    end)`` on ``time.perf_counter()``'s clock (None for a part the program
+    did not reach), ``cache`` (``"hit"``, ``"miss"``, None where the
+    persistent cache was not asked), ``cache_read_s``, ``thread``, ``seq``
+    (its number in the process, from 1), ``end``. ``since`` / ``until`` cut
+    by ``end``."""
+    return _LOG.records(since, until)
+
+
+def compile_count() -> int:
+    """Programs the log has closed so far (a record's ``seq``): what a
+    caller compares to know that nothing compiled since it last read."""
+    return _LOG.seq
+
+
+def compile_log_stats() -> dict:
+    return _LOG.stats()
+
+
+def note_import(name: str, t0_perf: float, jax_preloaded: bool) -> None:
+    """The last line of ``hetu_tpu/__init__.py`` (``IMPORT``) and of
+    ``hetu_tpu/kernels/__init__.py`` (``IMPORT_KERNELS``): one record a
+    name, from the stamp its first line took to now."""
+    _listen()
+    if all(r["name"] != name for r in _IMPORTS):
+        t1 = time.perf_counter()
+        _IMPORTS.append({"name": name, "start": t0_perf, "end": t1,
+                         "dur_s": t1 - t0_perf,
+                         "jax_preloaded": bool(jax_preloaded)})
+
+
+def import_records() -> list:
+    """``{"name", "start", "end", "dur_s", "jax_preloaded"}`` of each import
+    this process has finished, ``IMPORT`` and, once something used a kernel,
+    ``IMPORT_KERNELS`` (on ``time.perf_counter()``'s clock; the second lies
+    inside the first, or inside a program's trace, where that is what
+    imported it first)."""
+    return [dict(r) for r in _IMPORTS]
+
+
+_listen()
