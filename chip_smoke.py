@@ -695,6 +695,9 @@ KERNEL_SHAPES = {
                     "laguna-full": (1, 16384, 48, 8, 128, 64, True),
                     "laguna-window": (1, 16384, 64, 8, 128, 0, False),
                     "lfm2": (4, 8192, 32, 8, 64, 0, False)},
+    # (batch, seq, heads, head width, groups, state, chunk): a quarter of
+    # granite-4.0-h-micro's scan, eight chunks of 256, bf16
+    "ssd": (1, 2048, 64, 64, 1, 128, 256),
 }
 
 
@@ -711,7 +714,8 @@ def phase_kernels(*, shapes=None, chip=True):
                                            linear_nll_reference)
     from hetu_tpu.kernels.rope import rope_halves, rope_interleaved
     from hetu_tpu.models.transformer import (YarnConfig, _rope,
-                                             _rope_interleaved)
+                                             _rope_interleaved, _ssd,
+                                             _ssd_kernels)
 
     shapes = {**KERNEL_SHAPES, **(shapes or {})}
     rng = np.random.RandomState(0)
@@ -915,6 +919,31 @@ def phase_kernels(*, shapes=None, chip=True):
                     q_k_and_cotangent(lambda x, at, *rope: _rope(
                         x[..., at[0]:at[0] + at[1]], *rope)), (rx, rg),
                     atol=2 ** -5, exact=chip)
+
+        # -- the chunked Mamba-2 scan: y and the five cotangents against
+        # `_ssd`'s einsums, each over its own largest entry (d dt is in the
+        # hundreds where dx is in units)
+        b, s, h, d, groups, state, chunk = shapes["ssd"]
+        sx, sg = (jnp.asarray(rng.randn(b, s, h, d), t)
+                  for t in (jnp.bfloat16, jnp.float32))
+        sdt = jnp.asarray(np.log1p(np.exp(rng.randn(b, s, h) - 2.0)),
+                          jnp.float32)
+        s_a = jnp.asarray(np.log(rng.uniform(1.0, 16.0, h)), jnp.float32)
+        sb, sc = (jnp.asarray(0.3 * rng.randn(b, s, groups, state),
+                              jnp.bfloat16) for _ in range(2))
+
+        def scan_and_cotangents(fn):
+            def run(x, dt, a_log, bm, cm, g):
+                y, vjp = jax.vjp(lambda *a: fn(*a, chunk), x, dt, a_log, bm,
+                                 cm)
+                return [leaf.astype(jnp.float32)
+                        / jnp.max(jnp.abs(leaf.astype(jnp.float32)))
+                        for leaf in (y, *vjp(g))]
+            return run
+
+        compare("ssd", scan_and_cotangents(_ssd_kernels),
+                scan_and_cotangents(_ssd), (sx, sdt, s_a, sb, sc, sg),
+                atol=2e-2)
 
         # -- the four registry kernels
         n, d, vocab = shapes["embed_grad"]

@@ -38,6 +38,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import rope as rope_kernel
+from ..kernels import ssd as ssd_kernel
 from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  REMAT_ATTN_V, REMAT_CANDIDATES,
                                  REMAT_DSA_GRADS, REMAT_MLA_LATENT,
@@ -1481,6 +1482,28 @@ def _ssd(x, dt, A_log, Bm, Cm, chunk):
     return y.reshape(shape)
 
 
+def _scan(x, dt, A_log, Bm, Cm, chunk, mesh=None):
+    """``_ssd`` by whichever implementation serves the call: the Mosaic
+    kernels of ``kernels/ssd.py`` where ``ssd_kernel.takes`` admits it (a
+    TPU, one program, whole chunks of whole lane tiles), which hold a
+    chunk's decay matrix, masked scores and state in VMEM and bring their
+    own backward pass; the einsums of ``_ssd`` everywhere else."""
+    if ssd_kernel.takes(x, Bm, chunk, mesh):
+        return _ssd_kernels(x, dt, A_log, Bm, Cm, chunk)
+    return _ssd(x, dt, A_log, Bm, Cm, chunk)
+
+
+def _ssd_kernels(x, dt, A_log, Bm, Cm, chunk):
+    """``_ssd``'s signature over the kernels, without the rule (tests and
+    ``chip_smoke.py`` call it at shapes of their own). The kernels are handed
+    the cumulative log-decay, so ``_ssm_log_decay`` stays the one place it is
+    made; all of their time is under ``hetu_ssd_inchunk``."""
+    B, T, H = dt.shape
+    with jax.named_scope(SCOPE_SSD_INCHUNK):
+        acs = _ssm_log_decay(dt.reshape(B, T // chunk, chunk, H), A_log)
+        return ssd_kernel.ssd(x, dt, acs.reshape(B, T, H), Bm, Cm, chunk)
+
+
 def _causal_conv(x, w, bias=None, activation=None):
     """A causal depthwise convolution by shifted sums: x (B, T, C), one
     weight a tap and channel ``w`` (K, C), zeros before the sequence;
@@ -1527,8 +1550,8 @@ def _mamba(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
         raise NotImplementedError("a mamba layer takes no attention bias")
     z, x, Bm, Cm, dt_raw = _mamba_inputs(h, p, cfg)
     with jax.named_scope(SCOPE_SSM_SCAN):
-        y = _ssd(x, _ssm_dt(dt_raw, p["dt_bias"]), p["A_log"], Bm, Cm,
-                 cfg.ssm.chunk)
+        y = _scan(x, _ssm_dt(dt_raw, p["dt_bias"]), p["A_log"], Bm, Cm,
+                  cfg.ssm.chunk, mesh)
         with jax.named_scope(SCOPE_SSD_ENTER):
             y = y + p["D"][:, None] * x
     with jax.named_scope(SCOPE_SSM_GATE):

@@ -46,7 +46,9 @@ _T0_UNIX = time.time()
 # for one of several, whose calls a step say which backward a cell takes;
 # `rope_pairs` and `rope_halves`, the rotation of q and k in one pass, whose
 # calls a step say that a cell's rotation runs in the kernel and not in
-# `transformer._rope` / `_rope_interleaved`).
+# `transformer._rope` / `_rope_interleaved`; `ssd_fwd` and `ssd_bwd`, the
+# chunked Mamba-2 scan, whose calls say that a mamba layer's scan runs in
+# `kernels/ssd.py` and not in `transformer._ssd`'s einsums).
 STEP = "hetu_step"        # one SubExecutor.run call, step_num=<step>
 # phase scopes in the compiled program (HLO metadata `op_name`): a device op
 # under SCOPE_OPT is optimizer work, one under `transpose(` backward (its
@@ -102,7 +104,10 @@ SCONV_SCOPES = (SCOPE_SCONV_PROJ, SCOPE_SCONV_CONV)
 # softplus stays directly under the outer scope
 SCOPE_SSD_INCHUNK = "hetu_ssd_inchunk"  # the chunks cut, the log-decay, the
                                         # decay matrix, masked C B^T, its
-                                        # product with x dt
+                                        # product with x dt; where the scan
+                                        # runs in `kernels/ssd.py`, all of
+                                        # both kernels (they hold the other
+                                        # two parts too)
 SCOPE_SSD_STATES = "hetu_ssd_states"    # each chunk's own state and the
                                         # recurrence over the chunk states
 SCOPE_SSD_ENTER = "hetu_ssd_enter"      # the entering state's part C S, and

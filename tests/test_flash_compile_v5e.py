@@ -1,5 +1,5 @@
-"""The three flash kernels, the three of the fused CE and the rotary kernel
-compiled for a v5e that is described, not attached (the TPU's compiler is
+"""The three flash kernels, the three of the fused CE, the rotary kernel and
+the two of the chunked scan compiled for a v5e that is described, not attached (the TPU's compiler is
 installed here): what Mosaic refuses at the real shapes — a misaligned slice, too much VMEM, a transpose it does not
 take — fails here and costs no chip time. Nothing runs, so nothing here is a
 result or a time.
@@ -24,6 +24,7 @@ from hetu_tpu.kernels import dsa
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels import fused_ce as fc
 from hetu_tpu.kernels import rope
+from hetu_tpu.kernels import ssd
 
 
 @pytest.fixture(scope="module")
@@ -773,3 +774,54 @@ def test_share_layer_loops_over_its_rows_in_place(one_chip, no_compile_cache):
     # whose trip count the compiler does not know
     loops = [l for l in text.splitlines() if re.search(r" while\(", l)]
     assert sum("known_trip_count" not in l for l in loops) >= 7, loops
+
+
+# granite-4.0-h-micro.pretrain-seq8192-b1's scan: (batch, seq, heads, head
+# columns, groups, state, chunk); digests of the lowered forward and backward
+# halves, locations cut, as `MANY_TILE` holds the attention kernels': a PR
+# that changes a kernel of `kernels/ssd.py` on purpose re-pins its half
+GRANITE_SCAN = (1, 8192, 64, 64, 1, 128, 256)
+SSD_LOWERED = {"forward": "e1690c5609fd625f", "backward": "83d065e4abef390b"}
+
+
+@pytest.mark.parametrize("half", ["forward", "backward"])
+def test_ssd_kernels_compile_for_v5e_at_granites_scan(
+        one_chip, no_compile_cache, monkeypatch, half):
+    """The chunked scan's two kernels at the cell's call, one sequence of
+    8,192 positions, 64 heads of 64 columns on one group's state of 128, in
+    chunks of 256, bfloat16: ONE Mosaic call a half under the kernel's name,
+    16 heads a grid step inside the VMEM Mosaic gives unasked, the entering
+    states the only array of the states' size, and the lowered kernel the
+    one that was measured."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
+    B, T, H, P, G, N, Q = GRANITE_SCAN
+
+    def arr(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, row, bc = arr(B, T, H, P), arr(B, T, H, dtype=jnp.float32), arr(
+        B, T, G, N)
+    assert ssd.takes(x, bc, Q) and ssd._heads(H, G, P, N, Q, 2) == (16, False)
+    if half == "forward":
+        fn, args, name = (lambda *a: ssd._forward(*a, Q),
+                          (x, row, row, bc, bc), ssd.SSD_FWD)
+    else:
+        entering = arr(B, T // Q, H // 16, N, 16 * P, dtype=jnp.float32)
+        fn, args, name = (lambda *a: ssd._backward(*a, Q),
+                          (x, row, row, bc, bc, entering,
+                           arr(B, T, H, P, dtype=jnp.float32)), ssd.SSD_BWD)
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.compile().as_text()
+    assert _count_by_name(_kernel_calls(text), (ssd.SSD_FWD, ssd.SSD_BWD)) == {
+        ssd.SSD_FWD: int(half == "forward"),
+        ssd.SSD_BWD: int(half == "backward")}, name
+    assert "vmem_limit_bytes" not in text
+    # nothing (H, Q, Q) or (chunks, H, Q, Q) leaves the kernel: the largest
+    # float32 array is y's or dy's, (T, H * P)
+    sizes = {math.prod(map(int, dims.split(",")))
+             for kind, dims in re.findall(r"(f32)\[([\d,]+)\]", text)}
+    assert max(sizes) == T * H * P
+    lowered_text, kernels = _lowered_without_locations(lowered.as_text())
+    assert kernels == 1
+    assert hashlib.sha256(lowered_text.encode()).hexdigest()[:16] == (
+        SSD_LOWERED[half])

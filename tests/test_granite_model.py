@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hetu_tpu.kernels import ssd as ssd_kernel
 from hetu_tpu.models import bert, hf_granite, hf_olmoe, hf_ouro
 from hetu_tpu.models import transformer as tfm
 from hetu_tpu.telemetry import tracing
@@ -195,22 +196,55 @@ ORDERS = {"mamba_first": ["mamba", "mamba", "attention", "attention"],
           "alternating": ["mamba", "attention", "mamba", "attention"]}
 
 
-@pytest.mark.parametrize("order", sorted(ORDERS))
-@pytest.mark.parametrize("chunk", [T, T // 2, T // 8])
-def test_chunked_form_is_the_recurrence_over_time(order, chunk):
+# the widths the Mosaic kernels of `kernels/ssd.py` slice: heads of 64
+# columns, a state of 128, eight heads a grid step, two chunks of 128
+KERNEL_WIDTHS = dict(hidden_size=256, mamba_n_heads=8, mamba_d_head=64,
+                     mamba_d_state=128, max_position_embeddings=256)
+
+
+@pytest.fixture()
+def ssd_kernels_taken(monkeypatch):
+    """What `transformer._scan` does on a TPU: the kernels wherever their
+    rule admits the call (interpreted here). -> the chunks they ran at."""
+    seen = []
+    scan = ssd_kernel.ssd
+
+    def noting(x, dt, acs, Bm, Cm, chunk):
+        seen.append(chunk)
+        return scan(x, dt, acs, Bm, Cm, chunk)
+
+    monkeypatch.setattr(ssd_kernel, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssd_kernel, "ssd", noting)
+    return seen
+
+
+@pytest.mark.parametrize("order,chunk,impl", [
+    *((order, chunk, "einsums") for chunk in (T, T // 2, T // 8)
+      for order in sorted(ORDERS)),
+    ("mamba_first", 128, "kernels"), ("alternating", 128, "kernels")])
+def test_chunked_form_is_the_recurrence_over_time(order, chunk, impl,
+                                                  request):
     """The SSD form at 1, 2 and 8 chunks a sequence against the reference's
     scan over time, in stacks whose runs differ: loss within 1e-6, final
-    hidden state within 1e-5 of its RMS (float32 both, summation order)."""
-    hf = {**HF, "layer_types": ORDERS[order], "mamba_chunk_size": chunk}
+    hidden state within 1e-5 of its RMS (float32 both, summation order).
+    Both implementations of it: `_ssd`'s einsums at the toy widths, and the
+    kernels of `kernels/ssd.py` at the narrowest widths they slice, two
+    chunks of 128 (there dt rides in the decay's exponent: 2e-5)."""
+    taken = (request.getfixturevalue("ssd_kernels_taken")
+             if impl == "kernels" else None)
+    hf = {**HF, **(KERNEL_WIDTHS if taken is not None else {}),
+          "layer_types": ORDERS[order], "mamba_chunk_size": chunk}
     cfg, params = _seeded(hf, 6)
     sd = hf_granite.state_dict_from_params(params, cfg)
-    tokens, targets = _data(hf["vocab_size"], 7)
+    tokens, targets = _data(hf["vocab_size"], 7,
+                            seq=T if taken is None else 2 * chunk)
     want_loss, want = reference.loss_terms(sd, tokens, targets, hf)
     with jax.default_matmul_precision("highest"):
         loss = tfm.loss_fn(params, tokens, targets, cfg)
         h, _ = tfm.forward_hidden(params, tokens, cfg)
     assert abs(float(loss) - float(want_loss)) < 1e-6
-    assert _rel(h, want["hidden"][-1]) < 1e-5
+    assert _rel(h, want["hidden"][-1]) < (1e-5 if taken is None else 2e-5)
+    assert taken is None or (taken and set(taken) == {chunk})
 
 
 def test_two_groups_share_b_and_c_by_group():

@@ -13,7 +13,8 @@ import pytest
 
 import hetu_tpu as ht
 from hetu_tpu.kernels import (csr_spmm, embed_grad, flash_attention,
-                              fused_ce, fused_opt, quant_comm, registry, rope)
+                              fused_ce, fused_opt, quant_comm, registry, rope,
+                              ssd)
 from hetu_tpu.telemetry import tracing as tr
 
 
@@ -77,6 +78,12 @@ KERNEL_PROGRAMS = {
     rope.ROPE_HALVES: (
         lambda x: rope.rope_halves(x, 0, 1e4, 128, 64, at=(128, 256)),
         (_f32(1, 32, 512),)),
+    ssd.SSD_FWD: (
+        lambda x, dt, bc: ssd.ssd(x, dt, -dt, bc, bc, 128),
+        (_f32(1, 128, 2, 64), _f32(1, 128, 2), _f32(1, 128, 1, 128))),
+    ssd.SSD_BWD: (
+        jax.grad(lambda x, dt, bc: ssd.ssd(x, dt, -dt, bc, bc, 128).sum()),
+        (_f32(1, 128, 2, 64), _f32(1, 128, 2), _f32(1, 128, 1, 128))),
     quant_comm.QUANT_BLOCKS: (
         lambda x: quant_comm.quantize_blocks(x, 128, "int8"),
         (_f32(1024),)),
