@@ -698,6 +698,10 @@ KERNEL_SHAPES = {
     # (batch, seq, heads, head width, groups, state, chunk): a quarter of
     # granite-4.0-h-micro's scan, eight chunks of 256, bf16
     "ssd": (1, 2048, 64, 64, 1, 128, 256),
+    # a quarter of nemotron-twotower-30b-a3b's scan: B and C in 8 groups of
+    # 8 heads (8 heads a grid step where Granite takes 16), sixteen chunks
+    # of ONE lane tile
+    "ssd_groups": (1, 2048, 64, 64, 8, 128, 128),
 }
 
 
@@ -923,27 +927,28 @@ def phase_kernels(*, shapes=None, chip=True):
         # -- the chunked Mamba-2 scan: y and the five cotangents against
         # `_ssd`'s einsums, each over its own largest entry (d dt is in the
         # hundreds where dx is in units)
-        b, s, h, d, groups, state, chunk = shapes["ssd"]
-        sx, sg = (jnp.asarray(rng.randn(b, s, h, d), t)
-                  for t in (jnp.bfloat16, jnp.float32))
-        sdt = jnp.asarray(np.log1p(np.exp(rng.randn(b, s, h) - 2.0)),
-                          jnp.float32)
-        s_a = jnp.asarray(np.log(rng.uniform(1.0, 16.0, h)), jnp.float32)
-        sb, sc = (jnp.asarray(0.3 * rng.randn(b, s, groups, state),
-                              jnp.bfloat16) for _ in range(2))
+        for name in ("ssd", "ssd_groups"):
+            b, s, h, d, groups, state, chunk = shapes[name]
+            sx, sg = (jnp.asarray(rng.randn(b, s, h, d), t)
+                      for t in (jnp.bfloat16, jnp.float32))
+            sdt = jnp.asarray(np.log1p(np.exp(rng.randn(b, s, h) - 2.0)),
+                              jnp.float32)
+            s_a = jnp.asarray(np.log(rng.uniform(1.0, 16.0, h)), jnp.float32)
+            sb, sc = (jnp.asarray(0.3 * rng.randn(b, s, groups, state),
+                                  jnp.bfloat16) for _ in range(2))
 
-        def scan_and_cotangents(fn):
-            def run(x, dt, a_log, bm, cm, g):
-                y, vjp = jax.vjp(lambda *a: fn(*a, chunk), x, dt, a_log, bm,
-                                 cm)
-                return [leaf.astype(jnp.float32)
-                        / jnp.max(jnp.abs(leaf.astype(jnp.float32)))
-                        for leaf in (y, *vjp(g))]
-            return run
+            def scan_and_cotangents(fn, chunk=chunk):
+                def run(x, dt, a_log, bm, cm, g):
+                    y, vjp = jax.vjp(lambda *a: fn(*a, chunk), x, dt, a_log,
+                                     bm, cm)
+                    return [leaf.astype(jnp.float32)
+                            / jnp.max(jnp.abs(leaf.astype(jnp.float32)))
+                            for leaf in (y, *vjp(g))]
+                return run
 
-        compare("ssd", scan_and_cotangents(_ssd_kernels),
-                scan_and_cotangents(_ssd), (sx, sdt, s_a, sb, sc, sg),
-                atol=2e-2)
+            compare(name, scan_and_cotangents(_ssd_kernels),
+                    scan_and_cotangents(_ssd), (sx, sdt, s_a, sb, sc, sg),
+                    atol=2e-2)
 
         # -- the four registry kernels
         n, d, vocab = shapes["embed_grad"]

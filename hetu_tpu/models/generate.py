@@ -186,6 +186,11 @@ def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
         "cached index keys and attends to the kept ones, or d_head="
         f"{cfg.d_head} is a head width of its own (_decode_layer mirrors "
         "the attention block at d_model // n_heads)")
+    assert not cfg.single_sublayer and cfg.mlp != "relu2", (
+        f"decode mirrors a block of two halves with a GELU or SwiGLU MLP: "
+        f"single_sublayer={cfg.single_sublayer} (layers of ONE sublayer: a "
+        f"mixer without an MLP half, an MLP half without a mixer), mlp="
+        f"{cfg.mlp!r} (_decode_layer mirrors neither)")
     assert not cfg.d_ff_shared, (
         f"decode does not mirror a shared expert (d_ff_shared="
         f"{cfg.d_ff_shared}: the always-on branch of an expert layer)")

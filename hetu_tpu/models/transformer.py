@@ -52,14 +52,14 @@ from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  SCOPE_EMBED, SCOPE_EXIT, SCOPE_FWD,
                                  SCOPE_HEAD,
                                  SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP,
-                                 SCOPE_MLA_Q, SCOPE_MOE_COMBINE,
+                                 SCOPE_MLA_Q, SCOPE_MOE_ACT, SCOPE_MOE_COMBINE,
                                  SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
                                  SCOPE_MOE_ROUTE, SCOPE_MOE_SHARED,
                                  SCOPE_OPT, SCOPE_SCONV_CONV,
                                  SCOPE_SCONV_PROJ, SCOPE_SSD_ENTER,
                                  SCOPE_SSD_INCHUNK, SCOPE_SSD_STATES,
                                  SCOPE_SSM_CONV, SCOPE_SSM_GATE,
-                                 SCOPE_SWA_ATTN,
+                                 SCOPE_SSM_GATE_NORM, SCOPE_SWA_ATTN,
                                  SCOPE_SSM_PROJ, SCOPE_SSM_SCAN, scoped)
 
 _log = logging.getLogger(__name__)
@@ -92,13 +92,24 @@ class MoEConfigError(ValueError):
 class SSMConfig:
     """The six sizes of a Mamba-2 mixer (Dao & Gu 2024, arXiv:2405.21060;
     ``_mamba``): ``n_heads`` heads of ``head_dim`` channels, each head a
-    (head_dim, d_state) state; B and C are shared by the heads of a group."""
+    (head_dim, d_state) state; B and C are shared by the heads of a group.
+    The last two are ``mamba_ssm``'s ``Mamba2`` where HF Granite has neither
+    (``models/hf_nemotron_h.py`` sets both); the defaults leave the program
+    and the initial weights what they are."""
     n_heads: int = 64
     head_dim: int = 64
     d_state: int = 128
     n_groups: int = 1
     d_conv: int = 4             # width of the causal depthwise convolution
     chunk: int = 256            # positions a chunk of the SSD form
+    norm_groups: int = 1        # the gated norm's statistic runs over each
+                                # of this many runs of d_inner / norm_groups
+                                # channels (``RMSNormGated``'s ``group_size``);
+                                # 1 = over all channels
+    dt_init: Optional[tuple] = None     # (min, max, floor): a head's initial
+                                        # step size log-uniform in [min, max],
+                                        # at least floor, and ``dt_bias`` its
+                                        # inverse softplus; None = dt_bias 1
 
     @property
     def d_inner(self):
@@ -269,7 +280,9 @@ class TransformerConfig:
                                 # learned "pos" table
     rope_theta: float = 10000.0
     mlp: str = "gelu"           # "swiglu": down(silu(gate(x))·up(x)) with
-                                # an extra w3 (up) weight, no biases used
+                                # an extra w3 (up) weight, no biases used;
+                                # "relu2": down(relu(up(x))²), two matrices,
+                                # no gate and no bias leaves (Nemotron-H)
     n_kv_heads: int = 0         # grouped-query attention: 0 = n_heads
                                 # (MHA); otherwise k/v project to n_kv
                                 # heads and broadcast to the q heads
@@ -292,7 +305,8 @@ class TransformerConfig:
                                  # ``ln2_post_*``); pre-LN only
     # Hybrid stacks (models/hf_granite.py sets all three):
     layer_types: tuple = ()     # a mixer of ``_KINDS`` a layer ("attention",
-                                # "mamba", "conv", "mla", "dsa", "window");
+                                # "mamba", "conv", "mla", "dsa", "window";
+                                # "mlp" under ``single_sublayer``);
                                 # () =
                                 # ``n_layers``
                                 # of attention. ``encode`` scans each run of
@@ -341,6 +355,12 @@ class TransformerConfig:
     attn_gate: bool = False     # a per-head sigmoid gate (leaf ``wg``, (D,
                                 # heads)) from the layer's normed input on
                                 # attention's output, before ``wo``
+    # Layers of ONE sublayer (models/hf_nemotron_h.py sets it):
+    single_sublayer: bool = False   # every layer is x + f(norm(x)) with ONE
+                                    # norm: a mixer of ``layer_types`` WITHOUT
+                                    # an MLP half (kind: its name + ``ALONE``),
+                                    # or, named "mlp", the MLP half WITHOUT a
+                                    # mixer (experts where ``n_experts``)
 
     def __post_init__(self):
         if self.layer_types:
@@ -382,11 +402,20 @@ class TransformerConfig:
                 f"attn_gate={self.attn_gate}: of attention and window "
                 "layers (mla and dsa layers have neither), rope_dim an even "
                 "count of a head's columns")
+        if ("mlp" in self.layer_types) > self.single_sublayer or (
+                self.single_sublayer and (
+                    self.post_ln or self.sandwich_norm or self.n_dense_layers
+                    or self.n_loops > 1)):
+            raise ValueError(
+                f"layer_types={self.layer_types}, single_sublayer="
+                f"{self.single_sublayer}: an \"mlp\" layer is a layer of a "
+                "single-sublayer stack, which is pre-LN without sandwich "
+                "norms, leading dense layers or loops")
         if self.d_ff_shared and not (self.n_experts
-                                     and self.mlp == "swiglu"):
+                                     and self.mlp in ("swiglu", "relu2")):
             raise MoEConfigError(
                 f"d_ff_shared={self.d_ff_shared}: the shared expert of an "
-                "expert model (`n_experts` > 0) with SwiGLU experts")
+                "expert model (`n_experts` > 0) with SwiGLU or relu2 experts")
         r = self.router
         if self.n_experts and not (
                 1 <= self.n_experts_per_tok <= (r.width or self.n_experts)):
@@ -451,26 +480,38 @@ def init_trunk_params(rng, cfg: TransformerConfig):
 
 # the suffix of a kind whose MLP half is the dense MLP in an expert model
 DENSE = "+dense"
+# the suffix of a kind WITHOUT an MLP half (``cfg.single_sublayer``)
+ALONE = "+alone"
 
 
 def layer_kinds(cfg: TransformerConfig):
     """A kind a layer, naming its mixer AND its MLP half: the mixer's name
     of ``_KINDS`` where the MLP half is the model's own (experts where
     ``cfg.n_experts``, else dense), the name + ``DENSE`` on the
-    ``cfg.n_dense_layers`` leading layers of an expert model."""
+    ``cfg.n_dense_layers`` leading layers of an expert model. In a stack of
+    single sublayers (``cfg.single_sublayer``) a mixer's name + ``ALONE``
+    (no MLP half), and "mlp" as it is (the MLP half, no mixer)."""
     mixers = cfg.layer_types or ("attention",) * cfg.n_layers
+    if cfg.single_sublayer:
+        return tuple(m if m == "mlp" else m + ALONE for m in mixers)
     return tuple(m + DENSE if i < cfg.n_dense_layers else m
                  for i, m in enumerate(mixers))
 
 
 def mixer_of(kind):
-    """A kind's mixer, a name of ``_KINDS``."""
-    return kind.removesuffix(DENSE)
+    """A kind's mixer, a name of ``_KINDS`` ("mlp": none)."""
+    return kind.removesuffix(DENSE).removesuffix(ALONE)
+
+
+def has_mlp(kind):
+    """Whether a layer of ``kind`` has an MLP half."""
+    return not kind.endswith(ALONE)
 
 
 def experts_of(cfg: TransformerConfig, kind):
-    """Experts held by a layer of ``kind``; 0 = its MLP half is dense."""
-    return 0 if kind.endswith(DENSE) else cfg.n_experts
+    """Experts held by a layer of ``kind``; 0 = its MLP half is dense, or
+    it has none."""
+    return 0 if kind.endswith((DENSE, ALONE)) else cfg.n_experts
 
 
 def layer_runs(cfg: TransformerConfig):
@@ -568,15 +609,23 @@ def _window_specs(cfg: TransformerConfig):
 def _init_mamba(ks, cfg: TransformerConfig, n):
     """HF ``GraniteMoeHybridPreTrainedModel._init_weights``: A = 1..H a
     head, dt_bias = D = 1, the gated norm's scale 1, the convolution's bias
-    0, normal(0.02) elsewhere."""
+    0, normal(0.02) elsewhere. Under ``SSMConfig.dt_init`` dt_bias is
+    ``mamba_ssm``'s ``Mamba2``'s instead: the inverse softplus of a step size
+    drawn log-uniform in [min, max] a head and layer, at least floor."""
     m, D = cfg.ssm, cfg.d_model
     ones = lambda *shape: jnp.ones((n,) + shape, jnp.float32)
+    dt_bias = ones(m.n_heads)
+    if m.dt_init is not None:
+        lo, hi, floor = m.dt_init
+        u = jax.random.uniform(jax.random.fold_in(ks[11], 4), (n, m.n_heads))
+        dt = jnp.maximum(jnp.exp(u * np.log(hi / lo) + np.log(lo)), floor)
+        dt_bias = dt + jnp.log(-jnp.expm1(-dt))
     return {
         "w_in": _init_normal(
             ks[0], (n, D, m.d_inner + m.conv_dim + m.n_heads), 0.02),
         "conv_w": _init_normal(ks[11], (n, m.d_conv, m.conv_dim), 0.02),
         "conv_b": jnp.zeros((n, m.conv_dim), jnp.float32),
-        "dt_bias": ones(m.n_heads),
+        "dt_bias": dt_bias,
         "A_log": jnp.tile(jnp.log(jnp.arange(1, m.n_heads + 1,
                                              dtype=jnp.float32)), (n, 1)),
         "D": ones(m.n_heads),
@@ -661,17 +710,23 @@ def _dsa_specs(cfg: TransformerConfig):
 def _init_run(ks, cfg: TransformerConfig, kind, n):
     """``n`` stacked layers of one kind: the two norms, the kind's mixer
     (``_KINDS``) and its MLP half (``experts_of``: dense at ``d_ff``, or the
-    experts held here at ``d_ff_expert`` with their router)."""
+    experts held here at ``d_ff_expert`` with their router). A single
+    sublayer has its own half alone: ``ln1`` and the mixer, or (kind "mlp")
+    ``ln2`` and the MLP."""
     D, E = cfg.d_model, experts_of(cfg, kind)
     F = (cfg.d_ff_expert or cfg.d_ff) if E else cfg.d_ff
     norm = _init_normal
-    blocks = {
-        "ln1_scale": jnp.ones((n, D), jnp.float32),
-        "ln1_bias": jnp.zeros((n, D), jnp.float32),
-        **_KINDS[mixer_of(kind)].init(ks, cfg, n),
+    blocks = {}
+    if mixer_of(kind) != "mlp":
+        blocks.update({
+            "ln1_scale": jnp.ones((n, D), jnp.float32),
+            "ln1_bias": jnp.zeros((n, D), jnp.float32),
+            **_KINDS[mixer_of(kind)].init(ks, cfg, n)})
+    if not has_mlp(kind):
+        return blocks
+    blocks.update({
         "ln2_scale": jnp.ones((n, D), jnp.float32),
-        "ln2_bias": jnp.zeros((n, D), jnp.float32),
-    }
+        "ln2_bias": jnp.zeros((n, D), jnp.float32)})
     if cfg.sandwich_norm:
         for name in ("ln1_post", "ln2_post"):
             blocks[name + "_scale"] = jnp.ones((n, D), jnp.float32)
@@ -704,6 +759,9 @@ def _init_run(ks, cfg: TransformerConfig, kind, n):
             "w2": norm(ks[4], (n, F, D), out_scale),
             "b2": jnp.zeros((n, D), jnp.float32),
         })
+    if cfg.mlp == "relu2":      # two matrices and nothing else
+        for name in ("b1", "b2", "ws3"):
+            blocks.pop(name, None)
     return blocks
 
 
@@ -737,13 +795,13 @@ def init_params(rng, cfg: TransformerConfig):
 
 def _run_specs(cfg: TransformerConfig, kind):
     moe = experts_of(cfg, kind) > 0
-    blocks = {
-        "ln1_scale": P(None, None),
-        "ln1_bias": P(None, None),
-        **_KINDS[mixer_of(kind)].specs(cfg),
-        "ln2_scale": P(None, None),
-        "ln2_bias": P(None, None),
-    }
+    blocks = {}
+    if mixer_of(kind) != "mlp":
+        blocks.update({"ln1_scale": P(None, None), "ln1_bias": P(None, None),
+                       **_KINDS[mixer_of(kind)].specs(cfg)})
+    if not has_mlp(kind):
+        return blocks
+    blocks.update({"ln2_scale": P(None, None), "ln2_bias": P(None, None)})
     if cfg.sandwich_norm:
         for name in ("ln1_post", "ln2_post"):
             blocks[name + "_scale"] = blocks[name + "_bias"] = P(None, None)
@@ -771,6 +829,9 @@ def _run_specs(cfg: TransformerConfig, kind):
             "w2": P(None, "tp", None),
             "b2": P(None, None),
         })
+    if cfg.mlp == "relu2":
+        for name in ("b1", "b2", "ws3"):
+            blocks.pop(name, None)
     return blocks
 
 
@@ -846,6 +907,15 @@ def _rms_norm_heads(x, scale, eps):
     ms = jnp.mean(jnp.square(x32.reshape(x.shape[:-1] + (-1, hd))), -1)
     x32 = x32 * jnp.repeat(jax.lax.rsqrt(ms + eps), hd, axis=-1)
     return (x32 * jnp.tile(scale, x.shape[-1] // hd)).astype(x.dtype)
+
+
+def _rms_norm_groups(x, scale, groups, eps):
+    """RMSNorm over each of ``groups`` runs of x's channels, side by side:
+    x (B, T, C) float32, ``scale`` (C,) the whole width's; -> float32. The
+    statistic alone takes the (B, T, groups, C / groups) view."""
+    size = x.shape[-1] // groups
+    ms = jnp.mean(jnp.square(x.reshape(x.shape[:-1] + (groups, size))), -1)
+    return x * jnp.repeat(jax.lax.rsqrt(ms + eps), size, axis=-1) * scale
 
 
 def _norm(x, scale, bias, cfg: TransformerConfig):
@@ -1544,10 +1614,22 @@ def _mamba(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     """The Mamba-2 mixer as HF ``GraniteMoeHybridMambaLayer`` computes it:
     [z | xBC | dt] = h W_in; xBC = SiLU(causal depthwise conv(xBC) + b);
     [x | B | C] = xBC; the recurrence (``_ssd``) + D x; RMSNorm(y SiLU(z))
-    over all channels; W_out. ``attn_bias`` (a padding mask) is refused: the
-    recurrence reads every position."""
+    over all channels, or over each of ``cfg.ssm.norm_groups`` runs of them
+    (``mamba_ssm``'s ``RMSNormGated`` at ``group_size``; Nemotron-H); W_out.
+    ``attn_bias`` (a padding mask) is refused: the recurrence reads every
+    position."""
     if attn_bias is not None:
         raise NotImplementedError("a mamba layer takes no attention bias")
+    y = _mamba_gate_norm(_mamba_gated(h, p, cfg, mesh), p, cfg).astype(
+        h.dtype)
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        return jnp.einsum("bte,ed->btd", y, p["w_out"].astype(h.dtype),
+                          preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+def _mamba_gated(h, p, cfg: TransformerConfig, mesh):
+    """The mixer up to its gated norm -> (y + D x) SiLU(z), (B, T, d_inner)
+    float32."""
     z, x, Bm, Cm, dt_raw = _mamba_inputs(h, p, cfg)
     with jax.named_scope(SCOPE_SSM_SCAN):
         y = _scan(x, _ssm_dt(dt_raw, p["dt_bias"]), p["A_log"], Bm, Cm,
@@ -1555,11 +1637,18 @@ def _mamba(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
         with jax.named_scope(SCOPE_SSD_ENTER):
             y = y + p["D"][:, None] * x
     with jax.named_scope(SCOPE_SSM_GATE):
-        y = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
-        y = _rms_norm(y, p["ssm_norm"], cfg.ln_eps).astype(h.dtype)
-    with jax.named_scope(SCOPE_SSM_PROJ):
-        return jnp.einsum("bte,ed->btd", y, p["w_out"].astype(h.dtype),
-                          preferred_element_type=jnp.float32).astype(h.dtype)
+        return y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+
+
+def _mamba_gate_norm(y, p, cfg: TransformerConfig):
+    """The gated norm on ``_mamba_gated``'s product, float32 -> float32: over
+    all channels, or by group under its own scope."""
+    with jax.named_scope(SCOPE_SSM_GATE):
+        if cfg.ssm.norm_groups == 1:
+            return _rms_norm32(y, p["ssm_norm"], cfg.ln_eps)
+        with jax.named_scope(SCOPE_SSM_GATE_NORM):
+            return _rms_norm_groups(y, p["ssm_norm"], cfg.ssm.norm_groups,
+                                    cfg.ln_eps)
 
 
 def _short_conv(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
@@ -1616,7 +1705,9 @@ _KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
           "conv": _Kind(_init_short_conv, _short_conv_specs, _short_conv),
           "mla": _Kind(_init_mla, _mla_specs, _mla),
           "dsa": _Kind(_init_dsa, _dsa_specs, _dsa, side_loss=True),
-          "window": _Kind(_init_window, _window_specs, _window)}
+          "window": _Kind(_init_window, _window_specs, _window),
+          # no mixer: a single sublayer that is its MLP half (``_block``)
+          "mlp": _Kind(lambda ks, cfg, n: {}, lambda cfg: {}, None)}
 
 
 def _dense_mlp(h, p, cfg, mesh):
@@ -1629,6 +1720,16 @@ def _dense_mlp(h, p, cfg, mesh):
             up = jnp.einsum("btd,df->btf", h, p["w3"].astype(h.dtype),
                             preferred_element_type=jnp.float32)
             u = (jax.nn.silu(gate) * up).astype(h.dtype)
+        with jax.named_scope(SCOPE_BLK_MLP_DOWN):
+            return jnp.einsum(
+                "btf,fd->btd", u, p["w2"].astype(h.dtype),
+                preferred_element_type=jnp.float32).astype(h.dtype)
+    if cfg.mlp == "relu2":
+        # Nemotron-H's MLP: down(relu(up(x))^2), no gate and no bias
+        with jax.named_scope(SCOPE_BLK_MLP_UP):
+            u = _relu2(jnp.einsum("btd,df->btf", h, p["w1"].astype(h.dtype),
+                                  preferred_element_type=jnp.float32)
+                       ).astype(h.dtype)
         with jax.named_scope(SCOPE_BLK_MLP_DOWN):
             return jnp.einsum(
                 "btf,fd->btd", u, p["w2"].astype(h.dtype),
@@ -1777,6 +1878,11 @@ def _swiglu(gate, up):
     """silu(gate) * up, in float32, at ``gate``'s dtype."""
     return (jax.nn.silu(gate.astype(jnp.float32))
             * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+def _relu2(u):
+    """relu(u)^2, in float32, at ``u``'s dtype."""
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(u.dtype)
 
 
 # -- a share's row loops ----------------------------------------------------------
@@ -1934,32 +2040,34 @@ def _rows_twice_bwd(R, rows, g):
 _rows_twice.defvjp(_rows_twice_fwd, _rows_twice_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _swiglu_rows(gate, up, rows, R):
-    """``_swiglu`` on the first ``rows`` rows, a chunk a step."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 3))
+def _act_rows(act, operands, rows, R):
+    """The experts' activation ``act`` (``_swiglu`` of (gate, up), ``_relu2``
+    of (u,)) on the first ``rows`` rows of its ``operands``, a chunk a
+    step."""
     def body(start, out):
-        return _put(out, _swiglu(_cut(gate, start, R), _cut(up, start, R)),
-                    start)
+        return _put(out, act(*(_cut(a, start, R) for a in operands)), start)
 
-    return _loop_rows(rows, R, body, jax.lax.empty(gate.shape, gate.dtype))
-
-
-def _swiglu_rows_fwd(gate, up, rows, R):
-    return _swiglu_rows(gate, up, rows, R), (gate, up, rows)
+    return _loop_rows(rows, R, body, jax.lax.empty(operands[0].shape,
+                                                   operands[0].dtype))
 
 
-def _swiglu_rows_bwd(R, res, g):
-    gate, up, rows = res
+def _act_rows_fwd(act, operands, rows, R):
+    return _act_rows(act, operands, rows, R), (operands, rows)
 
-    def body(start, grads):      # written over gate and up, a chunk read
-        _, pull = jax.vjp(_swiglu, *(_cut(a, start, R) for a in grads))
+
+def _act_rows_bwd(act, R, res, g):
+    operands, rows = res
+
+    def body(start, grads):      # written over the operands, a chunk read
+        _, pull = jax.vjp(act, *(_cut(a, start, R) for a in grads))
         return tuple(_put(a, d, start) for a, d in
                      zip(grads, pull(_cut(g, start, R))))
 
-    return _loop_rows(rows, R, body, (gate, up)) + (None,)
+    return _loop_rows(rows, R, body, operands), None
 
 
-_swiglu_rows.defvjp(_swiglu_rows_fwd, _swiglu_rows_bwd)
+_act_rows.defvjp(_act_rows_fwd, _act_rows_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -2001,7 +2109,8 @@ _share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
 def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     """An expert layer's MLP half -> (out, aux (2,)): the routed picks
     (``_routed_experts``) and, under ``cfg.d_ff_shared``, the shared expert
-    beside them: ONE SwiGLU MLP on every token, added to the routed sum as
+    beside them: ONE MLP of the experts' form (SwiGLU, or relu2's two
+    matrices ``ws1`` / ``ws2``) on every token, added to the routed sum as
     HF ``DeepseekV3MoE.forward`` adds it. It is no expert of the router's:
     a share (``cfg.router.width``) computes it whole, once, whatever it
     holds, so the parts of the members of a group add up to the layer only
@@ -2009,9 +2118,10 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     out, aux = _routed_experts(h, p, cfg, mesh)
     if cfg.d_ff_shared:
         with jax.named_scope(SCOPE_MOE_SHARED):
-            out = out + _dense_mlp(
-                h, {"w1": p["ws1"], "w3": p["ws3"], "w2": p["ws2"]}, cfg,
-                mesh)
+            shared = {"w1": p["ws1"], "w2": p["ws2"]}
+            if "ws3" in p:      # the gated form's third matrix
+                shared["w3"] = p["ws3"]
+            out = out + _dense_mlp(h, shared, cfg, mesh)
     return out, aux
 
 
@@ -2081,8 +2191,14 @@ def _routed_experts(h, p, cfg: TransformerConfig, mesh):
                          else (xs, xs))
             u = _grouped_matmul(xs, p["w1"], group_sizes)
             up = _grouped_matmul(xs_up, p["w3"], group_sizes)
-            u = (_swiglu_rows(u, up, plan["rows_run"], R) if share
+            u = (_act_rows(_swiglu, (u, up), plan["rows_run"], R) if share
                  else _swiglu(u, up))
+            ys = _grouped_matmul(u, p["w2"], group_sizes)
+        elif cfg.mlp == "relu2":    # ungated, no bias: the held rows alone
+            u = _grouped_matmul(xs, p["w1"], group_sizes)
+            with jax.named_scope(SCOPE_MOE_ACT):
+                u = (_act_rows(_relu2, (u,), plan["rows_run"], R) if share
+                     else _relu2(u))
             ys = _grouped_matmul(u, p["w2"], group_sizes)
         else:   # biased GELU experts: over every row, on a share too
             u = _grouped_matmul(xs, p["w1"], group_sizes)
@@ -2177,29 +2293,34 @@ def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
 def _block_mixer(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
                  dropout_rng, kind):
     """``_block_attn`` and, third, the mixer's own loss (None without:
-    ``_Kind.side_loss``)."""
+    ``_Kind.side_loss``). A single sublayer (``cfg.single_sublayer``): kind
+    "mlp" has no mixer and h passes to the MLP half's norm as it came; a
+    kind without an MLP half (``has_mlp``) has no such input, None."""
     post = cfg.post_ln
     mixer = _KINDS[mixer_of(kind)]
     h = _constrain(h, mesh, "dp", "sp", None)
-    attn_in = h if post else _norm(
-        h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
-    attn_out = mixer.mixer(attn_in, layer_params, cfg, mesh, attn_bias)
     side = None
-    if mixer.side_loss:
-        attn_out, side = attn_out
-    if cfg.sandwich_norm:
-        # the norm's backward pass reads its input: kept, the mixer's last
-        # matmul (`wo`) is not run again for it
-        attn_out = _norm(checkpoint_name(attn_out, REMAT_NORM1_IN),
-                         layer_params["ln1_post_scale"],
-                         layer_params["ln1_post_bias"], cfg)
-    attn_out = _dropout(_residual(attn_out, cfg), cfg.dropout_rate,
-                        dropout_rng)
-    h = checkpoint_name(h + attn_out, REMAT_X1)
-    if post:
-        h = _norm(h, layer_params["ln1_scale"],
-                  layer_params["ln1_bias"], cfg)
-    h = _constrain(h, mesh, "dp", "sp", None)
+    if mixer.mixer is not None:
+        attn_in = h if post else _norm(
+            h, layer_params["ln1_scale"], layer_params["ln1_bias"], cfg)
+        attn_out = mixer.mixer(attn_in, layer_params, cfg, mesh, attn_bias)
+        if mixer.side_loss:
+            attn_out, side = attn_out
+        if cfg.sandwich_norm:
+            # the norm's backward pass reads its input: kept, the mixer's
+            # last matmul (`wo`) is not run again for it
+            attn_out = _norm(checkpoint_name(attn_out, REMAT_NORM1_IN),
+                             layer_params["ln1_post_scale"],
+                             layer_params["ln1_post_bias"], cfg)
+        attn_out = _dropout(_residual(attn_out, cfg), cfg.dropout_rate,
+                            dropout_rng)
+        h = checkpoint_name(h + attn_out, REMAT_X1)
+        if post:
+            h = _norm(h, layer_params["ln1_scale"],
+                      layer_params["ln1_bias"], cfg)
+        h = _constrain(h, mesh, "dp", "sp", None)
+    if not has_mlp(kind):
+        return h, None, side
     mlp_in = h if post else _norm(
         h, layer_params["ln2_scale"], layer_params["ln2_bias"], cfg)
     return h, mlp_in, side
@@ -2217,7 +2338,10 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     ``kind``'s mixer (``_KINDS``: attention, a Mamba-2 mixer, a gated short
     convolution), the second its MLP half (``experts_of``: the experts, or
     the dense MLP); each sublayer's output takes
-    ``cfg.multipliers.residual`` before its add.
+    ``cfg.multipliers.residual`` before its add. A stack of single
+    sublayers (``cfg.single_sublayer``) runs ONE of the two a layer, with
+    its one norm: the mixer (``has_mlp`` false), or the MLP half (kind
+    "mlp").
 
     LOCKSTEP CONTRACT: any new dialect knob added here must be mirrored
     in ``generate._decode_layer`` (the KV-cache form of this block) or
@@ -2226,7 +2350,9 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
         dropout_rng)
     h, mlp_in, side = _block_mixer(h, layer_params, cfg, mesh, attn_bias, k1,
                                    kind)
-    if experts_of(cfg, kind) > 0:
+    if mlp_in is None:      # a single sublayer: the mixer alone
+        out, aux = None, jnp.zeros((2,), jnp.float32)
+    elif experts_of(cfg, kind) > 0:
         out, aux = _moe_mlp(mlp_in, layer_params, cfg, mesh)
     else:
         out = _dense_mlp(mlp_in, layer_params, cfg, mesh)
@@ -2234,6 +2360,8 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     if _aux_size(cfg) > 2:
         # a stack with dsa layers: every layer's aux has the third entry
         aux = jnp.append(aux, 0.0 if side is None else side)
+    if out is None:
+        return h, aux
     if cfg.sandwich_norm:
         # as for the mixer's output: `w2` (a MoE block: the combine)
         out = _norm(checkpoint_name(out, REMAT_NORM2_IN),
@@ -2399,6 +2527,11 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     by_seq, by_head = act // sp, act // tp
     lse = B * T * cfg.n_heads * 4 // (dp * tp)
     applications = cfg.n_layers * cfg.n_loops
+    # the applications that WRITE an x1 apart from their output: both halves
+    # (a single sublayer's one sum is its output, which the scan keeps)
+    two_halves = cfg.n_loops * sum(
+        n for kind, n in layer_runs(cfg)
+        if has_mlp(kind) and mixer_of(kind) != "mlp")
     # one run's stacked weights stand for its kind's shapes
     by_kind = {kind: blocks for (kind, _), blocks in zip(
         layer_runs(cfg), run_blocks(cfg, params["blocks"]))}
@@ -2436,7 +2569,7 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
         w_head = act // tp * wn * cfg.head_dim // D
         w_lse = B * T * wn * 4 // (dp * tp)
         w_kv = 2 * w_head * cfg.kv_heads // wn
-    costs = (applications * by_seq * (2 if cfg.post_ln else 1),
+    costs = (two_halves * by_seq * (2 if cfg.post_ln else 1),
              attention * (by_head + lse) + mla * (mla_o + lse)
              + window * (w_head + w_lse),
              mla * mla_latent,
@@ -2590,6 +2723,12 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig, terms=False):
 
     def body(h, layer_params, kind):
         _, mlp_in = _block_attn(h, layer_params, cfg, None, None, None, kind)
+        # the rows AS ROUNDED to the compute dtype, for the router and for
+        # ``router_in`` alike: without the barrier the compiler may feed the
+        # router's float32 matmul the norm's unrounded output (its excess
+        # precision), and a check that recomputes the picks from
+        # ``router_in`` then sees picks it cannot explain (PERF.md, PR 55)
+        mlp_in = jax.lax.optimization_barrier(mlp_in)
         _, top_e, counts, probs, _ = _route(
             mlp_in.reshape(S, -1), layer_params, cfg)
         if r.score != "softmax":
@@ -2624,6 +2763,17 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig, terms=False):
         lambda *runs: jnp.concatenate(runs), *stats)
 
 
+def _first_mamba_layer(params, tokens, cfg: TransformerConfig, who):
+    """(the stack's FIRST layer's params, its mixer's normed input) where
+    that layer is a mamba layer; ``who`` asks."""
+    if mixer_of(layer_runs(cfg)[0][0]) != "mamba":
+        raise ValueError(f"{who}: the stack's first layer is not a mamba "
+                         f"layer (layer_types={cfg.layer_types})")
+    p = jax.tree.map(lambda x: x[0], run_blocks(cfg, params["blocks"])[0])
+    h = embed_tokens(params, tokens, cfg)
+    return p, _norm(h, p["ln1_scale"], p["ln1_bias"], cfg)
+
+
 def ssm_scan_terms(params, tokens, cfg: TransformerConfig):
     """The float32 parts of the FIRST mamba layer's scan on ``tokens`` (B,
     T), with what each was computed from: a pure function beside the step,
@@ -2632,13 +2782,8 @@ def ssm_scan_terms(params, tokens, cfg: TransformerConfig):
     chunk; ``B`` (B, c, Q, G, N) and ``xd`` (B, c, Q, G, R, P) the state
     matmul's operands; ``local`` and ``entering`` (B, c, G, R, P, N) the
     chunks' own states and the recurrence's; ``dt_bias``, ``A_log``."""
-    if layer_runs(cfg)[0][0] != "mamba":
-        raise ValueError("ssm_scan_terms: the stack's first layer is not a "
-                         f"mamba layer (layer_types={cfg.layer_types})")
-    p = jax.tree.map(lambda x: x[0], run_blocks(cfg, params["blocks"])[0])
-    h = embed_tokens(params, tokens, cfg)
-    _, x, Bm, Cm, dt_raw = _mamba_inputs(
-        _norm(h, p["ln1_scale"], p["ln1_bias"], cfg), p, cfg)
+    p, h = _first_mamba_layer(params, tokens, cfg, "ssm_scan_terms")
+    _, x, Bm, Cm, dt_raw = _mamba_inputs(h, p, cfg)
     dt = _ssm_dt(dt_raw, p["dt_bias"])
     x, dt_c, acs, Bm, _ = _ssd_chunks(x, dt, p["A_log"], Bm, Cm,
                                       cfg.ssm.chunk)
@@ -2646,6 +2791,18 @@ def ssm_scan_terms(params, tokens, cfg: TransformerConfig):
     return {"dt_raw": dt_raw, "dt": dt, "log_decay": acs, "B": Bm, "xd": xd,
             "local": local, "entering": entering, "dt_bias": p["dt_bias"],
             "A_log": p["A_log"]}
+
+
+def ssm_gate_terms(params, tokens, cfg: TransformerConfig):
+    """The float32 gated norm of the FIRST mamba layer (the stack's first
+    layer, as ``ssm_scan_terms``) on ``tokens`` (B, T), with what it was
+    computed from: ``gated`` (B, T, d_inner) = (y + D x) SiLU(z) as the norm
+    reads it, ``normed`` what the norm made of it before any cast,
+    ``scale`` (d_inner,) its weight."""
+    p, h = _first_mamba_layer(params, tokens, cfg, "ssm_gate_terms")
+    gated = _mamba_gated(h, p, cfg, None)
+    return {"gated": gated, "normed": _mamba_gate_norm(gated, p, cfg),
+            "scale": p["ssm_norm"]}
 
 
 def _first_layer_of(params, tokens, cfg: TransformerConfig, wanted):

@@ -72,6 +72,11 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
             "kind of block a stage")
 
     kind = tfm.layer_runs(cfg)[0][0]
+    if cfg.single_sublayer:
+        raise NotImplementedError(
+            f"single_sublayer=True (layer kind {kind!r}): a stage's body is "
+            "the block of two halves; a layer that is a mixer alone, or an "
+            "MLP half alone, has no stage rule")
     if tfm.mixer_of(kind) != "attention":
         raise NotImplementedError(
             f"layer kind {kind!r}: a stage's body is the attention block; "

@@ -69,6 +69,12 @@ SCOPE_MOE_EXPERTS = "hetu_moe_experts"    # grouped matmuls + activation
 SCOPE_MOE_COMBINE = "hetu_moe_combine"    # un-permute, weight, sum over k
 MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
               SCOPE_MOE_COMBINE)
+# INSIDE SCOPE_MOE_EXPERTS (`.../hetu_moe_experts/hetu_moe_act/...`), so a
+# reader of the four counts it where it counted it: the activation of UNGATED
+# experts (`cfg.mlp` "relu2": relu(.)^2 on the held rows between the two
+# grouped matmuls); benchmark/reduce/nemotron_h.py reads it. Gated experts'
+# SiLU(gate) * up stays unnamed inside SCOPE_MOE_EXPERTS, as it lowered
+SCOPE_MOE_ACT = "hetu_moe_act"
 # the always-on branch of an expert layer (`cfg.d_ff_shared`: the shared
 # expert, a SwiGLU on every token beside the routed picks; transformer.
 # _moe_mlp), a FIFTH part beside the four and inside none of them: a reader
@@ -89,6 +95,12 @@ SCOPE_SSM_SCAN = "hetu_ssm_scan"  # from the convolution's output to the
                                   # recurrence (`_ssd`), the D skip
 SCOPE_SSM_GATE = "hetu_ssm_gate"  # y SiLU(z) and its RMSNorm
 SSM_SCOPES = (SCOPE_SSM_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE)
+# INSIDE SCOPE_SSM_GATE (`.../hetu_ssm_gate/hetu_ssm_gate_norm/...`): the
+# gated norm BY GROUP (`SSMConfig.norm_groups` > 1: a statistic a group of
+# d_inner / norm_groups channels, and the scaling); the product y SiLU(z)
+# stays outside it. A norm over all channels (Granite) opens no such scope;
+# benchmark/reduce/nemotron_h.py reads it
+SCOPE_SSM_GATE_NORM = "hetu_ssm_gate_norm"
 # the two parts of a gated short convolution (transformer._short_conv, LFM2),
 # each nested INSIDE the mamba mixer's scope of the same part:
 # `.../hetu_ssm_proj/hetu_sconv_proj/...`, `.../hetu_ssm_conv/hetu_sconv_conv/
