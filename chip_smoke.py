@@ -702,6 +702,11 @@ KERNEL_SHAPES = {
     # 8 heads (8 heads a grid step where Granite takes 16), sixteen chunks
     # of ONE lane tile
     "ssd_groups": (1, 2048, 64, 64, 8, 128, 128),
+    # (rows, K, N, rows a group): a quarter of the rows of
+    # nemotron-twotower-30b-a3b's `w1` call at its widths, an odd number of
+    # lane tiles and a half tile, bf16; 768 rows in eight uneven groups, one
+    # empty, edges inside the row tiles, the other rows in no group
+    "grouped_matmul": (12288, 2688, 1856, (90, 0, 130, 200, 64, 100, 120, 64)),
 }
 
 
@@ -718,6 +723,7 @@ def phase_kernels(*, shapes=None, chip=True):
                                            linear_nll_reference)
     from hetu_tpu.kernels.rope import rope_halves, rope_interleaved
     from hetu_tpu.models.transformer import (YarnConfig, _rope,
+                                             _grouped_matmul,
                                              _rope_interleaved, _ssd,
                                              _ssd_kernels)
 
@@ -949,6 +955,26 @@ def phase_kernels(*, shapes=None, chip=True):
             compare(name, scan_and_cotangents(_ssd_kernels),
                     scan_and_cotangents(_ssd), (sx, sdt, s_a, sb, sc, sg),
                     atol=2e-2)
+
+        # -- the experts' grouped matmul where the compiler's kernel would
+        # tile by one lane tile: the product and both cotangents against
+        # `ragged_dot` INSIDE the groups (neither side defines a row past
+        # them), each over its own largest entry
+        m, kk, nn, sizes = shapes["grouped_matmul"]
+        gx, gg = (jnp.asarray(rng.randn(m, w), jnp.bfloat16) for w in (kk, nn))
+        gw = jnp.asarray(0.02 * rng.randn(len(sizes), kk, nn), jnp.float32)
+
+        def product_and_cotangents(x, w, g):
+            held = sum(sizes)
+            y, vjp = jax.vjp(lambda x, w: _grouped_matmul(
+                x, w, jnp.asarray(sizes, jnp.int32)), x, w)
+            dx, dw = vjp(g)
+            return [leaf.astype(jnp.float32)
+                    / jnp.max(jnp.abs(leaf.astype(jnp.float32)))
+                    for leaf in (y[:held], dx[:held], dw)]
+
+        compare("grouped_matmul", product_and_cotangents,
+                product_and_cotangents, (gx, gw, gg), atol=2e-2)
 
         # -- the four registry kernels
         n, d, vocab = shapes["embed_grad"]

@@ -18,6 +18,10 @@ Layout:
 - :mod:`rope` — the rotation of q and k in one pass through VMEM, latent
   attention's interleaved pairs and every other model's rotate-half
   columns, gated the pre-tier way (``rope.takes``).
+- :mod:`grouped_matmul` — the experts' grouped matmul, forward, dx and dW,
+  with tiles made from the widths, where the compiler's ``ragged_dot``
+  kernel would tile K or N by one lane tile; registered here
+  (``grouped_matmul``) and dispatched by ``transformer._grouped_matmul``.
 
 Importing this package registers the four tier kernels; the graph ops
 import it lazily inside their compute fns so jax-free tools never pay

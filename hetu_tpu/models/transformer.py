@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kernels import grouped_matmul as gmm_kernel
+from ..kernels import registry as kernel_registry
 from ..kernels import rope as rope_kernel
 from ..kernels import ssd as ssd_kernel
 from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
@@ -1865,13 +1867,17 @@ def _permute_rows_bwd(inv, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-def _grouped_matmul(xs, w, group_sizes):
+def _grouped_matmul(xs, w, group_sizes, mesh=None):
     """Rows sorted by group (M, K) x one matrix a group (E, K, N) -> (M, N):
     ``jax.lax.ragged_dot``, which the TPU compiler lowers to its own grouped
     matmul kernel (M*K*N multiply-adds, not E times that) and differentiates
-    into two more (PERF.md has its share of the roofline)."""
-    return jax.lax.ragged_dot(xs, w.astype(xs.dtype), group_sizes,
-                              preferred_element_type=xs.dtype)
+    into two more (PERF.md has its share of the roofline). That kernel tiles
+    a width by the largest of 512 / 256 / 128 that divides it: where K or N
+    gets ONE lane tile (2,688, 1,856), on a TPU and in one program, the
+    Pallas kernels of ``kernels/grouped_matmul.py`` serve the call with
+    tiles made from the widths (``gmm_kernel.takes`` is the rule)."""
+    return kernel_registry.dispatch(gmm_kernel.GROUPED_MATMUL, xs,
+                                    w.astype(xs.dtype), group_sizes, mesh=mesh)
 
 
 def _swiglu(gate, up):
@@ -2189,19 +2195,19 @@ def _routed_experts(h, p, cfg: TransformerConfig, mesh):
         if cfg.mlp == "swiglu":
             xs, xs_up = (_rows_twice(xs, plan["rows_run"], R) if share
                          else (xs, xs))
-            u = _grouped_matmul(xs, p["w1"], group_sizes)
-            up = _grouped_matmul(xs_up, p["w3"], group_sizes)
+            u = _grouped_matmul(xs, p["w1"], group_sizes, mesh)
+            up = _grouped_matmul(xs_up, p["w3"], group_sizes, mesh)
             u = (_act_rows(_swiglu, (u, up), plan["rows_run"], R) if share
                  else _swiglu(u, up))
-            ys = _grouped_matmul(u, p["w2"], group_sizes)
+            ys = _grouped_matmul(u, p["w2"], group_sizes, mesh)
         elif cfg.mlp == "relu2":    # ungated, no bias: the held rows alone
-            u = _grouped_matmul(xs, p["w1"], group_sizes)
+            u = _grouped_matmul(xs, p["w1"], group_sizes, mesh)
             with jax.named_scope(SCOPE_MOE_ACT):
                 u = (_act_rows(_relu2, (u,), plan["rows_run"], R) if share
                      else _relu2(u))
-            ys = _grouped_matmul(u, p["w2"], group_sizes)
+            ys = _grouped_matmul(u, p["w2"], group_sizes, mesh)
         else:   # biased GELU experts: over every row, on a share too
-            u = _grouped_matmul(xs, p["w1"], group_sizes)
+            u = _grouped_matmul(xs, p["w1"], group_sizes, mesh)
             sorted_e = flat_e[order]
             b1, b2 = p["b1"], p["b2"]
             if share:
@@ -2212,7 +2218,7 @@ def _routed_experts(h, p, cfg: TransformerConfig, mesh):
                 b1, b2 = (jnp.pad(b, ((0, 1), (0, 0))) for b in (b1, b2))
             b1, b2 = (b.astype(x.dtype)[sorted_e] for b in (b1, b2))
             u = _gelu(u + b1, cfg)
-            ys = _grouped_matmul(u, p["w2"], group_sizes) + b2
+            ys = _grouped_matmul(u, p["w2"], group_sizes, mesh) + b2
     with jax.named_scope(SCOPE_MOE_COMBINE):
         if share:
             out = _share_combine(ys, top_p, plan, R)

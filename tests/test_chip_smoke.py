@@ -108,7 +108,8 @@ def test_kernels_phase_tiny(capsys):
         "rope_halves": {"half-a-head-under-yarn": (1, 32, 4, 2, 128, 64, True),
                         "heads-of-64": (2, 32, 4, 2, 64, 0, False)},
         "ssd": (1, 256, 2, 64, 1, 128, 128),
-        "ssd_groups": (1, 256, 16, 64, 2, 128, 128)})
+        "ssd_groups": (1, 256, 16, 64, 2, 128, 128),
+        "grouped_matmul": (96, 384, 192, (10, 0, 50, 20))})
     assert _last_json(capsys)["phase"] == "kernels"
     assert set(rec["kernels"]) == {
         "flash_causal", "flash_key_padding", "fused_ce", "fused_embed_grad",
@@ -116,4 +117,4 @@ def test_kernels_phase_tiny(capsys):
         "fused_sgd", "rope_pairs", "flash_bwd_dqkv:two-widths",
         "flash_bwd_dqkv:pairs-of-64", "flash_window",
         "rope_halves:half-a-head-under-yarn", "rope_halves:heads-of-64",
-        "ssd", "ssd_groups"}
+        "ssd", "ssd_groups", "grouped_matmul"}

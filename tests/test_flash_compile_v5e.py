@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from hetu_tpu.kernels import dsa
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels import fused_ce as fc
+from hetu_tpu.kernels import grouped_matmul as gmm
 from hetu_tpu.kernels import rope
 from hetu_tpu.kernels import ssd
 
@@ -825,3 +826,52 @@ def test_ssd_kernels_compile_for_v5e_at_granites_scan(
     assert kernels == 1
     assert hashlib.sha256(lowered_text.encode()).hexdigest()[:16] == (
         SSD_LOWERED[half])
+
+
+# rows, groups and the two matrices (K, N) of an expert layer of
+# nemotron-twotower-30b-a3b.pretrain-seq8192-b1-ep16share: 8,192 tokens x 6
+# picks, 8 of 128 experts held
+NEMOTRON_EXPERTS = (49152, 8, {"w1": (2688, 1856), "w2": (1856, 2688)})
+
+
+@pytest.mark.parametrize("product", ["forward", "dx", "dw"])
+@pytest.mark.parametrize("matrix", ["w1", "w2"])
+def test_grouped_matmul_compiles_for_v5e_at_nemotrons_calls(
+        one_chip, no_compile_cache, monkeypatch, matrix, product):
+    """The three products of both matrices at the cell's real calls, bfloat16:
+    the rule takes them, each is ONE Mosaic call under the kernel's name
+    (the reader of `moe_held_experts_roofline_pct` counts calls), no
+    `ragged-dot` is left, dx reads the weights where they lie (no transposed
+    copy of their size) and the VMEM the compiled kernel holds is within
+    the count that chose its tiles."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
+    M, E, widths = NEMOTRON_EXPERTS
+    K, N = widths[matrix]
+
+    def arr(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    xs, w, ct, sizes = arr(M, K), arr(E, K, N), arr(M, N), arr(
+        E, dtype=jnp.int32)
+    assert gmm.takes(xs, w)
+    tm, forward, dx, dw = gmm._tiles(M, K, N, 2)
+
+    def run(xs, w, ct, sizes):
+        y, pull = jax.vjp(lambda a, b: gmm.grouped_matmul(a, b, sizes), xs, w)
+        return {"forward": lambda: y, "dx": lambda: pull(ct)[0],
+                "dw": lambda: pull(ct)[1]}[product]()
+
+    text = jax.jit(run).lower(xs, w, ct, sizes).compile().as_text()
+    names = (gmm.GROUPED_MATMUL, gmm.GROUPED_MATMUL_DW)
+    assert _count_by_name(_kernel_calls(text), names) == {
+        gmm.GROUPED_MATMUL: int(product != "dw"),
+        gmm.GROUPED_MATMUL_DW: int(product == "dw")}
+    assert len(_kernel_calls(text)) == 1 and "ragged-dot" not in text
+    # the weights' array, and dW's, in one orientation only
+    assert f"bf16[{E},{N},{K}]" not in text
+    count = gmm._vmem_bytes(tm, *{"forward": forward, "dx": dx,
+                                  "dw": dw}[product], 2, product == "dw")
+    used, = map(int, re.findall(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+        _kernel_calls(text)[0]))
+    assert used <= count <= gmm._VMEM_BUDGET < gmm._VMEM_LIMIT

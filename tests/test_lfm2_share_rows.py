@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hetu_tpu.kernels import grouped_matmul as gmm, registry
 from hetu_tpu.models import hf_lfm2, transformer as tfm
 import test_lfm2_model
 from test_lfm2_model import HF, SHARE, _data, _params, reference
@@ -138,6 +139,28 @@ def test_a_share_works_on_the_rows_it_holds(
                            counts[2:4], ROWS)
     assert int(plan["rows_run"]) == min(-(-case // ROWS) * ROWS, 128)
     assert int(plan["tokens_run"]) == -(-((case + 1) // 2) // ROWS) * ROWS
+
+
+@pytest.fixture
+def grouped_matmul_kernels_taken(monkeypatch):
+    """The experts' grouped matmuls by the Pallas kernels, interpreted: what
+    `registry.dispatch` serves on a TPU at this file's widths (64 and 48 are
+    under one lane tile, so the compiler's kernel would tile them by one)."""
+    monkeypatch.setattr(registry, "_on_tpu", lambda: True)
+    registry.reset_stats()
+    yield
+    assert set(registry.dispatch_stats()) == {(gmm.GROUPED_MATMUL, "pallas")}
+
+
+@pytest.mark.parametrize("case,mlp", [
+    (n, mlp) for mlp in ("swiglu", "gelu") for n in (0, 1, ROWS + 1, 128)])
+def test_a_share_works_on_its_rows_by_the_grouped_matmul_kernels(
+        case, mlp, small_chunks, nan_where_nothing_was_written,
+        grouped_matmul_kernels_taken):
+    """The same layer and the same reference with every grouped matmul,
+    forward, dx and dW, in the kernels of `kernels/grouped_matmul.py`: no
+    row held, one, a chunk and one, all; NaN where no loop wrote."""
+    test_a_share_works_on_the_rows_it_holds(case, mlp, None, None)
 
 
 def test_the_share_step_reads_nothing_on_the_host_and_counts_its_rows(

@@ -13,8 +13,8 @@ import pytest
 
 import hetu_tpu as ht
 from hetu_tpu.kernels import (csr_spmm, embed_grad, flash_attention,
-                              fused_ce, fused_opt, quant_comm, registry, rope,
-                              ssd)
+                              fused_ce, fused_opt, grouped_matmul, quant_comm,
+                              registry, rope, ssd)
 from hetu_tpu.telemetry import tracing as tr
 
 
@@ -84,6 +84,13 @@ KERNEL_PROGRAMS = {
     ssd.SSD_BWD: (
         jax.grad(lambda x, dt, bc: ssd.ssd(x, dt, -dt, bc, bc, 128).sum()),
         (_f32(1, 128, 2, 64), _f32(1, 128, 2), _f32(1, 128, 1, 128))),
+    grouped_matmul.GROUPED_MATMUL: (
+        grouped_matmul.grouped_matmul,
+        (_f32(32, 384), _f32(2, 384, 192), _i32(2))),
+    grouped_matmul.GROUPED_MATMUL_DW: (
+        jax.grad(lambda w, xs, sizes: grouped_matmul.grouped_matmul(
+            xs, w, sizes).sum()),
+        (_f32(2, 384, 192), _f32(32, 384), _i32(2))),
     quant_comm.QUANT_BLOCKS: (
         lambda x: quant_comm.quantize_blocks(x, 128, "int8"),
         (_f32(1024),)),
