@@ -227,7 +227,9 @@ def _pretrain_heads(params, h, batch, cfg: BertConfig, mesh):
 def make_pretrain_step(cfg: BertConfig, mesh: Optional[Mesh] = None,
                        lr: float = 1e-4):
     """Jitted (params, opt_state, batch) -> (loss, (mlm, nsp), params, opt);
-    AdamW fused into the step, buffers donated, GSPMD dp/tp sharding."""
+    AdamW fused into the step, buffers donated, GSPMD dp/tp sharding.
+    Without a mesh the state may be committed or not: ``tfm.StateStep``
+    compiles one program either way."""
 
     def step(params, opt_state, batch):
         (loss, parts), grads = jax.value_and_grad(
@@ -238,7 +240,7 @@ def make_pretrain_step(cfg: BertConfig, mesh: Optional[Mesh] = None,
         return loss, parts, new_params, new_opt
 
     if mesh is None:
-        return jax.jit(step, donate_argnums=(0, 1))
+        return tfm.StateStep(step)
     specs = param_specs(cfg)
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                           is_leaf=lambda x: isinstance(x, P))
@@ -289,7 +291,8 @@ def classify_logits(params, input_ids, segment_ids, cfg: BertConfig,
 
 def make_finetune_step(cfg: BertConfig, lr: float = 2e-5, mesh=None):
     """Jitted (params, opt_state, batch{input_ids, segment_ids, label,
-    [input_mask]}) -> (loss, acc, params, opt)."""
+    [input_mask]}) -> (loss, acc, params, opt), one program whether the
+    state comes committed or not (``tfm.StateStep``)."""
 
     def step(params, opt_state, batch):
         def loss_fn(params):
@@ -308,7 +311,7 @@ def make_finetune_step(cfg: BertConfig, lr: float = 2e-5, mesh=None):
             params, grads, opt_state, lr=lr)
         return loss, acc, new_params, new_opt
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    return tfm.StateStep(step)
 
 
 def batch_from_instances(instances):

@@ -3235,10 +3235,63 @@ def shard_opt_state(opt_state, cfg: TransformerConfig, mesh: Mesh,
     return place_opt_state(opt_state, specs, mesh)
 
 
+def _loose(x) -> bool:
+    """A leaf jit reads as uncommitted: a jax array no ``device_put`` or
+    committed program made, or host data. Not a tracer: under an outer
+    trace the step is part of that program."""
+    return (not isinstance(x, jax.core.Tracer)
+            and not getattr(x, "committed", False))
+
+
+def _commit_state(args):
+    """``args`` with the state, its first two, committed to the ONE device
+    the committed leaves of ``args`` are on. jit makes uncommitted and
+    committed arguments two programs (the lowered text differs by the
+    committed ones' ``sdy.sharding``: two lowerings, two compilations, two
+    cache keys), and a step's outputs are committed as soon as one argument
+    is: ``jax.jit(init)(key)``'s uncommitted state beside a ``device_put``
+    batch compiled the step on call 1 and AGAIN on call 2. ``device_put`` of
+    an array to the device it is on makes a new handle on the same buffer:
+    nothing is copied, and donating the handle frees the caller's too.
+    Nothing committed: nothing to do (jit keeps the outputs uncommitted, so
+    call 2 reads as call 1). Committed leaves on several devices are GSPMD's
+    by the arguments' own shardings, or jit's own error."""
+    state = jax.tree.leaves(args[:2])
+    if not any(map(_loose, state)):
+        return args
+    devices = {d for x in jax.tree.leaves(args[2:]) + state
+               if getattr(x, "committed", False) for d in x.devices()}
+    if len(devices) != 1:
+        return args
+    return (*jax.device_put(args[:2], devices.pop()), *args[2:])
+
+
+class StateStep:
+    """The one-device train step: ``jax.jit(step, donate_argnums=(0, 1))``
+    called with its state committed (``_commit_state``), so that the first
+    call and every later one, on the step's own outputs, are one program.
+    ``lower``, ``trace``, ``eval_shape`` and ``clear_cache`` are the
+    jit's own, on the arguments as given."""
+
+    def __init__(self, step):
+        self._jitted = jax.jit(step, donate_argnums=(0, 1))
+
+    def __call__(self, *args, **kwargs):
+        return self._jitted(*_commit_state(args), **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
 def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
                     lr=1e-3, accum_steps: int = 1, zero1: bool = False):
     """Returns jitted (params, opt_state, tokens, targets) ->
     (loss, params, opt_state) with GSPMD dp/tp/sp/ep sharding.
+
+    Without a mesh the state may be handed over committed or not
+    (``jax.jit(init)(key)``'s outputs, a ``device_put`` tree, a checkpoint's
+    numpy arrays): the step commits it to the device of its committed
+    arguments without a copy, and compiles ONCE.
 
     ``zero1=True`` (mesh only): AdamW m/v shard over dp — see
     ``zero1_opt_specs``; place the state with
@@ -3309,7 +3362,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         step_fn = step
 
     if mesh is None:
-        return jax.jit(step_fn, donate_argnums=(0, 1))
+        return StateStep(step_fn)
 
     specs = param_specs(cfg)
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,

@@ -2,6 +2,7 @@
 `telemetry/tracing.py` (one record a compiled program, from jax's own
 `monitoring` events), `hetu.import`, and the Executor's `compile_ms`."""
 import contextlib
+import functools
 import importlib.util
 import json
 import os
@@ -463,3 +464,168 @@ def test_two_executors_write_each_program_once(telemetry_dir):
                  tuple(r["backend"] or ())) for r in written}) == len(written)
     assert len([r for r in written
                 if r["fun_name"] == "jit(startup_not_an_executors)"]) == 1
+
+
+# -- the one-device train step compiles once (PR 58) ---------------------------
+
+@functools.cache
+def _tiny_decoder(**moe):
+    """(jitted init, a NEW step a call, the batch, the step's name)."""
+    from hetu_tpu.models import transformer as tfm
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                                n_layers=1, d_ff=64, max_seq_len=16, **moe)
+    tok = np.arange(32, dtype=np.int32).reshape(2, 16) % 64
+
+    def init(key):
+        params = tfm.init_params(key, cfg)
+        return params, tfm.init_opt_state(params)
+    return jax.jit(init), (lambda: tfm.make_train_step(cfg)), (tok, tok), \
+        "<lambda>"
+
+
+@functools.cache
+def _tiny_bert(finetune=False):
+    from hetu_tpu.models import bert
+    cfg = bert.BertConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=1,
+                          d_ff=64, max_seq_len=16)
+    B, T, P = 2, 16, 4
+    batch = {"input_ids": np.ones((B, T), np.int32),
+             "input_mask": np.ones((B, T), np.float32),
+             "segment_ids": np.zeros((B, T), np.int32)}
+    if finetune:
+        batch["label"] = np.zeros((B,), np.int32)
+    else:
+        batch.update(mlm_positions=np.ones((B, P), np.int32),
+                     mlm_ids=np.ones((B, P), np.int32),
+                     mlm_weights=np.ones((B, P), np.float32),
+                     nsp_label=np.zeros((B,), np.int32))
+
+    def init(key):
+        params = (bert.init_classifier_params(key, cfg, 2) if finetune
+                  else bert.init_params(key, cfg))
+        return params, bert.init_opt_state(params)
+    make = ((lambda: bert.make_finetune_step(cfg)) if finetune
+            else (lambda: bert.make_pretrain_step(cfg)))
+    return jax.jit(init), make, (batch,), "step"
+
+
+STEPS = {"dense": _tiny_decoder,
+         "experts": lambda: _tiny_decoder(n_experts=4, n_experts_per_tok=2),
+         "bert_pretrain": _tiny_bert,
+         "bert_finetune": lambda: _tiny_bert(finetune=True)}
+# how a caller may have made the state it hands the step
+STATES = {
+    # `params, opt = jax.jit(init)(key)`: what every adapter and example does
+    "loose": lambda state, device: state,
+    "held": lambda state, device: jax.device_put(state, device),
+    # a checkpoint read into numpy
+    "host": lambda state, device: jax.tree.map(np.asarray, state),
+}
+
+
+def _step_records(since, name):
+    return [r for r in _by_name(tr.compile_log(), name) if r["seq"] > since]
+
+
+@pytest.mark.parametrize("model,state_made", [
+    ("dense", "loose"), ("dense", "held"), ("dense", "host"),
+    ("experts", "loose"), ("experts", "held"),
+    ("bert_pretrain", "loose"), ("bert_pretrain", "held"),
+    ("bert_finetune", "loose")])
+def test_the_one_device_step_is_lowered_and_compiled_once(model, state_made):
+    """Three calls, the first on the state as the caller made it beside a
+    `device_put` batch and the later ones on the step's own (committed)
+    outputs, are ONE program: one record of the step's name, one lowering.
+    Plain `jax.jit(step, donate_argnums=(0, 1))` leaves two from `loose`
+    and `host`. The state is donated whatever committed it."""
+    init, make, batch, name = STEPS[model]()
+    device = jax.devices()[0]
+    state = STATES[state_made](init(jax.random.PRNGKey(0)), device)
+    step = make()
+    since = tr.compile_count()
+    losses = []
+    for _ in range(3):
+        given = jax.tree.leaves(state)
+        out = step(*state, *jax.device_put(batch, device))
+        losses.append(float(out[0]))
+        state = out[-2:]
+        # the caller's own handles went with the buffers
+        assert all(x.is_deleted() for x in given if isinstance(x, jax.Array))
+    (rec,) = _step_records(since, name)
+    assert rec["lower_s"] > 0 and rec["backend_s"] > 0
+    assert all(x.committed for x in jax.tree.leaves(state))
+    assert losses[2] < losses[0]        # and it trains
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+def test_committing_the_state_copies_nothing(model):
+    from hetu_tpu.models import transformer as tfm
+    init, _, batch, _ = STEPS[model]()
+    state = init(jax.random.PRNGKey(0))
+    leaves = jax.tree.leaves(state)
+    assert not any(x.committed for x in leaves)
+    device = jax.devices()[0]
+    args = tfm._commit_state((*state, *jax.device_put(batch, device)))
+    held = jax.tree.leaves(args[:2])
+    assert all(x.committed and x.devices() == {device} for x in held)
+    # a new handle on the SAME buffer: 8.6-10 GiB of state is never copied
+    assert ([x.unsafe_buffer_pointer() for x in held]
+            == [x.unsafe_buffer_pointer() for x in leaves])
+    # and committed state is handed on as the very objects it was
+    assert tfm._commit_state(args) is args
+
+
+def test_loose_state_follows_the_batch_to_its_device():
+    """The device is the one the call's committed arguments are on, not the
+    default one; committed state is never moved (jit's own error says so)."""
+    init, make, batch, name = STEPS["dense"]()
+    there = jax.devices()[3]
+    step = make()
+    state = init(jax.random.PRNGKey(0))
+    since = tr.compile_count()
+    for _ in range(2):
+        loss, *state = step(*state, *jax.device_put(batch, there))
+    assert loss.devices() == {there}
+    assert len(_step_records(since, name)) == 1
+    with pytest.raises(ValueError, match="incompatible devices"):
+        step(*jax.device_put(state, jax.devices()[1]),
+             *jax.device_put(batch, there))
+
+
+def test_nothing_committed_is_left_to_jit():
+    """Uncommitted state beside an uncommitted batch is one program already
+    (jit keeps the outputs uncommitted): the arguments go through as given,
+    and the default device stays jit's to resolve."""
+    from hetu_tpu.models import transformer as tfm
+    init, make, batch, name = STEPS["dense"]()
+    state = init(jax.random.PRNGKey(0))
+    args = (*state, *batch)
+    assert tfm._commit_state(args) is args
+    step = make()
+    since = tr.compile_count()
+    for _ in range(2):
+        loss, *state = step(*state, *batch)
+    assert not loss.committed
+    assert len(_step_records(since, name)) == 1
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+def test_the_step_lowers_on_shapes_to_the_jits_own_text(model):
+    """`.lower` is `jax.jit`'s own, on the arguments as given: on shapes it
+    is the text a plain jit of the step gives (no argument carries a
+    sharding, the state's are donated), under the function's own name. The
+    pinned digests of `test_lfm2_model.py`, unedited, say so for the real
+    cells."""
+    init, make, batch, name = STEPS[model]()
+    shapes = jax.eval_shape(lambda: (*init(jax.random.PRNGKey(0)), *batch))
+    step = make()
+    lowered = step.lower(*shapes)
+    text = lowered.as_text()
+    # S12(e) would name the lambda; every trace reader matches on this one
+    assert text.startswith({"<lambda>": "module @jit__lambda ",
+                            "step": "module @jit_step "}[name])
+    head = text[text.index("func.func public @main("):].split("\n", 1)[0]
+    n_state = len(jax.tree.leaves(shapes[:2]))
+    assert head.count("tf.aliasing_output") == n_state
+    assert "sharding" not in head
+    assert step.eval_shape(*shapes)[0].shape == ()      # the jit's own too
