@@ -956,8 +956,8 @@ def phase_kernels(*, shapes=None, chip=True):
                     scan_and_cotangents(_ssd), (sx, sdt, s_a, sb, sc, sg),
                     atol=2e-2)
 
-        # -- the experts' grouped matmul where the compiler's kernel would
-        # tile by one lane tile: the product and both cotangents against
+        # -- the experts' grouped matmul at the widths the compiler's kernel
+        # would tile by one lane tile: the product and both cotangents against
         # `ragged_dot` INSIDE the groups (neither side defines a row past
         # them), each over its own largest entry
         m, kk, nn, sizes = shapes["grouped_matmul"]

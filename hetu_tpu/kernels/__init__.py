@@ -19,8 +19,8 @@ Layout:
   attention's interleaved pairs and every other model's rotate-half
   columns, gated the pre-tier way (``rope.takes``).
 - :mod:`grouped_matmul` — the experts' grouped matmul, forward, dx and dW,
-  with tiles made from the widths, where the compiler's ``ragged_dot``
-  kernel would tile K or N by one lane tile; registered here
+  with tiles made from the widths, on a TPU in one program at every
+  width (``ragged_dot`` under a mesh and off a TPU); registered here
   (``grouped_matmul``) and dispatched by ``transformer._grouped_matmul``.
 
 Importing this package registers the four tier kernels; the graph ops

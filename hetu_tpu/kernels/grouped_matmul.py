@@ -1,14 +1,17 @@
 """The experts' grouped matmul with tiles made from the widths.
 
-`jax.lax.ragged_dot` is the TPU compiler's own grouped matmul kernel, and it
-tiles K and N each by the largest of 512 / 256 / 128 that DIVIDES the width
-(`ragged_dot_tiling` in the compiled HLO). A width of an odd number of lane
-tiles (2,688 = 21 x 128) or of a half tile (1,856 = 14.5 x 128) gets ONE lane
-tile, and a call is then thousands of grid steps of 512 x 128 x 128, each
-0.085 us of the MXU at ~0.4 us a step (docs/KERNELS.md, "The grouped matmul
-at widths of one lane tile", has both kernels alone on the chip). Here the
-same three products run with a weight block that is whole wherever VMEM
-holds it:
+`jax.lax.ragged_dot` is the TPU compiler's own grouped matmul kernel. It
+walks the rows in tiles of 512 whatever a group holds, and tiles K and N
+each by the largest of 512 / 256 / 128 that DIVIDES the width
+(`ragged_dot_tiling` in the compiled HLO), so a weight block is never whole.
+A width of an odd number of lane tiles (2,688 = 21 x 128) or of a half tile
+(1,856 = 14.5 x 128) gets ONE lane tile, and a call is then thousands of
+grid steps of 512 x 128 x 128; at widths it tiles by 512 (2,048 x 1,792,
+1,024, 768, 512) it reads 39-70 % of peak on groups a few hundred to a few
+thousand rows long, where these kernels read 78-94 (docs/KERNELS.md, "The
+experts' grouped matmul in Pallas at every width", has every cell's calls
+alone on the chip and in the step). Here the same three products run with a
+weight block that is whole wherever VMEM holds it:
 
     forward   (M, K) x (E, K, N) -> (M, N)     rows sorted by group
     dx        (M, N) x (E, K, N)^T -> (M, K)   the weights read transposed
@@ -39,7 +42,6 @@ how tests drive them.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -58,14 +60,16 @@ GROUPED_MATMUL = "grouped_matmul"
 GROUPED_MATMUL_DW = "grouped_matmul_dw"
 
 # rows a visit takes. A visit multiplies the whole tile whatever part of it
-# is the group's, and a share's groups are a few hundred rows (382 on the
-# nemotron cell): in that cell's step 512-row visits read 38.8 % of peak, 256
-# 49.8, 128 51.1; alone on the chip at 1,536 rows a group 0.671, 0.711 and
-# 0.747 ms a call (docs/KERNELS.md)
+# is the group's. Judged in the step (docs/KERNELS.md): on the nemotron
+# cell's groups of ~380 rows 512-row visits read 38.8 % of peak, 256 49.8,
+# 128 51.1 (alone on the chip at 1,536 rows a group 0.671, 0.711 and 0.747 ms
+# a call); on OLMoE's groups of 4,096 rows and up 512 reads 86.9 % for 256's
+# 89.2 and `tokens_per_s` 0.2 % less, on lfm2's 4,096 86.7 for 89.9 and 0.4 %
+# less. One constant for every cell
 _ROW_TILE = 256
 # What a grid step may hold by `_vmem_bytes`' count, and what the call then
 # asks of Mosaic (`vmem_limit_bytes`; the chip has 128 MiB, a kernel gets 16
-# unasked): a whole weight block of the widths this kernel exists for is
+# unasked): a whole weight block of the widest cell (2,688 x 1,856) is
 # 10 MB, twice for the pipeline's two buffers. Whole, a group's block is
 # fetched once for all its visits; in blocks of 896 of the contracted width
 # it streams again every visit, and the step read 45.5 % of peak for 49.8
@@ -139,9 +143,6 @@ def _declines(xs, w, mesh=None):
     if xs.dtype not in (jnp.bfloat16, jnp.float32) or w.dtype != xs.dtype:
         return f"operands {xs.dtype} and {w.dtype}: bfloat16 or float32, alike"
     K, N = w.shape[1:]
-    if math.gcd(K, 512) >= 256 and math.gcd(N, 512) >= 256:
-        return (f"the compiler tiles K {K} and N {N} by two lane tiles or "
-                "more: `ragged_dot` is the faster there")
     if _tiles(xs.shape[0], K, N, xs.dtype.itemsize) is None:
         return f"no tiles of K {K} and N {N} fit VMEM"
     return None
@@ -149,13 +150,11 @@ def _declines(xs, w, mesh=None):
 
 def takes(xs, w, mesh=None) -> bool:
     """The ONE gating rule: the kernel multiplies xs (M, K) with w (E, K, N)
-    on the single-program TPU path, in bfloat16 or float32, where the
-    compiler's own kernel would tile K or N by ONE lane tile: it takes the
-    largest of 512 / 256 / 128 that divides a width, so that is
-    ``gcd(K, 512) < 256 or gcd(N, 512) < 256``. Everywhere else
-    `ragged_dot` stays: at widths the compiler tiles well, under a mesh
-    (GSPMD cannot partition the custom kernel) and off a TPU, where
-    interpret mode would be slower."""
+    on the single-program TPU path, in bfloat16 or float32, at every width
+    whose tiles fit `_VMEM_BUDGET`. `ragged_dot` stays what it is for: under
+    a mesh (GSPMD cannot partition the custom kernel), off a TPU (interpret
+    mode would be slower), an operand type the kernel does not take, no
+    tiles that fit."""
     return registry._on_tpu() and _declines(xs, w, mesh) is None
 
 
@@ -228,7 +227,14 @@ def _asking(count):
     return {"vmem_limit_bytes": _VMEM_LIMIT} if count > _VMEM_UNASKED else {}
 
 
-def _gmm(xs, w, group_sizes, tm, tc, to, transposed):
+# `_gmm` and `_dw` are jitted so that a SIGNATURE is traced and lowered once
+# a process: a layer calls each product with one signature for `w1` and `w3`,
+# again under `remat`, again in every run of layers and in the check's
+# programs, and a call's trace (the visits' tables, the kernel's body) is
+# ~0.1 s on the chip's host (docs/KERNELS.md). `interpret` is an argument so
+# that the backend, which tests patch, is part of the key.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _gmm(xs, w, group_sizes, tm, tc, to, transposed, interpret):
     """Forward, or dx where `transposed`: xs (M, C) against w (E, C, O), or
     (E, O, C) read transposed -> (M, O). Grid (O's tiles, visits, C's
     blocks), the contraction innermost."""
@@ -259,7 +265,7 @@ def _gmm(xs, w, group_sizes, tm, tc, to, transposed):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             **_asking(_vmem_bytes(tm, tc, to, xs.dtype.itemsize, False))),
-        interpret=_interpreted(), name=GROUPED_MATMUL,
+        interpret=interpret, name=GROUPED_MATMUL,
     )(offsets, group, tile, xs, w)
 
 
@@ -290,7 +296,8 @@ def _dw_kernel(offsets, group, tile, x_ref, dy_ref, o_ref, acc):
         o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
-def _dw(xs, dy, group_sizes, tm, tk, tn):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _dw(xs, dy, group_sizes, tm, tk, tn, interpret):
     """dW: xs (M, K) and dy (M, N) -> (E, K, N), group g's the product of
     its rows. Grid (K's tiles, N's tiles, visits), a group's visits in a
     row."""
@@ -313,14 +320,14 @@ def _dw(xs, dy, group_sizes, tm, tk, tn):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             **_asking(_vmem_bytes(tm, tk, tn, xs.dtype.itemsize, True))),
-        interpret=_interpreted(), name=GROUPED_MATMUL_DW,
+        interpret=interpret, name=GROUPED_MATMUL_DW,
     )(offsets, group, tile, xs, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _grouped_matmul(xs, w, group_sizes, tiles):
     tm, (tc, to) = tiles[0], tiles[1]
-    return _gmm(xs, w, group_sizes, tm, tc, to, False)
+    return _gmm(xs, w, group_sizes, tm, tc, to, False, _interpreted())
 
 
 def _grouped_matmul_fwd(xs, w, group_sizes, tiles):
@@ -330,8 +337,9 @@ def _grouped_matmul_fwd(xs, w, group_sizes, tiles):
 def _grouped_matmul_bwd(tiles, saved, dy):
     xs, w, group_sizes = saved
     tm, _, (tc, to), (tk, tn) = tiles
-    return (_gmm(dy, w, group_sizes, tm, tc, to, True),
-            _dw(xs, dy, group_sizes, tm, tk, tn), None)
+    interpret = _interpreted()
+    return (_gmm(dy, w, group_sizes, tm, tc, to, True, interpret),
+            _dw(xs, dy, group_sizes, tm, tk, tn, interpret), None)
 
 
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

@@ -1871,11 +1871,11 @@ def _grouped_matmul(xs, w, group_sizes, mesh=None):
     """Rows sorted by group (M, K) x one matrix a group (E, K, N) -> (M, N):
     ``jax.lax.ragged_dot``, which the TPU compiler lowers to its own grouped
     matmul kernel (M*K*N multiply-adds, not E times that) and differentiates
-    into two more (PERF.md has its share of the roofline). That kernel tiles
-    a width by the largest of 512 / 256 / 128 that divides it: where K or N
-    gets ONE lane tile (2,688, 1,856), on a TPU and in one program, the
-    Pallas kernels of ``kernels/grouped_matmul.py`` serve the call with
-    tiles made from the widths (``gmm_kernel.takes`` is the rule)."""
+    into two more (PERF.md has its share of the roofline). That kernel walks
+    512-row tiles and never holds a weight block whole: on a TPU and in one
+    program the Pallas kernels of ``kernels/grouped_matmul.py`` serve the
+    call at every width, with tiles made from the widths
+    (``gmm_kernel.takes`` is the rule)."""
     return kernel_registry.dispatch(gmm_kernel.GROUPED_MATMUL, xs,
                                     w.astype(xs.dtype), group_sizes, mesh=mesh)
 

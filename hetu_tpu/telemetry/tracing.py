@@ -49,8 +49,8 @@ _T0_UNIX = time.time()
 # `transformer._rope` / `_rope_interleaved`; `ssd_fwd` and `ssd_bwd`, the
 # chunked Mamba-2 scan, whose calls say that a mamba layer's scan runs in
 # `kernels/ssd.py` and not in `transformer._ssd`'s einsums; `grouped_matmul`
-# and `grouped_matmul_dw`, the experts' products at widths the compiler's
-# `ragged-dot-none` would tile by one lane tile, under `hetu_moe_experts`).
+# and `grouped_matmul_dw`, the experts' products on a TPU in one program,
+# where `ragged-dot-none` ran before them, under `hetu_moe_experts`).
 STEP = "hetu_step"        # one SubExecutor.run call, step_num=<step>
 # phase scopes in the compiled program (HLO metadata `op_name`): a device op
 # under SCOPE_OPT is optimizer work, one under `transpose(` backward (its

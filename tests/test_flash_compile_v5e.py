@@ -828,25 +828,35 @@ def test_ssd_kernels_compile_for_v5e_at_granites_scan(
         SSD_LOWERED[half])
 
 
-# rows, groups and the two matrices (K, N) of an expert layer of
-# nemotron-twotower-30b-a3b.pretrain-seq8192-b1-ep16share: 8,192 tokens x 6
-# picks, 8 of 128 experts held
-NEMOTRON_EXPERTS = (49152, 8, {"w1": (2688, 1856), "w2": (1856, 2688)})
+# an expert layer's grouped matmuls at the six expert cells' real calls:
+# rows (tokens x picks), the experts a call sees (a share's held ones) and
+# the matrix (K, N). The nemotron cell's two matrices (8,192 tokens x 6
+# picks, 8 of 128 experts held; PR 56), and `w1` of the five cells whose
+# widths the compiler tiles by 256 or 512 and the rule left to it until PR
+# 59 (`w2` is the same two widths the other way round, which dx already is)
+EXPERT_CALLS = {
+    "nemotron-twotower-30b-a3b.w1": (49152, 8, 2688, 1856),
+    "nemotron-twotower-30b-a3b.w2": (49152, 8, 1856, 2688),
+    "lfm2-8b-a1b.w1": (131072, 8, 2048, 1792),
+    "olmoe-1b-7b.w1": (262144, 64, 2048, 1024),
+    "kanana-2-30b-a3b.w1": (196608, 16, 2048, 768),
+    "keye-vl-2.0-30b-a3b.w1": (262144, 16, 2048, 768),
+    "laguna-xs.2.w1": (131072, 32, 2048, 512)}
 
 
 @pytest.mark.parametrize("product", ["forward", "dx", "dw"])
-@pytest.mark.parametrize("matrix", ["w1", "w2"])
-def test_grouped_matmul_compiles_for_v5e_at_nemotrons_calls(
-        one_chip, no_compile_cache, monkeypatch, matrix, product):
-    """The three products of both matrices at the cell's real calls, bfloat16:
-    the rule takes them, each is ONE Mosaic call under the kernel's name
-    (the reader of `moe_held_experts_roofline_pct` counts calls), no
-    `ragged-dot` is left, dx reads the weights where they lie (no transposed
-    copy of their size) and the VMEM the compiled kernel holds is within
-    the count that chose its tiles."""
+@pytest.mark.parametrize("call", list(EXPERT_CALLS))
+def test_grouped_matmul_compiles_for_v5e_at_the_cells_calls(
+        one_chip, no_compile_cache, monkeypatch, call, product):
+    """The three products at each cell's real call, bfloat16: the rule takes
+    them, each is ONE Mosaic call under the kernel's name (the readers of
+    `moe_held_experts_roofline_pct` and `moe_experts_roofline_pct` count
+    calls), no `ragged-dot` is left, dx reads the weights where they lie (no
+    transposed copy of their size) and the VMEM the compiled kernel holds is
+    within the count that chose its tiles, or within what Mosaic gives a
+    kernel that asks for nothing."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
-    M, E, widths = NEMOTRON_EXPERTS
-    K, N = widths[matrix]
+    M, E, K, N = EXPERT_CALLS[call]
 
     def arr(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -874,4 +884,8 @@ def test_grouped_matmul_compiles_for_v5e_at_nemotrons_calls(
     used, = map(int, re.findall(
         r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
         _kernel_calls(text)[0]))
-    assert used <= count <= gmm._VMEM_BUDGET < gmm._VMEM_LIMIT
+    # a count within `_VMEM_UNASKED` asks Mosaic for nothing and has a
+    # quarter of the default to be wrong by: laguna's forward, the smallest
+    # weight block (2,048 x 512), holds 8,011,776 bytes for 7,864,320 counted
+    assert used <= max(count, gmm._VMEM_UNASKED)
+    assert count <= gmm._VMEM_BUDGET < gmm._VMEM_LIMIT

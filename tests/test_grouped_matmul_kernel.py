@@ -2,9 +2,9 @@
 in interpret mode on the CPU against `jax.lax.ragged_dot`: one table of calls
 for each of the three products (forward, dx, dW), the grid's tables against a
 count by hand, the tiles the widths get, the one gating rule as a table over
-the six expert cells' widths, and the path `transformer._grouped_matmul`
+the six expert cells' calls, and the path `transformer._grouped_matmul`
 takes by what the rule says. What the chip's compiler makes of the kernels at
-the nemotron cell's calls is in `tests/test_flash_compile_v5e.py`."""
+the six cells' calls is in `tests/test_flash_compile_v5e.py`."""
 import functools
 
 import jax
@@ -19,44 +19,77 @@ from hetu_tpu.parallel import mesh as meshlib
 
 # K of an odd number of lane tiles, N of one and a half
 K, N, M = 384, 192, 96
+SMALL = (M, K, N)
 WHOLE = (32, (K, N), (N, K), (K, N))
 # the contraction in blocks of one lane tile (a sum in scratch); the written
 # width cut at a lane tile, so N's second block is a tail: 64 columns of 128
 CUT = (32, (128, 128), (N, 128), (128, 128))
-CHOSEN = None       # `_tiles`' own: one row tile of all 96 rows, widths whole
+CHOSEN = None       # `_tiles`' own: at SMALL one row tile of all 96 rows,
+                    # at W1 and W2 row tiles of 256; the widths whole
 
-# group sizes (rows past their sum belong to no group), tiles, dtype
+# group sizes (rows past their sum belong to no group), tiles, dtype, and
+# the call's (rows, K, N)
 CASES = [
-    pytest.param((10, 50, 20, 16), WHOLE, jnp.float32,
+    pytest.param((10, 50, 20, 16), WHOLE, jnp.float32, SMALL,
                  id="edges-inside-row-tiles"),
-    pytest.param((10, 50, 20, 16), CUT, jnp.float32,
+    pytest.param((10, 50, 20, 16), CUT, jnp.float32, SMALL,
                  id="edges-inside-row-tiles.cut"),
-    pytest.param((32, 0, 40, 24), WHOLE, jnp.float32, id="an-empty-group"),
-    pytest.param((0, 0, 70, 0), CUT, jnp.float32,
+    pytest.param((32, 0, 40, 24), WHOLE, jnp.float32, SMALL,
+                 id="an-empty-group"),
+    pytest.param((0, 0, 70, 0), CUT, jnp.float32, SMALL,
                  id="empty-groups-first-and-last.cut"),
-    pytest.param((0, 96, 0, 0), WHOLE, jnp.float32,
+    pytest.param((0, 96, 0, 0), WHOLE, jnp.float32, SMALL,
                  id="all-rows-in-one-group"),
-    pytest.param((0, 0, 0, 0), WHOLE, jnp.float32, id="no-row-in-any-group"),
-    pytest.param((0, 0, 0, 0), CUT, jnp.float32,
+    pytest.param((0, 0, 0, 0), WHOLE, jnp.float32, SMALL,
+                 id="no-row-in-any-group"),
+    pytest.param((0, 0, 0, 0), CUT, jnp.float32, SMALL,
                  id="no-row-in-any-group.cut"),
-    pytest.param((7, 9, 1, 30), WHOLE, jnp.float32,
+    pytest.param((7, 9, 1, 30), WHOLE, jnp.float32, SMALL,
                  id="half-the-rows-past-the-groups"),
-    pytest.param((33, 31, 1, 0), CUT, jnp.float32,
+    pytest.param((33, 31, 1, 0), CUT, jnp.float32, SMALL,
                  id="a-row-tile-of-three-groups.cut"),
-    pytest.param((24, 24, 24, 24), CHOSEN, jnp.float32, id="chosen-tiles"),
-    pytest.param((10, 50, 20, 16), WHOLE, jnp.bfloat16, id="bfloat16"),
-    pytest.param((7, 9, 1, 30), CUT, jnp.bfloat16, id="bfloat16.cut"),
-    pytest.param((12, 0, 40, 3), CHOSEN, jnp.bfloat16,
+    pytest.param((24, 24, 24, 24), CHOSEN, jnp.float32, SMALL,
+                 id="chosen-tiles"),
+    pytest.param((10, 50, 20, 16), WHOLE, jnp.bfloat16, SMALL, id="bfloat16"),
+    pytest.param((7, 9, 1, 30), CUT, jnp.bfloat16, SMALL, id="bfloat16.cut"),
+    pytest.param((12, 0, 40, 3), CHOSEN, jnp.bfloat16, SMALL,
                  id="bfloat16.chosen-tiles"),
 ]
 
 
+# what the nemotron-shaped cases do not reach: widths of whole 256- and
+# 512-column tiles, which the compiler's kernel tiled by 256 and 512 and the
+# rule left to it before PR 59, with 64 groups, a group across many row
+# tiles, runs of empty groups and a share's rows past the groups
+W1, W2 = (1024, 512, 256), (1024, 256, 512)
+# OLMoE-like: 64 experts, every row held, the fullest group 7 x the mean
+# and across four row tiles, several experts with no row
+OLMOE_LIKE = (700, 0, 0, 3, 17, 0, 40, 8) + (4,) * 56 + (32,)
+# share-like: 8 held experts' rows first, three quarters of the rows in no
+# group; and 64 experts of which most hold nothing
+SHARE_LIKE = (40, 0, 70, 33, 0, 1, 90, 22)
+SPARSE_64 = (0,) * 20 + (300,) + (0,) * 20 + (5,) * 22 + (0,)
+CASES += [
+    pytest.param(OLMOE_LIKE, CHOSEN, jnp.bfloat16, W1, id="olmoe-like.w1"),
+    pytest.param(OLMOE_LIKE, CHOSEN, jnp.bfloat16, W2, id="olmoe-like.w2"),
+    pytest.param(OLMOE_LIKE, CHOSEN, jnp.float32, W1,
+                 id="olmoe-like.w1.float32"),
+    pytest.param(SHARE_LIKE, CHOSEN, jnp.bfloat16, W1, id="share-like.w1"),
+    pytest.param(SHARE_LIKE, CHOSEN, jnp.bfloat16, W2, id="share-like.w2"),
+    pytest.param(SPARSE_64, CHOSEN, jnp.bfloat16, W2,
+                 id="64-experts-most-empty-rows-past.w2"),
+    pytest.param(SPARSE_64, CHOSEN, jnp.float32, W1,
+                 id="64-experts-most-empty-rows-past.w1.float32"),
+]
+
+
 @functools.lru_cache(maxsize=None)
-def _products(sizes, tiles, dtype):
+def _products(sizes, tiles, dtype, shape):
     """-> (held, the kernels' (y, dx, dW), `ragged_dot`'s): seeded operands
     whose rows PAST the groups are NaN, in xs and in y's cotangent, for the
     kernels; the oracle gets zeros there (the rows are no group's, so its
     dW sees nothing of them either way)."""
+    M, K, N = shape
     ks = jax.random.split(jax.random.PRNGKey(len(sizes) + sum(sizes)), 3)
     xs = jax.random.normal(ks[0], (M, K), jnp.float32).astype(dtype)
     w = jax.random.normal(ks[1], (len(sizes), K, N), jnp.float32).astype(dtype)
@@ -86,25 +119,25 @@ def _close(got, want, dtype):
         np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -7)
 
 
-@pytest.mark.parametrize("sizes,tiles,dtype", CASES)
-def test_forward_is_ragged_dot_inside_the_groups(sizes, tiles, dtype):
-    held, got, want = _products(sizes, tiles, dtype)
+@pytest.mark.parametrize("sizes,tiles,dtype,shape", CASES)
+def test_forward_is_ragged_dot_inside_the_groups(sizes, tiles, dtype, shape):
+    held, got, want = _products(sizes, tiles, dtype, shape)
     _close(got[0][:held], want[0][:held], dtype)
 
 
-@pytest.mark.parametrize("sizes,tiles,dtype", CASES)
-def test_dx_is_ragged_dots_inside_the_groups(sizes, tiles, dtype):
+@pytest.mark.parametrize("sizes,tiles,dtype,shape", CASES)
+def test_dx_is_ragged_dots_inside_the_groups(sizes, tiles, dtype, shape):
     """The weights read transposed in the kernel; a NaN cotangent past the
     groups changes no row inside them."""
-    held, got, want = _products(sizes, tiles, dtype)
+    held, got, want = _products(sizes, tiles, dtype, shape)
     _close(got[1][:held], want[1][:held], dtype)
 
 
-@pytest.mark.parametrize("sizes,tiles,dtype", CASES)
-def test_dw_is_ragged_dots_and_finite(sizes, tiles, dtype):
+@pytest.mark.parametrize("sizes,tiles,dtype,shape", CASES)
+def test_dw_is_ragged_dots_and_finite(sizes, tiles, dtype, shape):
     """Every group's matrix, an empty group's zeros among them, with NaN in
     both operands' rows past the groups."""
-    _, got, want = _products(sizes, tiles, dtype)
+    _, got, want = _products(sizes, tiles, dtype, shape)
     _close(got[2], want[2], dtype)
     for g, size in enumerate(sizes):
         if size == 0:
@@ -156,14 +189,17 @@ def test_tiles_at_the_nemotron_cells_widths():
     assert gmm._tiles(4096, 100_000 * 128 + 64, 192, 4) is None
 
 
-# the six expert cells' (hidden, expert) widths: w1 is (K, N) = (D, F), w2
-# (F, D), and dx and dW of both are calls of the same two widths
-CELLS = {"olmoe-1b-7b": (2048, 1024), "lfm2-8b-a1b": (2048, 1792),
-         "kanana-2-30b-a3b": (2048, 768), "keye-vl-2.0-30b-a3b": (2048, 768),
-         "laguna-xs.2": (2048, 512),
-         "nemotron-twotower-30b-a3b": (2688, 1856)}
-RULE = [pytest.param(D, F, name.startswith("nemotron"), id=f"{name}.{which}")
-        for name, widths in CELLS.items()
+# the six expert cells' calls: rows (tokens x picks), experts a call sees,
+# and the (hidden, expert) widths: w1 is (K, N) = (D, F), w2 (F, D), and dx
+# and dW of both are calls of the same two widths
+CELLS = {"olmoe-1b-7b": (262144, 64, (2048, 1024)),
+         "lfm2-8b-a1b": (131072, 8, (2048, 1792)),
+         "kanana-2-30b-a3b": (196608, 16, (2048, 768)),
+         "keye-vl-2.0-30b-a3b": (262144, 16, (2048, 768)),
+         "laguna-xs.2": (131072, 32, (2048, 512)),
+         "nemotron-twotower-30b-a3b": (49152, 8, (2688, 1856))}
+RULE = [pytest.param(rows, E, D, F, id=f"{name}.{which}")
+        for name, (rows, E, widths) in CELLS.items()
         for which, (D, F) in (("w1", widths), ("w2", widths[::-1]))]
 
 
@@ -172,30 +208,54 @@ def _call(K_, N_, dtype=jnp.bfloat16, M_=4096, E=8):
             jax.ShapeDtypeStruct((E, K_, N_), dtype))
 
 
-@pytest.mark.parametrize("K_,N_,engages", RULE)
-def test_only_the_nemotron_cells_widths_engage(monkeypatch, K_, N_, engages):
-    """The compiler's tile rule, the largest of 512 / 256 / 128 that divides
-    a width: the kernel takes a call where that is ONE lane tile for K or
-    for N. Under a mesh and off a TPU never."""
+@pytest.mark.parametrize("rows,E,K_,N_", RULE)
+def test_every_expert_cells_call_engages_on_a_tpu_in_one_program(
+        monkeypatch, rows, E, K_, N_):
+    """On a TPU, in one program, every cell's `w1` and `w2` call goes to the
+    kernels, whatever the compiler would tile the widths by, with the
+    weights' block whole in forward and dx; under a mesh and off a TPU none
+    does."""
     monkeypatch.setattr(registry, "_on_tpu", lambda: True)
-    assert gmm.takes(*_call(K_, N_)) is engages
-    assert gmm.takes(*_call(K_, N_, jnp.float32)) is engages
+    call = _call(K_, N_, M_=rows, E=E)
+    assert gmm.takes(*call)
+    assert gmm.takes(*_call(K_, N_, jnp.float32, rows, E))
+    assert gmm._tiles(rows, K_, N_, 2)[:3] == (256, (K_, N_), (N_, K_))
     mesh = meshlib.make_mesh(dp=2, devices=jax.devices()[:2])
-    assert not gmm.takes(*_call(K_, N_), mesh)
-    assert gmm.takes(*_call(K_, N_), meshlib.make_mesh(
-        dp=1, devices=jax.devices()[:1])) is engages
+    assert not gmm.takes(*call, mesh)
+    assert "under a mesh" in gmm._declines(*call, mesh)
+    assert gmm.takes(*call, meshlib.make_mesh(
+        dp=1, devices=jax.devices()[:1]))
     monkeypatch.setattr(registry, "_on_tpu", lambda: False)
-    assert not gmm.takes(*_call(K_, N_))
+    assert not gmm.takes(*call)
 
 
-@pytest.mark.parametrize("K_,N_,dtype,engages", [
-    (2688, 2048, jnp.bfloat16, True),      # K alone of one lane tile
-    (3072, 1856, jnp.bfloat16, True),      # N alone
-    (3072, 2048, jnp.bfloat16, False), (2560, 1792, jnp.bfloat16, False),
-    (2688, 1856, jnp.float16, False), (384, 192, jnp.float32, True)])
-def test_rule_by_width_and_dtype(monkeypatch, K_, N_, dtype, engages):
+def _operands(xs_shape, w_shape, xs_dtype=jnp.bfloat16, w_dtype=None):
+    return (jax.ShapeDtypeStruct(xs_shape, xs_dtype),
+            jax.ShapeDtypeStruct(w_shape, w_dtype or xs_dtype))
+
+
+@pytest.mark.parametrize("call,declines", [
+    # no width condition: one lane tile for K, for N, for neither
+    (_call(2688, 2048), None), (_call(3072, 1856), None),
+    (_call(3072, 2048), None), (_call(2560, 1792), None),
+    # float32 as bfloat16 (docs/KERNELS.md has the call timed on the chip)
+    (_call(384, 192, jnp.float32), None),
+    (_call(2048, 1024, jnp.float32, 262144, 64), None),
+    # the operand types and shapes the kernel does not take
+    (_call(2688, 1856, jnp.float16), "bfloat16 or float32, alike"),
+    (_operands((4096, 512), (8, 512, 256), jnp.bfloat16, jnp.float32),
+     "bfloat16 or float32, alike"),
+    (_operands((4096, 512), (8, 384, 256)), "not (M, K) x (E, K, N)"),
+    (_operands((4, 4096, 512), (8, 512, 256)), "not (M, K) x (E, K, N)"),
+    # a contracted width with no lane-tile divisor that VMEM cannot hold whole
+    (_call(100_000 * 128 + 64, 192, jnp.float32), "fit VMEM"),
+    (_call(100_000 * 128 + 64, 2048), "fit VMEM")])
+def test_rule_by_dtype_shape_and_vmem(monkeypatch, call, declines):
     monkeypatch.setattr(registry, "_on_tpu", lambda: True)
-    assert gmm.takes(*_call(K_, N_, dtype)) is engages
+    reason = gmm._declines(*call)
+    assert gmm.takes(*call) is (declines is None)
+    assert (reason is None) if declines is None else (declines in reason)
+    assert gmm._eligible(*call, None) == (declines is None, reason)
 
 
 def _served(monkeypatch, on_tpu, mode, K_, N_, mesh=None):
@@ -216,16 +276,16 @@ def _served(monkeypatch, on_tpu, mode, K_, N_, mesh=None):
 
 @pytest.mark.parametrize("on_tpu,mode,K_,N_,meshed,path,kernel", [
     (True, "auto", 384, 192, False, "pallas", True),
-    (True, "auto", 512, 256, False, "fallback", False),
+    (True, "auto", 512, 256, False, "pallas", True),
     (True, "auto", 384, 192, True, "fallback", False),
     (False, "auto", 384, 192, False, "fallback", False),
     (True, "off", 384, 192, False, "off", False),
     (False, "force", 384, 192, False, "forced", True)])
 def test_the_experts_call_takes_the_path_the_rule_names(
         monkeypatch, on_tpu, mode, K_, N_, meshed, path, kernel):
-    """One call site, one dispatch: the kernel on a TPU at widths of one
-    lane tile, `ragged_dot` at the others, under a mesh, off a TPU and with
-    the tier off; the tier's counter says which."""
+    """One call site, one dispatch: the kernel on a TPU at any width,
+    `ragged_dot` under a mesh, off a TPU and with the tier off; the tier's
+    counter says which."""
     mesh = (meshlib.make_mesh(dp=2, devices=jax.devices()[:2]) if meshed
             else None)
     paths, primitives = _served(monkeypatch, on_tpu, mode, K_, N_, mesh)

@@ -144,8 +144,7 @@ def test_a_share_works_on_the_rows_it_holds(
 @pytest.fixture
 def grouped_matmul_kernels_taken(monkeypatch):
     """The experts' grouped matmuls by the Pallas kernels, interpreted: what
-    `registry.dispatch` serves on a TPU at this file's widths (64 and 48 are
-    under one lane tile, so the compiler's kernel would tile them by one)."""
+    `registry.dispatch` serves on a TPU in one program at any width."""
     monkeypatch.setattr(registry, "_on_tpu", lambda: True)
     registry.reset_stats()
     yield
