@@ -18,17 +18,17 @@ scan/while loop decodes from the boundary; ragged batches (per-row
 crosses its own prompt boundary at a different step. Either way the whole
 thing is one compiled program.
 
-Dense MLP blocks only (the switch MoE flagship path is a training
-configuration; decode asserts ``n_experts == 0`` and refuses
-``qk_norm``, ``n_loops > 1``, ``sandwich_norm``, mamba, conv and latent-
-attention layers, a shared expert, a sigmoid router and a share of an expert
-layer). Decode runs single-program (``mesh=None``) or distributed: with a mesh, params keep
-their Megatron tp layout, the KV cache shards batch-over-dp and
-heads-over-tp, and GSPMD inserts the collectives (see
-``make_generate_fn``).
+The plain attention block only: ``_check_decode_args`` lists the config
+fields the mirrored block reads (``_MIRRORED``) and refuses, by name, every
+other field that is off its default: experts, other mixers, loops, a share of
+an expert layer, and whatever a later model adds. Decode runs single-program
+(``mesh=None``) or distributed: with a mesh, params keep their Megatron tp
+layout, the KV cache shards batch-over-dp and heads-over-tp, and GSPMD
+inserts the collectives (see ``make_generate_fn``).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -164,56 +164,35 @@ def _prefill_prefix(params, cfg, prompt, kcache, vcache, enabled,
     return P - 1, prefix, kcache, vcache
 
 
+# The ``TransformerConfig`` fields the mirrored block READS (``_decode_layer``,
+# ``_chunk_hidden``, and ``tfm._norm`` / ``_rope`` / ``_dense_mlp`` /
+# ``lm_head`` under them; ``causal`` has a check of its own), beside the
+# knobs of the TRAINING step alone, which no decode program sees
+# (``attn_impl``, ``fused_lm_ce``, ``remat``, ``dropout_rate``). Every OTHER
+# field says "not the plain attention block" when it leaves its default, so
+# the field a later model adds is refused with no edit here.
+_MIRRORED = frozenset({
+    "vocab_size", "d_model", "n_heads", "n_layers", "d_ff", "max_seq_len",
+    "dtype", "norm", "ln_eps", "post_ln", "rope", "rope_theta", "mlp",
+    "gelu_exact", "n_kv_heads", "attn_proj_bias", "tied_head", "use_pos_emb",
+    "causal", "attn_impl", "fused_lm_ce", "remat", "dropout_rate"})
+
+
 def _check_decode_args(cfg: tfm.TransformerConfig, max_len: int,
                        top_k: int) -> None:
-    assert "mla" not in cfg.layer_types, (
-        f"decode has no latent cache: layer_types={cfg.layer_types} holds "
-        "latent-attention (mla) layers, whose cache is the 512-wide latent "
-        "and one rotary key a token, not k and v (_decode_layer mirrors the "
-        "attention block)")
-    assert "window" not in cfg.layer_types and not (
-            cfg.rope_dim or cfg.rope_yarn or cfg.attn_gate), (
-        f"decode has one cache shape a model and no window eviction: "
-        f"layer_types={cfg.layer_types} holds window layers (a cache of the "
-        "last `window` keys beside the full layers' whole one, at another "
-        f"head count), or rope_dim={cfg.rope_dim} / rope_yarn="
-        f"{cfg.rope_yarn} / attn_gate={cfg.attn_gate} (a partial or scaled "
-        "rotary table, a gate on attention's output: _decode_layer mirrors "
-        "none of them)")
-    assert "dsa" not in cfg.layer_types and not cfg.d_head, (
-        f"decode has no indexer cache: layer_types={cfg.layer_types} holds "
-        "learned-sparse-attention (dsa) layers, whose decode step ranks the "
-        "cached index keys and attends to the kept ones, or d_head="
-        f"{cfg.d_head} is a head width of its own (_decode_layer mirrors "
-        "the attention block at d_model // n_heads)")
-    assert not cfg.single_sublayer and cfg.mlp != "relu2", (
-        f"decode mirrors a block of two halves with a GELU or SwiGLU MLP: "
-        f"single_sublayer={cfg.single_sublayer} (layers of ONE sublayer: a "
-        f"mixer without an MLP half, an MLP half without a mixer), mlp="
-        f"{cfg.mlp!r} (_decode_layer mirrors neither)")
-    assert not cfg.d_ff_shared, (
-        f"decode does not mirror a shared expert (d_ff_shared="
-        f"{cfg.d_ff_shared}: the always-on branch of an expert layer)")
-    assert cfg.n_experts == 0, "decode supports dense blocks (no MoE)"
-    assert not cfg.qk_norm, "decode does not mirror qk_norm (_decode_layer)"
-    assert cfg.n_loops == 1 and not cfg.sandwich_norm, (
-        f"decode does not mirror n_loops={cfg.n_loops} (a cache entry a pass "
-        f"and layer) or sandwich_norm={cfg.sandwich_norm} (_decode_layer)")
-    assert "mamba" not in cfg.layer_types, (
-        f"decode has no recurrent-state cache: layer_types={cfg.layer_types} "
-        "holds mamba layers (_decode_layer mirrors the attention block)")
-    assert "conv" not in cfg.layer_types, (
-        f"decode has no convolution-state cache: layer_types="
-        f"{cfg.layer_types} holds conv layers (_decode_layer mirrors the "
-        "attention block)")
-    assert cfg.router.score == "softmax" and not cfg.router.bias, (
-        f"decode does not mirror {cfg.router}: a sigmoid router or a "
-        "selection bias (_decode_layer)")
-    assert not cfg.router.width, (
-        f"decode of a share of an expert layer ({cfg.router}): the partial "
-        "result of one chip of a group is no model's logits")
-    assert cfg.multipliers == tfm.Multipliers(), (
-        f"decode does not mirror {cfg.multipliers} (_decode_layer)")
+    plain = tfm.TransformerConfig()
+    unmirrored = {
+        f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
+        if f.name not in _MIRRORED
+        and getattr(cfg, f.name) != getattr(plain, f.name)}
+    if cfg.layer_types and set(cfg.layer_types) == {"attention"}:
+        del unmirrored["layer_types"]      # () spelled out
+    if cfg.mlp not in ("gelu", "swiglu"):
+        unmirrored["mlp"] = cfg.mlp
+    assert not unmirrored, (
+        "_decode_layer mirrors the plain attention block (two halves, one "
+        "k/v cache shape a model, a GELU or SwiGLU MLP) and nothing of: "
+        + ", ".join(f"{k}={v!r}" for k, v in unmirrored.items()))
     assert cfg.causal, "decode is autoregressive — causal configs only"
     assert max_len <= cfg.max_seq_len
     assert 0 <= top_k <= cfg.vocab_size, (

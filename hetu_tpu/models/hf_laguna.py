@@ -33,8 +33,8 @@ where ``num_experts`` counts the experts HELD: the chip's share of an expert
 layer) and ``first_expert_held``.
 
 What the program cannot follow is refused BY NAME, here or where it would
-run: decode (``generate._check_decode_args``: one cache shape a model, no
-window eviction), the pipeline (``parallel/pipeline.py``: one kind of block a
+run: decode (``generate._check_decode_args``: the plain attention block
+only), the pipeline (``parallel/pipeline.py``: one kind of block a
 stage), a window layer on a mesh that shards the sequence
 (``transformer._attention_core``: the ring), a share on an ``ep`` mesh
 (``transformer._moe_mlp``), a dense layer that is not leading and a head count

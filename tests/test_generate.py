@@ -350,3 +350,15 @@ def test_eos_decode_matches_scan_and_exits_early():
         last_eos = max((3 + np.where(full[b, 3:] == eos)[0][0])
                        for b in range(4))
         assert int(steps) <= last_eos + 1 < M   # genuinely exited early
+
+
+def test_a_field_the_block_does_not_mirror_is_refused_by_name():
+    """The rule, not a list: `conv_width` is no model's mixer and no assert
+    ever named it; off its default it is refused with its value, and
+    `layer_types` spelled out as attention throughout is the plain block."""
+    import dataclasses
+    with pytest.raises(AssertionError, match=r"plain attention block.*"
+                                             r"conv_width=4"):
+        gen._check_decode_args(dataclasses.replace(CFG, conv_width=4), 16, 0)
+    gen._check_decode_args(dataclasses.replace(
+        CFG, layer_types=("attention",) * CFG.n_layers), 16, 0)

@@ -1,12 +1,12 @@
 """Granite 4.0-H (a hybrid stack: Mamba-2 layers beside grouped-query
 attention without positions) on the flagship trunk, at toy sizes on the CPU:
-the float32 reference against ``transformers``' own forward, the system
-against the reference (hidden states, loss, every gradient leaf by kind),
-the chunked SSD form against the recurrence over time, and the refactor's
-contract that a homogeneous stack is the pytree and the bits it was.
+the system against the float32 reference (hidden states, loss, every
+gradient leaf by kind), the chunked SSD form against the recurrence over
+time, and the refactor's contract that a homogeneous stack is the pytree and
+the bits it was. The reference against ``transformers``' own forward is in
+test_references_against_transformers.py.
 """
 import dataclasses
-import importlib.util
 import json
 import os
 import re
@@ -20,20 +20,10 @@ from hetu_tpu.kernels import ssd as ssd_kernel
 from hetu_tpu.models import bert, hf_granite, hf_olmoe, hf_ouro
 from hetu_tpu.models import transformer as tfm
 from hetu_tpu.telemetry import tracing
+from model_harness import ROOT, load_reference, refuses, rel
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG_DIR = os.path.join(ROOT, "benchmark", "configs", "granite-4.0-h-micro")
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-reference = _load(os.path.join(CONFIG_DIR, "reference.py"),
-                  "granite_reference")
+reference = load_reference("granite-4.0-h-micro")
 
 T = 32
 # the published config's keys at toy widths; every kind of layer, 2 kv heads
@@ -78,44 +68,7 @@ def _seeded(hf, seed):
     return cfg, params
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / max(np.sqrt(np.mean(want ** 2)), 1e-30))
-
-
-# -- the reference against transformers -----------------------------------------
-
-def test_reference_matches_transformers_torch_forward():
-    """The reference's recurrence over time against HF's chunked
-    ``torch_forward`` (and its eager attention, gated norm, multipliers) on
-    copied seeded weights: logits within 1e-4, and within 1e-4 of their RMS
-    (the logits' spread is 0.02 at these weights). Both are float32 on the
-    CPU: HF's chunked sums and the time scan differ by summation order,
-    measured 6e-8 and 1.7e-7."""
-    torch = pytest.importorskip("torch", reason="torch is not installed")
-    try:
-        from transformers import (GraniteMoeHybridConfig,
-                                  GraniteMoeHybridForCausalLM)
-    except ImportError as e:
-        pytest.skip(f"transformers has no GraniteMoeHybridForCausalLM: {e}")
-    cfg, params = _seeded(HF, 0)
-    sd = hf_granite.state_dict_from_params(params, cfg)
-    hf_cfg = GraniteMoeHybridConfig(
-        **{**HF, "intermediate_size": 128, "num_experts_per_tok": 0,
-           "attention_dropout": 0.0, "attn_implementation": "eager"})
-    model = GraniteMoeHybridForCausalLM(hf_cfg).float().eval()
-    missing = model.load_state_dict(
-        {k: torch.tensor(np.asarray(v)) for k, v in sd.items()}, strict=True)
-    assert not missing.missing_keys and not missing.unexpected_keys
-    tokens, _ = _data(HF["vocab_size"], 1)
-    with torch.no_grad():
-        want = model(torch.tensor(np.asarray(tokens), dtype=torch.long),
-                     use_cache=False).logits.numpy()
-    got = np.asarray(reference.logits(sd, tokens, HF))
-    assert np.max(np.abs(got - want)) < 1e-4
-    assert _rel(got, want) < 1e-4 and np.std(want) > 0.01
-
+# -- the reference's own gradient ---------------------------------------------
 
 def test_reference_grads_of_is_jax_grad_of_its_loss():
     """`grads_of` (each layer under `jax.checkpoint`) against `jax.grad` of
@@ -132,7 +85,7 @@ def test_reference_grads_of_is_jax_grad_of_its_loss():
     want = jax.grad(lambda part: reference.loss_terms(
         {**sd, **part}, tokens, targets, HF)[0])({n: sd[n] for n in names})
     for n in names:
-        assert _rel(got[n], want[n]) < 1e-6, n
+        assert rel(got[n], want[n]) < 1e-6, n
 
 
 # -- the system against the reference -------------------------------------------
@@ -166,7 +119,7 @@ def test_system_matches_reference_hidden_loss_and_every_gradient():
             h, _ = tfm.forward_hidden(
                 {**params, "blocks": tfm.blocks_of_runs(runs[:r])},
                 tokens, sub)
-            assert _rel(h, want["hidden"][n - 1]) < 1e-5, n
+            assert rel(h, want["hidden"][n - 1]) < 1e-5, n
     assert abs(float(loss) - float(want_loss)) < 1e-6
     assert np.isfinite(float(loss))
     names = sorted(n for n in sd if n != "lm_head.weight")
@@ -185,7 +138,7 @@ def test_system_matches_reference_hidden_loss_and_every_gradient():
             continue
         seen.add(leaf)
         assert np.sqrt(np.mean(np.asarray(w) ** 2)) > 0, leaf
-        assert _rel(g, w) < 1e-4, (jax.tree_util.keystr(path), _rel(g, w))
+        assert rel(g, w) < 1e-4, (jax.tree_util.keystr(path), rel(g, w))
     assert seen == {"A_log", "dt_bias", "D", "conv_w", "conv_b", "w_in",
                     "w_out", "ssm_norm", "wqkv", "wo", "w1", "w2", "w3",
                     "ln1_scale", "ln2_scale", "lnf_scale", "embed"}
@@ -243,7 +196,7 @@ def test_chunked_form_is_the_recurrence_over_time(order, chunk, impl,
         loss = tfm.loss_fn(params, tokens, targets, cfg)
         h, _ = tfm.forward_hidden(params, tokens, cfg)
     assert abs(float(loss) - float(want_loss)) < 1e-6
-    assert _rel(h, want["hidden"][-1]) < (1e-5 if taken is None else 2e-5)
+    assert rel(h, want["hidden"][-1]) < (1e-5 if taken is None else 2e-5)
     assert taken is None or (taken and set(taken) == {chunk})
 
 
@@ -386,8 +339,8 @@ def test_generate_and_pipeline_refuse_mamba_layers_by_name():
     from hetu_tpu.models import generate
     from hetu_tpu.parallel import pipeline
     cfg, _ = _seeded(HF, 17)
-    with pytest.raises(AssertionError, match="mamba"):
-        generate._check_decode_args(cfg, 16, 0)
+    refuses(lambda: generate._check_decode_args(cfg, 16, 0),
+            "layer_types=('mamba', 'mamba', 'attention', 'mamba')")
     with pytest.raises(NotImplementedError, match="mamba"):
         pipeline._make_stage_fn(cfg, 2)
 

@@ -15,8 +15,8 @@ from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels import rope as rope_kernel
 from hetu_tpu.models import hf_deepseek_v3 as hd, transformer as tfm
 from hetu_tpu.telemetry import tracing
-from test_kanana_model import (ROTATED, SHARE, reference,  # noqa: F401
-                               rope_kernel_taken)
+from model_harness import rope_kernel_taken  # noqa: F401
+from test_kanana_model import ROTATED, SHARE, reference
 
 
 # -- the rotary columns ------------------------------------------------------------

@@ -2,14 +2,15 @@
 on the CPU at a small size with the real structure (latent attention whose
 keys are wider than its values, 1 dense layer + 2 expert layers with a shared
 expert; 8 experts, top 2): the system against the float32 reference
-(benchmark/configs/kanana-2-30b-a3b/reference.py), the reference against
-`transformers`' `DeepseekV3ForCausalLM` on copied weights, the eight shares
-of an expert layer adding up to the whole with the shared expert counted
-once, the scopes, and the refusals by name (the other cells' lowered steps:
-test_lfm2_model.py's one table of digests). Its kernels (rotation, flash at two widths, remat's
-names), which need no trained system, are in test_kanana_kernels.py."""
+(benchmark/configs/kanana-2-30b-a3b/reference.py), the eight shares of an
+expert layer adding up to the whole with the shared expert counted once, the
+scopes, and the refusals by name. The reference against `transformers`'
+`DeepseekV3ForCausalLM` is in test_references_against_transformers.py, the
+other cells' lowered steps in test_cell_digests.py, and its kernels
+(rotation, flash at two widths, remat's names), which need no trained
+system, in test_kanana_kernels.py."""
 import dataclasses
-import importlib.util
+import functools
 import json
 import os
 import re
@@ -24,20 +25,11 @@ from hetu_tpu.models import (generate, hf_deepseek_v3 as hd,
                              transformer as tfm)
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
+from model_harness import (ROOT, grads_of_loss, jitted, load_reference,
+                           refuses, rel, rope_kernel_taken,  # noqa: F401
+                           round_trip, seeded_params, seeded_tokens)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT,
-                                                                     path))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-reference = _load("benchmark/configs/kanana-2-30b-a3b/reference.py",
-                  "kanana_reference")
+reference = load_reference("kanana-2-30b-a3b")
 
 # the published keys at a small size, every expert held
 HF = dict(
@@ -65,48 +57,10 @@ ROTATED = {**SHARE, "num_attention_heads": 2, "num_key_value_heads": 2,
 CONFIGS = {"whole": HF, "share": SHARE, "rope-kernel": ROTATED}
 
 
-@pytest.fixture()
-def rope_kernel_taken(monkeypatch):
-    """What `transformer._rope_q` does on a TPU: the kernel wherever its
-    blocks divide the shape. -> the list of the shapes it was called at."""
-    seen = []
-    rotate = rope_kernel._rotate
-
-    def noting(x, *rest):
-        seen.append(x.shape)
-        return rotate(x, *rest)
-
-    monkeypatch.setattr(rope_kernel, "_on_tpu", lambda: True)
-    monkeypatch.setattr(rope_kernel, "_rotate", noting)
-    return seen
-
-
-def _data(hf, seed, B=2, T=32):
-    ids = jax.random.randint(jax.random.PRNGKey(seed), (B, T + 1), 0,
-                             hf["vocab_size"])
-    return ids[:, :-1], ids[:, 1:]
-
-
-def _params(cfg, seed=0, bias=0.05):
-    """Seeded weights, the selection bias moved off zero so that it matters
-    to the picks, the norms' scales off one."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    key = jax.random.PRNGKey(seed + 100)
-
-    def off(path, x):
-        if tfm._is_router_bias(path):
-            return bias * jax.random.normal(key, x.shape)
-        if path[-1].key in ("kv_norm", "ln1_scale", "ln2_scale"):
-            return x + 0.1 * jax.random.normal(key, x.shape)
-        return x
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / max(np.sqrt(np.mean(want ** 2)), 1e-30))
+# seeded weights, the selection bias moved off zero so that it matters to the
+# picks, the norms' scales off one
+_params = functools.partial(
+    seeded_params, noisy=("kv_norm", "ln1_scale", "ln2_scale"))
 
 
 # -- the loader ------------------------------------------------------------------
@@ -162,14 +116,14 @@ def test_config_from_hf_reads_every_key_of_the_row():
     ("num_key_value_heads", 2, "every head's own"),
     ("qk_head_dim", 64, "qk_nope_head_dim +")])
 def test_loader_refuses_by_name(key, value, named):
-    with pytest.raises(NotImplementedError, match=re.escape(named)):
-        hd.config_from_hf({**HF, key: value})
+    refuses(lambda: hd.config_from_hf({**HF, key: value}), named,
+            NotImplementedError)
 
 
 def test_state_dict_round_trip_and_names():
     cfg = hd.config_from_hf(SHARE)
     params = _params(cfg)
-    sd = hd.state_dict_from_params(params, cfg)
+    sd = round_trip(hd, params, cfg)
     assert sd["model.layers.1.mlp.gate.e_score_correction_bias"].shape == (8,)
     assert sd["model.layers.0.self_attn.kv_b_proj.weight"].shape == (
         4 * (32 + 24), 32)
@@ -177,9 +131,6 @@ def test_state_dict_round_trip_and_names():
         64, 96)
     assert "model.layers.1.mlp.experts.2.gate_proj.weight" in sd
     assert "model.layers.1.mlp.experts.0.gate_proj.weight" not in sd
-    back = hd.params_from_state_dict(sd, cfg, xp=jnp)
-    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # kv_b_proj's rows are a head [k_nope | v]; the trunk's columns are
     # [every head's k_nope | every head's v]
     w = np.asarray(sd["model.layers.0.self_attn.kv_b_proj.weight"])
@@ -199,14 +150,15 @@ def test_system_matches_reference_loss_hidden_picks_and_gradients(
              if which == "rope-kernel" else None)
     cfg = hd.config_from_hf(hf, router_bias_rate=1e-3)
     params = _params(cfg)
-    tokens, targets = _data(hf, 1)
+    tokens, targets = seeded_tokens(hf, 1)
     sd = hd.state_dict_from_params(params, cfg)
     want_loss, want = reference.loss_terms(sd, tokens, targets, hf)
-    loss = tfm.loss_fn(params, tokens, targets, cfg)
+    # `which` in the key: the rope-kernel case traces under its patch
+    loss = jitted(tfm.loss_fn, cfg, which)(params, tokens, targets)
     assert abs(float(loss) - float(want_loss)) < 2e-6
-    hidden, _ = tfm.forward_hidden(params, tokens, cfg)
-    assert _rel(hidden, want["hidden"][-1]) < 2e-6
-    stats = tfm.moe_routing_stats(params, tokens, cfg)
+    hidden, _ = jitted(tfm.forward_hidden, cfg, which)(params, tokens)
+    assert rel(hidden, want["hidden"][-1]) < 2e-6
+    stats = jitted(tfm.moe_routing_stats, cfg, which)(params, tokens)
     np.testing.assert_array_equal(
         np.sort(np.asarray(stats["experts"]), -1),
         np.sort(np.asarray(want["experts"]), -1))
@@ -214,11 +166,11 @@ def test_system_matches_reference_loss_hidden_picks_and_gradients(
                                   np.asarray(want["counts"]))
     assert int(stats["dropped"].sum()) == 0
     grads = hd.state_dict_from_params(
-        jax.grad(tfm.loss_fn)(params, tokens, targets, cfg), cfg)
+        jitted(grads_of_loss, cfg, which)(params, tokens, targets), cfg)
     names = [n for n in sd if "e_score" not in n]
     _, want_grads = reference.grads_of(names)(sd, tokens, targets, hf)
     for n in names:
-        assert _rel(grads[n], want_grads[n]) < 2e-5, n
+        assert rel(grads[n], want_grads[n]) < 2e-5, n
     # the lean gradient is jax.grad of the plain forward
     few = ["model.layers.1.self_attn.kv_a_layernorm.weight",
            "model.layers.1.mlp.gate.weight",
@@ -226,7 +178,7 @@ def test_system_matches_reference_loss_hidden_picks_and_gradients(
     plain = jax.grad(lambda part: reference.loss_terms(
         {**sd, **part}, tokens, targets, hf)[0])({n: sd[n] for n in few})
     for n in few:
-        assert _rel(want_grads[n], plain[n]) < 1e-5, n
+        assert rel(want_grads[n], plain[n]) < 1e-5, n
     if taken is not None:
         # forward, `jax.grad`'s forward and its transpose, a layer each
         assert taken and set(taken) == {(2, 32, 2 * 192)}
@@ -240,7 +192,7 @@ def test_flash_path_is_the_dot_path(which, request):
     the dot path."""
     cfg = hd.config_from_hf(CONFIGS[which])
     params = _params(cfg)
-    tokens, targets = _data(SHARE, 2)
+    tokens, targets = seeded_tokens(SHARE, 2)
     flash = dataclasses.replace(cfg, attn_impl="flash")
     a, ga = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, cfg)
     taken = (request.getfixturevalue("rope_kernel_taken")
@@ -256,7 +208,7 @@ def test_flash_path_is_the_dot_path(which, request):
 def test_bias_moves_by_the_sign_rule_and_adamw_leaves_it():
     cfg = hd.config_from_hf(HF, router_bias_rate=1e-2)
     params = _params(cfg, bias=0.0)
-    tokens, targets = _data(HF, 3)
+    tokens, targets = seeded_tokens(HF, 3)
     opt = tfm.init_opt_state(params)
     want = reference.loss_terms(hd.state_dict_from_params(params, cfg),
                                 tokens, targets, HF)[1]["counts"]
@@ -267,41 +219,6 @@ def test_bias_moves_by_the_sign_rule_and_adamw_leaves_it():
         reference.bias_after_step(np.zeros((2, 8)), want, 1e-2), atol=1e-7)
     np.testing.assert_array_equal(
         np.asarray(opt["m"]["blocks"][1][tfm.ROUTER_BIAS]), np.asarray(want))
-
-
-# -- the reference against transformers --------------------------------------------
-
-def test_reference_matches_transformers_deepseek_v3():
-    """`DeepseekV3ForCausalLM` (eager attention, float32) on copied weights,
-    every expert held, the selection bias off zero: the reference's logits
-    are HF's."""
-    torch = pytest.importorskip("torch")
-    transformers = pytest.importorskip("transformers")
-    keys = {k: v for k, v in HF.items() if k not in ("qk_head_dim",)}
-    config = transformers.DeepseekV3Config(**keys,
-                                           attn_implementation="eager")
-    torch.manual_seed(0)
-    model = transformers.DeepseekV3ForCausalLM(config).eval().float()
-    with torch.no_grad():
-        for name, buf in model.named_buffers():
-            if name.endswith("e_score_correction_bias"):
-                buf.copy_(0.05 * torch.randn_like(buf))
-        for name, p in model.named_parameters():
-            if name.endswith("layernorm.weight") or name == "model.norm.weight":
-                p.add_(0.1 * torch.randn_like(p))
-    sd = {k: v.detach().numpy() for k, v in model.state_dict().items()
-          if "rotary_emb" not in k}
-    tokens, _ = _data(HF, 4)
-    with torch.no_grad():
-        want = model(torch.tensor(np.asarray(tokens))).logits.numpy()
-    got = reference.logits(sd, tokens, HF)
-    assert _rel(got, want) < 2e-5
-    # and the trunk loads the same checkpoint to the same logits
-    cfg = hd.config_from_hf(config)
-    assert cfg.mla.qk_dim == 48 and cfg.d_ff_shared == 96
-    params = hd.params_from_hf(model.state_dict(), cfg)
-    ours, _ = tfm.forward(params, tokens, cfg)
-    assert _rel(ours, want) < 2e-5
 
 
 # -- the shares add up -------------------------------------------------------------
@@ -372,7 +289,7 @@ def test_scopes_of_latent_attention_and_the_shared_expert_in_the_step(
         which, request):
     cfg = hd.config_from_hf(CONFIGS[which], router_bias_rate=1e-3)
     params = _params(cfg)
-    tokens, targets = _data(SHARE, 8)
+    tokens, targets = seeded_tokens(SHARE, 8)
     if which == "rope-kernel":
         request.getfixturevalue("rope_kernel_taken")
     text = tfm.make_train_step(cfg).lower(
@@ -428,17 +345,15 @@ def test_scopes_of_latent_attention_and_the_shared_expert_in_the_step(
 
 def test_decode_and_pipeline_refuse_by_name():
     cfg = hd.config_from_hf(HF)
-    with pytest.raises(AssertionError, match="latent cache"):
-        generate._check_decode_args(cfg, 16, 0)
+    decode = lambda c: lambda: generate._check_decode_args(c, 16, 0)
+    refuses(decode(cfg), "mla=MLAConfig(")
     no_mla = dataclasses.replace(cfg, layer_types=(), mla=None)
-    with pytest.raises(AssertionError, match="shared expert"):
-        generate._check_decode_args(no_mla, 16, 0)
+    refuses(decode(no_mla), "d_ff_shared=96")
     share = dataclasses.replace(
         hd.config_from_hf(SHARE), layer_types=(), mla=None, d_ff_shared=0,
         n_experts=0, n_dense_layers=0,
         router=tfm.Router(width=8, first_held=2))
-    with pytest.raises(AssertionError, match="share of an expert layer"):
-        generate._check_decode_args(share, 16, 0)
+    refuses(decode(share), "width=8, first_held=2")
     with pytest.raises(NotImplementedError, match="unequal kinds"):
         pipeline._make_stage_fn(cfg, 1)
     one_kind = dataclasses.replace(cfg, n_dense_layers=0)

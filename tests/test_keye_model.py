@@ -8,10 +8,10 @@ L_I, the kept sets, every gradient leaf; the indexer's leaves get nothing
 from the cross-entropy and the trunk's nothing from L_I; with top_k >= T the
 mixer is the dense `attention` kind bit for bit; the eight shares of an
 expert layer add up to the uncut layer; `d_head` round-trips through
-`hf_keye`; the kept-pair counter against its closed form; the scopes; refusals by
-name (the other cells' lowered steps: test_lfm2_model.py's one table)."""
+`hf_keye`; the kept-pair counter against its closed form; the scopes;
+refusals by name (the other cells' lowered steps: test_cell_digests.py)."""
 import dataclasses
-import importlib.util
+import functools
 import json
 import os
 import re
@@ -25,22 +25,11 @@ from hetu_tpu.kernels import dsa, flash_attention as fa
 from hetu_tpu.models import generate, hf_keye, transformer as tfm
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
+from model_harness import (ROOT, grads_of_loss, jitted, load_reference,
+                           refuses, rel, round_trip, seeded_params,
+                           seeded_tokens, sub_jaxprs)
 
-from test_remat import _sub_jaxprs
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT,
-                                                                     path))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-reference = _load("benchmark/configs/keye-vl-2.0-30b-a3b/reference.py",
-                  "keye_reference")
+reference = load_reference("keye-vl-2.0-30b-a3b")
 
 ASSUMED = {"router_aux_loss_coef": 0.01, "router_z_loss_coef": 0.001,
            "indexer_loss_coef": 1.0}
@@ -72,38 +61,17 @@ INDEXER = tuple(hf_keye.hf_name(0, hf_keye.VECTORS[n]) for n in (
         "wq_idx", "wk_idx", "ww_idx"))
 
 
-def _data(hf, seed, B=2, T=32):
-    ids = jax.random.randint(jax.random.PRNGKey(seed), (B, T + 1), 0,
-                             hf["vocab_size"])
-    return ids[:, :-1], ids[:, 1:]
-
-
-def _params(cfg, seed=0):
-    """Seeded weights, every vector off its initial 1 or 0 so that it
-    matters, the indexer's Linears larger so that its scores spread."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    key = jax.random.PRNGKey(seed + 100)
-
-    def off(path, x):
-        name = path[-1].key
-        if name.endswith(("_scale", "_norm")) or name == "k_idx_norm_bias":
-            return x + 0.1 * jax.random.normal(key, x.shape)
-        if name in ("wq_idx", "wk_idx", "ww_idx"):
-            return 10.0 * x
-        return x
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / max(np.sqrt(np.mean(want ** 2)), 1e-30))
+# seeded weights, every vector off its initial 1 or 0 so that it matters, the
+# indexer's Linears larger so that its scores spread
+_params = functools.partial(
+    seeded_params, bias=None, tenfold=("wq_idx", "wk_idx", "ww_idx"),
+    noisy=lambda name: name.endswith(("_scale", "_norm"))
+    or name == "k_idx_norm_bias")
 
 
 def _kept_sets(params, tokens, cfg):
-    """The system's kept set of every layer, [L x (B, T, T) bool], from the
-    packed masks its own forward pass makes."""
+    """The system's kept set of every layer, [L x (B, T, T) bool] by query
+    and the same by key, from the packed masks its own forward pass makes."""
     sets = []
     h = tfm.embed_tokens(params, tokens, cfg)
     for i in range(cfg.n_layers):
@@ -111,13 +79,14 @@ def _kept_sets(params, tokens, cfg):
         x = tfm._norm(h, p["ln1_scale"], p["ln1_bias"], cfg)
         (by_query, by_key), _ = dsa.select(*tfm._dsa_index(x, p, cfg),
                                            cfg.dsa.top_k)
-        keep = fa.unpack_row_mask(by_query)
-        np.testing.assert_array_equal(
-            np.asarray(fa.unpack_row_mask(by_key)),
-            np.asarray(keep).swapaxes(1, 2))
-        sets.append(keep)
+        sets.append((fa.unpack_row_mask(by_query),
+                     fa.unpack_row_mask(by_key)))
         h, _ = tfm._block(h, p, cfg, None, kind="dsa")
     return sets
+
+
+def _routing_terms(params, tokens, cfg):
+    return tfm.moe_routing_stats(params, tokens, cfg, terms=True)
 
 
 # -- the loader ------------------------------------------------------------------
@@ -169,8 +138,8 @@ def test_config_from_hf_reads_the_cell_and_the_small_size():
      "indexer_num_kv_heads"),
 ])
 def test_loader_refuses_by_name(key, value, named):
-    with pytest.raises(NotImplementedError, match=named):
-        hf_keye.config_from_hf({**HF, key: value})
+    refuses(lambda: hf_keye.config_from_hf({**HF, key: value}), named,
+            NotImplementedError)
 
 
 def test_head_dim_of_its_own_round_trips_through_the_loader():
@@ -180,7 +149,7 @@ def test_head_dim_of_its_own_round_trips_through_the_loader():
     cfg = hf_keye.config_from_hf(SHARE)
     assert cfg.n_heads * cfg.head_dim == 2 * cfg.d_model
     params = _params(cfg)
-    sd = hf_keye.state_dict_from_params(params, cfg)
+    sd = round_trip(hf_keye, params, cfg)
     assert sd["model.layers.1.self_attn.q_proj.weight"].shape == (64, 32)
     assert sd["model.layers.1.self_attn.k_proj.weight"].shape == (32, 32)
     assert sd["model.layers.1.self_attn.o_proj.weight"].shape == (32, 64)
@@ -188,10 +157,7 @@ def test_head_dim_of_its_own_round_trips_through_the_loader():
     assert "model.layers.0.mlp.experts.2.up_proj.weight" in sd
     assert "model.layers.0.mlp.experts.0.up_proj.weight" not in sd
     back = hf_keye.params_from_state_dict(sd, cfg, xp=jnp)
-    assert jax.tree.structure(back) == jax.tree.structure(params)
-    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    tokens, targets = _data(SHARE, 4)
+    tokens, targets = seeded_tokens(SHARE, 4)
     assert float(tfm.loss_fn(back, tokens, targets, cfg)) == float(
         tfm.loss_fn(params, tokens, targets, cfg))
 
@@ -204,27 +170,31 @@ def test_system_matches_reference_hidden_loss_kept_sets_and_gradients(
     hf = CONFIGS[which]
     cfg = hf_keye.config_from_hf(hf)
     params = _params(cfg)
-    tokens, targets = _data(hf, 1, T=T)
+    tokens, targets = seeded_tokens(hf, 1, T=T)
     sd = hf_keye.state_dict_from_params(params, cfg)
     want_loss, want = reference.loss_terms(sd, tokens, targets, hf)
     # the kept sets: equal, float32 on both sides; and they bite
-    kept = _kept_sets(params, tokens, cfg)
+    kept = []
+    for by_query, by_key in jitted(_kept_sets, cfg)(params, tokens):
+        np.testing.assert_array_equal(np.asarray(by_key),
+                                      np.asarray(by_query).swapaxes(1, 2))
+        kept.append(by_query)
     for ours, theirs in zip(kept, want["kept"]):
         np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
         assert int(ours[0].sum()) == sum(min(t + 1, 8) for t in range(T))
-    stats = tfm.dsa_stats(params, tokens, cfg)
+    stats = jitted(tfm.dsa_stats, cfg)(params, tokens)
     np.testing.assert_allclose(np.asarray(stats["loss"]),
                                np.asarray(want["index_loss"]), rtol=2e-5)
     assert float(stats["loss"].min()) > 1e-3
-    loss = tfm.loss_fn(params, tokens, targets, cfg)
+    loss = jitted(tfm.loss_fn, cfg)(params, tokens, targets)
     assert abs(float(loss) - float(want_loss)) < 3e-6
-    hidden, aux = tfm.forward_hidden(params, tokens, cfg)
-    assert _rel(hidden, want["hidden"][-1]) < 2e-6
+    hidden, aux = jitted(tfm.forward_hidden, cfg)(params, tokens)
+    assert rel(hidden, want["hidden"][-1]) < 2e-6
     np.testing.assert_allclose(
         np.asarray(aux), [float(want[k].sum()) for k in (
             "balance", "z", "index_loss")], rtol=2e-5)
     # the reference handed the system's sets and picks says the same
-    routed = tfm.moe_routing_stats(params, tokens, cfg, terms=True)
+    routed = jitted(_routing_terms, cfg)(params, tokens)
     picks = routed["experts"]
     given_loss, given = reference.loss_terms(sd, tokens, targets, hf,
                                              kept=kept, picks=list(picks))
@@ -238,17 +208,17 @@ def test_system_matches_reference_hidden_loss_kept_sets_and_gradients(
         np.sort(np.argsort(-logits, -1)[..., :picks.shape[-1]], -1))
     # every gradient leaf
     grads = hf_keye.state_dict_from_params(
-        jax.grad(tfm.loss_fn)(params, tokens, targets, cfg), cfg)
+        jitted(grads_of_loss, cfg)(params, tokens, targets), cfg)
     _, want_grads = reference.grads_of(list(sd))(sd, tokens, targets, hf)
     for n in sd:
-        assert _rel(grads[n], want_grads[n]) < 3e-5, n
+        assert rel(grads[n], want_grads[n]) < 3e-5, n
     # the lean gradient is jax.grad of the plain forward
     few = [INDEXER[2], INDEXER[0], "model.layers.1.mlp.gate.weight",
            "model.layers.1.self_attn.k_proj.weight"]
     plain = jax.grad(lambda part: reference.loss_terms(
         {**sd, **part}, tokens, targets, hf)[0])({n: sd[n] for n in few})
     for n in few:
-        assert _rel(want_grads[n], plain[n]) < 1e-5, n
+        assert rel(want_grads[n], plain[n]) < 1e-5, n
 
 
 def test_indexer_learns_from_its_loss_alone_and_the_trunk_not_from_it():
@@ -256,14 +226,14 @@ def test_indexer_learns_from_its_loss_alone_and_the_trunk_not_from_it():
     leaves, and the gradient of L_I alone is zero on every other leaf."""
     cfg = hf_keye.config_from_hf(HF)
     params = _params(cfg)
-    tokens, targets = _data(HF, 2)
+    tokens, targets = seeded_tokens(HF, 2)
 
     def parts(params):
         hidden, aux = tfm.forward_hidden(params, tokens, cfg)
         return tfm.loss_fn(params, tokens, targets, cfg) - aux[2], aux[2]
 
-    rest = jax.grad(lambda p: parts(p)[0])(params)
-    index = jax.grad(lambda p: parts(p)[1])(params)
+    rest = jax.jit(jax.grad(lambda p: parts(p)[0]))(params)
+    index = jax.jit(jax.grad(lambda p: parts(p)[1]))(params)
     for path, g in jax.tree_util.tree_leaves_with_path(index):
         name = path[-1].key
         if name in tfm.DSA_LEAVES:
@@ -275,7 +245,7 @@ def test_indexer_learns_from_its_loss_alone_and_the_trunk_not_from_it():
     for name in ("wqkv", "wo", "router", "w1"):
         assert float(jnp.abs(rest["blocks"][name]).max()) > 1e-6
     # the whole gradient is the sum of the two
-    whole = jax.grad(tfm.loss_fn)(params, tokens, targets, cfg)
+    whole = jitted(grads_of_loss, cfg)(params, tokens, targets)
     for w, r, i in zip(*(jax.tree.leaves(t) for t in (whole, rest, index))):
         np.testing.assert_allclose(np.asarray(w), np.asarray(r + i),
                                    atol=1e-7)
@@ -288,7 +258,7 @@ def _rematted_ops(jaxpr, scope):
     for e in jaxpr.eqns:
         stack = str(e.source_info.name_stack).split("/")
         n += "rematted_computation" in stack and scope in stack
-        n += sum(_rematted_ops(sub, scope) for sub in _sub_jaxprs(e))
+        n += sum(_rematted_ops(sub, scope) for sub in sub_jaxprs(e))
     return n
 
 
@@ -310,7 +280,7 @@ def test_indexer_loss_and_every_gradient_under_remat(monkeypatch, remat,
     projections and index scores it still runs: the flash call's mask)."""
     cfg = hf_keye.config_from_hf(HF)
     params = _params(cfg)
-    tokens, targets = _data(HF, 5)
+    tokens, targets = seeded_tokens(HF, 5)
 
     def value(params, cfg):
         _, aux = tfm.forward_hidden(params, tokens, cfg)
@@ -318,15 +288,17 @@ def test_indexer_loss_and_every_gradient_under_remat(monkeypatch, remat,
 
     with monkeypatch.context() as m:
         m.setattr(dsa, "indexer_loss", dsa.indexer_loss.fun)
-        (_, want_index), want = jax.value_and_grad(value, has_aux=True)(
-            params, cfg)
+        # a jit of its own: traced here, under the patch
+        (_, want_index), want = jax.jit(jax.value_and_grad(
+            value, has_aux=True), static_argnums=1)(params, cfg)
     monkeypatch.setattr(tfm, "_device_bytes_limit", lambda: limit)
     run = dataclasses.replace(cfg, remat=remat)
     names, held, budget = tfm._remat_names(
         run, params, tfm.embed_tokens(params, tokens, cfg), None)
     assert names == ((tracing.REMAT_DSA_GRADS,) if limit else ())
     assert not limit or budget < 0 < held
-    (_, index), got = jax.value_and_grad(value, has_aux=True)(params, run)
+    (_, index), got = jax.jit(jax.value_and_grad(
+        value, has_aux=True), static_argnums=1)(params, run)
     np.testing.assert_allclose(float(index), float(want_index), rtol=1e-6)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
                             jax.tree.leaves(want)):
@@ -353,7 +325,7 @@ def test_top_k_of_all_keys_is_the_dense_attention_kind_bit_for_bit():
         dsa=tfm.DSAConfig(n_heads=4, head_dim=8, top_k=32))
     dense = dataclasses.replace(cfg, layer_types=(), dsa=None)
     params = _params(cfg)
-    tokens, _ = _data(HF, 3)
+    tokens, _ = seeded_tokens(HF, 3)
     p = jax.tree.map(lambda x: x[0], params["blocks"])
     h = tfm._norm(tfm.embed_tokens(params, tokens, cfg), p["ln1_scale"],
                   p["ln1_bias"], cfg)
@@ -382,7 +354,7 @@ def test_flash_path_is_the_dot_path(monkeypatch):
         hf_keye.config_from_hf(SHARE),
         dsa=tfm.DSAConfig(n_heads=4, head_dim=8, top_k=32))
     params = _params(cfg)
-    tokens, targets = _data(SHARE, 2, T=256)
+    tokens, targets = seeded_tokens(SHARE, 2, T=256)
     assert dsa.row_block(256) == 128
     flash = dataclasses.replace(cfg, attn_impl="flash")
     a, ga = jax.jit(jax.value_and_grad(
@@ -481,7 +453,7 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_whole():
 
 
 # -- the defaults stay what they were (the other cells' lowered steps:
-# test_lfm2_model.py's one table of digests) -------------------------------------
+# test_cell_digests.py) -------------------------------------------------------
 
 def test_default_config_has_no_indexer_and_a_derived_head_width():
     cfg = tfm.TransformerConfig(n_layers=2)
@@ -505,7 +477,7 @@ def test_scopes_of_the_indexer_the_selection_and_its_loss_in_the_step():
     and docs/OBSERVABILITY.md names all four."""
     cfg = hf_keye.config_from_hf(SHARE)
     params = _params(cfg)
-    tokens, targets = _data(SHARE, 8)
+    tokens, targets = seeded_tokens(SHARE, 8)
     text = tfm.make_train_step(cfg).lower(
         params, tfm.init_opt_state(params), tokens,
         targets).compile().as_text()
@@ -539,11 +511,10 @@ def test_scopes_of_the_indexer_the_selection_and_its_loss_in_the_step():
 
 def test_decode_pipeline_mesh_and_a_share_on_an_ep_mesh_refuse_by_name():
     cfg = hf_keye.config_from_hf(HF)
-    with pytest.raises(AssertionError, match="indexer cache"):
-        generate._check_decode_args(cfg, 16, 0)
+    refuses(lambda: generate._check_decode_args(cfg, 16, 0),
+            "dsa=DSAConfig(")
     wide = dataclasses.replace(cfg, layer_types=(), dsa=None)
-    with pytest.raises(AssertionError, match="head width of its own"):
-        generate._check_decode_args(wide, 16, 0)
+    refuses(lambda: generate._check_decode_args(wide, 16, 0), "d_head=16")
     with pytest.raises(NotImplementedError,
                        match=r"learned sparse attention \(dsa"):
         pipeline._make_stage_fn(cfg, 1)

@@ -9,7 +9,7 @@ eight shares of an expert layer, the name map, the pair counters against
 their closed forms, the scopes, and the refusals by name. The kernels' own
 window cases are in test_flash_window.py."""
 import dataclasses
-import importlib.util
+import functools
 import json
 import math
 import os
@@ -26,21 +26,13 @@ from hetu_tpu.kernels import rope as rope_kernel
 from hetu_tpu.models import generate, hf_laguna as hl, transformer as tfm
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
-from test_kanana_model import rope_kernel_taken  # noqa: F401
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT,
-                                                                     path))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from model_harness import (ROOT, hidden_after_runs, jitted, load_reference,
+                           grads_of_loss, loss_and_grads, refuses, rel,
+                           rope_kernel_taken, round_trip,  # noqa: F401
+                           seeded_params, seeded_tokens)
 
 
-reference = _load("benchmark/configs/laguna-xs.2/reference.py",
-                  "laguna_reference")
+reference = load_reference("laguna-xs.2")
 
 FULL = {"rope_theta": 10000, "rope_type": "yarn", "factor": 8,
         "original_max_position_embeddings": 16, "beta_slow": 0.01,
@@ -79,44 +71,11 @@ SIZES_OF_A_TOY = {
     "num_key_value_heads": 2, "num_attention_heads_per_layer": [4, 6, 6, 6, 4]}
 
 
-def _data(hf, seed, B=2, T=32):
-    ids = jax.random.randint(jax.random.PRNGKey(seed), (B, T + 1), 0,
-                             hf["vocab_size"])
-    return ids[:, :-1], ids[:, 1:]
-
-
-def _params(cfg, seed=0, bias=0.05):
-    """Seeded weights, the selection bias moved off zero so that it matters
-    to the picks, the norms' scales off one, the gate's weights large enough
-    that the gate is no constant half."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    key = jax.random.PRNGKey(seed + 100)
-
-    def off(path, x):
-        if tfm._is_router_bias(path):
-            return bias * jax.random.normal(key, x.shape)
-        if path[-1].key in ("ln1_scale", "ln2_scale"):
-            return x + 0.1 * jax.random.normal(key, x.shape)
-        if path[-1].key == "wg":
-            return 10.0 * x
-        return x
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / max(np.sqrt(np.mean(want ** 2)), 1e-30))
-
-
-def _hidden_after_runs(params, tokens, cfg):
-    h, after = tfm.embed_tokens(params, tokens, cfg), []
-    for (kind, _), blocks in zip(tfm.layer_runs(cfg),
-                                 tfm.run_blocks(cfg, params["blocks"])):
-        h = tfm._through_run(h, blocks, cfg, kind)
-        after.append(h)
-    return after
+# seeded weights, the selection bias moved off zero so that it matters to the
+# picks, the norms' scales off one, the gate's weights large enough that the
+# gate is no constant half
+_params = functools.partial(
+    seeded_params, noisy=("ln1_scale", "ln2_scale"), tenfold=("wg",))
 
 
 # -- the loader ------------------------------------------------------------------
@@ -191,14 +150,14 @@ def test_config_from_hf_reads_every_key_of_the_row():
     ({"rope_parameters": {**HF["rope_parameters"], "full_attention": {
         **FULL, "rope_type": "llama3"}}}, "YaRN")])
 def test_loader_refuses_by_name(change, named):
-    with pytest.raises(NotImplementedError, match=re.escape(named)):
-        hl.config_from_hf({**HF, **change})
+    refuses(lambda: hl.config_from_hf({**HF, **change}), named,
+            NotImplementedError)
 
 
 def test_state_dict_round_trip_four_and_six_head_layers_in_one_checkpoint():
     cfg = hl.config_from_hf(SHARE)
     params = _params(cfg)
-    sd = hl.state_dict_from_params(params, cfg)
+    sd = round_trip(hl, params, cfg)
     assert sd["model.layers.0.self_attn.q_proj.weight"].shape == (4 * 16, 64)
     assert sd["model.layers.1.self_attn.q_proj.weight"].shape == (6 * 16, 64)
     assert sd["model.layers.2.self_attn.k_proj.weight"].shape == (2 * 16, 64)
@@ -211,10 +170,6 @@ def test_state_dict_round_trip_four_and_six_head_layers_in_one_checkpoint():
     assert "model.layers.1.mlp.experts.2.gate_proj.weight" in sd
     assert "model.layers.1.mlp.experts.0.gate_proj.weight" not in sd
     assert "model.layers.0.mlp.gate_proj.weight" in sd
-    back = hl.params_from_state_dict(sd, cfg, xp=jnp)
-    assert jax.tree.structure(back) == jax.tree.structure(params)
-    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # through the scope-less names a checkpoint may carry, as float32 jnp
     bare = {k.removeprefix("model."): np.asarray(v) for k, v in sd.items()}
     again = hl.params_from_hf(bare, cfg)
@@ -224,21 +179,33 @@ def test_state_dict_round_trip_four_and_six_head_layers_in_one_checkpoint():
 
 # -- the system against the reference ---------------------------------------------
 
+@functools.lru_cache(maxsize=None)
+def _both_sides(which):
+    """-> (params, tokens, targets, state dict, the reference's loss and
+    terms) of CONFIGS[which] at seed 1: the reference side of the comparison,
+    run once for the cases that share it."""
+    hf = CONFIGS[which]
+    cfg = hl.config_from_hf(hf)
+    params = _params(cfg)
+    tokens, targets = seeded_tokens(hf, 1)
+    sd = hl.state_dict_from_params(params, cfg)
+    return (params, tokens, targets, sd,
+            *reference.loss_terms(sd, tokens, targets, hf))
+
+
 @pytest.mark.parametrize("which", sorted(CONFIGS))
 def test_system_matches_reference_hidden_loss_picks_and_gradients(which):
     hf = CONFIGS[which]
     cfg = hl.config_from_hf(hf, router_bias_rate=1e-3)
-    params = _params(cfg)
-    tokens, targets = _data(hf, 1)
-    sd = hl.state_dict_from_params(params, cfg)
-    want_loss, want = reference.loss_terms(sd, tokens, targets, hf)
-    loss = tfm.loss_fn(params, tokens, targets, cfg)
+    params, tokens, targets, sd, want_loss, want = _both_sides(which)
+    loss = jitted(tfm.loss_fn, cfg)(params, tokens, targets)
     assert abs(float(loss) - float(want_loss)) < 2e-6
     # after each run of layers: the dense full layer, the window run, the
     # full expert layer
-    for got, last in zip(_hidden_after_runs(params, tokens, cfg), (0, 2, 3)):
-        assert _rel(got, want["hidden"][last]) < 2e-6, last
-    stats = tfm.moe_routing_stats(params, tokens, cfg)
+    for got, last in zip(jitted(hidden_after_runs, cfg)(params, tokens),
+                         (0, 2, 3)):
+        assert rel(got, want["hidden"][last]) < 2e-6, last
+    stats = jitted(tfm.moe_routing_stats, cfg)(params, tokens)
     np.testing.assert_array_equal(
         np.sort(np.asarray(stats["experts"]), -1),
         np.sort(np.asarray(want["experts"]), -1))
@@ -252,11 +219,11 @@ def test_system_matches_reference_hidden_loss_picks_and_gradients(which):
     np.testing.assert_array_equal(np.asarray(same["experts"]),
                                   np.asarray(stats["experts"]))
     grads = hl.state_dict_from_params(
-        jax.grad(tfm.loss_fn)(params, tokens, targets, cfg), cfg)
+        jitted(grads_of_loss, cfg)(params, tokens, targets), cfg)
     names = [n for n in sd if "e_score" not in n]
     _, want_grads = reference.grads_of(names)(sd, tokens, targets, hf)
     for n in names:
-        assert _rel(grads[n], want_grads[n]) < 2e-5, n
+        assert rel(grads[n], want_grads[n]) < 2e-5, n
     assert float(jnp.max(jnp.abs(
         want_grads["model.layers.1.self_attn.g_proj.weight"]))) > 1e-6
     # the lean gradient is jax.grad of the plain forward
@@ -266,7 +233,7 @@ def test_system_matches_reference_hidden_loss_picks_and_gradients(which):
     plain = jax.grad(lambda part: reference.loss_terms(
         {**sd, **part}, tokens, targets, hf)[0])({n: sd[n] for n in few})
     for n in few:
-        assert _rel(want_grads[n], plain[n]) < 1e-5, n
+        assert rel(want_grads[n], plain[n]) < 1e-5, n
 
 
 @pytest.mark.parametrize("wrong,first", [
@@ -278,10 +245,8 @@ def test_the_comparison_sees_each_mechanism(wrong, first):
     run of layers that has it on (`first`: layer 0 is a full layer, the
     window run is the second)."""
     cfg = hl.config_from_hf(HF)
-    params = _params(cfg)
-    tokens, targets = _data(HF, 1)
-    sd = hl.state_dict_from_params(params, cfg)
-    want = reference.loss_terms(sd, tokens, targets, HF)[1]["hidden"]
+    params, tokens, _, _, _, terms = _both_sides("whole")
+    want = terms["hidden"]
     y = cfg.rope_yarn
     off = {"window off by one": dict(window=dataclasses.replace(
                cfg.window, window=9)),
@@ -290,9 +255,9 @@ def test_the_comparison_sees_each_mechanism(wrong, first):
                y, attention_factor=1.0)),
            "rotary on every column": dict(rope_dim=0),
            "no gate": dict(attn_gate=False)}[wrong]
-    got = _hidden_after_runs(params, tokens,
-                             dataclasses.replace(cfg, **off))
-    errs = [_rel(g, want[last]) for g, last in zip(got, (0, 2, 3))]
+    got = jitted(hidden_after_runs, dataclasses.replace(cfg, **off))(
+        params, tokens)
+    errs = [rel(g, want[last]) for g, last in zip(got, (0, 2, 3))]
     assert all(e < 2e-6 for e in errs[:first]) and errs[first] > 1e-4, errs
 
 
@@ -302,10 +267,10 @@ def test_flash_path_is_the_dot_path():
     loss and gradients."""
     cfg = hl.config_from_hf(SHARE)
     params = _params(cfg)
-    tokens, targets = _data(SHARE, 2)
+    tokens, targets = seeded_tokens(SHARE, 2)
     flash = dataclasses.replace(cfg, attn_impl="flash")
-    a, ga = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, cfg)
-    b, gb = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, flash)
+    a, ga = jitted(loss_and_grads, cfg)(params, tokens, targets)
+    b, gb = jitted(loss_and_grads, flash)(params, tokens, targets)
     assert abs(float(a) - float(b)) < 1e-6
     for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-6)
@@ -320,7 +285,7 @@ def test_the_rotation_kernel_in_the_trunk_is_rope(rope_kernel_taken,
     hf = {**SHARE, "head_dim": 128}
     cfg = dataclasses.replace(hl.config_from_hf(hf), remat=True)
     params = _params(cfg)
-    tokens, targets = _data(hf, 2)
+    tokens, targets = seeded_tokens(hf, 2)
     b, gb = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, cfg)
     widths = {(2, 32, (h + 4) * 128) for h in (4, 6)}   # [q | k | v]
     assert len(rope_kernel_taken) >= 2 * 2 * cfg.n_layers
@@ -337,7 +302,7 @@ def test_the_rotation_kernel_in_the_trunk_is_rope(rope_kernel_taken,
 def test_bias_moves_by_the_sign_rule_and_adamw_leaves_it():
     cfg = hl.config_from_hf(HF, router_bias_rate=1e-2)
     params = _params(cfg, bias=0.0)
-    tokens, targets = _data(HF, 3)
+    tokens, targets = seeded_tokens(HF, 3)
     opt = tfm.init_opt_state(params)
     want = reference.loss_terms(hl.state_dict_from_params(params, cfg),
                                 tokens, targets, HF)[1]["counts"]
@@ -367,7 +332,7 @@ def test_a_window_of_the_whole_sequence_is_the_attention_kind_to_the_bit(
     params = _params(full)
     assert jax.tree.structure(params) == jax.tree.structure(
         tfm.init_params(jax.random.PRNGKey(0), windowed))
-    tokens, targets = _data({"vocab_size": 128}, 5)
+    tokens, targets = seeded_tokens({"vocab_size": 128}, 5)
     a, ga = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets, full)
     b, gb = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets,
                                             windowed)
@@ -560,7 +525,7 @@ def test_measured_visits_are_the_plans_and_see_a_kernel_without_its_bound(
         {**c, **SIZES_OF_A_TOY, "sliding_window": W}, dtype=jnp.bfloat16)
     flash = dataclasses.replace(cfg, attn_impl="flash")
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    tokens = _data({"vocab_size": cfg.vocab_size}, 3, B=1, T=T)[0]
+    tokens = seeded_tokens({"vocab_size": cfg.vocab_size}, 3, B=1, T=T)[0]
     visits = lambda cfg, mixer: int(np.asarray(tfm.attention_visits(
         params, tokens, cfg, mixer, chunk)).sum()) * chunk
     plan = tfm.attention_pairs(flash, T)
@@ -586,7 +551,7 @@ def test_scopes_of_the_window_the_rotation_and_the_gate_in_the_step(
     cfg = dataclasses.replace(hl.config_from_hf(SHARE, router_bias_rate=1e-3),
                               attn_impl="flash")
     params = _params(cfg)
-    tokens, targets = _data(SHARE, 8)
+    tokens, targets = seeded_tokens(SHARE, 8)
     text = tfm.make_train_step(cfg).lower(
         params, tfm.init_opt_state(params), tokens,
         targets).compile().as_text()
@@ -635,14 +600,14 @@ def test_small_tables_keep_the_rotation_in_the_projection_scope(monkeypatch):
         d_ff=96, max_seq_len=64, norm="rmsnorm", rope=True, mlp="swiglu",
         use_pos_emb=False, dtype=jnp.float32)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    tokens, targets = _data({"vocab_size": 128}, 8)
+    tokens, targets = seeded_tokens({"vocab_size": 128}, 8)
     lowered = lambda cfg, params, data: tfm.make_train_step(cfg).lower(
         params, tfm.init_opt_state(params), *data).as_text(debug_info=True)
     toy = hl.config_from_hf(SHARE)
     assert tracing.SCOPE_ATTN_ROPE not in lowered(cfg, params,
                                                   (tokens, targets))
     assert tracing.SCOPE_ATTN_ROPE not in lowered(toy, _params(toy),
-                                                  _data(SHARE, 8))
+                                                  seeded_tokens(SHARE, 8))
     whole = tfm.forward_hidden(params, tokens, cfg)[0]
     monkeypatch.setattr(tfm, "ROPE_TABLE_BYTES", 0)
     assert tracing.SCOPE_ATTN_ROPE in lowered(cfg, params, (tokens, targets))
@@ -661,11 +626,10 @@ def test_small_tables_keep_the_rotation_in_the_projection_scope(monkeypatch):
 
 def test_decode_and_pipeline_refuse_by_name():
     cfg = hl.config_from_hf(HF)
-    with pytest.raises(AssertionError, match="no window eviction"):
-        generate._check_decode_args(cfg, 16, 0)
-    gated = tfm.TransformerConfig(attn_gate=True)
-    with pytest.raises(AssertionError, match="attn_gate=True"):
-        generate._check_decode_args(gated, 16, 0)
+    refuses(lambda: generate._check_decode_args(cfg, 16, 0),
+            "window=WindowConfig(window=8")
+    refuses(lambda: generate._check_decode_args(
+        tfm.TransformerConfig(attn_gate=True), 16, 0), "attn_gate=True")
     with pytest.raises(NotImplementedError, match="unequal kinds"):
         pipeline._make_stage_fn(cfg, 1)
     one_kind = dataclasses.replace(

@@ -2,15 +2,14 @@
 with the real structure (1 dense layer + a period of 4: conv + dense,
 attention + experts, 3 x conv + experts; 8 experts, top 2, two query heads a
 key-value head): the system against the float32 reference
-(benchmark/configs/lfm2-8b-a1b/reference.py), the reference's mixer, attention
-and dense MLP against `transformers`' `lfm2` modules on copied weights, the
-shares of an expert layer adding up to the whole, the selection bias's rule,
-a skewed router, the four other flagship cells' trees and lowered programs,
-and the refusals by name. A share's row loops (ISSUE 38) are in
-test_lfm2_share_rows.py."""
+(benchmark/configs/lfm2-8b-a1b/reference.py), the shares of an expert layer
+adding up to the whole, the selection bias's rule, a skewed router, and the
+refusals by name. A share's row loops (ISSUE 38) are in
+test_lfm2_share_rows.py, the reference's mixer, attention and dense MLP
+against `transformers`' `lfm2` modules in
+test_references_against_transformers.py, and the other flagship cells' trees
+and lowered programs in test_cell_digests.py."""
 import dataclasses
-import hashlib
-import importlib.util
 import json
 import os
 import re
@@ -20,25 +19,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu.models import (bert, generate, hf_deepseek_v3, hf_granite,
-                             hf_keye, hf_lfm2, hf_olmoe, hf_ouro,
-                             transformer as tfm)
+from hetu_tpu.models import generate, hf_lfm2, transformer as tfm
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
+from model_harness import (ROOT, grads_of_loss, jitted, load_reference,
+                           loss_and_grads, refuses, rel, round_trip,
+                           seeded_params, seeded_tokens)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT,
-                                                                     path))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-reference = _load("benchmark/configs/lfm2-8b-a1b/reference.py",
-                  "lfm2_reference")
+reference = load_reference("lfm2-8b-a1b")
 
 # layers 1-5 of the published order, every expert held
 HF = dict(
@@ -55,28 +43,6 @@ HF = dict(
 SHARE = {**HF, "num_experts": 2, "num_routed_experts": 8,
          "first_expert_held": 2}
 CONFIGS = {"whole": HF, "share": SHARE}
-
-
-def _data(hf, seed, B=2, T=32):
-    ids = jax.random.randint(jax.random.PRNGKey(seed), (B, T + 1), 0,
-                             hf["vocab_size"])
-    return ids[:, :-1], ids[:, 1:]
-
-
-def _params(cfg, seed=0, bias=0.05):
-    """Seeded weights, the selection bias moved off zero so that it matters
-    to the picks."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    key = jax.random.PRNGKey(seed + 100)
-    return jax.tree_util.tree_map_with_path(
-        lambda path, x: bias * jax.random.normal(key, x.shape)
-        if tfm._is_router_bias(path) else x, params)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / max(np.sqrt(np.mean(want ** 2)), 1e-30))
 
 
 # -- the loader ------------------------------------------------------------------
@@ -118,19 +84,14 @@ def test_config_from_hf_reads_every_key_of_the_row():
 
 def test_state_dict_round_trip():
     cfg = hf_lfm2.config_from_hf(SHARE)
-    params = _params(cfg)
-    sd = hf_lfm2.state_dict_from_params(params, cfg)
+    sd = round_trip(hf_lfm2, seeded_params(cfg), cfg,
+                    back=hf_lfm2.params_from_hf)
     # the held experts under the MODEL's indices
     assert "model.layers.1.feed_forward.experts.2.w1.weight" in sd
     assert "model.layers.1.feed_forward.experts.0.w1.weight" not in sd
     assert sd["model.layers.1.feed_forward.expert_bias"].shape == (8,)
     assert sd["model.layers.0.feed_forward.w1.weight"].shape == (128, 64)
     assert sd["model.layers.0.conv.conv.weight"].shape == (64, 1, 3)
-    back = hf_lfm2.params_from_hf(sd, cfg)
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(params),
-                            jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=jax.tree_util.keystr(path))
 
 
 # -- the system against the reference -------------------------------------------
@@ -141,18 +102,17 @@ def test_system_equals_reference(which):
     gradient leaf, float32 on both sides."""
     hf = CONFIGS[which]
     cfg = hf_lfm2.config_from_hf(hf)
-    params = _params(cfg)
-    tokens, targets = _data(hf, 1)
+    params = seeded_params(cfg)
+    tokens, targets = seeded_tokens(hf, 1)
     sd = hf_lfm2.state_dict_from_params(params, cfg)
     want_loss, want = reference.loss_terms(sd, tokens, targets, hf)
 
-    loss, grads = jax.value_and_grad(tfm.loss_fn)(params, tokens, targets,
-                                                  cfg)
+    loss, grads = jitted(loss_and_grads, cfg)(params, tokens, targets)
     assert abs(float(loss) - float(want_loss)) < 2e-5
-    hidden, aux = tfm.forward_hidden(params, tokens, cfg)
-    assert _rel(hidden, want["hidden"][-1]) < 2e-5
+    hidden, aux = jitted(tfm.forward_hidden, cfg)(params, tokens)
+    assert rel(hidden, want["hidden"][-1]) < 2e-5
     np.testing.assert_array_equal(np.asarray(aux), 0.0)   # no auxiliary loss
-    stats = tfm.moe_routing_stats(params, tokens, cfg)
+    stats = jitted(tfm.moe_routing_stats, cfg)(params, tokens)
     np.testing.assert_array_equal(np.asarray(stats["experts"]),
                                   np.asarray(want["experts"]))
     np.testing.assert_array_equal(np.asarray(stats["picks"]),
@@ -188,8 +148,8 @@ def test_system_equals_reference(which):
 def test_reference_grads_of_is_jax_grad_of_its_plain_loss():
     hf = SHARE
     cfg = hf_lfm2.config_from_hf(hf)
-    tokens, targets = _data(hf, 2, B=1, T=16)
-    sd = hf_lfm2.state_dict_from_params(_params(cfg), cfg)
+    tokens, targets = seeded_tokens(hf, 2, B=1, T=16)
+    sd = hf_lfm2.state_dict_from_params(seeded_params(cfg), cfg)
     names = ["model.layers.1.feed_forward.gate.weight",
              "model.layers.0.conv.conv.weight",
              "model.layers.1.self_attn.q_layernorm.weight",
@@ -208,9 +168,9 @@ def test_bias_enters_the_selection_only():
     those of the SCORES: on the tokens whose picks did not change, the layer's
     output is what it was."""
     cfg = hf_lfm2.config_from_hf(HF)
-    tokens, _ = _data(HF, 3)
-    base = _params(cfg, bias=0.0)
-    moved = _params(cfg, bias=0.2)
+    tokens, _ = seeded_tokens(HF, 3)
+    base = seeded_params(cfg, bias=0.0)
+    moved = seeded_params(cfg, bias=0.2)
     run = lambda p: (tfm.router_terms(p, tokens, cfg))
     a, b = run(base), run(moved)
     np.testing.assert_array_equal(np.asarray(a["scores"]),
@@ -227,88 +187,6 @@ def test_bias_enters_the_selection_only():
             rtol=1e-6)
 
 
-# -- the reference against transformers' lfm2 modules --------------------------
-
-@pytest.fixture(scope="module")
-def hf_modules():
-    torch = pytest.importorskip("torch")
-    lfm2 = pytest.importorskip("transformers.models.lfm2.modeling_lfm2")
-    from transformers import Lfm2Config
-    config = Lfm2Config(
-        vocab_size=256, hidden_size=64, intermediate_size=128,
-        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
-        max_position_embeddings=64, norm_eps=1e-5, rope_theta=1000000.0,
-        conv_bias=False, conv_L_cache=3, block_auto_adjust_ff_dim=False,
-        layer_types=["conv", "full_attention"])
-    config._attn_implementation = "eager"
-    torch.manual_seed(0)
-    return torch, lfm2, config
-
-
-def _np_state(module):
-    return {k: v.detach().numpy() for k, v in module.state_dict().items()}
-
-
-def test_conv_mixer_is_transformers_slow_forward(hf_modules):
-    torch, lfm2, config = hf_modules
-    conv = lfm2.Lfm2ShortConv(config, 0).eval()
-    u = torch.randn(2, 16, 64)
-    with torch.no_grad():
-        want = conv.slow_forward(u).numpy()
-    w = {"conv." + k: jnp.asarray(v) for k, v in _np_state(conv).items()}
-    got = reference._conv_math(jnp.asarray(u.numpy()), w, HF)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-6)
-    # the system's mixer on the same weights
-    cfg = hf_lfm2.config_from_hf(HF)
-    p = {"w_in": w["conv.in_proj.weight"].T,
-         "conv_w": w["conv.conv.weight"][:, 0, :].T,
-         "w_out": w["conv.out_proj.weight"].T}
-    mine = tfm._short_conv(jnp.asarray(u.numpy()), p, cfg, None)
-    np.testing.assert_allclose(np.asarray(mine), want, rtol=2e-4, atol=2e-6)
-
-
-def test_attention_layer_is_transformers_lfm2_attention(hf_modules):
-    torch, lfm2, config = hf_modules
-    attn = lfm2.Lfm2Attention(config, 1).eval()
-    with torch.no_grad():      # norm scales off 1, so that they matter
-        attn.q_layernorm.weight.uniform_(0.5, 1.5)
-        attn.k_layernorm.weight.uniform_(0.5, 1.5)
-    T = 16
-    u = torch.randn(2, T, 64)
-    rope = lfm2.Lfm2RotaryEmbedding(config)
-    cos_sin = rope(u, torch.arange(T)[None])
-    mask = torch.full((T, T), float("-inf")).triu(1)[None, None]
-    with torch.no_grad():
-        want = attn(u, cos_sin, mask)[0].numpy()
-    w = {"self_attn." + k: jnp.asarray(v) for k, v in _np_state(attn).items()}
-    got = reference._attention_math(jnp.asarray(u.numpy()), w, HF)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-6)
-    # the system's attention (per-head QK-norm, RoPE, GQA) on the same
-    cfg = hf_lfm2.config_from_hf(HF)
-    p = {"wqkv": jnp.concatenate(
-        [w[f"self_attn.{n}_proj.weight"].T for n in "qkv"], 1),
-        "wo": w["self_attn.out_proj.weight"].T,
-        "q_norm": w["self_attn.q_layernorm.weight"],
-        "k_norm": w["self_attn.k_layernorm.weight"]}
-    mine = tfm._attention(jnp.asarray(u.numpy()), p, cfg, None)
-    np.testing.assert_allclose(np.asarray(mine), want, rtol=2e-4, atol=2e-6)
-
-
-def test_dense_mlp_and_layer_are_transformers(hf_modules):
-    torch, lfm2, config = hf_modules
-    mlp = lfm2.Lfm2MLP(config).eval()
-    assert mlp.w1.weight.shape == (128, 64)     # the width taken as it is
-    layer = lfm2.Lfm2DecoderLayer(config, 0).eval()     # conv + dense
-    T = 16
-    u = torch.randn(2, T, 64)
-    rope = lfm2.Lfm2RotaryEmbedding(config)
-    with torch.no_grad():
-        want = layer(u, rope(u, torch.arange(T)[None])).numpy()
-    w = {k: jnp.asarray(v) for k, v in _np_state(layer).items()}
-    got = reference._layer_math(jnp.asarray(u.numpy()), w, HF, ("conv", None))
-    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-6)
-
-
 # -- the shares add up -------------------------------------------------------------
 
 def test_the_four_shares_of_an_expert_layer_add_up_to_the_whole():
@@ -319,7 +197,7 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_whole():
     shared expert), so nothing is counted once."""
     whole_cfg = hf_lfm2.config_from_hf(HF)
     p = jax.tree.map(lambda x: x[0], tfm.run_blocks(
-        whole_cfg, _params(whole_cfg)["blocks"])[1])
+        whole_cfg, seeded_params(whole_cfg)["blocks"])[1])
     m = jax.random.normal(jax.random.PRNGKey(7), (2, 32, 64))
     # the uncut reference on HF names
     w = {"feed_forward.gate.weight": p["router"].T,
@@ -346,7 +224,7 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_whole():
                     m.reshape(-1, 64), w, {**c, "num_experts": 2},
                     2 * chip)[0]),
                 rtol=1e-4, atol=1e-7)
-            assert _rel(parts[-1], want) > 0.3       # a part, not the whole
+            assert rel(parts[-1], want) > 0.3       # a part, not the whole
     np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(want),
                                rtol=1e-4, atol=1e-7)
     whole, _ = tfm._moe_mlp(m, p, whole_cfg, None)
@@ -358,33 +236,35 @@ def test_a_skewed_router_computes_every_held_pick():
     """Every token picks the two held experts: all S*k rows lie in the
     groups, none is dropped, and the layer's output is the reference's."""
     cfg = hf_lfm2.config_from_hf(SHARE)
-    params = _params(cfg, bias=0.0)
+    params = seeded_params(cfg, bias=0.0)
     skew = jnp.zeros((8,)).at[2:4].set(5.0)
     params = jax.tree_util.tree_map_with_path(
         lambda path, x: x + skew if tfm._is_router_bias(path) else x, params)
-    tokens, targets = _data(SHARE, 4)
-    stats = tfm.moe_routing_stats(params, tokens, cfg)
+    tokens, targets = seeded_tokens(SHARE, 4)
+    routing = jitted(tfm.moe_routing_stats, cfg)
+    hidden_of = jitted(tfm.forward_hidden, cfg)
+    stats = routing(params, tokens)
     S_k = 2 * 32 * 2
     assert np.asarray(stats["held"]).tolist() == [S_k] * 4
     assert np.asarray(stats["dropped"]).tolist() == [0] * 4
     assert np.asarray(stats["max_over_mean"]).tolist() == [4.0] * 4
     sd = hf_lfm2.state_dict_from_params(params, cfg)
     want_loss, want = reference.loss_terms(sd, tokens, targets, SHARE)
-    hidden, _ = tfm.forward_hidden(params, tokens, cfg)
-    assert _rel(hidden, want["hidden"][-1]) < 2e-5
-    assert abs(float(tfm.loss_fn(params, tokens, targets, cfg))
+    hidden, _ = hidden_of(params, tokens)
+    assert rel(hidden, want["hidden"][-1]) < 2e-5
+    assert abs(float(jitted(tfm.loss_fn, cfg)(params, tokens, targets))
                - float(want_loss)) < 2e-5
     # and the other way: no pick lands here, the expert block adds nothing
     away = jax.tree_util.tree_map_with_path(
         lambda path, x: x - 2 * skew if tfm._is_router_bias(path) else x,
         params)
-    stats = tfm.moe_routing_stats(away, tokens, cfg)
+    stats = routing(away, tokens)
     assert np.asarray(stats["held"]).tolist() == [0] * 4
     sd = hf_lfm2.state_dict_from_params(away, cfg)
     _, want = reference.loss_terms(sd, tokens, targets, SHARE)
-    hidden, _ = tfm.forward_hidden(away, tokens, cfg)
-    assert _rel(hidden, want["hidden"][-1]) < 2e-5
-    g = jax.grad(tfm.loss_fn)(away, tokens, targets, cfg)
+    hidden, _ = hidden_of(away, tokens)
+    assert rel(hidden, want["hidden"][-1]) < 2e-5
+    g = jitted(grads_of_loss, cfg)(away, tokens, targets)
     assert all(bool(jnp.all(jnp.isfinite(x))) for x in jax.tree.leaves(g))
     assert float(jnp.abs(g["blocks"][2]["w1"]).max()) == 0.0
 
@@ -394,11 +274,11 @@ def test_a_skewed_router_computes_every_held_pick():
 def test_the_bias_follows_its_rule_and_no_other_leaf_moves_otherwise():
     cfg0 = hf_lfm2.config_from_hf(SHARE)                     # rate 0
     cfg = hf_lfm2.config_from_hf(SHARE, router_bias_rate=1e-2)
-    tokens, targets = _data(SHARE, 5)
-    fresh = lambda: (lambda p: (p, tfm.init_opt_state(p)))(_params(cfg))
-    before = _params(cfg)
-    counts = np.asarray(tfm.moe_routing_stats(before, tokens, cfg)["picks"],
-                        np.float64)
+    tokens, targets = seeded_tokens(SHARE, 5)
+    fresh = lambda: (lambda p: (p, tfm.init_opt_state(p)))(seeded_params(cfg))
+    before = seeded_params(cfg)
+    counts = np.asarray(jitted(tfm.moe_routing_stats, cfg)(
+        before, tokens)["picks"], np.float64)
     loss0, still, opt0 = tfm.make_train_step(cfg0, lr=1e-3)(
         *fresh(), tokens, targets)
     loss, moved, opt = tfm.make_train_step(cfg, lr=1e-3)(
@@ -426,7 +306,7 @@ def test_the_bias_follows_its_rule_and_no_other_leaf_moves_otherwise():
                 err_msg=jax.tree_util.keystr(path))
     # and AdamW itself is what it is on a tree without such a leaf: one leaf
     # by hand
-    g = jax.grad(tfm.loss_fn)(before, tokens, targets, cfg)["lnf_scale"]
+    g = jitted(grads_of_loss, cfg)(before, tokens, targets)["lnf_scale"]
     m, v = 0.1 * g, 0.001 * g * g
     want = before["lnf_scale"] - 1e-3 * (
         (m / 0.1) / (jnp.sqrt(v / 0.001) + 1e-8) + 0.01 * before["lnf_scale"])
@@ -436,11 +316,11 @@ def test_the_bias_follows_its_rule_and_no_other_leaf_moves_otherwise():
 
 def test_the_bias_evens_a_skewed_load_over_steps():
     cfg = hf_lfm2.config_from_hf(HF, router_bias_rate=5e-3)
-    params = _params(cfg, bias=0.0)
+    params = seeded_params(cfg, bias=0.0)
     # a router whose scores spread (logits of std 1.3), so loads start uneven
     params["blocks"][1]["router"] = 20.0 * params["blocks"][1]["router"]
     opt = tfm.init_opt_state(params)
-    tokens, targets = _data(HF, 6, B=4)
+    tokens, targets = seeded_tokens(HF, 6, B=4)
     step = tfm.make_train_step(cfg, lr=0.0)    # the weights stand still
     loads = []
     for _ in range(60):
@@ -454,101 +334,7 @@ def test_the_bias_evens_a_skewed_load_over_steps():
     assert np.mean(loads[-10:]) < 1.15 < np.mean(loads[:3])
 
 
-# -- the other flagship cells stay what they were ------------------------------
-
-# (sha256[:16] of the LOWERED train step at the cell's own config and traffic
-# shapes with the counters cut off private symbols, its lines; sha256[:16] of
-# the parameter tree's shapes) of BERT and the six decoder cells: computed at
-# the PARENT of ISSUE 49 (commit d8625bc) by this same function, each in a
-# process of its own. The first four read what they read at the parent of
-# ISSUE 37, lfm2's what it read at ISSUE 39's and kanana's what it read at
-# ISSUE 44's (where `test_kanana_model.py` and `test_keye_model.py` pinned
-# them, each with a copy of this recipe): no PR since has changed what any of
-# them lowers to. The digests are of the CPU's lowering, where the `dot` path
-# stands for the kernels; the kernels' own are
-# `test_flash_compile_v5e.py::test_many_tile_kernels_lower_to_what_they_were`.
-PARENT = {
-    ("bert-base", "pretrain-seq512"):
-        (("5dd9818f8e559ca8", 2401), "0ca3cf6cdc80eded"),
-    ("olmoe-1b-7b", "pretrain-seq4096"):
-        (("c1c73dbf0bed4d29", 2575), "c2ddcd977285a1b3"),
-    ("ouro-2.6b", "pretrain-seq4096-b1"):
-        (("1e39cfb68388bbb3", 2434), "bdd3f4f2570a57aa"),
-    ("granite-4.0-h-micro", "pretrain-seq8192-b1"):
-        (("fbbf3f6e6fc2fcb1", 3519), "68b156d54bca4aa7"),
-    ("lfm2-8b-a1b", "pretrain-seq8192-ep4load"):
-        (("8c8e334e63485216", 7026), "df8cd1acd6687a54"),
-    ("kanana-2-30b-a3b", "pretrain-seq8192-ep8share"):
-        (("aabae1f6455f1620", 6215), "c0f6aef309cbdd46"),
-    ("keye-vl-2.0-30b-a3b", "pretrain-seq16384-ep8share"):
-        (("fc6934dddd62afe8", 6636), "c287774ff5bcf2b1"),
-}
-LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
-           "granite-4.0-h-micro": hf_granite, "lfm2-8b-a1b": hf_lfm2,
-           "kanana-2-30b-a3b": hf_deepseek_v3,
-           "keye-vl-2.0-30b-a3b": hf_keye}
-
-
-def _cell_digest(config, traffic):
-    with open(os.path.join(ROOT, "benchmark/configs", config,
-                           "config.json")) as f:
-        c = json.load(f)
-    with open(os.path.join(ROOT, "benchmark/traffic", traffic + ".json")) as f:
-        t = json.load(f)
-    B, T = t["sequences"], t["seq_len"]
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-    # what a fresh process lowers: jax emits a jitted helper it has traced
-    # before (`_where`, `_roll_static`) under another private name and
-    # another count of them, so the text would follow the tests that ran
-    # earlier in this worker (kanana's step read 6,223 lines for 6,215 after
-    # the rest of test_keye_model.py: the failure ISSUE 49 found)
-    jax.clear_caches()
-    if config == "bert-base":
-        cfg = bert.BertConfig.hf(
-            vocab_size=c["vocab_size"], d_model=c["hidden_size"],
-            n_heads=c["num_attention_heads"],
-            n_layers=c["num_hidden_layers"], d_ff=c["intermediate_size"],
-            max_seq_len=c["max_position_embeddings"],
-            type_vocab_size=c["type_vocab_size"], dtype=jnp.bfloat16)
-        params = jax.eval_shape(
-            lambda: bert.init_params(jax.random.PRNGKey(0), cfg))
-        opt = jax.eval_shape(bert.init_opt_state, params)
-        P = t["predictions"]
-        batch = {"input_ids": i32(B, T), "segment_ids": i32(B, T),
-                 "input_mask": f32(B, T), "mlm_positions": i32(B, P),
-                 "mlm_ids": i32(B, P), "mlm_weights": f32(B, P),
-                 "nsp_label": i32(B)}
-        text = bert.make_pretrain_step(cfg, lr=1e-4).lower(
-            params, opt, batch).as_text()
-    else:
-        # the bias's rate where the cell's file gives one (lfm2, kanana)
-        rate = c.get("assumed", {}).get("expert_bias_update_rate")
-        cfg = LOADERS[config].config_from_hf(
-            c, dtype=jnp.bfloat16,
-            **({} if rate is None else {"router_bias_rate": rate}))
-        params = jax.eval_shape(
-            lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-        opt = jax.eval_shape(tfm.init_opt_state, params)
-        text = tfm.make_train_step(cfg, lr=1e-4).lower(
-            params, opt, i32(B, T), i32(B, T)).as_text()
-    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
-    tree = str(jax.tree_util.tree_map(lambda x: (x.shape, str(x.dtype)),
-                                      params))
-    return ((hashlib.sha256(text.encode()).hexdigest()[:16],
-             text.count("\n")),
-            hashlib.sha256(tree.encode()).hexdigest()[:16])
-
-
-@pytest.mark.parametrize("cell", sorted(PARENT), ids=".".join)
-def test_other_cells_tree_and_lowered_program_are_the_parents(cell):
-    """No option an existing configuration has to set: the parameter tree
-    and the whole lowered train step (loss, gradients, AdamW, the bias's
-    rule) of BERT and of each of the six decoder cells are, to the character,
-    what the parent commit lowers. ONE recipe and one table: a PR that adds a
-    cell adds a line and records every digest at ITS parent."""
-    assert _cell_digest(*cell) == PARENT[cell]
-
+# -- the defaults -------------------------------------------------------------
 
 def test_default_router_and_kinds_leave_configs_what_they_were():
     cfg = tfm.TransformerConfig(n_layers=3)
@@ -566,8 +352,8 @@ def test_default_router_and_kinds_leave_configs_what_they_were():
 
 def test_scopes_of_the_conv_mixer_and_the_share_in_the_compiled_step():
     cfg = hf_lfm2.config_from_hf(SHARE, router_bias_rate=1e-3)
-    params = _params(cfg)
-    tokens, targets = _data(SHARE, 8)
+    params = seeded_params(cfg)
+    tokens, targets = seeded_tokens(SHARE, 8)
     text = tfm.make_train_step(cfg).lower(
         params, tfm.init_opt_state(params), tokens,
         targets).compile().as_text()
@@ -592,20 +378,19 @@ def test_scopes_of_the_conv_mixer_and_the_share_in_the_compiled_step():
 
 def test_decode_refuses_the_conv_kind_the_router_and_the_share_by_name():
     cfg = hf_lfm2.config_from_hf(SHARE)
-    with pytest.raises(AssertionError, match="MoE"):
-        generate._check_decode_args(cfg, 16, 0)
-    dense = dataclasses.replace(cfg, n_experts=0, n_dense_layers=0,
+    decode = lambda c: lambda: generate._check_decode_args(c, 16, 0)
+    refuses(decode(cfg), "n_experts=2")
+    dense = dataclasses.replace(cfg, n_experts=0, n_experts_per_tok=1,
+                                n_dense_layers=0, d_ff_expert=0,
                                 router=tfm.Router(), qk_norm=False)
-    with pytest.raises(AssertionError, match="conv"):
-        generate._check_decode_args(dense, 16, 0)
+    refuses(decode(dense), "layer_types=('conv', 'attention'")
     attention = dataclasses.replace(dense, layer_types=())
-    with pytest.raises(AssertionError, match="sigmoid"):
-        generate._check_decode_args(dataclasses.replace(
-            attention, router=tfm.Router(score="sigmoid")), 16, 0)
-    with pytest.raises(AssertionError, match="share"):
-        generate._check_decode_args(dataclasses.replace(
-            attention, router=tfm.Router(width=8)), 16, 0)
-    generate._check_decode_args(attention, 16, 0)
+    refuses(decode(dataclasses.replace(
+        attention, router=tfm.Router(score="sigmoid"))),
+        "router=Router(score='sigmoid'")
+    refuses(decode(dataclasses.replace(
+        attention, router=tfm.Router(width=8))), "width=8")
+    decode(attention)()
 
 
 def test_pipeline_refuses_runs_of_unequal_kinds_by_name():
@@ -618,7 +403,7 @@ def test_a_share_on_an_expert_parallel_mesh_is_refused_by_name():
     from hetu_tpu.parallel import mesh as meshlib
     cfg = hf_lfm2.config_from_hf(SHARE)
     p = jax.tree.map(lambda x: x[0], tfm.run_blocks(
-        cfg, _params(cfg)["blocks"])[1])
+        cfg, seeded_params(cfg)["blocks"])[1])
     mesh = meshlib.make_mesh(ep=2, devices=jax.devices()[:2])
     with pytest.raises(tfm.MoEConfigError, match="share"):
         tfm._moe_mlp(jnp.zeros((2, 8, 64)), p, cfg, mesh)

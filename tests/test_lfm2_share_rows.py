@@ -10,8 +10,9 @@ import pytest
 
 from hetu_tpu.kernels import grouped_matmul as gmm, registry
 from hetu_tpu.models import hf_lfm2, transformer as tfm
+from model_harness import seeded_params, seeded_tokens
 import test_lfm2_model
-from test_lfm2_model import HF, SHARE, _data, _params, reference
+from test_lfm2_model import HF, SHARE, reference
 
 ROWS = 16           # a chunk of the loops below: 64 tokens x 2 picks = 128 rows
 
@@ -33,7 +34,7 @@ def _layer_holding(n, seed=11):
     its second pulls them apart (one picked)."""
     cfg = hf_lfm2.config_from_hf(SHARE)
     p = jax.tree.map(lambda x: x[0], tfm.run_blocks(
-        cfg, _params(cfg, seed, bias=0.0)["blocks"])[1])
+        cfg, seeded_params(cfg, seed, bias=0.0)["blocks"])[1])
     router = 0.3 * jax.random.normal(jax.random.PRNGKey(seed), (64, 8))
     router = router.at[0].set(0.0).at[1].set(0.0)
     router = router.at[0, 2:4].set(8.0).at[1, 2].set(8.0).at[1, 3].set(-8.0)
@@ -168,8 +169,8 @@ def test_the_share_step_reads_nothing_on_the_host_and_counts_its_rows(
     and no callback, outfeed or host transfer; `rows_run` is `held` up to
     whole chunks, clipped to S*k."""
     cfg = hf_lfm2.config_from_hf(SHARE, router_bias_rate=1e-3)
-    params = _params(cfg)
-    tokens, targets = _data(SHARE, 8)
+    params = seeded_params(cfg)
+    tokens, targets = seeded_tokens(SHARE, 8)
     text = tfm.make_train_step(cfg).lower(
         params, tfm.init_opt_state(params), tokens,
         targets).compile().as_text()
@@ -190,5 +191,5 @@ def test_the_share_step_reads_nothing_on_the_host_and_counts_its_rows(
             np.minimum(-(-n // ROWS) * ROWS, 128))
     # not a share: every row, whatever the routing
     whole = hf_lfm2.config_from_hf(HF)
-    stats = tfm.moe_routing_stats(_params(whole), tokens, whole)
+    stats = tfm.moe_routing_stats(seeded_params(whole), tokens, whole)
     assert np.asarray(stats["rows_run"]).tolist() == [128] * 4

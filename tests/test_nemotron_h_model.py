@@ -6,12 +6,13 @@ ungated relu^2 experts, top 3, beside a shared one) against
 `benchmark/configs/nemotron-twotower-30b-a3b/reference.py`: the loader, the
 system against the reference (loss, hidden states after each sublayer, every
 gradient), the sixteen shares of an expert layer, the grouped norm against
-the whole-width one, the reference's mixer against `transformers`'
-`Mamba2Mixer`, relu^2 through a share's row loops, `_ssd` at the cell's
-groups against the time recurrence, the scopes, the refusals, and the other
-configurations' programs unchanged. No number here is a device number."""
+the whole-width one, relu^2 through a share's row loops, `_ssd` at the cell's
+groups against the time recurrence, the scopes and the refusals. The
+reference's mixer against `transformers`' `Mamba2Mixer` is in
+test_references_against_transformers.py, every cell's lowered program in
+test_cell_digests.py. No number here is a device number."""
 import dataclasses
-import importlib.util
+import functools
 import json
 import os
 import re
@@ -21,25 +22,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu.models import (generate, hf_laguna, hf_nemotron_h as hn,
-                             transformer as tfm)
+from hetu_tpu.models import generate, hf_nemotron_h as hn, transformer as tfm
 from hetu_tpu.parallel import pipeline
 from hetu_tpu.telemetry import tracing
+from model_harness import (ROOT, grads_of_loss, hidden_after_runs, jitted,
+                           load_reference, refuses, rel, round_trip,
+                           seeded_params, seeded_tokens)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG = "benchmark/configs/nemotron-twotower-30b-a3b/config.json"
 
 
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT,
-                                                                     path))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-reference = _load("benchmark/configs/nemotron-twotower-30b-a3b/reference.py",
-                  "nemotron_h_reference")
+reference = load_reference("nemotron-twotower-30b-a3b")
 
 # the published keys at a small size, every expert held: T = 32 is four
 # chunks of 8, two heads a group
@@ -67,42 +60,11 @@ KINDS = ("mamba+alone", "mlp", "mamba+alone", "mlp", "mamba+alone",
          "attention+alone", "mlp", "mamba+alone", "mlp")
 
 
-def _data(hf, seed, B=2, T=32):
-    ids = jax.random.randint(jax.random.PRNGKey(seed), (B, T + 1), 0,
-                             hf["vocab_size"])
-    return ids[:, :-1], ids[:, 1:]
-
-
-def _params(cfg, seed=0, bias=0.05):
-    """Seeded weights, the selection bias moved off zero so that it matters
-    to the picks, and every scale (the layers' norms, the gated norm, D) off
-    one so that a scale applied to the wrong channels shows."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    key = jax.random.PRNGKey(seed + 100)
-
-    def off(path, x):
-        if tfm._is_router_bias(path):
-            return bias * jax.random.normal(key, x.shape)
-        if path[-1].key in ("ln1_scale", "ln2_scale", "ssm_norm", "D"):
-            return x + 0.1 * jax.random.normal(key, x.shape)
-        return x
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / max(np.sqrt(np.mean(want ** 2)), 1e-30))
-
-
-def _hidden_after_layers(params, tokens, cfg):
-    h, after = tfm.embed_tokens(params, tokens, cfg), []
-    for (kind, _), blocks in zip(tfm.layer_runs(cfg),
-                                 tfm.run_blocks(cfg, params["blocks"])):
-        h = tfm._through_run(h, blocks, cfg, kind)
-        after.append(h)
-    return after
+# seeded weights, the selection bias moved off zero so that it matters to the
+# picks, and every scale (the layers' norms, the gated norm, D) off one so
+# that a scale applied to the wrong channels shows
+_params = functools.partial(
+    seeded_params, noisy=("ln1_scale", "ln2_scale", "ssm_norm", "D"))
 
 
 # -- the loader ------------------------------------------------------------------
@@ -173,8 +135,9 @@ def test_loader_refuses_by_name(change, named):
 @pytest.mark.parametrize("which", sorted(CONFIGS))
 def test_state_dict_round_trip(which):
     cfg = hn.config_from_hf(CONFIGS[which])
-    params = _params(cfg)
-    sd = hn.state_dict_from_params(params, cfg)
+    sd = round_trip(hn, _params(cfg), cfg,
+                    back=lambda sd, cfg: hn.params_from_state_dict(
+                        {k: np.asarray(v) for k, v in sd.items()}, cfg))
     first = cfg.router.first_held
     assert sd["backbone.layers.0.mixer.conv1d.weight"].shape == (
         128 + 2 * 4 * 16, 1, 4)
@@ -183,11 +146,6 @@ def test_state_dict_round_trip(which):
               ].shape == (48, 64)
     assert sd["backbone.layers.1.mixer.gate.weight"].shape == (8, 64)
     assert "backbone.layers.1.mixer.experts.0.up_proj.weight" in sd or first
-    back = hn.params_from_state_dict(
-        {k: np.asarray(v) for k, v in sd.items()}, cfg)
-    assert jax.tree.structure(back) == jax.tree.structure(params)
-    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_dt_initialisation_is_mamba_ssms():
@@ -207,21 +165,32 @@ def test_dt_initialisation_is_mamba_ssms():
 
 # -- the system against the reference --------------------------------------------
 
+@functools.lru_cache(maxsize=None)
+def _both_sides(which):
+    """-> (params, tokens, targets, state dict, the reference's loss and
+    terms) of CONFIGS[which] at seed 1: the reference side of the comparison,
+    run once for the cases that share it."""
+    hf = CONFIGS[which]
+    cfg = hn.config_from_hf(hf)
+    params = _params(cfg)
+    tokens, targets = seeded_tokens(hf, 1)
+    sd = hn.state_dict_from_params(params, cfg)
+    return (params, tokens, targets, sd,
+            *reference.loss_terms(sd, tokens, targets, hf))
+
+
 @pytest.mark.parametrize("which", sorted(CONFIGS))
 def test_system_matches_reference_hidden_loss_picks_and_gradients(which):
     hf = CONFIGS[which]
     cfg = hn.config_from_hf(hf, router_bias_rate=1e-3)
-    params = _params(cfg)
-    tokens, targets = _data(hf, 1)
-    sd = hn.state_dict_from_params(params, cfg)
-    want_loss, want = reference.loss_terms(sd, tokens, targets, hf)
-    loss = tfm.loss_fn(params, tokens, targets, cfg)
+    params, tokens, targets, sd, want_loss, want = _both_sides(which)
+    loss = jitted(tfm.loss_fn, cfg)(params, tokens, targets)
     assert abs(float(loss) - float(want_loss)) < 2e-6
-    after = _hidden_after_layers(params, tokens, cfg)
+    after = jitted(hidden_after_runs, cfg)(params, tokens)
     assert len(after) == len(want["hidden"]) == 9
     for i, got in enumerate(after):
-        assert _rel(got, want["hidden"][i]) < 2e-6, i
-    stats = tfm.moe_routing_stats(params, tokens, cfg)
+        assert rel(got, want["hidden"][i]) < 2e-6, i
+    stats = jitted(tfm.moe_routing_stats, cfg)(params, tokens)
     np.testing.assert_array_equal(
         np.sort(np.asarray(stats["experts"]), -1),
         np.sort(np.asarray(want["experts"]), -1))
@@ -235,7 +204,7 @@ def test_system_matches_reference_hidden_loss_picks_and_gradients(which):
     np.testing.assert_array_equal(np.asarray(same["experts"]),
                                   np.asarray(stats["experts"]))
     grads = hn.state_dict_from_params(
-        jax.grad(tfm.loss_fn)(params, tokens, targets, cfg), cfg)
+        jitted(grads_of_loss, cfg)(params, tokens, targets), cfg)
     names = [n for n in sd if "e_score" not in n]
     lean_loss, lean_hidden, want_grads = reference.grads_of(names)(
         sd, tokens, targets, hf)
@@ -243,7 +212,7 @@ def test_system_matches_reference_hidden_loss_picks_and_gradients(which):
     for a, b in zip(lean_hidden, want["hidden"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for n in names:
-        assert _rel(grads[n], want_grads[n]) < 5e-5, n
+        assert rel(grads[n], want_grads[n]) < 5e-5, n
     # the lean gradient (time segments under jax.checkpoint, a layer run
     # again) is jax.grad of the plain forward
     few = ["backbone.layers.0.mixer.A_log", "backbone.layers.0.mixer.dt_bias",
@@ -252,7 +221,7 @@ def test_system_matches_reference_hidden_loss_picks_and_gradients(which):
     plain = jax.grad(lambda part: reference.loss_terms(
         {**sd, **part}, tokens, targets, hf)[0])({n: sd[n] for n in few})
     for n in few:
-        assert _rel(want_grads[n], plain[n]) < 1e-5, n
+        assert rel(want_grads[n], plain[n]) < 1e-5, n
         assert float(jnp.max(jnp.abs(plain[n]))) > 1e-9, n
 
 
@@ -280,10 +249,8 @@ def test_the_comparison_sees_each_mechanism(wrong, monkeypatch):
     gradient instead."""
     first, edit = WRONG[wrong]
     cfg = hn.config_from_hf(HF)
-    params = _params(cfg)
-    tokens, targets = _data(HF, 1)
-    sd = hn.state_dict_from_params(params, cfg)
-    want = reference.loss_terms(sd, tokens, targets, HF)[1]["hidden"]
+    params, tokens, targets, sd, _, terms = _both_sides("whole")
+    want = terms["hidden"]
     if wrong == "SiLU for relu^2":
         monkeypatch.setattr(tfm, "_relu2", lambda u: jax.nn.silu(u))
     elif wrong == "plain relu for relu^2":
@@ -302,13 +269,13 @@ def test_the_comparison_sees_each_mechanism(wrong, monkeypatch):
                                             jnp.roll(Cm, 1, 2), chunk, mesh))
     if first is None:
         name = "backbone.layers.0.mixer.A_log"
-        got = hn.state_dict_from_params(jax.grad(tfm.loss_fn)(
-            params, tokens, targets, cfg), cfg)[name]
+        got = hn.state_dict_from_params(jitted(grads_of_loss, cfg, wrong)(
+            params, tokens, targets), cfg)[name]
         want = reference.grads_of([name])(sd, tokens, targets, HF)[2]
-        assert _rel(got, want[name]) > 0.05
+        assert rel(got, want[name]) > 0.05
         return
-    got = _hidden_after_layers(params, tokens, edit(cfg))
-    errs = [_rel(g, w) for g, w in zip(got, want)]
+    got = jitted(hidden_after_runs, edit(cfg), wrong)(params, tokens)
+    errs = [rel(g, w) for g, w in zip(got, want)]
     assert all(e < 2e-6 for e in errs[:first]) and errs[first] > 1e-4, errs
 
 
@@ -378,9 +345,9 @@ def test_relu2_experts_through_the_share_loops_are_the_every_row_form():
     got, got_g = jax.value_and_grad(loops, (0, 1, 2))(m, p["w1"][2:4],
                                                       p["w2"][2:4])
     assert float(want) > 1e-6 and abs(float(got) / float(want) - 1) < 1e-5
-    assert _rel(got_g[0], want_g[0]) < 1e-5
-    assert _rel(got_g[1], want_g[1][2:4]) < 1e-5
-    assert _rel(got_g[2], want_g[2][2:4]) < 1e-5
+    assert rel(got_g[0], want_g[0]) < 1e-5
+    assert rel(got_g[1], want_g[1][2:4]) < 1e-5
+    assert rel(got_g[2], want_g[2][2:4]) < 1e-5
 
 
 # -- the mixer ----------------------------------------------------------------------
@@ -398,46 +365,12 @@ def test_grouped_norm_is_granites_at_one_group_and_another_above(groups):
     if groups == 1:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(whole))
     else:
-        assert _rel(got, whole) > 0.1
+        assert rel(got, whole) > 0.1
     want = reference._rms_by_group(y, scale, groups, cfg.ln_eps)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6,
                                atol=1e-6)
     by_group = np.asarray(got / scale).reshape(2, 16, groups, -1)
     np.testing.assert_allclose(np.mean(by_group ** 2, -1), 1.0, rtol=1e-3)
-
-
-def test_reference_mixer_is_transformers_mamba2_at_one_group():
-    """The reference's Mamba-2 mixer (a recurrence over time) against
-    `transformers`' `Mamba2Mixer.torch_forward` (the chunked form) on copied
-    weights at ONE group, where the norm by group IS the norm over all
-    channels (HF's gated norm knows no groups)."""
-    torch = pytest.importorskip("torch", reason="torch is not installed")
-    try:
-        from transformers import Mamba2Config
-        from transformers.models.mamba2.modeling_mamba2 import Mamba2Mixer
-    except ImportError as e:
-        pytest.skip(f"transformers has no Mamba2Mixer: {e}")
-    hf = {**HF, "n_groups": 1}
-    mixer = Mamba2Mixer(Mamba2Config(
-        num_heads=8, head_dim=16, hidden_size=64, state_size=16, n_groups=1,
-        conv_kernel=4, expand=2, chunk_size=8, use_bias=False,
-        use_conv_bias=True, hidden_act="silu", layer_norm_epsilon=1e-5,
-        time_step_limit=(0.0, float("inf")), num_hidden_layers=1,
-        vocab_size=256), layer_idx=0).float().eval()
-    rng = np.random.default_rng(0)
-    w = {n: (0.3 * rng.standard_normal(tuple(p.shape))).astype(np.float32)
-         for n, p in mixer.named_parameters()}
-    w["A_log"] = np.log(np.arange(1, 9, dtype=np.float32))
-    w["dt_bias"] = rng.uniform(-5, -2, 8).astype(np.float32)
-    w["norm.weight"] = w["norm.weight"] + 1.0
-    mixer.load_state_dict({n: torch.tensor(v) for n, v in w.items()},
-                          strict=True)
-    u = rng.standard_normal((2, 32, 64)).astype(np.float32)
-    with torch.no_grad():
-        want = mixer.torch_forward(torch.tensor(u)).numpy()
-    got = np.asarray(reference._mamba_math(
-        jnp.asarray(u), {n: jnp.asarray(v) for n, v in w.items()}, hf))
-    assert np.std(want) > 0.05 and _rel(got, want) < 2e-5
 
 
 def test_ssd_at_eight_groups_of_eight_heads_is_the_time_recurrence():
@@ -458,9 +391,9 @@ def test_ssd_at_eight_groups_of_eight_heads_is_the_time_recurrence():
         want = reference._recurrence(x, Bm[:, :, own], Cm[:, :, own], dt,
                                      -jnp.exp(A_log))
         alone = tfm._ssd(x[:, Q:], dt[:, Q:], A_log, Bm[:, Q:], Cm[:, Q:], Q)
-    assert _rel(got, want) < 1e-5
+    assert rel(got, want) < 1e-5
     # the state carried in matters at these step sizes
-    assert _rel(alone[:, :Q], want[:, Q:2 * Q]) > 1e-2
+    assert rel(alone[:, :Q], want[:, Q:2 * Q]) > 1e-2
 
 
 # -- what the trunk counts and names ---------------------------------------------
@@ -486,7 +419,7 @@ def test_remat_counts_a_single_sublayers_one_sum_as_its_output():
 def test_scopes_of_the_activation_and_the_grouped_norm_in_the_step():
     cfg = hn.config_from_hf(SHARE, router_bias_rate=1e-3)
     params = _params(cfg)
-    tokens, targets = _data(SHARE, 8)
+    tokens, targets = seeded_tokens(SHARE, 8)
     text = tfm.make_train_step(cfg).lower(
         params, tfm.init_opt_state(params), tokens,
         targets).compile().as_text()
@@ -517,7 +450,7 @@ def test_scopes_of_the_activation_and_the_grouped_norm_in_the_step():
 def test_step_writes_the_picks_and_moves_the_bias_for_the_new_stack():
     cfg = hn.config_from_hf(SHARE, router_bias_rate=1e-2)
     params = _params(cfg, bias=0.0)
-    tokens, targets = _data(SHARE, 9)
+    tokens, targets = seeded_tokens(SHARE, 9)
     want = np.asarray(tfm.moe_routing_stats(params, tokens, cfg)["picks"])
     _, new, opt = tfm.make_train_step(cfg, lr=1e-4)(
         params, tfm.init_opt_state(params), tokens, targets)
@@ -534,13 +467,13 @@ def test_step_writes_the_picks_and_moves_the_bias_for_the_new_stack():
 
 def test_decode_and_pipeline_refuse_by_name():
     cfg = hn.config_from_hf(HF)
-    with pytest.raises(AssertionError, match="single_sublayer=True"):
-        generate._check_decode_args(
-            dataclasses.replace(cfg, layer_types=("attention",) * 9,
-                                n_experts=0, d_ff_shared=0, d_head=0,
-                                router=tfm.Router()), 16, 0)
-    with pytest.raises(AssertionError, match="mlp='relu2'"):
-        generate._check_decode_args(tfm.TransformerConfig(mlp="relu2"), 16, 0)
+    refuses(lambda: generate._check_decode_args(
+        dataclasses.replace(cfg, layer_types=("attention",) * 9,
+                            n_experts=0, d_ff_shared=0, d_head=0,
+                            router=tfm.Router()), 16, 0),
+        "single_sublayer=True")
+    refuses(lambda: generate._check_decode_args(
+        tfm.TransformerConfig(mlp="relu2"), 16, 0), "mlp='relu2'")
     with pytest.raises(NotImplementedError, match="unequal kinds"):
         pipeline._make_stage_fn(cfg, 1)
     one_kind = dataclasses.replace(cfg, layer_types=("attention",) * 9)
@@ -561,19 +494,6 @@ def test_config_refuses_by_name(kw, named):
 
 
 # -- the other configurations ---------------------------------------------------------
-
-def test_lagunas_tree_and_lowered_program_are_the_parents():
-    """`test_lfm2_model.py`'s recipe and table hold BERT and the six older
-    decoder cells to what ISSUE 49's parent lowered; this adds laguna-xs.2
-    at ISSUE 55's parent (commit 4414f1f), computed there by the same
-    function: nothing this PR adds is an option an existing configuration
-    has to set."""
-    import test_lfm2_model as recipe
-    recipe.LOADERS.setdefault("laguna-xs.2", hf_laguna)
-    assert recipe._cell_digest(
-        "laguna-xs.2", "pretrain-seq16384-b1-ep8share") == (
-        ("fc81640f06f3be0d", 10347), "6cc57da7b68d31b8")
-
 
 def test_defaults_leave_configs_what_they_were():
     cfg = tfm.TransformerConfig(n_layers=3)

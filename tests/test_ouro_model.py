@@ -16,7 +16,6 @@ gradient (each moves the result by more than 1e-2).
 """
 import dataclasses
 import hashlib
-import importlib.util
 import json
 import os
 import re
@@ -30,11 +29,11 @@ from hetu_tpu.models import bert, generate, hf_olmoe, hf_ouro
 from hetu_tpu.models import transformer as tfm
 from hetu_tpu.parallel import mesh as meshlib, pipeline
 from hetu_tpu.telemetry import tracing
+from model_harness import ROOT, load_reference, refuses
 
 from test_olmoe_model import HF as OLMOE_HF
 
 TOL = 1e-4
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG_JSON = os.path.join(ROOT, "benchmark", "configs", "ouro-2.6b",
                            "config.json")
 # 2 layers x 3 loops
@@ -52,12 +51,7 @@ HF = {"head_dim": 16, "hidden_act": "silu", "hidden_size": 64,
 
 @pytest.fixture(scope="module")
 def reference():
-    path = os.path.join(ROOT, "benchmark", "configs", "ouro-2.6b",
-                        "reference.py")
-    spec = importlib.util.spec_from_file_location("ouro_reference", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_reference("ouro-2.6b")
 
 
 def _state_dict(hf, seed):
@@ -344,11 +338,9 @@ def test_ouro_trains_and_decode_and_pipeline_refuse_it():
 
 def test_decode_refuses_a_looped_model_by_name():
     cfg = hf_ouro.config_from_hf(HF)
-    with pytest.raises(AssertionError, match="n_loops=3"):
-        generate._check_decode_args(cfg, 8, 0)
-    with pytest.raises(AssertionError, match="sandwich_norm=True"):
-        generate._check_decode_args(
-            dataclasses.replace(cfg, n_loops=1), 8, 0)
+    refuses(lambda: generate._check_decode_args(cfg, 8, 0), "n_loops=3")
+    refuses(lambda: generate._check_decode_args(
+        dataclasses.replace(cfg, n_loops=1), 8, 0), "sandwich_norm=True")
 
 
 def test_pipeline_refuses_a_looped_model_by_name():

@@ -21,6 +21,7 @@ from hetu_tpu.telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_LSE,
                                         REMAT_DSA_GRADS, REMAT_NORM1_IN,
                                         REMAT_NORM2_IN, REMAT_X1, REMAT_X2)
 
+from model_harness import sub_jaxprs
 from test_transformer import tiny_cfg
 
 GiB = 2 ** 30
@@ -231,15 +232,6 @@ def test_remat_names_reach_every_prefix(chain):
 # the mechanism: what the backward scan of `encode` holds
 # ---------------------------------------------------------------------------
 
-def _sub_jaxprs(eqn):
-    for v in eqn.params.values():
-        for x in (v if isinstance(v, (list, tuple)) else [v]):
-            if hasattr(x, "jaxpr") and hasattr(x, "consts"):
-                yield x.jaxpr
-            elif hasattr(x, "eqns"):
-                yield x
-
-
 def _count(jaxpr, into):
     """Matmuls and kernel calls of a jaxpr, nested calls included (not the
     kernels' own bodies)."""
@@ -247,7 +239,7 @@ def _count(jaxpr, into):
         if e.primitive.name in ("dot_general", "pallas_call"):
             into[e.primitive.name] = into.get(e.primitive.name, 0) + 1
         if e.primitive.name != "pallas_call":
-            for sub in _sub_jaxprs(e):
+            for sub in sub_jaxprs(e):
                 _count(sub, into)
     return into
 
@@ -260,7 +252,7 @@ def _backward_scan_counts(fn, *args):
             if e.primitive.name == "scan" and e.params["reverse"]:
                 yield e
             else:
-                for sub in _sub_jaxprs(e):
+                for sub in sub_jaxprs(e):
                     yield from scans(sub)
 
     found = list(scans(jax.make_jaxpr(jax.grad(fn))(*args).jaxpr))
