@@ -215,3 +215,32 @@ def test_reference_mixer_is_transformers_mamba2_at_one_group():
     got = np.asarray(nemotron_reference._mamba_math(
         jnp.asarray(u), {n: jnp.asarray(v) for n, v in w.items()}, hf))
     assert np.std(want) > 0.05 and rel(got, want) < 2e-5
+
+
+# -- bert-base ----------------------------------------------------------------
+
+@pytest.mark.parametrize("name,exact", [("gelu", True), ("gelu_new", False)])
+def test_gelu_is_transformers_activation(name, exact):
+    """`transformer._gelu` is the activation `gelu_exact` stands for:
+    `ACT2FN["gelu"]` (torch's erf form; BERT, ViT) under it, `gelu_new` (the
+    tanh form; GPT-2) without. In float32 on the CPU to 2e-6 (torch's `erf`
+    and XLA's differ by 2.4e-7), and the erf form in bfloat16 to one place of
+    the result: torch too computes it in float32 and rounds once (its tanh
+    form rounds every step to bfloat16 and is no oracle there)."""
+    torch = pytest.importorskip("torch", reason="torch is not installed")
+    activations = pytest.importorskip("transformers.activations")
+    cfg = tfm.TransformerConfig(gelu_exact=exact)
+    x = np.linspace(-10.0, 10.0, 40001).astype(np.float32)
+    with torch.no_grad():
+        want = activations.ACT2FN[name](torch.tensor(x)).numpy()
+    got = np.asarray(tfm._gelu(jnp.asarray(x), cfg))
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-7)
+    if not exact:
+        return
+    xb = jnp.asarray(x).astype(jnp.bfloat16)
+    with torch.no_grad():
+        want = activations.ACT2FN[name](
+            torch.tensor(np.asarray(xb.astype(jnp.float32))).bfloat16()
+        ).float().numpy()
+    got = np.asarray(tfm._gelu(xb, cfg).astype(jnp.float32))
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2.0 ** -7)
