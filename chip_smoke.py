@@ -678,8 +678,11 @@ KERNEL_SHAPES = {
     "flash_bwd_dqkv": {"kanana": (4, 32, 8192, 192, 128),
                        "lfm2": (4, 32, 8192, 64, 64)},
     # (batch, heads, seq, head width, window): laguna-xs.2's window layers
-    # at a small size, four 512 x 512 tiles a head, causal bf16
-    "flash_window": (1, 8, 2048, 128, 512),
+    # at a small size, four 512 x 512 tiles a head, causal bf16; and
+    # smallthinker-21b-a3b's at half its sequence: ONE group of seven query
+    # heads, a window of 4,096 = eight 512-key tiles deep
+    "flash_window": {"laguna": (1, 8, 2048, 128, 512),
+                     "smallthinker": (1, 7, 8192, 128, 4096)},
     "fused_ce": (256, 768, 30522),       # MLM rows x d_model x vocab, bf16
     "embed_grad": (3328, 128, 100000),   # 128 x 26 slots, published width
     "csr_spmm": (4096, 1024, 1024, 128),  # nnz, nrow, K, F
@@ -694,6 +697,9 @@ KERNEL_SHAPES = {
     "rope_halves": {"ouro": (1, 4096, 16, 16, 128, 0, False),
                     "laguna-full": (1, 16384, 48, 8, 128, 64, True),
                     "laguna-window": (1, 16384, 64, 8, 128, 0, False),
+                    # smallthinker-21b-a3b's window layers: 28 on 4, groups
+                    # of seven, q 28 lane tiles wide
+                    "smallthinker-window": (1, 16384, 28, 4, 128, 0, False),
                     "lfm2": (4, 8192, 32, 8, 64, 0, False)},
     # (batch, seq, heads, head width, groups, state, chunk): a quarter of
     # granite-4.0-h-micro's scan, eight chunks of 256, bf16
@@ -839,35 +845,37 @@ def phase_kernels(*, shapes=None, chip=True):
         # -- a sliding window: the forward against the unfused reference
         # under the window's mask, the one backward kernel against the XLA
         # blockwise backward under it, on the forward kernel's o and lse ----
-        b, h, s, d, window = shapes["flash_window"]
-        qkv = tuple(jnp.asarray(rng.randn(b, s, h * d), jnp.bfloat16)
-                    for _ in range(3))
-        do = jnp.asarray(rng.randn(b, s, h * d), jnp.bfloat16)
-        kw = dict(n_heads=h, scale=d ** -0.5, causal=True, window=window)
-        o, lse = jax.jit(lambda qkv: fa._fwd_pallas(
-            qkv, h, None, kw["scale"], True, None, None, interpret=not chip,
-            window=window))(qkv)
-        heads = lambda x: x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
-        want_o = mha_reference(*map(heads, qkv), causal=True, window=window)
-        np.testing.assert_allclose(
-            np.asarray(heads(o), np.float32), np.asarray(want_o, np.float32),
-            atol=2e-2, rtol=2e-2, err_msg="chip_smoke: flash_fwd, window")
-        got = jax.jit(lambda qkv, o, lse, do: fa._bwd_pallas(
-            (qkv, o, lse, None), do, **kw, block_q=None, block_k=None,
-            interpret=not chip))(qkv, o, lse, do)
-        want = jax.jit(lambda qkv, o, lse, do: fa._bwd_blockwise(
-            (qkv, o, lse, None), do, **kw, block_k=128))(qkv, o, lse, do)
-        err = float(np.max(np.abs(np.asarray(heads(o), np.float32)
-                                  - np.asarray(want_o, np.float32))))
-        for g, w in zip(got, want):
-            g, w = (np.asarray(x, np.float32) for x in (g, w))
-            _check(g.shape == w.shape and _finite(g),
-                   "window: bad shape or non-finite gradient")
+        for cell, (b, h, s, d, window) in shapes["flash_window"].items():
+            qkv = tuple(jnp.asarray(rng.randn(b, s, h * d), jnp.bfloat16)
+                        for _ in range(3))
+            do = jnp.asarray(rng.randn(b, s, h * d), jnp.bfloat16)
+            kw = dict(n_heads=h, scale=d ** -0.5, causal=True, window=window)
+            o, lse = jax.jit(lambda qkv: fa._fwd_pallas(
+                qkv, h, None, kw["scale"], True, None, None,
+                interpret=not chip, window=window))(qkv)
+            heads = lambda x: x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+            want_o = mha_reference(*map(heads, qkv), causal=True,
+                                   window=window)
             np.testing.assert_allclose(
-                g, w, atol=2e-2, rtol=2e-2,
-                err_msg="chip_smoke: the backward kernel under a window")
-            err = max(err, float(np.max(np.abs(g - w))))
-        results["flash_window"] = {"max_abs_err": err}
+                np.asarray(heads(o), np.float32),
+                np.asarray(want_o, np.float32), atol=2e-2, rtol=2e-2,
+                err_msg="chip_smoke: flash_fwd, window")
+            got = jax.jit(lambda qkv, o, lse, do: fa._bwd_pallas(
+                (qkv, o, lse, None), do, **kw, block_q=None, block_k=None,
+                interpret=not chip))(qkv, o, lse, do)
+            want = jax.jit(lambda qkv, o, lse, do: fa._bwd_blockwise(
+                (qkv, o, lse, None), do, **kw, block_k=128))(qkv, o, lse, do)
+            err = float(np.max(np.abs(np.asarray(heads(o), np.float32)
+                                      - np.asarray(want_o, np.float32))))
+            for g, w in zip(got, want):
+                g, w = (np.asarray(x, np.float32) for x in (g, w))
+                _check(g.shape == w.shape and _finite(g),
+                       "window: bad shape or non-finite gradient")
+                np.testing.assert_allclose(
+                    g, w, atol=2e-2, rtol=2e-2,
+                    err_msg="chip_smoke: the backward kernel under a window")
+                err = max(err, float(np.max(np.abs(g - w))))
+            results[f"flash_window:{cell}"] = {"max_abs_err": err}
 
         # -- fused linear + softmax CE, fwd + bwd, bf16 -------------------
         n, dm, vocab = shapes["fused_ce"]

@@ -56,7 +56,8 @@ from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP,
                                  SCOPE_MLA_Q, SCOPE_MOE_ACT, SCOPE_MOE_COMBINE,
                                  SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
-                                 SCOPE_MOE_ROUTE, SCOPE_MOE_SHARED,
+                                 SCOPE_MOE_ROUTE, SCOPE_MOE_ROUTE_EARLY,
+                                 SCOPE_MOE_SHARED,
                                  SCOPE_OPT, SCOPE_SCONV_CONV,
                                  SCOPE_SCONV_PROJ, SCOPE_SSD_ENTER,
                                  SCOPE_SSD_INCHUNK, SCOPE_SSD_STATES,
@@ -83,6 +84,12 @@ EXIT_ENTROPY_WEIGHT = 0.05
 # name alone and ``make_train_step`` moves it by the rule of
 # ``Router.bias_rate``
 ROUTER_BIAS = "router_bias"
+
+
+# the gated MLP forms, down(act(gate(x)) * up(x)): three matrices ``w1``
+# (gate), ``w3`` (up), ``w2`` (down), dense or an expert; they differ in
+# the activation alone (``_swiglu`` / ``_reglu``)
+GATED_MLPS = ("swiglu", "reglu")
 
 
 class MoEConfigError(ValueError):
@@ -159,7 +166,10 @@ class WindowConfig:
     the ``window`` keys t - window < s <= t, with ``n_heads`` query heads
     (on the model's ``kv_heads``, at its ``head_dim``) and rotate-half RoPE
     at ``rope_theta`` on ALL of a head's columns, no frequency scaling,
-    whatever ``rope_dim`` and ``rope_yarn`` say of the "attention" layers."""
+    whatever ``rope``, ``rope_dim`` and ``rope_yarn`` say of the "attention"
+    layers: the rotary form is the KIND's, and a stack may rotate in its
+    window layers alone (``cfg.rope`` false: NoPE attention layers beside
+    them, SmallThinker's)."""
     window: int = 512
     n_heads: int = 64
     rope_theta: float = 10000.0
@@ -218,6 +228,11 @@ class Router:
     # first_held + cfg.n_experts) and computes the picks that land there
     width: int = 0              # 0 = ``cfg.n_experts``: every expert is here
     first_held: int = 0
+    input: str = "mlp"          # what the router reads: "mlp" the MLP half's
+                                # normed input, as the experts do; "block"
+                                # the residual stream as it ENTERS the layer,
+                                # before the mixer and its norm (``_block``
+                                # routes ahead of the mixer: SmallThinker)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -279,12 +294,16 @@ class TransformerConfig:
                                 # is dialect-independent)
     rope: bool = False          # rotary position embeddings on q/k (the
                                 # cache stores ROTATED keys); replaces the
-                                # learned "pos" table
+                                # learned "pos" table. The "attention" (and
+                                # "dsa") layers'; a "window" layer rotates
+                                # by ``window`` whatever this says
     rope_theta: float = 10000.0
     mlp: str = "gelu"           # "swiglu": down(silu(gate(x))·up(x)) with
                                 # an extra w3 (up) weight, no biases used;
                                 # "relu2": down(relu(up(x))²), two matrices,
-                                # no gate and no bias leaves (Nemotron-H)
+                                # no gate and no bias leaves (Nemotron-H);
+                                # "reglu": down(relu(gate(x))·up(x)), the
+                                # leaves of "swiglu" (SmallThinker)
     n_kv_heads: int = 0         # grouped-query attention: 0 = n_heads
                                 # (MHA); otherwise k/v project to n_kv
                                 # heads and broadcast to the q heads
@@ -390,12 +409,16 @@ class TransformerConfig:
                 "rotates its queries and keys too)")
         if "window" in self.layer_types and (
                 self.window is None or self.post_ln or not self.causal
-                or not self.rope or self.window.window < 1
+                or self.window.window < 1
                 or self.window.n_heads % self.kv_heads):
             raise ValueError(
                 f"layer_types={self.layer_types}: a window layer takes "
                 "`window` sizes (a positive window, a head count that the "
-                "k/v heads divide), pre-LN, causal attention and RoPE")
+                "k/v heads divide), pre-LN and causal attention")
+        if (self.rope_dim or self.rope_yarn) and not self.rope:
+            raise ValueError(
+                f"rope_dim={self.rope_dim}, rope_yarn={self.rope_yarn}: the "
+                "rotary form of \"attention\" layers that rotate (`rope`)")
         if (self.rope_dim or self.rope_yarn or self.attn_gate) and (
                 {"mla", "dsa"} & set(self.layer_types)
                 or self.rope_dim % 2 or self.rope_dim > self.head_dim):
@@ -413,6 +436,9 @@ class TransformerConfig:
                 f"{self.single_sublayer}: an \"mlp\" layer is a layer of a "
                 "single-sublayer stack, which is pre-LN without sandwich "
                 "norms, leading dense layers or loops")
+        if self.mlp not in ("gelu", "relu2") + GATED_MLPS:
+            raise ValueError(f"mlp={self.mlp!r}: 'gelu', 'relu2' or one of "
+                             f"{GATED_MLPS}")
         if self.d_ff_shared and not (self.n_experts
                                      and self.mlp in ("swiglu", "relu2")):
             raise MoEConfigError(
@@ -431,10 +457,14 @@ class TransformerConfig:
                 f"{r.first_held + self.n_experts}) held of a router of "
                 f"width {r.width}")
         if r.score not in ("softmax", "sigmoid") or (
-                r.bias_rate and not r.bias):
+                r.bias_rate and not r.bias) or r.input not in (
+                    "mlp", "block") or (
+                        r.input == "block" and (self.post_ln
+                                                or self.single_sublayer)):
             raise MoEConfigError(
                 f"{r}: score is 'softmax' or 'sigmoid'; bias_rate moves a "
-                "bias that is there")
+                "bias that is there; input is 'mlp' or 'block' (a pre-LN "
+                "layer of two halves: its input, ahead of the mixer)")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm={self.qk_norm!r}: False, True (the "
                              "whole projection) or 'head'")
@@ -586,11 +616,12 @@ def _attention_specs(cfg: TransformerConfig):
 @functools.lru_cache(maxsize=None)
 def _window_view(cfg: TransformerConfig):
     """The config a "window" layer's attention reads: the model's, with the
-    window kind's own head count and rotary form (``WindowConfig``) where
+    window kind's own head count and rotary form (``WindowConfig``: it
+    rotates whether or not the "attention" layers do) where
     ``_init_attention``, ``_split_heads`` and ``_flash`` read the
     "attention" layers'."""
     w = cfg.window
-    return dataclasses.replace(cfg, n_heads=w.n_heads,
+    return dataclasses.replace(cfg, n_heads=w.n_heads, rope=True,
                                rope_theta=w.rope_theta, rope_dim=0,
                                rope_yarn=None)
 
@@ -733,7 +764,7 @@ def _init_run(ks, cfg: TransformerConfig, kind, n):
         for name in ("ln1_post", "ln2_post"):
             blocks[name + "_scale"] = jnp.ones((n, D), jnp.float32)
             blocks[name + "_bias"] = jnp.zeros((n, D), jnp.float32)
-    if cfg.mlp == "swiglu":
+    if cfg.mlp in GATED_MLPS:
         blocks["w3"] = norm(ks[8], (n, E, D, F) if E > 0 else (n, D, F),
                             0.02)
     out_scale = 0.02 / np.sqrt(2 * cfg.n_layers)
@@ -807,7 +838,7 @@ def _run_specs(cfg: TransformerConfig, kind):
     if cfg.sandwich_norm:
         for name in ("ln1_post", "ln2_post"):
             blocks[name + "_scale"] = blocks[name + "_bias"] = P(None, None)
-    if cfg.mlp == "swiglu":
+    if cfg.mlp in GATED_MLPS:
         blocks["w3"] = (P(None, "ep", None, "tp") if moe
                         else P(None, None, "tp"))
     if moe:
@@ -1723,15 +1754,17 @@ _KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
 
 
 def _dense_mlp(h, p, cfg, mesh):
-    if cfg.mlp == "swiglu":
+    if cfg.mlp in GATED_MLPS:
         # Llama MLP: down(silu(gate(x)) * up(x)); the b1/b2 params exist
-        # but are zero/unused in this dialect (no biases in the family)
+        # but are zero/unused in this dialect (no biases in the family).
+        # "reglu": relu for silu, nothing else
+        act = jax.nn.silu if cfg.mlp == "swiglu" else jax.nn.relu
         with jax.named_scope(SCOPE_BLK_MLP_UP):
             gate = jnp.einsum("btd,df->btf", h, p["w1"].astype(h.dtype),
                               preferred_element_type=jnp.float32)
             up = jnp.einsum("btd,df->btf", h, p["w3"].astype(h.dtype),
                             preferred_element_type=jnp.float32)
-            u = (jax.nn.silu(gate) * up).astype(h.dtype)
+            u = (act(gate) * up).astype(h.dtype)
         with jax.named_scope(SCOPE_BLK_MLP_DOWN):
             return jnp.einsum(
                 "btf,fd->btd", u, p["w2"].astype(h.dtype),
@@ -1893,6 +1926,12 @@ def _grouped_matmul(xs, w, group_sizes, mesh=None):
 def _swiglu(gate, up):
     """silu(gate) * up, in float32, at ``gate``'s dtype."""
     return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+def _reglu(gate, up):
+    """relu(gate) * up, in float32, at ``gate``'s dtype."""
+    return (jax.nn.relu(gate.astype(jnp.float32))
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
@@ -2058,9 +2097,9 @@ _rows_twice.defvjp(_rows_twice_fwd, _rows_twice_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 3))
 def _act_rows(act, operands, rows, R):
-    """The experts' activation ``act`` (``_swiglu`` of (gate, up), ``_relu2``
-    of (u,)) on the first ``rows`` rows of its ``operands``, a chunk a
-    step."""
+    """The experts' activation ``act`` (``_swiglu`` or ``_reglu`` of (gate,
+    up), ``_relu2`` of (u,)) on the first ``rows`` rows of its ``operands``,
+    a chunk a step."""
     def body(start, out):
         return _put(out, act(*(_cut(a, start, R) for a in operands)), start)
 
@@ -2122,7 +2161,7 @@ def _share_combine_bwd(R, res, g):
 _share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
 
 
-def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
+def _moe_mlp(h, p, cfg: TransformerConfig, mesh, routing=None):
     """An expert layer's MLP half -> (out, aux (2,)): the routed picks
     (``_routed_experts``) and, under ``cfg.d_ff_shared``, the shared expert
     beside them: ONE MLP of the experts' form (SwiGLU, or relu2's two
@@ -2130,8 +2169,9 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     HF ``DeepseekV3MoE.forward`` adds it. It is no expert of the router's:
     a share (``cfg.router.width``) computes it whole, once, whatever it
     holds, so the parts of the members of a group add up to the layer only
-    with the shared expert counted once."""
-    out, aux = _routed_experts(h, p, cfg, mesh)
+    with the shared expert counted once. ``routing``: the layer's routing
+    where ``_block`` made it ahead of the mixer (``_plan_routing``)."""
+    out, aux = _routed_experts(h, p, cfg, mesh, routing)
     if cfg.d_ff_shared:
         with jax.named_scope(SCOPE_MOE_SHARED):
             shared = {"w1": p["ws1"], "w2": p["ws2"]}
@@ -2141,7 +2181,36 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh):
     return out, aux
 
 
-def _routed_experts(h, p, cfg: TransformerConfig, mesh):
+def _plan_routing(x, p, cfg: TransformerConfig):
+    """Everything an expert layer takes from its ROUTER, from the rows ``x``
+    (S, D) the router reads (``cfg.router.input``: the MLP half's normed
+    input, or the layer's own input) and nothing else, so it can be made
+    wherever those rows exist: ``top_p`` (S, k) the picks' weights (with the
+    router's gradient), ``flat_e`` (S*k,) the picks' experts as the sort
+    reads them, ``order`` / ``inv`` the stable sort by expert and its
+    inverse, ``group_sizes`` the held experts' rows, ``aux`` (2,); on a share
+    also ``R`` and ``plan`` (``_share_plan``)."""
+    first, E = cfg.router.first_held, cfg.n_experts
+    share = (cfg.router.width or E) != E
+    top_p, top_e, counts, _, aux = _route(x, p, cfg)
+    flat_e = top_e.reshape(-1)
+    if share:
+        held = (top_e >= first) & (top_e < first + E)
+        # held picks first, by expert; the rest behind them
+        flat_e = jnp.where(held.reshape(-1), flat_e - first, E)
+        counts = counts[first:first + E]
+    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    routing = {"top_p": top_p, "flat_e": flat_e, "order": order, "inv": inv,
+               "group_sizes": counts,  # picks an expert = rows of its group
+               "aux": aux}
+    if share:
+        R = _row_chunk(x.shape[0], x.shape[1], x.dtype)
+        routing.update(R=R, plan=_share_plan(order, inv, held, counts, R))
+    return routing
+
+
+def _routed_experts(h, p, cfg: TransformerConfig, mesh, routing=None):
     """Dropless top-k MoE: every pick on an expert held here is computed.
     The S*k picks are sorted by expert (stable), token rows gathered in that
     order, each projection is one grouped matmul over the uneven groups, and
@@ -2170,45 +2239,51 @@ def _routed_experts(h, p, cfg: TransformerConfig, mesh):
 
     Under a mesh with ``ep > 1`` the older top-1 capacity form runs instead
     (``_moe_mlp_capacity``): experts over ``ep`` by all-to-all for this
-    form is ROADMAP R1's remaining item, and a share there is refused."""
-    first, E = cfg.router.first_held, cfg.n_experts
+    form is ROADMAP R1's remaining item, and a share there is refused, as is
+    a router that reads the layer's input.
+
+    ``routing``: ``_plan_routing``'s, made by ``_block`` ahead of the mixer
+    where the router reads the layer's input (``cfg.router.input``
+    "block"); made here, from ``h``'s rows, otherwise."""
+    E = cfg.n_experts
     share = (cfg.router.width or E) != E
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
-        if share:
+        if share or routing is not None:
             raise MoEConfigError(
                 f"a share of an expert layer ({E} of {cfg.router.width} "
-                f"experts held) on a mesh with ep={mesh.shape['ep']}: the "
-                "share is what ONE member of an expert-parallel group "
-                "computes; the exchange over `ep` is not written")
+                "experts held), or a router on the layer's input "
+                f"(router.input={cfg.router.input!r}), on a mesh with "
+                f"ep={mesh.shape['ep']}: the share is what ONE member of an "
+                "expert-parallel group computes, and early routing's "
+                "exchange of counts behind the mixer is part of the same "
+                "exchange over `ep`, which is not written")
         return _moe_mlp_capacity(h, p, cfg, mesh)
     B, T, D = h.shape
     k = cfg.n_experts_per_tok
     x = h.reshape(B * T, D)
-    with jax.named_scope(SCOPE_MOE_ROUTE):
-        top_p, top_e, counts, _, aux = _route(x, p, cfg)
-        flat_e = top_e.reshape(-1)
-        if share:
-            held = (top_e >= first) & (top_e < first + E)
-            # held picks first, by expert; the rest behind them
-            flat_e = jnp.where(held.reshape(-1), flat_e - first, E)
-            counts = counts[first:first + E]
-        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-        inv = jnp.argsort(order).astype(jnp.int32)
-        group_sizes = counts       # picks an expert = rows of its group
-        if share:
-            R = _row_chunk(B * T, D, x.dtype)
-            plan = _share_plan(order, inv, held, counts, R)
+    if routing is None:
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            routing = _plan_routing(x, p, cfg)
+    top_p, flat_e, order, inv, group_sizes, aux = (routing[n] for n in (
+        "top_p", "flat_e", "order", "inv", "group_sizes", "aux"))
+    if share:
+        R, plan = routing["R"], routing["plan"]
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         xs = (_share_dispatch(x, plan, R) if share
               else _dispatch_rows(x, order, inv, k))         # (S*k, D)
     with jax.named_scope(SCOPE_MOE_EXPERTS):
-        if cfg.mlp == "swiglu":
+        if cfg.mlp in GATED_MLPS:
             xs, xs_up = (_rows_twice(xs, plan["rows_run"], R) if share
                          else (xs, xs))
             u = _grouped_matmul(xs, p["w1"], group_sizes, mesh)
             up = _grouped_matmul(xs_up, p["w3"], group_sizes, mesh)
-            u = (_act_rows(_swiglu, (u, up), plan["rows_run"], R) if share
-                 else _swiglu(u, up))
+            if cfg.mlp == "swiglu":     # unnamed, as it lowered
+                u = (_act_rows(_swiglu, (u, up), plan["rows_run"], R)
+                     if share else _swiglu(u, up))
+            else:
+                with jax.named_scope(SCOPE_MOE_ACT):
+                    u = (_act_rows(_reglu, (u, up), plan["rows_run"], R)
+                         if share else _reglu(u, up))
             ys = _grouped_matmul(u, p["w2"], group_sizes, mesh)
         elif cfg.mlp == "relu2":    # ungated, no bias: the held rows alone
             u = _grouped_matmul(xs, p["w1"], group_sizes, mesh)
@@ -2267,10 +2342,11 @@ def _moe_mlp_capacity(h, p, cfg: TransformerConfig, mesh):
     expert_in = _constrain(expert_in, mesh, "ep", None, None)
     u = jnp.einsum("ecd,edf->ecf", expert_in, p["w1"].astype(x.dtype),
                    preferred_element_type=jnp.float32)
-    if cfg.mlp == "swiglu":
+    if cfg.mlp in GATED_MLPS:
         up = jnp.einsum("ecd,edf->ecf", expert_in, p["w3"].astype(x.dtype),
                         preferred_element_type=jnp.float32)
-        u = (jax.nn.silu(u) * up).astype(x.dtype)
+        act = jax.nn.silu if cfg.mlp == "swiglu" else jax.nn.relu
+        u = (act(u) * up).astype(x.dtype)
         y = jnp.einsum("ecf,efd->ecd", u, p["w2"].astype(x.dtype),
                        preferred_element_type=jnp.float32).astype(x.dtype)
     else:
@@ -2296,6 +2372,15 @@ def _residual(out, cfg: TransformerConfig):
     # in float32: bf16(0.22) is 0.1 % off, the same way every layer
     return out if r == 1.0 else (out.astype(jnp.float32) * r).astype(
         out.dtype)
+
+
+def _router_rows(h):
+    """The stream (B, T, D) as the rows (S, D) a router reads, AS ROUNDED to
+    the compute dtype: behind the barrier the compiler cannot feed the
+    router's float32 matmul an unrounded producer's output (its excess
+    precision; PERF.md, PR 55), so a check that recomputes the picks from
+    these rows can explain every one."""
+    return jax.lax.optimization_barrier(h.reshape(-1, h.shape[-1]))
 
 
 def _block_attn(h, layer_params, cfg: TransformerConfig, mesh, attn_bias,
@@ -2364,12 +2449,20 @@ def _block(h, layer_params, cfg: TransformerConfig, mesh, attn_bias=None,
     decode silently diverges from training for that config."""
     k1, k2 = (None, None) if dropout_rng is None else jax.random.split(
         dropout_rng)
+    routing = None
+    if experts_of(cfg, kind) > 0 and cfg.router.input == "block":
+        # the router reads the stream as it ENTERS the layer: the routing
+        # (picks, weights, counts, the sort, a share's plan) waits for
+        # nothing of the mixer, and stands before it in program order
+        with jax.named_scope(SCOPE_MOE_ROUTE_EARLY), \
+                jax.named_scope(SCOPE_MOE_ROUTE):
+            routing = _plan_routing(_router_rows(h), layer_params, cfg)
     h, mlp_in, side = _block_mixer(h, layer_params, cfg, mesh, attn_bias, k1,
                                    kind)
     if mlp_in is None:      # a single sublayer: the mixer alone
         out, aux = None, jnp.zeros((2,), jnp.float32)
     elif experts_of(cfg, kind) > 0:
-        out, aux = _moe_mlp(mlp_in, layer_params, cfg, mesh)
+        out, aux = _moe_mlp(mlp_in, layer_params, cfg, mesh, routing)
     else:
         out = _dense_mlp(mlp_in, layer_params, cfg, mesh)
         aux = jnp.zeros((2,), jnp.float32)
@@ -2730,7 +2823,9 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig, terms=False):
     the experts (the softmax itself; sigmoid scores over their sum), in
     nats. With ``terms`` also what the picks were computed from:
     ``router_in`` (B*T, D) the rows the router read (its weights are
-    ``params["blocks"]["router"]``)."""
+    ``params["blocks"]["router"]``; the layer's input where
+    ``Router.input`` is "block"), and ``weights`` (B*T, k) the picks' as the
+    combine reads them."""
     if not cfg.n_experts:
         raise MoEConfigError("moe_routing_stats: a dense config")
     k, r = cfg.n_experts_per_tok, cfg.router
@@ -2743,10 +2838,11 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig, terms=False):
         # ``router_in`` alike: without the barrier the compiler may feed the
         # router's float32 matmul the norm's unrounded output (its excess
         # precision), and a check that recomputes the picks from
-        # ``router_in`` then sees picks it cannot explain (PERF.md, PR 55)
-        mlp_in = jax.lax.optimization_barrier(mlp_in)
-        _, top_e, counts, probs, _ = _route(
-            mlp_in.reshape(S, -1), layer_params, cfg)
+        # ``router_in`` then sees picks it cannot explain (PERF.md, PR 55).
+        # The rows are the router's OWN input: the layer's, where it reads
+        # that (``Router.input``)
+        rows = _router_rows(h if r.input == "block" else mlp_in)
+        top_p, top_e, counts, probs, _ = _route(rows, layer_params, cfg)
         if r.score != "softmax":
             probs = probs / jnp.sum(probs, -1, keepdims=True)
         held = jnp.sum((top_e >= first) & (top_e < first + n_held))
@@ -2758,11 +2854,12 @@ def moe_routing_stats(params, tokens, cfg: TransformerConfig, terms=False):
             "dropped": held - covered,
             # not a share: every pick is covered, so all S * k
             "rows_run": _rows_run(covered, _row_chunk(
-                S, mlp_in.shape[-1], mlp_in.dtype), S * k),
+                S, rows.shape[-1], rows.dtype), S * k),
             "entropy": -jnp.mean(jnp.sum(
                 probs * jnp.log(jnp.maximum(probs, 1e-30)), -1))}
         if terms:
-            stats["router_in"] = mlp_in.reshape(S, -1)
+            stats["router_in"] = rows
+            stats["weights"] = top_p
         h, _ = _block(h, layer_params, cfg, None, kind=kind)
         return h, stats
 
@@ -2842,7 +2939,10 @@ def router_terms(params, tokens, cfg: TransformerConfig):
     theirs as the combine reads them."""
     h, p, kind = _first_layer_of(params, tokens, cfg,
                                  lambda kind: experts_of(cfg, kind) > 0)
-    _, mlp_in = _block_attn(h, p, cfg, None, None, None, kind)
+    if cfg.router.input == "block":
+        mlp_in = h          # the router's rows are the layer's own input
+    else:
+        _, mlp_in = _block_attn(h, p, cfg, None, None, None, kind)
     x = mlp_in.reshape(-1, mlp_in.shape[-1])
     top_p, top_e, _, probs, _ = _route(x, p, cfg)
     return {"x": x, "router": p["router"], "scores": probs,

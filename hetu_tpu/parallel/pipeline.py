@@ -77,6 +77,11 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
             f"single_sublayer=True (layer kind {kind!r}): a stage's body is "
             "the block of two halves; a layer that is a mixer alone, or an "
             "MLP half alone, has no stage rule")
+    if cfg.router.input != "mlp":
+        raise NotImplementedError(
+            f"router.input={cfg.router.input!r}: a stage's body routes on "
+            "the MLP half's input; routing issued ahead of the mixer has no "
+            "stage rule")
     if tfm.mixer_of(kind) != "attention":
         raise NotImplementedError(
             f"layer kind {kind!r}: a stage's body is the attention block; "

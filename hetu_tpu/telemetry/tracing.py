@@ -74,9 +74,19 @@ MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
 # INSIDE SCOPE_MOE_EXPERTS (`.../hetu_moe_experts/hetu_moe_act/...`), so a
 # reader of the four counts it where it counted it: the activation of UNGATED
 # experts (`cfg.mlp` "relu2": relu(.)^2 on the held rows between the two
-# grouped matmuls); benchmark/reduce/nemotron_h.py reads it. Gated experts'
-# SiLU(gate) * up stays unnamed inside SCOPE_MOE_EXPERTS, as it lowered
+# grouped matmuls) and of ReGLU experts (`cfg.mlp` "reglu": relu(gate) * up
+# on the held rows between the two pairs); benchmark/reduce/nemotron_h.py
+# reads it. SwiGLU experts' SiLU(gate) * up stays unnamed inside
+# SCOPE_MOE_EXPERTS, as it lowered
 SCOPE_MOE_ACT = "hetu_moe_act"
+# AROUND SCOPE_MOE_ROUTE (`.../hetu_moe_route_early/hetu_moe_route/...`), so
+# a reader of the four counts the routing where it counted it: the routing
+# of a layer whose router reads the layer's INPUT (`Router.input` "block":
+# transformer._block makes the picks, their weights, the counts, the sort
+# and a share's plan BEFORE the mixer in program order).
+# benchmark/reduce/smallthinker.py reads its time and whether its forward
+# ops end before the layer's first attention kernel starts
+SCOPE_MOE_ROUTE_EARLY = "hetu_moe_route_early"
 # the always-on branch of an expert layer (`cfg.d_ff_shared`: the shared
 # expert, a SwiGLU on every token beside the routed picks; transformer.
 # _moe_mlp), a FIFTH part beside the four and inside none of them: a reader

@@ -19,14 +19,15 @@ import pytest
 
 from hetu_tpu.models import (bert, hf_deepseek_v3, hf_granite, hf_keye,
                              hf_laguna, hf_lfm2, hf_nemotron_h, hf_olmoe,
-                             hf_ouro, transformer as tfm)
+                             hf_ouro, hf_smallthinker, transformer as tfm)
 from model_harness import ROOT
 
 LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
            "granite-4.0-h-micro": hf_granite, "lfm2-8b-a1b": hf_lfm2,
            "kanana-2-30b-a3b": hf_deepseek_v3,
            "keye-vl-2.0-30b-a3b": hf_keye, "laguna-xs.2": hf_laguna,
-           "nemotron-twotower-30b-a3b": hf_nemotron_h}
+           "nemotron-twotower-30b-a3b": hf_nemotron_h,
+           "smallthinker-21b-a3b": hf_smallthinker}
 
 # (sha256[:16] of the LOWERED train step at the cell's own config and traffic
 # shapes with the counters cut off private symbols, its lines; sha256[:16] of
@@ -43,6 +44,10 @@ LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
 # form: one float32 `erf` where `erfc` was; 5dd9818f8e559ca8, 2401 lines at
 # its parent fb731aa; the parameter tree's digest did not move): the other
 # eight lines, unedited, say that no other cell reaches `_gelu`.
+# smallthinker's is of ISSUE 63's own tree, the PR that added the cell (its
+# parent, 43cc042, has no loader for it): the nine lines above it, unedited,
+# say that `Router.input`, `mlp="reglu"` and the window kind's own rotary
+# flag changed no program that existed.
 PARENT = {
     ("bert-base", "pretrain-seq512"):
         (("74ac9d1c59c8f61d", 2415), "0ca3cf6cdc80eded"),
@@ -62,6 +67,8 @@ PARENT = {
         (("fc81640f06f3be0d", 10347), "6cc57da7b68d31b8"),
     ("nemotron-twotower-30b-a3b", "pretrain-seq8192-b1-ep16share"):
         (("75d12ffe4f5aeefc", 14469), "de05633a1305e7f2"),
+    ("smallthinker-21b-a3b", "pretrain-seq16384-b1-ep4share"):
+        (("d322fa2782e4e0b8", 6153), "ae3edc310cab3dd8"),
 }
 
 

@@ -101,7 +101,7 @@ def test_kernels_phase_tiny(capsys):
         "flash": (1, 2, 128, 32), "fused_ce": (32, 64, 300),
         "flash_bwd_dqkv": {"two-widths": (1, 2, 1024, 192, 128),
                            "pairs-of-64": (2, 4, 1024, 64, 64)},
-        "flash_window": (1, 2, 512, 128, 100),
+        "flash_window": {"a-window-of-100": (1, 2, 512, 128, 100)},
         "embed_grad": (128, 128, 1000), "csr_spmm": (300, 16, 16, 128),
         "quant": (4096, 256), "opt": (300, 700),
         "rope": (1, 32, 2, 128, 64),
@@ -115,6 +115,6 @@ def test_kernels_phase_tiny(capsys):
         "flash_causal", "flash_key_padding", "fused_ce", "fused_embed_grad",
         "csr_spmm", "quant_blocks", "dequant_blocks", "fused_adam",
         "fused_sgd", "rope_pairs", "flash_bwd_dqkv:two-widths",
-        "flash_bwd_dqkv:pairs-of-64", "flash_window",
+        "flash_bwd_dqkv:pairs-of-64", "flash_window:a-window-of-100",
         "rope_halves:half-a-head-under-yarn", "rope_halves:heads-of-64",
         "ssd", "ssd_groups", "grouped_matmul"}

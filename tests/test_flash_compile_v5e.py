@@ -328,15 +328,20 @@ def test_many_tile_kernels_lower_to_what_they_were(one_chip, cell, half):
     assert digest == MANY_TILE[cell][at]
 
 
-@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
-                         ids=["window-64-heads", "full-48-heads"])
+@pytest.mark.parametrize("heads,window", [
+    (64, 512), (48, None), (28, 4096), (28, None)],
+    ids=["window-64-heads", "full-48-heads", "smallthinker-window-28-heads",
+         "smallthinker-full-28-heads"])
 def test_flash_compiles_for_v5e_at_lagunas_two_head_counts(
         one_chip, no_compile_cache, heads, window):
     """laguna-xs.2's two calls: 1 x 16,384 tokens of 128-column heads,
     bfloat16, three arrays; a window layer's 64 heads under a window of 512
     (the loops' bounds read at run time: a lower one forward, an upper one
     backward) and a full layer's 48. One kernel each way, at 512 x 512
-    tiles, k and v whole in the VMEM the call asks for."""
+    tiles, k and v whole in the VMEM the call asks for. And
+    smallthinker-21b-a3b's two at the same tokens: 28 heads (four groups of
+    seven, the k/v heads repeated), a window of 4,096 = eight key tiles deep,
+    and the NoPE global layer's."""
     # (qkv, o, lse, dO): the backward half's abstract arguments
     args = _many_tile_halves(one_chip, (1, heads, 16384, 128, 128))[1][1][:4]
     scale = 1.0 / 128 ** 0.5
@@ -481,7 +486,9 @@ def test_rope_pairs_compiles_for_v5e_at_kananas_q(one_chip, no_compile_cache,
     pytest.param(1, 16384, 48, 8, 64, True, ((512, 768), (512, 512)),
                  id="laguna-full-half-a-head-under-yarn"),
     pytest.param(1, 4096, 16, 16, 0, None, ((512, 512), (512, 512)),
-                 id="ouro-one-sequence")])
+                 id="ouro-one-sequence"),
+    pytest.param(1, 16384, 28, 4, 0, None, ((512, 512), (512, 512)),
+                 id="smallthinker-window-28-on-4")])
 def test_rope_halves_compiles_for_v5e_in_the_projection(
         one_chip, no_compile_cache, monkeypatch, B, T, nh, nkv, rot, yarn,
         blocks):
@@ -841,7 +848,9 @@ EXPERT_CALLS = {
     "olmoe-1b-7b.w1": (262144, 64, 2048, 1024),
     "kanana-2-30b-a3b.w1": (196608, 16, 2048, 768),
     "keye-vl-2.0-30b-a3b.w1": (262144, 16, 2048, 768),
-    "laguna-xs.2.w1": (131072, 32, 2048, 512)}
+    "laguna-xs.2.w1": (131072, 32, 2048, 512),
+    "smallthinker-21b-a3b.w1": (98304, 16, 2560, 768),
+    "smallthinker-21b-a3b.w2": (98304, 16, 768, 2560)}
 
 
 @pytest.mark.parametrize("product", ["forward", "dx", "dw"])
