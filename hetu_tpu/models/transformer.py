@@ -43,7 +43,8 @@ from ..kernels import rope as rope_kernel
 from ..kernels import ssd as ssd_kernel
 from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  REMAT_ATTN_V, REMAT_CANDIDATES,
-                                 REMAT_DSA_GRADS, REMAT_MLA_LATENT,
+                                 REMAT_DSA_GRADS, REMAT_DSA_MASK,
+                                 REMAT_MLA_LATENT,
                                  REMAT_NORM1_IN, REMAT_NORM2_IN, REMAT_X1,
                                  REMAT_X2,
                                  SCOPE_ATTN_GATE, SCOPE_ATTN_ROPE,
@@ -1432,7 +1433,13 @@ def _dsa_parts(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     kept set one bit a pair and compute every tile below the diagonal (a
     dense kernel under a mask); off the chip the ``dot`` path adds the same
     mask as a bias. With ``top_k`` >= T the kept set is the causal triangle
-    and the output is ``_attention``'s."""
+    and the output is ``_attention``'s. The kept set by query bears
+    ``REMAT_DSA_MASK`` and the one by key is made from it here
+    (``dsa.by_key_of``: only ``flash_bwd_dqkv`` reads it, so the forward pass
+    never builds it): under the trunk's checkpoint, where ``_remat_names``
+    admits the name, the backward pass reads the forward pass's own bits,
+    turns them by key, and runs the indexer and the selection no second time
+    (without the policy the name is an identity)."""
     from ..kernels import dsa
     from ..kernels.flash_attention import (flash_attention_btd,
                                            unpack_row_mask)
@@ -1453,7 +1460,13 @@ def _dsa_parts(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     with jax.named_scope(SCOPE_DSA_PROJ):
         qI, kI, w = _dsa_index(h, p, cfg)
     with jax.named_scope(SCOPE_DSA_SELECT):
-        row_mask, kept = dsa.select(qI, kI, w, cfg.dsa.top_k)
+        (by_query, _), kept = dsa.select(qI, kI, w, cfg.dsa.top_k)
+        # by name: where the checkpoint keeps the bits (`_remat_names`) the
+        # backward pass turns THEM by key and runs nothing of the indexer
+        # again. `by_key` has to come from the named array: from the
+        # unnamed one, the backward pass would need the selection to make it
+        by_query = checkpoint_name(by_query, REMAT_DSA_MASK)
+        row_mask = (by_query, dsa.by_key_of(by_query))
     with jax.named_scope(SCOPE_BLK_ATTN):
         if impl == "flash":
             out, lse = flash_attention_btd((q, k, v), nh, True,
@@ -2624,7 +2637,20 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     indexer's loss, the gradient of its five leaves (their own bytes a layer,
     counted in the bytes held), and a residual is held either way: unnamed,
     the recomputation makes it again, the loss and all. Keeping it costs
-    nothing of what the budget counts."""
+    nothing of what the budget counts.
+
+    It admits ``REMAT_DSA_MASK`` next, the selection's kept set packed by
+    query (``_dsa_parts``; every dsa application x B x T x T / 32 words,
+    counted in the bytes held: the ordered candidates see them taken), while
+    the bytes held stay within the budget WITHOUT ``_block_residual_bytes``:
+    the limit less ``_REMAT_MARGIN``, ``_state_bytes`` and the layer inputs.
+    That estimate reads 8.8 GiB for keye's block, where the step's own peak on
+    the v5e (14.60 of 15.75 GiB, PERF.md, PR 48) leaves the block's working
+    set and the head's 7.2 together; and the bits save ~900 ms of the step a
+    GiB kept, above every ordered candidate (the selection, the index scores
+    and the indexer's projections run once a layer; PERF.md, PR 64). When the
+    estimate is made true (ROADMAP S2.2(b)), both dsa names join the ordered
+    loop."""
     if bytes_limit is None:
         bytes_limit = _device_bytes_limit()
     if bytes_limit is None:
@@ -2644,9 +2670,12 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
     # one run's stacked weights stand for its kind's shapes
     by_kind = {kind: blocks for (kind, _), blocks in zip(
         layer_runs(cfg), run_blocks(cfg, params["blocks"]))}
+    # the limit less what the step holds whatever is kept: the state and the
+    # layer inputs; then a block's working set
+    room = (bytes_limit * (1 - _REMAT_MARGIN)
+            - _state_bytes(cfg, params, mesh) - applications * by_seq)
     budget = int(
-        bytes_limit * (1 - _REMAT_MARGIN) - _state_bytes(cfg, params, mesh)
-        - applications * by_seq
+        room
         # activations carry the batch: dp cuts them, and maybe more
         - max(_block_residual_bytes(cfg, mesh, h, blocks, attn_bias, kind)
               for kind, blocks in by_kind.items()) // dp)
@@ -2687,13 +2716,21 @@ def _remat_names(cfg: TransformerConfig, params, h, mesh, attn_bias=None,
              applications * 2 * by_seq if cfg.sandwich_norm else 0)
     order = (REMAT_CANDIDATES[:2] + ((REMAT_MLA_LATENT,),)
              + REMAT_CANDIDATES[2:])
-    names, held = (), 0
+    names, held, dsa = (), 0, 0
     for kind, n in layer_runs(cfg):
         if mixer_of(kind) == "dsa":
             names = (REMAT_DSA_GRADS,)
+            dsa += cfg.n_loops * n
             held += cfg.n_loops * n * sum(
                 leaf.size // leaf.shape[0] * leaf.dtype.itemsize
                 for leaf in map(by_kind[kind].get, DSA_LEAVES))
+    if dsa:
+        from ..kernels.flash_attention import mask_planes
+        # every dsa application's kept set by query: a bit a (query, key)
+        # pair, in int32 words
+        bits = dsa * B * T * (T // mask_planes(T)) * 4 // dp
+        if held + bits <= room:
+            names, held = names + (REMAT_DSA_MASK,), held + bits
     for candidate, cost in zip(order, costs):
         if not cost:
             continue

@@ -263,6 +263,26 @@ REMAT_MLA_LATENT = "hetu_mla_latent"
 # `recompute_ms_per_step` 636.3 -> 428.3, `hetu_dsa_loss` 194.3 -> 101.8 ms a
 # step, `tokens_per_s` +6.2 %, the step's own peak 14.74 -> 14.60 GiB
 REMAT_DSA_GRADS = "hetu_dsa_idx_grads"
+# learned sparse attention's kept set (`transformer._dsa_parts`): the first
+# array of the `pack_row_mask` pair `kernels/dsa.select` returns, one bit a
+# (query, key) pair BY QUERY. The selection has no gradient and nothing else
+# in the backward pass reads the indexer, so with these bits kept (and the
+# name above) the recomputation runs nothing of `hetu_dsa_index_proj` or
+# `hetu_dsa_index_scores` and, of `hetu_dsa_select`, only `dsa.by_key_of`:
+# the recomputed `flash_fwd` reads the forward pass's own bits and
+# `flash_bwd_dqkv` the same bits turned by key, which `_dsa_parts` makes FROM
+# THE NAMED ARRAY (made from the unnamed one, the backward pass needs the
+# whole selection again: 0.25 GiB kept, nothing saved; PERF.md, PR 64). B x T
+# x T / 32 int32 words a layer, 64 MiB at 2 x 16,384 tokens, 0.25 GiB over
+# keye's four layers. A stack with a dsa layer admits it right after the
+# gradients' name and before `REMAT_CANDIDATES`, counted in the bytes held,
+# while the device's limit less state and layer inputs has room
+# (`transformer._remat_names`). On the v5e, keye's four layers (PERF.md, PR
+# 64): `recompute_ms_per_step` 390.4 -> 243.3, `fwd_ms_per_step` 722.1 ->
+# 655.2, `tokens_per_s` +14.7 %, the step's own peak 14.07 -> 14.29 GiB.
+# Keeping the PAIR (0.5 GiB) moves `by_key_of` into the forward pass and the
+# forward loop out of VMEM: +7.2 % only
+REMAT_DSA_MASK = "hetu_dsa_mask"
 # host spans inside SubExecutor.run, children of STEP, in call order
 (BOUNDARY, FEED, DL_WAIT, PS_PULL, BUILD, DISPATCH, PREFETCH, PS_PUSH,
  POSTSTEP) = STEP_SPANS = (

@@ -47,7 +47,13 @@ LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
 # smallthinker's is of ISSUE 63's own tree, the PR that added the cell (its
 # parent, 43cc042, has no loader for it): the nine lines above it, unedited,
 # say that `Router.input`, `mlp="reglu"` and the window kind's own rotary
-# flag changed no program that existed.
+# flag changed no program that existed. ISSUE 64 (the selection's packed bits
+# kept by name, `tracing.REMAT_DSA_MASK`) expected to pin keye's line again
+# and did not have to: the CPU reports no limit, so the step lowered here
+# takes the bare checkpoint, and a `checkpoint_name` lowers to nothing
+# (fc6934dddd62afe8, 6636 lines on both sides). What the name changes where a
+# limit is reported is held by `test_keye_model.py::
+# test_indexer_loss_and_every_gradient_under_remat`, in the jaxpr.
 PARENT = {
     ("bert-base", "pretrain-seq512"):
         (("74ac9d1c59c8f61d", 2415), "0ca3cf6cdc80eded"),

@@ -262,22 +262,32 @@ def _rematted_ops(jaxpr, scope):
     return n
 
 
-@pytest.mark.parametrize("remat,limit,loss_again", [
-    pytest.param(False, None, False, id="no-remat"),
+@pytest.mark.parametrize("remat,limit,names", [
+    pytest.param(False, None, (), id="no-remat"),
     # the CPU reports no limit: the bare checkpoint runs the chain again
-    pytest.param(True, None, True, id="bare-checkpoint"),
-    # any limit, one that leaves no budget too: the leaves' gradient is kept
-    # by name, the chain runs once
-    pytest.param(True, 4096, False, id="checkpoint-keeps-the-gradient"),
+    pytest.param(True, None, (), id="bare-checkpoint"),
+    # room for the leaves' gradient (any limit has) and, over state and the
+    # layer inputs, for the selection's packed bits (2 layers x 2 x 32 x 32
+    # words = 16 KiB): the chain and the selection run once
+    pytest.param(True, 5 << 18, (tracing.REMAT_DSA_GRADS,
+                                 tracing.REMAT_DSA_MASK),
+                 id="checkpoint-keeps-the-gradient"),
+    # 16.3 KiB over state and inputs: the gradient's 11 KiB, not the bits'
+    # 16 more. The selection IS run again
+    pytest.param(True, 1000 << 10, (tracing.REMAT_DSA_GRADS,),
+                 id="checkpoint-has-no-room-for-the-bits"),
 ])
 def test_indexer_loss_and_every_gradient_under_remat(monkeypatch, remat,
-                                                     limit, loss_again):
+                                                     limit, names):
     """L_I and the gradient of every leaf, the indexer's five and the
     trunk's, against AUTODIFF of the loss's own expressions (the rule taken
     off: ``indexer_loss``'s primal), with and without the trunk's checkpoint;
     and what the checkpoint runs again: the loss's whole chain when bare,
-    nothing under ``hetu_dsa_loss`` once the name is kept (the selection's
-    projections and index scores it still runs: the flash call's mask)."""
+    nothing under ``hetu_dsa_loss`` once the gradient's name is kept, and
+    nothing of the indexer at all (its projections, the index scores, the
+    selection) once the packed kept set is kept too (the ``dot`` path reads
+    it by query alone; the flash path's ``by_key_of``: the next test): where
+    the limit has no room for the bits, all three still run for the mask."""
     cfg = hf_keye.config_from_hf(HF)
     params = _params(cfg)
     tokens, targets = seeded_tokens(HF, 5)
@@ -293,9 +303,10 @@ def test_indexer_loss_and_every_gradient_under_remat(monkeypatch, remat,
             value, has_aux=True), static_argnums=1)(params, cfg)
     monkeypatch.setattr(tfm, "_device_bytes_limit", lambda: limit)
     run = dataclasses.replace(cfg, remat=remat)
-    names, held, budget = tfm._remat_names(
+    got_names, held, budget = tfm._remat_names(
         run, params, tfm.embed_tokens(params, tokens, cfg), None)
-    assert names == ((tracing.REMAT_DSA_GRADS,) if limit else ())
+    assert got_names == names
+    # no ordered candidate rides along: the budget they are held to is spent
     assert not limit or budget < 0 < held
     (_, index), got = jax.jit(jax.value_and_grad(
         value, has_aux=True), static_argnums=1)(params, run)
@@ -310,10 +321,45 @@ def test_indexer_loss_and_every_gradient_under_remat(monkeypatch, remat,
         if name in tfm.DSA_LEAVES:
             assert float(jnp.abs(w).max()) > 1e-3, name
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: value(p, run)[0]))(params)
-    again = {scope: _rematted_ops(jaxpr.jaxpr, scope)
-             for scope in (tracing.SCOPE_DSA_LOSS, tracing.SCOPE_DSA_SELECT)}
-    assert (again[tracing.SCOPE_DSA_LOSS] > 0) == loss_again, again
-    assert (again[tracing.SCOPE_DSA_SELECT] > 0) == remat, again
+    again = {scope: _rematted_ops(jaxpr.jaxpr, scope) for scope in (
+        tracing.SCOPE_DSA_LOSS, tracing.SCOPE_DSA_SELECT,
+        tracing.SCOPE_DSA_PROJ, tracing.SCOPE_DSA_SCORES,
+        tracing.SCOPE_BLK_ATTN)}
+    assert (again[tracing.SCOPE_DSA_LOSS] > 0) == (remat and not names), again
+    for scope in (tracing.SCOPE_DSA_SELECT, tracing.SCOPE_DSA_PROJ,
+                  tracing.SCOPE_DSA_SCORES):
+        assert (again[scope] > 0) == (
+            remat and tracing.REMAT_DSA_MASK not in names), again
+    # the attention itself the backward pass always runs again
+    assert (again[tracing.SCOPE_BLK_ATTN] > 0) == remat, again
+
+
+def test_the_backward_kernels_mask_is_made_from_the_kept_bits(monkeypatch):
+    """On the flash path the backward kernel reads the kept set BY KEY, which
+    nothing in the forward pass does: with the bits by query kept by name,
+    the recomputation makes it from THEM (``by_key_of`` under
+    ``hetu_dsa_select``) and evaluates no index score and no projection. Made
+    from the unnamed array instead, it would take the whole selection again
+    (my chip run, PR 64: 0.25 GiB kept and nothing saved)."""
+    monkeypatch.setattr(dsa, "ROWS", 128)
+    cfg = dataclasses.replace(
+        hf_keye.config_from_hf(SHARE), attn_impl="flash", remat=True,
+        dsa=tfm.DSAConfig(n_heads=4, head_dim=8, top_k=32))
+    params = _params(cfg)
+    tokens, targets = seeded_tokens(SHARE, 2, T=256)
+    monkeypatch.setattr(tfm, "_device_bytes_limit", lambda: 1 << 30)
+    names, _, _ = tfm._remat_names(
+        cfg, params, tfm.embed_tokens(params, tokens, cfg), None)
+    assert names[:2] == (tracing.REMAT_DSA_GRADS, tracing.REMAT_DSA_MASK)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: tfm.loss_fn(p, tokens, targets, cfg)))(params)
+    again = {scope: _rematted_ops(jaxpr.jaxpr, scope) for scope in (
+        tracing.SCOPE_DSA_SELECT, tracing.SCOPE_DSA_PROJ,
+        tracing.SCOPE_DSA_SCORES, tracing.SCOPE_DSA_LOSS)}
+    assert again[tracing.SCOPE_DSA_SELECT] > 0, again
+    for scope in (tracing.SCOPE_DSA_PROJ, tracing.SCOPE_DSA_SCORES,
+                  tracing.SCOPE_DSA_LOSS):
+        assert again[scope] == 0, again
 
 
 def test_top_k_of_all_keys_is_the_dense_attention_kind_bit_for_bit():

@@ -18,8 +18,9 @@ from hetu_tpu.parallel import mesh as meshlib
 from hetu_tpu.telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_LSE,
                                         REMAT_ATTN_O, REMAT_ATTN_Q,
                                         REMAT_ATTN_V, REMAT_CANDIDATES,
-                                        REMAT_DSA_GRADS, REMAT_NORM1_IN,
-                                        REMAT_NORM2_IN, REMAT_X1, REMAT_X2)
+                                        REMAT_DSA_GRADS, REMAT_DSA_MASK,
+                                        REMAT_NORM1_IN, REMAT_NORM2_IN,
+                                        REMAT_X1, REMAT_X2)
 
 from model_harness import sub_jaxprs
 from test_transformer import tiny_cfg
@@ -30,6 +31,8 @@ ALL = (REMAT_X1, REMAT_X2, REMAT_ATTN_O, REMAT_ATTN_LSE)
 # sandwich norms add (PR 36): BERT writes neither
 QKV = (REMAT_ATTN_Q, REMAT_ATTN_K, REMAT_ATTN_V)
 SANDWICH = (REMAT_NORM1_IN, REMAT_NORM2_IN)
+# a dsa stack's own two, ahead of the ordered ones (PRs 48 and 64)
+DSA = (REMAT_DSA_GRADS, REMAT_DSA_MASK)
 EVERY = ALL + QKV + SANDWICH
 
 
@@ -137,17 +140,26 @@ def _rule(model, batch, seq, dp, limit_gib, bias=True):
     pytest.param(_ouro, 1, 4096, 1, None, (), (0, 0), id="ouro-no-limit"),
     pytest.param(_bert, 128, 512, 1, None, (), (0, 0), id="bert-no-limit"),
     pytest.param(_olmoe, 8, 4096, 1, None, (), (0, 0), id="olmoe-no-limit"),
-    # a dsa stack keeps its indexers' gradient at ANY limit, the v5e's, whose
-    # budget reads negative here, and one of no room at all: four layers x
-    # (2,048 x (1,024 + 64 + 16) + 2 x 64) float32 = 34.5 MiB, and nothing
-    # else until the budget has room: then four x1 of 128 MiB, o + lse (256
-    # + 4) and q, k, v (256 + 2 x 32) a layer behind it
-    pytest.param(_keye, 2, 16384, 1, 15.75, (REMAT_DSA_GRADS,),
-                 (0.03369, 0.0337), id="keye-seq16384-v5e-limit"),
+    # a dsa stack keeps its indexers' gradient at ANY limit: four layers x
+    # (2,048 x (1,024 + 64 + 16) + 2 x 64) float32 = 34.5 MiB. The selection's
+    # bits packed by query next (PR 64), four layers x 2 x 16,384 x 512 words
+    # = 0.25 GiB, while the limit less its margin, 6.94 GiB of state and four
+    # layer inputs of 128 MiB has room: the v5e's has 7.8 GiB, though the
+    # budget the ordered names are held to reads negative there; 1 GiB has
+    # none; 7.9 GiB has 0.21, the gradients' 0.03 and not 0.25 more. The
+    # ordered names behind them see the bits taken: four x1 of 128 MiB, o +
+    # lse (256 + 4) and q, k, v (256 + 2 x 32) a layer; at 17.5 GiB the
+    # budget's 0.70 would hold x1's 0.5 without the bits and does not with
+    pytest.param(_keye, 2, 16384, 1, 15.75, DSA, (0.2836, 0.2838),
+                 id="keye-seq16384-v5e-limit"),
     pytest.param(_keye, 2, 16384, 1, 1, (REMAT_DSA_GRADS,),
                  (0.03369, 0.0337), id="keye-seq16384-no-room"),
-    pytest.param(_keye, 2, 16384, 1, 24, (REMAT_DSA_GRADS,) + ALL + QKV,
-                 (2.799, 2.8), id="keye-seq16384-room"),
+    pytest.param(_keye, 2, 16384, 1, 7.9, (REMAT_DSA_GRADS,),
+                 (0.03369, 0.0337), id="keye-seq16384-no-room-for-the-bits"),
+    pytest.param(_keye, 2, 16384, 1, 17.5, DSA, (0.2836, 0.2838),
+                 id="keye-seq16384-bits-ahead-of-x1"),
+    pytest.param(_keye, 2, 16384, 1, 24, DSA + ALL + QKV, (3.049, 3.05),
+                 id="keye-seq16384-room"),
     pytest.param(_keye, 2, 16384, 1, None, (), (0, 0), id="keye-no-limit"),
 ])
 def test_remat_names_by_bytes(model, batch, seq, dp, limit_gib, names,
@@ -157,11 +169,21 @@ def test_remat_names_by_bytes(model, batch, seq, dp, limit_gib, names,
     assert got == names
     assert held_gib[0] * GiB <= held <= held_gib[1] * GiB
     if model is _keye:
-        # the one name no budget is asked for: on a full chip it is alone
-        assert REMAT_DSA_GRADS not in sum(REMAT_CANDIDATES, ())
-        assert (budget < 0) == (names == (REMAT_DSA_GRADS,))
+        # the two names no budget is asked for: on a full chip they are alone
+        assert not set(DSA) & set(sum(REMAT_CANDIDATES, ()))
+        ordered = tuple(n for n in got if n not in DSA)
+        assert not ordered or 0 <= held <= budget
+        if limit_gib:
+            # the bits against the limit less state and layer inputs, the
+            # block's estimated residuals left out
+            cfg, params = model()
+            room = (limit_gib * GiB * (1 - tfm._REMAT_MARGIN)
+                    - tfm._state_bytes(cfg, params, None)
+                    - cfg.n_layers * batch * seq * cfg.d_model * 2)
+            grads = 4 * (2048 * (1024 + 64 + 16) + 2 * 64) * 4
+            assert (REMAT_DSA_MASK in got) == (grads + GiB // 4 <= room)
         return
-    assert REMAT_DSA_GRADS not in got
+    assert not set(DSA) & set(got)
     assert held <= max(budget, 0)
     if model is _olmoe and limit_gib:
         # not by a hair: the block's own residuals put it far under water
