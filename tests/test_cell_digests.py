@@ -43,7 +43,12 @@ LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
 # pinned again ONCE, by ISSUE 62, which changed it on purpose (`_gelu`'s exact
 # form: one float32 `erf` where `erfc` was; 5dd9818f8e559ca8, 2401 lines at
 # its parent fb731aa; the parameter tree's digest did not move): the other
-# eight lines, unedited, say that no other cell reaches `_gelu`.
+# eight lines, unedited, say that no other cell reaches `_gelu`. And ONCE more
+# by ISSUE 65, on purpose again (`_gelu`'s exact form under its own
+# derivative rule, which ends in a barrier over (u, GELU'), so that the
+# backward half evaluates `erf` once: 74ac9d1c59c8f61d, 2415 lines at its
+# parent feaa9f6; the tree's digest did not move): again the other lines,
+# smallthinker's with them, are unedited.
 # smallthinker's is of ISSUE 63's own tree, the PR that added the cell (its
 # parent, 43cc042, has no loader for it): the nine lines above it, unedited,
 # say that `Router.input`, `mlp="reglu"` and the window kind's own rotary
@@ -56,7 +61,7 @@ LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
 # test_indexer_loss_and_every_gradient_under_remat`, in the jaxpr.
 PARENT = {
     ("bert-base", "pretrain-seq512"):
-        (("74ac9d1c59c8f61d", 2415), "0ca3cf6cdc80eded"),
+        (("9e2f27a018dc2806", 2426), "0ca3cf6cdc80eded"),
     ("olmoe-1b-7b", "pretrain-seq4096"):
         (("c1c73dbf0bed4d29", 2575), "c2ddcd977285a1b3"),
     ("ouro-2.6b", "pretrain-seq4096-b1"):
@@ -147,12 +152,22 @@ def test_the_cells_tree_and_lowered_program_are_the_parents(cell):
 def test_berts_step_holds_one_erf_a_gelu_and_no_erfc():
     """The exact GELU engages as ONE `erf` wherever the step evaluates it:
     the trunk's layer, the layer again under `remat`, the MLM head's
-    transform (its derivative is autodiff's own, an exponential, and holds
-    no second `erf`). `erfc`, which has no HLO opcode and which the compiler
-    expands to some seventy vector operations an element, is gone (PR 62;
-    the compiled step's count on the chip is in PERF.md, section 5)."""
+    transform. `erfc`, which has no HLO opcode and which the compiler
+    expands to some seventy vector operations an element, is gone (PR 62).
+    Each of the three ends in a barrier over the pair (u, GELU') in the
+    compute dtype, the fourth barrier being `remat`'s own over the layer's
+    fourteen arguments: the backward pass reads the pair and derives no
+    `erf` of its own (PR 65; the forward pass drops its GELU' unread, the
+    head's is its saved residual; the compiled step's count on the chip is
+    in PERF.md, section 5)."""
     text, _ = _cell_step("bert-base", "pretrain-seq512")
     assert len(re.findall(r"chlo\.erfc\b", text)) == 0
     assert len(re.findall(r"chlo\.erf\b", text)) == 3
     assert all("xf32>" in line for line in text.splitlines()
                if "chlo.erf" in line)
+    barriers = [line.rstrip() for line in text.splitlines()
+                if "stablehlo.optimization_barrier" in line]
+    assert len(barriers) == 4
+    pair = lambda shape: f": tensor<{shape}xbf16>, tensor<{shape}xbf16>"
+    assert sum(b.endswith(pair("128x512x3072")) for b in barriers) == 2
+    assert sum(b.endswith(pair("128x80x768")) for b in barriers) == 1

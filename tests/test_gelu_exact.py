@@ -100,23 +100,57 @@ def test_other_dtypes_come_back_as_they_went_in(dtype, lo, atol, rtol):
                                atol=atol, rtol=rtol)
 
 
-@pytest.mark.parametrize("how", ["grad", "jvp", "grad-of-bf16"])
+@pytest.mark.parametrize("how", [
+    "grad", "jvp", "grad-of-bf16", "grad-under-checkpoint",
+    "grad-of-bf16-under-checkpoint"])
 def test_the_derivative_is_the_cdf_plus_x_times_the_density(how):
+    """Under `_gelu_erf`'s own rule (PR 65), which `jax.checkpoint` runs
+    again in the backward pass as the trunk's `remat` does."""
     x = np.linspace(-12.0, 12.0, 20001).astype(np.float32)
     want = _dgelu64(x.astype(np.float64))
     f = lambda v: tfm._gelu(v, EXACT)
-    if how == "grad":
+    if how.endswith("under-checkpoint"):
+        f = jax.checkpoint(f)
+    if how in ("grad", "grad-under-checkpoint"):
         got, tol = jax.vmap(jax.grad(f))(jnp.asarray(x)), 1e-5
     elif how == "jvp":
         got, tol = jax.jvp(f, (jnp.asarray(x),), (jnp.ones_like(x),))[1], 1e-5
     else:
-        # the cotangent is rounded to x's dtype on its way out
+        # GELU' is rounded once to x's dtype, and so is the cotangent
         xb = jnp.asarray(x).astype(jnp.bfloat16)
         want = _dgelu64(np.asarray(xb, np.float64))
         got = jax.grad(lambda v: jnp.sum(f(v).astype(jnp.float32)))(xb)
         assert got.dtype == jnp.bfloat16
         tol = 2.0 ** -8 * 1.2
     np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=tol)
+
+
+def test_the_value_beside_the_derivative_is_the_forwards_bit_for_bit():
+    """The rule's `u`, what a differentiated step's forward and recomputed
+    passes carry on, against the primal function, which is all that runs
+    outside differentiation: every bfloat16 in [-12, 12]."""
+    x = jnp.asarray(_every_bf16(-12.0, 12.0))
+    f = lambda v: tfm._gelu(v, EXACT)
+    u, _ = jax.jvp(f, (x,), (jnp.ones_like(x),))
+    assert u.dtype == jnp.bfloat16
+    assert (_bits(u) == _bits(f(x))).all()
+
+
+@pytest.mark.parametrize("how", ["jvp-of-grad", "grad-of-grad"])
+def test_a_second_derivative_goes_through_the_rule(how):
+    """The rule's own lines are plain jax and a barrier differentiates as
+    the identity, so a second order works (nothing here asks for one: it
+    must not be a silent zero): GELU'' is the density times 2 - x^2."""
+    x = np.linspace(-6.0, 6.0, 2001).astype(np.float32)
+    x64 = x.astype(np.float64)
+    want = np.exp(-x64 * x64 / 2) / math.sqrt(2 * math.pi) * (2 - x64 * x64)
+    g = jax.grad(lambda v: tfm._gelu(v, EXACT))
+    if how == "jvp-of-grad":
+        got = jax.vmap(lambda v: jax.jvp(g, (v,), (jnp.float32(1.0),))[1])(
+            jnp.asarray(x))
+    else:
+        got = jax.vmap(jax.grad(g))(jnp.asarray(x))
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=1e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -148,3 +182,37 @@ def test_what_the_form_lowers_to(exact, erf, erfc, tanh):
     assert len(re.findall(r"stablehlo\.tanh\b", text)) == tanh
     if exact:
         assert re.search(r"chlo\.erf .*tensor<8x128xf32>", text)
+
+
+@pytest.mark.parametrize("exact,erf,tanh", [
+    pytest.param(True, 1, 0, id="exact"),
+    pytest.param(False, 0, 1, id="tanh"),
+])
+def test_the_backward_half_of_an_mlp_evaluates_the_gelu_once(exact, erf,
+                                                              tanh):
+    """`jax.grad(jax.checkpoint(...))` of w2' gelu(w1 x), the trunk's MLP
+    under `remat`: ONE float32 `erf`, in the recomputed pass, whose rule ends
+    in a barrier over the pair (u, GELU'), both in x's dtype, so that the
+    `w2` weight gradient and dU read them and derive no `erf` of their own
+    (PR 65; the compiled step's fusions on the chip are in PERF.md). The
+    checkpoint's own barrier is the one over its three arguments. The tanh
+    form is autodiff's, with no rule and no barrier of its own."""
+    cfg = tfm.TransformerConfig(gelu_exact=exact)
+
+    def loss(x, w1, w2):
+        return jnp.sum((tfm._gelu(x @ w1, cfg) @ w2).astype(jnp.float32))
+
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    text = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))).lower(
+        bf16(8, 128), bf16(128, 256), bf16(256, 128)).as_text()
+    assert len(re.findall(r"chlo\.erf\b", text)) == erf
+    assert len(re.findall(r"chlo\.erfc\b", text)) == 0
+    assert len(re.findall(r"stablehlo\.tanh\b", text)) == tanh
+    barriers = [line for line in text.splitlines()
+                if "stablehlo.optimization_barrier" in line]
+    assert len(barriers) == 1 + erf
+    assert sum(line.rstrip().endswith(
+        ": tensor<8x256xbf16>, tensor<8x256xbf16>")
+        for line in barriers) == erf
+    if exact:
+        assert re.search(r"chlo\.erf .*tensor<8x256xf32>", text)
