@@ -34,6 +34,10 @@ import numpy as np
 # Mosaic kernels appear in compiled HLO as this custom-call target; an
 # interpret-mode pallas_call lowers to plain HLO and never does
 _MOSAIC = "tpu_custom_call"
+# the experts' grouped matmul in a compiled program: the Pallas kernel of
+# `kernels/grouped_matmul.py` on every expert call since PR 59 (the compiler's
+# `ragged-dot` custom call before it, which three rows here still asked for)
+_GROUPED_MATMUL = "grouped_matmul"
 
 
 # ---------------------------------------------------------------------------
@@ -256,7 +260,7 @@ def _bert_batch(rng, cfg, batch, seq, n_pred, padded):
 def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                    ref_batch=2, mesh_axes=None, chip=True, name="flagship",
                    moe=None, moe_batch=2, mla=None, mla_batch=1, dsa=None,
-                   dsa_batch=1):
+                   dsa_batch=1, kda=None, kda_batch=1):
     """BERT pretrain steps on an unpadded then a padded batch; the loss
     must stay finite (at lr 1e-4 without warm-up AdamW's first steps
     overshoot at BERT-base size, so "falling" is not asked here). On one
@@ -266,7 +270,9 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
     (``_moe_row``) and the ``mla`` row one train step of a latent-attention
     model with a shared expert, ``mla`` or ``MLA_ROW`` (``_mla_row``), the
     ``dsa`` row one of a model with learned sparse attention, ``dsa`` or
-    ``DSA_ROW`` (``_dsa_row``)."""
+    ``DSA_ROW`` (``_dsa_row``), the ``kda`` row one of a model with Kimi Delta
+    Attention beside unrotated latent attention, ``kda`` or ``KDA_ROW``
+    (``_kda_row``)."""
     import jax
     from hetu_tpu.kernels.fused_ce import should_fuse
     from hetu_tpu.models import bert
@@ -368,6 +374,7 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
             rec["moe"] = _moe_row(moe or MOE_ROW, moe_batch, chip)
             rec["mla"] = _mla_row(mla or MLA_ROW, mla_batch, chip)
             rec["dsa"] = _dsa_row(dsa or DSA_ROW, dsa_batch, chip)
+            rec["kda"] = _kda_row(kda or KDA_ROW, kda_batch, chip)
         rec.update({"model": "bert", "d_model": cfg.d_model,
                     "n_heads": cfg.n_heads, "n_layers": cfg.n_layers,
                     "d_ff": cfg.d_ff, "vocab": cfg.vocab_size, "seq": seq,
@@ -444,7 +451,7 @@ def _moe_row(sizes, batch, chip):
            "moe: picks do not sum to tokens x k")
     if chip:
         hlo = system.lower(params).compile().as_text()
-        _check("ragged-dot" in hlo and _MOSAIC in hlo,
+        _check(_GROUPED_MATMUL in hlo and _MOSAIC in hlo,
                "moe: no grouped matmul custom call in the compiled layer")
     f32 = lambda a: np.asarray(a, np.float32)
     rel = lambda a, b: float(np.sqrt(np.mean((f32(a) - f32(b)) ** 2))
@@ -520,7 +527,7 @@ def _mla_row(sizes, batch, chip):
     if chip:
         hlo = step.as_text()
         _check(all(k in hlo for k in ("flash_fwd", "flash_bwd_dqkv",
-                                      "ragged-dot")),
+                                      _GROUPED_MATMUL)),
                "mla: a kernel is missing from the compiled step")
     loss, params, opt = step(params, opt, tokens, targets)
     _check(_finite(loss), f"mla: step loss {float(loss)}")
@@ -531,6 +538,82 @@ def _mla_row(sizes, batch, chip):
             "step_loss": round(float(loss), 5), "attn_impl": impl,
             "heads": cfg.n_heads, "qk_dim": cfg.mla.qk_dim,
             "v_dim": cfg.mla.v_dim, "d_ff_shared": cfg.d_ff_shared,
+            "held_picks": int(np.sum(stats["held"])),
+            "dropped_picks": dropped, "tokens": int(tokens.size)}
+
+
+# Kimi-Linear-48B-A3B's two kinds of mixer (models/hf_kimi_linear.py) at the
+# published widths: a KDA layer with the dense MLP (a quarter of its width)
+# and a latent-attention layer without rotation over 8 of 32 experts held, a
+# small vocabulary, 1,024 tokens (16 chunks of 64): the `kda` row of the
+# flagship phase
+KDA_ROW = dict(
+    hidden_size=2304, intermediate_size=2304, moe_intermediate_size=1024,
+    num_attention_heads=32, num_key_value_heads=32, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    mla_use_nope=True, first_k_dense_replace=1, num_hidden_layers=2,
+    linear_attn_config=dict(kda_layers=[1], full_attn_layers=[2],
+                            num_heads=32, head_dim=128,
+                            short_conv_kernel_size=4),
+    num_experts=8, num_routed_experts=32, first_expert_held=0,
+    num_shared_experts=1, num_experts_per_token=8, moe_renormalize=True,
+    routed_scaling_factor=2.446, rms_norm_eps=1e-5, vocab_size=1024,
+    model_max_length=1024)
+
+
+def _kda_row(sizes, batch, chip):
+    """One train step of a model with a Kimi Delta Attention layer and a
+    latent-attention layer that rotates nothing through `make_train_step`:
+    the chunked rule's output on the first layer's own inputs agrees with the
+    recurrence over positions in float64 (two heads), every value finite
+    where the cumulated log-decay is past float32's 1 / exp(G); the step's
+    loss is finite and no held pick is dropped."""
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.models import hf_kimi_linear, transformer as tfm
+    dtype = sizes.get("dtype", jnp.bfloat16)
+    cfg = hf_kimi_linear.config_from_hf(
+        {k: v for k, v in sizes.items() if k not in ("dtype", "kda_chunk")},
+        dtype=dtype, router_bias_rate=1e-2,
+        **({"kda_chunk": sizes["kda_chunk"]} if "kda_chunk" in sizes else {}))
+    params = tfm.init_params(jax.random.PRNGKey(3), cfg)
+    ids = jnp.asarray(np.random.RandomState(3).randint(
+        0, cfg.vocab_size, (batch, cfg.max_seq_len + 1)), jnp.int32)
+    tokens, targets = ids[:, :-1], ids[:, 1:]
+    heads = (0, cfg.kda.n_heads - 1)
+    t = jax.device_get(jax.jit(lambda p: tfm.kda_terms(
+        p, tokens, cfg, heads=heads))(params))
+    f64 = lambda x: np.asarray(x).astype(np.float64)[0]
+    q, k, v, g, beta = (f64(t[n]) for n in ("q", "k", "v", "g", "beta"))
+    S, want = np.zeros(q.shape[1:] + v.shape[-1:]), np.empty_like(v)
+    for i in range(q.shape[0]):
+        S *= np.exp(g[i])[..., None]
+        u = beta[i][:, None] * (v[i] - np.einsum("hkv,hk->hv", S, k[i]))
+        S += k[i][..., None] * u[:, None, :]
+        want[i] = np.einsum("hkv,hk->hv", S, q[i])
+    err = float(np.sqrt(np.mean((f64(t["o"]) - want) ** 2)
+                        / np.mean(want ** 2)))
+    _check(np.isfinite(err) and err <= 1e-4,
+           f"kda: the chunked rule is {err} from the recurrence")
+    stats = jax.jit(lambda p: tfm.moe_routing_stats(p, tokens, cfg))(params)
+    dropped = int(np.sum(stats["dropped"]))
+    _check(dropped == 0, f"kda: {dropped} dropped picks")
+    opt = tfm.init_opt_state(params)
+    step = tfm.make_train_step(cfg, lr=3e-6).lower(
+        params, opt, tokens, targets).compile()
+    if chip:
+        hlo = step.as_text()
+        _check(all(k in hlo for k in ("flash_fwd", "hetu_kda_scan",
+                                      "hetu_kda_solve")),
+               "kda: a kernel or a scope is missing from the compiled step")
+    loss, params, opt = step(params, opt, tokens, targets)
+    _check(_finite(loss), f"kda: step loss {float(loss)}")
+    return {"step_loss": round(float(loss), 5),
+            "scan_rel_rms_err_vs_f64": float(f"{err:.3g}"),
+            "chunk_log_decay_min": round(
+                float(t["chunk_log_decay_min"]), 2),
+            "heads": cfg.kda.n_heads, "head_dim": cfg.kda.head_dim,
+            "chunk": cfg.kda.chunk, "rotate": cfg.mla.rotate,
             "held_picks": int(np.sum(stats["held"])),
             "dropped_picks": dropped, "tokens": int(tokens.size)}
 
@@ -591,7 +674,7 @@ def _dsa_row(sizes, batch, chip):
     if chip:
         hlo = step.as_text()
         _check(all(k in hlo for k in ("flash_fwd", "flash_bwd_dqkv",
-                                      "ragged-dot")),
+                                      _GROUPED_MATMUL)),
                "dsa: a kernel is missing from the compiled step")
     loss, params, opt = step(params, opt, tokens, targets)
     _check(_finite(loss), f"dsa: step loss {float(loss)}")

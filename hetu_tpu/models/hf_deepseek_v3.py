@@ -41,9 +41,12 @@ from collections.abc import Mapping
 import numpy as np
 import jax.numpy as jnp
 
-from .hf_common import np_f32, tree_to_jnp
-from .transformer import (ROUTER_BIAS, MLAConfig, Router, TransformerConfig,
-                          blocks_of_runs, experts_of, run_blocks, run_layers)
+from .hf_common import (MLA_KV_B as KV_B, MLA_KV_NORM as KV_NORM,
+                        MLA_LINEARS as ATTN_LINEARS, mla_from_hf,
+                        mla_leaves_from_hf, mla_leaves_to_hf, np_f32,
+                        sigmoid_router_from_hf, tree_to_jnp)
+from .transformer import (ROUTER_BIAS, TransformerConfig, blocks_of_runs,
+                          experts_of, run_blocks, run_layers)
 
 
 def config_from_hf(hf_config, **overrides) -> TransformerConfig:
@@ -55,17 +58,11 @@ def config_from_hf(hf_config, **overrides) -> TransformerConfig:
     config.json)."""
     c = (hf_config if isinstance(hf_config, Mapping)
          else hf_config.to_dict())
-    for key in ("q_lora_rank", "rope_scaling", "attention_bias",
-                "attention_dropout"):
+    for key in ("rope_scaling", "attention_bias", "attention_dropout"):
         if c.get(key):
             raise NotImplementedError(
-                f"{key}={c[key]!r}: the trunk has no such path (a low-rank "
-                "q projection, scaled rotary frequencies, projection biases)")
-    if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
-        raise NotImplementedError(
-            f"n_group={c.get('n_group')}, topk_group={c.get('topk_group')}: "
-            "group-limited selection (the picks from the best groups of "
-            "experts only) is not written; 1 and 1 make it the identity")
+                f"{key}={c[key]!r}: the trunk has no such path (scaled "
+                "rotary frequencies, projection biases)")
     if c.get("moe_layer_freq", 1) != 1 or c.get(
             "scoring_func", "sigmoid") != "sigmoid" or not c.get(
             "rope_interleave", True) or c.get("hidden_act", "silu") != "silu":
@@ -76,21 +73,13 @@ def config_from_hf(hf_config, **overrides) -> TransformerConfig:
             "layers after the leading dense ones, every one; sigmoid "
             "scores; interleaved rotary columns; SiLU")
     heads = c["num_attention_heads"]
-    if c.get("num_key_value_heads", heads) != heads:
-        raise NotImplementedError(
-            f"num_key_value_heads={c['num_key_value_heads']}: latent "
-            "attention's keys and values are every head's own")
-    mla = MLAConfig(kv_rank=c["kv_lora_rank"],
-                    nope_dim=c["qk_nope_head_dim"],
-                    rope_dim=c["qk_rope_head_dim"], v_dim=c["v_head_dim"])
-    if c.get("qk_head_dim", mla.qk_dim) != mla.qk_dim:
-        raise NotImplementedError(
-            f"qk_head_dim={c['qk_head_dim']} is not qk_nope_head_dim + "
-            f"qk_rope_head_dim = {mla.qk_dim}")
+    mla = mla_from_hf(c)
     held = c["n_routed_experts"]
-    width = c.get("num_routed_experts", held)
     layers = c["num_hidden_layers"]
-    bias_rate = overrides.pop("router_bias_rate", 0.0)
+    router = sigmoid_router_from_hf(
+        c, groups=("n_group", "topk_group"), held=held,
+        normalize=c.get("norm_topk_prob", True),
+        bias_rate=overrides.pop("router_bias_rate", 0.0))
     kw = dict(
         vocab_size=c["vocab_size"], d_model=c["hidden_size"], n_heads=heads,
         n_layers=layers, d_ff=c["intermediate_size"],
@@ -103,15 +92,7 @@ def config_from_hf(hf_config, **overrides) -> TransformerConfig:
         rope_theta=float(c.get("rope_theta", 1e4)), mlp="swiglu",
         use_pos_emb=False, causal=True,
         tied_head=bool(c.get("tie_word_embeddings", False)),
-        layer_types=("mla",) * layers, mla=mla,
-        router=Router(
-            score="sigmoid", bias=True,
-            normalize=bool(c.get("norm_topk_prob", True)),
-            normalize_eps=1e-20,
-            scale=float(c.get("routed_scaling_factor", 1.0)),
-            aux_losses=False, bias_rate=bias_rate,
-            width=0 if width == held else width,
-            first_held=c.get("first_expert_held", 0)),
+        layer_types=("mla",) * layers, mla=mla, router=router,
         dtype=jnp.float32)
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -121,10 +102,6 @@ def config_from_hf(hf_config, **overrides) -> TransformerConfig:
 # a norm's scale (1-D, as it is), a Linear (transposed to (in, out))
 NORMS = {"ln1_scale": "input_layernorm.weight",
          "ln2_scale": "post_attention_layernorm.weight"}
-ATTN_LINEARS = {"wq": "self_attn.q_proj.weight",
-                "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
-                "wo": "self_attn.o_proj.weight"}
-KV_NORM, KV_B = "self_attn.kv_a_layernorm.weight", "self_attn.kv_b_proj.weight"
 MLP = {"w1": "gate_proj.weight", "w3": "up_proj.weight",
        "w2": "down_proj.weight"}
 SHARED = {"ws1": "w1", "ws3": "w3", "ws2": "w2"}
@@ -146,16 +123,6 @@ def shared_name(i, w):
     return hf_name(i, f"mlp.shared_experts.{MLP[w]}")
 
 
-def _kv_b_columns(cfg: TransformerConfig):
-    """The columns of HF's ``kv_b_proj`` output (a head [k_nope | v], the
-    heads side by side) in the trunk's order: [every head's k_nope | every
-    head's v]."""
-    m, nh = cfg.mla, cfg.n_heads
-    cols = np.arange(nh * (m.nope_dim + m.v_dim)).reshape(nh, -1)
-    return np.concatenate([cols[:, :m.nope_dim].reshape(-1),
-                           cols[:, m.nope_dim:].reshape(-1)])
-
-
 def params_from_state_dict(sd, cfg: TransformerConfig, xp=np):
     """HF-named arrays (``DeepseekV3ForCausalLM.state_dict()`` names, with or
     without the ``model.`` scope; numpy or jax arrays; an expert's index the
@@ -164,7 +131,6 @@ def params_from_state_dict(sd, cfg: TransformerConfig, xp=np):
     sd = {(k if k.startswith(("model.", "lm_head.")) else "model." + k): v
           for k, v in sd.items()}
     D, first = cfg.d_model, cfg.router.first_held
-    kv_b = _kv_b_columns(cfg)
     runs = []
     for kind, layers in run_layers(cfg):
         stack = lambda part, f=lambda w: w: xp.stack(
@@ -175,10 +141,7 @@ def params_from_state_dict(sd, cfg: TransformerConfig, xp=np):
             blocks[name] = stack(part)
             blocks[name[:-len("scale")] + "bias"] = xp.zeros(
                 (n, D), xp.float32)                  # unused (rmsnorm)
-        for name, part in ATTN_LINEARS.items():
-            blocks[name] = stack(part, lambda w: w.T)
-        blocks["kv_norm"] = stack(KV_NORM)
-        blocks["wkv_b"] = stack(KV_B, lambda w: w.T[:, kv_b])
+        blocks.update(mla_leaves_from_hf(stack, cfg))
         if E:
             F = cfg.d_ff_expert or cfg.d_ff
             for w in MLP:
@@ -220,7 +183,6 @@ def state_dict_from_params(params, cfg: TransformerConfig):
     -> HF-named arrays (of whatever array type ``params`` holds). Of a share
     only the experts held exist, under the model's indices."""
     first = cfg.router.first_held
-    back = np.argsort(_kv_b_columns(cfg))
     sd = {"model.embed_tokens.weight": params["embed"],
           "model.norm.weight": params["lnf_scale"],
           "lm_head.weight": (params["embed"] if cfg.tied_head
@@ -230,10 +192,8 @@ def state_dict_from_params(params, cfg: TransformerConfig):
         for j, i in enumerate(layers):
             for name, part in NORMS.items():
                 sd[hf_name(i, part)] = b[name][j]
-            for name, part in ATTN_LINEARS.items():
-                sd[hf_name(i, part)] = b[name][j].T
-            sd[hf_name(i, KV_NORM)] = b["kv_norm"][j]
-            sd[hf_name(i, KV_B)] = b["wkv_b"][j][:, back].T
+            for part, w in mla_leaves_to_hf(b, j, cfg).items():
+                sd[hf_name(i, part)] = w
             if experts_of(cfg, kind):
                 for e in range(cfg.n_experts):
                     for w in MLP:

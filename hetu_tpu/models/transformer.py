@@ -53,7 +53,8 @@ from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_DSA_LOSS,
                                  SCOPE_DSA_PROJ, SCOPE_DSA_SELECT,
                                  SCOPE_EMBED, SCOPE_EXIT, SCOPE_FWD,
-                                 SCOPE_HEAD,
+                                 SCOPE_HEAD, SCOPE_KDA_CONV, SCOPE_KDA_GATE,
+                                 SCOPE_KDA_PROJ, SCOPE_KDA_SCAN,
                                  SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP,
                                  SCOPE_MLA_Q, SCOPE_MOE_ACT, SCOPE_MOE_COMBINE,
                                  SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
@@ -143,10 +144,38 @@ class MLAConfig:
     nope_dim: int = 128
     rope_dim: int = 64
     v_dim: int = 128
+    rotate: bool = True         # False (Kimi Linear's ``mla_use_nope``):
+                                # NOTHING is rotated; the ``rope_dim`` columns
+                                # stay in the product, q's own and the one key
+                                # a token every head's, without position
 
     @property
     def qk_dim(self):
         return self.nope_dim + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """The sizes of a Kimi Delta Attention mixer (Kimi Linear,
+    arXiv:2510.26692; ``_kda``, ``models/kda.py``): ``n_heads`` heads of
+    ``head_dim`` columns for k AND for v (a head's state is head_dim x
+    head_dim), a log-decay a CHANNEL from a low-rank gate of ``head_dim``
+    columns, an output gate of the same rank, q, k and v each through its own
+    causal depthwise convolution of ``d_conv`` taps."""
+    n_heads: int = 32
+    head_dim: int = 128
+    d_conv: int = 4
+    chunk: int = 64             # positions a chunk of the chunked rule: 16 x
+                                # a power of two (``kda.SUB``), or fewer
+    dt_init: tuple = (1e-3, 1e-1, 1e-4)     # (min, max, floor): a channel's
+                                            # initial step log-uniform in [min,
+                                            # max], at least floor; ``dt_bias``
+                                            # its inverse softplus
+                                            # (``SSMConfig.dt_init``'s form)
+
+    @property
+    def d_inner(self):
+        return self.n_heads * self.head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -327,7 +356,7 @@ class TransformerConfig:
                                  # ``ln2_post_*``); pre-LN only
     # Hybrid stacks (models/hf_granite.py sets all three):
     layer_types: tuple = ()     # a mixer of ``_KINDS`` a layer ("attention",
-                                # "mamba", "conv", "mla", "dsa", "window";
+                                # "mamba", "conv", "mla", "dsa", "window", "kda";
                                 # "mlp" under ``single_sublayer``);
                                 # () =
                                 # ``n_layers``
@@ -351,6 +380,8 @@ class TransformerConfig:
     # Latent attention and a shared expert (models/hf_deepseek_v3.py sets
     # both):
     mla: Optional[MLAConfig] = None     # the "mla" layers' sizes
+    kda: Optional[KDAConfig] = None     # the "kda" layers' sizes
+                                        # (models/hf_kimi_linear.py)
     d_ff_shared: int = 0        # > 0: an expert layer has an always-on
                                 # branch too, ONE SwiGLU MLP of this width on
                                 # every token beside the routed picks
@@ -393,6 +424,13 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_types={self.layer_types}: {self.n_layers} kinds "
                     f"of {sorted(_KINDS)} (mamba: pre-LN, with `ssm` sizes)")
+        if "kda" in self.layer_types and (
+                self.kda is None or self.post_ln or not self.causal
+                or self.kda.chunk & (self.kda.chunk - 1)):
+            raise ValueError(
+                f"layer_types={self.layer_types}: a kda layer takes `kda` "
+                "sizes (a chunk that is a power of two), pre-LN and a causal "
+                "model (the state runs forward in time)")
         if "mla" in self.layer_types and (
                 self.mla is None or self.post_ln or self.attn_proj_bias
                 or self.qk_norm or self.n_kv_heads
@@ -400,7 +438,8 @@ class TransformerConfig:
             raise ValueError(
                 f"layer_types={self.layer_types}: an mla layer takes `mla` "
                 "sizes, pre-LN, and no projection bias, QK-norm, grouped "
-                "heads or attention multiplier")
+                "heads or attention multiplier (beside mamba, conv or kda "
+                "layers as beside its own kind)")
         if "dsa" in self.layer_types and (
                 self.dsa is None or self.post_ln or not self.causal
                 or not self.rope):
@@ -421,13 +460,13 @@ class TransformerConfig:
                 f"rope_dim={self.rope_dim}, rope_yarn={self.rope_yarn}: the "
                 "rotary form of \"attention\" layers that rotate (`rope`)")
         if (self.rope_dim or self.rope_yarn or self.attn_gate) and (
-                {"mla", "dsa"} & set(self.layer_types)
+                {"mla", "dsa", "kda"} & set(self.layer_types)
                 or self.rope_dim % 2 or self.rope_dim > self.head_dim):
             raise ValueError(
                 f"rope_dim={self.rope_dim}, rope_yarn={self.rope_yarn}, "
                 f"attn_gate={self.attn_gate}: of attention and window "
-                "layers (mla and dsa layers have neither), rope_dim an even "
-                "count of a head's columns")
+                "layers (mla, dsa and kda layers have neither), rope_dim an "
+                "even count of a head's columns")
         if ("mlp" in self.layer_types) > self.single_sublayer or (
                 self.single_sublayer and (
                     self.post_ln or self.sandwich_norm or self.n_dense_layers
@@ -713,6 +752,49 @@ def _mla_specs(cfg: TransformerConfig):
     cut of all five (a ``tp`` axis computes the projections on every device;
     the kernels still run a shard of the heads each, ``_flash``)."""
     return {name: P() for name in ("wq", "wkv_a", "kv_norm", "wkv_b", "wo")}
+
+
+KDA_LEAVES = ("kda_wqkv", "kda_conv", "kda_fa", "kda_fb", "kda_dt_bias",
+              "kda_A_log", "kda_wb", "kda_ga", "kda_gb", "kda_norm", "kda_wo")
+
+
+def _init_kda(ks, cfg: TransformerConfig, n):
+    """flash-linear-attention's ``KimiDeltaAttention`` layer: ``A_log`` = log
+    U(1, 16) a head; ``dt_bias`` the inverse softplus of a step log-uniform
+    in ``dt_init``'s [min, max], at least its floor, a CHANNEL; the
+    convolutions' taps U(-1/sqrt(taps), 1/sqrt(taps)) (``torch.nn.Conv1d``'s
+    own, depthwise: HF ``_init_weights`` passes a Conv1d by); the head norm's
+    scale 1; normal(0.02) for every Linear, `kda_wo` with the trunk's depth
+    scaling. ``kda_wqkv``'s columns are [q | k | v] and ``kda_conv``'s the
+    same: three convolutions side by side."""
+    m, D = cfg.kda, cfg.d_model
+    HK, R = m.d_inner, m.head_dim
+    key = jax.random.split(jax.random.fold_in(ks[11], 5), 8)
+    lo, hi, floor = m.dt_init
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(key[0], (n, HK))
+                             * np.log(hi / lo) + np.log(lo)), floor)
+    bound = 1.0 / np.sqrt(m.d_conv)
+    return {
+        "kda_wqkv": _init_normal(ks[0], (n, D, 3 * HK), 0.02),
+        "kda_conv": jax.random.uniform(key[1], (n, m.d_conv, 3 * HK),
+                                       jnp.float32, -bound, bound),
+        "kda_fa": _init_normal(key[2], (n, D, R), 0.02),
+        "kda_fb": _init_normal(key[3], (n, R, HK), 0.02),
+        "kda_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "kda_A_log": jnp.log(jax.random.uniform(
+            key[4], (n, m.n_heads), jnp.float32, 1.0, 16.0)),
+        "kda_wb": _init_normal(key[5], (n, D, m.n_heads), 0.02),
+        "kda_ga": _init_normal(key[6], (n, D, R), 0.02),
+        "kda_gb": _init_normal(key[7], (n, R, HK), 0.02),
+        "kda_norm": jnp.ones((n, m.head_dim), jnp.float32),
+        "kda_wo": _init_normal(ks[1], (n, HK, D),
+                               0.02 / np.sqrt(2 * cfg.n_layers))}
+
+
+def _kda_specs(cfg: TransformerConfig):
+    """Replicated, as the mamba mixer: the scan is one program a device
+    (``_kda`` refuses a mesh that cuts the sequence or the experts)."""
+    return {name: P() for name in KDA_LEAVES}
 
 
 # the indexer's leaves of a "dsa" layer, beside the attention's own
@@ -1378,7 +1460,8 @@ def _mla_qkv(h, p, cfg: TransformerConfig, mesh=None):
     HF ``DeepseekV3Attention`` computes them (``q_lora_rank`` None):
     q = h Wq, a head [q_nope | q_rope]; [c | k_rope] = h Wkv_a; [k_nope | v]
     = RMSNorm(c) Wkv_b; q_rope (``_rope_q``) and the one k_rope a token
-    (``_rope_interleaved``: 64 columns, narrower than a lane tile) rotated;
+    (``_rope_interleaved``: 64 columns, narrower than a lane tile) rotated
+    (``MLAConfig.rotate``; false: neither, and every other line as it is);
     k = [k_nope | k_rope], the rotary key every head's. The matmuls read
     bf16 operands; the latent's norm is float32."""
     m, nh = cfg.mla, cfg.n_heads
@@ -1386,14 +1469,17 @@ def _mla_qkv(h, p, cfg: TransformerConfig, mesh=None):
         "btd,de->bte", x, w.astype(h.dtype),
         preferred_element_type=jnp.float32).astype(h.dtype)
     with jax.named_scope(SCOPE_MLA_Q):
-        q = _rope_q(proj(h, p["wq"]), cfg, mesh)
+        q = proj(h, p["wq"])
+        if m.rotate:
+            q = _rope_q(q, cfg, mesh)
     with jax.named_scope(SCOPE_MLA_KV_DOWN):
         raw, k_rope = jnp.split(proj(h, p["wkv_a"]), [m.kv_rank], axis=-1)
         latent = _rms_norm32(raw, p["kv_norm"], cfg.ln_eps)
         c = checkpoint_name(latent.astype(h.dtype), REMAT_MLA_LATENT)
-        k_rope = checkpoint_name(
-            _rope_interleaved(k_rope, 0, cfg.rope_theta, m.rope_dim, 0),
-            REMAT_MLA_LATENT)
+        if m.rotate:
+            k_rope = _rope_interleaved(k_rope, 0, cfg.rope_theta, m.rope_dim,
+                                       0)
+        k_rope = checkpoint_name(k_rope, REMAT_MLA_LATENT)
     with jax.named_scope(SCOPE_MLA_KV_UP):
         k_nope, v = jnp.split(proj(c, p["wkv_b"]), [nh * m.nope_dim], axis=-1)
         k = _mla_keys(k_nope, k_rope, nh)
@@ -1778,6 +1864,95 @@ def _short_conv_gates(Bg, Cg, x, conv_w):
     return Cg.astype(jnp.float32) * _causal_conv(z, conv_w)
 
 
+def _kda_inputs(h, p, cfg: TransformerConfig):
+    """The KDA mixer up to its scan -> (q, k (B, T, H, K), both L2-normalised
+    a head, q times K^-0.5, v (B, T, H, K), the compute dtype; g (B, T, H, K)
+    the log-decay a channel and beta (B, T, H), float32; the output gate's
+    low-rank half (B, T, head_dim) float32)."""
+    m = cfg.kda
+    B, T, _ = h.shape
+    R = m.head_dim
+    with jax.named_scope(SCOPE_KDA_PROJ):
+        qkv = jnp.einsum("btd,de->bte", h, p["kda_wqkv"].astype(h.dtype),
+                         preferred_element_type=jnp.float32).astype(h.dtype)
+        small = jnp.einsum(
+            "btd,de->bte", h, jnp.concatenate(
+                [p["kda_fa"], p["kda_ga"], p["kda_wb"]], -1).astype(h.dtype),
+            preferred_element_type=jnp.float32)
+        f_low, gate_low, b_raw = jnp.split(small, [R, 2 * R], axis=-1)
+        # the decay's second half in float32 at full precision: it is
+        # cumulated over a chunk and exponentiated
+        f = jnp.einsum("btr,re->bte", f_low, p["kda_fb"],
+                       precision=jax.lax.Precision.HIGHEST)
+    with jax.named_scope(SCOPE_KDA_CONV):
+        qkv = _causal_conv(qkv, p["kda_conv"], None, jax.nn.silu)
+    heads = lambda x: x.reshape(B, T, m.n_heads, m.head_dim)
+    with jax.named_scope(SCOPE_KDA_GATE):
+        q, k, v = (heads(x) for x in jnp.split(qkv, 3, axis=-1))
+        q = _kda_l2(q) * m.head_dim ** -0.5
+        k = _kda_l2(k)
+        g = _kda_log_decay(heads(f), p["kda_dt_bias"], p["kda_A_log"])
+        beta = jax.nn.sigmoid(b_raw)
+    return (q.astype(h.dtype), k.astype(h.dtype), v.astype(h.dtype), g, beta,
+            gate_low)
+
+
+def _kda_l2(x):
+    """x / sqrt(sum x^2 + 1e-6) over a head's columns, float32."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def _kda_log_decay(f, dt_bias, A_log):
+    """g = -exp(A_log[head]) softplus(f + dt_bias), a channel, float32: f
+    (B, T, H, K) the low-rank gate's output."""
+    H, K = f.shape[-2:]
+    return -jnp.exp(A_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
+        f.astype(jnp.float32) + dt_bias.reshape(H, K))
+
+
+def _kda_gate(gate_low, gb, cfg: TransformerConfig):
+    """The output gate sigmoid((u Wga) Wgb), (B, T, columns of ``gb``)
+    float32; the second matmul reads the compute dtype."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "btr,re->bte", gate_low.astype(cfg.dtype), gb.astype(cfg.dtype),
+        preferred_element_type=jnp.float32))
+
+
+def _kda_gate_norm(o, gate, scale, eps):
+    """RMSNorm over each head's columns of o (B, T, H, K) float32, times the
+    head norm's one ``scale``, times ``gate`` (B, T, H * K): the gate AFTER
+    the norm -> (B, T, H * K) float32."""
+    B, T, H, K = o.shape
+    normed = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) * scale
+    return normed.reshape(B, T, H * K) * gate
+
+
+def _kda(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+    """Kimi Delta Attention (``cfg.kda``): q, k, v = SiLU(conv(h W)), each
+    its own convolution; q and k L2-normalised a head; a log-decay a channel
+    from a low-rank gate, a step a head; the gated delta rule in its chunked
+    form (``kda.scan``); RMSNorm a head, then a sigmoid gate from a second
+    low-rank pair; `kda_wo`. No position signal. ``attn_bias`` (a padding
+    mask) is refused: the recurrence reads every position."""
+    from . import kda
+    if attn_bias is not None:
+        raise NotImplementedError("a kda layer takes no attention bias")
+    if _axes(mesh, "sp", "ep") > 1:
+        raise NotImplementedError(
+            "a kda layer on a mesh that cuts the sequence or the experts (sp "
+            "or ep > 1): the state of a position waits for every position "
+            "before it, and no exchange of states is written")
+    q, k, v, g, beta, gate_low = _kda_inputs(h, p, cfg)
+    with jax.named_scope(SCOPE_KDA_SCAN):
+        o = kda.scan(q, k, v, g, beta, cfg.kda.chunk)
+    with jax.named_scope(SCOPE_KDA_GATE):
+        y = _kda_gate_norm(o, _kda_gate(gate_low, p["kda_gb"], cfg),
+                           p["kda_norm"], cfg.ln_eps).astype(h.dtype)
+    with jax.named_scope(SCOPE_KDA_PROJ):
+        return jnp.einsum("bte,ed->btd", y, p["kda_wo"].astype(h.dtype),
+                          preferred_element_type=jnp.float32).astype(h.dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class _Kind:
     """What a kind of mixer brings: its stacked weights, their
@@ -1797,6 +1972,7 @@ _KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
           "mla": _Kind(_init_mla, _mla_specs, _mla),
           "dsa": _Kind(_init_dsa, _dsa_specs, _dsa, side_loss=True),
           "window": _Kind(_init_window, _window_specs, _window),
+          "kda": _Kind(_init_kda, _kda_specs, _kda),
           # no mixer: a single sublayer that is its MLP half (``_block``)
           "mlp": _Kind(lambda ks, cfg, n: {}, lambda cfg: {}, None)}
 
@@ -3050,6 +3226,38 @@ def mla_terms(params, tokens, cfg: TransformerConfig):
     q, k, v, (c, latent) = _mla_qkv(x, p, cfg)
     return {"x": x, "c": c, "kv_norm": p["kv_norm"], "latent": latent,
             "q": q, "k": k, "v": v}
+
+
+def kda_terms(params, tokens, cfg: TransformerConfig, heads=None):
+    """The float32 parts of the FIRST kda layer on ``tokens`` (B, T), with
+    what each was computed from: a pure function beside the step, for checks
+    and counters (no mesh). Of the heads ``heads`` (indices; None: all): ``q``,
+    ``k``, ``v`` (B, T, h, K) as the scan takes them, ``g`` (B, T, h, K) the
+    log-decay and ``beta`` (B, T, h), float32; ``G`` the log-decay cumulated
+    over each chunk, ``U`` (B, T, h, K) the triangular system's solution,
+    ``entering`` (B, chunks, h, K, K) the state entering each chunk and ``o``
+    (B, T, h, K) the scan's output; ``normed`` (B, T, h * K) what the gated
+    head norm made of ``o`` before any cast, with ``gate`` the sigmoid it
+    multiplied by and ``scale`` the norm's; ``chunk_log_decay_min`` the most
+    negative cumulated log-decay inside a chunk, over ALL heads."""
+    from . import kda
+    h, p, _ = _first_layer_of(params, tokens, cfg,
+                              lambda kind: mixer_of(kind) == "kda")
+    q, k, v, g, beta, gate_low = _kda_inputs(
+        _norm(h, p["ln1_scale"], p["ln1_bias"], cfg), p, cfg)
+    low = kda.chunk_log_decay_min(g, cfg.kda.chunk)
+    if heads is not None:
+        take = lambda x: x[:, :, jnp.asarray(heads)]
+        q, k, v, g, beta = (take(x) for x in (q, k, v, g, beta))
+    o, terms = kda.scan(q, k, v, g, beta, cfg.kda.chunk, terms=True)
+    K = cfg.kda.head_dim
+    gb = p["kda_gb"] if heads is None else jnp.concatenate(
+        [p["kda_gb"][:, i * K:(i + 1) * K] for i in heads], -1)
+    gate = _kda_gate(gate_low, gb, cfg)
+    return {"q": q, "k": k, "v": v, "g": g, "beta": beta, "o": o, **terms,
+            "normed": _kda_gate_norm(o, gate, p["kda_norm"], cfg.ln_eps),
+            "scale": p["kda_norm"], "gate": gate,
+            "chunk_log_decay_min": low}
 
 
 def attention_terms(params, tokens, cfg: TransformerConfig, mixer):

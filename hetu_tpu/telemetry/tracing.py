@@ -167,6 +167,21 @@ SCOPE_MLA_KV_DOWN = "hetu_mla_kv_down"  # Wkv_a, the latent's RMSNorm, the
 SCOPE_MLA_KV_UP = "hetu_mla_kv_up"      # Wkv_b and the assembly of k: every
                                         # head's k_nope beside the one k_rope
 MLA_SCOPES = (SCOPE_MLA_Q, SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP)
+# parts of a Kimi Delta Attention mixer (`transformer._kda`), of their own
+# (not inside the block scopes: a KDA layer has no q k^T core), under
+# SCOPE_FWD like the others; benchmark/reduce/kda.py reads them
+SCOPE_KDA_PROJ = "hetu_kda_proj"    # Wq, Wk, Wv, Wo and the five small
+                                    # projections (Wfa, Wfb, Wga, Wgb, Wb)
+SCOPE_KDA_CONV = "hetu_kda_conv"    # the three causal depthwise convolutions
+                                    # and their SiLU
+SCOPE_KDA_GATE = "hetu_kda_gate"    # the log-decay g, beta, the L2 norms of
+                                    # q and k, the output's gated head norm
+SCOPE_KDA_SCAN = "hetu_kda_scan"    # the chunked gated delta rule
+                                    # (`models/kda.py`)
+# INSIDE SCOPE_KDA_SCAN (`.../hetu_kda_scan/hetu_kda_solve/...`): the
+# triangular system of a chunk, (I + A)^-1 and its product with [V | K]
+SCOPE_KDA_SOLVE = "hetu_kda_solve"
+KDA_SCOPES = (SCOPE_KDA_PROJ, SCOPE_KDA_CONV, SCOPE_KDA_GATE, SCOPE_KDA_SCAN)
 # learned sparse attention (transformer._dsa; kernels/dsa.py): the indexer
 # beside a grouped-query layer's own projections. The innermost of the four
 # names an op (benchmark/reduce/dsa.py): the index scores run once for the
@@ -250,6 +265,13 @@ REMAT_CANDIDATES = ((REMAT_X1, REMAT_X2), (REMAT_ATTN_O, REMAT_ATTN_LSE),
 # k, v (`transformer._remat_names`): by bytes it is the cheapest thing an
 # attention layer of any kind can keep. Not measured alone on the chip
 REMAT_MLA_LATENT = "hetu_mla_latent"
+# Kimi Delta Attention's own (`models/kda.py`), kept by the SEGMENT's
+# checkpoint inside the scan and not by the trunk's: the inverse of a chunk's
+# unit lower-triangular system, 64 x 64 float32 a chunk and head (16 MiB a
+# segment of 16 chunks at 32 heads). With it kept a segment's backward pass
+# runs the forward substitution and the block merges no second time; the
+# pairwise decays and the products around them are made again
+REMAT_KDA_INV = "hetu_kda_inv"
 # learned sparse attention's own (`kernels/dsa.indexer_loss`): the gradient
 # of a layer's L_I on the indexer's five leaves, made in the loss's forward
 # rule. The indexer reads the layer's input detached, so these ARE the

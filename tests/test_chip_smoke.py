@@ -64,9 +64,20 @@ def test_flagship_phase_tiny(capsys):
                max_position_embeddings=32, dtype=jnp.float32,
                sa_config=dict(indexer_num_heads=4, indexer_head_dim=8,
                               indexer_num_kv_heads=1, topk=8))
+    kda = dict(chip_smoke.KDA_ROW, hidden_size=64, intermediate_size=128,
+               moe_intermediate_size=32, num_attention_heads=4,
+               num_key_value_heads=4, kv_lora_rank=32, qk_nope_head_dim=32,
+               qk_rope_head_dim=16, v_head_dim=24, num_experts=2,
+               num_routed_experts=8, first_expert_held=2,
+               num_experts_per_token=2, vocab_size=64, model_max_length=40,
+               linear_attn_config=dict(
+                   kda_layers=[1], full_attn_layers=[2], num_heads=4,
+                   head_dim=16, short_conv_kernel_size=4),
+               kda_chunk=16, dtype=jnp.float32)
     rec = chip_smoke.phase_flagship(cfg=cfg, batch=4, seq=128, n_pred=8,
                                     steps=3, chip=False, moe=moe, mla=mla,
-                                    mla_batch=2, dsa=dsa, dsa_batch=2)
+                                    mla_batch=2, dsa=dsa, dsa_batch=2,
+                                    kda=kda, kda_batch=2)
     line = _last_json(capsys)
     assert line["phase"] == "flagship"
     assert (line["dsa"]["heads"], line["dsa"]["kv_heads"],
@@ -74,6 +85,12 @@ def test_flagship_phase_tiny(capsys):
     assert line["dsa"]["kept_pairs"] == 2 * (36 + 24 * 8)
     assert line["dsa"]["index_loss"] > 0
     assert abs(line["dsa"]["loss"] - line["dsa"]["dot_loss"]) < 1e-4
+    # 40 positions in chunks of 16: the last chunk is not whole
+    assert (line["kda"]["heads"], line["kda"]["head_dim"],
+            line["kda"]["chunk"], line["kda"]["rotate"]) == (4, 16, 16, False)
+    assert line["kda"]["scan_rel_rms_err_vs_f64"] < 1e-5
+    assert line["kda"]["chunk_log_decay_min"] < 0
+    assert line["kda"]["dropped_picks"] == 0 and line["kda"]["tokens"] == 80
     assert line["mla"]["dropped_picks"] == 0 and line["mla"]["tokens"] == 64
     assert (line["mla"]["qk_dim"], line["mla"]["v_dim"],
             line["mla"]["d_ff_shared"]) == (48, 24, 64)
