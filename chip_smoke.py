@@ -564,8 +564,9 @@ KDA_ROW = dict(
 def _kda_row(sizes, batch, chip):
     """One train step of a model with a Kimi Delta Attention layer and a
     latent-attention layer that rotates nothing through `make_train_step`:
-    the chunked rule's output on the first layer's own inputs agrees with the
-    recurrence over positions in float64 (two heads), every value finite
+    the chunked rule's output on the first layer's own inputs (every head; on
+    the chip the Mosaic kernel's, and the row says which form served) agrees
+    with the recurrence over positions in float64, every value finite
     where the cumulated log-decay is past float32's 1 / exp(G); the step's
     loss is finite and no held pick is dropped."""
     import jax
@@ -580,9 +581,10 @@ def _kda_row(sizes, batch, chip):
     ids = jnp.asarray(np.random.RandomState(3).randint(
         0, cfg.vocab_size, (batch, cfg.max_seq_len + 1)), jnp.int32)
     tokens, targets = ids[:, :-1], ids[:, 1:]
-    heads = (0, cfg.kda.n_heads - 1)
+    from hetu_tpu.telemetry import tracing
+    noted = len(tracing.forms("kda.scan"))
     t = jax.device_get(jax.jit(lambda p: tfm.kda_terms(
-        p, tokens, cfg, heads=heads))(params))
+        p, tokens, cfg))(params))
     f64 = lambda x: np.asarray(x).astype(np.float64)[0]
     q, k, v, g, beta = (f64(t[n]) for n in ("q", "k", "v", "g", "beta"))
     S, want = np.zeros(q.shape[1:] + v.shape[-1:]), np.empty_like(v)
@@ -601,11 +603,14 @@ def _kda_row(sizes, batch, chip):
     opt = tfm.init_opt_state(params)
     step = tfm.make_train_step(cfg, lr=3e-6).lower(
         params, opt, tokens, targets).compile()
+    served = sorted({r["form"] + (f" ({r['reason']})" if r["reason"] else "")
+                     for r in tracing.forms("kda.scan")[noted:]})
     if chip:
         hlo = step.as_text()
         _check(all(k in hlo for k in ("flash_fwd", "hetu_kda_scan",
-                                      "hetu_kda_solve")),
+                                      "kda_fwd", "kda_bwd")),
                "kda: a kernel or a scope is missing from the compiled step")
+        _check(served == ["kernel"], f"kda: the scan was served by {served}")
     loss, params, opt = step(params, opt, tokens, targets)
     _check(_finite(loss), f"kda: step loss {float(loss)}")
     return {"step_loss": round(float(loss), 5),
@@ -614,6 +619,7 @@ def _kda_row(sizes, batch, chip):
                 float(t["chunk_log_decay_min"]), 2),
             "heads": cfg.kda.n_heads, "head_dim": cfg.kda.head_dim,
             "chunk": cfg.kda.chunk, "rotate": cfg.mla.rotate,
+            "scan_served_by": served,
             "held_picks": int(np.sum(stats["held"])),
             "dropped_picks": dropped, "tokens": int(tokens.size)}
 

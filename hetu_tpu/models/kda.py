@@ -47,13 +47,18 @@ residuals.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..kernels import kda as kda_kernel
+from ..telemetry import tracing
 from ..telemetry.tracing import (REMAT_KDA_INV, SCOPE_KDA_SCAN,
                                  SCOPE_KDA_SOLVE)
+
+_log = logging.getLogger(__name__)
 
 SUB = 16                # positions a diagonal sub-block (trap (i))
 SEGMENT_CHUNKS = 16     # chunks a segment (trap (ii))
@@ -199,15 +204,32 @@ def chunk_log_decay_min(g, chunk):
     return jnp.min(jnp.cumsum(g.reshape((B, -1, chunk) + g.shape[2:]), 2))
 
 
-def scan(q, k, v, g, beta, chunk, terms=False):
+@functools.lru_cache(maxsize=None)
+def _log_form(reason):
+    """Once a distinct answer of the rule, at trace time."""
+    _log.info("kda.scan runs in %s", "the Mosaic kernel kda_fwd" if reason
+              is None else f"the XLA form ({reason})")
+
+
+def scan(q, k, v, g, beta, chunk, terms=False, mesh=None):
     """The gated delta rule over T positions in chunks of ``chunk``: q, k
     (B, T, H, K), v (B, T, H, V), g (B, T, H, K) float32 <= 0, beta (B, T, H)
     float32 -> o (B, T, H, V) float32. T need not be whole chunks: positions
     after T are k = v = 0, g = 0, beta = 0, which leave the state as it is.
     ``terms``: -> (o, {U (B, T, H, V), entering (B, c, H, K, V) the state
-    entering each chunk, G (B, T, H, K)}), for checks."""
+    entering each chunk, G (B, T, H, K)}), for checks. Where
+    ``kernels/kda.takes`` admits the call (one program on a TPU, whole
+    chunks of 64, heads of whole lane tiles) the Mosaic kernel serves it,
+    ``terms`` too; everywhere else the form below."""
     B, T, H, K = k.shape
     n = min(SEGMENT_CHUNKS, -(-T // chunk))
+    reason = kda_kernel.refusal(q, k, v, g, beta, chunk, mesh)
+    tracing.note_form("kda.scan", "xla" if reason else "kernel", reason)
+    _log_form(reason)
+    if reason is None:
+        if terms:
+            return kda_kernel.terms(q, k, v, g, beta, chunk)
+        return kda_kernel.kda(q, k, v, g, beta, chunk)
     xs = tuple(_cut(x, T, chunk, n) for x in (q, k, v, g, beta[..., None]))
     xs = xs[:4] + (xs[4][..., 0],)
     S0 = jnp.zeros((B, H, K, v.shape[-1]), jnp.float32)

@@ -1944,7 +1944,7 @@ def _kda(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
             "before it, and no exchange of states is written")
     q, k, v, g, beta, gate_low = _kda_inputs(h, p, cfg)
     with jax.named_scope(SCOPE_KDA_SCAN):
-        o = kda.scan(q, k, v, g, beta, cfg.kda.chunk)
+        o = kda.scan(q, k, v, g, beta, cfg.kda.chunk, mesh=mesh)
     with jax.named_scope(SCOPE_KDA_GATE):
         y = _kda_gate_norm(o, _kda_gate(gate_low, p["kda_gb"], cfg),
                            p["kda_norm"], cfg.ln_eps).astype(h.dtype)

@@ -48,7 +48,10 @@ _T0_UNIX = time.time()
 # calls a step say that a cell's rotation runs in the kernel and not in
 # `transformer._rope` / `_rope_interleaved`; `ssd_fwd` and `ssd_bwd`, the
 # chunked Mamba-2 scan, whose calls say that a mamba layer's scan runs in
-# `kernels/ssd.py` and not in `transformer._ssd`'s einsums; `grouped_matmul`
+# `kernels/ssd.py` and not in `transformer._ssd`'s einsums; `kda_fwd` and
+# `kda_bwd`, Kimi Delta Attention's chunked scan, whose calls say that a kda
+# layer's scan runs in `kernels/kda.py` and not in `models/kda.py`'s XLA
+# form (`forms("kda.scan")` says so at trace time); `grouped_matmul`
 # and `grouped_matmul_dw`, the experts' products on a TPU in one program,
 # where `ragged-dot-none` ran before them, under `hetu_moe_experts`).
 STEP = "hetu_step"        # one SubExecutor.run call, step_num=<step>
@@ -861,6 +864,24 @@ def compile_count() -> int:
 
 def compile_log_stats() -> dict:
     return _LOG.stats()
+
+
+# which implementation served a call that has two (a Mosaic kernel where its
+# `takes` rule admits the shapes, the XLA form elsewhere), noted at TRACE
+# time beside the compile log: one record a traced call, so a program that
+# was served from the cache notes nothing (`models/kda.scan` writes "kda.scan")
+_FORMS = []
+
+
+def note_form(site: str, form: str, reason: Optional[str] = None) -> None:
+    """``site`` was served by ``form`` ("kernel" or "xla"); ``reason``: the
+    first thing the kernel's rule refused, where it refused."""
+    _FORMS.append({"site": site, "form": form, "reason": reason})
+
+
+def forms(site: Optional[str] = None) -> list:
+    """The records of ``note_form``, oldest first, of ``site`` (None: all)."""
+    return [dict(r) for r in _FORMS if site is None or r["site"] == site]
 
 
 def note_import(name: str, t0_perf: float, jax_preloaded: bool) -> None:

@@ -24,6 +24,7 @@ from hetu_tpu.kernels import dsa
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels import fused_ce as fc
 from hetu_tpu.kernels import grouped_matmul as gmm
+from hetu_tpu.kernels import kda as kda_kernel
 from hetu_tpu.kernels import rope
 from hetu_tpu.kernels import ssd
 
@@ -833,6 +834,54 @@ def test_ssd_kernels_compile_for_v5e_at_granites_scan(
     assert kernels == 1
     assert hashlib.sha256(lowered_text.encode()).hexdigest()[:16] == (
         SSD_LOWERED[half])
+
+
+# kimi-linear-48b-a3b.pretrain-seq16384-b1-ep32share's scan (batch, seq,
+# heads, head columns) and the two heads its check's part (C) asks
+KIMI_SCAN = (1, 16384, 32, 128)
+
+
+@pytest.mark.parametrize("half", ["forward", "backward", "terms-two-heads"])
+def test_kda_kernels_compile_for_v5e_at_kimis_scan(
+        one_chip, no_compile_cache, monkeypatch, half):
+    """Kimi Delta Attention's two kernels at the cell's call, one sequence
+    of 16,384 positions, 32 heads of 128 columns in chunks of 64, bfloat16,
+    and the forward kernel writing its parts for TWO heads: ONE Mosaic call
+    a half under the kernel's name, inside the VMEM Mosaic gives unasked;
+    the entering states (256 chunks x 32 heads x 128 x 128) are the largest
+    float32 array: nothing (C, C, K) and nothing a segment wide leaves a
+    kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
+    B, T, H, K = KIMI_SCAN
+    H = 2 if half == "terms-two-heads" else H
+    n = T // kda_kernel.CHUNK
+
+    def arr(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, g, beta = arr(B, T, H, K), arr(B, T, H, K, dtype=jnp.float32), arr(
+        B, T, H, dtype=jnp.float32)
+    assert kda_kernel.takes(x, x, x, g, beta, kda_kernel.CHUNK)
+    if half == "forward":
+        fn, args = (lambda *a: kda_kernel._kda_fwd(*a, kda_kernel.CHUNK)[0],
+                    (x, x, x, g, beta))
+    elif half == "backward":
+        fn, args = (lambda *a: kda_kernel._backward(*a, kda_kernel.CHUNK),
+                    (x, x, x, g, beta,
+                     arr(B, n, H, K, K, dtype=jnp.float32),
+                     arr(B, T, H, K, dtype=jnp.float32)))
+    else:
+        fn, args = (lambda *a: kda_kernel.terms(*a, kda_kernel.CHUNK),
+                    (x, x, x, g, beta))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = (kda_kernel.KDA_FWD, kda_kernel.KDA_BWD)
+    assert _count_by_name(_kernel_calls(text), names) == {
+        kda_kernel.KDA_FWD: int(half != "backward"),
+        kda_kernel.KDA_BWD: int(half == "backward")}
+    assert "vmem_limit_bytes" not in text
+    sizes = {math.prod(map(int, dims.split(",")))
+             for kind, dims in re.findall(r"(f32)\[([\d,]+)\]", text)}
+    assert max(sizes) <= max(n * H * K * K, T * H * K)
 
 
 # an expert layer's grouped matmuls at the six expert cells' real calls:
