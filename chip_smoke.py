@@ -260,7 +260,7 @@ def _bert_batch(rng, cfg, batch, seq, n_pred, padded):
 def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
                    ref_batch=2, mesh_axes=None, chip=True, name="flagship",
                    moe=None, moe_batch=2, mla=None, mla_batch=1, dsa=None,
-                   dsa_batch=1, kda=None, kda_batch=1):
+                   dsa_batch=1, kda=None, kda_batch=1, gdn=None, gdn_batch=1):
     """BERT pretrain steps on an unpadded then a padded batch; the loss
     must stay finite (at lr 1e-4 without warm-up AdamW's first steps
     overshoot at BERT-base size, so "falling" is not asked here). On one
@@ -272,7 +272,8 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
     ``dsa`` row one of a model with learned sparse attention, ``dsa`` or
     ``DSA_ROW`` (``_dsa_row``), the ``kda`` row one of a model with Kimi Delta
     Attention beside unrotated latent attention, ``kda`` or ``KDA_ROW``
-    (``_kda_row``)."""
+    (``_kda_row``), the ``gdn`` row one of a model with a Gated DeltaNet
+    layer beside gated attention, ``gdn`` or ``GDN_ROW`` (``_gdn_row``)."""
     import jax
     from hetu_tpu.kernels.fused_ce import should_fuse
     from hetu_tpu.models import bert
@@ -375,6 +376,7 @@ def phase_flagship(*, cfg=None, batch=32, seq=512, n_pred=76, steps=4,
             rec["mla"] = _mla_row(mla or MLA_ROW, mla_batch, chip)
             rec["dsa"] = _dsa_row(dsa or DSA_ROW, dsa_batch, chip)
             rec["kda"] = _kda_row(kda or KDA_ROW, kda_batch, chip)
+            rec["gdn"] = _gdn_row(gdn or GDN_ROW, gdn_batch, chip)
         rec.update({"model": "bert", "d_model": cfg.d_model,
                     "n_heads": cfg.n_heads, "n_layers": cfg.n_layers,
                     "d_ff": cfg.d_ff, "vocab": cfg.vocab_size, "seq": seq,
@@ -561,6 +563,23 @@ KDA_ROW = dict(
     model_max_length=1024)
 
 
+def _delta_rule_err_f64(t):
+    """The scan's output ``t["o"]`` against the gated delta rule over
+    POSITIONS in numpy float64 on the scan's own inputs (``kda_terms`` /
+    ``gdn_terms``: the first sequence's q, k, v, beta and g, a channel's (T,
+    H, K) or a head's (T, H)) -> the relative RMS error."""
+    f64 = lambda x: np.asarray(x).astype(np.float64)[0]
+    q, k, v, g, beta = (f64(t[n]) for n in ("q", "k", "v", "g", "beta"))
+    S, want = np.zeros(q.shape[1:] + v.shape[-1:]), np.empty_like(v)
+    for i in range(q.shape[0]):
+        S *= np.exp(g[i]).reshape(g[i].shape + (1,) * (S.ndim - g[i].ndim))
+        u = beta[i][:, None] * (v[i] - np.einsum("hkv,hk->hv", S, k[i]))
+        S += k[i][..., None] * u[:, None, :]
+        want[i] = np.einsum("hkv,hk->hv", S, q[i])
+    return float(np.sqrt(np.mean((f64(t["o"]) - want) ** 2)
+                         / np.mean(want ** 2)))
+
+
 def _kda_row(sizes, batch, chip):
     """One train step of a model with a Kimi Delta Attention layer and a
     latent-attention layer that rotates nothing through `make_train_step`:
@@ -585,16 +604,7 @@ def _kda_row(sizes, batch, chip):
     noted = len(tracing.forms("kda.scan"))
     t = jax.device_get(jax.jit(lambda p: tfm.kda_terms(
         p, tokens, cfg))(params))
-    f64 = lambda x: np.asarray(x).astype(np.float64)[0]
-    q, k, v, g, beta = (f64(t[n]) for n in ("q", "k", "v", "g", "beta"))
-    S, want = np.zeros(q.shape[1:] + v.shape[-1:]), np.empty_like(v)
-    for i in range(q.shape[0]):
-        S *= np.exp(g[i])[..., None]
-        u = beta[i][:, None] * (v[i] - np.einsum("hkv,hk->hv", S, k[i]))
-        S += k[i][..., None] * u[:, None, :]
-        want[i] = np.einsum("hkv,hk->hv", S, q[i])
-    err = float(np.sqrt(np.mean((f64(t["o"]) - want) ** 2)
-                        / np.mean(want ** 2)))
+    err = _delta_rule_err_f64(t)
     _check(np.isfinite(err) and err <= 1e-4,
            f"kda: the chunked rule is {err} from the recurrence")
     stats = jax.jit(lambda p: tfm.moe_routing_stats(p, tokens, cfg))(params)
@@ -620,6 +630,93 @@ def _kda_row(sizes, batch, chip):
             "heads": cfg.kda.n_heads, "head_dim": cfg.kda.head_dim,
             "chunk": cfg.kda.chunk, "rotate": cfg.mla.rotate,
             "scan_served_by": served,
+            "held_picks": int(np.sum(stats["held"])),
+            "dropped_picks": dropped, "tokens": int(tokens.size)}
+
+
+# Qwen3-Next-80B-A3B's two kinds of mixer (models/hf_qwen3_next.py) at the
+# published widths: a Gated DeltaNet layer and a gated-attention layer (16
+# heads of 256 on 2, a quarter of a head rotated) over 8 of 32 experts held
+# and the gated shared expert, a small vocabulary, 1,024 tokens (16 chunks of
+# 64): the `gdn` row of the flagship phase
+GDN_ROW = dict(
+    hidden_size=2048, intermediate_size=5120, moe_intermediate_size=512,
+    shared_expert_intermediate_size=512, num_attention_heads=16,
+    num_key_value_heads=2, head_dim=256, partial_rotary_factor=0.25,
+    rope_theta=1e7, full_attention_interval=2, num_hidden_layers=2,
+    linear_num_key_heads=16, linear_num_value_heads=32,
+    linear_key_head_dim=128, linear_value_head_dim=128,
+    linear_conv_kernel_dim=4, num_experts=8, num_routed_experts=32,
+    first_expert_held=0, num_experts_per_tok=10, norm_topk_prob=True,
+    rms_norm_eps=1e-6, vocab_size=1024, max_position_embeddings=1024)
+
+
+def _gdn_row(sizes, batch, chip):
+    """One train step of a model with a Gated DeltaNet layer and a
+    gated-attention layer through `make_train_step`: the chunked rule's
+    output on the first layer's own inputs, the decay a head's broadcast over
+    the head's columns, agrees with the recurrence over positions in float64
+    in BOTH forms that can reach a chip: the one the step runs (pass "step":
+    on the chip the Mosaic kernels) and the XLA form that serves a mesh, a
+    ragged T or another chunk (pass "xla": the kernels' rule told it is off
+    the chip), every value finite where the cumulated log-decay is past
+    float32's 1 / exp(G); the step's loss is finite and no held pick is
+    dropped."""
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.kernels import kda as kda_kernel
+    from hetu_tpu.models import hf_qwen3_next, transformer as tfm
+    from hetu_tpu.telemetry import tracing
+    dtype = sizes.get("dtype", jnp.bfloat16)
+    cfg = hf_qwen3_next.config_from_hf(
+        {k: v for k, v in sizes.items() if k not in ("dtype", "gdn_chunk")},
+        dtype=dtype,
+        **({"gdn_chunk": sizes["gdn_chunk"]} if "gdn_chunk" in sizes else {}))
+    params = tfm.init_params(jax.random.PRNGKey(3), cfg)
+    ids = jnp.asarray(np.random.RandomState(3).randint(
+        0, cfg.vocab_size, (batch, cfg.max_seq_len + 1)), jnp.int32)
+    tokens, targets = ids[:, :-1], ids[:, 1:]
+    errs, served, on_tpu = {}, {}, kda_kernel._on_tpu
+    for form in ("step", "xla"):
+        if form == "xla":
+            kda_kernel._on_tpu = lambda: False
+        noted = len(tracing.forms("kda.scan"))
+        try:
+            t = jax.device_get(jax.jit(lambda p: tfm.gdn_terms(
+                p, tokens, cfg))(params))
+        finally:
+            kda_kernel._on_tpu = on_tpu
+        served[form] = sorted({r["form"] for r in
+                               tracing.forms("kda.scan")[noted:]})
+        errs[form] = _delta_rule_err_f64(t)
+        _check(np.isfinite(errs[form]) and errs[form] <= 1e-4,
+               f"gdn: the chunked rule ({form}: {served[form]}) is "
+               f"{errs[form]} from the recurrence")
+    stats = jax.jit(lambda p: tfm.moe_routing_stats(p, tokens, cfg))(params)
+    dropped = int(np.sum(stats["dropped"]))
+    _check(dropped == 0, f"gdn: {dropped} dropped picks")
+    opt = tfm.init_opt_state(params)
+    step = tfm.make_train_step(cfg, lr=3e-6).lower(
+        params, opt, tokens, targets).compile()
+    if chip:
+        hlo = step.as_text()
+        _check(all(k in hlo for k in ("flash_fwd", "hetu_gdn_scan",
+                                      "hetu_attn_gate")),
+               "gdn: a kernel or a scope is missing from the compiled step")
+        _check(served == {"step": ["kernel"], "xla": ["xla"]},
+               f"gdn: the scan was served by {served}")
+    loss, params, opt = step(params, opt, tokens, targets)
+    _check(_finite(loss), f"gdn: step loss {float(loss)}")
+    return {"step_loss": round(float(loss), 5),
+            "scan_rel_rms_err_vs_f64": {
+                form: float(f"{err:.3g}") for form, err in errs.items()},
+            "chunk_log_decay_min": round(
+                float(t["chunk_log_decay_min"]), 2),
+            "key_heads": cfg.gdn.n_k_heads, "value_heads": cfg.gdn.n_v_heads,
+            "head_dim": cfg.gdn.k_dim, "chunk": cfg.gdn.chunk,
+            "scan_served_by": served,
+            "attn_heads": [cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+                           cfg.rope_dim],
             "held_picks": int(np.sum(stats["held"])),
             "dropped_picks": dropped, "tokens": int(tokens.size)}
 

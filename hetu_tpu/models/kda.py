@@ -36,6 +36,21 @@ a time under ``jax.checkpoint`` (its backward pass keeps a segment's inputs
 and the state entering it, and makes the segment again), which keeps
 ``REMAT_KDA_INV`` alone: the inverse of I + A, 64 x 64 a chunk and head.
 
+A DECAY A HEAD (Gated DeltaNet: g_t ONE scalar a head and position, alpha_t
+a multiple of the identity) is the same rule, and ``scan`` takes it as g (B,
+T, H) and BROADCASTS it over the head's K columns: the kernels and the form
+below then run it as any channel's decay. Of the lines above only the
+products with exp(G_r[c] - G_i[c]) inside the sum over c are a CHANNEL's
+(``pair_products`` and its sub-blocks, trap (i)'s device); every other line (K
+. exp G, Q . exp G, K decayed to the chunk's end, the state's decay,
+``unit_lower_inverse``, ``_chunk_step``, ``_segment``, ``_cut``) is ANY
+decay's. With G_r a scalar the exponential leaves the sum, A[r, i] = beta_r
+(k_r . k_i) exp(G_r - G_i): two plain matmuls times ONE (C, C) matrix of
+decays, no pairwise tensor. PR 68 wrote that form in XLA, timed it at 16,384
+tokens on the v5e and took it out again: 63.5 ms a scan forward and backward
+against the kernels' 38.4 with g broadcast (docs/KERNELS.md). A Mosaic kernel
+of it is what is left (ROADMAP M6(c)).
+
 float32: g, G, the pairwise products, the triangular system and its inverse,
 U, the carried state and o; q, k and v arrive in the compute dtype and are
 read as float32. Every matmul here is float32 at HIGHEST precision (on a TPU
@@ -175,12 +190,13 @@ def _chunk_step(S, parts):
     return decay[..., None] * S + _mm(_T(Kd), U), (o, U, S)
 
 
-def _segment(S, xs, terms=False):
+def _segment(S, xs, terms=False, scope=SCOPE_KDA_SCAN):
     """A segment of chunks: xs = (q, k, v, g, beta), each (n, ..., C, .) with
-    the chunks first -> (S after it, o (n, ..., C, V))."""
+    the chunks first -> (S after it, o (n, ..., C, V)). ``scope``: the
+    calling mixer's name for the scan."""
     # the scope again INSIDE the checkpointed body: the ops the segment's
     # backward pass makes again carry the names their first run carried
-    with jax.named_scope(SCOPE_KDA_SCAN):
+    with jax.named_scope(scope):
         q, k, v, g, beta = (x.astype(jnp.float32) for x in xs)
         *parts, G = chunk_parts(q, k, v, g, beta)
         S, (o, U, entering) = jax.lax.scan(_chunk_step, S, tuple(parts))
@@ -197,10 +213,11 @@ def _cut(x, T, chunk, n):
 
 
 def chunk_log_decay_min(g, chunk):
-    """The most negative log-decay cumulated inside a chunk, g (B, T, H, K):
-    how far past float32's 1 / exp(G) (-88) the stable form is worked."""
+    """The most negative log-decay cumulated inside a chunk, g (B, T, H, K) a
+    channel's or (B, T, H) a head's: how far past float32's 1 / exp(G) (-88)
+    the stable form is worked."""
     B, T = g.shape[:2]
-    g = jnp.pad(g, ((0, 0), (0, -T % chunk), (0, 0), (0, 0)))
+    g = jnp.pad(g, ((0, 0), (0, -T % chunk)) + ((0, 0),) * (g.ndim - 2))
     return jnp.min(jnp.cumsum(g.reshape((B, -1, chunk) + g.shape[2:]), 2))
 
 
@@ -211,16 +228,24 @@ def _log_form(reason):
               is None else f"the XLA form ({reason})")
 
 
-def scan(q, k, v, g, beta, chunk, terms=False, mesh=None):
+def scan(q, k, v, g, beta, chunk, terms=False, mesh=None,
+         scope=SCOPE_KDA_SCAN):
     """The gated delta rule over T positions in chunks of ``chunk``: q, k
-    (B, T, H, K), v (B, T, H, V), g (B, T, H, K) float32 <= 0, beta (B, T, H)
-    float32 -> o (B, T, H, V) float32. T need not be whole chunks: positions
-    after T are k = v = 0, g = 0, beta = 0, which leave the state as it is.
+    (B, T, H, K), v (B, T, H, V), g (B, T, H, K) float32 <= 0 a channel's
+    log-decay, or (B, T, H) a HEAD's, broadcast here over the head's columns
+    (``terms``' G is then (B, T, H) too), beta (B, T, H) float32 -> o (B, T,
+    H, V) float32. ``scope``: the calling mixer's name for the scan. T need
+    not be whole chunks: positions after T are k = v = 0, g = 0, beta = 0,
+    which leave the state as it is.
     ``terms``: -> (o, {U (B, T, H, V), entering (B, c, H, K, V) the state
     entering each chunk, G (B, T, H, K)}), for checks. Where
     ``kernels/kda.takes`` admits the call (one program on a TPU, whole
     chunks of 64, heads of whole lane tiles) the Mosaic kernel serves it,
     ``terms`` too; everywhere else the form below."""
+    if g.ndim == 3:
+        out = scan(q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta,
+                   chunk, terms, mesh, scope)
+        return (out[0], {**out[1], "G": out[1]["G"][..., 0]}) if terms else out
     B, T, H, K = k.shape
     n = min(SEGMENT_CHUNKS, -(-T // chunk))
     reason = kda_kernel.refusal(q, k, v, g, beta, chunk, mesh)
@@ -233,7 +258,7 @@ def scan(q, k, v, g, beta, chunk, terms=False, mesh=None):
     xs = tuple(_cut(x, T, chunk, n) for x in (q, k, v, g, beta[..., None]))
     xs = xs[:4] + (xs[4][..., 0],)
     S0 = jnp.zeros((B, H, K, v.shape[-1]), jnp.float32)
-    body = functools.partial(_segment, terms=terms)
+    body = functools.partial(_segment, terms=terms, scope=scope)
     if not terms:
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.save_only_these_names(

@@ -53,6 +53,8 @@ from ..telemetry.tracing import (REMAT_ATTN_K, REMAT_ATTN_O, REMAT_ATTN_Q,
                                  SCOPE_BLK_QKV, SCOPE_BLK_WO, SCOPE_DSA_LOSS,
                                  SCOPE_DSA_PROJ, SCOPE_DSA_SELECT,
                                  SCOPE_EMBED, SCOPE_EXIT, SCOPE_FWD,
+                                 SCOPE_GDN_CONV, SCOPE_GDN_GATE,
+                                 SCOPE_GDN_PROJ, SCOPE_GDN_SCAN,
                                  SCOPE_HEAD, SCOPE_KDA_CONV, SCOPE_KDA_GATE,
                                  SCOPE_KDA_PROJ, SCOPE_KDA_SCAN,
                                  SCOPE_MLA_KV_DOWN, SCOPE_MLA_KV_UP,
@@ -179,6 +181,38 @@ class KDAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class GDNConfig:
+    """The sizes of a Gated DeltaNet mixer (Yang et al. 2024,
+    arXiv:2412.06464, as Qwen3-Next's ``Qwen3NextGatedDeltaNet`` has it;
+    ``_gdn``, ``models/kda.py``): ``n_k_heads`` key heads of ``k_dim`` columns
+    under ``n_v_heads`` value heads of ``v_dim`` (key head j serves value
+    heads r j .. r j + r - 1, r = n_v_heads / n_k_heads; a value head's state
+    is k_dim x v_dim), ONE log-decay a value head and position, [q | k | v]
+    through ONE causal depthwise convolution of ``d_conv`` taps, an output
+    gate SiLU(z) of the value heads' width after the head norm."""
+    n_k_heads: int = 16
+    n_v_heads: int = 32
+    k_dim: int = 128
+    v_dim: int = 128
+    d_conv: int = 4
+    chunk: int = 64             # positions a chunk of the chunked rule, a
+                                # power of two
+
+    @property
+    def qk_inner(self):
+        return self.n_k_heads * self.k_dim
+
+    @property
+    def v_inner(self):
+        return self.n_v_heads * self.v_dim
+
+    @property
+    def conv_dim(self):
+        """Channels the convolution runs over: [q | k | v]."""
+        return 2 * self.qk_inner + self.v_inner
+
+
+@dataclasses.dataclass(frozen=True)
 class DSAConfig:
     """The three sizes of a learned sparse attention's indexer (DeepSeek-
     V3.2-Exp's lightning indexer, arXiv:2512.02556; ``_dsa``): ``n_heads``
@@ -248,6 +282,10 @@ class Router:
     scale: float = 1.0          # on the picks' weights, after that
     aux_losses: bool = True     # the balance and z losses (``loss_fn``);
                                 # False: ``aux`` is zeros
+    loss_weights: Optional[tuple] = None    # (balance, z): the two losses'
+                                            # weights in ``loss_fn``; None =
+                                            # its caller's ``aux_weight`` and
+                                            # ``Z_LOSS_WEIGHT``
     bias_rate: float = 0.0      # u of the auxiliary-loss-free rule (Wang et
                                 # al. 2024, arXiv:2408.15664), applied by
                                 # ``make_train_step`` after a step: b_e += u
@@ -356,7 +394,8 @@ class TransformerConfig:
                                  # ``ln2_post_*``); pre-LN only
     # Hybrid stacks (models/hf_granite.py sets all three):
     layer_types: tuple = ()     # a mixer of ``_KINDS`` a layer ("attention",
-                                # "mamba", "conv", "mla", "dsa", "window", "kda";
+                                # "mamba", "conv", "mla", "dsa", "window", "kda",
+                                # "gdn";
                                 # "mlp" under ``single_sublayer``);
                                 # () =
                                 # ``n_layers``
@@ -382,6 +421,17 @@ class TransformerConfig:
     mla: Optional[MLAConfig] = None     # the "mla" layers' sizes
     kda: Optional[KDAConfig] = None     # the "kda" layers' sizes
                                         # (models/hf_kimi_linear.py)
+    # Gated DeltaNet beside gated attention, zero-centred norms and a gated
+    # shared expert (models/hf_qwen3_next.py sets all four):
+    gdn: Optional[GDNConfig] = None     # the "gdn" layers' sizes
+    norm_offset: bool = False   # zero-centred RMSNorm: the stored weight is
+                                # w of a scale 1 + w, initialised at ZERO
+                                # (AdamW decays w towards a scale of 1): every
+                                # norm of the stream (``_norm``) and the
+                                # per-head q/k norms (``qk_norm`` "head")
+    shared_gate: bool = False   # the shared expert's output times sigmoid(h
+                                # w_sg), ONE gate logit a token (leaf ``wsg``,
+                                # (D, 1))
     d_ff_shared: int = 0        # > 0: an expert layer has an always-on
                                 # branch too, ONE SwiGLU MLP of this width on
                                 # every token beside the routed picks
@@ -405,9 +455,11 @@ class TransformerConfig:
                                 # 0 = ``head_dim``
     rope_yarn: Optional[YarnConfig] = None  # the "attention" layers' rotary
                                             # table scaled by YaRN
-    attn_gate: bool = False     # a per-head sigmoid gate (leaf ``wg``, (D,
-                                # heads)) from the layer's normed input on
-                                # attention's output, before ``wo``
+    attn_gate: Any = False      # a sigmoid gate from the layer's normed
+                                # input on attention's output, before ``wo``:
+                                # True (Laguna) one a HEAD, leaf ``wg`` (D,
+                                # heads); "column" (Qwen3-Next) one a COLUMN,
+                                # ``wg`` (D, heads * head_dim)
     # Layers of ONE sublayer (models/hf_nemotron_h.py sets it):
     single_sublayer: bool = False   # every layer is x + f(norm(x)) with ONE
                                     # norm: a mixer of ``layer_types`` WITHOUT
@@ -430,6 +482,15 @@ class TransformerConfig:
             raise ValueError(
                 f"layer_types={self.layer_types}: a kda layer takes `kda` "
                 "sizes (a chunk that is a power of two), pre-LN and a causal "
+                "model (the state runs forward in time)")
+        if "gdn" in self.layer_types and (
+                self.gdn is None or self.post_ln or not self.causal
+                or self.gdn.chunk & (self.gdn.chunk - 1)
+                or self.gdn.n_v_heads % self.gdn.n_k_heads):
+            raise ValueError(
+                f"layer_types={self.layer_types}, gdn={self.gdn}: a gdn layer "
+                "takes `gdn` sizes (a chunk that is a power of two, value "
+                "heads in whole groups a key head), pre-LN and a causal "
                 "model (the state runs forward in time)")
         if "mla" in self.layer_types and (
                 self.mla is None or self.post_ln or self.attn_proj_bias
@@ -461,12 +522,25 @@ class TransformerConfig:
                 "rotary form of \"attention\" layers that rotate (`rope`)")
         if (self.rope_dim or self.rope_yarn or self.attn_gate) and (
                 {"mla", "dsa", "kda"} & set(self.layer_types)
-                or self.rope_dim % 2 or self.rope_dim > self.head_dim):
+                or self.rope_dim % 2 or self.rope_dim > self.head_dim
+                or self.attn_gate not in (False, True, "column")):
             raise ValueError(
                 f"rope_dim={self.rope_dim}, rope_yarn={self.rope_yarn}, "
-                f"attn_gate={self.attn_gate}: of attention and window "
-                "layers (mla, dsa and kda layers have neither), rope_dim an "
-                "even count of a head's columns")
+                f"attn_gate={self.attn_gate}: the \"attention\" and "
+                "\"window\" layers' own, of a stack whose other layers are "
+                "of those kinds or mamba, conv or gdn (mla, dsa and kda "
+                "layers have neither, and no stack mixes them with a rotary "
+                "width or a gate); rope_dim an even count of a head's "
+                "columns; attn_gate True (a head) or 'column'")
+        if self.norm_offset and (self.norm != "rmsnorm"
+                                 or self.qk_norm is True):
+            raise ValueError(
+                f"norm_offset with norm={self.norm!r}, qk_norm="
+                f"{self.qk_norm!r}: the zero-centred form is RMSNorm's, of "
+                "the stream's norms and the per-head q/k norms")
+        if self.shared_gate and not self.d_ff_shared:
+            raise MoEConfigError(
+                "shared_gate: the gate of a shared expert (`d_ff_shared`)")
         if ("mlp" in self.layer_types) > self.single_sublayer or (
                 self.single_sublayer and (
                     self.post_ln or self.sandwich_norm or self.n_dense_layers
@@ -619,6 +693,12 @@ def blocks_of_runs(runs):
     return runs[0] if len(runs) == 1 else tuple(runs)
 
 
+def _init_norm_scale(cfg: TransformerConfig, shape):
+    """A norm's stored weight as initialised: the scale 1, or under
+    ``cfg.norm_offset`` w = 0 of a scale 1 + w."""
+    return (jnp.zeros if cfg.norm_offset else jnp.ones)(shape, jnp.float32)
+
+
 def _init_attention(ks, cfg: TransformerConfig, n):
     D = cfg.d_model
     qkv_width = (cfg.n_heads + 2 * cfg.kv_heads) * cfg.head_dim
@@ -629,21 +709,23 @@ def _init_attention(ks, cfg: TransformerConfig, n):
         p["bqkv"] = jnp.zeros((n, qkv_width), jnp.float32)
         p["bo"] = jnp.zeros((n, D), jnp.float32)
     if cfg.qk_norm == "head":
-        p["q_norm"] = jnp.ones((n, cfg.head_dim), jnp.float32)
-        p["k_norm"] = jnp.ones((n, cfg.head_dim), jnp.float32)
+        p["q_norm"] = _init_norm_scale(cfg, (n, cfg.head_dim))
+        p["k_norm"] = _init_norm_scale(cfg, (n, cfg.head_dim))
     elif cfg.qk_norm:
         p["q_norm"] = jnp.ones((n, cfg.n_heads * cfg.head_dim), jnp.float32)
         p["k_norm"] = jnp.ones((n, cfg.kv_heads * cfg.head_dim), jnp.float32)
     if cfg.attn_gate:
-        p["wg"] = _init_normal(jax.random.fold_in(ks[11], 3),
-                               (n, D, cfg.n_heads), 0.02)
+        p["wg"] = _init_normal(
+            jax.random.fold_in(ks[11], 3),
+            (n, D, cfg.n_heads * (cfg.head_dim if cfg.attn_gate == "column"
+                                  else 1)), 0.02)
     return p
 
 
 def _attention_specs(cfg: TransformerConfig):
     p = {"wqkv": P(None, None, "tp"), "wo": P(None, "tp", None)}
     if cfg.attn_gate:
-        p["wg"] = P(None, None, "tp")       # a head's gate with the head
+        p["wg"] = P(None, None, "tp")       # a head's gate(s) with the head
     if cfg.attn_proj_bias:
         p["bqkv"], p["bo"] = P(None, "tp"), P(None, None)
     if cfg.qk_norm:
@@ -797,6 +879,40 @@ def _kda_specs(cfg: TransformerConfig):
     return {name: P() for name in KDA_LEAVES}
 
 
+GDN_LEAVES = ("gdn_wqkvz", "gdn_wba", "gdn_conv", "gdn_dt_bias", "gdn_A_log",
+              "gdn_norm", "gdn_wo")
+
+
+def _init_gdn(ks, cfg: TransformerConfig, n):
+    """HF ``Qwen3NextGatedDeltaNet``: ``A_log`` = log U(0, 16) a value head
+    (drawn in (0, 16]: log 0 is no weight), ``dt_bias`` ones, the head norm's
+    scale ones (NOT zero-centred), the convolution's taps ``torch.nn.
+    Conv1d``'s own (U(-1/sqrt(taps), 1/sqrt(taps)), depthwise), normal(0.02)
+    for every Linear, ``gdn_wo`` with the trunk's depth scaling.
+    ``gdn_wqkvz``'s columns are [q | k | v | z], every head of a part side by
+    side (the checkpoint groups them by key head: the loader's matter),
+    ``gdn_wba``'s [b | a], ``gdn_conv``'s [q | k | v]."""
+    m, D = cfg.gdn, cfg.d_model
+    key = jax.random.split(jax.random.fold_in(ks[11], 6), 3)
+    bound = 1.0 / np.sqrt(m.d_conv)
+    return {
+        "gdn_wqkvz": _init_normal(ks[0], (n, D, m.conv_dim + m.v_inner), 0.02),
+        "gdn_wba": _init_normal(key[0], (n, D, 2 * m.n_v_heads), 0.02),
+        "gdn_conv": jax.random.uniform(key[1], (n, m.d_conv, m.conv_dim),
+                                       jnp.float32, -bound, bound),
+        "gdn_dt_bias": jnp.ones((n, m.n_v_heads), jnp.float32),
+        "gdn_A_log": jnp.log(16.0 * (1.0 - jax.random.uniform(
+            key[2], (n, m.n_v_heads), jnp.float32))),
+        "gdn_norm": jnp.ones((n, m.v_dim), jnp.float32),
+        "gdn_wo": _init_normal(ks[1], (n, m.v_inner, D),
+                               0.02 / np.sqrt(2 * cfg.n_layers))}
+
+
+def _gdn_specs(cfg: TransformerConfig):
+    """Replicated, as the kda mixer: the scan is one program a device."""
+    return {name: P() for name in GDN_LEAVES}
+
+
 # the indexer's leaves of a "dsa" layer, beside the attention's own
 DSA_LEAVES = ("wq_idx", "wk_idx", "k_idx_norm_scale", "k_idx_norm_bias",
               "ww_idx")
@@ -835,17 +951,17 @@ def _init_run(ks, cfg: TransformerConfig, kind, n):
     blocks = {}
     if mixer_of(kind) != "mlp":
         blocks.update({
-            "ln1_scale": jnp.ones((n, D), jnp.float32),
+            "ln1_scale": _init_norm_scale(cfg, (n, D)),
             "ln1_bias": jnp.zeros((n, D), jnp.float32),
             **_KINDS[mixer_of(kind)].init(ks, cfg, n)})
     if not has_mlp(kind):
         return blocks
     blocks.update({
-        "ln2_scale": jnp.ones((n, D), jnp.float32),
+        "ln2_scale": _init_norm_scale(cfg, (n, D)),
         "ln2_bias": jnp.zeros((n, D), jnp.float32)})
     if cfg.sandwich_norm:
         for name in ("ln1_post", "ln2_post"):
-            blocks[name + "_scale"] = jnp.ones((n, D), jnp.float32)
+            blocks[name + "_scale"] = _init_norm_scale(cfg, (n, D))
             blocks[name + "_bias"] = jnp.zeros((n, D), jnp.float32)
     if cfg.mlp in GATED_MLPS:
         blocks["w3"] = norm(ks[8], (n, E, D, F) if E > 0 else (n, D, F),
@@ -868,6 +984,9 @@ def _init_run(ks, cfg: TransformerConfig, kind, n):
             blocks.update({"ws1": norm(k1, (n, D, Fs), 0.02),
                            "ws3": norm(k3, (n, D, Fs), 0.02),
                            "ws2": norm(k2, (n, Fs, D), out_scale)})
+            if cfg.shared_gate:
+                blocks["wsg"] = norm(jax.random.fold_in(ks[3], 2), (n, D, 1),
+                                     0.02)
     else:
         blocks.update({
             "w1": norm(ks[3], (n, D, F), 0.02),
@@ -889,7 +1008,7 @@ def _init_trunk(ks, cfg: TransformerConfig):
             _init_run(ks if r == 0 else jax.random.split(
                 jax.random.fold_in(ks[10], r), 12), cfg, kind, n)
             for r, (kind, n) in enumerate(layer_runs(cfg))]),
-        "lnf_scale": jnp.ones((cfg.d_model,), jnp.float32),
+        "lnf_scale": _init_norm_scale(cfg, (cfg.d_model,)),
         "lnf_bias": jnp.zeros((cfg.d_model,), jnp.float32),
     }
 
@@ -938,6 +1057,8 @@ def _run_specs(cfg: TransformerConfig, kind):
             blocks.update({"ws1": P(None, None, "tp"),
                            "ws3": P(None, None, "tp"),
                            "ws2": P(None, "tp", None)})
+            if cfg.shared_gate:
+                blocks["wsg"] = P(None, None, None)
     else:
         blocks.update({
             "w1": P(None, None, "tp"),
@@ -1059,11 +1180,14 @@ def _rms_norm(x, scale, eps):
     return _rms_norm32(x, scale, eps).astype(x.dtype)
 
 
-def _rms_norm_heads(x, scale, eps):
+def _rms_norm_heads(x, scale, eps, offset=False):
     """RMSNorm over each head's channels: x (B, T, heads * hd), the heads
-    side by side; ``scale`` (hd,) is every head's. The statistic alone takes
-    the (B, T, heads, hd) view; x keeps its layout."""
+    side by side; ``scale`` (hd,) is every head's (``offset``: the stored w
+    of 1 + w). The statistic alone takes the (B, T, heads, hd) view; x keeps
+    its layout."""
     hd = scale.shape[-1]
+    if offset:
+        scale = 1.0 + scale
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32.reshape(x.shape[:-1] + (-1, hd))), -1)
     x32 = x32 * jnp.repeat(jax.lax.rsqrt(ms + eps), hd, axis=-1)
@@ -1086,6 +1210,8 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
     it: ``SCOPE_BLK_NORM``."""
     with jax.named_scope(SCOPE_BLK_NORM):
         if cfg.norm == "rmsnorm":
+            if cfg.norm_offset:     # zero-centred: the stored weight is w
+                scale = 1.0 + scale
             return _rms_norm(x, scale, cfg.ln_eps)
         return _layer_norm(x, scale, bias, cfg.ln_eps)
 
@@ -1296,8 +1422,8 @@ def _split_heads(qkv, p, cfg: TransformerConfig, mesh, impl):
     # cut along the columns; a head stays hd columns of its array
     q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
     if cfg.qk_norm == "head":
-        q = _rms_norm_heads(q, p["q_norm"], cfg.ln_eps)
-        k = _rms_norm_heads(k, p["k_norm"], cfg.ln_eps)
+        q = _rms_norm_heads(q, p["q_norm"], cfg.ln_eps, cfg.norm_offset)
+        k = _rms_norm_heads(k, p["k_norm"], cfg.ln_eps, cfg.norm_offset)
     elif cfg.qk_norm:
         # the statistic runs over every head of the projection at once
         q = _rms_norm(q, p["q_norm"], cfg.ln_eps)
@@ -1409,14 +1535,17 @@ def _attention(h, p, cfg: TransformerConfig, mesh, attn_bias=None,
 
 
 def _gate_heads(o, x, wg, hd):
-    """The per-head gate on attention's output: o (B, T, heads * hd) times
-    sigmoid(x Wg) (B, T, heads), a head's one gate on its hd columns; x the
-    layer's normed input. The logits accumulate in float32 and the sigmoid
-    and the product are float32; the result is o's dtype."""
+    """The gate on attention's output: o (B, T, heads * hd) times sigmoid(x
+    Wg), x the layer's normed input: ``wg`` (D, heads) a head's one gate on
+    its hd columns (Laguna), or (D, heads * hd) a gate a COLUMN, the heads'
+    side by side as o's are (Qwen3-Next). The logits accumulate in float32
+    and the sigmoid and the product are float32; the result is o's dtype."""
     g = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x, wg.astype(x.dtype),
                                   preferred_element_type=jnp.float32))
-    return (o.astype(jnp.float32) * jnp.repeat(g, hd, axis=-1)).astype(
-        o.dtype)
+    o32 = o.astype(jnp.float32)
+    if g.shape[-1] != o.shape[-1]:      # a head's one gate on its columns
+        g = jnp.repeat(g, hd, axis=-1)
+    return (o32 * g).astype(o.dtype)
 
 
 def _window(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
@@ -1903,8 +2032,12 @@ def _kda_l2(x):
 
 
 def _kda_log_decay(f, dt_bias, A_log):
-    """g = -exp(A_log[head]) softplus(f + dt_bias), a channel, float32: f
-    (B, T, H, K) the low-rank gate's output."""
+    """g = -exp(A_log[head]) softplus(f + dt_bias), float32: a CHANNEL's, f
+    (B, T, H, K) the low-rank gate's output and ``dt_bias`` (H * K,); or a
+    HEAD's (``_gdn``), f (B, T, H) and ``dt_bias`` (H,)."""
+    if f.ndim == 3:
+        return -jnp.exp(A_log.astype(jnp.float32)) * jax.nn.softplus(
+            f.astype(jnp.float32) + dt_bias)
     H, K = f.shape[-2:]
     return -jnp.exp(A_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
         f.astype(jnp.float32) + dt_bias.reshape(H, K))
@@ -1927,6 +2060,19 @@ def _kda_gate_norm(o, gate, scale, eps):
     return normed.reshape(B, T, H * K) * gate
 
 
+def _refuse_bias_and_cut_sequence(kind, mesh, attn_bias):
+    """What a layer whose state runs through every position refuses, by the
+    kind's name: a padding mask, and a mesh that cuts the sequence or the
+    experts."""
+    if attn_bias is not None:
+        raise NotImplementedError(f"a {kind} layer takes no attention bias")
+    if _axes(mesh, "sp", "ep") > 1:
+        raise NotImplementedError(
+            f"a {kind} layer on a mesh that cuts the sequence or the experts "
+            "(sp or ep > 1): the state of a position waits for every "
+            "position before it, and no exchange of states is written")
+
+
 def _kda(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     """Kimi Delta Attention (``cfg.kda``): q, k, v = SiLU(conv(h W)), each
     its own convolution; q and k L2-normalised a head; a log-decay a channel
@@ -1935,13 +2081,7 @@ def _kda(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
     low-rank pair; `kda_wo`. No position signal. ``attn_bias`` (a padding
     mask) is refused: the recurrence reads every position."""
     from . import kda
-    if attn_bias is not None:
-        raise NotImplementedError("a kda layer takes no attention bias")
-    if _axes(mesh, "sp", "ep") > 1:
-        raise NotImplementedError(
-            "a kda layer on a mesh that cuts the sequence or the experts (sp "
-            "or ep > 1): the state of a position waits for every position "
-            "before it, and no exchange of states is written")
+    _refuse_bias_and_cut_sequence("kda", mesh, attn_bias)
     q, k, v, g, beta, gate_low = _kda_inputs(h, p, cfg)
     with jax.named_scope(SCOPE_KDA_SCAN):
         o = kda.scan(q, k, v, g, beta, cfg.kda.chunk, mesh=mesh)
@@ -1950,6 +2090,69 @@ def _kda(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
                            p["kda_norm"], cfg.ln_eps).astype(h.dtype)
     with jax.named_scope(SCOPE_KDA_PROJ):
         return jnp.einsum("bte,ed->btd", y, p["kda_wo"].astype(h.dtype),
+                          preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+def _gdn_inputs(h, p, cfg: TransformerConfig):
+    """The Gated DeltaNet mixer up to its scan -> (q, k (B, T, Hv, K), both
+    L2-normalised a key head, q times K^-0.5, each key head repeated for its
+    value heads; v (B, T, Hv, V); the compute dtype; g and beta (B, T, Hv)
+    float32, the log-decay a HEAD; z (B, T, Hv * V) the output gate's
+    argument, the compute dtype)."""
+    m = cfg.gdn
+    B, T, _ = h.shape
+    with jax.named_scope(SCOPE_GDN_PROJ):
+        qkvz = jnp.einsum("btd,de->bte", h, p["gdn_wqkvz"].astype(h.dtype),
+                          preferred_element_type=jnp.float32).astype(h.dtype)
+        # the decay's and the step's logits stay float32 as summed: g is
+        # cumulated over a chunk and exponentiated
+        ba = jnp.einsum("btd,de->bte", h, p["gdn_wba"].astype(h.dtype),
+                        preferred_element_type=jnp.float32)
+    qkv, z = jnp.split(qkvz, [m.conv_dim], axis=-1)
+    with jax.named_scope(SCOPE_GDN_CONV):
+        qkv = _causal_conv(qkv, p["gdn_conv"], None, jax.nn.silu)
+    with jax.named_scope(SCOPE_GDN_GATE):
+        q, k, v = jnp.split(qkv, [m.qk_inner, 2 * m.qk_inner], axis=-1)
+        q, k = (x.reshape(B, T, m.n_k_heads, m.k_dim) for x in (q, k))
+        q = _kda_l2(q) * m.k_dim ** -0.5
+        k = _kda_l2(k)
+        # key head j serves value heads r j .. r j + r - 1
+        q, k = (jnp.repeat(x.astype(h.dtype), m.n_v_heads // m.n_k_heads,
+                           axis=2) for x in (q, k))
+        v = v.reshape(B, T, m.n_v_heads, m.v_dim).astype(h.dtype)
+        b_raw, a_raw = jnp.split(ba, 2, axis=-1)
+        g = _kda_log_decay(a_raw, p["gdn_dt_bias"], p["gdn_A_log"])
+        beta = jax.nn.sigmoid(b_raw)
+    return q, k, v, g, beta, z
+
+
+def _gdn_scan(q, k, v, g, beta, cfg: TransformerConfig, mesh=None,
+              terms=False):
+    """``kda.scan`` with the decay a head's (broadcast there over the head's
+    columns), under the mixer's own scope name."""
+    from . import kda
+    return kda.scan(q, k, v, g, beta, cfg.gdn.chunk, terms=terms, mesh=mesh,
+                    scope=SCOPE_GDN_SCAN)
+
+
+def _gdn(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
+    """A Gated DeltaNet mixer (``cfg.gdn``): [q | k | v | z] = h W_qkvz, [b |
+    a] = h W_ba; [q | k | v] through ONE causal convolution and SiLU; q and k
+    L2-normalised a key head, each key head serving its value heads; ONE
+    log-decay a value head (``_kda_log_decay``), a step a head; the gated
+    delta rule in its chunked form (``_gdn_scan``); RMSNorm a head, THEN
+    SiLU(z) (``_kda_gate_norm``: the order of norm and gate is the kind's);
+    `gdn_wo`. No position signal. ``attn_bias`` (a padding mask) is refused:
+    the recurrence reads every position."""
+    _refuse_bias_and_cut_sequence("gdn", mesh, attn_bias)
+    q, k, v, g, beta, z = _gdn_inputs(h, p, cfg)
+    with jax.named_scope(SCOPE_GDN_SCAN):
+        o = _gdn_scan(q, k, v, g, beta, cfg, mesh)
+    with jax.named_scope(SCOPE_GDN_GATE):
+        y = _kda_gate_norm(o, jax.nn.silu(z.astype(jnp.float32)),
+                           p["gdn_norm"], cfg.ln_eps).astype(h.dtype)
+    with jax.named_scope(SCOPE_GDN_PROJ):
+        return jnp.einsum("bte,ed->btd", y, p["gdn_wo"].astype(h.dtype),
                           preferred_element_type=jnp.float32).astype(h.dtype)
 
 
@@ -1973,6 +2176,7 @@ _KINDS = {"attention": _Kind(_init_attention, _attention_specs, _attention),
           "dsa": _Kind(_init_dsa, _dsa_specs, _dsa, side_loss=True),
           "window": _Kind(_init_window, _window_specs, _window),
           "kda": _Kind(_init_kda, _kda_specs, _kda),
+          "gdn": _Kind(_init_gdn, _gdn_specs, _gdn),
           # no mixer: a single sublayer that is its MLP half (``_block``)
           "mlp": _Kind(lambda ks, cfg, n: {}, lambda cfg: {}, None)}
 
@@ -2401,7 +2605,13 @@ def _moe_mlp(h, p, cfg: TransformerConfig, mesh, routing=None):
             shared = {"w1": p["ws1"], "w2": p["ws2"]}
             if "ws3" in p:      # the gated form's third matrix
                 shared["w3"] = p["ws3"]
-            out = out + _dense_mlp(h, shared, cfg, mesh)
+            y = _dense_mlp(h, shared, cfg, mesh)
+            if cfg.shared_gate:     # ONE gate logit a token, float32
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "btd,do->bto", h, p["wsg"].astype(h.dtype),
+                    preferred_element_type=jnp.float32))
+                y = (y.astype(jnp.float32) * gate).astype(y.dtype)
+            out = out + y
     return out, aux
 
 
@@ -3260,6 +3470,41 @@ def kda_terms(params, tokens, cfg: TransformerConfig, heads=None):
             "chunk_log_decay_min": low}
 
 
+def gdn_terms(params, tokens, cfg: TransformerConfig, heads=None):
+    """``kda_terms`` of the FIRST gdn layer on ``tokens`` (B, T), of the
+    VALUE heads ``heads`` (indices; None: all): ``q``, ``k`` (B, T, h, K) as
+    the scan takes them (the key heads repeated), ``v`` (B, T, h, V), ``g``
+    and ``beta`` (B, T, h) float32, the log-decay a head; ``G`` (B, T, h) the
+    log-decay cumulated over each chunk, ``U``, ``entering`` and ``o`` as
+    ``kda_terms`` has them; ``normed`` (B, T, h * V) what the head norm and
+    SiLU(z) made of ``o`` before any cast, with ``gate`` = SiLU(z) and
+    ``scale`` the norm's; ``chunk_log_decay_min`` over ALL heads. Through the
+    form of the rule the step runs (``kda.scan``'s own rule)."""
+    from . import kda
+    h, p, _ = _first_layer_of(params, tokens, cfg,
+                              lambda kind: mixer_of(kind) == "gdn")
+    q, k, v, g, beta, z = _gdn_inputs(
+        _norm(h, p["ln1_scale"], p["ln1_bias"], cfg), p, cfg)
+    low = kda.chunk_log_decay_min(g, cfg.gdn.chunk)
+    gate = jax.nn.silu(z.astype(jnp.float32))
+    if heads is not None:
+        take = lambda x: x[:, :, jnp.asarray(heads)]
+        q, k, v, g, beta = (take(x) for x in (q, k, v, g, beta))
+        V = cfg.gdn.v_dim
+        gate = jnp.concatenate(
+            [gate[..., i * V:(i + 1) * V] for i in heads], -1)
+    # the arrays returned are the arrays the scan READS: without the barrier
+    # the TPU compiler hands the XLA form q, k and v from BEFORE their
+    # rounding to the compute dtype (excess precision: a cast down and up
+    # again is dropped), 1.7e-3 from what is returned here
+    q, k, v, g, beta = jax.lax.optimization_barrier((q, k, v, g, beta))
+    o, terms = _gdn_scan(q, k, v, g, beta, cfg, terms=True)
+    return {"q": q, "k": k, "v": v, "g": g, "beta": beta, "o": o, **terms,
+            "normed": _kda_gate_norm(o, gate, p["gdn_norm"], cfg.ln_eps),
+            "scale": p["gdn_norm"], "gate": gate,
+            "chunk_log_decay_min": low}
+
+
 def attention_terms(params, tokens, cfg: TransformerConfig, mixer):
     """The FIRST layer of mixer ``mixer`` ("attention" or "window") on
     ``tokens`` (B, T), with what its float32 parts were computed from: a pure
@@ -3394,12 +3639,14 @@ def attention_pairs(cfg: TransformerConfig, T):
     return out
 
 
-def aux_weights(aux_weight=0.01, size=2):
+def aux_weights(aux_weight=0.01, size=2, router=None):
     """Weights of ``encode``'s aux (``size``,): the balance loss takes the
-    caller's ``aux_weight``, the router z-loss ``Z_LOSS_WEIGHT``, the
+    caller's ``aux_weight``, the router z-loss ``Z_LOSS_WEIGHT`` (both
+    ``router.loss_weights`` where a model's router states them), the
     indexers' loss (``_aux_size``: a third entry) ``DSA_LOSS_WEIGHT``."""
-    return jnp.array([aux_weight, Z_LOSS_WEIGHT, DSA_LOSS_WEIGHT][:size],
-                     jnp.float32)
+    balance, z = (router and router.loss_weights) or (aux_weight,
+                                                       Z_LOSS_WEIGHT)
+    return jnp.array([balance, z, DSA_LOSS_WEIGHT][:size], jnp.float32)
 
 
 def _fused_head_nll(params, h, targets, cfg: TransformerConfig):
@@ -3453,7 +3700,7 @@ def _exit_loss(params, exits, aux, targets, cfg: TransformerConfig, mesh,
                 logp, jnp.tile(targets, (n, 1))[..., None], -1)
         nll = nll.reshape(n, B, T)
         per = jnp.sum(q * nll + EXIT_ENTROPY_WEIGHT * q * log_q, 0)
-        loss = jnp.mean(per) + aux_weights(aux_weight, aux.size) @ aux
+        loss = jnp.mean(per) + aux_weights(aux_weight, aux.size, cfg.router) @ aux
     return loss, {"nll": nll, "q": q, "log_q": log_q, "exits": exits}
 
 
@@ -3506,9 +3753,9 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
         if not cfg.post_ln:
             h = _norm(h, params["lnf_scale"], params["lnf_bias"], cfg)
         per = _fused_head_nll(params, h, targets, cfg)
-        return jnp.mean(per) + aux_weights(aux_weight, aux.size) @ aux
+        return jnp.mean(per) + aux_weights(aux_weight, aux.size, cfg.router) @ aux
     logits, aux = forward(params, tokens, cfg, mesh, dropout_rng=dropout_rng)
-    return nll_loss(logits, targets) + aux_weights(aux_weight, aux.size) @ aux
+    return nll_loss(logits, targets) + aux_weights(aux_weight, aux.size, cfg.router) @ aux
 
 
 # ---------------------------------------------------------------------------

@@ -67,7 +67,7 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
     if len(tfm.layer_runs(cfg)) > 1:
         raise NotImplementedError(
             f"layer_runs={tfm.layer_runs(cfg)}: no stage rule for layers "
-            "of unequal kinds (mamba, conv, kda or window layers beside "
+            "of unequal kinds (mamba, conv, kda, gdn or window layers beside "
             "attention, a dense MLP beside experts); the pipeline stacks ONE "
             "kind of block a stage")
 
@@ -86,8 +86,8 @@ def _make_stage_fn(cfg: tfm.TransformerConfig, layers_per_stage: int):
         raise NotImplementedError(
             f"layer kind {kind!r}: a stage's body is the attention block; "
             "latent attention (mla), learned sparse attention (dsa: a loss "
-            "of its own a layer), window, mamba, conv and kda mixers have no "
-            "stage rule")
+            "of its own a layer), window, mamba, conv, gdn and kda mixers have "
+            "no stage rule")
 
     def stage_fn(h, stage_blocks, stage, rng_mb):
         block = functools.partial(tfm._block, cfg=cfg, mesh=None)

@@ -185,6 +185,20 @@ SCOPE_KDA_SCAN = "hetu_kda_scan"    # the chunked gated delta rule
 # triangular system of a chunk, (I + A)^-1 and its product with [V | K]
 SCOPE_KDA_SOLVE = "hetu_kda_solve"
 KDA_SCOPES = (SCOPE_KDA_PROJ, SCOPE_KDA_CONV, SCOPE_KDA_GATE, SCOPE_KDA_SCAN)
+# parts of a Gated DeltaNet mixer (`transformer._gdn`), of their own as the
+# KDA mixer's are; benchmark/reduce/gdn.py reads them
+SCOPE_GDN_PROJ = "hetu_gdn_proj"    # W_qkvz, W_ba and W_o
+SCOPE_GDN_CONV = "hetu_gdn_conv"    # the ONE causal depthwise convolution
+                                    # over [q | k | v] and its SiLU
+SCOPE_GDN_GATE = "hetu_gdn_gate"    # the log-decay g a head, beta, the L2
+                                    # norms of q and k, the key heads'
+                                    # repeat, the head norm and SiLU(z)
+SCOPE_GDN_SCAN = "hetu_gdn_scan"    # the chunked gated delta rule, whichever
+                                    # form runs (`kda.scan`'s rule): the
+# Mosaic kernels' ops sit at `.../hetu_gdn_scan/hetu_kda_scan/kda_fwd/...` in
+# all three phases (the kernels' own scope inside the mixer's), the XLA
+# form's triangular system at `.../hetu_gdn_scan/hetu_kda_solve/...`
+GDN_SCOPES = (SCOPE_GDN_PROJ, SCOPE_GDN_CONV, SCOPE_GDN_GATE, SCOPE_GDN_SCAN)
 # learned sparse attention (transformer._dsa; kernels/dsa.py): the indexer
 # beside a grouped-query layer's own projections. The innermost of the four
 # names an op (benchmark/reduce/dsa.py): the index scores run once for the

@@ -20,7 +20,8 @@ import pytest
 from hetu_tpu.models import (bert, hf_deepseek_v3, hf_granite, hf_keye,
                              hf_kimi_linear, hf_laguna, hf_lfm2,
                              hf_nemotron_h, hf_olmoe, hf_ouro,
-                             hf_smallthinker, transformer as tfm)
+                             hf_qwen3_next, hf_smallthinker,
+                             transformer as tfm)
 from model_harness import ROOT
 
 LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
@@ -29,7 +30,8 @@ LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
            "keye-vl-2.0-30b-a3b": hf_keye, "laguna-xs.2": hf_laguna,
            "nemotron-twotower-30b-a3b": hf_nemotron_h,
            "smallthinker-21b-a3b": hf_smallthinker,
-           "kimi-linear-48b-a3b": hf_kimi_linear}
+           "kimi-linear-48b-a3b": hf_kimi_linear,
+           "qwen3-next-80b-a3b": hf_qwen3_next}
 
 # (sha256[:16] of the LOWERED train step at the cell's own config and traffic
 # shapes with the counters cut off private symbols, its lines; sha256[:16] of
@@ -65,6 +67,12 @@ LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
 # parent, 77d889e, has no loader for it): the ten lines above it, unedited,
 # kanana's among them, say that the kind "kda", `MLAConfig.rotate` and the
 # loaders' shared helpers (`hf_common`) changed no program that existed.
+# qwen3-next's is of ISSUE 68's own tree, the PR that added the cell (its
+# parent, 0af66c2, has no loader for it): the eleven lines above it,
+# unedited, kimi's, laguna's and olmoe's among them, say that the kind
+# "gdn", `kda.scan`'s broadcast of a head's decay and its `scope=`,
+# `norm_offset`, the gate a column, `shared_gate` and `Router.loss_weights`
+# changed no program that existed.
 PARENT = {
     ("bert-base", "pretrain-seq512"):
         (("9e2f27a018dc2806", 2426), "0ca3cf6cdc80eded"),
@@ -88,6 +96,8 @@ PARENT = {
         (("d322fa2782e4e0b8", 6153), "ae3edc310cab3dd8"),
     ("kimi-linear-48b-a3b", "pretrain-seq16384-b1-ep32share"):
         (("1af449db85b38743", 18938), "58d7205c4347be9c"),
+    ("qwen3-next-80b-a3b", "pretrain-seq16384-b1-ep16share"):
+        (("4ec10b2447249c84", 10898), "a40c3da188d4d176"),
 }
 
 

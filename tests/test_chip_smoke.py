@@ -74,10 +74,19 @@ def test_flagship_phase_tiny(capsys):
                    kda_layers=[1], full_attn_layers=[2], num_heads=4,
                    head_dim=16, short_conv_kernel_size=4),
                kda_chunk=16, dtype=jnp.float32)
+    gdn = dict(chip_smoke.GDN_ROW, hidden_size=64, intermediate_size=128,
+               moe_intermediate_size=32, shared_expert_intermediate_size=24,
+               num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+               linear_num_key_heads=2, linear_num_value_heads=4,
+               linear_key_head_dim=16, linear_value_head_dim=16,
+               num_experts=2, num_routed_experts=8, first_expert_held=2,
+               num_experts_per_tok=3, vocab_size=64,
+               max_position_embeddings=40, gdn_chunk=16, dtype=jnp.float32)
     rec = chip_smoke.phase_flagship(cfg=cfg, batch=4, seq=128, n_pred=8,
                                     steps=3, chip=False, moe=moe, mla=mla,
                                     mla_batch=2, dsa=dsa, dsa_batch=2,
-                                    kda=kda, kda_batch=2)
+                                    kda=kda, kda_batch=2, gdn=gdn,
+                                    gdn_batch=2)
     line = _last_json(capsys)
     assert line["phase"] == "flagship"
     assert (line["dsa"]["heads"], line["dsa"]["kv_heads"],
@@ -91,6 +100,16 @@ def test_flagship_phase_tiny(capsys):
     assert line["kda"]["scan_rel_rms_err_vs_f64"] < 1e-5
     assert line["kda"]["chunk_log_decay_min"] < 0
     assert line["kda"]["dropped_picks"] == 0 and line["kda"]["tokens"] == 80
+    # the XLA form off the chip in both passes, g broadcast, 16 not dividing
+    # 40; 4 heads of 32 on 2, a quarter of a head rotated
+    assert (line["gdn"]["key_heads"], line["gdn"]["value_heads"],
+            line["gdn"]["head_dim"], line["gdn"]["chunk"]) == (2, 4, 16, 16)
+    assert set(line["gdn"]["scan_rel_rms_err_vs_f64"]) == {"step", "xla"}
+    assert line["gdn"]["scan_served_by"] == {"step": ["xla"], "xla": ["xla"]}
+    assert max(line["gdn"]["scan_rel_rms_err_vs_f64"].values()) < 1e-5
+    assert line["gdn"]["chunk_log_decay_min"] < 0
+    assert line["gdn"]["attn_heads"] == [4, 2, 32, 8]
+    assert line["gdn"]["dropped_picks"] == 0 and line["gdn"]["tokens"] == 80
     assert line["mla"]["dropped_picks"] == 0 and line["mla"]["tokens"] == 64
     assert (line["mla"]["qk_dim"], line["mla"]["v_dim"],
             line["mla"]["d_ff_shared"]) == (48, 24, 64)

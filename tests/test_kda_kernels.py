@@ -220,6 +220,31 @@ def test_scan_hands_terms_to_the_kernel_where_the_rule_admits(monkeypatch):
         assert got.shape == want.shape and _rel(got, want) <= 3e-6, name
 
 
+def test_a_heads_decay_goes_through_the_kernel_broadcast(monkeypatch):
+    """g (B, T, H), a decay a HEAD (PR 68, the kind "gdn"): where the rule
+    admits the call `scan` runs the SAME kernel with g broadcast over the
+    head's columns, `terms` too (G comes back a head's), and gives what the
+    XLA form gives; off a TPU the XLA form serves it, g broadcast too."""
+    q, k, v, g, beta = _inputs("two-heads")[:5]
+    g = g[..., 0]
+    want_o, want = kda.scan(q, k, v, g, beta, CHUNK, terms=True)
+    assert tracing.forms("kda.scan")[-1]["form"] == "xla"
+    monkeypatch.setattr(kda_kernel, "_on_tpu", lambda: True)
+    traced = jax.make_jaxpr(lambda *a: kda.scan(*a, CHUNK, terms=True))(
+        q, k, v, g, beta)
+    assert str(traced).count("pallas_call") == 1
+    o, terms = kda.scan(q, k, v, g, beta, CHUNK, terms=True)
+    assert tracing.forms("kda.scan")[-1]["form"] == "kernel"
+    assert terms["G"].shape == g.shape
+    for name, got in {"o": o, **terms}.items():
+        assert got.shape == {"o": want_o, **want}[name].shape, name
+        assert _rel(got, {"o": want_o, **want}[name]) <= 3e-6, name
+    wide = jnp.broadcast_to(g[..., None], k.shape)
+    np.testing.assert_array_equal(
+        np.asarray(kda.scan(q, k, v, g, beta, CHUNK)),
+        np.asarray(kda.scan(q, k, v, wide, beta, CHUNK)))
+
+
 @pytest.mark.parametrize("name", [kda_kernel.KDA_FWD, kda_kernel.KDA_BWD])
 def test_the_kernels_names_are_no_other_readers(name):
     """`benchmark/reduce` finds the attention, selection, rotary and Mamba

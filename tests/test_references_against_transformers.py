@@ -1,27 +1,30 @@
-"""The `reference.py` files of four cells held to the PUBLISHED modelling code
-(`transformers`' granitemoehybrid, deepseek_v3, lfm2 and mamba2 modules on
-copied weights), in ONE file: importing `torch` and `transformers` costs a
+"""The `reference.py` files of five cells held to the PUBLISHED modelling code
+(`transformers`' granitemoehybrid, deepseek_v3, lfm2, mamba2 and qwen3_next
+modules on copied weights), in ONE file: importing `torch` and `transformers` costs a
 worker tens of seconds, and a file is xdist's unit, so one worker pays it and
 not four. Each test was its model file's (test_granite_model.py,
-test_kanana_model.py, test_lfm2_model.py, test_nemotron_h_model.py), whose
-toy configuration it still runs at. `test_torch_twin.py` and the
+test_kanana_model.py, test_lfm2_model.py, test_nemotron_h_model.py; the
+qwen3_next one was written here, PR 68), whose toy configuration it still
+runs at. `test_torch_twin.py` and the
 `test_hf_*.py` files need torch throughout and stay."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from hetu_tpu.models import (hf_deepseek_v3 as hd, hf_granite, hf_lfm2,
-                             transformer as tfm)
+                             hf_qwen3_next, transformer as tfm)
 from model_harness import load_reference, rel, seeded_params, seeded_tokens
 from test_granite_model import HF as GRANITE
 from test_kanana_model import HF as KANANA
 from test_lfm2_model import HF as LFM2
 from test_nemotron_h_model import HF as NEMOTRON
+from test_qwen3_next_model import HF as QWEN3_NEXT, _params as qwen3_params
 
 granite_reference = load_reference("granite-4.0-h-micro")
 kanana_reference = load_reference("kanana-2-30b-a3b")
 lfm2_reference = load_reference("lfm2-8b-a1b")
 nemotron_reference = load_reference("nemotron-twotower-30b-a3b")
+qwen3_next_reference = load_reference("qwen3-next-80b-a3b")
 
 
 # -- granite-4.0-h-micro ------------------------------------------------------
@@ -244,3 +247,47 @@ def test_gelu_is_transformers_activation(name, exact):
         ).float().numpy()
     got = np.asarray(tfm._gelu(xb, cfg).astype(jnp.float32))
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2.0 ** -7)
+
+
+# -- qwen3-next-80b-a3b -------------------------------------------------------
+
+def test_qwen3_next_reference_matches_transformers():
+    """The reference (the recurrence over POSITIONS, the zero-centred norms,
+    the gate a column, the gated shared expert) against `transformers`'
+    `Qwen3NextForCausalLM` (its torch CHUNKED gated delta rule, eager
+    attention) on copied seeded weights, every expert held: every key of the
+    loader's state dict lands (`strict=True`: the HF names, the key-head
+    grouping of `in_proj_qkvz` / `in_proj_ba` and the [q | gate] rows of
+    `q_proj` are the published ones), the residual stream after each layer
+    and every token's NLL within 1e-5 of their RMS (measured 1e-7, float32
+    on the CPU on both sides)."""
+    torch = pytest.importorskip("torch", reason="torch is not installed")
+    try:
+        from transformers import Qwen3NextConfig, Qwen3NextForCausalLM
+    except ImportError as e:
+        pytest.skip(f"transformers has no Qwen3NextForCausalLM: {e}")
+    cfg = hf_qwen3_next.config_from_hf(QWEN3_NEXT)
+    sd = hf_qwen3_next.state_dict_from_params(qwen3_params(cfg, 1), cfg)
+    hf_cfg = Qwen3NextConfig(
+        **{k: v for k, v in QWEN3_NEXT.items() if k != "assumed"},
+        attention_dropout=0.0, attn_implementation="eager")
+    assert hf_cfg.layer_types == ["linear_attention"] * 3 + [
+        "full_attention"]
+    model = Qwen3NextForCausalLM(hf_cfg).float().eval()
+    missing = model.load_state_dict(
+        {k: torch.tensor(np.asarray(v)) for k, v in sd.items()}, strict=True)
+    assert not missing.missing_keys and not missing.unexpected_keys
+    tokens, targets = seeded_tokens(QWEN3_NEXT, 3, B=2, T=40)
+    with torch.no_grad():
+        out = model(torch.tensor(np.asarray(tokens), dtype=torch.long),
+                    use_cache=False, output_hidden_states=True)
+    logp = torch.log_softmax(out.logits.double(), -1).numpy()
+    want_nll = -np.take_along_axis(logp, np.asarray(targets)[..., None],
+                                   -1)[..., 0]
+    _, terms = qwen3_next_reference.loss_terms(sd, tokens, targets,
+                                               QWEN3_NEXT)
+    assert rel(terms["nll"], want_nll) < 1e-5
+    # hidden_states[0] is the embedding; the last is after the final norm
+    for i in range(3):
+        assert rel(terms["hidden"][i],
+                   out.hidden_states[i + 1].numpy()) < 1e-5, i
