@@ -654,14 +654,14 @@ GDN_ROW = dict(
 def _gdn_row(sizes, batch, chip):
     """One train step of a model with a Gated DeltaNet layer and a
     gated-attention layer through `make_train_step`: the chunked rule's
-    output on the first layer's own inputs, the decay a head's broadcast over
-    the head's columns, agrees with the recurrence over positions in float64
-    in BOTH forms that can reach a chip: the one the step runs (pass "step":
-    on the chip the Mosaic kernels) and the XLA form that serves a mesh, a
-    ragged T or another chunk (pass "xla": the kernels' rule told it is off
-    the chip), every value finite where the cumulated log-decay is past
-    float32's 1 / exp(G); the step's loss is finite and no held pick is
-    dropped."""
+    output on the first layer's own inputs, the decay a head's, agrees with
+    the recurrence over positions in float64 in BOTH forms that can reach a
+    chip: the one the step runs (pass "step": on the chip the Mosaic head
+    kernels, `gdn_fwd` / `gdn_bwd`) and the XLA form that serves a mesh, a
+    ragged T or another chunk, g broadcast over the head's columns (pass
+    "xla": the kernels' rule told it is off the chip), every value finite
+    where the cumulated log-decay is past float32's 1 / exp(G); the step's
+    loss is finite and no held pick is dropped."""
     import jax
     import jax.numpy as jnp
     from hetu_tpu.kernels import kda as kda_kernel
@@ -701,9 +701,9 @@ def _gdn_row(sizes, batch, chip):
     if chip:
         hlo = step.as_text()
         _check(all(k in hlo for k in ("flash_fwd", "hetu_gdn_scan",
-                                      "hetu_attn_gate")),
+                                      "hetu_attn_gate", "gdn_fwd", "gdn_bwd")),
                "gdn: a kernel or a scope is missing from the compiled step")
-        _check(served == {"step": ["kernel"], "xla": ["xla"]},
+        _check(served == {"step": ["head-kernel"], "xla": ["xla"]},
                f"gdn: the scan was served by {served}")
     loss, params, opt = step(params, opt, tokens, targets)
     _check(_finite(loss), f"gdn: step loss {float(loss)}")

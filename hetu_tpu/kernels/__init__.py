@@ -18,9 +18,11 @@ Layout:
 - :mod:`rope` — the rotation of q and k in one pass through VMEM, latent
   attention's interleaved pairs and every other model's rotate-half
   columns, gated the pre-tier way (``rope.takes``).
-- :mod:`ssd` / :mod:`kda` — the chunked scans of Mamba-2 and of Kimi Delta
-  Attention through VMEM, forward and backward, the state in scratch over
-  the chunks, gated the same way (``ssd.takes``, ``kda.takes``).
+- :mod:`ssd` / :mod:`kda` / :mod:`gdn` — the chunked scans of Mamba-2, of
+  Kimi Delta Attention (the gated delta rule with a decay a channel) and of
+  Gated DeltaNet (a decay a head) through VMEM, forward and backward, the
+  state in scratch over the chunks, gated the same way (``ssd.takes``,
+  ``kda.refusal``, ``gdn.refusal``).
 - :mod:`grouped_matmul` — the experts' grouped matmul, forward, dx and dW,
   with tiles made from the widths, on a TPU in one program at every
   width (``ragged_dot`` under a mesh and off a TPU); registered here

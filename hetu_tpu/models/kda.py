@@ -38,18 +38,20 @@ and the state entering it, and makes the segment again), which keeps
 
 A DECAY A HEAD (Gated DeltaNet: g_t ONE scalar a head and position, alpha_t
 a multiple of the identity) is the same rule, and ``scan`` takes it as g (B,
-T, H) and BROADCASTS it over the head's K columns: the kernels and the form
-below then run it as any channel's decay. Of the lines above only the
-products with exp(G_r[c] - G_i[c]) inside the sum over c are a CHANNEL's
-(``pair_products`` and its sub-blocks, trap (i)'s device); every other line (K
-. exp G, Q . exp G, K decayed to the chunk's end, the state's decay,
-``unit_lower_inverse``, ``_chunk_step``, ``_segment``, ``_cut``) is ANY
-decay's. With G_r a scalar the exponential leaves the sum, A[r, i] = beta_r
-(k_r . k_i) exp(G_r - G_i): two plain matmuls times ONE (C, C) matrix of
-decays, no pairwise tensor. PR 68 wrote that form in XLA, timed it at 16,384
-tokens on the v5e and took it out again: 63.5 ms a scan forward and backward
-against the kernels' 38.4 with g broadcast (docs/KERNELS.md). A Mosaic kernel
-of it is what is left (ROADMAP M6(c)).
+T, Hv) with q and k a KEY head, (B, T, Hk, K), each serving Hv / Hk value
+heads. Of the lines above only the products with exp(G_r[c] - G_i[c]) inside
+the sum over c are a CHANNEL's (``pair_products`` and its sub-blocks, trap
+(i)'s device). With G_r a scalar the exponential leaves the sum, A[r, i] =
+beta_r (k_r . k_i) exp(G_r - G_i): plain products of positions times ONE (C,
+C) matrix of decays, no pairwise tensor. That form runs as two Mosaic
+kernels of its own (``kernels/gdn.py``, PR 69: the Gram matrix of [k; q]
+once a key head in one bfloat16 pass, no levels, q and k bfloat16 in every
+product) wherever their rule admits the call; where it refuses (every CPU
+run, a mesh, another chunk) ``scan`` repeats the key heads, BROADCASTS g over
+the head's K columns and runs the form below as any channel's decay. (PR 68
+wrote the head form in XLA, timed it on the v5e and took it out again: 63.5
+ms a scan forward and backward against 38.4 for the channel kernels with g
+broadcast; the head kernels read 20.6 against 40.1, docs/KERNELS.md.)
 
 float32: g, G, the pairwise products, the triangular system and its inverse,
 U, the carried state and o; q, k and v arrive in the compute dtype and are
@@ -68,7 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..kernels import kda as kda_kernel
+from ..kernels import gdn as gdn_kernel, kda as kda_kernel
 from ..telemetry import tracing
 from ..telemetry.tracing import (REMAT_KDA_INV, SCOPE_KDA_SCAN,
                                  SCOPE_KDA_SOLVE)
@@ -222,39 +224,53 @@ def chunk_log_decay_min(g, chunk):
 
 
 @functools.lru_cache(maxsize=None)
-def _log_form(reason):
+def _log_form(kernel, reason):
     """Once a distinct answer of the rule, at trace time."""
-    _log.info("kda.scan runs in %s", "the Mosaic kernel kda_fwd" if reason
-              is None else f"the XLA form ({reason})")
+    _log.info("kda.scan runs in %s", f"the XLA form ({reason})" if reason else
+              f"the Mosaic kernel {kernel}")
+
+
+# by g's rank, a channel's decay or a head's: (the form's name for
+# `tracing.note_form("kda.scan", ...)`, the kernels' module, their scan, the
+# forward kernel's name in a trace)
+_KERNELS = {4: ("kernel", kda_kernel, kda_kernel.kda, kda_kernel.KDA_FWD),
+            3: ("head-kernel", gdn_kernel, gdn_kernel.gdn, gdn_kernel.GDN_FWD)}
 
 
 def scan(q, k, v, g, beta, chunk, terms=False, mesh=None,
          scope=SCOPE_KDA_SCAN):
     """The gated delta rule over T positions in chunks of ``chunk``: q, k
     (B, T, H, K), v (B, T, H, V), g (B, T, H, K) float32 <= 0 a channel's
-    log-decay, or (B, T, H) a HEAD's, broadcast here over the head's columns
-    (``terms``' G is then (B, T, H) too), beta (B, T, H) float32 -> o (B, T,
-    H, V) float32. ``scope``: the calling mixer's name for the scan. T need
-    not be whole chunks: positions after T are k = v = 0, g = 0, beta = 0,
-    which leave the state as it is.
+    log-decay, beta (B, T, H) float32 -> o (B, T, H, V) float32; or g (B, T,
+    Hv) a HEAD's, with v and beta a value head and q, k (B, T, Hk, K) a KEY
+    head, Hk dividing Hv, key head j serving value heads r j .. r j + r - 1
+    (``terms``' G is then (B, T, Hv) too). ``scope``: the calling mixer's
+    name for the scan. T need not be whole chunks: positions after T are k =
+    v = 0, g = 0, beta = 0, which leave the state as it is.
     ``terms``: -> (o, {U (B, T, H, V), entering (B, c, H, K, V) the state
-    entering each chunk, G (B, T, H, K)}), for checks. Where
-    ``kernels/kda.takes`` admits the call (one program on a TPU, whole
-    chunks of 64, heads of whole lane tiles) the Mosaic kernel serves it,
-    ``terms`` too; everywhere else the form below."""
-    if g.ndim == 3:
-        out = scan(q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta,
-                   chunk, terms, mesh, scope)
-        return (out[0], {**out[1], "G": out[1]["G"][..., 0]}) if terms else out
+    entering each chunk, G (B, T, H, K)}), for checks. Where the kernels'
+    rule admits the call (one program on a TPU, whole chunks of 64, heads of
+    whole lane tiles) Mosaic kernels serve it, ``terms`` too:
+    ``kernels/kda.py``'s a channel's decay, ``kernels/gdn.py``'s a head's;
+    everywhere else the form below, a head's decay broadcast over the head's
+    columns and the key heads repeated."""
+    head = g.ndim == 3
+    form, kernel, run, name = _KERNELS[g.ndim]
+    reason = kernel.refusal(q, k, v, g, beta, chunk, mesh)
+    tracing.note_form("kda.scan", "xla" if reason else form, reason)
+    _log_form(name, reason)
+    if reason is None:
+        return (kernel.terms if terms else run)(q, k, v, g, beta, chunk)
+    if head:
+        if v.shape[2] % k.shape[2]:
+            raise ValueError(f"{v.shape[2]} value heads are not whole groups "
+                             f"on {k.shape[2]} key heads")
+        if v.shape[2] != k.shape[2]:
+            q, k = (jnp.repeat(x, v.shape[2] // k.shape[2], axis=2)
+                    for x in (q, k))
+        g = jnp.broadcast_to(g[..., None], k.shape)
     B, T, H, K = k.shape
     n = min(SEGMENT_CHUNKS, -(-T // chunk))
-    reason = kda_kernel.refusal(q, k, v, g, beta, chunk, mesh)
-    tracing.note_form("kda.scan", "xla" if reason else "kernel", reason)
-    _log_form(reason)
-    if reason is None:
-        if terms:
-            return kda_kernel.terms(q, k, v, g, beta, chunk)
-        return kda_kernel.kda(q, k, v, g, beta, chunk)
     xs = tuple(_cut(x, T, chunk, n) for x in (q, k, v, g, beta[..., None]))
     xs = xs[:4] + (xs[4][..., 0],)
     S0 = jnp.zeros((B, H, K, v.shape[-1]), jnp.float32)
@@ -274,5 +290,6 @@ def scan(q, k, v, g, beta, chunk, terms=False, mesh=None,
     o, U, entering, G = out
     entering = jnp.moveaxis(entering, 2, 0).reshape(
         (B, -1, H) + entering.shape[4:])
+    G = positions(G)
     return positions(o), {"U": positions(U), "entering": entering,
-                          "G": positions(G)}
+                          "G": G[..., 0] if head else G}

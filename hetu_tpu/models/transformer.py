@@ -2094,11 +2094,12 @@ def _kda(h, p, cfg: TransformerConfig, mesh, attn_bias=None):
 
 
 def _gdn_inputs(h, p, cfg: TransformerConfig):
-    """The Gated DeltaNet mixer up to its scan -> (q, k (B, T, Hv, K), both
-    L2-normalised a key head, q times K^-0.5, each key head repeated for its
-    value heads; v (B, T, Hv, V); the compute dtype; g and beta (B, T, Hv)
-    float32, the log-decay a HEAD; z (B, T, Hv * V) the output gate's
-    argument, the compute dtype)."""
+    """The Gated DeltaNet mixer up to its scan -> (q, k (B, T, Hk, K) a KEY
+    head, both L2-normalised, q times K^-0.5 (key head j serves value heads
+    r j .. r j + r - 1: ``kda.scan`` knows, nothing is repeated here); v (B,
+    T, Hv, V); the compute dtype; g and beta (B, T, Hv) float32, the
+    log-decay a HEAD; z (B, T, Hv * V) the output gate's argument, the
+    compute dtype)."""
     m = cfg.gdn
     B, T, _ = h.shape
     with jax.named_scope(SCOPE_GDN_PROJ):
@@ -2114,11 +2115,8 @@ def _gdn_inputs(h, p, cfg: TransformerConfig):
     with jax.named_scope(SCOPE_GDN_GATE):
         q, k, v = jnp.split(qkv, [m.qk_inner, 2 * m.qk_inner], axis=-1)
         q, k = (x.reshape(B, T, m.n_k_heads, m.k_dim) for x in (q, k))
-        q = _kda_l2(q) * m.k_dim ** -0.5
-        k = _kda_l2(k)
-        # key head j serves value heads r j .. r j + r - 1
-        q, k = (jnp.repeat(x.astype(h.dtype), m.n_v_heads // m.n_k_heads,
-                           axis=2) for x in (q, k))
+        q = (_kda_l2(q) * m.k_dim ** -0.5).astype(h.dtype)
+        k = _kda_l2(k).astype(h.dtype)
         v = v.reshape(B, T, m.n_v_heads, m.v_dim).astype(h.dtype)
         b_raw, a_raw = jnp.split(ba, 2, axis=-1)
         g = _kda_log_decay(a_raw, p["gdn_dt_bias"], p["gdn_A_log"])
@@ -2128,8 +2126,8 @@ def _gdn_inputs(h, p, cfg: TransformerConfig):
 
 def _gdn_scan(q, k, v, g, beta, cfg: TransformerConfig, mesh=None,
               terms=False):
-    """``kda.scan`` with the decay a head's (broadcast there over the head's
-    columns), under the mixer's own scope name."""
+    """``kda.scan`` with the decay a head's and q, k a key head, under the
+    mixer's own scope name."""
     from . import kda
     return kda.scan(q, k, v, g, beta, cfg.gdn.chunk, terms=terms, mesh=mesh,
                     scope=SCOPE_GDN_SCAN)
@@ -3487,6 +3485,9 @@ def gdn_terms(params, tokens, cfg: TransformerConfig, heads=None):
         _norm(h, p["ln1_scale"], p["ln1_bias"], cfg), p, cfg)
     low = kda.chunk_log_decay_min(g, cfg.gdn.chunk)
     gate = jax.nn.silu(z.astype(jnp.float32))
+    # a VALUE head's q and k, as the dict returns them
+    q, k = (jnp.repeat(x, cfg.gdn.n_v_heads // cfg.gdn.n_k_heads, axis=2)
+            for x in (q, k))
     if heads is not None:
         take = lambda x: x[:, :, jnp.asarray(heads)]
         q, k, v, g, beta = (take(x) for x in (q, k, v, g, beta))

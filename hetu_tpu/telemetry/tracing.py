@@ -51,7 +51,9 @@ _T0_UNIX = time.time()
 # `kernels/ssd.py` and not in `transformer._ssd`'s einsums; `kda_fwd` and
 # `kda_bwd`, Kimi Delta Attention's chunked scan, whose calls say that a kda
 # layer's scan runs in `kernels/kda.py` and not in `models/kda.py`'s XLA
-# form (`forms("kda.scan")` says so at trace time); `grouped_matmul`
+# form (`forms("kda.scan")` says so at trace time), and `gdn_fwd` and
+# `gdn_bwd` the same of a gdn layer's scan, the rule with a decay a head,
+# in `kernels/gdn.py` (the form `"head-kernel"`); `grouped_matmul`
 # and `grouped_matmul_dw`, the experts' products on a TPU in one program,
 # where `ragged-dot-none` ran before them, under `hetu_moe_experts`).
 STEP = "hetu_step"        # one SubExecutor.run call, step_num=<step>
@@ -191,13 +193,16 @@ SCOPE_GDN_PROJ = "hetu_gdn_proj"    # W_qkvz, W_ba and W_o
 SCOPE_GDN_CONV = "hetu_gdn_conv"    # the ONE causal depthwise convolution
                                     # over [q | k | v] and its SiLU
 SCOPE_GDN_GATE = "hetu_gdn_gate"    # the log-decay g a head, beta, the L2
-                                    # norms of q and k, the key heads'
-                                    # repeat, the head norm and SiLU(z)
+                                    # norms of q and k a KEY head, the head
+                                    # norm and SiLU(z)
 SCOPE_GDN_SCAN = "hetu_gdn_scan"    # the chunked gated delta rule, whichever
                                     # form runs (`kda.scan`'s rule): the
-# Mosaic kernels' ops sit at `.../hetu_gdn_scan/hetu_kda_scan/kda_fwd/...` in
-# all three phases (the kernels' own scope inside the mixer's), the XLA
-# form's triangular system at `.../hetu_gdn_scan/hetu_kda_solve/...`
+# Mosaic head kernels' ops (`kernels/gdn.py`) sit at `.../hetu_gdn_scan/
+# hetu_kda_scan/gdn_fwd/...` in all three phases (the kernels' own scope
+# inside the mixer's) with the cumulated sum of g over (T, Hv) beside them;
+# the XLA form's repeat of the key heads and broadcast of g at `.../
+# hetu_gdn_scan/...`, its triangular system at `.../hetu_gdn_scan/
+# hetu_kda_solve/...`
 GDN_SCOPES = (SCOPE_GDN_PROJ, SCOPE_GDN_CONV, SCOPE_GDN_GATE, SCOPE_GDN_SCAN)
 # learned sparse attention (transformer._dsa; kernels/dsa.py): the indexer
 # beside a grouped-query layer's own projections. The innermost of the four
@@ -888,8 +893,9 @@ _FORMS = []
 
 
 def note_form(site: str, form: str, reason: Optional[str] = None) -> None:
-    """``site`` was served by ``form`` ("kernel" or "xla"); ``reason``: the
-    first thing the kernel's rule refused, where it refused."""
+    """``site`` was served by ``form`` ("kernel", "xla" or, at "kda.scan"
+    with a decay a head, "head-kernel"); ``reason``: the first thing the
+    kernel's rule refused, where it refused."""
     _FORMS.append({"site": site, "form": form, "reason": reason})
 
 

@@ -72,7 +72,14 @@ LOADERS = {"olmoe-1b-7b": hf_olmoe, "ouro-2.6b": hf_ouro,
 # unedited, kimi's, laguna's and olmoe's among them, say that the kind
 # "gdn", `kda.scan`'s broadcast of a head's decay and its `scope=`,
 # `norm_offset`, the gate a column, `shared_gate` and `Router.loss_weights`
-# changed no program that existed.
+# changed no program that existed. ISSUE 69 (Gated DeltaNet's scan as its own
+# Mosaic kernels, `kernels/gdn.py`) renewed qwen3-next's line alone, from
+# 4ec10b2447249c84 at the same 10,898 lines: on the CPU the kernels' rule
+# refuses and the XLA form runs as before, and the repeat of the key heads
+# moved from `_gdn_inputs` into `kda.scan`'s XLA route (under the scan's
+# scope, after the cast); the eleven lines above it, unedited, kimi's among
+# them, say that `scan`'s channel branch and every shared helper lower to
+# what they lowered to.
 PARENT = {
     ("bert-base", "pretrain-seq512"):
         (("9e2f27a018dc2806", 2426), "0ca3cf6cdc80eded"),
@@ -97,7 +104,7 @@ PARENT = {
     ("kimi-linear-48b-a3b", "pretrain-seq16384-b1-ep32share"):
         (("1af449db85b38743", 18938), "58d7205c4347be9c"),
     ("qwen3-next-80b-a3b", "pretrain-seq16384-b1-ep16share"):
-        (("4ec10b2447249c84", 10898), "a40c3da188d4d176"),
+        (("0d199a3d40944a45", 10898), "a40c3da188d4d176"),
 }
 
 

@@ -24,6 +24,7 @@ from hetu_tpu.kernels import dsa
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels import fused_ce as fc
 from hetu_tpu.kernels import grouped_matmul as gmm
+from hetu_tpu.kernels import gdn as gdn_kernel
 from hetu_tpu.kernels import kda as kda_kernel
 from hetu_tpu.kernels import rope
 from hetu_tpu.kernels import ssd
@@ -882,6 +883,61 @@ def test_kda_kernels_compile_for_v5e_at_kimis_scan(
     sizes = {math.prod(map(int, dims.split(",")))
              for kind, dims in re.findall(r"(f32)\[([\d,]+)\]", text)}
     assert max(sizes) <= max(n * H * K * K, T * H * K)
+
+
+# qwen3-next-80b-a3b.pretrain-seq16384-b1-ep16share's scan (batch, seq, key
+# heads, value heads, head columns) and the two heads its check's part (C)
+# asks, their q and k repeated a value head by `gdn_terms`
+QWEN_SCAN = (1, 16384, 16, 32, 128)
+
+
+@pytest.mark.parametrize("half", ["forward", "backward", "terms-two-heads"])
+def test_gdn_kernels_compile_for_v5e_at_qwens_scan(
+        one_chip, no_compile_cache, monkeypatch, half):
+    """Gated DeltaNet's two kernels at the cell's call, one sequence of
+    16,384 positions, 16 key heads under 32 value heads of 128 columns in
+    chunks of 64, bfloat16, four value heads a grid step, and the forward
+    kernel writing its parts for TWO heads: ONE Mosaic call a half under the
+    kernel's name, inside the VMEM Mosaic gives unasked; the entering states
+    are the largest float32 array, q, k and their cotangents stay a KEY
+    head's and nothing (T, Hv * K) float32 but o and its cotangent exists."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
+    B, T, Hk, Hv, K = QWEN_SCAN
+    if half == "terms-two-heads":
+        Hk = Hv = 2
+    n = T // kda_kernel.CHUNK
+
+    def arr(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    key, x, row = arr(B, T, Hk, K), arr(B, T, Hv, K), arr(
+        B, T, Hv, dtype=jnp.float32)
+    assert gdn_kernel.refusal(key, key, x, row, row, kda_kernel.CHUNK) is None
+    assert kda_kernel._heads(Hv) == (2 if half == "terms-two-heads" else 4)
+    if half == "forward":
+        fn, args = (lambda *a: gdn_kernel._gdn_fwd(*a, kda_kernel.CHUNK)[0],
+                    (key, key, x, row, row))
+    elif half == "backward":
+        fn, args = (lambda *a: gdn_kernel._backward(*a, kda_kernel.CHUNK),
+                    (key, key, x, row, row,
+                     arr(B, n, Hv, K, K, dtype=jnp.float32),
+                     arr(B, T, Hv, K, dtype=jnp.float32)))
+    else:
+        fn, args = (lambda *a: gdn_kernel.terms(*a, kda_kernel.CHUNK),
+                    (key, key, x, row, row))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = (gdn_kernel.GDN_FWD, gdn_kernel.GDN_BWD, kda_kernel.KDA_FWD,
+             kda_kernel.KDA_BWD)
+    assert _count_by_name(_kernel_calls(text), names) == {
+        gdn_kernel.GDN_FWD: int(half != "backward"),
+        gdn_kernel.GDN_BWD: int(half == "backward"),
+        kda_kernel.KDA_FWD: 0, kda_kernel.KDA_BWD: 0}
+    assert "vmem_limit_bytes" not in text
+    sizes = {math.prod(map(int, dims.split(",")))
+             for kind, dims in re.findall(r"(f32)\[([\d,]+)\]", text)}
+    assert max(sizes) <= max(n * Hv * K * K, T * Hv * K)
+    if half == "backward":      # dq, dk leave the kernel a key head's
+        assert f"bf16[{B},{T},{Hk * K}]" in text
 
 
 # an expert layer's grouped matmuls at the six expert cells' real calls:

@@ -221,10 +221,12 @@ def test_scan_hands_terms_to_the_kernel_where_the_rule_admits(monkeypatch):
 
 
 def test_a_heads_decay_goes_through_the_kernel_broadcast(monkeypatch):
-    """g (B, T, H), a decay a HEAD (PR 68, the kind "gdn"): where the rule
-    admits the call `scan` runs the SAME kernel with g broadcast over the
-    head's columns, `terms` too (G comes back a head's), and gives what the
-    XLA form gives; off a TPU the XLA form serves it, g broadcast too."""
+    """g (B, T, H), a decay a HEAD (PR 68, the kind "gdn"): off a TPU the XLA
+    form serves it with g broadcast over the head's columns; where the rule
+    admits the call `scan` hands it to the HEAD kernels (PR 69,
+    `kernels/gdn.py`, `tests/test_gdn_kernels.py`), `terms` too (G comes back
+    a head's), which give what the XLA form gives; the channel kernel run by
+    hand on g broadcast gives it too."""
     q, k, v, g, beta = _inputs("two-heads")[:5]
     g = g[..., 0]
     want_o, want = kda.scan(q, k, v, g, beta, CHUNK, terms=True)
@@ -234,12 +236,15 @@ def test_a_heads_decay_goes_through_the_kernel_broadcast(monkeypatch):
         q, k, v, g, beta)
     assert str(traced).count("pallas_call") == 1
     o, terms = kda.scan(q, k, v, g, beta, CHUNK, terms=True)
-    assert tracing.forms("kda.scan")[-1]["form"] == "kernel"
+    assert tracing.forms("kda.scan")[-1]["form"] == "head-kernel"
     assert terms["G"].shape == g.shape
-    for name, got in {"o": o, **terms}.items():
-        assert got.shape == {"o": want_o, **want}[name].shape, name
-        assert _rel(got, {"o": want_o, **want}[name]) <= 3e-6, name
     wide = jnp.broadcast_to(g[..., None], k.shape)
+    for name, got in {"o": o, **terms, "channel": kda_kernel.kda(
+            q, k, v, wide, beta, CHUNK)}.items():
+        like = {"o": want_o, **want, "channel": want_o}[name]
+        assert got.shape == like.shape, name
+        assert _rel(got, like) <= 3e-6, name
+    monkeypatch.setattr(kda_kernel, "_on_tpu", lambda: False)
     np.testing.assert_array_equal(
         np.asarray(kda.scan(q, k, v, g, beta, CHUNK)),
         np.asarray(kda.scan(q, k, v, wide, beta, CHUNK)))

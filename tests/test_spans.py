@@ -15,6 +15,7 @@ import hetu_tpu as ht
 from hetu_tpu.kernels import (csr_spmm, embed_grad, flash_attention,
                               fused_ce, fused_opt, grouped_matmul, quant_comm,
                               registry, rope, ssd)
+from hetu_tpu.kernels import gdn as gdn_kernel
 from hetu_tpu.kernels import kda as kda_kernel
 from hetu_tpu.telemetry import tracing as tr
 
@@ -92,6 +93,12 @@ KERNEL_PROGRAMS = {
         jax.grad(lambda x, g, beta: kda_kernel.kda(
             x, x, x, g, beta, 64).sum()),
         (_f32(1, 128, 2, 128), _f32(1, 128, 2, 128), _f32(1, 128, 2))),
+    gdn_kernel.GDN_FWD: (
+        lambda x, v, g: gdn_kernel.gdn(x, x, v, g, g, 64),
+        (_f32(1, 128, 1, 128), _f32(1, 128, 2, 128), _f32(1, 128, 2))),
+    gdn_kernel.GDN_BWD: (
+        jax.grad(lambda x, v, g: gdn_kernel.gdn(x, x, v, g, g, 64).sum()),
+        (_f32(1, 128, 1, 128), _f32(1, 128, 2, 128), _f32(1, 128, 2))),
     grouped_matmul.GROUPED_MATMUL: (
         grouped_matmul.grouped_matmul,
         (_f32(32, 384), _f32(2, 384, 192), _i32(2))),
